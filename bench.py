@@ -13,7 +13,14 @@ the measured window) which also yields per-pod create->bind latency for
 the p99 the BASELINE asks for.
 
 Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline",
-"p99_pod_to_bind_ms", "p50_pod_to_bind_ms", "trials": [...]}.
+"p99_pod_to_bind_ms", "p50_pod_to_bind_ms", "trials": [...]}, and in
+every payload -- error payloads included -- the device JAX ran on
+("platform", "device_kind", "device_count"), the native host plane's
+state, and "solves_by_tier" (which ladder tier produced each batch's
+answer), so a run that never found a chip or that degraded to numpy
+cannot print the same line as one that did not. The process exits
+non-zero on any "error" payload, and when JAX finds no TPU unless the
+caller asked for the CPU by name (JAX_PLATFORMS=cpu).
 
 Noise robustness: ``--trials K`` (default 3) runs one DISCARDED warmup
 trial followed by K measured trials against the same warmed stack, and
@@ -28,8 +35,8 @@ a re-run bisect. ``--profile`` additionally times the per-pod classify
 stage.
 
 Env knobs: BENCH_NODES (default 5000), BENCH_PODS (default 10000),
-BENCH_BATCH (default 4096 -- the sweep winner: 2048 leaves round-trip
-overlap on the table, 8192 starves the commit pipeline).
+BENCH_BATCH (default 4096 -- picked by a sweep on an earlier machine;
+not re-measured on this one, see PERF.md "Decisions to re-measure").
 
 ``--mode open-loop`` replaces the closed-loop burst with an arrival
 PROCESS (kubernetes_tpu/streaming/): a seeded trace (Poisson by
@@ -73,17 +80,69 @@ BASELINE_PODS_PER_SEC = 30.0  # reference threshold3K
 
 def _host_env() -> dict:
     """Machine-readable run context merged into EVERY payload: the
-    host core count (the --partitions A/B on a 2-core box was
-    core-starved, and the caveat lived only in prose) and whether the
-    native ingest plane actually ran (KTPU_NATIVE_INGEST + build
-    state) -- an A/B against the Python twins is meaningless without
-    the flag recorded."""
+    device JAX ran on (a run that never found a chip must not read like
+    one that did), the host core count (the --partitions A/B on a
+    2-core box was core-starved, and the caveat lived only in prose)
+    and whether the native host plane actually ran (build state +
+    KTPU_NATIVE_INGEST) -- an A/B against the Python twins is
+    meaningless without the flag recorded."""
+    import jax
+
     from kubernetes_tpu import native
 
-    return {
+    devices = jax.devices()
+    env = {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         "host_cores": os.cpu_count() or 0,
+        "native_hotpath": native.hotpath is not None,
         "ingest_native": native.ingest_native_active(),
     }
+    if native.build_error:
+        env["native_build_error"] = native.build_error
+    return env
+
+
+def _tier_counts(scheds) -> dict:
+    """``solves_by_tier`` summed over the run's scheduler stacks: which
+    ladder tier (pallas / xla / host_greedy / sequential) produced each
+    batch's answer. A run that degraded to numpy prints it here."""
+    total: dict = {}
+    for sched in scheds:
+        ladder = getattr(sched, "ladder", None)
+        if ladder is None:
+            continue
+        for tier, count in ladder.solves_by_tier.items():
+            total[tier] = total.get(tier, 0) + count
+    return total
+
+
+def _emit(record: dict, scheds=()) -> int:
+    """Print the run's ONE JSON line -- run context and tier ledger
+    merged in -- and return the process exit code: 1 when the payload
+    carries an "error" (a failed phase never exits 0)."""
+    payload = {
+        **_host_env(),
+        "solves_by_tier": _tier_counts(scheds),
+        **record,
+    }
+    print(json.dumps(payload))
+    return 1 if "error" in payload else 0
+
+
+def _accelerator_error() -> str:
+    """Why this process may not publish numbers: JAX found no TPU and
+    the caller did not ask for the CPU by name. "" when fine."""
+    import jax
+
+    platform = jax.devices()[0].platform
+    if platform == "tpu" or os.environ.get("JAX_PLATFORMS") == "cpu":
+        return ""
+    return (
+        f"no TPU found (platform={platform}); set JAX_PLATFORMS=cpu to "
+        "bench the CPU on purpose"
+    )
 
 
 class BindWatcher:
@@ -161,7 +220,7 @@ class BindWatcher:
         self._thread.join(timeout=2)
 
 
-def run_ha_chaos_bench(fault_seed: int) -> None:
+def run_ha_chaos_bench(fault_seed: int) -> int:
     """The HA failover bench (--fault-profile ha-chaos): TWO full
     scheduler stacks (own informers/cache/queue/solver) leader-elected
     over one shared apiserver, under the seeded ha-chaos profile (renew
@@ -293,7 +352,6 @@ def run_ha_chaos_bench(fault_seed: int) -> None:
     install_injector(None)
 
     record = {
-        **_host_env(),
         "metric": "ha_chaos_failover_takeover",
         "value": round(takeover_s * 1000, 1),
         "unit": "ms",
@@ -307,7 +365,7 @@ def run_ha_chaos_bench(fault_seed: int) -> None:
     }
     if not completed or bound < num_pods:
         record["error"] = f"only {bound}/{num_pods} pods scheduled"
-    print(json.dumps(record))
+    return _emit(record, [stack[2] for stack in stacks])
 
 
 OPEN_LOOP_POLICIES = ("adaptive", "latency-static", "throughput-static")
@@ -353,7 +411,10 @@ def soak_once(
     if not warm_ok:
         sched.stop()
         informers.stop()
-        return {"error": "warmup incomplete", "slo_violation_minutes": -1.0}
+        return {
+            "error": "warmup incomplete", "slo_violation_minutes": -1.0,
+            "solves_by_tier": _tier_counts([sched]),
+        }
 
     offsets = load_trace(
         "diurnal", rate, duration_s, seed=trace_seed,
@@ -420,7 +481,7 @@ def soak_once(
     sched.stop()
     informers.stop()
     record = {
-        **_host_env(),
+        "solves_by_tier": _tier_counts([sched]),
         "metric": "soak_slo_violation_minutes",
         "value": round(violated * bucket_s / 60.0, 3),
         "unit": "minutes",
@@ -442,7 +503,7 @@ def soak_once(
     return record
 
 
-def run_soak_bench(args) -> None:
+def run_soak_bench(args) -> int:
     """--mode soak (ROADMAP item-2 residual c): hours-scale diurnal
     runs, reported as SLO-violation-minutes. Env knobs: SOAK_RATE
     (pods/s, default 600), SOAK_DURATION_S (default 120), SOAK_BUCKET_S
@@ -456,7 +517,7 @@ def run_soak_bench(args) -> None:
         max_batch=int(os.environ.get("BENCH_BATCH", 4096)),
         trace_seed=args.trace_seed,
     )
-    print(json.dumps(record))
+    return _emit(record)
 
 
 def _open_loop_stack(num_nodes, max_batch, policy, slo_s):
@@ -597,7 +658,7 @@ def _open_loop_step(
     return rec
 
 
-def run_open_loop_bench(args) -> None:
+def run_open_loop_bench(args) -> int:
     """The open-loop harness: for each policy, walk the offered-rate
     ladder on the SAME seeded trace shapes and report sustained pods/s
     at the p99 budget. The ladder is monotone: the first failing rung
@@ -631,10 +692,12 @@ def run_open_loop_bench(args) -> None:
         flightrecorder.start_trace()
     jprof.start()
     per_policy = {}
+    scheds = []
     for policy in policies:
         server, client, informers, sched, controller = _open_loop_stack(
             num_nodes, max_batch, policy, slo_s
         )
+        scheds.append(sched)
         if args.high_prio_fraction > 0:
             # arm band-aware draining for the high-priority arrivals
             # (priority 100 >= 50): their p99 rides each step record
@@ -718,7 +781,6 @@ def run_open_loop_bench(args) -> None:
     headline_policy = "adaptive" if "adaptive" in per_policy else policies[0]
     headline = per_policy[headline_policy]
     record = {
-        **_host_env(),
         "metric": "open_loop_sustained_at_slo",
         "value": headline["sustained_at_slo_pods_per_sec"],
         "unit": "pods/s",
@@ -732,10 +794,16 @@ def run_open_loop_bench(args) -> None:
         "max_batch": max_batch,
         "policies": per_policy,
     }
-    print(json.dumps(record))
+    failed = sorted(p for p, v in per_policy.items() if "error" in v)
+    if failed:
+        record["error"] = (
+            "policies failed before measuring: "
+            + ", ".join(f"{p} ({per_policy[p]['error']})" for p in failed)
+        )
+    return _emit(record, scheds)
 
 
-def run_partitioned_burst(args) -> None:
+def run_partitioned_burst(args) -> int:
     """--partitions N: the closed-loop burst through N ACTIVE partitioned
     scheduler stacks (scheduler/partition.py) over ONE apiserver -- the
     horizontal scale-out headline. Each stack owns a node-space slice
@@ -790,8 +858,6 @@ def run_partitioned_burst(args) -> None:
             .capacity(cpu="32", memory="64Gi", pods=110).obj()
         )
     # jit caches are process-global: one warmup compiles for every stack
-    for app in apps:
-        app.sched.max_batch = max_batch
     apps[0].sched.warmup()
     for app in apps:
         app.start()
@@ -811,12 +877,15 @@ def run_partitioned_burst(args) -> None:
     ]
     warm_watch = BindWatcher(server, [p.metadata.name for p in warm])
     client.create_pods_bulk(warm)
+    scheds = [app.sched for app in apps]
     if not warm_watch.wait_for_targets(time.time() + 600):
-        print(json.dumps({
+        warm_watch.stop()
+        for app in apps:
+            app.stop()
+        return _emit({
             "metric": f"pods_per_sec_burst_p{n_parts}", "value": 0.0,
             "unit": "pods/s", "error": "warmup did not complete",
-        }))
-        return
+        }, scheds)
     warm_watch.stop()
     for app in apps:
         app.sched.wait_for_inflight_binds(timeout=60)
@@ -878,15 +947,13 @@ def run_partitioned_burst(args) -> None:
     for app in apps:
         app.stop()
     if err or not trials:
-        print(json.dumps({
+        return _emit({
             "metric": f"pods_per_sec_burst_p{n_parts}", "value": 0.0,
             "unit": "pods/s", "error": err or "no trials",
             **ledger,
-        }))
-        return
+        }, scheds)
     median = pick_median_trial(trials)
     record = {
-        **_host_env(),
         "metric": (
             f"pods_per_sec_"
             f"{f'{num_pods//1000}k' if num_pods >= 1000 else num_pods}"
@@ -905,7 +972,7 @@ def run_partitioned_burst(args) -> None:
     }
     if fault_profile:
         record["fault_profile"] = fault_profile
-    print(json.dumps(record))
+    return _emit(record, scheds)
 
 
 def pick_median_trial(trials):
@@ -1062,7 +1129,7 @@ def run_burst_trial(sched, client, server, num_pods, trial):
     return record
 
 
-def main() -> None:
+def main(argv=None) -> int:
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)
@@ -1173,24 +1240,30 @@ def main() -> None:
         "armed plane's single-tenant overhead (the <5%% headline "
         "guard for ISSUE 15)",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from kubernetes_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+    no_chip = _accelerator_error()
+    if no_chip:
+        return _emit({
+            "metric": "pods_per_sec_burst", "value": 0.0,
+            "unit": "pods/s", "error": no_chip,
+        })
 
     if args.fault_profile == "ha-chaos":
         # the HA failover bench has its own two-stack harness
-        run_ha_chaos_bench(args.fault_seed)
-        return
+        return run_ha_chaos_bench(args.fault_seed)
 
     if args.mode == "soak":
-        run_soak_bench(args)
-        return
+        return run_soak_bench(args)
 
     if args.mode == "open-loop":
-        run_open_loop_bench(args)
-        return
+        return run_open_loop_bench(args)
 
     if args.partitions > 1:
-        run_partitioned_burst(args)
-        return
+        return run_partitioned_burst(args)
 
     num_nodes = int(os.environ.get("BENCH_NODES", 5000))
     num_pods = int(os.environ.get("BENCH_PODS", 10000))
@@ -1253,10 +1326,14 @@ def main() -> None:
     # generous: warmup is off the clock, and large clusters pay bigger
     # one-time compile + first-execution costs before the first bind
     if not warm_watch.wait_for_targets(time.time() + 600):
-        print(json.dumps({"metric": "pods_per_sec_burst", "value": 0.0,
-                          "unit": "pods/s", "vs_baseline": 0.0,
-                          "error": "warmup did not complete"}))
-        return
+        warm_watch.stop()
+        sched.stop()
+        informers.stop()
+        return _emit({
+            "metric": "pods_per_sec_burst", "value": 0.0,
+            "unit": "pods/s", "vs_baseline": 0.0,
+            "error": "warmup did not complete",
+        }, [sched])
     warm_watch.stop()
     sched.wait_for_inflight_binds(timeout=60)
 
@@ -1303,25 +1380,19 @@ def main() -> None:
         jprof.stop()
         sched.stop()
         informers.stop()
-        print(
-            json.dumps(
-                {
-                    "metric": "pods_per_sec_burst",
-                    "value": 0.0,
-                    "unit": "pods/s",
-                    "vs_baseline": 0.0,
-                    "error": str(e),
-                }
-            )
-        )
-        return
+        return _emit({
+            "metric": "pods_per_sec_burst",
+            "value": 0.0,
+            "unit": "pods/s",
+            "vs_baseline": 0.0,
+            "error": str(e),
+        }, [sched])
     sched.stop()
     informers.stop()
 
     median = pick_median_trial(trials)
     pods_per_sec = median["pods_per_sec"]
     record = {
-        **_host_env(),
         "metric": (
             f"pods_per_sec_"
             f"{f'{num_pods//1000}k' if num_pods >= 1000 else num_pods}"
@@ -1352,9 +1423,8 @@ def main() -> None:
         record["quota_grants"] = quota_ctrl.admissions_granted
         record["quota_denials"] = quota_ctrl.admissions_denied
     if fault_profile:
-        # chaos runs report the degradation profile next to throughput
+        # chaos runs name the profile that drove the tier ledger
         record["fault_profile"] = fault_profile
-        record["solves_by_tier"] = dict(sched.ladder.solves_by_tier)
     pre = getattr(sched, "preemptor", None)
     if pre is not None and pre.waves:
         # preemption-wave ledger (ISSUE 11): what the waves actually
@@ -1368,8 +1438,8 @@ def main() -> None:
             "victims_slow_death": pre.victims_slow_death,
             "wave_solves_by_tier": dict(pre.ladder.solves_by_tier),
         }
-    print(json.dumps(record))
+    return _emit(record, [sched])
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

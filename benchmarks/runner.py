@@ -528,8 +528,6 @@ def run_partition_workload(
             nw.label(HOSTNAME_LABEL, f"node-{i}")
             client.create_node(nw.obj())
         for app in apps:
-            app.sched.max_batch = max_batch
-        for app in apps:
             app.start()
         # settle: every partition claimed by exactly one stack. A claim
         # that never lands would otherwise surface 900s later as an
@@ -652,14 +650,15 @@ def run_workload(wl: Dict[str, Any], defaults: Dict[str, Any]) -> Dict[str, Any]
     client = Client(server)
     informers = InformerFactory(server)
     solver_cfg = GreedyConfig(**wl["solver"]) if wl.get("solver") else None
-    # workload-scoped node-axis mesh (the sharded delta path): the
-    # requested device count is CLAMPED to what this process actually
-    # has, so the matrix stays runnable on a 1-chip box (mesh of 1) and
-    # uses the full mesh on multi-chip hardware. CPU boxes can force
-    # virtual devices with KTPU_FORCE_HOST_DEVICES=N (read before jax
-    # initializes, see main()).
+    # workload-scoped node-axis mesh (the sharded delta path). A row
+    # that asks for more devices than this process has still runs, on a
+    # mesh of what is visible (the matrix stays runnable on a 1-chip
+    # box), but says so: the row carries both the requested and the
+    # actual device count, and a warning goes to stderr. CPU boxes can
+    # force virtual devices with KTPU_FORCE_HOST_DEVICES=N (read before
+    # jax initializes, see main()).
     mesh = None
-    mesh_devices = int(wl.get("mesh_devices", 0))
+    mesh_devices_requested = mesh_devices = int(wl.get("mesh_devices", 0))
     if mesh_devices > 0:
         import jax
         from jax.sharding import Mesh
@@ -667,7 +666,13 @@ def run_workload(wl: Dict[str, Any], defaults: Dict[str, Any]) -> Dict[str, Any]
         import numpy as _np
 
         devs = jax.devices()
-        mesh_devices = min(mesh_devices, len(devs))
+        if mesh_devices > len(devs):
+            print(
+                f"{name}: mesh_devices={mesh_devices} requested but only "
+                f"{len(devs)} visible; running on a mesh of {len(devs)}",
+                file=sys.stderr, flush=True,
+            )
+            mesh_devices = len(devs)
         mesh = Mesh(_np.array(devs[:mesh_devices]), axis_names=("nodes",))
     # `fleet:` closes the bind loop (ISSUE 17): a sharded
     # HollowNodeFleet acks every bind into Running, the scheduler's
@@ -1492,6 +1497,7 @@ def run_workload(wl: Dict[str, Any], defaults: Dict[str, Any]) -> Dict[str, Any]
             }
         result["solver"] = {
             "mesh_devices": mesh_devices,
+            "mesh_devices_requested": mesh_devices_requested,
             # which mesh tier the workload ACTUALLY solved on:
             # "pallas" = the shard_map'd per-shard tier (PR 10),
             # "xla" = the GSPMD twin (KTPU_MESH_PALLAS=0, ineligible
@@ -1790,10 +1796,20 @@ def run_workload(wl: Dict[str, Any], defaults: Dict[str, Any]) -> Dict[str, Any]
 
 
 def to_data_items(results: List[Dict[str, Any]]) -> Dict[str, Any]:
-    """The reference dashboard JSON shape (util.go:109 DataItems)."""
+    """The reference dashboard JSON shape (util.go:109 DataItems). Every
+    item names the device JAX ran on, so a CPU row never reads as a
+    chip row."""
+    import jax
+
+    devices = jax.devices()
+    device = {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": str(len(devices)),
+    }
     items = []
     for r in results:
-        labels = {"Name": r["name"]}
+        labels = {"Name": r["name"], **device}
         labels.update(
             {f"solver_{k}": str(v) for k, v in (r.get("solver") or {}).items()}
         )
@@ -1894,6 +1910,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--only", default="", help="substring filter on workload name")
     args = ap.parse_args(argv)
 
+    from kubernetes_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     with open(args.config) as f:
         cfg = yaml.safe_load(f)
     defaults = cfg.get("defaults") or {}

@@ -83,6 +83,9 @@ def main(argv=None) -> int:
         FeatureGate,
         load_config,
     )
+    from kubernetes_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from kubernetes_tpu.config.types import KubeSchedulerConfiguration
     from kubernetes_tpu.scheduler.app import SchedulerApp
 

@@ -2,14 +2,24 @@
 
 The C extension (_hotpath.c) is compiled ON FIRST IMPORT with the
 toolchain baked into the image (g++ against the running interpreter's
-headers -- no pip, no pybind11). A build or import failure degrades
-silently to the pure-Python implementations in api/selectors.py, which
-carry identical semantics (differentially fuzzed in
-tests/test_native_selectors.py).
+headers -- no pip, no pybind11). The built artefact is keyed on the
+CONTENT of _hotpath.c (a hash in its file name), never on mtimes: a
+tree copied with someone else's binary, or with whatever mtimes the
+copy gave it, rebuilds from the source it actually holds instead of
+loading a binary of unknown origin.
+
+A build or import failure degrades to the pure-Python implementations
+in api/selectors.py, which carry identical semantics (differentially
+fuzzed in tests/test_native_selectors.py) -- loudly: the failure is
+logged at WARNING with the compiler's output and kept in
+``build_error`` for bench payloads and chip_smoke.py to report.
 """
 
 from __future__ import annotations
 
+import glob
+import hashlib
+import importlib.util
 import logging
 import os
 import subprocess
@@ -19,20 +29,30 @@ logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "_hotpath.c")
-_SO = os.path.join(
-    _DIR, "_hotpath" + (sysconfig.get_config_var("EXT_SUFFIX") or ".so")
-)
+_EXT = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
+_MODULE = "kubernetes_tpu.native._hotpath"
 
 
-def _build() -> bool:
+def artefact_path(src: str = _SRC, out_dir: str = _DIR) -> str:
+    """Where the extension built from ``src``'s current content lives:
+    ``_hotpath_<sha256[:16]><EXT_SUFFIX>`` under ``out_dir``."""
+    with open(src, "rb") as f:
+        key = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(out_dir, f"_hotpath_{key}{_EXT}")
+
+
+def _build(src: str, so: str) -> None:
+    """Compile ``src`` to ``so``. Raises RuntimeError naming every
+    compiler tried, with its stderr."""
     include = sysconfig.get_paths()["include"]
-    tmp = _SO + f".build.{os.getpid()}"
+    tmp = so + f".build.{os.getpid()}"
+    errors = []
     for cc in ("g++", "cc", "gcc"):
         try:
             subprocess.run(
                 [
                     cc, "-O2", "-shared", "-fPIC", "-x", "c",
-                    f"-I{include}", _SRC, "-o", tmp,
+                    f"-I{include}", src, "-o", tmp,
                 ],
                 check=True,
                 capture_output=True,
@@ -40,36 +60,64 @@ def _build() -> bool:
             )
             # atomic publish: concurrent importers never dlopen a
             # half-written binary
-            os.replace(tmp, _SO)
-            return True
+            os.replace(tmp, so)
+            return
         except FileNotFoundError:
-            continue
-        except Exception as e:  # noqa: BLE001 - try the next compiler
-            logger.debug("native build with %s failed: %s", cc, e)
-            continue
+            errors.append(f"{cc}: not found")
+        except subprocess.CalledProcessError as e:
+            stderr = e.stderr.decode(errors="replace")[-2000:]
+            errors.append(f"{cc}: exit {e.returncode}: {stderr}")
+        except (subprocess.TimeoutExpired, OSError) as e:
+            errors.append(f"{cc}: {e}")
         finally:
             if os.path.exists(tmp):
                 try:
                     os.remove(tmp)
                 except OSError:
                     pass
-    return False
+    raise RuntimeError(
+        f"native build of {src} failed: " + "; ".join(errors)
+    )
+
+
+def ensure_built(src: str = _SRC, out_dir: str = _DIR) -> str:
+    """Path of the extension for ``src``'s current content, building it
+    when that exact artefact is absent. Artefacts of other contents (and
+    the old un-keyed name) are removed after a successful build. Raises
+    RuntimeError with the compilers' output when the build fails."""
+    so = artefact_path(src, out_dir)
+    if os.path.exists(so):
+        return so
+    _build(src, so)
+    for stale in glob.glob(os.path.join(out_dir, "_hotpath*" + _EXT)):
+        if stale != so:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
+    return so
+
+
+def _load(so: str):
+    spec = importlib.util.spec_from_file_location(_MODULE, so)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 hotpath = None
+#: "" when the extension built and loaded; otherwise why the Python
+#: twins are running
+build_error = ""
 try:
-    if not os.path.exists(_SO) or (
-        os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-    ):
-        _build()
-    # gate the import on the binary being CURRENT: importing a stale .so
-    # after a failed rebuild would silently run old matching semantics
-    if os.path.exists(_SO) and (
-        os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-    ):
-        from kubernetes_tpu.native import _hotpath as hotpath  # type: ignore
-except Exception:  # noqa: BLE001 - pure-Python fallback
+    hotpath = _load(ensure_built())
+except Exception as e:  # noqa: BLE001 - pure-Python fallback, reported
     hotpath = None
+    build_error = f"{type(e).__name__}: {e}"
+    logger.warning(
+        "native extension unavailable, running the Python twins: %s",
+        build_error,
+    )
 
 #: single source of truth for the native clone fast path: callers do
 #: ``from kubernetes_tpu.native import cow_clone`` and fall back to

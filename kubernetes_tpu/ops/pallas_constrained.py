@@ -2,10 +2,9 @@
 
 The XLA lowering of ops/assignment.greedy_assign_constrained executes a
 large fused-op chain per pod step (spread skew checks, three affinity
-count families, five score families with per-step normalizes); measured
-on the chip that costs ~2.5ms/step at 640 nodes -- ~25x the basic scan
--- of almost pure per-op dispatch (VERDICT r3 weak #2: PodAntiAffinity
-13x slower than basic). This kernel fuses the ENTIRE constrained step
+count families, five score families with per-step normalizes), most of
+it per-op dispatch (VERDICT r3 weak #2: PodAntiAffinity far slower than
+basic). This kernel fuses the ENTIRE constrained step
 into one pallas_call: every count tensor lives in VMEM for the whole
 batch, and a fori_loop runs fit + spread + affinity + all score families
 + masked argmax + every replay update with no per-op dispatch.
@@ -57,6 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kubernetes_tpu.ops.assignment import GreedyConfig, row_node_values
+from kubernetes_tpu.ops.pallas_solver import COMPILER_PARAMS
 from kubernetes_tpu.ops.scores import MAX_NODE_SCORE, _EPS
 from kubernetes_tpu.tensors.node_tensor import NUM_FIXED_DIMS, PODS
 
@@ -255,6 +255,17 @@ def _constrained_kernel(
         ipa_nv = I("ipa_nv")[:, :]
         ipa_ref = O("ipa")
         ipaw_ref = O("ipaw")
+    # Every state lives in its OUTPUT ref across the batch. The initial
+    # states are inputs aliased to those outputs, but the copy is made
+    # here, not left to the aliasing: read through the output ref alone,
+    # the spread+affinity specialization returned garbage that changed
+    # from call to call at some (n, b) on the v5e (PERF.md, PR 21)
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        for name in oi:
+            if name != "asg":
+                O(name)[:, :] = I(name + "0")[:, :]
+
     w_na = flags_ref[0].astype(jnp.float32)
     w_tt = flags_ref[1].astype(jnp.float32)
     w_sel = flags_ref[2].astype(jnp.float32)
@@ -680,8 +691,10 @@ def constrained_vmem_bytes(
     return bytes_n
 
 
-#: conservative per-core VMEM budget for the gate (v5e/v4 have ~16MB;
-#: leave headroom for Mosaic spills and the pipeline's own buffers)
+#: the constrained kernel's gate on constrained_vmem_bytes. The kernel
+#: compiles under pallas_solver.VMEM_LIMIT_BYTES; on the v5e every
+#: specialization compiled and agreed with the XLA scan up to TWICE
+#: this budget (PERF.md; tools/kernel_parity.py re-runs the sweep)
 VMEM_BUDGET = 13 * (1 << 20)
 
 
@@ -1002,6 +1015,7 @@ def pallas_constrained_solve(
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         input_output_aliases=aliases,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
     asg = outs[oidx["asg"]]

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubernetes_tpu.ops.pallas_solver import COMPILER_PARAMS
 from kubernetes_tpu.tensors.node_tensor import NUM_FIXED_DIMS, PODS
 
 _BIG = 1 << 30
@@ -80,7 +81,7 @@ def _preempt_kernel(
     vactive_ref,   # VMEM [V, N] int32
     cand_rows_ref,  # VMEM [U, N] int32 candidate masks (dedup)
     nomreq_ref,    # VMEM [M*A, N] int32 (nomination m's request, adims)
-    cols_ref,      # ANY/HBM [N, X_pad] int32 row-major victim columns
+    cols_ref,      # HBM [N, 1, X_pad] int32 row-major victim columns
     state_in_ref,  # VMEM [R, N] int32 (aliased -> state_ref)
     chosen_ref,    # OUT SMEM [chunk] int32
     vmask_lo_ref,  # OUT SMEM [chunk] int32 victim bits 0..15
@@ -106,6 +107,13 @@ def _preempt_kernel(
     vactive = vactive_ref[:, :] > 0
     imax = jnp.int32(_IMAX)
     imin = jnp.int32(-(1 << 31) + 1)
+
+    # the nomination carry lives in the output ref; its initial value is
+    # copied in here rather than left to the input/output aliasing (see
+    # pallas_constrained._constrained_kernel)
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        state_ref[:, :] = state_in_ref[:, :]
 
     def body(t, _):
         pod_prio = podprio_ref[t]
@@ -319,9 +327,13 @@ def _preempt_kernel(
         def _fixup():
             # the node's victim columns via ONE contiguous DMA from the
             # HBM row-major copy: cols_ref[node] = [prio V | vact V |
-            # start-bits V | vreq d-major A*V | alloc A]
+            # start-bits V | vreq d-major A*V | alloc A]. The node axis
+            # is a LEADING (untiled) dim indexed whole: a one-row slice
+            # of a 2-D [N, X] operand is not aligned to its (8, 128)
+            # tiling, and Mosaic refuses it once X_pad passes one lane
+            # tile (v_max = 32)
             dma = pltpu.make_async_copy(
-                cols_ref.at[pl.ds(choice, 1), :], colrow_s, dma_sem
+                cols_ref.at[choice], colrow_s, dma_sem
             )
             dma.start()
             # st0 lives in VMEM (updated per placement): extract its
@@ -552,7 +564,7 @@ def pallas_preempt_solve(
         ],
         axis=1,
     )
-    cols = jnp.pad(cols, ((0, 0), (0, x_pad - x)))
+    cols = jnp.pad(cols, ((0, 0), (0, x_pad - x)))[:, None, :]
 
     chosen, vlo, vhi, state_out = pl.pallas_call(
         kernel,
@@ -577,7 +589,7 @@ def pallas_preempt_solve(
             vmem((v, n), whole),
             vmem(cand_rows.shape, whole),
             vmem((m * a, n), whole),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.HBM),
             vmem((r, n), whole),
         ],
         out_specs=(
@@ -593,6 +605,7 @@ def pallas_preempt_solve(
             pltpu.SemaphoreType.DMA,
         ],
         input_output_aliases={14: 3},
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(
         pods_req.astype(jnp.int32).reshape(-1),
@@ -613,9 +626,9 @@ def pallas_preempt_solve(
         cols,
         base_requested.T,
     )
-    # ONE downloadable array: every separate output fetch pays its own
-    # ~120ms serving-link round trip (measured 3 fetches = 363ms against
-    # a near-free kernel). state_out stays device-side: a >512-pod wave
+    # ONE downloadable array instead of three separate output fetches
+    # (a choice made on an earlier machine; the cost of a fetch on this
+    # one is not re-measured). state_out stays device-side: a >512-pod wave
     # chains fixed-size kernel calls through it, keeping ONE compiled
     # variant for every wave size.
     packed = jnp.stack([chosen, vlo, vhi])

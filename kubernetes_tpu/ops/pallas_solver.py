@@ -2,12 +2,10 @@
 2.4: "Pallas kernels where XLA fusion falls short").
 
 The XLA lax.scan lowering of the solver executes ~10 separate vector
-ops per pod step; measured on the chip that costs ~6us/step (~12ms per
-2048-pod batch at 5120 nodes) of almost pure inter-op overhead -- the
-actual VPU work per step is a few [R, N] passes. This kernel runs the
-ENTIRE solve as ONE pallas_call: node state lives in VMEM for the whole
-batch and a fori_loop fuses fit + score + masked argmax + state update
-per step with no per-op dispatch.
+ops per pod step, while the actual VPU work per step is a few [R, N]
+passes. This kernel runs the ENTIRE solve as ONE pallas_call: node
+state lives in VMEM for the whole batch and a fori_loop fuses fit +
+score + masked argmax + state update per step with no per-op dispatch.
 
 Layouts are transposed to [R, N] / [2, N] / [1, B] so the lane axis is
 the node/pod axis (128-multiple by construction: NodeTensor capacity
@@ -16,7 +14,9 @@ and the batch both pad to 128-friendly buckets).
 Semantics are bit-compatible with ops/assignment._greedy_assign_impl
 (same _fits zero-request rules, same scorer arithmetic incl. the f32
 epsilon floors, same lowest-index tie-break); the differential tests
-run the kernel in interpreter mode on CPU against the XLA path.
+run the kernel in interpreter mode on CPU against the XLA path, and
+chip_smoke.py compares the compiled kernel with the XLA scan and the
+numpy replay on the chip.
 """
 
 from __future__ import annotations
@@ -34,6 +34,27 @@ from kubernetes_tpu.ops.scores import MAX_NODE_SCORE, _EPS
 from kubernetes_tpu.tensors.node_tensor import NUM_FIXED_DIMS, PODS
 
 _BIG = 1 << 30  # python int: jnp scalars at module scope become captured consts
+
+#: scoped-VMEM ceiling every pallas_call under ops/ compiles with (a
+#: v5e core has 128 MiB). It is stated here, not inherited from whatever
+#: default the installed libtpu ships (16 MiB on this one), so the "does
+#: it fit" gates are held against a number the code owns. The gates'
+#: budgets sit at under a quarter of it because their estimates are
+#: rough; what this compiler did at and past the gates' edges is in
+#: PERF.md ("Kernels and gates") and re-run by tools/kernel_parity.py.
+VMEM_LIMIT_BYTES = 64 * (1 << 20)
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+#: the basic kernel's gate (ops/assignment.pallas_candidate)
+BASIC_VMEM_BUDGET = 14 * (1 << 20)
+
+
+def basic_vmem_bytes(n: int, r: int, u: int) -> int:
+    """Estimated VMEM residency of ``pallas_greedy_solve`` at ``n`` node
+    columns, ``r`` resource rows and ``u`` static-mask rows: node state
+    in and out, pipeline buffers and step temporaries come to about
+    (10r + 3u + 30) int32 rows per node."""
+    return 4 * n * (10 * r + 3 * u + 30)
 
 
 def _step_fit_score_argmax(
@@ -290,6 +311,7 @@ def pallas_shard_candidate(
             pl.BlockSpec((1,), whole1, memory_space=pltpu.SMEM),
             pl.BlockSpec((1,), whole1, memory_space=pltpu.SMEM),
         ),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(
         pod_req.astype(jnp.int32),
@@ -368,6 +390,7 @@ def pallas_greedy_solve(
             pl.BlockSpec((r, n), whole, memory_space=pltpu.VMEM),
             pl.BlockSpec((2, n), whole, memory_space=pltpu.VMEM),
         ),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(
         mask_index.astype(jnp.int32),

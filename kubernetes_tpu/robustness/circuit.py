@@ -8,14 +8,18 @@ on failure). One breaker per solver tier (ladder.py), so a sick Pallas
 kernel routes subsequent batches straight to the XLA scan during
 cool-off instead of paying the failure per batch.
 
-The watchdog bounds a device solve's wall clock: JAX dispatch can block
-for minutes inside a pathological compile (the bench history's compile
-blowups trip the serving link's dead-man timer), and a wedged serving
-link blocks the result download forever. The guarded call runs on a
-worker thread; on timeout the caller gets SolveTimeout and steps down
-the ladder. The abandoned thread is left to finish/die on its own (a
-wedged device call is not interruptible from Python) -- the breaker
-keeps subsequent batches off the wedged tier.
+The watchdog bounds a device solve's wall clock: a wedged device blocks
+the upload, the dispatch or the result download forever. The guarded
+call runs on a worker thread; on timeout the caller gets SolveTimeout
+and steps down the ladder. The abandoned thread is left to finish/die
+on its own (a wedged device call is not interruptible from Python) --
+the breaker keeps subsequent batches off the wedged tier.
+
+Time the guarded call spends COMPILING does not count against the
+deadline (``_CompileClock``): a signature that was not warmed compiles
+inside the first solve that needs it, and a cold constrained compile on
+the chip runs past a minute -- a healthy first batch must not read as a
+hang and force a breaker open.
 """
 
 from __future__ import annotations
@@ -185,6 +189,71 @@ class RetryPolicy:
         )
 
 
+class _CompileClock:
+    """Per-thread seconds spent inside JAX trace + lower + compile, fed
+    by jax.monitoring. JAX fires its compile events at the END of each
+    phase on the compiling thread: the first trace-end marks a compile
+    in progress, the backend-compile-end closes it, so ``seconds()``
+    also covers a compile that has not finished yet. Only threads
+    between ``begin()`` and ``end()`` (a guarded call's worker) are
+    tracked; every other compile in the process costs one dict lookup.
+    Installed once per process (JAX offers no un-register)."""
+
+    _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+    _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._installed = False
+        self._done: dict = {}  # thread id -> finished compile seconds
+        self._since: dict = {}  # thread id -> start of the open compile
+
+    def install(self) -> None:
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration
+        )
+
+    def _on_duration(self, event: str, duration: float, **_kw) -> None:
+        tid = threading.get_ident()
+        if tid not in self._done:
+            return
+        now = time.monotonic()
+        with self._lock:
+            if event == self._TRACE_EVENT:
+                self._since.setdefault(tid, now - duration)
+            elif event == self._COMPILE_EVENT:
+                since = self._since.pop(tid, now - duration)
+                self._done[tid] += now - since
+
+    def begin(self, tid: int) -> None:
+        """Start tracking ``tid`` from zero (thread ids are reused)."""
+        with self._lock:
+            self._done[tid] = 0.0
+            self._since.pop(tid, None)
+
+    def end(self, tid: int) -> None:
+        with self._lock:
+            self._done.pop(tid, None)
+            self._since.pop(tid, None)
+
+    def seconds(self, tid: int) -> float:
+        with self._lock:
+            total = self._done.get(tid, 0.0)
+            since = self._since.get(tid)
+        if since is not None:
+            total += time.monotonic() - since
+        return total
+
+
+_compile_clock = _CompileClock()
+
+
 class Watchdog:
     """Run a callable with a wall-clock deadline on a worker thread.
 
@@ -204,6 +273,7 @@ class Watchdog:
         self.max_workers = max_workers
         self._lock = threading.Lock()
         self._abandoned = 0
+        _compile_clock.install()
 
     @property
     def abandoned_threads(self) -> int:
@@ -218,7 +288,8 @@ class Watchdog:
     ) -> T:
         """Run ``fn`` with a deadline. Raises SolveTimeout on overrun,
         re-raises fn's own exception otherwise. timeout None/<=0 runs
-        unguarded."""
+        unguarded. Seconds ``fn`` spends compiling extend the deadline
+        (see module docstring)."""
         if not timeout or timeout <= 0:
             return fn()
         with self._lock:
@@ -235,16 +306,30 @@ class Watchdog:
         done = threading.Event()
 
         def run() -> None:
+            _compile_clock.begin(threading.get_ident())
             try:
                 result.append(fn())
             except BaseException as e:  # noqa: BLE001 - relayed to caller
                 error.append(e)
             finally:
+                # done first: the waiter must never read a cleared
+                # clock for a call that has not reported in yet
                 done.set()
+                _compile_clock.end(threading.get_ident())
 
         t = threading.Thread(target=run, name=f"watchdog-{tier}", daemon=True)
+        started = time.monotonic()
         t.start()
-        if not done.wait(timeout):
+        remaining = timeout
+        while not done.wait(max(remaining, 0.05)):
+            # the deadline is on non-compile time: whatever the worker
+            # has spent (or is still spending) compiling is owed back
+            remaining = timeout - (
+                (time.monotonic() - started)
+                - _compile_clock.seconds(t.ident)
+            )
+            if remaining > 0:
+                continue
             with self._lock:
                 self._abandoned += 1
 

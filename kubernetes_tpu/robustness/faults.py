@@ -2,8 +2,8 @@
 path.
 
 Named injection points sit on the seams the bench history has actually
-seen fail (compile blowups, the serving-link dead-man timer, bind
-conflicts under churn, dropped watch streams). Each point fires with a
+seen fail (compile blowups, a wedged device, bind conflicts under
+churn, dropped watch streams). Each point fires with a
 configured probability from its OWN seeded RNG stream, so a chaos run is
 reproducible regardless of thread interleaving: the k-th evaluation of a
 given point always makes the same decision for a given seed.
@@ -28,10 +28,10 @@ class FaultPoint:
     """Injection point names (the seams in the scheduling path)."""
 
     #: device solve raises mid-dispatch (compile blowup, Mosaic lowering
-    #: failure, serving-link error)
+    #: failure, device transfer error)
     DEVICE_SOLVE = "device_solve"
-    #: device solve blocks past the wall-clock watchdog deadline (the
-    #: serving-link dead-man-timer wedge)
+    #: device solve blocks past the wall-clock watchdog deadline (a
+    #: wedged device)
     DEVICE_SOLVE_HANG = "device_solve_hang"
     #: solve "succeeds" but the downloaded assignments are garbage
     #: (NaN-score argmax artifacts, out-of-range node indices)
@@ -61,7 +61,7 @@ class FaultPoint:
     #: once (mass requeue + re-solve), cold replacements join later
     RECLAIM_STORM = "reclaim_storm"
     #: the device victim-search dispatch of a preemption wave raises
-    #: (compile blowup / serving-link error during the wave); the wave's
+    #: (compile blowup / device transfer error during the wave); the wave's
     #: solver ladder must charge the tier's breaker and complete on the
     #: jnp twin (or the host oracle at the floor)
     PREEMPT_SOLVE = "preempt_solve"

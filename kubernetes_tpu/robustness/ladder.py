@@ -6,10 +6,10 @@ Tier semantics:
 - ``pallas``: the fused Pallas kernels (ops/pallas_solver.py /
   pallas_constrained.py), fastest per solve; only live on TPU backends.
 - ``xla``: the plain jitted lax.scan lowering (ops/assignment.py) --
-  same answers, ~4x slower on the chip, immune to Mosaic lowering bugs.
+  same answers, immune to Mosaic lowering bugs.
 - ``host_greedy``: a pure-numpy replay of the unconstrained greedy scan
   (this module) -- no device round trip at all, so it survives a wedged
-  serving link. Constrained batches skip this tier (the constraint
+  device. Constrained batches skip this tier (the constraint
   families only exist as device tensors) and go straight to sequential.
 - ``sequential``: the per-pod oracle path (Scheduler.attempt_schedule)
   -- the floor of the ladder, always correct, always available.
@@ -19,7 +19,13 @@ consecutive failures the tier opens and subsequent batches route
 straight to the next healthy tier during cool-off; a half-open tier
 admits probe batches and closes again on success. Failures also retry
 in place (RetryPolicy) before stepping down, and every device attempt
-runs under the wall-clock Watchdog.
+runs under the wall-clock Watchdog (compile time excluded, see
+circuit.py).
+
+A tier that fails is NEVER re-run in place under its own name:
+ops/assignment.solve_packed raises when the Pallas path throws, and the
+step-down happens here, so ``solves_by_tier`` books each batch under
+the tier that actually produced its answer.
 """
 
 from __future__ import annotations
@@ -55,14 +61,13 @@ class RobustnessConfig:
     YAML form; defaults are production-shaped)."""
 
     #: False turns off the breakers, the watchdog, and in-place retries
-    #: (each batch gets exactly one attempt per tier; a workload whose
-    #: first-batch compile legitimately exceeds solveTimeout can disable
-    #: instead of tuning). The exception->step-down safety net itself
-    #: stays: a failed solve still completes on a lower tier.
+    #: (each batch gets exactly one attempt per tier). The
+    #: exception->step-down safety net itself stays: a failed solve
+    #: still completes on a lower tier.
     enabled: bool = True
-    #: wall-clock deadline for one device solve dispatch+execute; 0
-    #: disables the watchdog (tests that legitimately pay a first-batch
-    #: JIT compile may need a generous value -- compile time counts)
+    #: wall-clock deadline for one device solve's upload + dispatch, and
+    #: for its result download; 0 disables the watchdog. Time spent
+    #: compiling does not count (circuit.py _CompileClock)
     solve_timeout_seconds: float = 60.0
     failure_threshold: int = 3
     cooloff_seconds: float = 5.0
@@ -257,7 +262,11 @@ def _host_fits(free: np.ndarray, pod_req: np.ndarray) -> np.ndarray:
 def _host_score(caps, nzr_state, p_nzr, config) -> np.ndarray:
     """numpy mirror of the device resource scorers (ops/scores.py): same
     float32 arithmetic, same epsilon-floor, so the host tier's placements
-    match the device tiers bit-for-bit on the score path."""
+    match the device tiers bit-for-bit on the score path. The claim is
+    checked where it could break -- against the chip's float32, not only
+    the interpreter's: chip_smoke.py solves one 4096-pod batch on the
+    Pallas kernel, the XLA scan and this replay and requires identical
+    assignments and post-batch state (held on the v5e, PERF.md)."""
     eps = np.float32(1e-4)
     req = (nzr_state + p_nzr[None, :]).astype(np.float32)
     cap = caps.astype(np.float32)
@@ -299,7 +308,7 @@ def host_greedy_assign(
     """Pure-host replay of the unconstrained greedy scan
     (ops/assignment._greedy_assign_impl): same fit semantics, same
     scores, same lowest-index argmax tie-break. Used when both device
-    tiers are down -- no serving-link traffic at all. Returns
+    tiers are down -- no device traffic at all. Returns
     (assignments [B] int32, requested' [N, R], nzr' [N, 2]).
 
     The attachable-volume count columns (tensors/node_tensor.py) replay

@@ -1,6 +1,6 @@
 """Hot-path admission classification for the batch dispatcher.
 
-The round-5 regression (VERDICT.md: 24,544 -> 18,490 pods/s) came from
+The round-5 regression (VERDICT r5) came from
 re-deriving the solver-admission decision per pod per dispatch cycle:
 ``solver_supported`` walked NUMA annotations, spread constraints, and
 volume sources, and ``volumes_device_safe`` resolved PVC -> PV through

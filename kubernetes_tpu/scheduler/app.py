@@ -7,6 +7,7 @@ election :241-247, sched.Run) and options loading.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import logging
 import signal
@@ -25,7 +26,10 @@ from kubernetes_tpu.scheduler.resilience import (
     ControlPlaneReconciler,
     recover_on_startup,
 )
-from kubernetes_tpu.scheduler.scheduler import Scheduler, new_scheduler
+from kubernetes_tpu.scheduler.scheduler import (
+    Scheduler,
+    wire_scheduler_from_config,
+)
 from kubernetes_tpu.utils import flightrecorder, metrics
 
 logger = logging.getLogger(__name__)
@@ -91,36 +95,23 @@ class SchedulerApp:
         self.client = Client(self.server)
         self.informers = InformerFactory(self.server)
         self.identity = f"scheduler-{uuid.uuid4().hex[:8]}"
-        from kubernetes_tpu.robustness.faults import (
-            injector_from_configuration,
-            install_injector,
+        # one construction path for the binary, the benches and
+        # chip_smoke.py: the whole config surface -- tpuSolver (maxBatch,
+        # solverMode, meshDevices, batchWindow), robustness, containment,
+        # bindAck, streaming, faultInjection -- wires in
+        # scheduler.wire_scheduler_from_config. ``batch=False`` (the
+        # TPUBatchSolver feature gate off) overrides tpuSolver.enabled.
+        cfg = self.config
+        if not batch:
+            cfg = dataclasses.replace(
+                cfg,
+                tpu_solver=dataclasses.replace(
+                    cfg.tpu_solver, enabled=False
+                ),
+            )
+        self.sched: Scheduler = wire_scheduler_from_config(
+            self.client, self.informers, cfg
         )
-        from kubernetes_tpu.robustness.ladder import RobustnessConfig
-
-        self.sched: Scheduler = new_scheduler(
-            self.client,
-            self.informers,
-            profiles=self.config.profiles or None,
-            percentage_of_nodes_to_score=(
-                self.config.percentage_of_nodes_to_score
-            ),
-            batch=batch,
-            extenders=getattr(self.config, "extenders", None),
-            robustness_config=RobustnessConfig.from_configuration(
-                self.config.robustness
-            ),
-        )
-        from kubernetes_tpu.scheduler.scheduler import (
-            apply_streaming_config,
-        )
-
-        apply_streaming_config(
-            self.sched, self.config, self.informers, batch=batch,
-            max_batch=getattr(self.sched, "max_batch", 256),
-        )
-        injector = injector_from_configuration(self.config.fault_injection)
-        if injector is not None:
-            install_injector(injector)
         self.debugger = CacheDebugger(
             self.client,
             self.sched.cache,

@@ -199,8 +199,8 @@ class _EagerDownload:
     concurrently with the next batch's pop/pack instead of serializing
     inside the committer. ``result()`` blocks until the copy lands; the
     committer calls it under the same wall-clock watchdog that guarded
-    the old in-committer ``np.asarray`` (a wedged serving link still
-    times out and trips the breaker)."""
+    the old in-committer ``np.asarray`` (a wedged device still times
+    out and trips the breaker)."""
 
     __slots__ = ("_done", "_value", "_error")
 
@@ -254,11 +254,7 @@ class _JitCacheWatch:
     def refresh(self) -> None:
         from kubernetes_tpu.ops.assignment import jit_cache_sizes
 
-        try:
-            sizes = jit_cache_sizes(self._mesh)
-        except Exception:  # pragma: no cover - probe must never break solves
-            return
-        for sig, n in sizes.items():
+        for sig, n in jit_cache_sizes(self._mesh).items():
             prev = self._last.get(sig, 0)
             if n > prev:
                 metrics.jit_compiles.inc(n - prev, signature=sig)
@@ -276,10 +272,11 @@ class _JitCacheWatch:
 
 POD_BUCKET = 64  # batch padded to a multiple of this to bound re-JITs
 #: constrained batches above this node capacity take the sequential host
-#: path: the XLA constrained scan's compile at >32k nodes runs for
-#: minutes (long enough to trip the serving link's dead-man timer and
-#: wedge the device), and the fused kernel's VMEM gate already excludes
-#: these shapes. The 50k-node regime is a plain-pod churn workload
+#: path: the XLA constrained scan's compile at >32k nodes ran for
+#: minutes on an earlier machine, and the fused kernel's VMEM gate
+#: already excludes these shapes. The cap was set there and is not
+#: re-measured on this one (PERF.md "Decisions to re-measure"). The
+#: 50k-node regime is a plain-pod churn workload
 #: (BASELINE #5); constrained families at that scale are out of the
 #: supported envelope, like the reference's adaptive sampling regime.
 CONSTRAINED_NODE_CAP = 32768
@@ -287,13 +284,38 @@ MASK_ROW_BUCKET = 8  # dedup static-mask rows padded to a multiple of this
 #: solver batches in flight between dispatcher and committer. With the
 #: result download riding its own thread from dispatch time
 #: (_EagerDownload) extra slots keep the committer fed instead of idling
-#: on the serving-link round trip -- but only when the host has cores to
-#: run them: on a 2-core box a deeper pipeline steals GIL time from the
-#: committer (measured ~10% slower at 4 in flight there), so the depth
-#: scales with the host instead of being raised unconditionally.
+#: on the result download -- but only when the host has cores to run
+#: them: on a 2-core box a deeper pipeline steals GIL time from the
+#: committer, so the depth scales with the host instead of being raised
+#: unconditionally. Both this and _EAGER_DOWNLOAD_OK were sized on an
+#: earlier machine and are not re-measured on this one (PERF.md
+#: "Decisions to re-measure").
 MAX_INFLIGHT = max(3, min(6, (os.cpu_count() or 4) // 2))
 #: eager result downloads need a core to run on; see _eager_download
 _EAGER_DOWNLOAD_OK = (os.cpu_count() or 4) >= 4
+
+
+#: the constrained layouts' family combos: the fused kernel specializes
+#: per PRESENT family combo (pallas_constrained.live_caps) -- 2^3 - 1,
+#: each a distinct Caps and pallas compile; the triple comes last
+_FAMILY_COMBOS = (
+    ("sp",), ("af",), ("sc",), ("sp", "af"), ("sp", "sc"), ("af", "sc"),
+    ("sp", "af", "sc"),
+)
+
+
+def _family_pieces(fam_groups: dict, live) -> list:
+    """Packed pieces for one family combo: the ``live`` families ride
+    the buffer as real arrays, absent ones as ConstPiece device
+    constants (the single-device dispatch's ``fam_pieces`` contract)."""
+    from kubernetes_tpu.ops.assignment import ConstPiece
+
+    return [
+        (f"{prefix}{i}", np.asarray(a)) if prefix in live
+        else (f"{prefix}{i}", ConstPiece.from_uniform(a))
+        for prefix, arrs in fam_groups.items()
+        for i, a in enumerate(arrs)
+    ]
 
 
 def solver_supported(pod: Pod) -> bool:
@@ -421,7 +443,7 @@ class _DeviceNodeState:
     """Device-resident node tensors + the generation-handshake
     bookkeeping that validates their reuse.
 
-    Every host->device transfer over the serving link pays a round trip
+    Every host->device transfer pays a round trip
     (SURVEY.md section 7 "hardest parts (e)"), so the solver keeps node
     state ON DEVICE between batches: the scan already returns the
     post-batch (requested, nzr) on device, and the host mirrors the same
@@ -573,7 +595,7 @@ class BatchScheduler(Scheduler):
         self._shadow_lock = threading.Lock()
         # pipelined batches flow dispatcher -> committer through this
         # bounded FIFO; the committer thread owns download + commit so the
-        # dispatcher never blocks on a serving-link round trip
+        # dispatcher never blocks on a device round trip
         self._pending_q: "collections.deque" = collections.deque()
         self._pending_cv = threading.Condition()
         self._committer: Optional[threading.Thread] = None
@@ -700,9 +722,9 @@ class BatchScheduler(Scheduler):
         With ``pipeline=True`` (the production run loop) a pure-resource
         batch may be left in flight on device: the NEXT call dispatches
         its own solve against the device-resident carry BEFORE downloading
-        and committing the previous result, so the serving link's
-        round-trip latency is overlapped with host commit work instead of
-        serializing with it."""
+        and committing the previous result, so the device round trip
+        is overlapped with host commit work instead of serializing with
+        it."""
         ab = self.autobatch
         if ab is not None:
             # one controller decision per interval, taken between
@@ -891,7 +913,7 @@ class BatchScheduler(Scheduler):
         the gang quorum fixup re-solves the same batch, which must not
         see the first attempt's reservations. When the dispatch reused
         the carry, its pre-solve device refs are still alive
-        (``carry_in``) and the rewind costs nothing on the serving link;
+        (``carry_in``) and the rewind uploads nothing;
         otherwise the carry drops and the re-dispatch re-uploads."""
         ci = pending.get("carry_in")
         with self._shadow_lock:
@@ -904,7 +926,7 @@ class BatchScheduler(Scheduler):
         """The batch's downloaded assignments for the gang fixup: await
         the eager copy when one is in flight, else convert now -- under
         the same wall-clock watchdog that guards the committer's
-        download, so a wedged serving link raises SolveTimeout (routed
+        download, so a wedged device raises SolveTimeout (routed
         through _solve_and_commit's recovery) instead of hanging the
         dispatcher thread forever."""
         tier = p.get("tier", TIER_XLA)
@@ -1334,7 +1356,7 @@ class BatchScheduler(Scheduler):
             self._committer.join(timeout=10)
             if self._committer.is_alive():
                 # the join timed out: the committer is wedged (most
-                # likely a hung result download over the serving link).
+                # likely a hung result download).
                 # Silence here would strand in-flight batches invisibly
                 # -- log, count, and raise the degraded-health flag so
                 # operators and the health endpoint see it.
@@ -1378,7 +1400,7 @@ class BatchScheduler(Scheduler):
                     self._pending_cv.notify_all()
 
     def _recover_failed_batch(self, p) -> None:
-        """A committer crash (serving-link error mid-download, commit
+        """A committer crash (device error mid-download, commit
         bug) must not strand the batch's pods as Pending-forever: every
         pod not already assumed goes back through the failure path
         (requeue with backoff + condition), and the device carry is
@@ -2320,13 +2342,14 @@ class BatchScheduler(Scheduler):
             if compress:
                 span.note(compressed=True)
         if self.mesh is None or self.mesh_delta:
-            # single-buffer upload: over the serving link every device_put
-            # operand pays its own round trip (~40-90ms each); the whole
-            # batch -- including a constrained batch's ~40 family count
-            # tensors, which used to pay ~1s of per-leaf link round trips
-            # under host CPU contention -- rides ONE int32 buffer,
-            # re-sliced (and bitcast for float tensors) on device
-            # (ops/assignment.py solve_packed). On a mesh the buffer
+            # single-buffer upload: the whole batch -- including a
+            # constrained batch's ~40 family count tensors -- rides ONE
+            # int32 buffer, re-sliced (and bitcast for float tensors)
+            # on device (ops/assignment.py solve_packed), so a dispatch
+            # is one host->device transfer instead of one per operand.
+            # Chosen on an earlier machine; one transfer versus many is
+            # not re-measured on this one (PERF.md "Decisions to
+            # re-measure"). On a mesh the buffer
             # uploads replicated while the resident node state stays
             # SHARDED over the node axis; the delta-scatter slots apply
             # shard-locally in the sharded twin, so steady-state churn
@@ -2611,10 +2634,7 @@ class BatchScheduler(Scheduler):
                     # (and, for membership churn, the valid mask); keep
                     # the patched refs
                     ds.alloc_dev, ds.valid_dev = alloc_out, valid_out
-                try:
-                    assignments_dev.copy_to_host_async()
-                except AttributeError:
-                    pass
+                assignments_dev.copy_to_host_async()
                 if overlaid:
                     ds.invalidate_carry()
                 else:
@@ -2756,10 +2776,7 @@ class BatchScheduler(Scheduler):
             if self._device_lost_at is not None:
                 self._note_device_rebuilt()
         # start the result transfer now so it overlaps host commit work
-        try:
-            assignments_dev.copy_to_host_async()
-        except AttributeError:
-            pass
+        assignments_dev.copy_to_host_async()
         if overlaid:
             # nominee reservations are virtual: the post-scan state
             # includes them, so it must not become the carry
@@ -3361,7 +3378,7 @@ class BatchScheduler(Scheduler):
         commit pipeline.
 
         The download is the other blocking device interaction (a wedged
-        serving link hangs np.asarray forever), so it runs under the same
+        device hangs np.asarray forever), so it runs under the same
         wall-clock watchdog as the solve, and the result is validated
         before it drives commits: garbage indices from a sick device
         (NaN-score argmax artifacts) must degrade, not bind pods to
@@ -4541,36 +4558,113 @@ class BatchScheduler(Scheduler):
                 config=self.solver_config, mode="constrained",
             )
             jax.block_until_ready(c_steady)
-            # family-combo layouts (absent families ride as ConstPiece
-            # device constants): the kernel specializes per PRESENT
-            # family combo (pallas_constrained.live_caps), so warm the
-            # steady-carry variant of every combo a measured phase can
-            # hit -- 2^3 - 1, each a distinct Caps and pallas compile
-            from kubernetes_tpu.ops.assignment import ConstPiece
-
+            # family-combo layouts: warm the steady-carry variant of
+            # every combo a measured phase can hit (the triple is
+            # already warmed by c_cold/refresh/steady)
             fam_groups = {"sp": noops[0], "af": noops[1], "sc": noops[2]}
-            combos = (
-                ("sp",), ("af",), ("sc",),
-                ("sp", "af"), ("sp", "sc"), ("af", "sc"),
-            )  # the triple is already warmed by c_cold/refresh/steady
-            for live in combos:
-                fam_one = []
-                for prefix, arrs in fam_groups.items():
-                    for i, a in enumerate(arrs):
-                        fam_one.append(
-                            (f"{prefix}{i}", np.asarray(a))
-                            if prefix in live
-                            else (
-                                f"{prefix}{i}",
-                                ConstPiece.from_uniform(a),
-                            )
-                        )
+            for live in _FAMILY_COMBOS[:-1]:
                 out_one = solve_packed(
-                    base + delta_slots + fam_one, alloc_d, valid_d,
-                    req_d, nzr_d,
+                    base + delta_slots + _family_pieces(fam_groups, live),
+                    alloc_d, valid_d, req_d, nzr_d,
                     config=self.solver_config, mode="constrained",
                 )
                 jax.block_until_ready(out_one)
+            self._pallas_canary(nt, padded, fam_groups)
+
+    def _pallas_canary(self, nt, padded: int, fam_groups: dict) -> None:
+        """Hold every Pallas specialization warm-up just compiled to the
+        XLA scan, on a seeded non-trivial problem, before the run loop
+        trusts it. That a kernel compiles does not make it right: on the
+        v5e the spread+affinity specialization returned wrong placements,
+        silently, at some node counts past 16k, until PR 21 repaired its
+        state initialization (PERF.md); this guards the next such. With
+        every family a no-op the constrained kernels must reproduce the
+        basic scan exactly, so ONE reference solve -- on the
+        ``greedy_assign_compact`` signature warm-up already compiled --
+        judges them all, and each probe rides the steady signature its
+        combo just warmed: no compile is added.
+
+        A specialization that disagrees takes the Pallas tier out of the
+        ladder for this (mode, shape) (``ops.assignment.distrust_pallas``)
+        -- loudly -- and the XLA signatures warm in its place."""
+        from kubernetes_tpu.ops.assignment import (
+            distrust_pallas,
+            pallas_candidate,
+        )
+        from kubernetes_tpu.tensors.node_tensor import PODS
+
+        n, r = nt.capacity, nt.dims.num_dims
+        modes = [
+            m for m in (self.solver_mode, "constrained")
+            if m != "sinkhorn"
+            and pallas_candidate(m, padded, n, r, MASK_ROW_BUCKET)
+        ]
+        if not modes:
+            return
+        rng = np.random.default_rng(0)
+        # a half-loaded copy of THIS cluster and a batch of mixed pods
+        load = rng.random((n, 1)) * 0.5
+        requested = (nt.allocatable * load).astype(np.int32)
+        nzr_state = np.ascontiguousarray(requested[:, :2])
+        req = np.zeros((padded, r), dtype=np.int32)
+        req[:, 0] = rng.choice([100, 250, 500, 1000, 2000], padded)
+        req[:, 1] = rng.choice([128, 256, 512, 1024], padded) * 1024
+        req[:, PODS] = 1
+        pod_nzr = np.ascontiguousarray(req[:, :2])
+        rows = np.ones((MASK_ROW_BUCKET, n), dtype=bool)
+        midx = np.zeros(padded, dtype=np.int32)
+        active = np.ones(padded, dtype=bool)
+        reference = np.asarray(greedy_assign_compact(
+            nt.allocatable, requested, nzr_state, nt.valid,
+            req, pod_nzr, rows, midx, active, config=self.solver_config,
+        )[0])
+        state = jax.device_put(
+            (nt.allocatable, nt.valid, requested, nzr_state)
+        )
+        pieces = [
+            ("req", req), ("nzr", pod_nzr), ("midx", midx),
+            ("active", active.astype(np.int32)),
+            ("rows", rows.astype(np.int32)),
+        ] + _delta_slot_pieces(n, r)
+
+        def agrees(mode: str, fam: list) -> bool:
+            out = solve_packed(
+                pieces + fam, *state,
+                config=self.solver_config, mode=mode,
+            )
+            return np.array_equal(np.asarray(out[0]), reference)
+
+        for mode in modes:
+            # per combo, the family pieces riding the buffer (the basic
+            # modes have none)
+            probes = {mode: []} if mode != "constrained" else {
+                "+".join(live): _family_pieces(fam_groups, live)
+                for live in _FAMILY_COMBOS
+            }
+            bad = [
+                name for name, fam in probes.items()
+                if not agrees(mode, fam)
+            ]
+            if not bad:
+                continue
+            distrust_pallas(mode, padded, n)
+            metrics.solver_fallbacks.inc(
+                tier=TIER_XLA, reason="pallas_canary_mismatch"
+            )
+            flightrecorder.mark(
+                "fallback", tier=TIER_XLA, reason="pallas_canary_mismatch",
+            )
+            logger.error(
+                "Pallas %s kernel(s) %s disagree with the XLA scan at "
+                "b=%d n=%d: the Pallas tier is off for this shape",
+                mode, bad, padded, n,
+            )
+            # the XLA tier now serves these batches: warm ITS signatures
+            for fam in probes.values():
+                jax.block_until_ready(solve_packed(
+                    pieces + fam, *state,
+                    config=self.solver_config, mode=mode,
+                ))
 
     def _warmup_mesh_packed(self, nt, padded: int, full: bool) -> None:
         """Sharded-twin warmup: compile every packed-upload layout the

@@ -613,10 +613,10 @@ class TestEagerDownload:
 
         class Boom:
             def __array__(self, *a, **k):
-                raise RuntimeError("serving link down")
+                raise RuntimeError("device link down")
 
         dl = _EagerDownload(Boom())
-        with pytest.raises(RuntimeError, match="serving link down"):
+        with pytest.raises(RuntimeError, match="device link down"):
             dl.result()
 
     def test_pipeline_binds_with_eager_downloads_forced(self, cluster, monkeypatch):

@@ -2,7 +2,10 @@
 median headline; see the bench module docstring)."""
 
 import importlib.util
+import json
 import os
+
+import pytest
 
 _BENCH_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py"
@@ -57,3 +60,59 @@ def test_trials_flag_defaults():
     # mirror of bench.main's registration: default 3 measured trials
     ap.add_argument("--trials", type=int, default=3)
     assert ap.parse_args([]).trials == 3
+
+
+# -- exit codes and run context (ISSUE 21) ---------------------------------
+
+
+@pytest.fixture
+def no_cache_config(monkeypatch):
+    """bench.main places the persistent compile cache; keep the test
+    session's own compile configuration untouched."""
+    from kubernetes_tpu.utils import compile_cache
+
+    monkeypatch.setattr(
+        compile_cache, "configure_compile_cache", lambda: ""
+    )
+
+
+def _last_payload(capsys):
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_incomplete_burst_exits_nonzero(monkeypatch, capsys, no_cache_config):
+    """A burst that does not fully schedule ends the process non-zero,
+    and even the error payload names the device and the tier ledger."""
+    monkeypatch.setenv("BENCH_NODES", "8")
+    monkeypatch.setenv("BENCH_PODS", "16")
+    monkeypatch.setenv("BENCH_BATCH", "64")
+
+    def incomplete(sched, client, server, num_pods, trial):
+        raise AssertionError(
+            f"only 3/{num_pods} pods scheduled in trial {trial}"
+        )
+
+    monkeypatch.setattr(bench, "run_burst_trial", incomplete)
+    assert bench.main(["--trials", "1"]) == 1
+    payload = _last_payload(capsys)
+    assert payload["error"] == "only 3/16 pods scheduled in trial 0"
+    assert payload["platform"] == "cpu"
+    assert payload["device_kind"] and payload["device_count"] >= 1
+    assert set(payload["solves_by_tier"]) == {
+        "pallas", "xla", "host_greedy", "sequential",
+    }
+    # the warm burst did solve, on the only tier a CPU has
+    assert payload["solves_by_tier"]["xla"] > 0
+    assert payload["native_hotpath"] is True
+
+
+def test_no_tpu_is_refused_unless_cpu_is_asked_for_by_name(
+    monkeypatch, capsys, no_cache_config
+):
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert bench.main(["--trials", "1"]) == 1
+    payload = _last_payload(capsys)
+    assert "no TPU found" in payload["error"]
+    assert payload["platform"] == "cpu"
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert bench._accelerator_error() == ""

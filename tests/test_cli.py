@@ -80,3 +80,39 @@ def test_binary_boots_and_serves(tmp_path):
         app.stop()
     assert bound
     assert "scheduler_schedule_attempts_total" in metrics_body
+
+
+def test_binary_runs_the_configured_solver():
+    """SchedulerApp builds through new_scheduler_from_config: the
+    tpuSolver block reaches the scheduler the binary runs (it used to be
+    dropped, so the binary always ran maxBatch=256 with no mesh)."""
+    from kubernetes_tpu.config.loader import load_config_from_dict
+    from kubernetes_tpu.config.types import KubeSchedulerConfiguration
+    from kubernetes_tpu.scheduler.app import SchedulerApp
+    from kubernetes_tpu.scheduler.batch import BatchScheduler
+    from kubernetes_tpu.scheduler.scheduler import Scheduler
+
+    default = SchedulerApp(config=KubeSchedulerConfiguration()).sched
+    assert isinstance(default, BatchScheduler)
+    assert default.max_batch == 256
+    assert default.solver_mode == "greedy"
+    assert default.mesh is None
+    assert default.batch_window == 0.01
+
+    cfg = load_config_from_dict({
+        "tpuSolver": {
+            "maxBatch": 1024, "solverMode": "sinkhorn",
+            "batchWindow": "50ms", "meshDevices": 2,
+        }
+    })
+    sched = SchedulerApp(config=cfg).sched
+    assert sched.max_batch == 1024
+    assert 1024 in sched._warmup_pads
+    assert sched.solver_mode == "sinkhorn"
+    assert sched.batch_window == pytest.approx(0.05)
+    assert sched.mesh is not None and sched.mesh.devices.size == 2
+
+    # the TPUBatchSolver gate off still wins over tpuSolver.enabled
+    host = SchedulerApp(config=cfg, batch=False).sched
+    assert type(host) is Scheduler
+    assert cfg.tpu_solver.enabled is True  # caller's config untouched

@@ -194,6 +194,30 @@ class TestWatchdog:
         )
         assert tid == [threading.get_ident()]
 
+    def test_compile_time_does_not_count_against_the_deadline(self):
+        """A cold compile inside the guarded call (JAX fires trace-end,
+        then backend-compile-end, on the compiling thread) extends the
+        deadline by its own length; only non-compile time times out."""
+        import jax.monitoring as mon
+
+        def compile_for(seconds):
+            mon.record_event_duration_secs(
+                "/jax/core/compile/jaxpr_trace_duration", 0.0
+            )
+            time.sleep(seconds)
+            mon.record_event_duration_secs(
+                "/jax/core/compile/backend_compile_duration", seconds
+            )
+
+        wd = Watchdog()
+        assert wd.call(
+            lambda: (compile_for(0.5), "solved")[1], timeout=0.2
+        ) == "solved"
+        with pytest.raises(SolveTimeout):
+            wd.call(
+                lambda: (compile_for(0.3), time.sleep(1.0)), timeout=0.2
+            )
+
 
 class TestRetryPolicy:
     def test_exponential_backoff_capped(self):
@@ -296,6 +320,184 @@ class TestSolverLadder:
         )
         assert tier == TIER_XLA
         assert lad.breakers[TIER_XLA].state == CLOSED
+
+
+class TestHonestTiers:
+    """A Pallas failure is never re-run in place under the pallas name:
+    solve_packed raises, the ladder steps down, and the batch is booked
+    under the tier that produced its answer."""
+
+    @staticmethod
+    def _pieces(n=128, b=64, r=4):
+        rng = np.random.default_rng(0)
+        alloc = np.zeros((n, r), np.int32)
+        alloc[:, 0] = 8000
+        alloc[:, 1] = 16 << 20
+        alloc[:, 3] = 110
+        req = np.zeros((b, r), np.int32)
+        req[:, 0] = rng.choice([100, 500], b)
+        req[:, 3] = 1
+        return [
+            ("req", req),
+            ("nzr", req[:, :2].copy()),
+            ("midx", np.zeros(b, np.int32)),
+            ("active", np.ones(b, np.int32)),
+            ("rows", np.ones((8, n), np.int32)),
+            ("alloc", alloc),
+            ("valid", np.ones(n, np.int32)),
+            ("req_state", np.zeros((n, r), np.int32)),
+            ("nzr_state", np.zeros((n, 2), np.int32)),
+        ]
+
+    def test_solve_packed_raises_when_the_pallas_path_throws(
+        self, monkeypatch
+    ):
+        from kubernetes_tpu.ops import assignment
+
+        pieces = self._pieces()
+        # off-TPU the compiled kernel cannot lower: forcing the
+        # candidate predicate on makes the Pallas path throw for real
+        monkeypatch.setattr(
+            assignment, "pallas_candidate", lambda *a, **k: True
+        )
+        with pytest.raises(Exception, match="interpret mode"):
+            assignment.solve_packed(pieces, None, None, None, None)
+        # the same call with the ladder's xla-tier switch solves
+        out = assignment.solve_packed(
+            pieces, None, None, None, None, allow_pallas=False
+        )
+        assert (np.asarray(out[0]) >= 0).all()
+
+    def test_ladder_books_the_batch_under_xla(self, monkeypatch):
+        from kubernetes_tpu.apiserver.server import APIServer
+        from kubernetes_tpu.client.client import Client
+        from kubernetes_tpu.client.informer import InformerFactory
+        from kubernetes_tpu.ops import assignment
+        from kubernetes_tpu.scheduler.scheduler import new_scheduler
+        from kubernetes_tpu.testing import make_node, make_pod
+        from kubernetes_tpu.utils import metrics
+
+        monkeypatch.setattr(
+            assignment, "pallas_candidate", lambda *a, **k: True
+        )
+        server = APIServer()
+        client = Client(server)
+        informers = InformerFactory(server)
+        sched = new_scheduler(
+            client, informers, batch=True, max_batch=64,
+            robustness_config=RobustnessConfig(
+                retry=RetryPolicy(max_attempts=1),
+            ),
+        )
+        for i in range(8):
+            client.create_node(
+                make_node(f"node-{i}")
+                .capacity(cpu="8", memory="16Gi", pods=110).obj()
+            )
+        informers.start()
+        informers.wait_for_cache_sync()
+        fallbacks0 = metrics.solver_fallbacks.value(
+            tier="xla", reason="pallas_error"
+        )
+        try:
+            for i in range(16):
+                client.create_pod(
+                    make_pod(f"p-{i}").container(cpu="100m").obj()
+                )
+            deadline = time.time() + 10
+            while (
+                sched.queue.active_count() < 16 and time.time() < deadline
+            ):
+                time.sleep(0.01)
+            assert sched.schedule_batch(timeout=1.0) == 16
+            sched.wait_for_inflight_binds(timeout=30)
+        finally:
+            sched.stop()
+            informers.stop()
+        tiers = sched.ladder.solves_by_tier
+        assert tiers["pallas"] == 0 and tiers["xla"] == 1, tiers
+        assert metrics.solver_fallbacks.value(
+            tier="xla", reason="pallas_error"
+        ) == fallbacks0 + 1
+        bound = [p for p in client.list_pods()[0] if p.spec.node_name]
+        assert len(bound) == 16
+
+
+class TestPallasCanary:
+    """A compiled kernel is held to the XLA scan at warm-up before the
+    run loop trusts it (BatchScheduler._pallas_canary): on the chip one
+    constrained specialization compiled fine and placed wrongly."""
+
+    def test_disagreeing_specialization_loses_its_tier(self, monkeypatch):
+        import jax
+
+        from kubernetes_tpu.apiserver.server import APIServer
+        from kubernetes_tpu.client.client import Client
+        from kubernetes_tpu.client.informer import InformerFactory
+        from kubernetes_tpu.ops import assignment
+        from kubernetes_tpu.ops import pallas_constrained, pallas_solver
+        from kubernetes_tpu.scheduler.scheduler import new_scheduler
+        from kubernetes_tpu.testing import make_node
+        from kubernetes_tpu.utils import metrics
+
+        # stand-ins for the compiled kernels (a CPU cannot lower them):
+        # the greedy one is right, the constrained one is wrong for the
+        # spread+affinity specialization only
+        bad_caps = pallas_constrained.live_caps(True, True, False)
+
+        def fake_constrained(*args, config, caps=None, **_kw):
+            a, req, nzr = assignment.greedy_assign_constrained(
+                *args, config=config
+            )
+            if caps == bad_caps:
+                a = jax.numpy.where(a >= 0, 0, a)
+            return a, req, nzr
+
+        # traces of the REAL kernels (TestHonestTiers) must not be reused
+        jax.clear_caches()
+        monkeypatch.setattr(assignment, "_PALLAS_DISTRUST", set())
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            pallas_solver, "pallas_greedy_solve",
+            assignment.greedy_assign_compact,
+        )
+        monkeypatch.setattr(
+            pallas_constrained, "pallas_constrained_solve", fake_constrained
+        )
+        server = APIServer()
+        client = Client(server)
+        informers = InformerFactory(server)
+        sched = new_scheduler(client, informers, batch=True, max_batch=64)
+        for i in range(48):
+            client.create_node(
+                make_node(f"node-{i}")
+                .capacity(cpu="8", memory="16Gi", pods=110).obj()
+            )
+        informers.start()
+        informers.wait_for_cache_sync()
+        before = metrics.solver_fallbacks.value(
+            tier="xla", reason="pallas_canary_mismatch"
+        )
+        try:
+            sched.warmup()
+            nt = sched.tensor_cache.update(sched.algorithm.snapshot)
+            n, r = nt.capacity, nt.dims.num_dims
+            assert assignment._PALLAS_DISTRUST == {("constrained", 64, n)}
+            assert sched._device_tiers("constrained", 64, n, r, 8) == [
+                "xla"
+            ]
+            # the kernel that agreed keeps its tier
+            assert sched._device_tiers("greedy", 64, n, r, 8) == [
+                "pallas", "xla",
+            ]
+            assert metrics.solver_fallbacks.value(
+                tier="xla", reason="pallas_canary_mismatch"
+            ) == before + 1
+        finally:
+            sched.stop()
+            informers.stop()
+            # the stand-ins were traced into the process-wide jit caches
+            jax.clear_caches()
 
 
 class TestHostGreedyParity:

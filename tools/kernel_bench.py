@@ -1,7 +1,7 @@
 """Microbench the assignment kernels at bench shapes.
 
 Times greedy_assign_compact / greedy_assign_constrained for
-N=5000 nodes x B=2048 pods (the BENCH_r* shape): compile time, then
+N=5000 nodes x B=2048 pods: compile time, then
 steady-state solve latency with and without the result download.
 
 Usage: python tools/kernel_bench.py [N] [B]
@@ -25,6 +25,9 @@ from kubernetes_tpu.ops.assignment import (
 
 
 def main() -> None:
+    from kubernetes_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 5000
     b = int(sys.argv[2]) if len(sys.argv) > 2 else 2048
     r = 8
@@ -82,8 +85,8 @@ def main() -> None:
     jax.block_until_ready(out)
     print(f"dispatch (async) returned in {t_dispatch*1000:.1f} ms")
 
-    # A/B vs the fused Pallas kernel via forced 10-solve chains (the
-    # serving link's ~100ms round trip masks single-solve timings)
+    # A/B vs the fused Pallas kernel via forced 10-solve chains (a
+    # dependent chain amortizes the per-dispatch round trip)
     from kubernetes_tpu.ops.pallas_solver import pallas_greedy_solve
 
     def chain(fn, k):
