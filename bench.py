@@ -52,13 +52,11 @@ the budget at low rate doesn't get credit for a lucky high-rate pass.
 Open-loop env knobs: OPEN_LOOP_RATES, OPEN_LOOP_STEP_S; BENCH_NODES
 defaults to 2000 in this mode.
 
-Observability (ISSUE 13): ``--trace out.json`` arms the flight
-recorder's Chrome-trace buffer for the measured window and writes a
-Perfetto-loadable timeline (host stage spans per thread, device solve
-spans, ArrivalEngine backpressure stalls, autobatch decisions as
-instant events) -- load it at ui.perfetto.dev. ``--jax-profile DIR``
-brackets the measured window with ``jax.profiler`` for device-side
-attribution on real hardware (the v5e campaign artifact). Closed-loop
+Observability: ``--jax-profile DIR`` brackets the measured window with
+``jax.profiler``; the trace holds the device's operations and, on the
+same clock, the scheduler's own ``sched/*`` stage spans per thread and
+its ``sched/mark/*`` events (utils/flightrecorder.py ``stage`` /
+``mark``). It opens in Perfetto or TensorBoard. Closed-loop
 trials also record the LIVE p50/p99 pod-to-bind gauges (the P-squared
 sketch behind ``scheduler_pod_to_bind_quantile_seconds``) next to the
 bench-computed percentiles, so the streaming estimate is checked
@@ -682,14 +680,8 @@ def run_open_loop_bench(args) -> int:
     ]
 
     from kubernetes_tpu.testing import make_pod
-    from kubernetes_tpu.utils import flightrecorder
 
     jprof = _JaxProfileWindow(args.jax_profile)
-    if args.trace:
-        # arm the Chrome-trace buffer for the whole ladder: stage spans
-        # per thread, device solves, arrival stalls, and the adaptive
-        # policy's autobatch instant events all land on one timeline
-        flightrecorder.start_trace()
     jprof.start()
     per_policy = {}
     scheds = []
@@ -772,12 +764,6 @@ def run_open_loop_bench(args) -> int:
         }
 
     jprof.stop()
-    if args.trace:
-        n_events = flightrecorder.export_chrome_trace(args.trace)
-        print(
-            f"chrome trace: {n_events} events -> {args.trace}",
-            file=sys.stderr,
-        )
     headline_policy = "adaptive" if "adaptive" in per_policy else policies[0]
     headline = per_policy[headline_policy]
     record = {
@@ -1036,7 +1022,7 @@ def run_burst_trial(sched, client, server, num_pods, trial):
     trajectory without a --profile re-run. ``--profile`` only adds the
     per-pod classify timer."""
     from kubernetes_tpu.testing import make_pod
-    from kubernetes_tpu.utils import metrics, timeline
+    from kubernetes_tpu.utils import metrics
 
     # fresh live-quantile window per trial: the recorded live p50/p99
     # below then answer for THIS trial's distribution, directly
@@ -1070,9 +1056,7 @@ def run_burst_trial(sched, client, server, num_pods, trial):
                 create_times[p.metadata.name] = now
             client.create_pods_bulk(chunk)
 
-    timeline.reset()
     start = time.perf_counter()
-    timeline.mark("burst_start")
     creators = [
         threading.Thread(target=create_shard, args=(s,)) for s in shards
     ]
@@ -1080,9 +1064,7 @@ def run_burst_trial(sched, client, server, num_pods, trial):
         c.start()
     for c in creators:
         c.join()
-    timeline.mark("creates_done")
     completed = watcher.wait_for_targets(time.time() + 600)
-    timeline.mark("all_bound")
     elapsed = time.perf_counter() - start
     sched.wait_for_inflight_binds(timeout=60)
     watcher.stop()
@@ -1103,8 +1085,6 @@ def run_burst_trial(sched, client, server, num_pods, trial):
     )
     p50 = latencies[len(latencies) // 2]
     p99 = latencies[min(len(latencies) - 1, (len(latencies) * 99) // 100)]
-    if timeline.ENABLED:
-        print(timeline.dump(start), file=sys.stderr)
     record = {
         "trial": trial,
         "pods_per_sec": round(num_pods / elapsed, 1),
@@ -1154,22 +1134,15 @@ def main(argv=None) -> int:
         "--arrival-trace",
         default=os.environ.get("OPEN_LOOP_TRACE", "poisson"),
         choices=("poisson", "bursty", "diurnal", "replay"),
-        help="open-loop arrival trace kind (streaming/arrivals.py); "
-        "was --trace before the Chrome-trace exporter took that name",
-    )
-    ap.add_argument(
-        "--trace", default=os.environ.get("BENCH_TRACE_OUT", ""),
-        metavar="OUT.json",
-        help="write the measured window as Chrome-trace/Perfetto JSON "
-        "(host stage spans per thread + device solve spans + arrival "
-        "stalls + autobatch instant events); load at ui.perfetto.dev",
+        help="open-loop arrival trace kind (streaming/arrivals.py)",
     )
     ap.add_argument(
         "--jax-profile", default=os.environ.get("BENCH_JAX_PROFILE", ""),
         metavar="DIR",
-        help="bracket the measured window with jax.profiler traces "
-        "written to DIR (device-side attribution for the real-hardware "
-        "campaign; no-op when the profiler is unavailable)",
+        help="bracket the measured window with a jax.profiler trace "
+        "written to DIR: the device's operations and the scheduler's "
+        "sched/* stage spans on one clock (no-op when the profiler is "
+        "unavailable)",
     )
     ap.add_argument(
         "--trace-seed", type=int,
@@ -1351,17 +1324,12 @@ def main(argv=None) -> int:
     # capture cannot move the recorded numbers.
     num_trials = max(1, args.trials)
     trials = []
-    from kubernetes_tpu.utils import flightrecorder
-
     jprof = _JaxProfileWindow(args.jax_profile)
     try:
         for trial in range(num_trials + 1):
             if trial == 1:
                 # measured window starts here (trial 0 is the
-                # discarded warmup): arm the Chrome-trace buffer and
-                # the jax profiler bracket
-                if args.trace:
-                    flightrecorder.start_trace()
+                # discarded warmup): open the jax profiler bracket
                 jprof.start()
             rec = run_burst_trial(sched, client, server, num_pods, trial)
             if trial == 0:
@@ -1370,12 +1338,6 @@ def main(argv=None) -> int:
                 continue
             trials.append(rec)
         jprof.stop()
-        if args.trace:
-            n_events = flightrecorder.export_chrome_trace(args.trace)
-            print(
-                f"chrome trace: {n_events} events -> {args.trace}",
-                file=sys.stderr,
-            )
     except AssertionError as e:
         jprof.stop()
         sched.stop()

@@ -1177,11 +1177,7 @@ def run_workload(wl: Dict[str, Any], defaults: Dict[str, Any]) -> Dict[str, Any]
         coll = BindCollector(server, target_names)
         create_times: Dict[str, float] = {}
 
-        from kubernetes_tpu.utils import timeline as _timeline
-
-        _timeline.reset()
         start = time.perf_counter()
-        _timeline.mark("burst_start")
         scenario_thread = None
         if lifecycle_scenario is not None:
             scenario_thread = threading.Thread(
@@ -1316,8 +1312,6 @@ def run_workload(wl: Dict[str, Any], defaults: Dict[str, Any]) -> Dict[str, Any]
             # settled; the measured window ends at the LAST BIND, not at
             # the detector's return
             elapsed = max(coll.bind_times.values()) - start
-        if _timeline.ENABLED:
-            print(_timeline.dump(start), file=sys.stderr, flush=True)
         sched.wait_for_inflight_binds(timeout=60)
 
         if poison_names:

@@ -20,8 +20,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from kubernetes_tpu import native as _native
 from kubernetes_tpu.robustness.faults import FaultPoint, get_injector
-from kubernetes_tpu.utils import metrics
-from kubernetes_tpu.utils import timeline as _timeline
+from kubernetes_tpu.utils import flightrecorder, metrics
 
 from kubernetes_tpu.apiserver.server import (
     ADDED,
@@ -79,6 +78,7 @@ class ResourceEventHandler:
         on_delete: Optional[Callable[[Any], None]] = None,
         filter_func: Optional[Callable[[Any], bool]] = None,
         on_batch: Optional[Callable[[List], None]] = None,
+        stage_totals: Optional[flightrecorder.StageTotals] = None,
     ):
         self.on_add = on_add
         self.on_update = on_update
@@ -89,6 +89,10 @@ class ResourceEventHandler:
         # consumers (cache/queue bridges) amortize their locks over a
         # watch frame; the handler applies filter semantics itself
         self.on_batch = on_batch
+        # the consumer's stage totals: the informer adds each frame it
+        # applies (store update through this handler's return) as
+        # ``ingest`` to the totals its handlers carry
+        self.stage_totals = stage_totals
 
     def _passes(self, obj: Any) -> bool:
         return self.filter_func is None or self.filter_func(obj)
@@ -128,9 +132,12 @@ class Informer:
         self._stop = threading.Event()
         self._needs_relist = False
         self.synced = False
+        self._stage_totals: Optional[flightrecorder.StageTotals] = None
 
     def add_event_handler(self, handler: ResourceEventHandler) -> None:
         self._handlers.append(handler)
+        if handler.stage_totals is not None:
+            self._stage_totals = handler.stage_totals
 
     # -- lister surface -----------------------------------------------------
 
@@ -185,7 +192,11 @@ class Informer:
         cache, queue -- and must not nest inside the store lock)."""
         if not evs:
             return
-        with _timeline.span(f"informer.apply[{self.kind}]"):
+        # one span a frame, never one a pod
+        with flightrecorder.stage(
+            "ingest", totals=self._stage_totals,
+            kind=self.kind, events=len(evs),
+        ):
             self._apply_batch_inner(evs)
 
     def _apply_batch_inner(self, evs: List[WatchEvent]) -> None:

@@ -1016,6 +1016,8 @@ def pallas_constrained_solve(
         out_specs=tuple(out_specs),
         input_output_aliases=aliases,
         compiler_params=COMPILER_PARAMS,
+        # stable device-trace name (see pallas_greedy_solve)
+        name="pallas_constrained_solve",
         interpret=interpret,
     )(*args)
     asg = outs[oidx["asg"]]

@@ -606,6 +606,8 @@ def pallas_preempt_solve(
         ],
         input_output_aliases={14: 3},
         compiler_params=COMPILER_PARAMS,
+        # stable device-trace name (see pallas_greedy_solve)
+        name="pallas_preempt_solve",
         interpret=interpret,
     )(
         pods_req.astype(jnp.int32).reshape(-1),

@@ -20,7 +20,7 @@ from kubernetes_tpu.api.types import Pod
 from kubernetes_tpu.framework.interface import PodInfo
 from kubernetes_tpu.queue import events
 from kubernetes_tpu.queue.heap import Heap
-from kubernetes_tpu.utils import metrics
+from kubernetes_tpu.utils import flightrecorder, metrics
 
 DEFAULT_POD_INITIAL_BACKOFF = 1.0  # seconds
 DEFAULT_POD_MAX_BACKOFF = 10.0
@@ -199,6 +199,7 @@ class PriorityQueue:
         self.move_request_cycle = 0
         self._closed = False
         self.last_pop_wait_seconds = 0.0
+        self.last_pop_work_seconds = 0.0
         # priority-band queue jumping (streaming subsystem): pods with
         # spec.priority >= band_threshold form the HIGH band. The heap
         # already sorts them first; the band additionally cuts the batch
@@ -510,6 +511,7 @@ class PriorityQueue:
         max_size: int,
         timeout: Optional[float] = None,
         window=0.0,
+        totals: Optional[flightrecorder.StageTotals] = None,
     ) -> List[PodInfo]:
         """TPU batch drain: block for the first pod, then take up to
         ``max_size``. With ``window > 0``, wait up to that long for more
@@ -543,18 +545,36 @@ class PriorityQueue:
         ``move_request_cycle`` lost-wakeup gate sees batch pops the same
         way it sees single pops (pods 2..N used to skip the bump).
 
-        ``last_pop_wait_seconds`` holds the wall clock THIS call spent
-        blocked waiting for arrivals (first pod + window waits), so the
-        caller's stage timers can report drain WORK separately from
-        idle wait (single dispatcher thread; stats only). Window waits
-        cut short by a band arrival still count only the time actually
-        waited -- the split stays honest under band-aware drains."""
+        Drain WORK and idle wait are separate stages (``pop_batch``,
+        ``pop_wait``: flightrecorder.stage, into ``totals`` when the
+        caller gives its own), one after the other and never nested:
+        blocking on an empty queue is not hot-path time.
+        ``last_pop_wait_seconds`` / ``last_pop_work_seconds`` hold what
+        THIS call spent in each (first pod + window waits; single
+        dispatcher thread; stats only). Window waits cut short by a band
+        arrival still count only the time actually waited -- the split
+        stays honest under band-aware drains."""
         deadline = None if timeout is None else self._now() + timeout
         window_fn = window if callable(window) else None
         band = self.band_threshold
         batch: List[PodInfo] = []
-        waited = 0.0
+        waited = worked = 0.0
         has_high = False
+        work = flightrecorder.stage("pop_batch", totals=totals).__enter__()
+
+        def cond_wait(seconds: Optional[float]) -> None:
+            # the work stage closes for the wait and a new one opens
+            # after it, so a trace shows the two side by side
+            nonlocal waited, worked, work
+            work.__exit__(None, None, None)
+            worked += work.seconds
+            with flightrecorder.stage("pop_wait", totals=totals) as idle:
+                self._cond.wait(seconds)
+            waited += idle.seconds
+            work = flightrecorder.stage(
+                "pop_batch", totals=totals
+            ).__enter__()
+
         try:
             with self._cond:
                 # block for the first arrival (pop()'s wait loop, inlined
@@ -563,16 +583,12 @@ class PriorityQueue:
                     if self._closed:
                         return batch
                     if deadline is None:
-                        t0 = time.perf_counter()
-                        self._cond.wait()
-                        waited += time.perf_counter() - t0
+                        cond_wait(None)
                     else:
                         wait = deadline - self._now()
                         if wait <= 0.0:
                             return batch
-                        t0 = time.perf_counter()
-                        self._cond.wait(wait)
-                        waited += time.perf_counter() - t0
+                        cond_wait(wait)
                         if (
                             self._now() >= deadline
                             and len(self.active_q) == 0
@@ -612,12 +628,12 @@ class PriorityQueue:
                     remaining = window_deadline - self._now()
                     if remaining <= 0:
                         break
-                    t0 = time.perf_counter()
-                    self._cond.wait(remaining)
-                    waited += time.perf_counter() - t0
+                    cond_wait(remaining)
             return batch
         finally:
+            work.__exit__(None, None, None)
             self.last_pop_wait_seconds = waited
+            self.last_pop_work_seconds = worked + work.seconds
 
     @staticmethod
     def _observe_band_waits(

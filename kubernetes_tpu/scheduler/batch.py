@@ -113,7 +113,6 @@ from kubernetes_tpu.scheduler.scheduler import Scheduler
 from kubernetes_tpu.tensors import NodeTensorCache, pack_pod_batch
 from kubernetes_tpu.utils import flightrecorder
 from kubernetes_tpu.utils import metrics
-from kubernetes_tpu.utils import timeline
 
 try:
     from kubernetes_tpu.native import assume_clones as _assume_clones
@@ -624,24 +623,19 @@ class BatchScheduler(Scheduler):
         # the native cfg tuple is built once per scheduler
         self._plain_adm: Optional[Admission] = None
         self._ingest_cfg: Optional[tuple] = None
-        # per-stage wall-clock accumulators, ALWAYS on (bench.py emits
+        # per-stage wall-clock totals, ALWAYS on (bench.py emits
         # profile_stage_seconds every round; only the per-pod classify
-        # timer stays behind profile_stages). Per-THREAD dicts merged at
-        # read: the dispatcher (pop/classify/pack/device_solve) and the
-        # committer (download/commit) accumulate without sharing a
-        # read-modify-write -- the old single dict dropped stage time
-        # under pipelining whenever both threads raced the same key
+        # timer stays behind profile_stages): every flightrecorder.stage
+        # of this scheduler's threads, and of the informers it registers
+        # handlers on (eventhandlers.py), adds here
         self.profile_stages = False
-        self._stage_lock = threading.Lock()
-        self._stage_local = threading.local()
-        self._stage_dicts: List[dict] = []
+        self.stage_totals = flightrecorder.StageTotals()
         # flight-recorder spine (utils/flightrecorder.py): the pop-side
         # stage timings of the CURRENT drain, consumed by the first
         # span it dispatches (pop_batch drains before the flush loop
         # splits batches, so the pop cost belongs to the drain's head)
-        # (drain-work seconds, arrival-wait seconds, pop-start
-        # perf_counter) of the current drain
-        self._pop_note: Optional[Tuple[float, float, float]] = None
+        # (drain-work seconds, arrival-wait seconds) of the current drain
+        self._pop_note: Optional[Tuple[float, float]] = None
         # runtime jit-cache watchdog: sealed at the end of warmup();
         # unsealed growth still counts compiles, it just isn't flagged
         # as a mid-run recompile (tests that skip warmup stay quiet)
@@ -738,23 +732,20 @@ class BatchScheduler(Scheduler):
             if not cap
             else max(1, min(self.max_batch, cap))
         )
-        t_pop = time.perf_counter()
+        # the queue times drain WORK (pop_batch) and arrival wait
+        # (pop_wait) apart, into this scheduler's totals
         batch_infos = self.queue.pop_batch(
             size,
             timeout=timeout,
             window=(self._live_window if ab is not None
                     else self.batch_window),
+            totals=self.stage_totals,
         )
-        dt_pop = time.perf_counter() - t_pop
-        # split drain WORK from arrival wait: blocking on an empty queue
-        # (burst still streaming in, or plain idle) is not hot-path time
-        # and would drown the pop_batch share the profile exists to watch
-        waited = getattr(self.queue, "last_pop_wait_seconds", 0.0)
-        self._stage_add("pop_batch", max(0.0, dt_pop - waited))
-        if waited:
-            self._stage_add("pop_wait", waited)
         # the first span this drain dispatches claims the pop timings
-        self._pop_note = (max(0.0, dt_pop - waited), waited, t_pop)
+        self._pop_note = (
+            self.queue.last_pop_work_seconds,
+            self.queue.last_pop_wait_seconds,
+        )
         guard = self._gc_guard
         if not batch_infos:
             # idle: finish whatever is still in flight
@@ -813,9 +804,12 @@ class BatchScheduler(Scheduler):
                 # through every later batch
                 poison_stamp_maybe(pi.pod)
             if profiling:
+                # per pod, so a total alone: no span
                 t_cls = time.perf_counter()
                 adm = self._admission_of(pi.pod)
-                self._stage_add("classify", time.perf_counter() - t_cls)
+                self.stage_totals.add(
+                    "classify", time.perf_counter() - t_cls
+                )
             else:
                 adm = self._admission_of(pi.pod)
             if adm.device_ok:
@@ -1292,31 +1286,11 @@ class BatchScheduler(Scheduler):
         self.dispatch_batch_cap = controller.batch_cap
         self.solve_pad = controller.batch_cap
 
-    def _stage_add(self, name: str, seconds: float) -> None:
-        # lock-free on the hot path: each thread owns its accumulator
-        # dict; the lock is only taken once per thread to register it
-        d = getattr(self._stage_local, "d", None)
-        if d is None:
-            d = {}
-            self._stage_local.d = d
-            with self._stage_lock:
-                self._stage_dicts.append(d)
-        d[name] = d.get(name, 0.0) + seconds
-
     @property
     def stage_seconds(self) -> dict:
-        """Merged per-stage wall-clock totals across every accumulating
-        thread (dispatcher, committer, bind pool). dict.copy() is atomic
-        under the GIL, so a concurrent _stage_add never corrupts the
-        merge -- at worst the freshest increment lands in the next
-        read."""
-        with self._stage_lock:
-            dicts = [d.copy() for d in self._stage_dicts]
-        out: dict = {}
-        for d in dicts:
-            for k, v in d.items():
-                out[k] = out.get(k, 0.0) + v
-        return out
+        """Per-stage wall-clock totals, merged across every thread that
+        accumulates (dispatcher, committer, bind pool, informers)."""
+        return self.stage_totals.seconds()
 
     @property
     def mesh_solver_tier(self) -> str:
@@ -1824,6 +1798,24 @@ class BatchScheduler(Scheduler):
         inactive_uids=None,
         raise_on_exhaust: bool = False,
     ):
+        """``_dispatch_batch`` as one ``sched/dispatch`` span of a
+        profiler trace, which carries the batch's size and queue waits."""
+        with flightrecorder.stage(
+            "dispatch", pods=len(solver_infos)
+        ) as dispatch:
+            return self._dispatch_batch(
+                dispatch, solver_infos, pod_scheduling_cycle,
+                inactive_uids, raise_on_exhaust,
+            )
+
+    def _dispatch_batch(
+        self,
+        dispatch: flightrecorder.stage,
+        solver_infos: List[PodInfo],
+        pod_scheduling_cycle: int,
+        inactive_uids,
+        raise_on_exhaust: bool,
+    ):
         """Pack + upload + dispatch one solver batch. Returns a pending
         record for _complete_solve, or None when the batch was routed to
         the sequential path. Paths that read host-side cluster state the
@@ -1835,7 +1827,6 @@ class BatchScheduler(Scheduler):
         un-booking -- instead of routing the batch to containment or
         the sequential floor (the bisection loop owns that batch's
         disposition)."""
-        timeline.mark(f"dispatch_start b={len(solver_infos)}")
         if not raise_on_exhaust:
             inj0 = get_injector()
             if inj0 is not None and inj0.should_fire(
@@ -1846,409 +1837,424 @@ class BatchScheduler(Scheduler):
             # under the lock: the committer bumps this too, and a lost
             # increment would blind the carry audit's race detector
             self._dispatch_seq += 1
-        t_pack = time.perf_counter()
         # -- flight-recorder span: one per dispatch (a gang re-solve or
         # drain-redispatch is honestly its own span), with the per-pod
         # linkage (uid -> batch id, queue-wait, attempts) that makes a
         # pod's whole pod-to-bind path one join
+        now_m = time.monotonic()
+        waits = [max(0.0, now_m - pi.timestamp) for pi in solver_infos]
         if flightrecorder.ENABLED:
-            now_m = time.monotonic()
             span = flightrecorder.begin_batch(
                 len(solver_infos),
                 pods=[
-                    (pi.pod.metadata.uid,
-                     max(0.0, now_m - pi.timestamp), pi.attempts)
-                    for pi in solver_infos
+                    (pi.pod.metadata.uid, wait, pi.attempts)
+                    for pi, wait in zip(solver_infos, waits)
                 ],
             )
             pop_note = self._pop_note
             if pop_note is not None:
+                # the stages ran in the queue, before this batch had a
+                # span: only the ring is still to be written
                 self._pop_note = None
-                work, pop_waited, t_pop0 = pop_note
-                # the drain blocks for arrivals first, then drains:
-                # wait span at t_pop0, work span after it
+                work, pop_waited = pop_note
                 if pop_waited:
-                    span.stage("pop_wait", pop_waited, t0=t_pop0)
-                span.stage("pop_batch", work, t0=t_pop0 + pop_waited)
+                    span.stage("pop_wait", pop_waited)
+                span.stage("pop_batch", work)
             if inactive_uids:
                 span.note(gang_redispatch=True)
             if raise_on_exhaust:
                 span.note(bisect=True)
         else:
             span = flightrecorder.NULL_SPAN
-        pods = [pi.pod for pi in solver_infos]
-        # poison manifestation: any stamped pod in the dispatch fails
-        # every ladder tier (PoisonError), driving the exhaustion the
-        # bisection containment hangs off; a sub-batch WITHOUT the
-        # stamped pod solves normally -- exactly the signature the
-        # O(log B) search isolates on
-        poison_key = None
-        if get_injector() is not None:
-            for pod_p in pods:
-                if pod_is_poisoned(pod_p):
-                    poison_key = pod_p.key()
-                    break
-        # batch-level constraint aggregates from the cached admission
-        # feature bits (scheduler/admission.py): any() over memo reads
-        # instead of re-walking every spec per dispatch
-        adms = self._memo_admissions(solver_infos)
-        has_hard_spread = any(a.hard_spread for a in adms)
-        batch_ports = any(a.ports for a in adms)
-        has_affinity_terms = any(a.affinity_req for a in adms)
-        has_affinity = has_affinity_terms or batch_ports
-        has_required_anti = any(a.required_anti for a in adms)
-        prof0 = self.profiles.get(pods[0].spec.scheduler_name)
-        # gated on the profile actually scoring with InterPodAffinity --
-        # otherwise the ipa family packs nothing and draining for it
-        # would serialize the pipeline for free
-        ipa_weight = (
-            prof0.score_plugin_weights().get("InterPodAffinity", 0)
-            if prof0 is not None
-            else 0
+        dispatch.set_metadata(
+            batch=span.batch_id,
+            queue_wait_sum_ms=round(sum(waits) * 1e3, 3),
+            queue_wait_max_ms=round(max(waits, default=0.0) * 1e3, 3),
         )
-        score_dynamic = (
-            any(a.score_soft for a in adms)
-            or (
-                bool(ipa_weight)
-                and any(a.score_pref for a in adms)
+        totals = self.stage_totals
+        with flightrecorder.stage("pack", span, totals):
+            pods = [pi.pod for pi in solver_infos]
+            # poison manifestation: any stamped pod in the dispatch fails
+            # every ladder tier (PoisonError), driving the exhaustion the
+            # bisection containment hangs off; a sub-batch WITHOUT the
+            # stamped pod solves normally -- exactly the signature the
+            # O(log B) search isolates on
+            poison_key = None
+            if get_injector() is not None:
+                for pod_p in pods:
+                    if pod_is_poisoned(pod_p):
+                        poison_key = pod_p.key()
+                        break
+            # batch-level constraint aggregates from the cached admission
+            # feature bits (scheduler/admission.py): any() over memo reads
+            # instead of re-walking every spec per dispatch
+            adms = self._memo_admissions(solver_infos)
+            has_hard_spread = any(a.hard_spread for a in adms)
+            batch_ports = any(a.ports for a in adms)
+            has_affinity_terms = any(a.affinity_req for a in adms)
+            has_affinity = has_affinity_terms or batch_ports
+            has_required_anti = any(a.required_anti for a in adms)
+            prof0 = self.profiles.get(pods[0].spec.scheduler_name)
+            # gated on the profile actually scoring with InterPodAffinity --
+            # otherwise the ipa family packs nothing and draining for it
+            # would serialize the pipeline for free
+            ipa_weight = (
+                prof0.score_plugin_weights().get("InterPodAffinity", 0)
+                if prof0 is not None
+                else 0
             )
-            or batch_selector_spread_live(
-                pods, prof0.informers if prof0 is not None else None
+            score_dynamic = (
+                any(a.score_soft for a in adms)
+                or (
+                    bool(ipa_weight)
+                    and any(a.score_pref for a in adms)
+                )
+                or batch_selector_spread_live(
+                    pods, prof0.informers if prof0 is not None else None
+                )
             )
-        )
-        # this batch's pods become symmetric scorers for later batches
-        # once placed (preferred terms, and required affinity terms via
-        # hardPodAffinityWeight)
-        has_scoring_terms = bool(ipa_weight) and any(
-            a.scoring_terms for a in adms
-        )
-        nominated_by_node = self.queue.all_nominated_pods_by_node()
-
-        def drained(reason_predicate: bool) -> bool:
-            """Land every in-flight batch when the predicate holds, then
-            rebuild the drain-sensitive inputs (nominee overlay source;
-            callers refresh the snapshot themselves when they hold one).
-            Returns True when a drain happened."""
-            nonlocal nominated_by_node
-            if not reason_predicate or not self._pending_exists():
-                return False
-            self.pipeline_drains += 1
-            self._drain_pending()
-            # the drain can assume previously nominated pods (dropping
-            # their nomination) and nominate new ones via preemption --
-            # rebuild the overlay source from the post-drain state
+            # this batch's pods become symmetric scorers for later batches
+            # once placed (preferred terms, and required affinity terms via
+            # hardPodAffinityWeight)
+            has_scoring_terms = bool(ipa_weight) and any(
+                a.scoring_terms for a in adms
+            )
             nominated_by_node = self.queue.all_nominated_pods_by_node()
-            return True
 
-        nominee_uids = (
-            {
-                p.metadata.uid
-                for noms in nominated_by_node.values()
-                for p in noms
-            }
-            if nominated_by_node else set()
-        )
-        drained(
-            has_hard_spread or has_affinity_terms or score_dynamic
-            # a port batch must see in-flight PORT placements committed
-            # into the static mask; port-free in-flight batches cannot
-            # conflict, so they don't force the drain
-            or (batch_ports and self._pending_has_ports())
-            # an in-flight batch carrying required anti-affinity or
-            # scoring-relevant terms imposes symmetric constraints this
-            # batch can only see once its placements are committed
-            or self._pending_has_required_anti()
-            or self._pending_has_scoring_terms()
-            # a batch RETRYING preemption nominees must see the fully
-            # committed post-eviction state, or in-flight placements
-            # race it onto the freed capacity and cascade re-preemption
-            # (the old answer -- drain while ANY nomination lived --
-            # serialized every post-preemption dispatch; this drains
-            # only the nominees' own retry batches)
-            or any(
-                pi.pod.metadata.uid in nominee_uids
-                for pi in solver_infos
+            def drained(reason_predicate: bool) -> bool:
+                """Land every in-flight batch when the predicate holds, then
+                rebuild the drain-sensitive inputs (nominee overlay source;
+                callers refresh the snapshot themselves when they hold one).
+                Returns True when a drain happened."""
+                nonlocal nominated_by_node
+                if not reason_predicate or not self._pending_exists():
+                    return False
+                self.pipeline_drains += 1
+                self._drain_pending()
+                # the drain can assume previously nominated pods (dropping
+                # their nomination) and nominate new ones via preemption --
+                # rebuild the overlay source from the post-drain state
+                nominated_by_node = self.queue.all_nominated_pods_by_node()
+                return True
+
+            nominee_uids = (
+                {
+                    p.metadata.uid
+                    for noms in nominated_by_node.values()
+                    for p in noms
+                }
+                if nominated_by_node else set()
             )
-        )
-
-        snapshot = self.algorithm.snapshot
-        self.cache.update_snapshot(snapshot)
-        # existing pods with required anti-affinity constrain EVERY
-        # incoming pod symmetrically (filtering.go:404) -- such clusters
-        # need the affinity tensors even for batches without affinity, and
-        # their counts must include any in-flight placements
-        if not has_affinity_terms and cluster_has_required_anti_affinity(
-            snapshot
-        ):
-            has_affinity = True
-            has_affinity_terms = True
-            if drained(True):
-                self.cache.update_snapshot(snapshot)
-        # existing pods with symmetric scoring terms make EVERY batch's
-        # preferred-affinity family live (scoring.go:111): the in-flight
-        # counts must land before packing
-        cluster_ipa = bool(ipa_weight) and cluster_has_affinity_scoring(
-            snapshot
-        )
-        if not score_dynamic and cluster_ipa:
-            score_dynamic = True
-            if drained(True):
-                self.cache.update_snapshot(snapshot)
-                cluster_ipa = cluster_has_affinity_scoring(snapshot)
-        if nominated_by_node and (
-            has_hard_spread or has_affinity or score_dynamic
-            # a CONSTRAINED nominee (required (anti-)affinity / spread)
-            # imposes symmetric constraints the resource-only overlay
-            # can't express even for a plain batch
-            or any(
-                p.spec.affinity is not None
-                and (
-                    p.spec.affinity.pod_affinity is not None
-                    or p.spec.affinity.pod_anti_affinity is not None
+            drained(
+                has_hard_spread or has_affinity_terms or score_dynamic
+                # a port batch must see in-flight PORT placements committed
+                # into the static mask; port-free in-flight batches cannot
+                # conflict, so they don't force the drain
+                or (batch_ports and self._pending_has_ports())
+                # an in-flight batch carrying required anti-affinity or
+                # scoring-relevant terms imposes symmetric constraints this
+                # batch can only see once its placements are committed
+                or self._pending_has_required_anti()
+                or self._pending_has_scoring_terms()
+                # a batch RETRYING preemption nominees must see the fully
+                # committed post-eviction state, or in-flight placements
+                # race it onto the freed capacity and cascade re-preemption
+                # (the old answer -- drain while ANY nomination lived --
+                # serialized every post-preemption dispatch; this drains
+                # only the nominees' own retry batches)
+                or any(
+                    pi.pod.metadata.uid in nominee_uids
+                    for pi in solver_infos
                 )
-                or p.spec.topology_spread_constraints
-                for noms in nominated_by_node.values()
-                for p in noms
-            )
-        ):
-            # ADVICE r2 (medium): nominees are overlaid as RESOURCES
-            # only; the affinity/spread/score count tensors pack from
-            # the snapshot, which excludes them, so a constrained device
-            # batch could violate a nominee's symmetric constraints.
-            # The host path runs _add_nominated_pods exactly
-            # (generic_scheduler.go:535) -- take it for this rare
-            # combination (active nominations + constraints on either
-            # side).
-            self._drain_pending()
-            self.nominee_constrained_fallbacks += 1
-            span.finish(
-                tier=TIER_SEQUENTIAL, routed="nominee_constrained"
-            )
-            for pi in solver_infos:
-                self.pods_fallback += 1
-                self.attempt_schedule(pi)
-            return None
-        with timeline.span("nt.update"):
-            nt = self.tensor_cache.update(snapshot)
-        with timeline.span("pack_pod_batch"):
-            batch = pack_pod_batch(
-                pods, nt.dims,
-                timestamps=[pi.timestamp for pi in solver_infos],
-            )
-        with timeline.span("static_mask"):
-            mask_rows, mask_index = static_mask_compact(pods, snapshot, nt)
-        # pods requesting resources no node advertises are unsatisfiable:
-        # point them at a dedicated all-False row
-        if batch.unsatisfiable.any():
-            mask_rows = np.concatenate(
-                [mask_rows, np.zeros((1, nt.capacity), dtype=bool)]
-            )
-            mask_index = mask_index.copy()
-            mask_index[batch.unsatisfiable] = mask_rows.shape[0] - 1
-
-        # Nominated-pod overlay: reserve capacity for preemption nominees
-        # (the batch analogue of _add_nominated_pods' virtual add,
-        # generic_scheduler.go:535). Conservatively reserves for ALL
-        # nominees EXCEPT pods already being placed: this batch's own
-        # members and pods inside in-flight batches (their placement
-        # rides the device carry; overlaying them too would double-count
-        # and spuriously starve nodes -- the old answer was a full
-        # pipeline drain per dispatch while ANY nomination lived, which
-        # serialized the dispatcher against the committer for the whole
-        # post-preemption burst).
-        node_requested, node_nzr = nt.requested, nt.non_zero_requested
-        # skip the overlay for pods being placed RIGHT NOW: this batch's
-        # members and pods inside dispatched-but-not-yet-committing
-        # batches (their placement rides the device carry; overlaying
-        # them too over-reserves their nodes and cascades spurious
-        # preemption). The mid-COMMIT head batch is NOT excluded: its
-        # failures are being requeued with live nominations by the
-        # deferred wave at this very moment, and their reservations
-        # must stand.
-        batch_uids = {pi.pod.metadata.uid for pi in solver_infos}
-        with self._pending_cv:
-            for pend in self._pending_q:
-                if not pend.get("committing"):
-                    batch_uids.update(
-                        pi.pod.metadata.uid
-                        for pi in pend["solver_infos"]
-                    )
-        overlay_pods = []
-        overlay_rows = []
-        for node_name, nominated in nominated_by_node.items():
-            if node_name not in nt.names:
-                continue
-            j = nt.row(node_name)
-            for npod in nominated:
-                if npod.metadata.uid in batch_uids:
-                    continue
-                overlay_pods.append(npod)
-                overlay_rows.append(j)
-        overlaid = bool(overlay_pods)
-        if overlaid:
-            node_requested = node_requested.copy()
-            node_nzr = node_nzr.copy()
-            nbatch = pack_pod_batch(overlay_pods, nt.dims)
-            np.add.at(
-                node_requested, np.asarray(overlay_rows), nbatch.requests
-            )
-            np.add.at(
-                node_nzr, np.asarray(overlay_rows),
-                nbatch.non_zero_requests,
             )
 
-        b = batch.size
-        # fixed solve shape: every batch pads to max_batch so the solver
-        # JITs exactly once per (node-bucket, variant). The adaptive
-        # controller may floor the pad at its current rung instead --
-        # small batches then run a proportionally cheaper solve -- so
-        # the signature set is {warmed rungs} + {max_batch} plus the
-        # defensive oversize bucket. Warmup compiles the BASIC layouts
-        # for every rung; constrained layouts warm at max_batch only
-        # (the pre-existing latency-rung tradeoff: rare enough that
-        # the one-time compile lands on demand), so a batch whose
-        # aggregates say constraint families may pack never ESCALATES
-        # to a mid rung -- it takes the max_batch signature as before.
-        pad_floor = self.solve_pad
-        if not pad_floor or b > pad_floor:
-            # escalate to the smallest pre-compiled rung that fits
-            # (ladder-aware: an oversize plain batch lands on the next
-            # warmed rung up instead of jumping straight to the
-            # max_batch signature); anything past every warmed rung,
-            # or possibly-constrained, takes the max_batch signature
-            may_constrain = (
+            snapshot = self.algorithm.snapshot
+            self.cache.update_snapshot(snapshot)
+            # existing pods with required anti-affinity constrain EVERY
+            # incoming pod symmetrically (filtering.go:404) -- such clusters
+            # need the affinity tensors even for batches without affinity, and
+            # their counts must include any in-flight placements
+            if not has_affinity_terms and cluster_has_required_anti_affinity(
+                snapshot
+            ):
+                has_affinity = True
+                has_affinity_terms = True
+                if drained(True):
+                    self.cache.update_snapshot(snapshot)
+            # existing pods with symmetric scoring terms make EVERY batch's
+            # preferred-affinity family live (scoring.go:111): the in-flight
+            # counts must land before packing
+            cluster_ipa = bool(ipa_weight) and cluster_has_affinity_scoring(
+                snapshot
+            )
+            if not score_dynamic and cluster_ipa:
+                score_dynamic = True
+                if drained(True):
+                    self.cache.update_snapshot(snapshot)
+                    cluster_ipa = cluster_has_affinity_scoring(snapshot)
+            if nominated_by_node and (
                 has_hard_spread or has_affinity or score_dynamic
-                or has_scoring_terms
-            )
-            fitting = [p for p in self._warmup_pads if p >= b]
-            pad_floor = (
-                min(fitting) if fitting and not may_constrain
-                else self.max_batch
-            )
-        padded = max(
-            pad_floor, POD_BUCKET * math.ceil(b / POD_BUCKET)
-        )
-        order = batch.order
-        # -- tenant fairness bias (scheduler/tenancy.py): within each
-        # priority level, re-merge the solve order so the tenant with
-        # the lowest virtual dominant share places next -- the solve
-        # order IS the arbitration point of the sequential-replay scan,
-        # so every tier (pallas/XLA/mesh/host-greedy) honors the bias
-        # with zero kernel changes. Single-tenant batches exit after
-        # one namespace sweep.
-        tt = self.tenant_shares
-        if tt is not None and b > 1:
-            from kubernetes_tpu.scheduler.tenancy import fair_order
-
-            tt.refresh_capacity(nt)
-            order = fair_order(order, pods, batch.priorities, tt)
-        req = np.zeros((padded, nt.dims.num_dims), dtype=np.int32)
-        nzr = np.zeros((padded, 2), dtype=np.int32)
-        midx = np.zeros(padded, dtype=np.int32)
-        active = np.zeros(padded, dtype=bool)
-        req[:b] = batch.requests[order]
-        nzr[:b] = batch.non_zero_requests[order]
-        midx[:b] = mask_index[order]
-        active[:b] = True
-        if inactive_uids:
-            # gang quorum fixup: masked group members solve to NO_NODE
-            for k in range(b):
-                if (
-                    solver_infos[int(order[k])].pod.metadata.uid
-                    in inactive_uids
-                ):
-                    active[k] = False
-        u = mask_rows.shape[0]
-        u_padded = MASK_ROW_BUCKET * math.ceil(u / MASK_ROW_BUCKET)
-        rows = np.zeros((u_padded, nt.capacity), dtype=bool)
-        rows[:u] = mask_rows
-
-        # hard topology-spread constraints solve on device via the
-        # group-count scan (ops/topology.py); required (anti-)affinity via
-        # the count-tensor replay (ops/affinity.py)
-        # non-resource score plugins: pack when they can influence ranking
-        # (dynamic families already forced a pipeline drain above, so the
-        # snapshot these counts come from includes in-flight placements)
-        ordered_pods = [pods[int(i)] for i in order]
-        try:
-            hard_w = 1
-            if prof0 is not None:
-                ipa_plugin = prof0.plugin_instance("InterPodAffinity")
-                hard_w = getattr(
-                    ipa_plugin, "hard_pod_affinity_weight", 1
-                ) if ipa_plugin is not None else 1
-            score_batch = pack_score_batch(
-                ordered_pods, snapshot, nt,
-                prof0.informers if prof0 is not None else None,
-                prof0.score_plugin_weights() if prof0 is not None else {},
-                hard_pod_affinity_weight=hard_w,
-                cluster_affinity_scoring=cluster_ipa,
-            )
-        except ScoreEnvelopeExceeded:
-            # the sequential path filters against the host cache, which
-            # must include every in-flight placement
-            self.envelope_fallbacks += 1
-            self._drain_pending()
-            span.finish(tier=TIER_SEQUENTIAL, routed="score_envelope")
-            for pi in solver_infos:
-                self.pods_fallback += 1
-                self.attempt_schedule(pi)
-            return None
-
-        spread = None
-        affinity = None
-        if has_hard_spread:
-            spread = pack_spread_batch(ordered_pods, snapshot, nt)
-            if spread is None:
-                # envelope exceeded: host path keeps full correctness
-                self.envelope_fallbacks += 1
+                # a CONSTRAINED nominee (required (anti-)affinity / spread)
+                # imposes symmetric constraints the resource-only overlay
+                # can't express even for a plain batch
+                or any(
+                    p.spec.affinity is not None
+                    and (
+                        p.spec.affinity.pod_affinity is not None
+                        or p.spec.affinity.pod_anti_affinity is not None
+                    )
+                    or p.spec.topology_spread_constraints
+                    for noms in nominated_by_node.values()
+                    for p in noms
+                )
+            ):
+                # ADVICE r2 (medium): nominees are overlaid as RESOURCES
+                # only; the affinity/spread/score count tensors pack from
+                # the snapshot, which excludes them, so a constrained device
+                # batch could violate a nominee's symmetric constraints.
+                # The host path runs _add_nominated_pods exactly
+                # (generic_scheduler.go:535) -- take it for this rare
+                # combination (active nominations + constraints on either
+                # side).
+                self._drain_pending()
+                self.nominee_constrained_fallbacks += 1
                 span.finish(
-                    tier=TIER_SEQUENTIAL, routed="spread_envelope"
+                    tier=TIER_SEQUENTIAL, routed="nominee_constrained"
                 )
                 for pi in solver_infos:
                     self.pods_fallback += 1
                     self.attempt_schedule(pi)
                 return None
-        if has_affinity:
-            affinity = pack_affinity_batch(ordered_pods, snapshot, nt)
-            if affinity is None and has_affinity_terms:
-                # envelope exceeded (real affinity/exist rows expected
-                # but the packer bailed): the host path keeps full
-                # correctness -- port-only batches fall through to the
-                # port-row builder instead
-                self.envelope_fallbacks += 1
-                span.finish(
-                    tier=TIER_SEQUENTIAL, routed="affinity_envelope"
+            # pack's three parts: totals and trace only, the ring
+            # keeps the one ``pack``
+            batch_id = span.batch_id
+            with flightrecorder.stage(
+                "pack.state", totals=totals, batch=batch_id
+            ):
+                nt = self.tensor_cache.update(snapshot)
+            with flightrecorder.stage(
+                "pack.pods", totals=totals, batch=batch_id
+            ):
+                batch = pack_pod_batch(
+                    pods, nt.dims,
+                    timestamps=[pi.timestamp for pi in solver_infos],
                 )
+            with flightrecorder.stage(
+                "pack.masks", totals=totals, batch=batch_id
+            ):
+                mask_rows, mask_index = static_mask_compact(
+                    pods, snapshot, nt
+                )
+            # pods requesting resources no node advertises are unsatisfiable:
+            # point them at a dedicated all-False row
+            if batch.unsatisfiable.any():
+                mask_rows = np.concatenate(
+                    [mask_rows, np.zeros((1, nt.capacity), dtype=bool)]
+                )
+                mask_index = mask_index.copy()
+                mask_index[batch.unsatisfiable] = mask_rows.shape[0] - 1
+
+            # Nominated-pod overlay: reserve capacity for preemption nominees
+            # (the batch analogue of _add_nominated_pods' virtual add,
+            # generic_scheduler.go:535). Conservatively reserves for ALL
+            # nominees EXCEPT pods already being placed: this batch's own
+            # members and pods inside in-flight batches (their placement
+            # rides the device carry; overlaying them too would double-count
+            # and spuriously starve nodes -- the old answer was a full
+            # pipeline drain per dispatch while ANY nomination lived, which
+            # serialized the dispatcher against the committer for the whole
+            # post-preemption burst).
+            node_requested, node_nzr = nt.requested, nt.non_zero_requested
+            # skip the overlay for pods being placed RIGHT NOW: this batch's
+            # members and pods inside dispatched-but-not-yet-committing
+            # batches (their placement rides the device carry; overlaying
+            # them too over-reserves their nodes and cascades spurious
+            # preemption). The mid-COMMIT head batch is NOT excluded: its
+            # failures are being requeued with live nominations by the
+            # deferred wave at this very moment, and their reservations
+            # must stand.
+            batch_uids = {pi.pod.metadata.uid for pi in solver_infos}
+            with self._pending_cv:
+                for pend in self._pending_q:
+                    if not pend.get("committing"):
+                        batch_uids.update(
+                            pi.pod.metadata.uid
+                            for pi in pend["solver_infos"]
+                        )
+            overlay_pods = []
+            overlay_rows = []
+            for node_name, nominated in nominated_by_node.items():
+                if node_name not in nt.names:
+                    continue
+                j = nt.row(node_name)
+                for npod in nominated:
+                    if npod.metadata.uid in batch_uids:
+                        continue
+                    overlay_pods.append(npod)
+                    overlay_rows.append(j)
+            overlaid = bool(overlay_pods)
+            if overlaid:
+                node_requested = node_requested.copy()
+                node_nzr = node_nzr.copy()
+                nbatch = pack_pod_batch(overlay_pods, nt.dims)
+                np.add.at(
+                    node_requested, np.asarray(overlay_rows), nbatch.requests
+                )
+                np.add.at(
+                    node_nzr, np.asarray(overlay_rows),
+                    nbatch.non_zero_requests,
+                )
+
+            b = batch.size
+            # fixed solve shape: every batch pads to max_batch so the solver
+            # JITs exactly once per (node-bucket, variant). The adaptive
+            # controller may floor the pad at its current rung instead --
+            # small batches then run a proportionally cheaper solve -- so
+            # the signature set is {warmed rungs} + {max_batch} plus the
+            # defensive oversize bucket. Warmup compiles the BASIC layouts
+            # for every rung; constrained layouts warm at max_batch only
+            # (the pre-existing latency-rung tradeoff: rare enough that
+            # the one-time compile lands on demand), so a batch whose
+            # aggregates say constraint families may pack never ESCALATES
+            # to a mid rung -- it takes the max_batch signature as before.
+            pad_floor = self.solve_pad
+            if not pad_floor or b > pad_floor:
+                # escalate to the smallest pre-compiled rung that fits
+                # (ladder-aware: an oversize plain batch lands on the next
+                # warmed rung up instead of jumping straight to the
+                # max_batch signature); anything past every warmed rung,
+                # or possibly-constrained, takes the max_batch signature
+                may_constrain = (
+                    has_hard_spread or has_affinity or score_dynamic
+                    or has_scoring_terms
+                )
+                fitting = [p for p in self._warmup_pads if p >= b]
+                pad_floor = (
+                    min(fitting) if fitting and not may_constrain
+                    else self.max_batch
+                )
+            padded = max(
+                pad_floor, POD_BUCKET * math.ceil(b / POD_BUCKET)
+            )
+            order = batch.order
+            # -- tenant fairness bias (scheduler/tenancy.py): within each
+            # priority level, re-merge the solve order so the tenant with
+            # the lowest virtual dominant share places next -- the solve
+            # order IS the arbitration point of the sequential-replay scan,
+            # so every tier (pallas/XLA/mesh/host-greedy) honors the bias
+            # with zero kernel changes. Single-tenant batches exit after
+            # one namespace sweep.
+            tt = self.tenant_shares
+            if tt is not None and b > 1:
+                from kubernetes_tpu.scheduler.tenancy import fair_order
+
+                tt.refresh_capacity(nt)
+                order = fair_order(order, pods, batch.priorities, tt)
+            req = np.zeros((padded, nt.dims.num_dims), dtype=np.int32)
+            nzr = np.zeros((padded, 2), dtype=np.int32)
+            midx = np.zeros(padded, dtype=np.int32)
+            active = np.zeros(padded, dtype=bool)
+            req[:b] = batch.requests[order]
+            nzr[:b] = batch.non_zero_requests[order]
+            midx[:b] = mask_index[order]
+            active[:b] = True
+            if inactive_uids:
+                # gang quorum fixup: masked group members solve to NO_NODE
+                for k in range(b):
+                    if (
+                        solver_infos[int(order[k])].pod.metadata.uid
+                        in inactive_uids
+                    ):
+                        active[k] = False
+            u = mask_rows.shape[0]
+            u_padded = MASK_ROW_BUCKET * math.ceil(u / MASK_ROW_BUCKET)
+            rows = np.zeros((u_padded, nt.capacity), dtype=bool)
+            rows[:u] = mask_rows
+
+            # hard topology-spread constraints solve on device via the
+            # group-count scan (ops/topology.py); required (anti-)affinity via
+            # the count-tensor replay (ops/affinity.py)
+            # non-resource score plugins: pack when they can influence ranking
+            # (dynamic families already forced a pipeline drain above, so the
+            # snapshot these counts come from includes in-flight placements)
+            ordered_pods = [pods[int(i)] for i in order]
+            try:
+                hard_w = 1
+                if prof0 is not None:
+                    ipa_plugin = prof0.plugin_instance("InterPodAffinity")
+                    hard_w = getattr(
+                        ipa_plugin, "hard_pod_affinity_weight", 1
+                    ) if ipa_plugin is not None else 1
+                score_batch = pack_score_batch(
+                    ordered_pods, snapshot, nt,
+                    prof0.informers if prof0 is not None else None,
+                    prof0.score_plugin_weights() if prof0 is not None else {},
+                    hard_pod_affinity_weight=hard_w,
+                    cluster_affinity_scoring=cluster_ipa,
+                )
+            except ScoreEnvelopeExceeded:
+                # the sequential path filters against the host cache, which
+                # must include every in-flight placement
+                self.envelope_fallbacks += 1
+                self._drain_pending()
+                span.finish(tier=TIER_SEQUENTIAL, routed="score_envelope")
                 for pi in solver_infos:
                     self.pods_fallback += 1
                     self.attempt_schedule(pi)
                 return None
-            if batch_ports:
-                # within-batch host-port conflicts ride synthetic anti
-                # rows (ops/affinity.add_host_port_rows); existing-pod
-                # conflicts are already in the static mask
-                affinity = add_host_port_rows(
-                    ordered_pods, snapshot, nt, affinity
-                )
-                if affinity is None:
-                    # port-row envelope exceeded: the sequential filter
-                    # must see every in-flight placement committed (a
-                    # port-only batch may not have drained above)
-                    self._drain_pending()
+
+            spread = None
+            affinity = None
+            if has_hard_spread:
+                spread = pack_spread_batch(ordered_pods, snapshot, nt)
+                if spread is None:
+                    # envelope exceeded: host path keeps full correctness
                     self.envelope_fallbacks += 1
                     span.finish(
-                        tier=TIER_SEQUENTIAL, routed="port_envelope"
+                        tier=TIER_SEQUENTIAL, routed="spread_envelope"
                     )
                     for pi in solver_infos:
                         self.pods_fallback += 1
                         self.attempt_schedule(pi)
                     return None
+            if has_affinity:
+                affinity = pack_affinity_batch(ordered_pods, snapshot, nt)
+                if affinity is None and has_affinity_terms:
+                    # envelope exceeded (real affinity/exist rows expected
+                    # but the packer bailed): the host path keeps full
+                    # correctness -- port-only batches fall through to the
+                    # port-row builder instead
+                    self.envelope_fallbacks += 1
+                    span.finish(
+                        tier=TIER_SEQUENTIAL, routed="affinity_envelope"
+                    )
+                    for pi in solver_infos:
+                        self.pods_fallback += 1
+                        self.attempt_schedule(pi)
+                    return None
+                if batch_ports:
+                    # within-batch host-port conflicts ride synthetic anti
+                    # rows (ops/affinity.add_host_port_rows); existing-pod
+                    # conflicts are already in the static mask
+                    affinity = add_host_port_rows(
+                        ordered_pods, snapshot, nt, affinity
+                    )
+                    if affinity is None:
+                        # port-row envelope exceeded: the sequential filter
+                        # must see every in-flight placement committed (a
+                        # port-only batch may not have drained above)
+                        self._drain_pending()
+                        self.envelope_fallbacks += 1
+                        span.finish(
+                            tier=TIER_SEQUENTIAL, routed="port_envelope"
+                        )
+                        for pi in solver_infos:
+                            self.pods_fallback += 1
+                            self.attempt_schedule(pi)
+                        return None
 
-        dt_pack = time.perf_counter() - t_pack
-        self._stage_add("pack", dt_pack)
-        span.stage("pack", dt_pack, t0=t_pack)
         span.note(padded=padded)
+        dispatch.set_metadata(padded=padded)
         solve_timer = metrics.SinceTimer(metrics.batch_solve_duration)
 
         # preemption prewarm: when the batch's most demanding request
@@ -2498,22 +2504,13 @@ class BatchScheduler(Scheduler):
                 else None
             )
             try:
-                t_solve = time.perf_counter()
-                with timeline.span("solve_dispatch"):
+                with flightrecorder.stage(
+                    "device_solve", span, totals
+                ) as solving:
                     tier, out = self.ladder.run(
                         attempts, label=f"batch b={b}"
                     )
-                dt_solve = time.perf_counter() - t_solve
-                self._stage_add("device_solve", dt_solve)
-                span.stage("device_solve", dt_solve, t0=t_solve)
-                if flightrecorder.trace_active():
-                    # the device's own track, next to the host threads
-                    flightrecorder.trace_span(
-                        f"solve b={b}", t_solve, dt_solve,
-                        track="device",
-                        args={"batch": span.batch_id, "tier": tier}
-                        if span else None,
-                    )
+                    solving.set_metadata(tier=tier)
                 self._jit_watch.refresh()
             except LadderExhausted as exhaust_err:
                 with self._shadow_lock:
@@ -2713,13 +2710,12 @@ class BatchScheduler(Scheduler):
             inj = get_injector()
             if inj is not None:
                 inj.raise_maybe(FaultPoint.DEVICE_SOLVE)
-            t_solve = time.perf_counter()
-            assignments_dev, req_out, nzr_out = self._mesh_solve(
-                common_args, spread, affinity, score_batch, padded, nt
-            )
-            dt_solve = time.perf_counter() - t_solve
-            self._stage_add("device_solve", dt_solve)
-            span.stage("device_solve", dt_solve, t0=t_solve)
+            with flightrecorder.stage(
+                "device_solve", span, totals, tier="mesh"
+            ):
+                assignments_dev, req_out, nzr_out = self._mesh_solve(
+                    common_args, spread, affinity, score_batch, padded, nt
+                )
             self._jit_watch.refresh()
         except Exception as mesh_err:
             with self._shadow_lock:
@@ -3411,15 +3407,12 @@ class BatchScheduler(Scheduler):
             return np.asarray(p["assignments_dev"])
 
         fspan = p.get("span") or flightrecorder.NULL_SPAN
+        totals = self.stage_totals
         try:
-            t_dl = time.perf_counter()
-            with timeline.span("download"):
+            with flightrecorder.stage("download", fspan, totals):
                 assignments = self.ladder.watchdog.call(
                     download, timeout, tier=tier
                 )
-            dt_dl = time.perf_counter() - t_dl
-            self._stage_add("download", dt_dl)
-            fspan.stage("download", dt_dl, t0=t_dl)
         except SolveTimeout:
             if breaker is not None:
                 breaker.force_open()
@@ -3496,26 +3489,13 @@ class BatchScheduler(Scheduler):
             self._pending_cv.notify_all()
         if inj is not None and inj.should_fire(FaultPoint.CARRY_CORRUPT):
             self._corrupt_carry_row()
-        t_commit = time.perf_counter()
-        with timeline.span("commit_batch"):
+        with flightrecorder.stage("commit", fspan, totals):
             self._commit_batch(
                 p["solver_infos"], p["order"], assignments, p["names"],
                 p["num_nodes"], p["snapshot"], p["cycle"],
                 mask_info=(p.get("mask_rows"), p.get("mask_index_solved")),
                 gang_failed_uids=p.get("gang_failed_uids"),
                 span=fspan,
-            )
-        dt_commit = time.perf_counter() - t_commit
-        self._stage_add("commit", dt_commit)
-        fspan.stage("commit", dt_commit, t0=t_commit)
-        if flightrecorder.trace_active():
-            # the committer's own named track: in the Perfetto artifact
-            # this span overlaps the "device" track's next solve span,
-            # making the solve/commit pipeline overlap visible
-            flightrecorder.trace_span(
-                f"commit b={b}", t_commit, dt_commit,
-                track="committer",
-                args={"batch": getattr(fspan, "batch_id", None)},
             )
         fspan.finish()
         if (
@@ -3604,7 +3584,9 @@ class BatchScheduler(Scheduler):
                 for pi in solver_infos
             )
         if fast:
-            with timeline.span("commit.gather"):
+            with flightrecorder.stage(
+                "commit.gather", batch=span.batch_id
+            ):
                 head = np.asarray(assignments[:b])
                 grp = np.argsort(head, kind="stable")
                 n_unplaced = int((head == NO_NODE).sum())
@@ -3677,7 +3659,9 @@ class BatchScheduler(Scheduler):
                 else:
                     slow.append((pi, choice, k))
             if plain:
-                with timeline.span("commit.clone"):
+                with flightrecorder.stage(
+                    "commit.clone", batch=span.batch_id
+                ):
                     if _assume_clones is not None:
                         clones = _assume_clones(
                             [pi.pod for pi, _ in plain],
@@ -3695,7 +3679,9 @@ class BatchScheduler(Scheduler):
         bulk: List[Tuple] = []
         deferred: List[Tuple] = []  # sync-mode Permit waiters
         if plain_pis:
-            with timeline.span("commit.assume"):
+            with flightrecorder.stage(
+                "commit.assume", batch=span.batch_id
+            ):
                 # on the fast path the argsort grouped the clones by
                 # target node, so the cache lands them as per-node runs
                 # (one node lookup + one generation bump per run)
@@ -3940,7 +3926,9 @@ class BatchScheduler(Scheduler):
         for prof, items in by_prof.values():
             victim_uids: Optional[List[str]] = []
             try:
-                with timeline.span("preempt_wave"):
+                with flightrecorder.stage(
+                    "preempt_wave", totals=self.stage_totals
+                ):
                     nominated, victim_uids = self.preemptor.preempt_batch(
                         prof, [(pi.pod, fe) for pi, fe, _ in items]
                     )
@@ -3962,7 +3950,9 @@ class BatchScheduler(Scheduler):
             # an instant retry against a cache that still holds the
             # victims would waste a scheduling cycle
             if victim_uids:
-                with timeline.span("victim_wait"):
+                with flightrecorder.stage(
+                    "victim_wait", totals=self.stage_totals
+                ):
                     deadline = time.monotonic() + 0.5
                     pending = list(victim_uids)
                     while pending and time.monotonic() < deadline:
@@ -3972,7 +3962,9 @@ class BatchScheduler(Scheduler):
                         ]
                         if pending:
                             time.sleep(0.002)
-            with timeline.span("preempt_requeue"):
+            with flightrecorder.stage(
+                "preempt_requeue", totals=self.stage_totals
+            ):
                 for (pi, fe, cycle), node in zip(items, nominated):
                     if self.cache.has_pod_uid(pi.pod.metadata.uid):
                         # stale parked record: the pod bound during the
@@ -3993,9 +3985,10 @@ class BatchScheduler(Scheduler):
                 # finds it (and its device upload) already warm
                 self._prewarm_next_commit = True
 
-    def _bind_bulk_with_retry(self, assumed_list):
+    def _bind_bulk_with_retry(self, assumed_list, binding):
         """bind_assumed_bulk with retry-with-backoff around TRANSACTION
-        failures (apiserver unavailable, injected conflict burst).
+        failures (apiserver unavailable, injected conflict burst);
+        ``binding`` is the bind's stage, which learns of the retries.
         Per-slot errors are the API's answer, not a transport failure --
         they return to the caller, whose per-slot handling already does
         forget + Unreserve + requeue. On terminal transaction failure
@@ -4027,6 +4020,7 @@ class BatchScheduler(Scheduler):
                     )
                     return [(i, e) for i in range(len(assumed_list))]
                 metrics.bind_retries.inc()
+                binding.set_metadata(retries=attempt)
                 self.ladder.config.sleep(
                     policy.backoff_for_attempt(attempt)
                 )
@@ -4094,7 +4088,8 @@ class BatchScheduler(Scheduler):
             logger.exception("requeueing conflicted pod %s", pi.pod.key())
 
     def _bulk_binding_cycle_safe(
-        self, items, pod_scheduling_cycle, snapshot=None, span=None
+        self, items, pod_scheduling_cycle, snapshot=None,
+        span=flightrecorder.NULL_SPAN,
     ) -> None:
         try:
             self._bulk_binding_cycle(
@@ -4112,7 +4107,8 @@ class BatchScheduler(Scheduler):
                 self._inflight_lock.notify_all()
 
     def _bulk_binding_cycle(
-        self, items, pod_scheduling_cycle, snapshot=None, span=None
+        self, items, pod_scheduling_cycle, snapshot=None,
+        span=flightrecorder.NULL_SPAN,
     ) -> None:
         """One API transaction commits the batch (the pipelined bulk
         analogue of BindingREST.Create, storage.go:142). PreBind still
@@ -4242,63 +4238,70 @@ class BatchScheduler(Scheduler):
                 ready = kept
                 if not ready:
                     return
-        assumed_list = [t[3] for t in ready]
-        bind_timer = metrics.SinceTimer(metrics.binding_duration)
-        with timeline.span("bind_bulk"):
-            errors = self._bind_bulk_with_retry(assumed_list)
-        bind_timer.observe()
-        if errors:
-            failed = dict(errors)
-            bound = []
-            for i, item in enumerate(ready):
-                err = failed.get(i)
-                if err is None:
-                    bound.append(item)
-                    continue
-                prof, state, pi, assumed, host = item
-                if isinstance(err, ApiConflict):
-                    # typed conflict (already-bound / uid-mismatch /
-                    # foreign-partition): the optimistic-concurrency
-                    # answer of a multi-active control plane, absorbed
-                    # through the requeue path -- never a scheduler
-                    # error, never silently dropped
-                    self._absorb_bind_conflict(
-                        prof,
-                        state if state is not None else mk_state(),
-                        pi, assumed, host, err, pod_scheduling_cycle,
-                        span=span,
-                    )
-                    continue
-                metrics.schedule_attempts.inc(result="error")
-                self._forget(assumed)
-                prof.run_unreserve_plugins(
-                    state if state is not None else mk_state(),
-                    assumed, host,
-                )
-                self.record_scheduling_failure(
-                    prof, pi, str(err), "SchedulerError", "",
-                    pod_scheduling_cycle,
-                )
-            bound_assumed = [t[3] for t in bound]
-        else:
-            bound = ready
-            bound_assumed = assumed_list
-        if not bound:
-            return
-        with timeline.span("finish_binding_bulk"):
-            self.cache.finish_binding_bulk(bound_assumed)
-        if any(p.has_plugins("post_bind") for p in profs.values()):
-            for prof, state, pi, assumed, host in bound:
-                if prof.has_plugins("post_bind"):
-                    prof.run_post_bind_plugins(
+        # one span per bulk bind, on the bind pool's thread: the API
+        # transaction (``bind.api``), then the cache's finish_binding and
+        # the events
+        totals = self.stage_totals
+        with flightrecorder.stage(
+            "bind", span, totals, pods=len(ready)
+        ) as binding:
+            assumed_list = [t[3] for t in ready]
+            bind_timer = metrics.SinceTimer(metrics.binding_duration)
+            with flightrecorder.stage(
+                "bind.api", totals=totals, batch=span.batch_id
+            ):
+                errors = self._bind_bulk_with_retry(assumed_list, binding)
+            bind_timer.observe()
+            if errors:
+                failed = dict(errors)
+                bound = []
+                for i, item in enumerate(ready):
+                    err = failed.get(i)
+                    if err is None:
+                        bound.append(item)
+                        continue
+                    prof, state, pi, assumed, host = item
+                    if isinstance(err, ApiConflict):
+                        # typed conflict (already-bound / uid-mismatch /
+                        # foreign-partition): the optimistic-concurrency
+                        # answer of a multi-active control plane, absorbed
+                        # through the requeue path -- never a scheduler
+                        # error, never silently dropped
+                        self._absorb_bind_conflict(
+                            prof,
+                            state if state is not None else mk_state(),
+                            pi, assumed, host, err, pod_scheduling_cycle,
+                            span=span,
+                        )
+                        continue
+                    metrics.schedule_attempts.inc(result="error")
+                    self._forget(assumed)
+                    prof.run_unreserve_plugins(
                         state if state is not None else mk_state(),
                         assumed, host,
                     )
-        # single-profile bulks take the batched-recorder fast path; a
-        # mixed bulk passes recorder=None so _emit_bound's fallback
-        # routes each event through the pod's own profile recorder
-        recorder = bound[0][0].recorder if len(profs) == 1 else None
-        with timeline.span("events+metrics"):
+                    self.record_scheduling_failure(
+                        prof, pi, str(err), "SchedulerError", "",
+                        pod_scheduling_cycle,
+                    )
+                bound_assumed = [t[3] for t in bound]
+            else:
+                bound = ready
+                bound_assumed = assumed_list
+            if not bound:
+                return
+            self.cache.finish_binding_bulk(bound_assumed)
+            if any(p.has_plugins("post_bind") for p in profs.values()):
+                for prof, state, pi, assumed, host in bound:
+                    if prof.has_plugins("post_bind"):
+                        prof.run_post_bind_plugins(
+                            state if state is not None else mk_state(),
+                            assumed, host,
+                        )
+            # single-profile bulks take the batched-recorder fast path; a
+            # mixed bulk passes recorder=None so _emit_bound's fallback
+            # routes each event through the pod's own profile recorder
+            recorder = bound[0][0].recorder if len(profs) == 1 else None
             self._emit_bound(recorder, bound)
         # arm the bind-ack ledger: each committed bind is pending until
         # its Running ack arrives over the watch (zombie-kubelet
@@ -4779,7 +4782,7 @@ class BatchScheduler(Scheduler):
         from kubernetes_tpu.utils.gc_tuning import GCBatchGuard
 
         self.queue.run()
-        self._gc_guard = GCBatchGuard()
+        self._gc_guard = GCBatchGuard(self.stage_totals)
         try:
             while not self._stop.is_set():
                 # in-flight batches land on the committer thread, so the

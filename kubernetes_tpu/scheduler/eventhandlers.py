@@ -76,6 +76,10 @@ def add_all_event_handlers(
     # tracker dedups per uid, so re-echoes are free)
     note_node_cap = getattr(sched, "note_node_capacity", None)
     note_node_gone = getattr(sched, "note_node_gone", None)
+    # the scheduler's stage totals ride on the handlers of the two hot
+    # kinds: their informers time each frame (store update, classify,
+    # cache add, queue add) as the scheduler's ``ingest``
+    stage_totals = getattr(sched, "stage_totals", None)
 
     def _note_foreign_bound(pod: Pod) -> None:
         if note_bound is not None:
@@ -450,6 +454,7 @@ def add_all_event_handlers(
             on_update=combined_pod_update,
             on_delete=combined_pod_delete,
             on_batch=pods_batch,
+            stage_totals=stage_totals,
         )
     )
 
@@ -541,7 +546,8 @@ def add_all_event_handlers(
 
     nodes.add_event_handler(
         ResourceEventHandler(
-            on_add=add_node, on_update=update_node, on_delete=delete_node
+            on_add=add_node, on_update=update_node, on_delete=delete_node,
+            stage_totals=stage_totals,
         )
     )
 

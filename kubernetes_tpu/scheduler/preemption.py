@@ -34,7 +34,7 @@ from kubernetes_tpu.robustness.ladder import (
     LadderExhausted,
     SolverLadder,
 )
-from kubernetes_tpu.utils import metrics
+from kubernetes_tpu.utils import flightrecorder, metrics
 
 logger = logging.getLogger(__name__)
 
@@ -410,8 +410,6 @@ class Preemptor:
         )
         from kubernetes_tpu.tensors import pack_pod_batch
 
-        from kubernetes_tpu.utils import timeline as _tl
-
         if snapshot is not None:
             # private-snapshot path (drain planning): a PERSISTENT
             # sibling tensor cache sharing the dims/topology interners,
@@ -438,7 +436,7 @@ class Preemptor:
                 self._plan_pack if self._plan_pack_key == key else None
             )
             if pack is None:
-                with _tl.span("pack_build"):
+                with flightrecorder.stage("preempt_wave.pack_build"):
                     pack = pack_preemption_state(snapshot, nt, pdbs)
                 self._plan_pack = pack
                 self._plan_pack_key = key
@@ -449,7 +447,9 @@ class Preemptor:
             with self._nt_lock:
                 nt = self._tensor_cache.update(snapshot)
             key = self._pack_cache_key(snapshot, pdbs)
-            with _tl.span("pack_wait"), self._pack_cv:
+            with flightrecorder.stage(
+                "preempt_wave.pack_wait"
+            ), self._pack_cv:
                 # a prewarm in flight is about to deliver this exact
                 # pack: wait for it instead of duplicating ~0.3s of
                 # packing work
@@ -462,7 +462,7 @@ class Preemptor:
                     self._pack_cv.wait(0.05)
                 pack = self._pack if self._pack_key == key else None
             if pack is None:
-                with _tl.span("pack_build"):
+                with flightrecorder.stage("preempt_wave.pack_build"):
                     pack = pack_preemption_state(snapshot, nt, pdbs)
                 with self._pack_cv:
                     self._pack = pack
@@ -573,14 +573,10 @@ class Preemptor:
         if wave_pallas_eligible(pack, pack_num_pdbs(pack)):
             attempts.append((TIER_PALLAS, _tier_thunk("pallas")))
         attempts.append((TIER_XLA, _tier_thunk("xla")))
-        _span = _tl.span("preempt_device")
-        _span.__enter__()
-        try:
+        with flightrecorder.stage("preempt_wave.solve"):
             tier, (chosen, victims, viol, nviol) = self.ladder.run(
                 attempts, label="preempt_wave"
             )
-        finally:
-            _span.__exit__(None, None, None)
         if prio_override is None:
             # a drain PLAN's solve must not relabel the eviction ledger
             # a concurrent preempt() is about to book against

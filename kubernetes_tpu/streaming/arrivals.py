@@ -270,13 +270,6 @@ class ArrivalEngine:
             "arrival_stall", seconds=round(stalled, 4),
             stalls=self.backpressure_stalls,
         )
-        # the --trace timeline gets the stall as a span on the
-        # arrival-engine track (a stalled engine means the offered rate
-        # did not actually enter the system -- that must be visible
-        # next to the solve spans it starves)
-        flightrecorder.trace_span(
-            "backpressure_stall", t0, stalled, track="arrival-engine",
-        )
 
     def _run(self) -> None:
         offsets = self._offsets

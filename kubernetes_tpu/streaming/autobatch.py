@@ -360,14 +360,6 @@ class AutoBatchController:
             window_ms=round(self.window * 1000.0, 3),
             cap=self.batch_cap,
         )
-        # --trace timelines show controller moves as instant events on
-        # their own track, between the stage spans they retune
-        flightrecorder.trace_instant(
-            f"autobatch_{direction}",
-            args={"window_ms": round(self.window * 1000.0, 3),
-                  "cap": self.batch_cap},
-            track="autobatch",
-        )
         return direction
 
     # -- dispatcher-facing wrapper -------------------------------------------

@@ -27,11 +27,15 @@ and ``dump_on_degraded`` wherever a component raises the
 degraded-health gauge. Chaos e2es assert against ``RECORDER.dump()``
 instead of grepping logs.
 
-The module doubles as the Chrome-trace event sink: ``start_trace()``
-arms a buffer (bench.py --trace) and every span stage / instant mark
-also lands there as a Chrome-trace event; ``export_chrome_trace``
-writes JSON that loads in ui.perfetto.dev. Zero cost when not armed
-(one None check).
+``stage`` is the one way a stage of the hot path is timed: one ``with``
+adds the seconds to the always-on totals (``StageTotals``, what
+``BatchScheduler.stage_seconds`` reads), records them on the batch's
+span, and is a ``jax.profiler.TraceAnnotation("sched/<name>")`` for its
+whole duration, so a profiler session (``jax.profiler.trace``) shows the
+scheduler's stages on the device trace's clock, on the line of the
+thread that did the work. ``mark`` lands there too, as a zero-length
+``sched/mark/<kind>``. The totals and the annotations do not depend on
+``KTPU_FLIGHTRECORDER``: only the ring does.
 """
 
 from __future__ import annotations
@@ -43,6 +47,8 @@ import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 logger = logging.getLogger(__name__)
 
@@ -90,15 +96,9 @@ class BatchSpan:
         self.thread = threading.current_thread().name
         self.extra: Optional[dict] = None
 
-    def stage(self, name: str, seconds: float,
-              t0: Optional[float] = None) -> None:
-        """Accumulate one stage's wall clock; when the Chrome-trace
-        buffer is armed the stage also lands there as a duration event
-        on the calling thread's track (t0 = perf_counter at start)."""
+    def stage(self, name: str, seconds: float) -> None:
+        """Accumulate one stage's wall clock."""
         self.stages[name] = self.stages.get(name, 0.0) + seconds
-        if _trace is not None and t0 is not None:
-            trace_span(name, t0, seconds,
-                       args={"batch": self.batch_id})
 
     def note(self, **fields) -> None:
         for k, v in fields.items():
@@ -172,7 +172,10 @@ class _NullSpan:
 
     __slots__ = ()
 
-    def stage(self, name, seconds, t0=None):
+    #: what a stage's ``batch`` stat reads when the ring is off
+    batch_id = 0
+
+    def stage(self, name, seconds):
         pass
 
     def note(self, **fields):
@@ -273,6 +276,10 @@ def begin_batch(size: int, pods=()) -> BatchSpan:
 
 
 def mark(kind: str, /, **fields) -> None:
+    # zero-length, so a recompile, fallback or breaker transition shows
+    # in a profiler trace where it happened (marks are rare)
+    with TraceAnnotation("sched/mark/" + kind):
+        pass
     if not ENABLED:
         return
     RECORDER.mark(kind, **fields)
@@ -288,95 +295,92 @@ def dump_on_degraded(reason: str) -> Optional[str]:
     return RECORDER.dump_to_file(reason)
 
 
-# -- Chrome-trace event buffer (bench.py --trace) ------------------------
-
-_trace: Optional[list] = None
-_trace_lock = threading.Lock()
-_trace_tids: Dict[str, int] = {}
+# -- the stage primitive ---------------------------------------------------
 
 
-def start_trace() -> None:
-    """Arm the Chrome-trace buffer: from here every span stage, arrival
-    stall, and autobatch decision lands as a trace event."""
-    global _trace
-    with _trace_lock:
-        _trace = []
-        _trace_tids.clear()
+class StageTotals:
+    """Always-on seconds and calls per stage. Per-THREAD dicts merged at
+    read: the dispatcher, the committer, the bind pool and the informer
+    threads accumulate without sharing a read-modify-write, and the lock
+    is taken once per thread, to register its dict."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self._dicts: List[dict] = []
+
+    def add(self, name: str, seconds: float) -> None:
+        d = getattr(self._local, "d", None)
+        if d is None:
+            d = {}
+            self._local.d = d
+            with self._lock:
+                self._dicts.append(d)
+        rec = d.get(name)
+        if rec is None:
+            d[name] = [seconds, 1]
+        else:
+            rec[0] += seconds
+            rec[1] += 1
+
+    def _merged(self, field: int) -> dict:
+        # list() of a dict's items is atomic under the GIL, so a
+        # concurrent add never corrupts the merge -- at worst the
+        # freshest increment lands in the next read
+        with self._lock:
+            items = [list(d.items()) for d in self._dicts]
+        out: dict = {}
+        for pairs in items:
+            for name, rec in pairs:
+                out[name] = out.get(name, 0) + rec[field]
+        return out
+
+    def seconds(self) -> dict:
+        return self._merged(0)
+
+    def calls(self) -> dict:
+        return self._merged(1)
 
 
-def trace_active() -> bool:
-    return _trace is not None
+#: three totals keep the names the benchmark and the ring's ``stages_ms``
+#: have always read; the trace says what they are (``device_solve`` is
+#: the dispatch alone: JAX returns before the device finishes, and
+#: ``download`` is the wait for it plus the copy)
+_SPAN_NAMES = {
+    "pop_batch": "pop",
+    "device_solve": "solve_dispatch",
+    "download": "solve_wait",
+}
 
 
-def _tid_for(name: str) -> int:
-    """Stable small-int tid per track name, with a Perfetto thread_name
-    metadata event emitted on first sight."""
-    tid = _trace_tids.get(name)
-    if tid is None:
-        tid = len(_trace_tids) + 1
-        _trace_tids[name] = tid
-        _trace.append({  # type: ignore[union-attr]
-            "ph": "M", "name": "thread_name", "pid": 1, "tid": tid,
-            "args": {"name": name},
-        })
-    return tid
+class stage(TraceAnnotation):
+    """``with stage("pack", span, totals, **stats):`` times one stage,
+    once: the seconds go to ``totals`` (when given) and to the batch's
+    ``span`` (when given), and the block is a ``sched/<name>`` span of
+    any running profiler session (``_SPAN_NAMES`` renames three), with
+    the span's ``batch`` id and ``stats`` on it (``set_metadata`` adds
+    what is known only later).
+    ``seconds`` holds the duration after exit. Spans are per batch, per
+    informer frame, per bulk bind and per collection, never per pod."""
 
+    def __init__(self, name: str, span=NULL_SPAN,
+                 totals: Optional[StageTotals] = None, **stats) -> None:
+        if span:
+            stats["batch"] = span.batch_id
+        super().__init__("sched/" + _SPAN_NAMES.get(name, name), **stats)
+        self.name = name
+        self.span = span
+        self.totals = totals
+        self.seconds = 0.0
 
-def trace_span(name: str, t0: float, dur: float,
-               track: Optional[str] = None, args: Optional[dict] = None
-               ) -> None:
-    """One complete ('X') duration event; t0/dur in perf_counter
-    seconds, converted to the trace's microsecond clock."""
-    buf = _trace
-    if buf is None:
-        return
-    with _trace_lock:
-        if _trace is None:
-            return
-        ev = {
-            "ph": "X", "name": name, "pid": 1,
-            "tid": _tid_for(track or threading.current_thread().name),
-            "ts": t0 * 1e6, "dur": max(dur, 0.0) * 1e6,
-        }
-        if args:
-            ev["args"] = args
-        _trace.append(ev)
+    def __enter__(self) -> "stage":
+        super().__enter__()
+        self._t0 = time.perf_counter()
+        return self
 
-
-def trace_instant(name: str, args: Optional[dict] = None,
-                  track: Optional[str] = None) -> None:
-    buf = _trace
-    if buf is None:
-        return
-    with _trace_lock:
-        if _trace is None:
-            return
-        ev = {
-            "ph": "i", "name": name, "pid": 1, "s": "t",
-            "tid": _tid_for(track or threading.current_thread().name),
-            "ts": time.perf_counter() * 1e6,
-        }
-        if args:
-            ev["args"] = args
-        _trace.append(ev)
-
-
-def stop_trace() -> List[dict]:
-    """Disarm and return the collected events."""
-    global _trace
-    with _trace_lock:
-        events, _trace = (_trace or []), None
-        _trace_tids.clear()
-    return events
-
-
-def export_chrome_trace(path: str) -> int:
-    """Write the armed buffer as Chrome-trace JSON (the object form,
-    which Perfetto and chrome://tracing both load) and disarm. Returns
-    the event count."""
-    events = stop_trace()
-    with open(path, "w") as f:
-        json.dump(
-            {"traceEvents": events, "displayTimeUnit": "ms"}, f
-        )
-    return len(events)
+    def __exit__(self, *exc) -> None:
+        self.seconds = seconds = time.perf_counter() - self._t0
+        super().__exit__(*exc)
+        if self.totals is not None:
+            self.totals.add(self.name, seconds)
+        self.span.stage(self.name, seconds)

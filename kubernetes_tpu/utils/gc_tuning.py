@@ -15,6 +15,8 @@ from __future__ import annotations
 import gc
 import time as _time
 
+from kubernetes_tpu.utils import flightrecorder
+
 
 def freeze_steady_state_graph(
     gen0: int = 100_000, gen1: int = 50, gen2: int = 50
@@ -50,7 +52,10 @@ class GCBatchGuard:
     #: wait for an idle transition that sustained load never reaches
     FULL_COLLECT_EVERY = 6
 
-    def __init__(self) -> None:
+    def __init__(self, totals=None) -> None:
+        #: the scheduler's StageTotals: every collection is a ``gc``
+        #: stage (a ``sched/gc`` span on the dispatcher's line)
+        self._totals = totals
         self._active = False
         self._last_collect = 0.0
         self._active_collects = 0
@@ -69,16 +74,22 @@ class GCBatchGuard:
             # periodic full pass to drain gen-2 promotions
             self._active_collects += 1
             if self._active_collects % self.FULL_COLLECT_EVERY == 0:
-                gc.collect()
+                self._collect(2)
             else:
-                gc.collect(1)
+                self._collect(1)
             self._last_collect = now
 
     def idle(self) -> None:
         if self._active:
             gc.enable()
-            gc.collect()
+            self._collect(2)
             self._active = False
+
+    def _collect(self, generation: int) -> None:
+        with flightrecorder.stage(
+            "gc", totals=self._totals, generation=generation
+        ):
+            gc.collect(generation)
 
     def close(self) -> None:
         self.idle()

@@ -20,7 +20,6 @@ from kubernetes_tpu.scheduler.app import SchedulerApp
 from kubernetes_tpu.scheduler.leaderelection import LeaderElector
 from kubernetes_tpu.testing import make_node, make_pod
 from kubernetes_tpu.utils import metrics
-from kubernetes_tpu.utils.tracing import Trace
 
 
 class TestMetrics:
@@ -191,17 +190,6 @@ class TestConfigLoader:
         assert not fg.enabled("TPUBatchSolver")
         with pytest.raises(ValueError):
             fg.set_from_map({"NoSuchGate": True})
-
-
-class TestTrace:
-    def test_steps_logged_when_long(self, caplog):
-        import logging
-        with caplog.at_level(logging.INFO, logger="trace"):
-            t = Trace("schedule", pod="default/p")
-            t.step("filtering")
-            t.step("scoring")
-            t.log_if_long(0.0)
-        assert "filtering" in caplog.text and "schedule" in caplog.text
 
 
 class TestDurationParsing:

@@ -1,0 +1,382 @@
+"""The one stage primitive (utils/flightrecorder.py ``stage``): each use
+writes the always-on total, the batch's ring record and the profiler's
+trace once, and a CPU ``jax.profiler`` session around a small burst shows
+the scheduler's stages on the lines of the threads that did the work."""
+
+import gc
+import glob
+import threading
+import time
+from contextlib import contextmanager
+
+import jax
+import pytest
+
+from kubernetes_tpu.apiserver.server import ADDED, APIServer, WatchEvent
+from kubernetes_tpu.client.client import Client
+from kubernetes_tpu.client.informer import (
+    Informer,
+    InformerFactory,
+    ResourceEventHandler,
+)
+from kubernetes_tpu.plugins.queuesort import PrioritySort
+from kubernetes_tpu.queue.scheduling_queue import PriorityQueue
+from kubernetes_tpu.scheduler.scheduler import new_scheduler
+from kubernetes_tpu.testing import make_node, make_pod
+from kubernetes_tpu.utils import flightrecorder
+from kubernetes_tpu.utils.gc_tuning import GCBatchGuard
+
+
+@pytest.fixture(autouse=True)
+def _clean():
+    flightrecorder.RECORDER.reset()
+    yield
+    flightrecorder.ENABLED = True
+
+
+@contextmanager
+def profiled(tmp_path):
+    """A profiler session; the list it yields holds, after the block, the
+    ``sched/`` events as dicts (name, start, end, line, stats)."""
+    events = []
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        yield events
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+    ))[-1]
+    data = jax.profiler.ProfileData.from_file(path)
+    for plane in data.planes:
+        for index, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("sched/"):
+                    events.append({
+                        "name": ev.name, "start": ev.start_ns,
+                        "end": ev.start_ns + ev.duration_ns,
+                        "line": (plane.name, index),
+                        "stats": dict(ev.stats),
+                    })
+
+
+def named(events, name):
+    return [ev for ev in events if ev["name"] == name]
+
+
+def _stack(num_nodes=16, max_batch=64):
+    server = APIServer()
+    client = Client(server)
+    informers = InformerFactory(server)
+    sched = new_scheduler(client, informers, batch=True, max_batch=max_batch)
+    for i in range(num_nodes):
+        client.create_node(
+            make_node(f"node-{i}")
+            .capacity(cpu="32", memory="64Gi", pods=110).obj()
+        )
+    informers.start()
+    informers.wait_for_cache_sync()
+    return server, client, informers, sched
+
+
+def _burst(client, sched, count, tag="p"):
+    client.create_pods_bulk([
+        make_pod(f"{tag}-{i}").container(cpu="10m", memory="16Mi").obj()
+        for i in range(count)
+    ])
+    deadline = time.time() + 60
+    while time.time() < deadline:
+        pods, _ = client.list_pods()
+        if len(pods) >= count and all(p.spec.node_name for p in pods):
+            break
+        time.sleep(0.02)
+    else:
+        raise AssertionError("the burst did not bind")
+    sched.wait_for_inflight_binds()
+
+
+# -- the primitive alone -----------------------------------------------------
+
+
+def test_stage_writes_total_ring_and_trace_once_each(tmp_path):
+    totals = flightrecorder.StageTotals()
+    span = flightrecorder.RECORDER.begin_batch(3)
+    with profiled(tmp_path) as events:
+        with flightrecorder.stage("pack", span, totals, pods=3) as st:
+            time.sleep(0.002)
+            st.set_metadata(padded=8)
+    assert totals.calls() == {"pack": 1}
+    assert totals.seconds()["pack"] == pytest.approx(st.seconds)
+    assert st.seconds >= 0.002
+    assert span.stages == {"pack": st.seconds}
+    (ev,) = named(events, "sched/pack")
+    assert ev["stats"] == {"batch": span.batch_id, "pods": 3, "padded": 8}
+    assert (ev["end"] - ev["start"]) / 1e9 == pytest.approx(
+        st.seconds, abs=1e-3
+    )
+
+
+@pytest.mark.parametrize("total,span_name", [
+    ("pop_batch", "sched/pop"),
+    ("device_solve", "sched/solve_dispatch"),
+    ("download", "sched/solve_wait"),
+])
+def test_three_totals_keep_their_names_under_honest_span_names(
+    tmp_path, total, span_name
+):
+    totals = flightrecorder.StageTotals()
+    span = flightrecorder.RECORDER.begin_batch(1)
+    with profiled(tmp_path) as events:
+        with flightrecorder.stage(total, span, totals):
+            pass
+    assert list(totals.seconds()) == [total]
+    assert list(span.to_dict()["stages_ms"]) == [total]
+    assert len(named(events, span_name)) == 1
+
+
+def test_totals_merge_threads_without_losing_time():
+    totals = flightrecorder.StageTotals()
+
+    def work():
+        for _ in range(200):
+            totals.add("commit", 0.001)
+
+    threads = [threading.Thread(target=work) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert totals.calls()["commit"] == 800
+    assert totals.seconds()["commit"] == pytest.approx(0.8)
+
+
+def test_mark_lands_in_the_trace_and_in_the_ring(tmp_path):
+    with profiled(tmp_path) as events:
+        flightrecorder.mark("jit_recompile", signature="solve_packed")
+    (ev,) = named(events, "sched/mark/jit_recompile")
+    assert ev["end"] - ev["start"] < 1e6  # zero-length: under a millisecond
+    marks = flightrecorder.RECORDER.dump()["marks"]
+    assert [m["kind"] for m in marks] == ["jit_recompile"]
+
+
+def test_the_guards_collections_are_gc_stages(tmp_path):
+    totals = flightrecorder.StageTotals()
+    guard = GCBatchGuard(totals)
+    try:
+        with profiled(tmp_path) as events:
+            guard.active()
+            guard._last_collect -= 2 * guard.ACTIVE_COLLECT_INTERVAL_S
+            guard.active()  # overdue under sustained load: a gen-1 pass
+            guard.idle()  # active -> idle: the full pass
+    finally:
+        gc.enable()
+    assert totals.calls() == {"gc": 2}
+    assert [ev["stats"]["generation"] for ev in named(events, "sched/gc")] \
+        == [1, 2]
+
+
+def test_pop_wait_and_pop_work_are_side_by_side(tmp_path):
+    queue = PriorityQueue(PrioritySort().queue_sort_less)
+    totals = flightrecorder.StageTotals()
+    pod = make_pod("late").container(cpu="10m", memory="16Mi").obj()
+    timer = threading.Timer(0.15, queue.add, args=(pod,))
+    with profiled(tmp_path) as events:
+        timer.start()
+        batch = queue.pop_batch(8, timeout=5.0, totals=totals)
+        timer.join()
+    assert [pi.pod.metadata.name for pi in batch] == ["late"]
+    seconds = totals.seconds()
+    assert seconds["pop_wait"] == pytest.approx(
+        queue.last_pop_wait_seconds
+    )
+    assert seconds["pop_wait"] >= 0.1
+    assert seconds["pop_batch"] == pytest.approx(
+        queue.last_pop_work_seconds
+    )
+    assert seconds["pop_batch"] < 0.05
+    waits, works = named(events, "sched/pop_wait"), named(events, "sched/pop")
+    assert waits and len(works) == len(waits) + 1
+    for wait in waits:  # never nested: a wait is open only between works
+        assert not any(
+            work["start"] < wait["end"] and wait["start"] < work["end"]
+            for work in works
+        )
+
+
+def test_an_informer_frame_is_one_ingest_span(tmp_path):
+    totals = flightrecorder.StageTotals()
+    seen = []
+    informer = Informer(APIServer(), "Pod")
+    informer.add_event_handler(ResourceEventHandler(
+        on_batch=seen.extend, stage_totals=totals
+    ))
+    frame = [
+        WatchEvent(ADDED, make_pod(f"f-{i}").obj(), i + 1) for i in range(3)
+    ]
+    with profiled(tmp_path) as events:
+        informer._apply_batch(frame)
+    assert len(seen) == 3
+    assert totals.calls() == {"ingest": 1}
+    (ev,) = named(events, "sched/ingest")
+    assert ev["stats"] == {"kind": "Pod", "events": 3}
+
+
+# -- on a small burst --------------------------------------------------------
+
+
+@pytest.fixture
+def burst_trace(tmp_path):
+    """120 pods in batches of at most 64 under a profiler session."""
+    server, client, informers, sched = _stack()
+    sched.start()
+    try:
+        with profiled(tmp_path) as events:
+            _burst(client, sched, 120)
+            time.sleep(0.7)  # past the run loop's idle point: one collect
+        dump = flightrecorder.RECORDER.dump()
+        yield events, dump, sched
+    finally:
+        sched.stop()
+        informers.stop()
+
+
+def test_a_burst_shows_every_stage_with_a_shared_batch(burst_trace):
+    events, dump, _sched = burst_trace
+    for name in ("sched/pack", "sched/commit", "sched/bind", "sched/ingest",
+                 "sched/dispatch", "sched/solve_dispatch", "sched/solve_wait",
+                 "sched/bind.api", "sched/pop", "sched/pop_wait", "sched/gc"):
+        assert named(events, name), name
+    batch_ids = {s["batch_id"] for s in dump["spans"]}
+    assert len(batch_ids) >= 2
+    for batch in batch_ids:
+        mine = [ev for ev in events if ev["stats"].get("batch") == batch]
+        names = {ev["name"] for ev in mine}
+        assert {"sched/dispatch", "sched/pack", "sched/solve_dispatch",
+                "sched/solve_wait", "sched/commit", "sched/bind",
+                "sched/bind.api"} <= names
+        # dispatcher, committer and bind pool: three threads' lines
+        assert len({ev["line"] for ev in mine}) >= 3
+        # no site is instrumented twice
+        for name in ("sched/pack", "sched/commit", "sched/bind"):
+            assert len([ev for ev in mine if ev["name"] == name]) == 1
+
+
+def test_packs_children_lie_inside_their_parent(burst_trace):
+    events, _dump, _sched = burst_trace
+    packs = named(events, "sched/pack")
+    for child_name in ("sched/pack.state", "sched/pack.pods",
+                       "sched/pack.masks"):
+        children = named(events, child_name)
+        assert len(children) == len(packs)
+        for child in children:
+            (parent,) = [p for p in packs
+                         if p["stats"]["batch"] == child["stats"]["batch"]]
+            assert parent["line"] == child["line"]
+            assert parent["start"] <= child["start"]
+            assert child["end"] <= parent["end"]
+    for pack in packs:
+        (dispatch,) = [d for d in named(events, "sched/dispatch")
+                       if d["stats"]["batch"] == pack["stats"]["batch"]]
+        assert dispatch["start"] <= pack["start"]
+        assert pack["end"] <= dispatch["end"]
+
+
+def test_dispatch_spans_carry_the_rings_queue_waits(burst_trace):
+    events, dump, _sched = burst_trace
+    dispatches = {d["stats"]["batch"]: d["stats"]
+                  for d in named(events, "sched/dispatch")}
+    assert len(dispatches) == len(dump["spans"])
+    for span in dump["spans"]:
+        stats = dispatches[span["batch_id"]]
+        waits = [p["queue_wait_ms"] for p in span["pods"]]
+        assert stats["pods"] == span["size"] == len(waits)
+        assert stats["padded"] == span["padded"]
+        assert stats["queue_wait_sum_ms"] == pytest.approx(
+            sum(waits), abs=0.001 * len(waits) + 0.001
+        )
+        assert stats["queue_wait_max_ms"] == pytest.approx(
+            max(waits), abs=0.002
+        )
+    assert sum(s["pods"] for s in dispatches.values()) == 120
+
+
+def test_stage_seconds_keeps_its_keys_and_gains_the_new(burst_trace):
+    _events, dump, sched = burst_trace
+    seconds = sched.stage_seconds
+    old = {"pop_batch", "pop_wait", "pack", "device_solve", "download",
+           "commit"}
+    new = {"ingest", "bind", "bind.api", "gc", "pack.state", "pack.pods",
+           "pack.masks"}
+    assert old | new <= set(seconds)
+    assert "classify" not in seconds  # per pod: only under profile_stages
+    assert all(v >= 0 for v in seconds.values())
+    parts = seconds["pack.state"] + seconds["pack.pods"] + seconds["pack.masks"]
+    assert parts <= seconds["pack"]
+    assert seconds["bind.api"] <= seconds["bind"]
+    # the ring's stages_ms keeps its names; the bulk bind joins them
+    for span in dump["spans"]:
+        assert {"pack", "device_solve", "download", "commit", "bind"} <= set(
+            span["stages_ms"]
+        )
+    batches = len(dump["spans"])
+    calls = sched.stage_totals.calls()
+    for name in ("pack", "device_solve", "download", "commit", "bind"):
+        assert calls[name] == batches, name
+
+
+def test_classify_is_timed_per_pod_only_when_asked():
+    server, client, informers, sched = _stack(num_nodes=4)
+    sched.profile_stages = True
+    sched.start()
+    try:
+        _burst(client, sched, 10, tag="c")
+    finally:
+        sched.stop()
+        informers.stop()
+    assert sched.stage_totals.calls()["classify"] == 10
+
+
+def test_without_the_ring_totals_and_annotations_still_work(tmp_path):
+    flightrecorder.ENABLED = False
+    server, client, informers, sched = _stack(num_nodes=4)
+    sched.start()
+    try:
+        with profiled(tmp_path) as events:
+            _burst(client, sched, 20, tag="off")
+            flightrecorder.mark("fallback", tier="xla")
+    finally:
+        sched.stop()
+        informers.stop()
+    assert flightrecorder.RECORDER.dump()["spans"] == []
+    assert flightrecorder.RECORDER.dump()["marks"] == []
+    seconds = sched.stage_seconds
+    assert {"pack", "device_solve", "download", "commit", "bind",
+            "ingest"} <= set(seconds)
+    assert named(events, "sched/pack") and named(events, "sched/bind")
+    assert named(events, "sched/mark/fallback")
+    (dispatch,) = named(events, "sched/dispatch")[:1]
+    assert dispatch["stats"]["batch"] == 0  # no ring, no batch id
+    assert dispatch["stats"]["pods"] > 0
+    assert dispatch["stats"]["queue_wait_sum_ms"] >= 0
+
+
+def test_a_fresh_schedulers_totals_are_its_own():
+    """An informer frame belongs to the scheduler whose handlers are
+    registered on it: another stack's burst adds nothing here."""
+    _s1, client1, informers1, sched1 = _stack(num_nodes=4)
+    _s2, _client2, informers2, sched2 = _stack(num_nodes=4)
+    base = sched2.stage_seconds.get("ingest", 0.0)
+    sched1.start()
+    try:
+        _burst(client1, sched1, 10, tag="own")
+    finally:
+        sched1.stop()
+        informers1.stop()
+        informers2.stop()
+    assert sched1.stage_seconds["ingest"] > 0
+    assert "pack" in sched1.stage_seconds
+    assert sched2.stage_seconds.get("ingest", 0.0) == base
+    assert "pack" not in sched2.stage_seconds

@@ -82,7 +82,7 @@ Prints ONE JSON line:
                          O(events), watcher-count independent) and
                          batched delivery beats per-event ~4x,
    "trace_{on,off}_hot_ms" / "trace_overhead_pct" /
-   "trace_{span,mark}_us":
+   "recorder_{batch,mark}_us":
                          the ISSUE-13 flight-recorder spine on a real
                          1k-pod closed-loop burst, recorder ON vs
                          compiled-out (interleaved arms, best-of-2
@@ -1290,7 +1290,9 @@ def bench_trace_overhead(num_pods: int = 1000, num_nodes: int = 200):
     is dominated by apiserver/bind threads the recorder never touches).
 
     Also measures the recorder's raw op costs (one full span lifecycle
-    with a 256-pod link list + 5 stage stamps, and one mark), which the
+    with a 256-pod link list + every stage of a batch through
+    ``flightrecorder.stage``, with no profiler session; and one mark),
+    which the
     tier-1 guard (tests/test_flightrecorder.py) multiplies by the op
     counts of a real burst for a deterministic <1% self-time bound.
     """
@@ -1301,7 +1303,7 @@ def bench_trace_overhead(num_pods: int = 1000, num_nodes: int = 200):
     from kubernetes_tpu.testing import make_node, make_pod
     from kubernetes_tpu.utils import flightrecorder
 
-    HOT = ("pop_batch", "pack", "device_solve", "download", "commit")
+    HOT = HOT_STAGES
 
     server = APIServer()
     client = Client(server)
@@ -1380,7 +1382,8 @@ def bench_trace_overhead(num_pods: int = 1000, num_nodes: int = 200):
     pod_links = [(f"uid-{i}", 0.001, 1) for i in range(256)]
     n_ops = 2000
     span_us = min(
-        _time_span_ops(rec, pod_links, HOT, n_ops) for _ in range(3)
+        _time_span_ops(rec, pod_links, BATCH_STAGES, n_ops)
+        for _ in range(3)
     )
     mark_us = min(_time_mark_ops(rec, n_ops * 5) for _ in range(3))
 
@@ -1396,9 +1399,9 @@ def bench_trace_overhead(num_pods: int = 1000, num_nodes: int = 200):
         "trace_overhead_wallclock_pct": round(
             (on_ms - off_ms) / off_ms * 100.0, 2
         ) if off_ms > 0 else 0.0,
-        "trace_spans_per_burst": spans_per_burst,
-        "trace_span_us": round(span_us, 2),
-        "trace_mark_us": round(mark_us, 3),
+        "recorder_batches_per_burst": spans_per_burst,
+        "recorder_batch_us": round(span_us, 2),
+        "recorder_mark_us": round(mark_us, 3),
         "trace_selftime_ms": round(self_ms, 3),
         "trace_overhead_selftime_pct": round(
             self_ms / off_ms * 100.0, 3
@@ -1406,26 +1409,51 @@ def bench_trace_overhead(num_pods: int = 1000, num_nodes: int = 200):
     }
 
 
+#: the stage timers that make the burst's hot path
+HOT_STAGES = ("pop_batch", "pack", "device_solve", "download", "commit")
+#: every flightrecorder.stage one batch passes through
+BATCH_STAGES = (
+    "pop_wait", "pop_batch", "dispatch", "pack", "pack.state",
+    "pack.pods", "pack.masks", "device_solve", "download", "commit",
+    "commit.gather", "commit.clone", "commit.assume", "bind", "bind.api",
+)
+
+
 def _time_span_ops(rec, pod_links, stages, n_ops: int) -> float:
     """us per full span lifecycle: the 256-entry pod-link list build
     (the per-pod tuple comprehension _dispatch_solve pays), begin (ring
-    append), 5 stage stamps, finish."""
+    append), each of ``stages`` through ``flightrecorder.stage`` (total,
+    ring and the profiler annotation, which no session reads here),
+    finish."""
+    from kubernetes_tpu.utils import flightrecorder
+
     uids = [u for u, _, _ in pod_links]
+    totals = flightrecorder.StageTotals()
     t0 = time.perf_counter()
     for _ in range(n_ops):
         links = [(u, 0.001, 1) for u in uids]
         span = rec.begin_batch(256, pods=links)
         for st in stages:
-            span.stage(st, 0.001)
+            with flightrecorder.stage(st, span, totals, pods=256):
+                pass
         span.finish(tier="xla")
     return (time.perf_counter() - t0) / n_ops * 1e6
 
 
 def _time_mark_ops(rec, n_ops: int) -> float:
-    t0 = time.perf_counter()
-    for _ in range(n_ops):
-        rec.mark("fallback", tier="xla", reason="bench")
-    return (time.perf_counter() - t0) / n_ops * 1e6
+    """us per mark as the program makes one: the ring's append and the
+    zero-length annotation."""
+    from kubernetes_tpu.utils import flightrecorder
+
+    saved = flightrecorder.RECORDER
+    flightrecorder.RECORDER = rec
+    try:
+        t0 = time.perf_counter()
+        for _ in range(n_ops):
+            flightrecorder.mark("fallback", tier="xla", reason="bench")
+        return (time.perf_counter() - t0) / n_ops * 1e6
+    finally:
+        flightrecorder.RECORDER = saved
 
 
 def bench_speculative(num_nodes: int = 5000, num_pods: int = 2000):
