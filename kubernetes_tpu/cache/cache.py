@@ -7,15 +7,20 @@ of assumed pods that finished binding).
 
 The incremental snapshot uses per-NodeInfo generation counters: only
 NodeInfos whose generation advanced past the snapshot's generation are
-re-cloned (reference orders nodes in a doubly-linked list by modification
-generation, cache.go:53; here a generation compare over the map achieves the
-same "copy only changed nodes" property).
+re-cloned. As the reference does (cache.go:53, a doubly-linked list
+ordered by modification generation), the cache keeps its node names in
+generation order (``_gen_order``, moved to the end wherever a NodeInfo's
+generation is bumped), so ``update_snapshot`` visits the changed nodes
+and stops at the first one the snapshot already has: any number of
+snapshots, each refreshed at its own time, stay correct.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
+from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -24,6 +29,29 @@ from kubernetes_tpu.cache.node_info import NodeInfo, next_generation
 from kubernetes_tpu.cache.snapshot import Snapshot
 
 DEFAULT_ASSUME_TTL_SECONDS = 30.0  # reference scheduler.go:240
+
+# node-spec epochs are drawn from one counter for every cache, as
+# generations are: a snapshot never reads one cache's epoch as another's
+_node_spec_epochs = itertools.count(1)
+
+
+def _node_spec_changed(prev: Optional[Node], node: Node) -> bool:
+    """Whether ``node`` differs from ``prev`` in what the static mask
+    rows and the score packer's node-side facts read of a Node object:
+    labels, taints, ``unschedulable``, annotations, images. A status
+    write that moves only conditions or allocatable (a kubelet's
+    heartbeat, many a second in a large cluster) is not such a change.
+    The same object handed in again may have been edited where it
+    stands, which cannot be told, so it counts as changed."""
+    if prev is None or prev is node:
+        return True
+    return (
+        prev.metadata.labels != node.metadata.labels
+        or prev.spec.taints != node.spec.taints
+        or prev.spec.unschedulable != node.spec.unschedulable
+        or prev.metadata.annotations != node.metadata.annotations
+        or prev.status.images != node.status.images
+    )
 
 
 @dataclass
@@ -54,6 +82,25 @@ class SchedulerCache:
         # CSINode objects stashed by node name: a CSINode can arrive
         # before its Node (separate informers), so add_node re-applies it
         self._csi_nodes: Dict[str, object] = {}
+        # names of ``_nodes`` by generation, the newest last
+        # (reference cache.go:53 headNode): every site that bumps a
+        # NodeInfo's generation calls ``_touch``
+        self._gen_order: "OrderedDict[str, None]" = OrderedDict()
+        # entries that have left ``_nodes`` or lost their Node object:
+        # a snapshot that saw this count has no deleted node to find
+        self._removals = 0
+        # moves whenever a Node object is added or removed, or replaced
+        # by one that ``_node_spec_changed``; snapshots carry it, and
+        # what depends on those parts of the node objects alone (static
+        # mask rows, the score packer's node-side facts) is kept while
+        # it stands. 0 is a snapshot no cache has fed
+        self._node_spec_epoch = next(_node_spec_epochs)
+
+    def _touch(self, name: str) -> None:
+        """``name``'s NodeInfo has just taken a new generation."""
+        order = self._gen_order
+        order[name] = None
+        order.move_to_end(name)
 
     # -- assume / bind lifecycle (cache.go:344-) ----------------------------
 
@@ -80,7 +127,6 @@ class SchedulerCache:
         with self._lock:
             states = self._pod_states
             assumed = self._assumed_pods
-            nodes = self._nodes
             run: List[Pod] = []
             run_node: Optional[str] = None
             for pod in pods:
@@ -93,7 +139,7 @@ class SchedulerCache:
                 node = pod.spec.node_name
                 if node != run_node:
                     if run:
-                        self._node_for(nodes, run_node).add_pods(run)
+                        self._add_run(run_node, run)
                     run = []
                     run_node = node
                 run.append(pod)
@@ -101,18 +147,18 @@ class SchedulerCache:
                 assumed[key] = True
                 out.append(None)
             if run:
-                self._node_for(nodes, run_node).add_pods(run)
+                self._add_run(run_node, run)
         return out
 
-    @staticmethod
-    def _node_for(nodes, name) -> NodeInfo:
-        ni = nodes.get(name)
+    def _add_run(self, name: str, run: List[Pod]) -> None:
+        ni = self._nodes.get(name)
         if ni is None:
             # pod observed before its node: nodeless NodeInfo, matching
             # _add_pod_to_node
             ni = NodeInfo()
-            nodes[name] = ni
-        return ni
+            self._nodes[name] = ni
+        ni.add_pods(run)
+        self._touch(name)
 
     def finish_binding(self, pod: Pod) -> None:
         key = pod.metadata.uid
@@ -260,13 +306,18 @@ class SchedulerCache:
         with self._lock:
             ni = self._nodes.get(node.metadata.name)
             if ni is None:
+                prev = None
                 ni = NodeInfo(node)
                 self._nodes[node.metadata.name] = ni
             else:
+                prev = ni.node
                 ni.set_node(node)
             csi = self._csi_nodes.get(node.metadata.name)
             if csi is not None and not ni.csi_volume_limits:
                 ni.set_csi_node(csi)
+            self._touch(node.metadata.name)
+            if _node_spec_changed(prev, node):
+                self._node_spec_epoch = next(_node_spec_epochs)
 
     def update_node(self, old: Node, new: Node) -> None:
         self.add_node(new)
@@ -275,6 +326,10 @@ class SchedulerCache:
         with self._lock:
             name = node.metadata.name
             ni = self._nodes.pop(name, None)
+            self._gen_order.pop(name, None)
+            if ni is not None:
+                self._removals += 1
+                self._node_spec_epoch = next(_node_spec_epochs)
             if ni is not None and ni.pods:
                 # Keep a nodeless NodeInfo while pods remain (reference
                 # removes the node object but keeps pod accounting;
@@ -282,6 +337,7 @@ class SchedulerCache:
                 ni.node = None
                 ni.generation = next_generation()
                 self._nodes[name] = ni
+                self._touch(name)
             # Assumed pods stranded on the deleted node (drain / spot
             # reclamation racing an in-flight bind) fast-expire: the
             # resilience sweeper's NEXT pass routes them by apiserver
@@ -309,6 +365,7 @@ class SchedulerCache:
             ni = self._nodes.get(csi_node.metadata.name)
             if ni is not None:
                 ni.set_csi_node(csi_node)
+                self._touch(csi_node.metadata.name)
 
     def update_csi_node(self, old, new) -> None:
         self.add_csi_node(new)
@@ -319,6 +376,7 @@ class SchedulerCache:
             ni = self._nodes.get(csi_node.metadata.name)
             if ni is not None:
                 ni.set_csi_node(None)
+                self._touch(csi_node.metadata.name)
 
     def node_count(self) -> int:
         with self._lock:
@@ -388,33 +446,74 @@ class SchedulerCache:
     def update_snapshot(self, snapshot: Snapshot) -> Snapshot:
         """Incrementally refresh ``snapshot`` in place: clone only NodeInfos
         whose generation advanced; drop deleted nodes; refresh derived
-        lists."""
+        lists. The work is O(nodes changed since this snapshot's last
+        refresh): the generation order names them, deleted nodes are
+        looked for only after a removal, and while the node set stands
+        the clones replace their predecessors where they are. A change
+        of membership takes the full walk, which keeps the map's order
+        what it always was."""
         with self._lock:
-            max_gen = snapshot.generation
-            changed = False
-            for name, ni in self._nodes.items():
-                if ni.generation > snapshot.generation:
-                    prev = snapshot.node_info_map.get(name)
-                    if prev is None or (prev.node is None) != (
-                        ni.node is None
-                    ):
-                        # a new map entry, or a node-object transition,
-                        # moves node_info_list membership/row identity
-                        snapshot.note_membership_change()
-                    snapshot.node_info_map[name] = ni.clone()
-                    snapshot.note_changed(name)
-                    changed = True
-                    if ni.generation > max_gen:
-                        max_gen = ni.generation
-            stale = set(snapshot.node_info_map) - set(self._nodes)
-            for name in stale:
-                del snapshot.node_info_map[name]
-                snapshot.note_membership_change()
-                changed = True
-            if changed:
-                snapshot.refresh_lists()
-            snapshot.generation = max_gen
+            snap_gen = snapshot.generation
+            nodes = self._nodes
+            info_map = snapshot.node_info_map
+            # deleted nodes are looked for only where there can be one
+            # (reference cache.go:272 compares the lengths first)
+            membership = snapshot.source is not self or (
+                (
+                    len(info_map) != len(nodes)
+                    or snapshot.removals_seen != self._removals
+                )
+                and bool(info_map.keys() - nodes.keys())
+            )
+            changed: List[Tuple[str, NodeInfo]] = []
+            for name in reversed(self._gen_order):
+                ni = nodes[name]
+                if ni.generation <= snap_gen:
+                    break
+                changed.append((name, ni))
+                prev = info_map.get(name)
+                if prev is None or (prev.node is None) != (ni.node is None):
+                    # a new map entry, or a node-object transition,
+                    # moves node_info_list membership/row identity
+                    membership = True
+            snapshot.source = self
+            snapshot.removals_seen = self._removals
+            snapshot.set_node_spec_epoch(self._node_spec_epoch)
+            snapshot.last_refreshed = len(changed)
+            if membership or not snapshot.replace_in_place(
+                [(name, ni.clone()) for name, ni in changed]
+            ):
+                self._update_snapshot_full(snapshot)
+            elif changed:
+                snapshot.generation = changed[0][1].generation
             return snapshot
+
+    def _update_snapshot_full(self, snapshot: Snapshot) -> None:
+        """The walk over every node: for a snapshot another cache fed,
+        or when nodes joined or left. New names enter the map in
+        ``_nodes``' order, and ``refresh_lists`` rebuilds from it."""
+        max_gen = snapshot.generation
+        changed = False
+        for name, ni in self._nodes.items():
+            if ni.generation > snapshot.generation:
+                prev = snapshot.node_info_map.get(name)
+                if prev is None or (prev.node is None) != (
+                    ni.node is None
+                ):
+                    snapshot.note_membership_change()
+                snapshot.node_info_map[name] = ni.clone()
+                snapshot.note_changed(name)
+                changed = True
+                if ni.generation > max_gen:
+                    max_gen = ni.generation
+        stale = set(snapshot.node_info_map) - set(self._nodes)
+        for name in stale:
+            del snapshot.node_info_map[name]
+            snapshot.note_membership_change()
+            changed = True
+        if changed:
+            snapshot.refresh_lists()
+        snapshot.generation = max_gen
 
     # -- debugger support (internal/cache/debugger) -------------------------
 
@@ -436,12 +535,16 @@ class SchedulerCache:
             ni = NodeInfo()
             self._nodes[name] = ni
         ni.add_pod(pod)
+        self._touch(name)
 
     def _remove_pod_from_node(self, pod: Pod) -> None:
         name = pod.spec.node_name
         ni = self._nodes.get(name)
         if ni is None:
             return
-        ni.remove_pod(pod)
+        if ni.remove_pod(pod):
+            self._touch(name)
         if ni.node is None and not ni.pods:
             del self._nodes[name]
+            self._gen_order.pop(name, None)
+            self._removals += 1

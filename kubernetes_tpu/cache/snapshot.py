@@ -16,9 +16,12 @@ from typing import Dict, Iterable, List, Optional, Set, Tuple
 from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.cache.node_info import NodeInfo, pod_has_affinity_constraints
 
-#: above this many accumulated changed names the per-name tracking stops
-#: paying for itself -- consumers fall back to the full generation walk
-CHANGE_TRACK_CAP = 4096
+#: the change log holds at least this many names, and twice the node
+#: count where that is more: one refresh notes each node at most once,
+#: so the newer half always holds the last refresh whole, and a consumer
+#: that reads at every refresh is never sent to the full generation walk
+#: (a constant of 4,096 was what one full batch of a 5,000-node burst hit)
+CHANGE_TRACK_MIN = 4096
 
 
 def _entry_seq(entry: Tuple[int, str]) -> int:
@@ -36,6 +39,22 @@ class Snapshot:
             ni for ni in self.node_info_list if ni.pods_with_affinity
         ]
         self.generation: int = 0
+        # -- what the cache that feeds this snapshot has told it ------------
+        # (SchedulerCache.update_snapshot writes these under its lock)
+        self.source: Optional[object] = None
+        self.removals_seen = 0
+        #: moves when the cache adds or removes a Node object or takes
+        #: one whose labels, taints, ``unschedulable``, annotations or
+        #: images differ; 0 while no cache has fed the snapshot. What
+        #: depends on those alone is kept while it stands.
+        self.node_spec_epoch = 0
+        #: NodeInfos the last refresh cloned
+        self.last_refreshed = 0
+        self._list_pos: Optional[Dict[str, int]] = None
+        self._image_num_nodes: Optional[Dict[str, int]] = None
+        #: the score packer's node-side facts (ops/scoring.py), None
+        #: until taken and again whenever ``node_spec_epoch`` moves
+        self.score_facts: Optional[Tuple[bool, bool, bool]] = None
         # -- change tracking (epoch plumbing for the tensor packer) ---------
         # update_snapshot notes every name it re-clones in an APPEND-ONLY
         # sequence-stamped log so any NodeTensorCache can repack O(changed)
@@ -71,14 +90,22 @@ class Snapshot:
 
     def note_changed(self, name: str) -> None:
         """update_snapshot re-cloned this node's NodeInfo."""
+        self.note_changed_many((name,))
+
+    def note_changed_many(self, names: Iterable[str]) -> None:
         with self._change_lock:
-            self._change_seq += 1
-            self._change_log.append((self._change_seq, name))
-            if len(self._change_log) > CHANGE_TRACK_CAP:
-                # tracking stopped paying for itself: drop the log and
-                # send every cursor behind this point to the full walk
-                self._dropped_seq = self._change_seq
-                self._change_log.clear()
+            log = self._change_log
+            seq = self._change_seq
+            for name in names:
+                seq += 1
+                log.append((seq, name))
+            self._change_seq = seq
+            if len(log) > max(CHANGE_TRACK_MIN, 2 * len(self.node_info_map)):
+                # drop the older half; a cursor behind it takes the
+                # full walk, one that reads at every refresh never is
+                drop = len(log) // 2
+                self._dropped_seq = log[drop - 1][0]
+                del log[:drop]
 
     def note_membership_change(self) -> None:
         """A node appeared in / disappeared from the map (or lost its
@@ -114,6 +141,51 @@ class Snapshot:
             names = {n for _s, n in self._change_log[i:]}
             return names, membership_moved, self._change_seq
 
+    def replace_in_place(self, clones: List[Tuple[str, NodeInfo]]) -> bool:
+        """Put each ``(name, clone)`` where its predecessor stands in the
+        map and the lists: the refresh of a snapshot whose node set has
+        not moved. Nothing is touched and False returned when the
+        position index does not hold (the caller then takes the full
+        walk). The list is copied before it is written: a reader on
+        another thread that holds the old one (the preemptor, the
+        prewarm thread) keeps seeing one refresh's state whole."""
+        if not clones:
+            return True
+        pos = self._list_pos
+        lst = self.node_info_list
+        if pos is None:
+            pos = self._list_pos = {
+                ni.node.metadata.name: i for i, ni in enumerate(lst)
+            }
+        info_map = self.node_info_map
+        for name, clone in clones:
+            if clone.node is not None:
+                i = pos.get(name)
+                if i is None or lst[i] is not info_map[name]:
+                    return False
+        lst = list(lst)
+        affinity_moved = False
+        for name, clone in clones:
+            prev = info_map[name]
+            info_map[name] = clone
+            if clone.node is not None:
+                lst[pos[name]] = clone
+            if prev.pods_with_affinity or clone.pods_with_affinity:
+                affinity_moved = True
+        self.node_info_list = lst
+        if affinity_moved:
+            self.have_pods_with_affinity_list = [
+                ni for ni in lst if ni.pods_with_affinity
+            ]
+        self.note_changed_many(name for name, _clone in clones)
+        return True
+
+    def set_node_spec_epoch(self, epoch: int) -> None:
+        if epoch != self.node_spec_epoch:
+            self.node_spec_epoch = epoch
+            self._image_num_nodes = None
+            self.score_facts = None
+
     def refresh_lists(self) -> None:
         old = self.node_info_list
         self.node_info_list = [
@@ -131,12 +203,14 @@ class Snapshot:
             ni for ni in self.node_info_list if ni.pods_with_affinity
         ]
         self._image_num_nodes = None
+        self.score_facts = None
+        self._list_pos = None
 
     def image_num_nodes(self) -> Dict[str, int]:
         """image name -> number of nodes holding it; computed once per
         snapshot refresh (reference ImageStateSummary.NumNodes,
         snapshot.go:124 createImageStates)."""
-        cached = getattr(self, "_image_num_nodes", None)
+        cached = self._image_num_nodes
         if cached is None:
             cached = {}
             for ni in self.node_info_list:

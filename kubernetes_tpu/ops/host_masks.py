@@ -16,7 +16,8 @@ reference evaluating per pod with 16 goroutines
 
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from collections import OrderedDict
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -26,7 +27,7 @@ from kubernetes_tpu.api.types import (
     TAINT_EFFECT_NO_SCHEDULE,
     Taint,
 )
-from kubernetes_tpu.cache.node_info import pod_host_ports
+from kubernetes_tpu.cache.node_info import pod_host_ports, pod_hot_info
 from kubernetes_tpu.cache.snapshot import Snapshot
 from kubernetes_tpu.plugins.nodeaffinity import (
     pod_matches_node_selector_and_affinity,
@@ -39,6 +40,59 @@ _UNSCHEDULABLE_TAINT = Taint(
 )
 
 _EMPTY_SIG: Tuple = ("", (), (), ())
+
+#: static mask rows a ``MaskRowCache`` keeps, least recently used out
+#: first (a row is one byte a node slot: 64 rows of a 5,000-node
+#: cluster are 360 KB)
+MASK_ROWS_KEPT = 64
+
+
+class MaskRowCache:
+    """Static mask rows kept from batch to batch, by constraint
+    signature. A row depends on the signature, the Node objects and the
+    node -> tensor row map, so the rows stand while (a) the snapshot's
+    ``node_spec_epoch`` says no Node object was added or removed and
+    none changed its labels, taints or ``unschedulable`` (a status write
+    that moves none of them keeps the rows) and (b) the tensor's slot ->
+    name list is the one they were built for: a NodeTensorCache
+    replaces that list at every change of membership and every full
+    repack (layout, capacity), and shares it with no other cache. Anything else empties the cache. A snapshot no
+    cache feeds (epoch 0) keeps nothing, and a signature with host
+    ports is never kept: its row depends on ``used_ports``, which pods
+    change. Rows are handed out read-only."""
+
+    def __init__(self) -> None:
+        self._epoch = 0
+        self._names: Optional[List[str]] = None
+        self._rows: "OrderedDict[Tuple, np.ndarray]" = OrderedDict()
+        self.rows_built = 0
+        self.rows_reused = 0
+
+    def __len__(self) -> int:
+        return len(self._rows)
+
+    def validate(self, snapshot: Snapshot, nt: NodeTensor) -> bool:
+        """Empty the cache unless it was filled for these Node objects
+        and this row map; False when nothing may be kept at all."""
+        epoch = snapshot.node_spec_epoch
+        if epoch != self._epoch or nt.names is not self._names:
+            self._epoch = epoch
+            self._names = nt.names
+            self._rows.clear()
+        return bool(epoch)
+
+    def get(self, sig: Tuple) -> Optional[np.ndarray]:
+        row = self._rows.get(sig)
+        if row is not None:
+            self._rows.move_to_end(sig)
+            self.rows_reused += 1
+        return row
+
+    def put(self, sig: Tuple, row: np.ndarray) -> None:
+        row.flags.writeable = False
+        self._rows[sig] = row
+        if len(self._rows) > MASK_ROWS_KEPT:
+            self._rows.popitem(last=False)
 
 
 def _constraint_signature(pod: Pod) -> Tuple:
@@ -55,7 +109,7 @@ def _constraint_signature(pod: Pod) -> Tuple:
         and not spec.node_selector
         and not spec.tolerations
         and (spec.affinity is None or spec.affinity.node_affinity is None)
-        and not any(p.host_port for c in spec.containers for p in c.ports)
+        and not pod_hot_info(pod)[7]  # host ports, memoized at ingest
     ):
         # the burst common case: no placement constraints at all -- skip
         # the per-pod tuple assembly entirely
@@ -82,7 +136,7 @@ def _constraint_signature(pod: Pod) -> Tuple:
     tols = tuple(
         (t.key, t.operator, t.value, t.effect) for t in spec.tolerations
     )
-    memo = (spec.node_name, sel, aff, tols, tuple(pod_host_ports(pod)))
+    memo = (spec.node_name, sel, aff, tols, pod_hot_info(pod)[7])
     pod.__dict__["_sig_memo"] = memo
     return memo
 
@@ -98,57 +152,80 @@ def _tolerates_node_taints(pod: Pod, node) -> bool:
     return True
 
 
+def _build_mask_row(
+    pod: Pod, infos, node_rows: List[int], capacity: int
+) -> np.ndarray:
+    row = np.zeros(capacity, dtype=bool)
+    ports = pod_host_ports(pod)
+    for j, ni in zip(node_rows, infos):
+        node = ni.node
+        if node is None:
+            continue
+        # same fake-taint check as the NodeUnschedulable plugin
+        if node.spec.unschedulable and not any(
+            t.tolerates(_UNSCHEDULABLE_TAINT)
+            for t in pod.spec.tolerations
+        ):
+            continue
+        if pod.spec.node_name and pod.spec.node_name != node.metadata.name:
+            continue
+        if not pod_matches_node_selector_and_affinity(pod, ni):
+            continue
+        if not _tolerates_node_taints(pod, node):
+            continue
+        # NodePorts (node_ports.go): exclude nodes whose
+        # usedPorts conflict with the pod's host ports -- the
+        # static row covers EXISTING pods; within-batch port
+        # interactions are serialized by the dispatcher
+        # (batch.py routes host-port pods one per solver batch)
+        if ports and any(
+            ni.used_ports.conflicts(ip, proto, port)
+            for ip, proto, port in ports
+        ):
+            continue
+        row[j] = True
+    return row
+
+
 def static_mask_compact(
-    pods: List[Pod], snapshot: Snapshot, nt: NodeTensor
+    pods: List[Pod],
+    snapshot: Snapshot,
+    nt: NodeTensor,
+    row_cache: Optional[MaskRowCache] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Deduplicated mask: (rows [U, capacity] bool, index [B] int32) with
     ``mask[b] == rows[index[b]]``. U = distinct constraint signatures --
     typically a handful -- so shipping (rows, index) to the device and
     gathering there cuts the per-batch host->device transfer from
-    O(B x N) to O(U x N + B)."""
-    infos = snapshot.list_node_infos()
-    node_rows = nt.rows_for(infos).tolist()
-    index = np.zeros(len(pods), dtype=np.int32)
-    cache: Dict[Tuple, int] = {}
+    O(B x N) to O(U x N + B). With a ``row_cache`` a signature's row is
+    built once for as long as the cache's conditions hold, and a batch
+    costs O(distinct signatures), not O(signatures x N)."""
+    index: List[int] = []
+    usable = row_cache is not None and row_cache.validate(snapshot, nt)
+    infos = node_rows = None
+    seen: Dict[Tuple, int] = {}
     rows: List[np.ndarray] = []
-    for b, pod in enumerate(pods):
+    for pod in pods:
         sig = _constraint_signature(pod)
-        u = cache.get(sig)
+        u = seen.get(sig)
         if u is None:
-            row = np.zeros(nt.capacity, dtype=bool)
-            for j, ni in zip(node_rows, infos):
-                node = ni.node
-                if node is None:
-                    continue
-                # same fake-taint check as the NodeUnschedulable plugin
-                if node.spec.unschedulable and not any(
-                    t.tolerates(_UNSCHEDULABLE_TAINT)
-                    for t in pod.spec.tolerations
-                ):
-                    continue
-                if pod.spec.node_name and pod.spec.node_name != node.metadata.name:
-                    continue
-                if not pod_matches_node_selector_and_affinity(pod, ni):
-                    continue
-                if not _tolerates_node_taints(pod, node):
-                    continue
-                # NodePorts (node_ports.go): exclude nodes whose
-                # usedPorts conflict with the pod's host ports -- the
-                # static row covers EXISTING pods; within-batch port
-                # interactions are serialized by the dispatcher
-                # (batch.py routes host-port pods one per solver batch)
-                ports = pod_host_ports(pod)
-                if ports and any(
-                    ni.used_ports.conflicts(ip, proto, port)
-                    for ip, proto, port in ports
-                ):
-                    continue
-                row[j] = True
+            # a signature with host ports (its fifth part) is built anew
+            keepable = usable and not (len(sig) > 4 and sig[4])
+            row = row_cache.get(sig) if keepable else None
+            if row is None:
+                if infos is None:
+                    infos = snapshot.list_node_infos()
+                    node_rows = nt.rows_for(infos).tolist()
+                row = _build_mask_row(pod, infos, node_rows, nt.capacity)
+                if row_cache is not None:
+                    row_cache.rows_built += 1
+                if keepable:
+                    row_cache.put(sig, row)
             u = len(rows)
             rows.append(row)
-            cache[sig] = u
-        index[b] = u
-    return np.stack(rows), index
+            seen[sig] = u
+        index.append(u)
+    return np.stack(rows), np.array(index, dtype=np.int32)
 
 
 def static_mask(

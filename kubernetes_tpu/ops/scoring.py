@@ -287,6 +287,32 @@ def _soft_constraints(pod: Pod):
     ]
 
 
+def _node_side_facts(snapshot: Snapshot) -> Tuple[bool, bool, bool]:
+    """(any node holds an image, any carries a PreferNoSchedule taint,
+    any carries the avoid-pods annotation): facts of the Node objects
+    alone, so taken once for each of the snapshot's node-spec epochs and
+    not at every batch. A snapshot no cache feeds (epoch 0) cannot tell
+    when its nodes change, and is walked at every call."""
+    facts = snapshot.score_facts if snapshot.node_spec_epoch else None
+    if facts is None:
+        infos = snapshot.list_node_infos()
+        facts = snapshot.score_facts = (
+            any(ni.image_states for ni in infos),
+            any(
+                t.effect == TAINT_EFFECT_PREFER_NO_SCHEDULE
+                for ni in infos
+                if ni.node is not None
+                for t in ni.node.spec.taints
+            ),
+            any(
+                ni.node is not None
+                and AVOID_ANNOTATION in ni.node.metadata.annotations
+                for ni in infos
+            ),
+        )
+    return facts
+
+
 def pack_score_batch(
     pods: List[Pod],
     snapshot: Snapshot,
@@ -295,39 +321,40 @@ def pack_score_batch(
     weights: Dict[str, int],
     hard_pod_affinity_weight: int = 1,
     cluster_affinity_scoring: Optional[bool] = None,
+    admissions=None,
 ) -> Optional[ScoreBatch]:
     """Returns None when no non-resource scorer can influence ranking for
     this batch (the common fast path); raises ScoreEnvelopeExceeded when
-    the batch needs the host path."""
+    the batch needs the host path. ``admissions`` are the pods'
+    admission records (scheduler/admission.py), in any order."""
     infos = snapshot.list_node_infos()
-    node_rows = nt.rows_for(infos).tolist()
     n_cap = nt.capacity
     b = len(pods)
 
-    any_images = any(ni.image_states for ni in infos)
-    any_soft_taints = any(
-        t.effect == TAINT_EFFECT_PREFER_NO_SCHEDULE
-        for ni in infos
-        if ni.node is not None
-        for t in ni.node.spec.taints
-    )
-    any_avoid = any(
-        ni.node is not None
-        and AVOID_ANNOTATION in ni.node.metadata.annotations
-        for ni in infos
-    )
+    any_images, any_soft_taints, any_avoid = _node_side_facts(snapshot)
+    # per pod: the admission record's bits where it has them (the
+    # dispatcher classified every pod at ingest), the walk where not
     need_images = any_images and any(
         c.image for p in pods for c in p.spec.containers
     )
-    need_nodeaff = any(
-        p.spec.affinity is not None
-        and p.spec.affinity.node_affinity is not None
-        and p.spec.affinity.node_affinity.preferred_during_scheduling
-        for p in pods
-    )
+    if admissions is not None:
+        need_nodeaff = any(a.node_pref for a in admissions)
+        need_soft = any(a.score_soft for a in admissions)
+        pods_prefer = any(a.score_pref for a in admissions)
+    else:
+        need_nodeaff = any(
+            p.spec.affinity is not None
+            and p.spec.affinity.node_affinity is not None
+            and p.spec.affinity.node_affinity.preferred_during_scheduling
+            for p in pods
+        )
+        need_soft = any(_soft_constraints(p) for p in pods)
+        pods_prefer = any(
+            _preferred_aff_terms(p) or _preferred_anti_terms(p)
+            for p in pods
+        )
     need_avoid = any_avoid
     need_taint = any_soft_taints
-    need_soft = any(_soft_constraints(p) for p in pods)
 
     # combined selectors only exist when owner objects do
     selectors: List[Optional[CombinedSelector]] = [None] * b
@@ -355,12 +382,8 @@ def pack_score_batch(
     # answer it already computed for its drain decision)
     if cluster_affinity_scoring is None:
         cluster_affinity_scoring = cluster_has_affinity_scoring(snapshot)
-    need_ipa = bool(weights.get("InterPodAffinity", 0)) and (
-        any(
-            _preferred_aff_terms(p) or _preferred_anti_terms(p)
-            for p in pods
-        )
-        or cluster_affinity_scoring
+    need_ipa = bool(weights.get("InterPodAffinity", 0)) and bool(
+        pods_prefer or cluster_affinity_scoring
     )
 
     if not (
@@ -369,6 +392,7 @@ def pack_score_batch(
     ):
         return None
 
+    node_rows = nt.rows_for(infos).tolist()
     # ---- static rows ------------------------------------------------------
     sig_ids: Dict[Tuple, int] = {}
     pod_sig = np.zeros(b, dtype=np.int32)

@@ -100,7 +100,7 @@ class Admission:
         "device_ok", "reason", "vol_counts", "has_pvc", "volume_gen",
         "pinned", "token", "hard_spread", "ports", "affinity_req",
         "required_anti", "scoring_terms", "score_pref", "score_soft",
-        "gang",
+        "node_pref", "gang",
     )
 
     def __init__(self) -> None:
@@ -118,6 +118,7 @@ class Admission:
         self.scoring_terms = False
         self.score_pref = False
         self.score_soft = False
+        self.node_pref = False  # preferred node-affinity terms
         self.gang = False
 
     @property
@@ -215,6 +216,10 @@ def classify_pod(
             _preferred_aff_terms(pod) or _preferred_anti_terms(pod)
         )
         adm.scoring_terms = adm.score_pref or bool(_required_aff_terms(pod))
+        na = spec.affinity.node_affinity if spec.affinity is not None else None
+        adm.node_pref = bool(
+            na is not None and na.preferred_during_scheduling
+        )
 
     # effective priority for the streaming band (stamped ONCE at ingest
     # next to the admission memo): pods that carry only a

@@ -69,6 +69,7 @@ from kubernetes_tpu.ops.affinity import (
     pad_affinity_tensors,
 )
 from kubernetes_tpu.ops.host_masks import (
+    MaskRowCache,
     mask_rows_upload,
     static_mask_compact,
 )
@@ -523,6 +524,8 @@ class BatchScheduler(Scheduler):
         self.max_batch = max_batch
         self.solver_config = solver_config
         self.tensor_cache = tensor_cache or NodeTensorCache()
+        # static mask rows kept from batch to batch (ops/host_masks.py)
+        self.mask_row_cache = MaskRowCache()
         self.batch_window = batch_window
         # SLO-adaptive batching (streaming/autobatch.py): when a
         # controller is attached it rewrites batch_window AND these two
@@ -1969,7 +1972,21 @@ class BatchScheduler(Scheduler):
             )
 
             snapshot = self.algorithm.snapshot
-            self.cache.update_snapshot(snapshot)
+            # pack's parts: totals and trace only, the ring keeps the
+            # one ``pack``
+            batch_id = span.batch_id
+
+            def refresh_snapshot() -> None:
+                with flightrecorder.stage(
+                    "pack.snapshot", totals=totals, batch=batch_id
+                ) as refresh:
+                    self.cache.update_snapshot(snapshot)
+                    refresh.set_metadata(
+                        nodes_refreshed=snapshot.last_refreshed,
+                        nodes=len(snapshot.node_info_list),
+                    )
+
+            refresh_snapshot()
             # existing pods with required anti-affinity constrain EVERY
             # incoming pod symmetrically (filtering.go:404) -- such clusters
             # need the affinity tensors even for batches without affinity, and
@@ -1980,7 +1997,7 @@ class BatchScheduler(Scheduler):
                 has_affinity = True
                 has_affinity_terms = True
                 if drained(True):
-                    self.cache.update_snapshot(snapshot)
+                    refresh_snapshot()
             # existing pods with symmetric scoring terms make EVERY batch's
             # preferred-affinity family live (scoring.go:111): the in-flight
             # counts must land before packing
@@ -1990,7 +2007,7 @@ class BatchScheduler(Scheduler):
             if not score_dynamic and cluster_ipa:
                 score_dynamic = True
                 if drained(True):
-                    self.cache.update_snapshot(snapshot)
+                    refresh_snapshot()
                     cluster_ipa = cluster_has_affinity_scoring(snapshot)
             if nominated_by_node and (
                 has_hard_spread or has_affinity or score_dynamic
@@ -2025,13 +2042,18 @@ class BatchScheduler(Scheduler):
                     self.pods_fallback += 1
                     self.attempt_schedule(pi)
                 return None
-            # pack's three parts: totals and trace only, the ring
-            # keeps the one ``pack``
-            batch_id = span.batch_id
             with flightrecorder.stage(
                 "pack.state", totals=totals, batch=batch_id
-            ):
+            ) as state:
+                # the stage's wall clock holds whatever the other
+                # threads did under the GIL meanwhile: its own work is
+                # the rows it repacked and this thread's CPU time
+                cpu0 = time.thread_time()
                 nt = self.tensor_cache.update(snapshot)
+                state.set_metadata(
+                    rows=int(nt.delta.changed_rows.size),
+                    cpu_ms=round((time.thread_time() - cpu0) * 1e3, 3),
+                )
             with flightrecorder.stage(
                 "pack.pods", totals=totals, batch=batch_id
             ):
@@ -2041,9 +2063,15 @@ class BatchScheduler(Scheduler):
                 )
             with flightrecorder.stage(
                 "pack.masks", totals=totals, batch=batch_id
-            ):
+            ) as masks:
+                kept = self.mask_row_cache
+                reused0 = kept.rows_reused
                 mask_rows, mask_index = static_mask_compact(
-                    pods, snapshot, nt
+                    pods, snapshot, nt, kept
+                )
+                masks.set_metadata(
+                    rows=mask_rows.shape[0],
+                    rows_reused=kept.rows_reused - reused0,
                 )
             # pods requesting resources no node advertises are unsatisfiable:
             # point them at a dedicated all-False row
@@ -2178,80 +2206,71 @@ class BatchScheduler(Scheduler):
             # (dynamic families already forced a pipeline drain above, so the
             # snapshot these counts come from includes in-flight placements)
             ordered_pods = [pods[int(i)] for i in order]
-            try:
-                hard_w = 1
-                if prof0 is not None:
-                    ipa_plugin = prof0.plugin_instance("InterPodAffinity")
-                    hard_w = getattr(
-                        ipa_plugin, "hard_pod_affinity_weight", 1
-                    ) if ipa_plugin is not None else 1
-                score_batch = pack_score_batch(
-                    ordered_pods, snapshot, nt,
-                    prof0.informers if prof0 is not None else None,
-                    prof0.score_plugin_weights() if prof0 is not None else {},
-                    hard_pod_affinity_weight=hard_w,
-                    cluster_affinity_scoring=cluster_ipa,
-                )
-            except ScoreEnvelopeExceeded:
-                # the sequential path filters against the host cache, which
-                # must include every in-flight placement
+            hard_w = 1
+            if prof0 is not None:
+                ipa_plugin = prof0.plugin_instance("InterPodAffinity")
+                hard_w = getattr(
+                    ipa_plugin, "hard_pod_affinity_weight", 1
+                ) if ipa_plugin is not None else 1
+            score_batch = None
+            spread = None
+            affinity = None
+            # a family past its envelope sends the batch to the host
+            # path: (reason, whether that path must first see every
+            # in-flight placement committed)
+            routed = None
+            with flightrecorder.stage(
+                "pack.families", totals=totals, batch=batch_id
+            ):
+                try:
+                    score_batch = pack_score_batch(
+                        ordered_pods, snapshot, nt,
+                        prof0.informers if prof0 is not None else None,
+                        prof0.score_plugin_weights()
+                        if prof0 is not None else {},
+                        hard_pod_affinity_weight=hard_w,
+                        cluster_affinity_scoring=cluster_ipa,
+                        admissions=adms,
+                    )
+                except ScoreEnvelopeExceeded:
+                    # the sequential path filters against the host
+                    # cache, which must include every in-flight placement
+                    routed = ("score_envelope", True)
+                if routed is None and has_hard_spread:
+                    spread = pack_spread_batch(ordered_pods, snapshot, nt)
+                    if spread is None:
+                        routed = ("spread_envelope", False)
+                if routed is None and has_affinity:
+                    affinity = pack_affinity_batch(
+                        ordered_pods, snapshot, nt
+                    )
+                    if affinity is None and has_affinity_terms:
+                        # real affinity/exist rows expected but the
+                        # packer bailed -- port-only batches fall through
+                        # to the port-row builder instead
+                        routed = ("affinity_envelope", False)
+                    elif batch_ports:
+                        # within-batch host-port conflicts ride synthetic
+                        # anti rows (ops/affinity.add_host_port_rows);
+                        # existing-pod conflicts are already in the
+                        # static mask
+                        affinity = add_host_port_rows(
+                            ordered_pods, snapshot, nt, affinity
+                        )
+                        if affinity is None:
+                            # a port-only batch may not have drained above
+                            routed = ("port_envelope", True)
+            if routed is not None:
+                # envelope exceeded: the host path keeps full correctness
+                reason, drain = routed
                 self.envelope_fallbacks += 1
-                self._drain_pending()
-                span.finish(tier=TIER_SEQUENTIAL, routed="score_envelope")
+                if drain:
+                    self._drain_pending()
+                span.finish(tier=TIER_SEQUENTIAL, routed=reason)
                 for pi in solver_infos:
                     self.pods_fallback += 1
                     self.attempt_schedule(pi)
                 return None
-
-            spread = None
-            affinity = None
-            if has_hard_spread:
-                spread = pack_spread_batch(ordered_pods, snapshot, nt)
-                if spread is None:
-                    # envelope exceeded: host path keeps full correctness
-                    self.envelope_fallbacks += 1
-                    span.finish(
-                        tier=TIER_SEQUENTIAL, routed="spread_envelope"
-                    )
-                    for pi in solver_infos:
-                        self.pods_fallback += 1
-                        self.attempt_schedule(pi)
-                    return None
-            if has_affinity:
-                affinity = pack_affinity_batch(ordered_pods, snapshot, nt)
-                if affinity is None and has_affinity_terms:
-                    # envelope exceeded (real affinity/exist rows expected
-                    # but the packer bailed): the host path keeps full
-                    # correctness -- port-only batches fall through to the
-                    # port-row builder instead
-                    self.envelope_fallbacks += 1
-                    span.finish(
-                        tier=TIER_SEQUENTIAL, routed="affinity_envelope"
-                    )
-                    for pi in solver_infos:
-                        self.pods_fallback += 1
-                        self.attempt_schedule(pi)
-                    return None
-                if batch_ports:
-                    # within-batch host-port conflicts ride synthetic anti
-                    # rows (ops/affinity.add_host_port_rows); existing-pod
-                    # conflicts are already in the static mask
-                    affinity = add_host_port_rows(
-                        ordered_pods, snapshot, nt, affinity
-                    )
-                    if affinity is None:
-                        # port-row envelope exceeded: the sequential filter
-                        # must see every in-flight placement committed (a
-                        # port-only batch may not have drained above)
-                        self._drain_pending()
-                        self.envelope_fallbacks += 1
-                        span.finish(
-                            tier=TIER_SEQUENTIAL, routed="port_envelope"
-                        )
-                        for pi in solver_infos:
-                            self.pods_fallback += 1
-                            self.attempt_schedule(pi)
-                        return None
 
         span.note(padded=padded)
         dispatch.set_metadata(padded=padded)

@@ -43,7 +43,6 @@ from kubernetes_tpu.api.types import (
 )
 from kubernetes_tpu.cache.node_info import (
     NodeInfo,
-    Resource,
     non_zero_requests,
     pod_hot_info,
 )
@@ -53,6 +52,7 @@ from kubernetes_tpu.tensors.encoding import TopologyEncoder
 from kubernetes_tpu.utils import metrics as _metrics
 
 NODE_BUCKET = 128  # row padding granularity (TPU lane width)
+
 
 #: extra row slots allocated past the live node count so membership
 #: churn (autoscaler adds, spot replacements) claims pre-zeroed rows
@@ -76,6 +76,23 @@ def value_capacity(n_cap: int, floor: int = VALUE_FLOOR) -> int:
     The cap adapts to the padded node capacity -- n_cap is already
     bucketed, so the derived shapes are re-JIT-stable per cluster."""
     return max(floor, n_cap)
+
+
+def _node_ints(ni: NodeInfo) -> Tuple[int, ...]:
+    """A node's ten fixed-column integers in the tensor's units (module
+    docstring): allocatable with bytes floored to KiB, requested with
+    bytes ceiled and the pod count in the pods column, non-zero
+    requested (milliCPU, KiB ceiled)."""
+    a = ni.allocatable
+    r = ni.requested
+    z = ni.non_zero_requested
+    return (
+        a.milli_cpu, a.memory // 1024, a.ephemeral_storage // 1024,
+        a.allowed_pod_number,
+        r.milli_cpu, -(-r.memory // 1024),
+        -(-r.ephemeral_storage // 1024), len(ni.pods),
+        z.milli_cpu, -(-z.memory // 1024),
+    )
 
 
 def _kib_floor(b: int) -> int:
@@ -164,17 +181,6 @@ class ResourceDims:
                 self._volume_cols_cache = cache
         return cache
 
-    def encode_resource(self, r: Resource, *, ceil_bytes: bool) -> np.ndarray:
-        kib = _kib_ceil if ceil_bytes else _kib_floor
-        row = np.zeros(self.num_dims, dtype=np.int32)
-        row[CPU] = r.milli_cpu
-        row[MEM] = kib(r.memory)
-        row[EPH] = kib(r.ephemeral_storage)
-        row[PODS] = r.allowed_pod_number
-        for name, qty in r.scalar.items():
-            row[self.column(name)] = qty
-        return row
-
     def encode_requests(
         self, rl: ResourceList, *, ceil_bytes: bool = True, grow: bool = True
     ) -> Tuple[np.ndarray, bool]:
@@ -238,7 +244,12 @@ class NodeTensor:
     and free slots are infeasible for any non-zero request (allocatable
     all-zero) and masked off for zero-request pods by ``valid``."""
 
-    names: List[str]  # slot -> node name; "" marks a free (retired) slot
+    #: slot -> node name; "" marks a free (retired) slot. The cache
+    #: replaces this list (never writes one it has handed out) whenever a
+    #: slot's identity moves -- membership change or full repack -- so the
+    #: same list object means the same node -> row map (in-flight batches
+    #: and ops/host_masks.MaskRowCache rely on it)
+    names: List[str]
     allocatable: np.ndarray  # [N, R] int32
     requested: np.ndarray  # [N, R] int32 (col PODS = current pod count)
     non_zero_requested: np.ndarray  # [N, 2] int32 (milliCPU, KiB)
@@ -338,33 +349,56 @@ class NodeTensorCache:
         self._last_snapshot = None
         self._change_cursor = 0
 
-    # -- packing one node ---------------------------------------------------
+    # -- packing rows --------------------------------------------------------
+
+    def _pack_rows(self, rows: List[int], infos: List[NodeInfo]) -> None:
+        """Encode ``infos`` into the slots ``rows`` (distinct): the
+        integers are gathered into one list and each array is written
+        once, whatever the number of rows."""
+        n = len(rows)
+        if not n:
+            return
+        dims = self.dims
+        at = np.asarray(rows, dtype=np.int64)
+        ints = np.array([_node_ints(ni) for ni in infos], dtype=np.int32)
+        alloc = np.zeros((n, dims.num_dims), dtype=np.int32)
+        req = np.zeros((n, dims.num_dims), dtype=np.int32)
+        alloc[:, :NUM_FIXED_DIMS] = ints[:, :4]
+        req[:, :NUM_FIXED_DIMS] = ints[:, 4:8]
+        vol_cols = dims.volume_columns()
+        for k, ni in enumerate(infos):
+            if ni.allocatable.scalar:
+                for name, qty in ni.allocatable.scalar.items():
+                    alloc[k, dims.column(name)] = qty
+            if ni.requested.scalar:
+                for name, qty in ni.requested.scalar.items():
+                    req[k, dims.column(name)] = qty
+            if vol_cols:
+                # attachable-volume columns: allocatable = CSINode limit /
+                # in-tree default / unlimited; requested = additive in-use
+                # count from resident pods (cache/node_info.py). Volume-free
+                # pods skip these dims in the fit scan (zero request).
+                viu = ni.volume_in_use
+                for name, col in vol_cols.items():
+                    alloc[k, col] = ni.volume_limit(name)
+                    req[k, col] = viu.get(name, 0)
+        self._alloc[at] = alloc
+        self._req[at] = req
+        self._nzr[at] = ints[:, 8:]
+        if self.topology.keys:
+            encode = self.topology.encode_node_labels
+            self._topo[at] = [
+                encode(ni.node.metadata.labels if ni.node else {})
+                for ni in infos
+            ]
+        generations = self._generations
+        for i, ni in zip(rows, infos):
+            generations[i] = ni.generation
+        self._occupied[at] = True
+        self._row_epoch[at] = self._epoch
 
     def _pack_row(self, i: int, ni: NodeInfo) -> None:
-        self._alloc[i] = self.dims.encode_resource(ni.allocatable, ceil_bytes=False)
-        req = self.dims.encode_resource(ni.requested, ceil_bytes=True)
-        req[PODS] = len(ni.pods)
-        vol_cols = self.dims.volume_columns()
-        if vol_cols:
-            # attachable-volume columns: allocatable = CSINode limit /
-            # in-tree default / unlimited; requested = additive in-use
-            # count from resident pods (cache/node_info.py). Volume-free
-            # pods skip these dims in the fit scan (zero request).
-            viu = ni.volume_in_use
-            alloc_row = self._alloc[i]
-            for name, col in vol_cols.items():
-                alloc_row[col] = ni.volume_limit(name)
-                req[col] = viu.get(name, 0)
-        self._req[i] = req
-        self._nzr[i, 0] = ni.non_zero_requested.milli_cpu
-        self._nzr[i, 1] = _kib_ceil(ni.non_zero_requested.memory)
-        if self.topology.keys:
-            self._topo[i] = self.topology.encode_node_labels(
-                ni.node.metadata.labels if ni.node else {}
-            )
-        self._generations[i] = ni.generation
-        self._occupied[i] = True
-        self._row_epoch[i] = self._epoch
+        self._pack_rows([i], [ni])
 
     def _grow(self, n: int) -> None:
         target = max(n + _row_headroom(n), NODE_BUCKET)
@@ -440,6 +474,11 @@ class NodeTensorCache:
         )
 
     def _register_columns(self, ni: NodeInfo) -> None:
+        if not (
+            ni.allocatable.scalar or ni.requested.scalar
+            or ni.csi_volume_limits or ni.volume_in_use
+        ):
+            return  # the common node: fixed columns only
         dims = self.dims
         for name in ni.allocatable.scalar:
             dims.column(name)
@@ -536,18 +575,21 @@ class NodeTensorCache:
             or self.topology.version != self._topo_version
         ):
             return None  # schema grew: full repack
-        changed_rows = []
-        for i, ni in changed_infos:
-            if self._generations[i] != ni.generation:
-                self._pack_row(i, ni)
-                self.rows_repacked += 1
-                changed_rows.append(i)
-        changed_rows.sort()
+        generations = self._generations
+        moved = [
+            pair for pair in changed_infos
+            if generations[pair[0]] != pair[1].generation
+        ]
+        changed_rows = [i for i, _ni in moved]
+        self._pack_rows(changed_rows, [ni for _i, ni in moved])
+        self.rows_repacked += len(moved)
         return self._build_tensor(
             TensorDelta(
                 epoch=self._epoch,
                 layout_epoch=self._layout_epoch,
-                changed_rows=np.asarray(changed_rows, dtype=np.int64),
+                changed_rows=np.sort(
+                    np.asarray(changed_rows, dtype=np.int64)
+                ),
                 full=False,
             ),
         )
@@ -592,8 +634,7 @@ class NodeTensorCache:
             self._free_rows = []
             self._node_count = len(infos)
             self._grow(len(infos))
-            for i, ni in enumerate(infos):
-                self._pack_row(i, ni)
+            self._pack_rows(list(range(len(infos))), infos)
             self.full_repacks += 1
             _metrics.tensor_full_repacks.inc()
             self.rows_repacked += len(infos)
@@ -641,24 +682,26 @@ class NodeTensorCache:
         # already walked the list)
         self._refresh_info_rows(infos)
         changed: List[int] = []
+        moved: List[NodeInfo] = []
         row_of = self._row_of
+        generations = self._generations
         if tracked is None:
             for ni in infos:
                 i = row_of[ni.node_name]
-                if self._generations[i] != ni.generation:
-                    self._pack_row(i, ni)
-                    self.rows_repacked += 1
+                if generations[i] != ni.generation:
                     changed.append(i)
+                    moved.append(ni)
         else:
             for name in tracked:
                 ni = info_map.get(name)
                 i = row_of.get(name)
                 if ni is None or ni.node is None or i is None:
                     continue  # removed this update: already retired
-                if self._generations[i] != ni.generation:
-                    self._pack_row(i, ni)
-                    self.rows_repacked += 1
+                if generations[i] != ni.generation:
                     changed.append(i)
+                    moved.append(ni)
+        self._pack_rows(changed, moved)
+        self.rows_repacked += len(changed)
         changed_rows = np.asarray(
             sorted(changed + member_rows), dtype=np.int64
         )

@@ -235,7 +235,15 @@ def burst_trace(tmp_path):
     try:
         with profiled(tmp_path) as events:
             _burst(client, sched, 120)
-            time.sleep(0.7)  # past the run loop's idle point: one collect
+            # the run loop's idle point, 0.5 s after the last pop, makes
+            # one full collection. Its span is in the trace only once it
+            # has ENDED, and in a worker whose heap earlier test files
+            # grew it takes longer than any fixed sleep allows: wait for
+            # the stage's total, which is written after the span closes
+            deadline = time.time() + 60
+            while not sched.stage_totals.calls().get("gc"):
+                assert time.time() < deadline, "the idle collect never ran"
+                time.sleep(0.02)
         dump = flightrecorder.RECORDER.dump()
         yield events, dump, sched
     finally:
@@ -267,8 +275,9 @@ def test_a_burst_shows_every_stage_with_a_shared_batch(burst_trace):
 def test_packs_children_lie_inside_their_parent(burst_trace):
     events, _dump, _sched = burst_trace
     packs = named(events, "sched/pack")
-    for child_name in ("sched/pack.state", "sched/pack.pods",
-                       "sched/pack.masks"):
+    for child_name in ("sched/pack.snapshot", "sched/pack.state",
+                       "sched/pack.pods", "sched/pack.masks",
+                       "sched/pack.families"):
         children = named(events, child_name)
         assert len(children) == len(packs)
         for child in children:
@@ -282,6 +291,32 @@ def test_packs_children_lie_inside_their_parent(burst_trace):
                        if d["stats"]["batch"] == pack["stats"]["batch"]]
         assert dispatch["start"] <= pack["start"]
         assert pack["end"] <= dispatch["end"]
+
+
+def test_packs_children_say_how_much_was_incremental(burst_trace):
+    events, _dump, _sched = burst_trace
+    by_start = lambda ev: ev["start"]
+    refreshes = sorted(named(events, "sched/pack.snapshot"), key=by_start)
+    for refresh in refreshes:
+        assert refresh["stats"]["nodes"] == 16
+        assert 0 <= refresh["stats"]["nodes_refreshed"] <= 16
+    # the first batch's commit changed nodes, so the second refreshed some
+    assert refreshes[-1]["stats"]["nodes_refreshed"] >= 1
+    masks = sorted(named(events, "sched/pack.masks"), key=by_start)
+    assert len(masks) >= 2
+    for mask in masks:  # plain pods: one signature a batch
+        assert mask["stats"]["rows"] == 1
+    # built once, for the first batch; no node object changed since
+    assert [m["stats"]["rows_reused"] for m in masks] == \
+        [0] + [1] * (len(masks) - 1)
+    # the row repack says what it did itself: rows written, and this
+    # thread's CPU time beside the span's wall clock
+    states = sorted(named(events, "sched/pack.state"), key=by_start)
+    for state in states:
+        assert 0 <= state["stats"]["rows"] <= 16
+        assert 0 <= state["stats"]["cpu_ms"] <= \
+            (state["end"] - state["start"]) / 1e6 + 1.0
+    assert states[-1]["stats"]["rows"] >= 1
 
 
 def test_dispatch_spans_carry_the_rings_queue_waits(burst_trace):
@@ -309,11 +344,14 @@ def test_stage_seconds_keeps_its_keys_and_gains_the_new(burst_trace):
     old = {"pop_batch", "pop_wait", "pack", "device_solve", "download",
            "commit"}
     new = {"ingest", "bind", "bind.api", "gc", "pack.state", "pack.pods",
-           "pack.masks"}
+           "pack.masks", "pack.snapshot", "pack.families"}
     assert old | new <= set(seconds)
     assert "classify" not in seconds  # per pod: only under profile_stages
     assert all(v >= 0 for v in seconds.values())
-    parts = seconds["pack.state"] + seconds["pack.pods"] + seconds["pack.masks"]
+    parts = sum(seconds[name] for name in (
+        "pack.snapshot", "pack.state", "pack.pods", "pack.masks",
+        "pack.families",
+    ))
     assert parts <= seconds["pack"]
     assert seconds["bind.api"] <= seconds["bind"]
     # the ring's stages_ms keeps its names; the bulk bind joins them
@@ -323,7 +361,8 @@ def test_stage_seconds_keeps_its_keys_and_gains_the_new(burst_trace):
         )
     batches = len(dump["spans"])
     calls = sched.stage_totals.calls()
-    for name in ("pack", "device_solve", "download", "commit", "bind"):
+    for name in ("pack", "device_solve", "download", "commit", "bind",
+                 "pack.snapshot", "pack.families"):
         assert calls[name] == batches, name
 
 

@@ -18,6 +18,14 @@ Prints ONE JSON line:
    "node_update_ms_churn{0,1pct,100pct}":
                          NodeTensorCache.update() at M nodes when 0% /
                          1% / 100% of rows changed since the last pack,
+   "snapshot_refresh_ms_churn{0,1pct,100pct}":
+                         SchedulerCache.update_snapshot() at the same
+                         three churns (the generation-ordered walk:
+                         clones what changed, looks at nothing else),
+   "static_mask_ms_{cold,reused}":
+                         static_mask_compact for an 80-pod batch of one
+                         signature at M nodes, built and then handed out
+                         again by a MaskRowCache,
    "reuse_check_ms_churn{0,1pct,100pct}":
                          the dispatch generation handshake (epoch compare
                          + changed-row content check) at the same churn,
@@ -295,7 +303,12 @@ def bench_node_state(num_nodes):
                 make_pod(f"ch-{seq}").node(f"bn-{i}")
                 .container(cpu="100m").obj()
             )
+        t0 = time.perf_counter()
         cache.update_snapshot(snap)
+        out[f"snapshot_refresh_ms_churn{label}"] = (
+            time.perf_counter() - t0
+        ) * 1000
+        assert snap.last_refreshed == k
         prev_epoch = nt.delta.epoch
         t0 = time.perf_counter()
         nt = tc.update(snap)
@@ -330,6 +343,22 @@ def bench_node_state(num_nodes):
     assert np.array_equal(nt.requested, shadow_req)
     assert np.array_equal(nt.non_zero_requested, shadow_nzr)
     out["reuse_check_full_sweep_ms"] = (time.perf_counter() - t0) * 1000
+    # the static mask row of one signature: built over every node, then
+    # handed out again while no node object and no row slot has changed
+    from kubernetes_tpu.ops.host_masks import (
+        MaskRowCache,
+        static_mask_compact,
+    )
+
+    pods = [
+        make_pod(f"sm-{i}").container(cpu="100m").obj() for i in range(80)
+    ]
+    kept = MaskRowCache()
+    for label in ("cold", "reused"):
+        t0 = time.perf_counter()
+        static_mask_compact(pods, snap, nt, kept)
+        out[f"static_mask_ms_{label}"] = (time.perf_counter() - t0) * 1000
+    assert (kept.rows_built, kept.rows_reused) == (1, 1)
     return out
 
 
@@ -1413,8 +1442,9 @@ def bench_trace_overhead(num_pods: int = 1000, num_nodes: int = 200):
 HOT_STAGES = ("pop_batch", "pack", "device_solve", "download", "commit")
 #: every flightrecorder.stage one batch passes through
 BATCH_STAGES = (
-    "pop_wait", "pop_batch", "dispatch", "pack", "pack.state",
-    "pack.pods", "pack.masks", "device_solve", "download", "commit",
+    "pop_wait", "pop_batch", "dispatch", "pack", "pack.snapshot",
+    "pack.state", "pack.pods", "pack.masks", "pack.families",
+    "device_solve", "download", "commit",
     "commit.gather", "commit.clone", "commit.assume", "bind", "bind.api",
 )
 
