@@ -2,8 +2,9 @@
 whatever device the test run has (``--rehearsal`` skips the look for a
 chip and nothing else); the same run with the timed path broken
 underneath, which has to come out as not correct; and a temporary copy
-of the benchmark that gains a configuration, a mix, a generator, a
-per-layer metric and a cell as new files and new entries only."""
+of the benchmark that gains a configuration, a mix, a generator, two
+per-layer metrics and a four-chip cell as new files and new entries only,
+and still keeps the benchmark's own rules (``benchmark_rules.py``)."""
 
 import json
 import os
@@ -12,6 +13,7 @@ import subprocess
 import sys
 from pathlib import Path
 
+import benchmark_rules as rules
 import pytest
 
 from chipbench import harness
@@ -33,8 +35,7 @@ def run_cell(capsys, workload, trace, seed=2**31 + 7, extra=()):
 
 def expected_metrics(workload, group):
     return {
-        m["name"] for m in BENCH[group]
-        if workload in m.get("workloads", [workload])
+        m["name"] for m in BENCH[group] if workload in rules.cells_of(BENCH, m)
     }
 
 
@@ -51,10 +52,12 @@ def test_cell_runs_at_rehearsal_size(capsys, workload, trace):
         device |= {"busy_s", "window_s"}
         assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
         assert len(line["breakdown"]["device_ops"]) <= 10
-        # the kernel metrics find no kernel in a CPU trace and are left out
-        want = expected_metrics(workload, "per_layer") - {
-            "solve_kernel_ms_per_batch", "solve_kernel_roofline"
-        }
+        # a metric whose file says ``on_chip_only`` reads device events
+        # that a CPU's trace does not have, and is left out of the line
+        declared = expected_metrics(workload, "per_layer")
+        chip_only = rules.on_chip_only(ROOT, declared)
+        assert chip_only.isdisjoint(line["metrics"])
+        want = declared - chip_only
         assert line["device"]["busy_s"] > 0
         # warm-up by the cell's own traffic covered every program
         assert line["metrics"]["compiles_in_window"]["value"] == 0
@@ -172,11 +175,20 @@ def read(sample, args):
 
 
 def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
+    """What a later PR does, in a copy: a configuration, a mix, a
+    generator, a reader, a cell on four chips (a rehearsal does not look
+    for chips), and two per-layer metrics appended at the end, of which
+    one reads a kernel that only the chip's trace has. The cell joins
+    every ``workloads`` list but those of the one-chip kernels' metrics,
+    which it cannot report. The copy then has to keep every rule the
+    real file is held to, run, and hold no edited file."""
+    cell = "tiny-40.one-wave"
     copy = tmp_path / "checkout"
     copy.mkdir()
     shutil.copy(ROOT / "BENCHMARK.json", copy)
-    shutil.copytree(ROOT / "chipbench", copy / "chipbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for base in BENCH["paths"]:
+        shutil.copytree(ROOT / base, copy / base,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     before = {
         p: p.read_bytes() for p in copy.rglob("*")
         if p.is_file() and p.name != "BENCHMARK.json"
@@ -191,31 +203,46 @@ def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
     (copy / "chipbench/traffic/one-wave.json").write_text(json.dumps(mix))
     (copy / "chipbench/generators/single.py").write_text(NEW_GENERATOR)
     (copy / "chipbench/readers/wave_count.py").write_text(NEW_READER)
-    metric = {"name": "waves_run", "unit": "count", "better": "higher",
-              "source": "host_clock", "layer": "harness",
-              "moves": "pod_to_bind_p99_ms",
-              "workloads": ["tiny-40.one-wave"]}
-    (copy / "chipbench/layer_metrics/waves_run.json").write_text(
-        json.dumps(dict(metric, reader="wave_count", args={}))
-    )
+    counted = {"name": "waves_run", "unit": "count", "better": "higher",
+               "source": "host_clock", "layer": "harness",
+               "moves": "pod_to_bind_p99_ms", "workloads": [cell]}
+    kernel = {"name": "shard_kernel_us_per_step", "unit": "us",
+              "better": "lower", "source": "device_trace", "layer": "kernel",
+              "moves": "pod_to_bind_p50_ms", "workloads": [cell]}
+    for metric, rest in (
+        (counted, {"reader": "wave_count", "args": {}}),
+        (kernel, {"reader": "kernel_time", "on_chip_only": True,
+                  "args": {"pattern": "^pallas_shard_candidate"}}),
+    ):
+        (copy / f"chipbench/layer_metrics/{metric['name']}.json").write_text(
+            json.dumps(dict(metric, **rest))
+        )
     bench = json.loads((copy / "BENCHMARK.json").read_text())
+    one_chip_kernels = rules.on_chip_only(
+        copy, [m["name"] for m in bench["per_layer"]]
+    )
     bench["configs"].append({
         "name": "tiny-40", "source": config["source"],
         "file": "chipbench/configs/tiny-40.json", "reduced": [], "why": "test",
     })
     bench["workloads"].append({
-        "name": "tiny-40.one-wave", "config": "tiny-40",
-        "traffic": "one-wave", "chips": 1, "why": "test",
+        "name": cell, "config": "tiny-40", "traffic": "one-wave", "chips": 4,
+        "why": "test",
     })
-    for m in bench["end_to_end"]:
-        if "workloads" in m:
-            m["workloads"].append("tiny-40.one-wave")
-    bench["per_layer"].append(metric)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m and m["name"] not in one_chip_kernels:
+            m["workloads"].append(cell)
+    bench["per_layer"] += [counted, kernel]
     (copy / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    for rule in rules.STRUCTURE:
+        rule(bench, copy)
+    for name in rules.NEW:
+        rules.declared_since_pr24(bench, copy, name)
 
     env = dict(os.environ, PYTHONPATH=str(ROOT), JAX_PLATFORMS="cpu")
     proc = subprocess.run(
-        [sys.executable, "-m", "chipbench", "--workload", "tiny-40.one-wave",
+        [sys.executable, "-m", "chipbench", "--workload", cell,
          "--seed", "5", "--seconds", "1", "--trace", "1", "--rehearsal"],
         cwd=copy, env=env, capture_output=True, text=True, timeout=300,
     )
@@ -223,6 +250,10 @@ def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["correct"] is True
     assert line["metrics"]["waves_run"] == {"value": 1.0, "unit": "count"}
+    declared = {m["name"] for m in bench["per_layer"]
+                if cell in rules.cells_of(bench, m)}
+    assert declared & one_chip_kernels == set()
+    assert set(line["metrics"]) == declared - {kernel["name"]}
     assert "window against the reference: not compared" in proc.stdout
     for path, body in before.items():  # no file that was there was edited
         assert path.read_bytes() == body

@@ -1,12 +1,12 @@
 """The three per-layer metrics of pack's incremental inputs (PR 25):
 ``pack_snapshot_ms_per_batch``, ``pack_families_ms_per_batch`` and
 ``pack_mask_rows_reused_share`` are files under ``layer_metrics/`` read
-by readers the benchmark had. Their ``per_layer`` entries are not in
-``BENCHMARK.json`` yet (``PERF.md`` section 7 says which file of the
-benchmark stands in the way), so a temporary copy gains them here, at the
-end of the list, and a traced rehearsal reads all three; a program from
+by readers the benchmark had, and since PR 26 entries of
+``BENCHMARK.json``, appended after PR 24's block, for the three cells
+they were read in. A traced rehearsal reads all three; a program from
 before the spans gives the readers nothing, and they raise nothing."""
 
+import functools
 import json
 import os
 import shutil
@@ -14,29 +14,32 @@ import subprocess
 import sys
 from pathlib import Path
 
+import benchmark_rules as rules
 import pytest
 
 from chipbench.readers import span_stat_ratio, stage_per_batch
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-CELLS = [w["name"] for w in BENCH["workloads"]]
 NAMES = ["pack_snapshot_ms_per_batch", "pack_families_ms_per_batch",
          "pack_mask_rows_reused_share"]
 
 
-def spec_of(name: str) -> dict:
-    return json.loads(
-        (ROOT / "chipbench" / "layer_metrics" / f"{name}.json").read_text()
-    )
+spec_of = functools.partial(rules.spec_of, ROOT)
 
 
-def entry_of(name: str) -> dict:
-    """The ``per_layer`` entry the metric's file stands for."""
+@pytest.mark.parametrize("name", NAMES)
+def test_the_metric_is_declared_for_the_cells_it_was_read_in(name):
+    """Not a file that waits for its entry, as in PR 25."""
+    listed = [m["name"] for m in BENCH["per_layer"]]
+    assert listed.index(name) > listed.index(rules.NEW[-1])  # appended
+    entry = BENCH["per_layer"][listed.index(name)]
+    assert set(rules.FIRST_CELLS) <= set(entry["workloads"])
     spec = spec_of(name)
-    entry = {key: spec[key] for key in
-             ("name", "unit", "better", "source", "layer", "moves")}
-    return dict(entry, workloads=CELLS)
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
+        k: spec[k] for k in
+        ("name", "unit", "better", "source", "layer", "moves")
+    }
 
 
 @pytest.mark.parametrize("name", NAMES)
@@ -82,12 +85,7 @@ def test_a_traced_rehearsal_reads_all_three(tmp_path):
     copy.mkdir()
     shutil.copytree(ROOT / "chipbench", copy / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    declared = {m["name"] for m in bench["per_layer"]}
-    bench["per_layer"] += [
-        entry_of(name) for name in NAMES if name not in declared
-    ]
-    (copy / "BENCHMARK.json").write_text(json.dumps(bench))
+    shutil.copy(ROOT / "BENCHMARK.json", copy)  # a root of its own to trace in
     env = dict(os.environ, PYTHONPATH=str(ROOT), JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "chipbench", "--workload",

@@ -4,9 +4,11 @@
 basic-5000.burst-10k recorded on the chip with the spans in it
 (chipbench/testdata/burst-10k-8s-spans.xplane.pb)."""
 
+import functools
 import json
 from pathlib import Path
 
+import benchmark_rules as rules
 import pytest
 
 from chipbench import program_spans
@@ -20,19 +22,10 @@ from chipbench.readers import (
 ROOT = Path(__file__).resolve().parents[2]
 DATA = ROOT / "chipbench" / "testdata"
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-NEW = [
-    "ingest_ms_per_batch", "bind_ms_per_batch", "pack_state_ms_per_batch",
-    "pack_pods_ms_per_batch", "pack_masks_ms_per_batch", "gc_pause_ms_per_s",
-    "queue_wait_ms_per_pod", "queue_wait_max_ms", "idle_under_pack_pct",
-    "idle_under_commit_pct", "idle_under_bind_pct", "idle_under_ingest_pct",
-    "idle_under_gc_pct", "idle_scheduler_waiting_pct",
-]
+NEW = rules.NEW  # PR 24's fourteen
 
 
-def spec_of(name: str) -> dict:
-    return json.loads(
-        (ROOT / "chipbench" / "layer_metrics" / f"{name}.json").read_text()
-    )
+spec_of = functools.partial(rules.spec_of, ROOT)
 
 
 def span(name, start, end, line=0, **stats):
@@ -150,14 +143,47 @@ def test_stage_per_second():
 
 @pytest.mark.parametrize("name", NEW)
 def test_new_metrics_are_declared_for_every_cell(name):
-    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == [w["name"] for w in BENCH["workloads"]]
-    assert entry["moves"] == "pod_to_bind_p50_ms"
-    spec = spec_of(name)
-    assert (ROOT / "chipbench" / "readers" / f"{spec['reader']}.py").is_file()
-    # appended: nothing that was there moved
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-len(NEW):] == NEW
+    """PR 24's fourteen, held to the rule that later PRs keep without
+    touching this test: a PR appends; nothing that was there moves
+    (``benchmark_rules.declared_since_pr24``)."""
+    rules.declared_since_pr24(BENCH, ROOT, name)
+
+
+def _appended(bench, entry):
+    bench["per_layer"].append(dict(bench["per_layer"][0], name="appended"))
+    bench["workloads"].append(dict(bench["workloads"][0], name="a.new-cell"))
+    for m in bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append("a.new-cell")
+
+
+def _moved(bench, entry):
+    bench["per_layer"].insert(
+        bench["per_layer"].index(entry) + 3,
+        dict(bench["per_layer"][0], name="put_in_the_middle"),
+    )
+
+
+def _rewritten(bench, entry):
+    del entry["workloads"][0]
+
+
+def _unknown_cell(bench, entry):
+    entry["workloads"].append("no-such.cell")
+
+
+@pytest.mark.parametrize("change, kept", [
+    (_appended, True), (_moved, False), (_rewritten, False),
+    (_unknown_cell, False),
+])
+def test_the_rule_takes_an_addition_and_refuses_a_move(change, kept):
+    bench = json.loads(json.dumps(BENCH))
+    change(bench, next(m for m in bench["per_layer"] if m["name"] == NEW[0]))
+    if kept:
+        rules.declared_since_pr24(bench, ROOT, NEW[0])
+    else:
+        with pytest.raises(AssertionError):
+            rules.declared_since_pr24(bench, ROOT, NEW[0])
 
 
 # -- a program without the spans, and a trace that lost them ----------------
