@@ -552,10 +552,14 @@ def phase_preempt(stack: Stack, rng) -> dict:
     }
 
 
-def phase_mesh(sizes: Sizes, rng) -> dict:
+def phase_mesh(sizes: Sizes, rng, expect_tier: str = "pallas") -> dict:
     """The same cluster on a ``meshDevices``-device mesh: the carry
-    sharded over the node axis, the shard_map'd Pallas tier."""
-    stack = Stack(sizes, mesh_devices=sizes.mesh_devices)
+    sharded over the node axis, the shard_map'd Pallas tier.
+    ``expect_tier`` is what the ledger has to say: off a TPU the
+    shard_map tier runs without its kernel and is counted ``xla``."""
+    stack = Stack(
+        sizes, mesh_devices=sizes.mesh_devices, expect_tier=expect_tier
+    )
     try:
         stack.warm_and_start()
         sched = stack.sched

@@ -565,6 +565,14 @@ def mesh_pallas_candidate(mode: str, n_cap: int, mesh) -> bool:
     )
 
 
+def mesh_shard_uses_kernel() -> bool:
+    """Whether the shard_map tier's step is the fused Pallas candidate
+    kernel (TPU backends) or its jnp twin (anywhere else). Shared with
+    the tier ledger (scheduler/batch.py): a shard_map batch that ran
+    without the kernel is not counted ``pallas``."""
+    return jax.default_backend() == "tpu"
+
+
 def _mesh_shard_solver(mesh, config: GreedyConfig, use_kernel: bool):
     """The shard_map'd solver tail (the mesh's Pallas tier): each device
     runs the whole-array greedy step on its OWN ``[N/P, R]`` shard of
@@ -744,8 +752,7 @@ def make_mesh_packed_solver(mesh: "jax.sharding.Mesh"):
         )
         if use_pallas and mode == "greedy":
             solver = _mesh_shard_solver(
-                mesh, config,
-                use_kernel=jax.default_backend() == "tpu",
+                mesh, config, use_kernel=mesh_shard_uses_kernel(),
             )
             assignment, req_out, nzr_out = solver(
                 alloc, req_state, nzr_state, valid,

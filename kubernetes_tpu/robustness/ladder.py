@@ -126,6 +126,16 @@ class SolverLadder:
         # visibility counters (mirrored to metrics; kept as attributes so
         # tests and the perf matrix can read them without scraping)
         self.solves_by_tier: Dict[str, int] = {t: 0 for t in TIERS}
+        # the ledger's name for an attempt's tier where the two differ,
+        # set once by whoever builds the attempts: an attempt keeps its
+        # own breaker, and ``solves_by_tier`` counts the batch on the
+        # tier whose kind of code produced the answer (a mesh's
+        # shard_map attempt runs under the name ``pallas``, and off a
+        # TPU, without its kernel, it is an XLA lowering)
+        self.book_as: Dict[str, str] = {}
+
+    def booked_tier(self, tier: str) -> str:
+        return self.book_as.get(tier, tier)
 
     def breaker(self, tier: str) -> CircuitBreaker:
         return self.breakers[tier]
@@ -197,7 +207,10 @@ class SolverLadder:
                 continue
             if breaker is not None:
                 breaker.record_success()
-            self.solves_by_tier[tier] = self.solves_by_tier.get(tier, 0) + 1
+            booked = self.booked_tier(tier)
+            self.solves_by_tier[booked] = (
+                self.solves_by_tier.get(booked, 0) + 1
+            )
             return tier, result
         raise LadderExhausted(
             f"every solver tier failed for {label}"
@@ -205,6 +218,11 @@ class SolverLadder:
 
     def record_sequential(self, count: int = 1) -> None:
         self.solves_by_tier[TIER_SEQUENTIAL] += count
+
+    def record_xla(self) -> None:
+        """A batch a plain XLA lowering solved outside ``run`` (the
+        legacy mesh path has no ladder of its own)."""
+        self.solves_by_tier[TIER_XLA] += 1
 
     @staticmethod
     def _next_tier_name(attempts, idx) -> str:

@@ -58,6 +58,7 @@ from kubernetes_tpu.ops.assignment import (
     decompress_carry,
     greedy_assign_compact,
     greedy_assign_constrained,
+    mesh_shard_uses_kernel,
     sinkhorn_assign,
     solve_packed,
 )
@@ -482,8 +483,12 @@ class _DeviceNodeState:
         self.req_shadow: Optional[np.ndarray] = None
         self.nzr_shadow: Optional[np.ndarray] = None
         # per-batch expected row deltas the host pack may not have shown
-        # yet: (node_rows [K], req_rows [K, R], nzr_rows [K, 2]), newest
-        # last (replaces the retired full-array shadow_gens ring)
+        # yet: (node_rows [K], req_rows [K, R], nzr_rows [K, 2], seq),
+        # newest last (replaces the retired full-array shadow_gens
+        # ring). ``seq`` is the batch's mirror sequence number: once
+        # the scheduler's ``_assumed_seq`` has reached it the batch is
+        # in the host cache, and a pack made after that can no longer
+        # be said to trail it (_explain_rows)
         self.pending_deltas: "collections.deque" = collections.deque(
             maxlen=_SHADOW_RING_CAP
         )
@@ -651,6 +656,10 @@ class BatchScheduler(Scheduler):
         # so a sick device path steps down Pallas -> XLA -> host greedy
         # -> sequential oracle and the batch ALWAYS completes
         self.ladder = SolverLadder(robustness_config)
+        if mesh is not None and not mesh_shard_uses_kernel():
+            # the shard_map tier is offered on any backend; only a TPU
+            # runs its kernel, and only such a batch is counted pallas
+            self.ladder.book_as[TIER_PALLAS] = TIER_XLA
         # bind retries share the ladder's policy + injectable sleep
         self.bind_retry_policy = self.ladder.config.retry
         self._retry_sleep = self.ladder.config.sleep
@@ -685,6 +694,13 @@ class BatchScheduler(Scheduler):
         # an audit detect that a dispatch/commit raced its checksum
         # window (bumped per dispatch AND per shadow mirror)
         self._dispatch_seq = 0
+        # batches the mesh's shard_map tier solved, with or without its
+        # kernel (mesh_solver_tier)
+        self.mesh_shard_solves = 0
+        # the mirror sequence number of the newest batch whose commit
+        # has finished (its pods assumed into the cache); written by
+        # the committer alone, read by the dispatcher before a refresh
+        self._assumed_seq = 0
         self.carry_audits = 0
         self.carry_audit_heals = 0
         # device-loss rebuild: perf_counter at loss detection; cleared
@@ -1297,14 +1313,18 @@ class BatchScheduler(Scheduler):
 
     @property
     def mesh_solver_tier(self) -> str:
-        """Which mesh tier the run ACTUALLY solved on, for the perf
-        matrix's ``solver_mesh_tier`` label: "pallas" once any batch
-        rode the shard_map'd Pallas tier, else "xla" (the GSPMD twin --
-        either KTPU_MESH_PALLAS=0, an ineligible shape, or every pallas
-        attempt faulted to the twin). Empty off-mesh."""
+        """Which of the mesh's two PROGRAMS the run actually solved
+        on, for the perf matrix's ``solver_mesh_tier`` label, under the
+        ladder's names for their attempts: "pallas" once any batch rode
+        the shard_map'd tier, else "xla" (the GSPMD twin -- either
+        KTPU_MESH_PALLAS=0, an ineligible shape, or every shard_map
+        attempt faulted to the twin). Whether the shard_map tier ran
+        its Pallas kernel is the ledger's to say
+        (``ladder.solves_by_tier``: off a TPU it did not, and the batch
+        is counted ``xla``). Empty off-mesh."""
         if self.mesh is None:
             return ""
-        if self.ladder.solves_by_tier.get(TIER_PALLAS):
+        if self.mesh_shard_solves:
             return "pallas"
         return "xla"
 
@@ -1453,13 +1473,21 @@ class BatchScheduler(Scheduler):
 
     # -- device-state generation handshake ----------------------------------
 
-    def _explain_rows(self, changed, host_req, host_nzr):
+    def _explain_rows(self, changed, host_req, host_nzr, assumed_seq=0):
         """Under ``_shadow_lock``: is every changed row's host content
         explained by the shadow expectation at some committer-trail
         depth? The host may trail the shadow by a suffix of
         ``pending_deltas`` (batches mirrored but whose cache assume the
         host pack predates) -- peel them newest-first until the changed
-        rows match. Returns ``(ok, divergent_rows, keep)``: on a match
+        rows match. Only batches past ``assumed_seq`` may be peeled:
+        the commits up to it had assumed their pods into the cache
+        before this dispatch refreshed its snapshot, so the pack holds
+        them, and a host row that equals the shadow less such a batch
+        is not lagging -- the batch's pods were bound and have since
+        been DELETED (a closed wave no larger than the ring would
+        otherwise read as a lagging host for ever, and the device
+        would go on placing around pods that are gone).
+        Returns ``(ok, divergent_rows, keep)``: on a match
         ``keep`` is the number of newest deltas still unconfirmed; on a
         mismatch ``divergent_rows`` holds the depth-0 mismatches and
         ``keep`` is 0 when NO pending delta touches them (the mismatch
@@ -1483,7 +1511,8 @@ class BatchScheduler(Scheduler):
         div_rows = changed[~row_ok]
         pos = {int(r): j for j, r in enumerate(changed)}
         keep = 0
-        for rows, req_rows, nzr_rows in reversed(ds.pending_deltas):
+        trailing = [d for d in ds.pending_deltas if d[3] > assumed_seq]
+        for rows, req_rows, nzr_rows, _seq in reversed(trailing):
             keep += 1
             for j, r in enumerate(rows.tolist()):
                 jj = pos.get(int(r))
@@ -1498,7 +1527,7 @@ class BatchScheduler(Scheduler):
         div_set = set(div_rows.tolist())
         lagging = any(
             int(r) in div_set
-            for rows, _req_rows, _nzr_rows in ds.pending_deltas
+            for rows, _req_rows, _nzr_rows, _seq in trailing
             for r in rows
         )
         return False, div_rows, (None if lagging else 0)
@@ -1524,17 +1553,17 @@ class BatchScheduler(Scheduler):
             scrubbed = collections.deque(
                 maxlen=ds.pending_deltas.maxlen
             )
-            for rows, req_rows, nzr_rows in ds.pending_deltas:
+            for rows, req_rows, nzr_rows, seq in ds.pending_deltas:
                 keepm = np.fromiter(
                     (int(r) not in mset for r in rows),
                     dtype=bool, count=len(rows),
                 )
                 if keepm.all():
-                    scrubbed.append((rows, req_rows, nzr_rows))
+                    scrubbed.append((rows, req_rows, nzr_rows, seq))
                 elif keepm.any():
-                    scrubbed.append(
-                        (rows[keepm], req_rows[keepm], nzr_rows[keepm])
-                    )
+                    scrubbed.append((
+                        rows[keepm], req_rows[keepm], nzr_rows[keepm], seq,
+                    ))
                 # entries fully on churned slots drop: nothing left to
                 # confirm
             ds.pending_deltas = scrubbed
@@ -1543,6 +1572,7 @@ class BatchScheduler(Scheduler):
     def _negotiate_device_state(
         self, nt, node_requested, node_nzr, overlaid,
         allow_scatter, pending_exists, unmirrored_exists=None,
+        assumed_seq=0,
     ):
         """Decide how this dispatch's node state reaches the device and
         reconcile the handshake bookkeeping. Returns None when in-flight
@@ -1574,6 +1604,10 @@ class BatchScheduler(Scheduler):
         carry, so every placement must have landed in the cache) still
         gates on ``pending_exists``. Defaults to ``pending_exists``
         (the conservative pre-pipelining behavior) when not given.
+
+        ``assumed_seq``: the newest batch whose commit had finished
+        before this dispatch refreshed the snapshot it packed from
+        (``_explain_rows`` says what that forbids).
         """
         if unmirrored_exists is None:
             unmirrored_exists = pending_exists
@@ -1638,7 +1672,8 @@ class BatchScheduler(Scheduler):
                                 member, node_requested, node_nzr
                             )
                         ok, div_rows, keep = self._explain_rows(
-                            nonmember, node_requested, node_nzr
+                            nonmember, node_requested, node_nzr,
+                            assumed_seq,
                         )
                         carry = "reuse" if ok else "diverged"
             static_full = (
@@ -1976,10 +2011,16 @@ class BatchScheduler(Scheduler):
             # one ``pack``
             batch_id = span.batch_id
 
+            assumed_seq = 0
+
             def refresh_snapshot() -> None:
+                nonlocal assumed_seq
                 with flightrecorder.stage(
                     "pack.snapshot", totals=totals, batch=batch_id
                 ) as refresh:
+                    # read BEFORE the refresh: commits up to here are
+                    # in what it reads
+                    assumed_seq = self._assumed_seq
                     self.cache.update_snapshot(snapshot)
                     refresh.set_metadata(
                         nodes_refreshed=snapshot.last_refreshed,
@@ -2315,6 +2356,7 @@ class BatchScheduler(Scheduler):
             allow_scatter=self.mesh is None or self.mesh_delta,
             pending_exists=self._pending_exists(),
             unmirrored_exists=self._unmirrored_exists(),
+            assumed_seq=assumed_seq,
         )
         if neg is None and self._await_mirrors():
             # the blocked path (membership adopt / divergence repair)
@@ -2327,6 +2369,7 @@ class BatchScheduler(Scheduler):
                 allow_scatter=self.mesh is None or self.mesh_delta,
                 pending_exists=self._pending_exists(),
                 unmirrored_exists=False,
+                assumed_seq=assumed_seq,
             )
             if retry is not None:
                 self.speculative_rewinds += 1
@@ -2348,14 +2391,20 @@ class BatchScheduler(Scheduler):
             )
         static_ok = neg["static_ok"]
         carry_ok = neg["carry_ok"]
-        span.note(
-            carry=(
-                "delta" if carry_ok and (
-                    neg["didx"].size or neg["sidx"].size
-                ) else "reuse" if carry_ok else "upload"
-            ),
-            delta_rows=int(neg["didx"].size + neg["sidx"].size),
+        # how the resident state is brought up to date and the rows sent
+        # for it, in the ring and on the profiler's
+        # ``sched/solve_dispatch`` span: a carry that is reused where it
+        # should have been uploaded shows here and nowhere else
+        delta_rows = int(neg["didx"].size + neg["sidx"].size)
+        carry_how = (
+            "upload" if not carry_ok else "scatter" if delta_rows else "reuse"
         )
+        span.note(carry=carry_how, delta_rows=delta_rows)
+        carry_stats = {
+            "devices": 1 if self.mesh is None else int(self.mesh.devices.size),
+            "carry": carry_how,
+            "carry_rows": delta_rows if carry_ok else int(nt.capacity),
+        }
         compress = False
         batch_load16 = 0
         if self.carry_compress_enabled:
@@ -2524,12 +2573,13 @@ class BatchScheduler(Scheduler):
             )
             try:
                 with flightrecorder.stage(
-                    "device_solve", span, totals
+                    "device_solve", span, totals, **carry_stats
                 ) as solving:
                     tier, out = self.ladder.run(
                         attempts, label=f"batch b={b}"
                     )
-                    solving.set_metadata(tier=tier)
+                    booked = self.ladder.booked_tier(tier)
+                    solving.set_metadata(tier=booked)
                 self._jit_watch.refresh()
             except LadderExhausted as exhaust_err:
                 with self._shadow_lock:
@@ -2655,8 +2705,11 @@ class BatchScheduler(Scheduler):
                     ds.invalidate_carry()
                 else:
                     ds.req_dev, ds.nzr_dev = req_out, nzr_out
-            span.note(tier=tier)
+            if self.mesh is not None and tier == TIER_PALLAS:
+                self.mesh_shard_solves += 1
+            span.note(tier=booked)
             return {
+                # the attempt's own name: its breaker guards the download
                 "tier": tier,
                 "carry_in": carry_in,
                 "span": span,
@@ -2730,7 +2783,7 @@ class BatchScheduler(Scheduler):
             if inj is not None:
                 inj.raise_maybe(FaultPoint.DEVICE_SOLVE)
             with flightrecorder.stage(
-                "device_solve", span, totals, tier="mesh"
+                "device_solve", span, totals, tier=TIER_XLA, **carry_stats
             ):
                 assignments_dev, req_out, nzr_out = self._mesh_solve(
                     common_args, spread, affinity, score_batch, padded, nt
@@ -2786,6 +2839,8 @@ class BatchScheduler(Scheduler):
                 self.pods_fallback += 1
                 self.attempt_schedule(pi)
             return None
+        # no ladder ran, and the ledger still says what solved the batch
+        self.ladder.record_xla()
         if not carry_ok:
             metrics.state_uploads.inc()
             if self._device_lost_at is not None:
@@ -3500,7 +3555,8 @@ class BatchScheduler(Scheduler):
                     ds.req_shadow, ds.nzr_shadow,
                 )
                 if delta is not None:
-                    ds.pending_deltas.append(delta)
+                    ds.pending_deltas.append((*delta, self._dispatch_seq))
+            mirror_seq = self._dispatch_seq
         # wake dispatchers parked in _await_mirrors at MIRROR time: the
         # commit/bind API transactions below can be hundreds of ms away,
         # and the speculative renegotiation only needs the mirror
@@ -3516,6 +3572,9 @@ class BatchScheduler(Scheduler):
                 gang_failed_uids=p.get("gang_failed_uids"),
                 span=fspan,
             )
+        # every pod this batch placed is in the cache now: a snapshot
+        # refreshed from here on holds them (_explain_rows)
+        self._assumed_seq = mirror_seq
         fspan.finish()
         if (
             self._prewarm_next_commit

@@ -130,8 +130,13 @@ def test_mesh_phase_holds_its_checks():
 
     if len(jax.devices()) < SMALL.mesh_devices:
         pytest.skip("needs 4 (virtual) devices")
-    mesh = chip_smoke.phase_mesh(SMALL, np.random.default_rng(1))
+    mesh = chip_smoke.phase_mesh(
+        SMALL, np.random.default_rng(1), expect_tier="xla"
+    )
+    # the shard_map program ran (``tier``), and the ledger counts it on
+    # the tier of what it ran without its kernel
     assert mesh["devices"] == 4 and mesh["tier"] == "pallas"
+    assert mesh["tiers"]["pallas"] == 0 and mesh["tiers"]["xla"] > 0
     assert mesh["state_uploads"] <= 1 and mesh["carry_divergences"] == 0
     json.dumps(mesh)
 

@@ -81,9 +81,12 @@ def _mirror(sched, rows, req_rows, nzr_rows):
     placements into the running shadow and remember the per-row delta."""
     ds = sched._dev
     with sched._shadow_lock:
+        sched._dispatch_seq += 1
         np.add.at(ds.req_shadow, rows, req_rows)
         np.add.at(ds.nzr_shadow, rows, nzr_rows)
-        ds.pending_deltas.append((rows, req_rows, nzr_rows))
+        ds.pending_deltas.append(
+            (rows, req_rows, nzr_rows, sched._dispatch_seq)
+        )
 
 
 def _pod_rows(nt, k):
@@ -152,6 +155,41 @@ class TestHandshake:
         # nothing confirmed: the ring still holds all K deltas
         assert len(sched._dev.pending_deltas) == k
         assert sched.state_uploads == 1
+
+    @pytest.mark.parametrize("assumed", [False, True])
+    def test_a_bound_and_deleted_batch_is_not_a_lagging_host(
+        self, sched_stack, assumed
+    ):
+        """A batch's pod is assumed, bound and then DELETED before the
+        next dispatch packs: the row is back at what it held before the
+        batch, which is also what a host that had not yet seen the
+        batch's commit would show. Only what the dispatcher knows of
+        the committer tells the two apart: a batch whose commit had
+        finished before the snapshot was refreshed cannot be trailed,
+        so the row has diverged and is set to host truth on the device.
+        Without that knowledge the same row reads as lag."""
+        sched = sched_stack
+        cache, snap = _cluster(5)
+        nt = sched.tensor_cache.update(snap)
+        _prime(sched, nt)
+        _mirror(sched, *_pod_rows(nt, 2))
+        seq = sched._dev.pending_deltas[-1][3]
+        pod = make_pod("gone").node("hs-2").container(cpu="500m").obj()
+        cache.add_pod(pod)
+        cache.remove_pod(pod)
+        cache.update_snapshot(snap)
+        nt = sched.tensor_cache.update(snap)
+        assert nt.delta.changed_rows.tolist() == [2]
+        assert not nt.requested[2].any()
+        neg = _negotiate(sched, nt, assumed_seq=seq if assumed else 0)
+        assert neg["carry_ok"] and sched.state_uploads == 1
+        if assumed:
+            assert neg["didx"].tolist() == [2]
+            assert not sched._dev.req_shadow[2].any()
+            assert sched.carry_divergences == 1
+        else:
+            assert neg["didx"].size == 0
+            assert len(sched._dev.pending_deltas) == 1  # still trailing
 
     def test_ring_overflow_degrades_to_counted_upload(self, sched_stack):
         """More unobserved mirrors than the ring holds: the oldest delta

@@ -229,7 +229,7 @@ class TestBatchSpanSpine:
         for s in solved:
             assert s["size"] > 0
             assert s["padded"] >= s["size"]
-            assert s["carry"] in ("reuse", "delta", "upload")
+            assert s["carry"] in ("reuse", "scatter", "upload")
             assert "pack" in s["stages_ms"]
             assert "device_solve" in s["stages_ms"]
             assert "commit" in s["stages_ms"]
