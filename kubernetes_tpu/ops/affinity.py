@@ -40,56 +40,25 @@ import numpy as np
 from kubernetes_tpu.api.selectors import labels_match_selector
 from kubernetes_tpu.api.types import LabelSelector, Pod, PodAffinityTerm
 from kubernetes_tpu.cache.snapshot import Snapshot
-from kubernetes_tpu.tensors.node_tensor import NodeTensor
+from kubernetes_tpu.ops import family_facts
+from kubernetes_tpu.ops.family_facts import (
+    ROW_INDEX,
+    FamilyFacts,
+    required_affinity as _required_affinity,
+    required_anti_affinity as _required_anti_affinity,
+    selector_sig as _selector_sig,
+    term_namespaces as _term_namespaces,
+    term_sig as _term_sig,
+)
+from kubernetes_tpu.tensors.node_tensor import NodeTensor, value_capacity
 
 MAX_KEYS = 8  # distinct topology keys per batch
 MAX_AFF_ROWS = 16
 MAX_ANTI_ROWS = 16
 MAX_EXIST_ROWS = 64
 MAX_TERMS_PER_POD = 4
-from kubernetes_tpu.tensors.node_tensor import value_capacity
 
 MAX_VALUES = 128  # interned-value floor (tensors.value_capacity grows it)
-
-
-def _selector_sig(sel: Optional[LabelSelector]) -> Tuple:
-    if sel is None:
-        return ("<nil>",)
-    return (
-        tuple(sorted(sel.match_labels.items())),
-        tuple(
-            (r.key, r.operator, tuple(r.values)) for r in sel.match_expressions
-        ),
-    )
-
-
-def _term_namespaces(owner: Pod, term: PodAffinityTerm) -> Tuple[str, ...]:
-    """topologies.go:28: empty term namespaces default to the owner's."""
-    if term.namespaces:
-        return tuple(sorted(term.namespaces))
-    return (owner.metadata.namespace,)
-
-
-def _term_sig(owner: Pod, term: PodAffinityTerm) -> Tuple:
-    return (
-        _term_namespaces(owner, term),
-        _selector_sig(term.label_selector),
-        term.topology_key,
-    )
-
-
-def _required_affinity(pod: Pod) -> List[PodAffinityTerm]:
-    a = pod.spec.affinity
-    if a is None or a.pod_affinity is None:
-        return []
-    return a.pod_affinity.required_during_scheduling
-
-
-def _required_anti_affinity(pod: Pod) -> List[PodAffinityTerm]:
-    a = pod.spec.affinity
-    if a is None or a.pod_anti_affinity is None:
-        return []
-    return a.pod_anti_affinity.required_during_scheduling
 
 
 class _Matcher:
@@ -134,6 +103,10 @@ class _Row:
     sel_sig: Tuple
     key_idx: int
 
+    @property
+    def term(self) -> family_facts.Term:
+        return (self.namespaces, self.selector, self.sel_sig)
+
 
 @dataclass
 class AffinityBatch:
@@ -175,18 +148,24 @@ class AffinityBatch:
 
 
 def pack_affinity_batch(
-    pods: List[Pod], snapshot: Snapshot, nt: NodeTensor
+    pods: List[Pod],
+    snapshot: Snapshot,
+    nt: NodeTensor,
+    facts: Optional[FamilyFacts] = None,
 ) -> Optional[AffinityBatch]:
     """Returns None when the batch exceeds the device envelope (too many
-    keys/rows/values) -- the caller falls back to the host path."""
-    b = len(pods)
-    infos = snapshot.list_node_infos()
-    node_rows = nt.rows_for(infos).tolist()
+    keys/rows/values) -- the caller falls back to the host path. The pod
+    rows are built once a pod template, the node rows and the counts of
+    the affinity and anti rows come from ``facts``
+    (ops/family_facts.py), which keeps them between batches where it
+    may."""
+    facts = family_facts.attach(facts, snapshot, nt)
+    index, firsts = facts.batch_templates(pods)
+    n_tpl = len(firsts)
     n_cap = nt.capacity
 
     v_cap = value_capacity(n_cap)
     keys: Dict[str, int] = {}
-    value_ids: List[Dict[str, int]] = []
 
     def key_idx(key: str) -> Optional[int]:
         idx = keys.get(key)
@@ -195,12 +174,11 @@ def pack_affinity_batch(
                 return None
             idx = len(keys)
             keys[key] = idx
-            value_ids.append({})
         return idx
 
     matcher = _Matcher()
 
-    # ---- collect rows -----------------------------------------------------
+    # ---- collect rows, a template at a time -------------------------------
     aff_rows: List[_Row] = []
     aff_groups: Dict[Tuple, Tuple[int, List[int]]] = {}  # sig -> (gid, rows)
     anti_rows: List[_Row] = []
@@ -208,10 +186,10 @@ def pack_affinity_batch(
     exist_rows: List[_Row] = []
     exist_row_ids: Dict[Tuple, int] = {}
 
-    pod_aff_rows = np.full((b, MAX_TERMS_PER_POD), -1, dtype=np.int32)
-    pod_anti_rows = np.full((b, MAX_TERMS_PER_POD), -1, dtype=np.int32)
-    pod_self_match = np.zeros(b, dtype=bool)
-    pod_bump_exist = np.zeros((b, MAX_EXIST_ROWS), dtype=np.int32)
+    tpl_aff_rows = np.full((n_tpl, MAX_TERMS_PER_POD), -1, dtype=np.int32)
+    tpl_anti_rows = np.full((n_tpl, MAX_TERMS_PER_POD), -1, dtype=np.int32)
+    tpl_self_match = np.zeros(n_tpl, dtype=bool)
+    tpl_bump_exist = np.zeros((n_tpl, MAX_EXIST_ROWS), dtype=np.int32)
 
     def add_exist_row(owner: Pod, term: PodAffinityTerm) -> Optional[int]:
         sig = _term_sig(owner, term)
@@ -230,7 +208,7 @@ def pack_affinity_batch(
             )
         return r
 
-    for i, pod in enumerate(pods):
+    for ti, pod in enumerate(firsts):
         aff_terms = _required_affinity(pod)
         anti_terms = _required_anti_affinity(pod)
         if (
@@ -260,15 +238,15 @@ def pack_affinity_batch(
                 entry = (len(aff_groups), rows)
                 aff_groups[gsig] = entry
             _, rows = entry
-            pod_aff_rows[i, : len(rows)] = rows
-            pod_self_match[i] = all(
+            tpl_aff_rows[ti, : len(rows)] = rows
+            tpl_self_match[ti] = all(
                 matcher.matches(
                     pod, _term_namespaces(pod, t), t.label_selector,
                     _selector_sig(t.label_selector),
                 )
                 for t in aff_terms
             )
-        for t in anti_terms:
+        for slot, t in enumerate(anti_terms):
             sig = _term_sig(pod, t)
             r = anti_row_ids.get(sig)
             if r is None:
@@ -283,14 +261,13 @@ def pack_affinity_batch(
                     _Row(_term_namespaces(pod, t), t.label_selector,
                          _selector_sig(t.label_selector), k)
                 )
-            slot = list(pod_anti_rows[i]).index(-1)
-            pod_anti_rows[i, slot] = r
+            tpl_anti_rows[ti, slot] = r
             # the pod's own anti term also constrains LATER batch pods
             # symmetrically once this pod places
             er = add_exist_row(pod, t)
             if er is None:
                 return None
-            pod_bump_exist[i, er] = 1
+            tpl_bump_exist[ti, er] = 1
 
     # existing pods' required anti-affinity -> exist rows
     existing_with_anti: List[Tuple[Pod, PodAffinityTerm, int]] = []
@@ -310,21 +287,10 @@ def pack_affinity_batch(
     # ---- node value interning --------------------------------------------
     node_value = np.full((MAX_KEYS, n_cap), -1, dtype=np.int32)
     for key, k in keys.items():
-        ids = value_ids[k]
-        for j, ni in zip(node_rows, infos):
-            node = ni.node
-            if node is None:
-                continue
-            val = node.metadata.labels.get(key)
-            if val is None:
-                continue
-            vid = ids.get(val)
-            if vid is None:
-                if len(ids) >= v_cap:
-                    return None
-                vid = len(ids)
-                ids[val] = vid
-            node_value[k, j] = vid
+        row = facts.node_values(key)
+        if row is None:
+            return None
+        node_value[k] = row.values
 
     # ---- count initialization from existing pods --------------------------
     counts_aff = np.zeros((MAX_AFF_ROWS, v_cap), dtype=np.int32)
@@ -333,50 +299,41 @@ def pack_affinity_batch(
 
     # exist rows: one bump per (existing pod, term) at the pod's node value
     # (filtering.go:212; the batch pods' own rows start at zero)
-    node_row_of = {ni.node_name: j for j, ni in zip(node_rows, infos)}
-    for e, t, r in existing_with_anti:
-        j = node_row_of.get(e.spec.node_name)
-        if j is None:
-            continue
-        v = node_value[exist_rows[r].key_idx, j]
-        if v >= 0:
-            counts_exist[r, v] += 1
-
-    # affinity groups: existing pod bumps every row of a group iff it
-    # matches ALL the group's terms (filtering.go:135); anti rows bump on
-    # any single-term match (filtering.go:153)
-    if aff_rows or anti_rows:
-        group_rows = [rows for (_gid, rows) in aff_groups.values()]
-        for j, ni in zip(node_rows, infos):
-            if ni.node is None:
+    if existing_with_anti:
+        node_row_of = {
+            ni.node_name: j
+            for j, ni in zip(facts.info_rows(), facts.infos)
+        }
+        for e, _t, r in existing_with_anti:
+            j = node_row_of.get(e.spec.node_name)
+            if j is None:
                 continue
-            for e in ni.pods:
-                for rows in group_rows:
-                    if all(
-                        matcher.matches(
-                            e, aff_rows[r].namespaces, aff_rows[r].selector,
-                            aff_rows[r].sel_sig,
-                        )
-                        for r in rows
-                    ):
-                        for r in rows:
-                            v = node_value[aff_rows[r].key_idx, j]
-                            if v >= 0:
-                                counts_aff[r, v] += 1
-                for r, row in enumerate(anti_rows):
-                    if matcher.matches(
-                        e, row.namespaces, row.selector, row.sel_sig
-                    ):
-                        v = node_value[row.key_idx, j]
-                        if v >= 0:
-                            counts_anti[r, v] += 1
+            v = node_value[exist_rows[r].key_idx, j]
+            if v >= 0:
+                counts_exist[r, v] += 1
+
+    # affinity groups: an existing pod bumps every row of a group iff it
+    # matches ALL the group's terms (filtering.go:135); anti rows bump on
+    # any single-term match (filtering.go:153). Neither skips a
+    # terminating pod.
+    group_row_lists = [rows for (_gid, rows) in aff_groups.values()]
+    for rows in group_row_lists:
+        classes = facts.matching([aff_rows[r].term for r in rows])
+        for r in rows:
+            counts_aff[r] = facts.counts(
+                classes, node_value[aff_rows[r].key_idx], live_only=False
+            )
+    for r, row in enumerate(anti_rows):
+        counts_anti[r] = facts.counts(
+            facts.matching([row.term]), node_value[row.key_idx],
+            live_only=False,
+        )
 
     # ---- per-pod match/bump matrices --------------------------------------
-    pod_bump_aff = np.zeros((b, MAX_AFF_ROWS), dtype=np.int32)
-    pod_bump_anti = np.zeros((b, MAX_ANTI_ROWS), dtype=np.int32)
-    pod_exist_match = np.zeros((b, MAX_EXIST_ROWS), dtype=bool)
-    group_row_lists = [rows for (_gid, rows) in aff_groups.values()]
-    for i, pod in enumerate(pods):
+    tpl_bump_aff = np.zeros((n_tpl, MAX_AFF_ROWS), dtype=np.int32)
+    tpl_bump_anti = np.zeros((n_tpl, MAX_ANTI_ROWS), dtype=np.int32)
+    tpl_exist_match = np.zeros((n_tpl, MAX_EXIST_ROWS), dtype=bool)
+    for ti, pod in enumerate(firsts):
         for rows in group_row_lists:
             if all(
                 matcher.matches(
@@ -386,13 +343,13 @@ def pack_affinity_batch(
                 for r in rows
             ):
                 for r in rows:
-                    pod_bump_aff[i, r] = 1
+                    tpl_bump_aff[ti, r] = 1
         for r, row in enumerate(anti_rows):
             if matcher.matches(pod, row.namespaces, row.selector, row.sel_sig):
-                pod_bump_anti[i, r] = 1
+                tpl_bump_anti[ti, r] = 1
         for r, row in enumerate(exist_rows):
             if matcher.matches(pod, row.namespaces, row.selector, row.sel_sig):
-                pod_exist_match[i, r] = True
+                tpl_exist_match[ti, r] = True
 
     row_key_aff = np.full(MAX_AFF_ROWS, -1, dtype=np.int32)
     for r, row in enumerate(aff_rows):
@@ -408,22 +365,26 @@ def pack_affinity_batch(
         node_value=node_value,
         counts_aff=counts_aff,
         row_key_aff=row_key_aff,
-        pod_aff_rows=pod_aff_rows,
-        pod_self_match=pod_self_match,
-        pod_bump_aff=pod_bump_aff,
+        pod_aff_rows=tpl_aff_rows[index],
+        pod_self_match=tpl_self_match[index],
+        pod_bump_aff=tpl_bump_aff[index],
         counts_anti=counts_anti,
         row_key_anti=row_key_anti,
-        pod_anti_rows=pod_anti_rows,
-        pod_bump_anti=pod_bump_anti,
+        pod_anti_rows=tpl_anti_rows[index],
+        pod_bump_anti=tpl_bump_anti[index],
         counts_exist=counts_exist,
         row_key_exist=row_key_exist,
-        pod_exist_match=pod_exist_match,
-        pod_bump_exist=pod_bump_exist,
+        pod_exist_match=tpl_exist_match[index],
+        pod_bump_exist=tpl_bump_exist[index],
     )
 
 
 def add_host_port_rows(
-    pods: List[Pod], snapshot: Snapshot, nt, af: Optional[AffinityBatch]
+    pods: List[Pod],
+    snapshot: Snapshot,
+    nt,
+    af: Optional[AffinityBatch],
+    facts: Optional[FamilyFacts] = None,
 ) -> Optional[AffinityBatch]:
     """Model WITHIN-BATCH host-port conflicts as synthetic anti-affinity
     rows (nodeinfo/host_ports.go semantics): each distinct
@@ -480,10 +441,8 @@ def add_host_port_rows(
     )
     if key_free is None:
         return None  # no key slot left: host path
-    infos = snapshot.list_node_infos()
-    for j, ni in zip(nt.rows_for(infos).tolist(), infos):
-        if ni.node is not None and j < n_cap:
-            af.node_value[key_free, j] = j
+    facts = family_facts.attach(facts, snapshot, nt)
+    af.node_value[key_free] = facts.node_values(ROW_INDEX).values
 
     # distinct port identities -> anti rows
     row_of: Dict[Tuple, int] = {}

@@ -1,0 +1,510 @@
+"""What the family packers keep from batch to batch.
+
+``pack_spread_batch``, ``pack_affinity_batch`` and ``add_host_port_rows``
+(ops/topology.py, ops/affinity.py) read three kinds of facts, each kept
+for as long as the code can see that it still holds, and no longer:
+
+- **node-value rows**: for a ``(topology key, eligibility signature)``,
+  the interned value of that key's label on every node row (-1 where
+  the node lacks the key or is out of the signature's scope) and which
+  value slots exist. They depend on the Node objects and on the
+  node -> tensor row map, so they stand while the snapshot's
+  ``node_spec_epoch`` and the tensor's slot list (``nt.names``, by
+  identity) stand: what ``host_masks.MaskRowCache`` keys on. Values
+  are interned first-seen in ``node_info_list`` order.
+- **the pod census**: for every node row, how many resident pods of
+  each class ``(namespace, labels)`` it holds, the terminating ones
+  told apart (topology spread skips them, filtering.go:255; the
+  affinity counts do not). It is advanced by the snapshot's change
+  log (``Snapshot.changes_since``, read by cursor and never consumed):
+  only the nodes the log names are recounted; a truncated log, a
+  membership move, another snapshot, another slot list or first use
+  recounts every node, through the same routine. A group's count row
+  is the sum, over the classes its selector matches, of the census
+  scattered through the group's node-value row. Memory is O(resident
+  pods), not classes x nodes.
+- **pod templates**: a batch's pods by ``(namespace, labels,
+  constraints)``; the packers build each template's rows once and
+  write them for all its pods with one indexed numpy write.
+
+The dispatcher owns one ``FamilyFacts`` beside its ``MaskRowCache`` and
+hands it to the packers. Without one, or on a snapshot no cache feeds
+(``node_spec_epoch`` 0), ``attach`` hands out a fresh object that is
+dropped with the batch: the same code builds everything once and keeps
+nothing. The census is fed only here, at pack time, by a batch that has
+family pods; no cache write, commit or ingest path knows of it.
+"""
+
+from __future__ import annotations
+
+from collections import OrderedDict
+from typing import Dict, FrozenSet, List, NamedTuple, Optional, Sequence, Tuple
+
+import numpy as np
+
+from kubernetes_tpu.api.selectors import labels_match_selector
+from kubernetes_tpu.api.types import LabelSelector, Pod, PodAffinityTerm
+from kubernetes_tpu.cache.node_info import NodeInfo
+from kubernetes_tpu.cache.snapshot import Snapshot
+from kubernetes_tpu.ops.host_masks import _constraint_signature
+from kubernetes_tpu.plugins.nodeaffinity import (
+    pod_matches_node_selector_and_affinity,
+)
+from kubernetes_tpu.plugins.podtopologyspread import DO_NOT_SCHEDULE
+from kubernetes_tpu.tensors.node_tensor import NodeTensor, value_capacity
+
+#: node-value rows kept, least recently used out first (a row is five
+#: bytes a node slot); selector memos kept, oldest out first; template
+#: keys held so that the pods of one template share one key object
+ROWS_KEPT = 64
+SELECTORS_KEPT = 256
+TEMPLATES_KEPT = 4096
+
+#: the eligibility signature of a pod with no node selector and no
+#: required node affinity: its groups count over every node
+UNSCOPED: Tuple = ((), ())
+
+#: the "key" of the synthetic row whose value is the node's own row
+#: (``add_host_port_rows``); no label key is a tuple
+ROW_INDEX = ("<row>",)
+
+#: the cumulative counters ``tally`` returns, in its order
+TALLY = ("nodes", "nodes_recounted", "node_rows", "node_rows_reused",
+         "templates")
+
+#: one term of a group: (namespaces, selector, selector signature)
+Term = Tuple[Tuple[str, ...], Optional[LabelSelector], Tuple]
+
+
+# -- what identifies a group, a term and a pod template -----------------------
+
+
+def selector_sig(sel: Optional[LabelSelector]) -> Tuple:
+    if sel is None:
+        return ("<nil>",)
+    labels = sel.match_labels
+    return (
+        tuple(sorted(labels.items())) if len(labels) > 1
+        else tuple(labels.items()),
+        tuple([
+            (r.key, r.operator, tuple(r.values)) for r in sel.match_expressions
+        ]) if sel.match_expressions else (),
+    )
+
+
+def eligibility_sig(pod: Pod) -> Tuple:
+    """Signature of the pod's node-affinity/selector scoping: spread
+    pair counting runs only over nodes the pod itself could land on
+    (filtering.go:245 PodMatchesNodeSelectorAndAffinityTerms), so pods
+    with different scoping cannot share a group. It is the node
+    selector and the required node affinity of the static mask's
+    signature, which is kept on the pod."""
+    return _constraint_signature(pod)[1:3]
+
+
+def hard_spread_constraints(pod: Pod) -> List:
+    return [
+        c
+        for c in pod.spec.topology_spread_constraints
+        if c.when_unsatisfiable == DO_NOT_SCHEDULE
+    ]
+
+
+def term_namespaces(owner: Pod, term: PodAffinityTerm) -> Tuple[str, ...]:
+    """topologies.go:28: empty term namespaces default to the owner's."""
+    if term.namespaces:
+        return tuple(sorted(term.namespaces))
+    return (owner.metadata.namespace,)
+
+
+def term_sig(owner: Pod, term: PodAffinityTerm) -> Tuple:
+    return (
+        term_namespaces(owner, term),
+        selector_sig(term.label_selector),
+        term.topology_key,
+    )
+
+
+def required_affinity(pod: Pod) -> List[PodAffinityTerm]:
+    a = pod.spec.affinity
+    if a is None or a.pod_affinity is None:
+        return []
+    return a.pod_affinity.required_during_scheduling
+
+
+def required_anti_affinity(pod: Pod) -> List[PodAffinityTerm]:
+    a = pod.spec.affinity
+    if a is None or a.pod_anti_affinity is None:
+        return []
+    return a.pod_anti_affinity.required_during_scheduling
+
+
+def template_key(pod: Pod) -> Tuple:
+    """Pods with equal keys get equal rows from the family packers:
+    namespace, labels, scoping, hard spread constraints, required
+    (anti-)affinity terms. Once a pod of every batch, so the empty
+    parts cost nothing."""
+    meta = pod.metadata
+    spec = pod.spec
+    spread: Tuple = ()
+    if spec.topology_spread_constraints:
+        spread = tuple([
+            (c.topology_key, c.max_skew, selector_sig(c.label_selector))
+            for c in spec.topology_spread_constraints
+            if c.when_unsatisfiable == DO_NOT_SCHEDULE
+        ])
+    aff: Tuple = ()
+    anti: Tuple = ()
+    if spec.affinity is not None:
+        aff = tuple([term_sig(pod, t) for t in required_affinity(pod)])
+        anti = tuple([term_sig(pod, t) for t in required_anti_affinity(pod)])
+    return (
+        meta.namespace, frozenset(meta.labels.items()),
+        eligibility_sig(pod), spread, aff, anti,
+    )
+
+
+class NodeValues(NamedTuple):
+    values: np.ndarray  # [n_cap] int32, read-only
+    valid: np.ndarray  # [v_cap] bool, read-only
+
+
+class _PodClass:
+    """The resident pods of one ``(namespace, labels)``, by node row."""
+
+    __slots__ = ("namespace", "key", "labels", "pods", "terminating")
+
+    def __init__(
+        self, namespace: str, key: FrozenSet, labels: Dict[str, str]
+    ) -> None:
+        self.namespace = namespace
+        self.key = key  # of ``_classes[namespace]``
+        self.labels = dict(labels)
+        self.pods: Dict[int, int] = {}  # node row -> pods, all of them
+        self.terminating: Dict[int, int] = {}  # of which terminating
+
+
+class FamilyFacts:
+    def __init__(self) -> None:
+        self.keeps = True
+        self._snapshot: Optional[Snapshot] = None
+        self._nt: Optional[NodeTensor] = None
+        self._names: Optional[List[str]] = None
+        self._epoch = 0
+        self._info_rows: Optional[List[int]] = None
+        # node-value rows (None: more values than slots)
+        self._rows: "OrderedDict[Tuple, Optional[NodeValues]]" = OrderedDict()
+        self._incomplete: Dict[str, bool] = {}
+        # the census
+        self._cursor: Optional[int] = None  # None: recount every node
+        self._counted = False  # the census is this attach's snapshot's
+        self._row_of: Dict[str, int] = {}
+        self._classes: Dict[str, Dict[FrozenSet, _PodClass]] = {}
+        self._on_row: Dict[int, List[_PodClass]] = {}
+        self._matches: "OrderedDict[Tuple, Dict[_PodClass, bool]]" = (
+            OrderedDict()
+        )
+        self._template_keys: Dict[Tuple, Tuple] = {}
+        # the batch's templates, by the identity of its pod list
+        self._tpl_pods: Optional[Sequence[Pod]] = None
+        self._tpl: Tuple[np.ndarray, List[Pod]] = (
+            np.zeros(0, dtype=np.int64), [],
+        )
+        self.nodes = 0
+        self.nodes_recounted = 0
+        self.node_rows = 0
+        self.node_rows_reused = 0
+        self.templates = 0
+
+    def tally(self) -> Tuple[int, ...]:
+        return tuple(getattr(self, name) for name in TALLY)
+
+    # -- validity -------------------------------------------------------------
+
+    def _bind(self, snapshot: Snapshot, nt: NodeTensor) -> None:
+        moved = snapshot is not self._snapshot or nt.names is not self._names
+        epoch = snapshot.node_spec_epoch
+        if moved or epoch != self._epoch:
+            self._epoch = epoch
+            self._rows.clear()
+            self._incomplete.clear()
+        if moved:
+            self._snapshot = snapshot
+            self._names = nt.names
+            self._cursor = None
+        self._nt = nt
+        self._info_rows = None
+        self._counted = False
+
+    @property
+    def infos(self) -> List[NodeInfo]:
+        return self._snapshot.node_info_list
+
+    def info_rows(self) -> List[int]:
+        """Tensor row per entry of ``infos``."""
+        rows = self._info_rows
+        if rows is None:
+            rows = self._info_rows = self._nt.rows_for(self.infos).tolist()
+        return rows
+
+    # -- node-value rows ------------------------------------------------------
+
+    def node_values(
+        self, key, scope: Tuple = UNSCOPED, rep: Optional[Pod] = None
+    ) -> Optional[NodeValues]:
+        """The row of ``key`` over the nodes ``scope`` admits (``rep`` is
+        a pod with that eligibility signature), or None when the key
+        has more values than the count tensors have slots."""
+        self.node_rows += 1
+        sig = (key, scope)
+        rows = self._rows
+        if sig in rows:
+            rows.move_to_end(sig)
+            self.node_rows_reused += 1
+            return rows[sig]
+        built = self._build_values(key, rep if scope != UNSCOPED else None)
+        rows[sig] = built
+        if len(rows) > ROWS_KEPT:
+            rows.popitem(last=False)
+        return built
+
+    def _build_values(self, key, rep: Optional[Pod]) -> Optional[NodeValues]:
+        n_cap = self._nt.capacity
+        v_cap = value_capacity(n_cap)
+        at: List[int] = []
+        vids: List[int] = []
+        ids: Dict[str, int] = {}
+        if key is ROW_INDEX:
+            at = vids = [
+                j for j, ni in zip(self.info_rows(), self.infos)
+                if ni.node is not None and j < n_cap
+            ]
+        else:
+            for j, ni in zip(self.info_rows(), self.infos):
+                node = ni.node
+                if node is None:
+                    continue
+                if (
+                    rep is not None
+                    and not pod_matches_node_selector_and_affinity(rep, ni)
+                ):
+                    continue  # out of the owner pods' scope: -1
+                val = node.metadata.labels.get(key)
+                if val is None:
+                    continue  # the node lacks the key: excluded
+                vid = ids.get(val)
+                if vid is None:
+                    if len(ids) >= v_cap:
+                        return None
+                    vid = ids[val] = len(ids)
+                at.append(j)
+                vids.append(vid)
+        values = np.full(n_cap, -1, dtype=np.int32)
+        values[at] = vids
+        valid = np.zeros(v_cap, dtype=bool)
+        valid[: len(ids)] = True
+        values.flags.writeable = False
+        valid.flags.writeable = False
+        return NodeValues(values, valid)
+
+    def key_incomplete(self, key: str) -> bool:
+        """Whether some node lacks the label ``key``."""
+        v = self._incomplete.get(key)
+        if v is None:
+            v = self._incomplete[key] = any(
+                ni.node is not None and key not in ni.node.metadata.labels
+                for ni in self.infos
+            )
+        return v
+
+    # -- the census -----------------------------------------------------------
+
+    def _advance(self) -> None:
+        """Bring the census up to the snapshot: recount the nodes its
+        change log names since the last read, or every node where that
+        cannot be told."""
+        self._counted = True
+        snapshot = self._snapshot
+        names = None
+        if self._cursor is None:
+            cursor = snapshot.change_cursor()
+        else:
+            names, moved, cursor = snapshot.changes_since(self._cursor)
+            if moved or (names is not None and len(names) >= len(self._row_of)):
+                names = None
+        self._cursor = cursor
+        pairs: Optional[List[Tuple[int, NodeInfo]]] = None
+        if names is not None:
+            pairs = []
+            row_of = self._row_of
+            info_map = snapshot.node_info_map
+            for name in names:
+                ni = info_map.get(name)
+                j = row_of.get(name)
+                # pods the cache holds for a name without a Node object
+                # are on no row and in no count
+                listed = ni is not None and ni.node is not None
+                if listed != (j is not None):
+                    pairs = None  # the log tells a membership move: not trusted
+                    break
+                if listed:
+                    pairs.append((j, ni))
+        if pairs is None:
+            infos = self.infos
+            rows = self.info_rows()
+            self._row_of = {ni.node_name: j for j, ni in zip(rows, infos)}
+            self._classes = {}
+            self._on_row = {}
+            pairs = list(zip(rows, infos))
+        self._recount(pairs)
+
+    def _recount(self, pairs: List[Tuple[int, NodeInfo]]) -> None:
+        """Count the pods of each ``(row, NodeInfo)`` anew."""
+        self.nodes_recounted += len(pairs)
+        on_row = self._on_row
+        classes = self._classes
+        emptied: List[_PodClass] = []
+        for j, ni in pairs:
+            for cls in on_row.pop(j, ()):
+                del cls.pods[j]
+                cls.terminating.pop(j, None)
+                if not cls.pods:
+                    emptied.append(cls)
+            if not ni.pods:
+                continue
+            here: List[_PodClass] = []
+            for p in ni.pods:
+                meta = p.metadata
+                by_labels = classes.get(meta.namespace)
+                if by_labels is None:
+                    by_labels = classes[meta.namespace] = {}
+                key = frozenset(meta.labels.items())
+                cls = by_labels.get(key)
+                if cls is None:
+                    cls = by_labels[key] = _PodClass(
+                        meta.namespace, key, meta.labels
+                    )
+                n = cls.pods.get(j)
+                if n is None:
+                    cls.pods[j] = 1
+                    here.append(cls)
+                else:
+                    cls.pods[j] = n + 1
+                if meta.deletion_timestamp is not None:
+                    cls.terminating[j] = cls.terminating.get(j, 0) + 1
+            on_row[j] = here
+        for cls in emptied:  # a class no node holds any more leaves
+            by_labels = classes[cls.namespace]
+            if not cls.pods and by_labels.get(cls.key) is cls:
+                del by_labels[cls.key]
+
+    def matching(self, terms: Sequence[Term]) -> List[_PodClass]:
+        """The resident classes that match every one of ``terms``
+        (PodMatchesTermsNamespaceAndSelector, topologies.go:40), each
+        (selector, class) pair matched once while both live."""
+        if not self._counted:
+            self._advance()
+        first = terms[0]
+        out: List[_PodClass] = []
+        for namespace in dict.fromkeys(first[0]):
+            by_labels = self._classes.get(namespace)
+            if not by_labels:
+                continue
+            for cls in by_labels.values():
+                if all(self._matches_term(cls, t) for t in terms):
+                    out.append(cls)
+        return out
+
+    def _matches_term(self, cls: _PodClass, term: Term) -> bool:
+        namespaces, selector, sel_sig = term
+        if cls.namespace not in namespaces:
+            return False
+        memos = self._matches
+        memo = memos.get(sel_sig)
+        if memo is None:
+            memo = memos[sel_sig] = {}
+            if len(memos) > SELECTORS_KEPT:
+                memos.popitem(last=False)
+        hit = memo.get(cls)
+        if hit is None:
+            hit = memo[cls] = labels_match_selector(cls.labels, selector)
+        return hit
+
+    def counts(
+        self, classes: List[_PodClass], values: np.ndarray, live_only: bool
+    ) -> np.ndarray:
+        """Pods of ``classes`` by the value ``values`` gives their node:
+        a count row ``[v_cap]`` int32. ``live_only`` leaves terminating
+        pods out."""
+        v_cap = value_capacity(values.shape[0])
+        out = np.zeros(v_cap, dtype=np.int64)
+        for cls in classes:
+            pods = cls.pods
+            rows = np.fromiter(pods.keys(), dtype=np.int64, count=len(pods))
+            if live_only and cls.terminating:
+                gone = cls.terminating
+                n = np.fromiter(
+                    (c - gone.get(j, 0) for j, c in pods.items()),
+                    dtype=np.int64, count=len(pods),
+                )
+            else:
+                n = np.fromiter(
+                    pods.values(), dtype=np.int64, count=len(pods)
+                )
+            vals = values[rows]
+            on = vals >= 0
+            out += np.bincount(
+                vals[on], weights=n[on], minlength=v_cap
+            ).astype(np.int64)
+        return out.astype(np.int32)
+
+    # -- pod templates --------------------------------------------------------
+
+    def batch_templates(
+        self, pods: Sequence[Pod]
+    ) -> Tuple[np.ndarray, List[Pod]]:
+        """``(index [B], first pods)``: the batch's distinct templates
+        in first-pod order, ``index[i]`` the template of pod i. An
+        object that keeps its facts also keeps a pod's key on the pod,
+        as ``host_masks._constraint_signature`` does, and hands a
+        dispatch's second packer what its first one found."""
+        if pods is self._tpl_pods and len(pods) == len(self._tpl[0]):
+            return self._tpl
+        seen: Dict[Tuple, int] = {}
+        firsts: List[Pod] = []
+        index: List[int] = []
+        keeps = self.keeps
+        known = self._template_keys
+        if len(known) > TEMPLATES_KEPT:
+            known.clear()  # the pods hold theirs
+        for pod in pods:
+            key = pod.__dict__.get("_family_memo") if keeps else None
+            if key is None:
+                key = template_key(pod)
+                if keeps:
+                    # one key object a template, not one a pod
+                    key = known.setdefault(key, key)
+                    pod.__dict__["_family_memo"] = key
+            t = seen.get(key)
+            if t is None:
+                t = seen[key] = len(firsts)
+                firsts.append(pod)
+            index.append(t)
+        self._tpl_pods = pods
+        self._tpl = (np.array(index, dtype=np.int64), firsts)
+        self.templates += len(firsts)
+        self.nodes += len(self.infos)
+        return self._tpl
+
+
+def attach(
+    facts: Optional[FamilyFacts], snapshot: Snapshot, nt: NodeTensor
+) -> FamilyFacts:
+    """``facts`` made valid for this snapshot and tensor, or, where
+    nothing may be kept (no ``facts``; a snapshot no cache feeds), an
+    object for this batch alone."""
+    if facts is None or not snapshot.node_spec_epoch:
+        facts = FamilyFacts()
+        facts.keeps = False
+    facts._bind(snapshot, nt)
+    return facts

@@ -34,24 +34,19 @@ import numpy as np
 from kubernetes_tpu.api.selectors import labels_match_selector
 from kubernetes_tpu.api.types import LabelSelector, Pod
 from kubernetes_tpu.cache.snapshot import Snapshot
-from kubernetes_tpu.plugins.podtopologyspread import DO_NOT_SCHEDULE
+from kubernetes_tpu.ops import family_facts
+from kubernetes_tpu.ops.family_facts import (
+    FamilyFacts,
+    eligibility_sig as _eligibility_sig,
+    hard_spread_constraints,
+    selector_sig as _selector_sig,
+)
 from kubernetes_tpu.tensors.node_tensor import NodeTensor, value_capacity
 
 MAX_GROUPS = 16  # batches needing more fall back to the host path
 MAX_VALUES = 128  # floor; tensors.node_tensor.value_capacity grows it
 MAX_CONSTRAINTS_PER_POD = 4
 BIG = np.int32(1 << 20)  # "absent value" sentinel for the min-reduce
-
-
-def _selector_sig(sel: Optional[LabelSelector]) -> Tuple:
-    if sel is None:
-        return ("<nil>",)
-    return (
-        tuple(sorted(sel.match_labels.items())),
-        tuple(
-            (r.key, r.operator, tuple(r.values)) for r in sel.match_expressions
-        ),
-    )
 
 
 @dataclass
@@ -85,90 +80,51 @@ class SpreadBatch:
         return self.group_counts.shape[0]
 
 
-def _eligibility_sig(pod: Pod) -> Tuple:
-    """Signature of the pod's node-affinity/selector scoping: spread
-    pair counting runs only over nodes the pod itself could land on
-    (filtering.go:245 PodMatchesNodeSelectorAndAffinityTerms), so pods
-    with different scoping cannot share a group."""
-    spec = pod.spec
-    sel = tuple(sorted(spec.node_selector.items()))
-    aff: Tuple = ()
-    if spec.affinity is not None and spec.affinity.node_affinity is not None:
-        na = spec.affinity.node_affinity
-        if na.required_during_scheduling is not None:
-            aff = tuple(
-                (
-                    tuple(
-                        (r.key, r.operator, tuple(r.values))
-                        for r in term.match_expressions
-                    ),
-                    tuple(
-                        (r.key, r.operator, tuple(r.values))
-                        for r in term.match_fields
-                    ),
-                )
-                for term in na.required_during_scheduling.node_selector_terms
-            )
-    return (sel, aff)
-
-
 def pack_spread_batch(
-    pods: List[Pod], snapshot: Snapshot, nt: NodeTensor
+    pods: List[Pod],
+    snapshot: Snapshot,
+    nt: NodeTensor,
+    facts: Optional[FamilyFacts] = None,
 ) -> Optional[SpreadBatch]:
     """Returns None when the batch exceeds the device envelope (too many
-    groups/values/constraints) -- caller falls back to the host path."""
-    b = len(pods)
+    groups/values/constraints) -- caller falls back to the host path.
+    The pod rows are built once a pod template, the node rows and the
+    initial counts come from ``facts`` (ops/family_facts.py), which
+    keeps them between batches where it may."""
+    facts = family_facts.attach(facts, snapshot, nt)
+    index, firsts = facts.batch_templates(pods)
     groups: Dict[Tuple, int] = {}
-    # ns, key, sel, representative pod (its node-affinity scopes the group)
-    specs: List[Tuple[str, str, Optional[LabelSelector], Pod]] = []
+    # ns, key, sel, its signature, the scoping, a pod that has it
+    specs: List[Tuple[str, str, Optional[LabelSelector], Tuple, Tuple, Pod]]
+    specs = []
 
-    pod_groups = np.full((b, MAX_CONSTRAINTS_PER_POD), -1, dtype=np.int32)
-    pod_max_skew = np.zeros((b, MAX_CONSTRAINTS_PER_POD), dtype=np.int32)
-    pod_self = np.zeros((b, MAX_CONSTRAINTS_PER_POD), dtype=np.int32)
+    t = len(firsts)
+    tpl_groups = np.full((t, MAX_CONSTRAINTS_PER_POD), -1, dtype=np.int32)
+    tpl_max_skew = np.zeros((t, MAX_CONSTRAINTS_PER_POD), dtype=np.int32)
+    tpl_self = np.zeros((t, MAX_CONSTRAINTS_PER_POD), dtype=np.int32)
 
-    infos = snapshot.list_node_infos()
-    node_rows = nt.rows_for(infos).tolist()
-    # Per-key "some node lacks it" cache: reference pair counting
-    # (common.go nodeLabelsMatchSpreadConstraints) excludes a node from
-    # ALL of a pod's constraints when it lacks ANY constraint key. Shared
-    # group counts can't express that per-pod eligibility, so a pod whose
-    # constraints span 2+ keys with incomplete node coverage falls back
-    # to the host path (ADVICE round-1, medium).
-    _key_incomplete: Dict[str, bool] = {}
-
-    def key_incomplete(key: str) -> bool:
-        v = _key_incomplete.get(key)
-        if v is None:
-            v = any(
-                ni.node is not None and key not in ni.node.metadata.labels
-                for ni in infos
-            )
-            _key_incomplete[key] = v
-        return v
-
-    for i, pod in enumerate(pods):
-        hard = [
-            c
-            for c in pod.spec.topology_spread_constraints
-            if c.when_unsatisfiable == DO_NOT_SCHEDULE
-        ]
+    for ti, pod in enumerate(firsts):
+        hard = hard_spread_constraints(pod)
         if len(hard) > MAX_CONSTRAINTS_PER_POD:
             return None
+        # Reference pair counting (common.go
+        # nodeLabelsMatchSpreadConstraints) excludes a node from ALL of
+        # a pod's constraints when it lacks ANY constraint key. Shared
+        # group counts can't express that per-pod eligibility, so a pod
+        # whose constraints span 2+ keys with incomplete node coverage
+        # falls back to the host path (ADVICE round-1, medium).
         keys = {c.topology_key for c in hard}
-        if len(keys) > 1 and any(key_incomplete(k) for k in keys):
+        if len(keys) > 1 and any(facts.key_incomplete(k) for k in keys):
             return None
+        # pair counting is scoped to nodes passing the pod's own
+        # nodeSelector/affinity (filtering.go:245): the scoping is
+        # part of the group identity, and the group's node_value
+        # row is -1 on out-of-scope nodes (no counts, no bumps,
+        # infeasible there -- matching the static mask)
+        scope = _eligibility_sig(pod) if hard else None
         for ci, c in enumerate(hard):
-            # pair counting is scoped to nodes passing the pod's own
-            # nodeSelector/affinity (filtering.go:245): the scoping is
-            # part of the group identity, and the group's node_value
-            # row is -1 on out-of-scope nodes (no counts, no bumps,
-            # infeasible there -- matching the static mask)
-            sig = (
-                pod.metadata.namespace,
-                c.topology_key,
-                _selector_sig(c.label_selector),
-                _eligibility_sig(pod),
-            )
+            sel_sig = _selector_sig(c.label_selector)
+            sig = (pod.metadata.namespace, c.topology_key, sel_sig, scope)
             g = groups.get(sig)
             if g is None:
                 if len(groups) >= MAX_GROUPS:
@@ -178,26 +134,25 @@ def pack_spread_batch(
                 specs.append(
                     (
                         pod.metadata.namespace, c.topology_key,
-                        c.label_selector, pod,
+                        c.label_selector, sel_sig, scope, pod,
                     )
                 )
-            pod_groups[i, ci] = g
-            pod_max_skew[i, ci] = c.max_skew
-            pod_self[i, ci] = int(
+            tpl_groups[ti, ci] = g
+            tpl_max_skew[ti, ci] = c.max_skew
+            tpl_self[ti, ci] = int(
                 labels_match_selector(pod.metadata.labels, c.label_selector)
             )
 
-    num_groups = len(groups)
-    if num_groups == 0:
+    if not groups:
         return None
 
-    pod_match = np.zeros((b, MAX_GROUPS), dtype=np.int32)
-    for i, pod in enumerate(pods):
-        for g, (ns, _key, sel, _rep) in enumerate(specs):
+    tpl_match = np.zeros((t, MAX_GROUPS), dtype=np.int32)
+    for ti, pod in enumerate(firsts):
+        for g, (ns, _key, sel, _sig, _scope, _rep) in enumerate(specs):
             if pod.metadata.namespace == ns and labels_match_selector(
                 pod.metadata.labels, sel
             ):
-                pod_match[i, g] = 1
+                tpl_match[ti, g] = 1
 
     n_cap = nt.capacity
     v_cap = value_capacity(n_cap)
@@ -205,55 +160,28 @@ def pack_spread_batch(
     value_valid = np.zeros((MAX_GROUPS, v_cap), dtype=bool)
     node_value = np.full((MAX_GROUPS, n_cap), -1, dtype=np.int32)
 
-    from kubernetes_tpu.plugins.nodeaffinity import (
-        pod_matches_node_selector_and_affinity,
-    )
-
-    for g, (ns, key, sel, rep) in enumerate(specs):
-        scoped = bool(_eligibility_sig(rep) != ((), ()))
-        value_ids: Dict[str, int] = {}
-        for j, ni in zip(node_rows, infos):
-            node = ni.node
-            if node is None:
-                continue
-            if scoped and not pod_matches_node_selector_and_affinity(
-                rep, ni
-            ):
-                continue  # out of the owner pod's scope: -1 everywhere
-            val = node.metadata.labels.get(key)
-            if val is None:
-                continue  # node lacks the key: hard-excluded for this group
-            vid = value_ids.get(val)
-            if vid is None:
-                if len(value_ids) >= v_cap:
-                    return None
-                vid = len(value_ids)
-                value_ids[val] = vid
-            node_value[g, j] = vid
-            value_valid[g, vid] = True
-            # initial counts: existing same-namespace matching pods
-            # (filtering.go:255; terminating pods skipped)
-            count = 0
-            for p in ni.pods:
-                if (
-                    p.metadata.deletion_timestamp is None
-                    and p.metadata.namespace == ns
-                    and labels_match_selector(p.metadata.labels, sel)
-                ):
-                    count += 1
-            group_counts[g, vid] += count
+    for g, (ns, key, sel, sel_sig, scope, rep) in enumerate(specs):
+        row = facts.node_values(key, scope, rep)
+        if row is None:
+            return None
+        node_value[g] = row.values
+        value_valid[g] = row.valid
+        # initial counts: existing same-namespace matching pods
+        # (filtering.go:255; terminating pods skipped)
+        group_counts[g] = facts.counts(
+            facts.matching([((ns,), sel, sel_sig)]), row.values,
+            live_only=True,
+        )
 
     return SpreadBatch(
         group_counts=group_counts,
         value_valid=value_valid,
         node_value=node_value,
-        pod_groups=pod_groups,
-        pod_max_skew=pod_max_skew,
-        pod_self=pod_self,
-        pod_match=pod_match,
+        pod_groups=tpl_groups[index],
+        pod_max_skew=tpl_max_skew[index],
+        pod_self=tpl_self[index],
+        pod_match=tpl_match[index],
     )
-
-
 
 
 def noop_spread_tensors(padded: int, n_cap: int):

@@ -69,6 +69,7 @@ from kubernetes_tpu.ops.affinity import (
     pack_affinity_batch,
     pad_affinity_tensors,
 )
+from kubernetes_tpu.ops import family_facts
 from kubernetes_tpu.ops.host_masks import (
     MaskRowCache,
     mask_rows_upload,
@@ -531,6 +532,7 @@ class BatchScheduler(Scheduler):
         self.tensor_cache = tensor_cache or NodeTensorCache()
         # static mask rows kept from batch to batch (ops/host_masks.py)
         self.mask_row_cache = MaskRowCache()
+        self.family_facts = family_facts.FamilyFacts()
         self.batch_window = batch_window
         # SLO-adaptive batching (streaming/autobatch.py): when a
         # controller is attached it rewrites batch_window AND these two
@@ -2262,7 +2264,9 @@ class BatchScheduler(Scheduler):
             routed = None
             with flightrecorder.stage(
                 "pack.families", totals=totals, batch=batch_id
-            ):
+            ) as families:
+                facts = self.family_facts
+                tally0 = facts.tally()
                 try:
                     score_batch = pack_score_batch(
                         ordered_pods, snapshot, nt,
@@ -2278,12 +2282,14 @@ class BatchScheduler(Scheduler):
                     # cache, which must include every in-flight placement
                     routed = ("score_envelope", True)
                 if routed is None and has_hard_spread:
-                    spread = pack_spread_batch(ordered_pods, snapshot, nt)
+                    spread = pack_spread_batch(
+                        ordered_pods, snapshot, nt, facts
+                    )
                     if spread is None:
                         routed = ("spread_envelope", False)
                 if routed is None and has_affinity:
                     affinity = pack_affinity_batch(
-                        ordered_pods, snapshot, nt
+                        ordered_pods, snapshot, nt, facts
                     )
                     if affinity is None and has_affinity_terms:
                         # real affinity/exist rows expected but the
@@ -2296,11 +2302,17 @@ class BatchScheduler(Scheduler):
                         # existing-pod conflicts are already in the
                         # static mask
                         affinity = add_host_port_rows(
-                            ordered_pods, snapshot, nt, affinity
+                            ordered_pods, snapshot, nt, affinity, facts
                         )
                         if affinity is None:
                             # a port-only batch may not have drained above
                             routed = ("port_envelope", True)
+                families.set_metadata(**{
+                    name: now - before
+                    for name, now, before in zip(
+                        family_facts.TALLY, facts.tally(), tally0
+                    )
+                })
             if routed is not None:
                 # envelope exceeded: the host path keeps full correctness
                 reason, drain = routed

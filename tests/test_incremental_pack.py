@@ -26,7 +26,25 @@ from kubernetes_tpu.cache.node_info import CSI_ATTACH_PREFIX, pod_host_ports
 from kubernetes_tpu.cache.snapshot import Snapshot, new_snapshot
 from kubernetes_tpu.client.client import Client
 from kubernetes_tpu.client.informer import InformerFactory
+from kubernetes_tpu.api.selectors import labels_match_selector
 from kubernetes_tpu.ops import host_masks
+from kubernetes_tpu.ops.affinity import (
+    MAX_AFF_ROWS,
+    MAX_ANTI_ROWS,
+    MAX_EXIST_ROWS,
+    MAX_KEYS,
+    MAX_TERMS_PER_POD,
+    AffinityBatch,
+    _Matcher,
+    _required_affinity,
+    _required_anti_affinity,
+    _Row,
+    _term_namespaces,
+    _term_sig,
+    add_host_port_rows,
+    noop_affinity_tensors,
+    pack_affinity_batch,
+)
 from kubernetes_tpu.ops.assignment import ConstPiece
 from kubernetes_tpu.ops.host_masks import (
     MaskRowCache,
@@ -35,13 +53,22 @@ from kubernetes_tpu.ops.host_masks import (
     _tolerates_node_taints,
     static_mask_compact,
 )
+from kubernetes_tpu.ops.family_facts import FamilyFacts
 from kubernetes_tpu.ops.scoring import pack_score_batch
+from kubernetes_tpu.ops.topology import (
+    MAX_CONSTRAINTS_PER_POD,
+    MAX_GROUPS,
+    SpreadBatch,
+    _selector_sig,
+    pack_spread_batch,
+)
 from kubernetes_tpu.plugins.nodeaffinity import (
     pod_matches_node_selector_and_affinity,
 )
 from kubernetes_tpu.plugins.nodepreferavoidpods import (
     ANNOTATION_KEY as AVOID_ANNOTATION,
 )
+from kubernetes_tpu.plugins.podtopologyspread import DO_NOT_SCHEDULE
 from kubernetes_tpu.scheduler import batch as batch_mod
 from kubernetes_tpu.scheduler.scheduler import new_scheduler
 from kubernetes_tpu.tensors import NodeTensorCache
@@ -52,6 +79,7 @@ from kubernetes_tpu.tensors.node_tensor import (
     PODS,
     _kib_ceil,
     _kib_floor,
+    value_capacity,
 )
 from kubernetes_tpu.testing import make_node, make_pod
 
@@ -181,6 +209,516 @@ def score_pack_twin(pods, snapshot, nt, informers, weights, **kwargs):
     kwargs.pop("admissions", None)
     snapshot.score_facts = None
     return pack_score_batch(pods, snapshot, nt, informers, weights, **kwargs)
+
+
+def eligibility_sig_twin(pod):
+    """Signature of the pod's node-affinity/selector scoping: spread
+    pair counting runs only over nodes the pod itself could land on
+    (filtering.go:245 PodMatchesNodeSelectorAndAffinityTerms), so pods
+    with different scoping cannot share a group."""
+    spec = pod.spec
+    sel = tuple(sorted(spec.node_selector.items()))
+    aff = ()
+    if spec.affinity is not None and spec.affinity.node_affinity is not None:
+        na = spec.affinity.node_affinity
+        if na.required_during_scheduling is not None:
+            aff = tuple(
+                (
+                    tuple(
+                        (r.key, r.operator, tuple(r.values))
+                        for r in term.match_expressions
+                    ),
+                    tuple(
+                        (r.key, r.operator, tuple(r.values))
+                        for r in term.match_fields
+                    ),
+                )
+                for term in na.required_during_scheduling.node_selector_terms
+            )
+    return (sel, aff)
+
+
+
+def pack_spread_twin(pods, snapshot, nt, facts=None):
+    """``ops.topology.pack_spread_batch`` as it was: for every group a
+    walk over every node and every pod of every node, and the pod rows
+    built pod by pod."""
+    b = len(pods)
+    groups = {}
+    # ns, key, sel, representative pod (its node-affinity scopes the group)
+    specs = []
+
+    pod_groups = np.full((b, MAX_CONSTRAINTS_PER_POD), -1, dtype=np.int32)
+    pod_max_skew = np.zeros((b, MAX_CONSTRAINTS_PER_POD), dtype=np.int32)
+    pod_self = np.zeros((b, MAX_CONSTRAINTS_PER_POD), dtype=np.int32)
+
+    infos = snapshot.list_node_infos()
+    node_rows = nt.rows_for(infos).tolist()
+    # Per-key "some node lacks it" cache: reference pair counting
+    # (common.go nodeLabelsMatchSpreadConstraints) excludes a node from
+    # ALL of a pod's constraints when it lacks ANY constraint key. Shared
+    # group counts can't express that per-pod eligibility, so a pod whose
+    # constraints span 2+ keys with incomplete node coverage falls back
+    # to the host path (ADVICE round-1, medium).
+    _key_incomplete = {}
+
+    def key_incomplete(key):
+        v = _key_incomplete.get(key)
+        if v is None:
+            v = any(
+                ni.node is not None and key not in ni.node.metadata.labels
+                for ni in infos
+            )
+            _key_incomplete[key] = v
+        return v
+
+    for i, pod in enumerate(pods):
+        hard = [
+            c
+            for c in pod.spec.topology_spread_constraints
+            if c.when_unsatisfiable == DO_NOT_SCHEDULE
+        ]
+        if len(hard) > MAX_CONSTRAINTS_PER_POD:
+            return None
+        keys = {c.topology_key for c in hard}
+        if len(keys) > 1 and any(key_incomplete(k) for k in keys):
+            return None
+        for ci, c in enumerate(hard):
+            # pair counting is scoped to nodes passing the pod's own
+            # nodeSelector/affinity (filtering.go:245): the scoping is
+            # part of the group identity, and the group's node_value
+            # row is -1 on out-of-scope nodes (no counts, no bumps,
+            # infeasible there -- matching the static mask)
+            sig = (
+                pod.metadata.namespace,
+                c.topology_key,
+                _selector_sig(c.label_selector),
+                eligibility_sig_twin(pod),
+            )
+            g = groups.get(sig)
+            if g is None:
+                if len(groups) >= MAX_GROUPS:
+                    return None
+                g = len(groups)
+                groups[sig] = g
+                specs.append(
+                    (
+                        pod.metadata.namespace, c.topology_key,
+                        c.label_selector, pod,
+                    )
+                )
+            pod_groups[i, ci] = g
+            pod_max_skew[i, ci] = c.max_skew
+            pod_self[i, ci] = int(
+                labels_match_selector(pod.metadata.labels, c.label_selector)
+            )
+
+    num_groups = len(groups)
+    if num_groups == 0:
+        return None
+
+    pod_match = np.zeros((b, MAX_GROUPS), dtype=np.int32)
+    for i, pod in enumerate(pods):
+        for g, (ns, _key, sel, _rep) in enumerate(specs):
+            if pod.metadata.namespace == ns and labels_match_selector(
+                pod.metadata.labels, sel
+            ):
+                pod_match[i, g] = 1
+
+    n_cap = nt.capacity
+    v_cap = value_capacity(n_cap)
+    group_counts = np.zeros((MAX_GROUPS, v_cap), dtype=np.int32)
+    value_valid = np.zeros((MAX_GROUPS, v_cap), dtype=bool)
+    node_value = np.full((MAX_GROUPS, n_cap), -1, dtype=np.int32)
+
+    for g, (ns, key, sel, rep) in enumerate(specs):
+        scoped = bool(eligibility_sig_twin(rep) != ((), ()))
+        value_ids = {}
+        for j, ni in zip(node_rows, infos):
+            node = ni.node
+            if node is None:
+                continue
+            if scoped and not pod_matches_node_selector_and_affinity(
+                rep, ni
+            ):
+                continue  # out of the owner pod's scope: -1 everywhere
+            val = node.metadata.labels.get(key)
+            if val is None:
+                continue  # node lacks the key: hard-excluded for this group
+            vid = value_ids.get(val)
+            if vid is None:
+                if len(value_ids) >= v_cap:
+                    return None
+                vid = len(value_ids)
+                value_ids[val] = vid
+            node_value[g, j] = vid
+            value_valid[g, vid] = True
+            # initial counts: existing same-namespace matching pods
+            # (filtering.go:255; terminating pods skipped)
+            count = 0
+            for p in ni.pods:
+                if (
+                    p.metadata.deletion_timestamp is None
+                    and p.metadata.namespace == ns
+                    and labels_match_selector(p.metadata.labels, sel)
+                ):
+                    count += 1
+            group_counts[g, vid] += count
+
+    return SpreadBatch(
+        group_counts=group_counts,
+        value_valid=value_valid,
+        node_value=node_value,
+        pod_groups=pod_groups,
+        pod_max_skew=pod_max_skew,
+        pod_self=pod_self,
+        pod_match=pod_match,
+    )
+
+
+
+
+
+def pack_affinity_twin(pods, snapshot, nt, facts=None):
+    """``ops.affinity.pack_affinity_batch`` as it was: a node walk per
+    key, every pod of every node against every row, and the pod rows
+    built pod by pod."""
+    b = len(pods)
+    infos = snapshot.list_node_infos()
+    node_rows = nt.rows_for(infos).tolist()
+    n_cap = nt.capacity
+
+    v_cap = value_capacity(n_cap)
+    keys = {}
+    value_ids = []
+
+    def key_idx(key):
+        idx = keys.get(key)
+        if idx is None:
+            if len(keys) >= MAX_KEYS:
+                return None
+            idx = len(keys)
+            keys[key] = idx
+            value_ids.append({})
+        return idx
+
+    matcher = _Matcher()
+
+    # ---- collect rows -----------------------------------------------------
+    aff_rows = []
+    aff_groups = {}  # sig -> (gid, rows)
+    anti_rows = []
+    anti_row_ids = {}
+    exist_rows = []
+    exist_row_ids = {}
+
+    pod_aff_rows = np.full((b, MAX_TERMS_PER_POD), -1, dtype=np.int32)
+    pod_anti_rows = np.full((b, MAX_TERMS_PER_POD), -1, dtype=np.int32)
+    pod_self_match = np.zeros(b, dtype=bool)
+    pod_bump_exist = np.zeros((b, MAX_EXIST_ROWS), dtype=np.int32)
+
+    def add_exist_row(owner, term):
+        sig = _term_sig(owner, term)
+        r = exist_row_ids.get(sig)
+        if r is None:
+            if len(exist_rows) >= MAX_EXIST_ROWS:
+                return None
+            k = key_idx(term.topology_key)
+            if k is None:
+                return None
+            r = len(exist_rows)
+            exist_row_ids[sig] = r
+            exist_rows.append(
+                _Row(_term_namespaces(owner, term), term.label_selector,
+                     _selector_sig(term.label_selector), k)
+            )
+        return r
+
+    for i, pod in enumerate(pods):
+        aff_terms = _required_affinity(pod)
+        anti_terms = _required_anti_affinity(pod)
+        if (
+            len(aff_terms) > MAX_TERMS_PER_POD
+            or len(anti_terms) > MAX_TERMS_PER_POD
+        ):
+            return None
+        if aff_terms:
+            gsig = (
+                pod.metadata.namespace,
+                tuple(_term_sig(pod, t) for t in aff_terms),
+            )
+            entry = aff_groups.get(gsig)
+            if entry is None:
+                if len(aff_rows) + len(aff_terms) > MAX_AFF_ROWS:
+                    return None
+                rows = []
+                for t in aff_terms:
+                    k = key_idx(t.topology_key)
+                    if k is None:
+                        return None
+                    rows.append(len(aff_rows))
+                    aff_rows.append(
+                        _Row(_term_namespaces(pod, t), t.label_selector,
+                             _selector_sig(t.label_selector), k)
+                    )
+                entry = (len(aff_groups), rows)
+                aff_groups[gsig] = entry
+            _, rows = entry
+            pod_aff_rows[i, : len(rows)] = rows
+            pod_self_match[i] = all(
+                matcher.matches(
+                    pod, _term_namespaces(pod, t), t.label_selector,
+                    _selector_sig(t.label_selector),
+                )
+                for t in aff_terms
+            )
+        for t in anti_terms:
+            sig = _term_sig(pod, t)
+            r = anti_row_ids.get(sig)
+            if r is None:
+                if len(anti_rows) >= MAX_ANTI_ROWS:
+                    return None
+                k = key_idx(t.topology_key)
+                if k is None:
+                    return None
+                r = len(anti_rows)
+                anti_row_ids[sig] = r
+                anti_rows.append(
+                    _Row(_term_namespaces(pod, t), t.label_selector,
+                         _selector_sig(t.label_selector), k)
+                )
+            slot = list(pod_anti_rows[i]).index(-1)
+            pod_anti_rows[i, slot] = r
+            # the pod's own anti term also constrains LATER batch pods
+            # symmetrically once this pod places
+            er = add_exist_row(pod, t)
+            if er is None:
+                return None
+            pod_bump_exist[i, er] = 1
+
+    # existing pods' required anti-affinity -> exist rows
+    existing_with_anti = []
+    for ni in snapshot.have_pods_with_affinity_list:
+        if ni.node is None:
+            continue
+        for e in ni.pods_with_affinity:
+            for t in _required_anti_affinity(e):
+                r = add_exist_row(e, t)
+                if r is None:
+                    return None
+                existing_with_anti.append((e, t, r))
+
+    if not aff_rows and not anti_rows and not exist_rows:
+        return None  # nothing affinity-shaped in this batch
+
+    # ---- node value interning --------------------------------------------
+    node_value = np.full((MAX_KEYS, n_cap), -1, dtype=np.int32)
+    for key, k in keys.items():
+        ids = value_ids[k]
+        for j, ni in zip(node_rows, infos):
+            node = ni.node
+            if node is None:
+                continue
+            val = node.metadata.labels.get(key)
+            if val is None:
+                continue
+            vid = ids.get(val)
+            if vid is None:
+                if len(ids) >= v_cap:
+                    return None
+                vid = len(ids)
+                ids[val] = vid
+            node_value[k, j] = vid
+
+    # ---- count initialization from existing pods --------------------------
+    counts_aff = np.zeros((MAX_AFF_ROWS, v_cap), dtype=np.int32)
+    counts_anti = np.zeros((MAX_ANTI_ROWS, v_cap), dtype=np.int32)
+    counts_exist = np.zeros((MAX_EXIST_ROWS, v_cap), dtype=np.int32)
+
+    # exist rows: one bump per (existing pod, term) at the pod's node value
+    # (filtering.go:212; the batch pods' own rows start at zero)
+    node_row_of = {ni.node_name: j for j, ni in zip(node_rows, infos)}
+    for e, t, r in existing_with_anti:
+        j = node_row_of.get(e.spec.node_name)
+        if j is None:
+            continue
+        v = node_value[exist_rows[r].key_idx, j]
+        if v >= 0:
+            counts_exist[r, v] += 1
+
+    # affinity groups: existing pod bumps every row of a group iff it
+    # matches ALL the group's terms (filtering.go:135); anti rows bump on
+    # any single-term match (filtering.go:153)
+    if aff_rows or anti_rows:
+        group_rows = [rows for (_gid, rows) in aff_groups.values()]
+        for j, ni in zip(node_rows, infos):
+            if ni.node is None:
+                continue
+            for e in ni.pods:
+                for rows in group_rows:
+                    if all(
+                        matcher.matches(
+                            e, aff_rows[r].namespaces, aff_rows[r].selector,
+                            aff_rows[r].sel_sig,
+                        )
+                        for r in rows
+                    ):
+                        for r in rows:
+                            v = node_value[aff_rows[r].key_idx, j]
+                            if v >= 0:
+                                counts_aff[r, v] += 1
+                for r, row in enumerate(anti_rows):
+                    if matcher.matches(
+                        e, row.namespaces, row.selector, row.sel_sig
+                    ):
+                        v = node_value[row.key_idx, j]
+                        if v >= 0:
+                            counts_anti[r, v] += 1
+
+    # ---- per-pod match/bump matrices --------------------------------------
+    pod_bump_aff = np.zeros((b, MAX_AFF_ROWS), dtype=np.int32)
+    pod_bump_anti = np.zeros((b, MAX_ANTI_ROWS), dtype=np.int32)
+    pod_exist_match = np.zeros((b, MAX_EXIST_ROWS), dtype=bool)
+    group_row_lists = [rows for (_gid, rows) in aff_groups.values()]
+    for i, pod in enumerate(pods):
+        for rows in group_row_lists:
+            if all(
+                matcher.matches(
+                    pod, aff_rows[r].namespaces, aff_rows[r].selector,
+                    aff_rows[r].sel_sig,
+                )
+                for r in rows
+            ):
+                for r in rows:
+                    pod_bump_aff[i, r] = 1
+        for r, row in enumerate(anti_rows):
+            if matcher.matches(pod, row.namespaces, row.selector, row.sel_sig):
+                pod_bump_anti[i, r] = 1
+        for r, row in enumerate(exist_rows):
+            if matcher.matches(pod, row.namespaces, row.selector, row.sel_sig):
+                pod_exist_match[i, r] = True
+
+    row_key_aff = np.full(MAX_AFF_ROWS, -1, dtype=np.int32)
+    for r, row in enumerate(aff_rows):
+        row_key_aff[r] = row.key_idx
+    row_key_anti = np.full(MAX_ANTI_ROWS, -1, dtype=np.int32)
+    for r, row in enumerate(anti_rows):
+        row_key_anti[r] = row.key_idx
+    row_key_exist = np.full(MAX_EXIST_ROWS, -1, dtype=np.int32)
+    for r, row in enumerate(exist_rows):
+        row_key_exist[r] = row.key_idx
+
+    return AffinityBatch(
+        node_value=node_value,
+        counts_aff=counts_aff,
+        row_key_aff=row_key_aff,
+        pod_aff_rows=pod_aff_rows,
+        pod_self_match=pod_self_match,
+        pod_bump_aff=pod_bump_aff,
+        counts_anti=counts_anti,
+        row_key_anti=row_key_anti,
+        pod_anti_rows=pod_anti_rows,
+        pod_bump_anti=pod_bump_anti,
+        counts_exist=counts_exist,
+        row_key_exist=row_key_exist,
+        pod_exist_match=pod_exist_match,
+        pod_bump_exist=pod_bump_exist,
+    )
+
+
+
+def add_host_port_rows_twin(pods, snapshot, nt, af, facts=None):
+    """``ops.affinity.add_host_port_rows`` as it was: the synthetic
+    row written node by node."""
+    per_pod_ports = [pod_host_ports(p) for p in pods]
+    if not any(per_pod_ports):
+        return af
+    b = len(pods)
+    n_cap = nt.capacity
+    # node-index values must fit the value axis of the counts arrays
+    assert value_capacity(n_cap) >= n_cap
+    if af is None:
+        noop = noop_affinity_tensors(b, n_cap)
+        af = AffinityBatch(
+            node_value=noop[0].copy(), counts_aff=noop[1].copy(),
+            row_key_aff=noop[2].copy(), pod_aff_rows=noop[3].copy(),
+            pod_self_match=noop[4].copy(), pod_bump_aff=noop[5].copy(),
+            counts_anti=noop[6].copy(), row_key_anti=noop[7].copy(),
+            pod_anti_rows=noop[8].copy(), pod_bump_anti=noop[9].copy(),
+            counts_exist=noop[10].copy(), row_key_exist=noop[11].copy(),
+            pod_exist_match=noop[12].copy(),
+            pod_bump_exist=noop[13].copy(),
+        )
+    # synthetic key whose value is the node's own row index (unique per
+    # node; value_capacity(n_cap) >= n_cap guarantees room)
+    keys_used = {
+        int(k)
+        for arr in (af.row_key_aff, af.row_key_anti, af.row_key_exist)
+        for k in arr
+        if k >= 0
+    }
+    key_free = next(
+        (
+            k
+            for k in range(af.node_value.shape[0])
+            if k not in keys_used and (af.node_value[k] == -1).all()
+        ),
+        None,
+    )
+    if key_free is None:
+        return None  # no key slot left: host path
+    infos = snapshot.list_node_infos()
+    for j, ni in zip(nt.rows_for(infos).tolist(), infos):
+        if ni.node is not None and j < n_cap:
+            af.node_value[key_free, j] = j
+
+    # distinct port identities -> anti rows
+    row_of = {}
+    by_proto_port = {}
+
+    def row_for(ident):
+        r = row_of.get(ident)
+        if r is None:
+            used = int(np.count_nonzero(af.row_key_anti >= 0))
+            if used >= af.row_key_anti.shape[0]:
+                return None
+            r = used
+            af.row_key_anti[r] = key_free
+            row_of[ident] = r
+            by_proto_port.setdefault(ident[:2], []).append(ident)
+        return r
+
+    for i, ports in enumerate(per_pod_ports):
+        if not ports:
+            continue
+        for ip, proto, port in ports:
+            ident = (proto, port, ip or "0.0.0.0")
+            if row_for(ident) is None:
+                return None
+    for i, ports in enumerate(per_pod_ports):
+        if not ports:
+            continue
+        block_rows = set()
+        for ip, proto, port in ports:
+            ident = (proto, port, ip or "0.0.0.0")
+            r = row_of[ident]
+            af.pod_bump_anti[i, r] = 1
+            if ident[2] == "0.0.0.0":
+                # wildcard conflicts with every identity of (proto, port)
+                for other in by_proto_port.get(ident[:2], ()):
+                    block_rows.add(row_of[other])
+            else:
+                block_rows.add(r)
+                wild = (proto, port, "0.0.0.0")
+                if wild in row_of:
+                    block_rows.add(row_of[wild])
+        slots = list(af.pod_anti_rows[i])
+        free = [c for c, v in enumerate(slots) if v == -1]
+        if len(free) < len(block_rows):
+            return None  # not enough term slots: host path
+        for c, r in zip(free, sorted(block_rows)):
+            af.pod_anti_rows[i, c] = r
+    return af
 
 
 # -- helpers ------------------------------------------------------------------
@@ -871,6 +1409,13 @@ def _uploads(kind, seed, monkeypatch, old_path):
         )
         monkeypatch.setattr(batch_mod, "static_mask_compact", static_mask_twin)
         monkeypatch.setattr(batch_mod, "pack_score_batch", score_pack_twin)
+        monkeypatch.setattr(batch_mod, "pack_spread_batch", pack_spread_twin)
+        monkeypatch.setattr(
+            batch_mod, "pack_affinity_batch", pack_affinity_twin
+        )
+        monkeypatch.setattr(
+            batch_mod, "add_host_port_rows", add_host_port_rows_twin
+        )
     seen = []
     real_solve = batch_mod.solve_packed
 
@@ -939,3 +1484,494 @@ def test_solve_packed_receives_the_same_arrays(kind, monkeypatch):
                 assert a.dtype == b.dtype and np.array_equal(a, b), name
     if kind == "spread_anti":
         assert any(name.startswith("sp") for name, _ in new[0])
+
+
+# -- 6. the family packers (ISSUE 28) ------------------------------------------
+# Node-value rows kept per node-spec epoch, a pod census advanced by the
+# snapshot's change log, pod rows built once a template: every array the
+# packers hand to the solver is held to the walks they replaced (the
+# twins above), through a real cache, snapshot and tensor cache.
+
+HOST = "kubernetes.io/hostname"
+POOL = "pool"
+
+
+def assert_same_batch(got, want, what):
+    assert (got is None) == (want is None), what
+    if want is None:
+        return
+    assert type(got) is type(want)
+    for name in type(want).__dataclass_fields__:
+        a, b = getattr(got, name), getattr(want, name)
+        assert a.dtype == b.dtype and a.shape == b.shape, (what, name)
+        assert np.array_equal(a, b), (what, name)
+
+
+class Cluster:
+    """One cache, with a snapshot, a tensor cache and the packers' kept
+    facts following it; ``check`` packs a batch both ways."""
+
+    def __init__(self, nodes=18, seed=0):
+        self.rng = random.Random(seed)
+        self.cache = SchedulerCache()
+        self.nodes = {}
+        self.pods = {}
+        self.seq = 0
+        for i in range(nodes):
+            self.add_node(rack=i % 4 != 3, pool=i < 8)
+        self.snapshot = Snapshot()
+        self.tensors = NodeTensorCache()
+        self.facts = FamilyFacts()
+        self.checks = 0
+
+    def _name(self, prefix):
+        self.seq += 1
+        return f"{prefix}{self.seq}"
+
+    def _node_obj(self, name, zone, rack, pool):
+        w = _node(name, zone)
+        if rack:
+            w.label("rack", f"r{zone}")
+        if pool:
+            w.label(POOL, "ballast")
+        return w.obj()
+
+    def add_node(self, rack=True, pool=False):
+        name = self._name("n")
+        node = self._node_obj(name, self.rng.choice(ZONES), rack, pool)
+        self.nodes[name] = node
+        self.cache.add_node(node)
+        return name
+
+    def remove_node(self, name):
+        self.cache.remove_node(self.nodes.pop(name))
+        for uid in [u for u, p in self.pods.items()
+                    if p.spec.node_name == name]:
+            self.cache.remove_pod(self.pods.pop(uid))
+
+    def relabel(self, name, zone):
+        old = self.nodes[name]
+        new = self._node_obj(
+            name, zone, "rack" in old.metadata.labels,
+            POOL in old.metadata.labels,
+        )
+        self.nodes[name] = new
+        self.cache.update_node(old, new)
+
+    def status_write(self, name):
+        old = self.nodes[name]
+        new = self._node_obj(
+            name, old.metadata.labels["zone"],
+            "rack" in old.metadata.labels, POOL in old.metadata.labels,
+        )
+        new.status.conditions = [NodeCondition(type="Ready", status="True")]
+        self.nodes[name] = new
+        self.cache.update_node(old, new)
+
+    def resident(self, node, app, anti=False, namespace="default"):
+        name = self._name("p")
+        w = make_pod(name, namespace).uid(name).node(node).labels(app=app)
+        if anti:
+            w.pod_affinity(HOST, {"app": app}, anti=True)
+        return w.container(cpu="100m", memory="64Mi").obj()
+
+    def add_pod(self, node=None, app=None, **kw):
+        pod = self.resident(
+            node or self.rng.choice(sorted(self.nodes)),
+            app or self.rng.choice(APPS), **kw
+        )
+        self.pods[pod.metadata.uid] = pod
+        self.cache.add_pod(pod)
+        return pod
+
+    def remove_pod(self, pod):
+        self.cache.remove_pod(self.pods.pop(pod.metadata.uid))
+
+    def terminate(self, pod):
+        """The pod gains a deletion timestamp and stays on its node."""
+        new = self.resident(pod.spec.node_name, pod.metadata.labels["app"])
+        new.metadata.name = pod.metadata.name
+        new.metadata.uid = pod.metadata.uid
+        new.metadata.namespace = pod.metadata.namespace
+        new.spec.affinity = pod.spec.affinity
+        new.metadata.deletion_timestamp = 1.0
+        self.cache.update_pod(pod, new)
+        self.pods[new.metadata.uid] = new
+
+    def assume_wave(self, count, app, anti=False, on=None):
+        names = on or sorted(self.nodes)
+        pods = sorted(
+            (self.resident(self.rng.choice(names), app, anti=anti)
+             for _ in range(count)),
+            key=lambda p: p.spec.node_name,
+        )
+        assert not any(self.cache.assume_pods(pods))
+        return pods
+
+    def refresh(self):
+        self.cache.update_snapshot(self.snapshot)
+        return self.tensors.update(self.snapshot)
+
+    def check(self, pods, facts=None, snapshot=None, nt=None):
+        """Pack ``pods`` with the kept facts and as it was done before:
+        every array equal."""
+        facts = self.facts if facts is None else facts
+        if snapshot is None:
+            snapshot, nt = self.snapshot, self.refresh()
+        self.checks += 1
+        got = pack_spread_batch(pods, snapshot, nt, facts)
+        want = pack_spread_twin(pods, snapshot, nt)
+        assert_same_batch(got, want, "spread")
+        got_af = pack_affinity_batch(pods, snapshot, nt, facts)
+        want_af = pack_affinity_twin(pods, snapshot, nt)
+        assert_same_batch(got_af, want_af, "affinity")
+        if any(pod_host_ports(p) for p in pods):
+            assert_same_batch(
+                add_host_port_rows(pods, snapshot, nt, got_af, facts),
+                add_host_port_rows_twin(pods, snapshot, nt, want_af),
+                "host ports",
+            )
+        return got, got_af
+
+
+APPS = ("a", "b", "c", "web")
+
+
+def _wave(rng, tag, apps=APPS, scoped=True, ports=False, count=4):
+    """A constrained batch in the benchmark's shape and around it:
+    spread apps, anti apps, an affinity app, node-selector-scoped
+    copies of each (the check wave's shape), another namespace."""
+    pods = []
+
+    def some(name, namespace="default"):
+        return [make_pod(f"{tag}-{name}-{i}", namespace)
+                for i in range(count)]
+
+    for app in apps:
+        for w in some(f"sp-{app}"):
+            pods.append(w.labels(app=app).spread_constraint(
+                1, "zone", match_labels={"app": app}))
+        for w in some(f"an-{app}"):
+            pods.append(w.labels(app=app).pod_affinity(
+                HOST, {"app": app}, anti=True))
+    for w in some("aff"):
+        pods.append(w.labels(app="web", tier="front").pod_affinity(
+            "zone", {"app": "web"}))
+    for w in some("other", "other"):
+        pods.append(w.labels(app="a").spread_constraint(
+            2, HOST, match_labels={"app": "a"}))
+    for w in some("plain"):
+        pods.append(w.labels(app="b"))
+    if scoped:
+        for w in some("scoped-sp"):
+            pods.append(w.labels(app="c").node_selector(**{POOL: "ballast"})
+                        .spread_constraint(1, "zone",
+                                           match_labels={"app": "c"}))
+        for w in some("scoped-an"):
+            pods.append(w.labels(app="c").node_selector(**{POOL: "ballast"})
+                        .pod_affinity(HOST, {"app": "c"}, anti=True))
+    if ports:
+        for i, w in enumerate(some("port")):
+            pods.append(w.labels(app="b").container(
+                cpu="10m", memory="1Mi", host_port=9000 + i % 2))
+    for w in pods:
+        if not w.pod.spec.containers:
+            w.container(cpu="100m", memory="64Mi")
+    out = [w.obj() for w in pods]
+    rng.shuffle(out)
+    return out
+
+
+FAMILY_STEPS = (
+    "pod_add", "pod_add", "pod_add", "pod_remove", "terminate", "assume",
+    "forget", "confirm", "anti_add", "other_namespace", "node_add",
+    "node_remove", "relabel", "status_write", "wave_delete",
+)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4, 5, 6])
+def test_family_packs_equal_the_walks_under_churn(seed):
+    c = Cluster(seed=seed)
+    rng = c.rng
+    assumed = []
+    c.check(_wave(rng, "w0"))
+    for step in range(40):
+        for _ in range(rng.randrange(1, 5)):
+            kind = rng.choice(FAMILY_STEPS)
+            if kind == "pod_add":
+                c.add_pod()
+            elif kind == "anti_add":
+                c.add_pod(anti=True)
+            elif kind == "other_namespace":
+                c.add_pod(namespace="other")
+            elif kind == "pod_remove" and c.pods:
+                c.remove_pod(c.pods[rng.choice(sorted(c.pods))])
+            elif kind == "terminate" and c.pods:
+                c.terminate(c.pods[rng.choice(sorted(c.pods))])
+            elif kind == "assume":
+                assumed += c.assume_wave(6, rng.choice(APPS),
+                                         anti=rng.random() < 0.3)
+            elif kind == "forget" and assumed:
+                c.cache.forget_pod(assumed.pop(rng.randrange(len(assumed))))
+            elif kind == "confirm" and assumed:
+                pod = assumed.pop(rng.randrange(len(assumed)))
+                c.pods[pod.metadata.uid] = pod
+                c.cache.add_pod(pod)
+            elif kind == "wave_delete" and assumed:
+                for pod in assumed:
+                    c.cache.forget_pod(pod)
+                assumed = []
+            elif kind == "node_add":
+                c.add_node(rack=rng.random() < 0.7, pool=rng.random() < 0.4)
+            elif kind == "node_remove" and len(c.nodes) > 6:
+                name = rng.choice(sorted(c.nodes))
+                assumed = [p for p in assumed if p.spec.node_name != name]
+                c.remove_node(name)
+            elif kind == "relabel":
+                c.relabel(rng.choice(sorted(c.nodes)), rng.choice(ZONES))
+            elif kind == "status_write":
+                c.status_write(rng.choice(sorted(c.nodes)))
+        c.check(_wave(rng, f"w{step}", ports=step % 5 == 0))
+    kept = c.facts
+    assert kept.node_rows_reused > 0
+    assert kept.nodes_recounted < kept.nodes  # not every node every time
+
+
+@pytest.mark.parametrize("lands_on", ["some nodes", "every node"])
+def test_a_whole_wave_deleted_at_once_is_counted_out(lands_on):
+    """PR 27's fault was a closed wave read as no change: the wave's
+    pods leave every node they were on between two packs."""
+    c = Cluster(nodes=30, seed=7)
+    for app in APPS:
+        for _ in range(10):
+            c.add_pod(app=app)
+    c.check(_wave(c.rng, "w"))
+    on = sorted(c.nodes)[:12] if lands_on == "some nodes" else None
+    wave = c.assume_wave(60, "a", on=on) + c.assume_wave(
+        30, "c", anti=True, on=on)
+    recounted = c.facts.nodes_recounted
+    sp, af = c.check(_wave(c.rng, "x"))
+    with_wave = sp.group_counts.sum(), af.counts_anti.sum()
+    for pod in wave:
+        c.cache.forget_pod(pod)
+    sp, af = c.check(_wave(c.rng, "y"))
+    assert sp.group_counts.sum() < with_wave[0]
+    assert af.counts_anti.sum() < with_wave[1]
+    assert c.facts.nodes_recounted - recounted == 2 * (
+        12 if on else len(c.nodes))
+
+
+def test_a_census_that_misses_the_wave_delete_is_caught(monkeypatch):
+    """The comparison has the power it is trusted for: with the change
+    log read as empty the counts keep the deleted wave and differ."""
+    c = Cluster(seed=7)
+    c.check(_wave(c.rng, "w"))
+    wave = c.assume_wave(60, "a")
+    c.check(_wave(c.rng, "x"))
+    for pod in wave:
+        c.cache.forget_pod(pod)
+    monkeypatch.setattr(
+        Snapshot, "changes_since",
+        lambda self, cursor: (set(), False, self._change_seq),
+    )
+    with pytest.raises(AssertionError):
+        c.check(_wave(c.rng, "y"))
+
+
+def test_a_terminating_pod_leaves_the_spread_counts_only():
+    c = Cluster(seed=8)
+    pods = [c.add_pod(app="a") for _ in range(12)]
+    batch = _wave(c.rng, "w", apps=("a",), scoped=False)
+    sp0, af0 = c.check(batch)
+    for pod in pods[:5]:
+        c.terminate(pod)
+    recounted = c.facts.nodes_recounted
+    sp1, af1 = c.check(_wave(c.rng, "x", apps=("a",), scoped=False))
+    assert sp1.group_counts.sum() < sp0.group_counts.sum()
+    assert af1.counts_anti.sum() == af0.counts_anti.sum()
+    assert 0 < c.facts.nodes_recounted - recounted <= 5
+
+
+def test_node_changes_keep_or_rebuild_the_rows():
+    c = Cluster(seed=9)
+    for _ in range(30):
+        c.add_pod()
+    kept = c.facts
+
+    def asked_and_reused(tag):
+        asked, reused = kept.node_rows, kept.node_rows_reused
+        c.check(_wave(c.rng, tag))
+        return kept.node_rows - asked, kept.node_rows_reused - reused
+
+    asked_and_reused("w0")
+    asked, reused = asked_and_reused("w1")
+    assert asked == reused > 0  # nothing moved: every row from the store
+    c.status_write(sorted(c.nodes)[0])  # a kubelet's write: rows kept
+    asked, reused = asked_and_reused("w2")
+    assert asked == reused
+    name = sorted(c.nodes)[1]
+    zone = c.nodes[name].metadata.labels["zone"]
+    c.relabel(name, next(z for z in ZONES if z != zone))
+    asked, reused = asked_and_reused("w3")
+    assert reused < asked  # the zone key moved: rows built anew
+    recounted = kept.nodes_recounted
+    c.add_node(pool=True)
+    asked, reused = asked_and_reused("w4")
+    assert reused < asked
+    # a membership move recounts every node
+    assert kept.nodes_recounted - recounted == len(c.nodes)
+    c.remove_node(sorted(c.nodes)[2])
+    asked, reused = asked_and_reused("w5")
+    assert reused < asked
+
+
+def test_a_truncated_change_log_recounts_every_node():
+    c = Cluster(nodes=10, seed=10)
+    for _ in range(20):
+        c.add_pod()
+    c.check(_wave(c.rng, "w0"))
+    pod = c.add_pod()
+    recounted = c.facts.nodes_recounted
+    c.check(_wave(c.rng, "w1"))
+    assert c.facts.nodes_recounted - recounted == 1
+    # more notes than the log keeps between two packs: each refresh
+    # notes the node again
+    from kubernetes_tpu.cache.snapshot import CHANGE_TRACK_MIN
+
+    for _ in range(CHANGE_TRACK_MIN + 2 * len(c.nodes) + 8):
+        c.remove_pod(pod)
+        pod = c.add_pod(node=pod.spec.node_name, app="a")
+        c.cache.update_snapshot(c.snapshot)
+    names, _moved, _cursor = c.snapshot.changes_since(c.facts._cursor)
+    assert names is None
+    recounted = c.facts.nodes_recounted
+    c.check(_wave(c.rng, "w2"))
+    assert c.facts.nodes_recounted - recounted == len(c.nodes)
+
+
+def test_two_snapshots_refreshed_at_different_times():
+    """Each snapshot has its own change log; facts that follow one are
+    right for it whenever the other was refreshed, and one object
+    handed both in turn keeps nothing across the switch and is right
+    for each."""
+    c = Cluster(seed=11)
+    other_snapshot, other_tensors = Snapshot(), NodeTensorCache()
+    other_facts, shared = FamilyFacts(), FamilyFacts()
+    rng = c.rng
+    for step in range(12):
+        for _ in range(5):
+            c.add_pod()
+        if step % 3 == 1:
+            c.remove_pod(c.pods[rng.choice(sorted(c.pods))])
+        if step == 5:
+            c.remove_node(sorted(c.nodes)[0])
+            c.add_node(pool=True)
+        batch = _wave(rng, f"w{step}")
+        c.check(batch)
+        c.check(batch, facts=shared)
+        if step % 4 == 3:  # the second snapshot lags by several steps
+            c.cache.update_snapshot(other_snapshot)
+            nt = other_tensors.update(other_snapshot)
+            c.check(batch, other_facts, other_snapshot, nt)
+            c.check(batch, shared, other_snapshot, nt)
+
+
+def test_a_foreign_snapshot_keeps_nothing():
+    c = Cluster(seed=12)
+    for _ in range(20):
+        c.add_pod()
+    c.check(_wave(c.rng, "w"))
+    tally = c.facts.tally()
+    kept_rows = dict(c.facts._rows)
+    foreign = new_snapshot(list(c.pods.values()), list(c.nodes.values()))
+    assert foreign.node_spec_epoch == 0
+    nt = NodeTensorCache().update(foreign)
+    fresh = _wave(c.rng, "x")
+    c.check(fresh, c.facts, foreign, nt)
+    assert c.facts.tally() == tally and dict(c.facts._rows) == kept_rows
+    assert not any("_family_memo" in p.__dict__ for p in fresh)
+    c.check(fresh)  # and the kept facts still follow their own snapshot
+    assert all("_family_memo" in p.__dict__ for p in fresh)
+
+
+def _two_keys(c):
+    return [make_pod(f"k{i}").labels(app="a")
+            .spread_constraint(1, "zone", match_labels={"app": "a"})
+            .spread_constraint(1, "rack", match_labels={"app": "a"}).obj()
+            for i in range(3)]
+
+
+def _many(n, build):
+    return [build(make_pod(f"e{i}").labels(app=f"e{i}"), i).obj()
+            for i in range(n)]
+
+
+def _spreads(w, n):
+    for k in range(n):
+        w.spread_constraint(1, "zone", match_labels={"app": f"s{k}"})
+    return w
+
+
+def _terms(w, n, anti, key=HOST):
+    for k in range(n):
+        w.pod_affinity(key, {"app": f"t{k}"}, anti=anti)
+    return w
+
+
+ENVELOPES = {
+    "two keys, one not on every node": (
+        "spread", lambda c: _two_keys(c)),
+    "groups": ("spread", lambda c: _many(
+        MAX_GROUPS + 1, lambda w, i: w.spread_constraint(
+            1, "zone", match_labels={"app": f"e{i}"}))),
+    "constraints a pod": ("spread", lambda c: [
+        make_pod("e").labels(app="a").obj()
+    ] + _many(1, lambda w, i: _spreads(w, MAX_CONSTRAINTS_PER_POD + 1))),
+    "affinity terms a pod": ("affinity", lambda c: _many(
+        1, lambda w, i: _terms(w, MAX_TERMS_PER_POD + 1, anti=False))),
+    "anti terms a pod": ("affinity", lambda c: _many(
+        1, lambda w, i: _terms(w, MAX_TERMS_PER_POD + 1, anti=True))),
+    "affinity rows": ("affinity", lambda c: _many(
+        MAX_AFF_ROWS // 2 + 1, lambda w, i: w.pod_affinity(
+            "zone", {"app": f"x{i}"}).pod_affinity(
+            HOST, {"app": f"y{i}"}))),
+    "anti rows": ("affinity", lambda c: _many(
+        MAX_ANTI_ROWS + 1, lambda w, i: w.pod_affinity(
+            HOST, {"app": f"x{i}"}, anti=True))),
+    "keys": ("affinity", lambda c: _many(
+        MAX_KEYS + 1, lambda w, i: w.pod_affinity(
+            f"key{i}", {"app": "a"}, anti=True))),
+    "exist rows": ("affinity", lambda c: _crowd(c) + _many(
+        2, lambda w, i: w.pod_affinity(HOST, {"app": "a"}, anti=True))),
+}
+
+
+def _crowd(c):
+    """More resident anti-affinity terms than there are exist rows."""
+    for i in range(MAX_EXIST_ROWS + 1):
+        c.add_pod(app=f"x{i}", anti=True)
+    return []
+
+
+@pytest.mark.parametrize("what", sorted(ENVELOPES))
+def test_each_envelope_bails_out_as_before(what):
+    family, build = ENVELOPES[what]
+    c = Cluster(seed=13)
+    for _ in range(10):
+        c.add_pod()
+    inside = _wave(c.rng, "in", scoped=False)
+    c.check(inside)
+    pods = build(c)
+    sp, af = c.check(pods)
+    assert (sp if family == "spread" else af) is None
+    c.check(inside)  # and the facts are whole after a bail-out
+    if what.startswith("two keys"):
+        # every node given the key: the same pods pack
+        for name in sorted(c.nodes):
+            old = c.nodes[name]
+            new = c._node_obj(name, old.metadata.labels["zone"], True,
+                              POOL in old.metadata.labels)
+            c.nodes[name] = new
+            c.cache.update_node(old, new)
+        sp, _af = c.check(_two_keys(c))
+        assert sp is not None and sp.pod_groups[0, 1] == 1

@@ -319,6 +319,100 @@ def test_packs_children_say_how_much_was_incremental(burst_trace):
     assert states[-1]["stats"]["rows"] >= 1
 
 
+def _constrained(tag, cpu):
+    """Two templates: a zone-spread app and a host-anti app."""
+    pods = [
+        make_pod(f"{tag}-sp-{i}").labels(app=f"{tag}-sp")
+        .spread_constraint(1, "zone", match_labels={"app": f"{tag}-sp"})
+        .container(cpu=cpu, memory="16Mi").obj() for i in range(6)
+    ]
+    return pods + [
+        make_pod(f"{tag}-an-{i}").labels(app=f"{tag}-an")
+        .pod_affinity("kubernetes.io/hostname", {"app": f"{tag}-an"},
+                      anti=True)
+        .container(cpu=cpu, memory="16Mi").obj() for i in range(4)
+    ]
+
+
+def test_pack_families_says_what_it_kept(tmp_path):
+    """``sched/pack.families`` carries the kept facts' five counters
+    (ops/family_facts.py): nodes, nodes recounted, node-value rows asked
+    for and served from the store, pod templates."""
+    server = APIServer()
+    client = Client(server)
+    informers = InformerFactory(server)
+    sched = new_scheduler(client, informers, batch=True, max_batch=64)
+    for i in range(6):
+        client.create_node(
+            make_node(f"node-{i}")
+            .labels(zone=f"z{i % 3}",
+                    **{"kubernetes.io/hostname": f"node-{i}"})
+            .capacity(cpu="32", memory="64Gi", pods=110).obj()
+        )
+    informers.start()
+    informers.wait_for_cache_sync()
+    sched.queue.run()
+
+    def batch(pods, binds):
+        client.create_pods_bulk(pods)
+        deadline = time.time() + 30
+        while len(sched.queue.pending_pods()) < len(pods):
+            assert time.time() < deadline, "the pods never queued"
+            time.sleep(0.01)
+        assert sched.schedule_batch(timeout=1.0) == len(pods)
+        sched.wait_for_inflight_binds()
+        while binds and time.time() < deadline:
+            if (
+                all(client.get_pod(p.metadata.namespace, p.metadata.name)
+                    .spec.node_name for p in pods)
+                and not sched.cache._assumed_pods
+            ):
+                break
+            time.sleep(0.01)
+        if not binds:  # out of the queue, so that no later batch retries
+            for p in pods:
+                client.delete_pod(p.metadata.namespace, p.metadata.name)
+            while sched.queue.pending_pods():
+                assert time.time() < deadline
+                time.sleep(0.01)
+
+    plain = [make_pod(f"plain-{i}").container(cpu="10m", memory="16Mi").obj()
+             for i in range(8)]
+    try:
+        with profiled(tmp_path) as events:
+            batch(_constrained("a", cpu="64"), binds=False)  # fits nowhere
+            batch(_constrained("b", cpu="64"), binds=False)
+            before_plain = sched.family_facts.tally()
+            batch(plain, binds=True)
+            assert sched.family_facts.tally() == before_plain
+            batch(_constrained("c", cpu="10m"), binds=True)
+            batch(_constrained("d", cpu="10m"), binds=True)
+    finally:
+        sched.stop()
+        informers.stop()
+    assert sched.pods_fallback == 0
+    spans = sorted(named(events, "sched/pack.families"),
+                   key=lambda ev: ev["start"])
+    first, quiet, plain_one, fourth, after_binds = [
+        {k: ev["stats"][k] for k in (
+            "nodes", "nodes_recounted", "node_rows", "node_rows_reused",
+            "templates")}
+        for ev in spans
+    ]
+    # first use: every node counted, the zone and hostname rows built
+    assert first == {"nodes": 6, "nodes_recounted": 6, "node_rows": 2,
+                     "node_rows_reused": 0, "templates": 2}
+    # nothing changed since: no node recounted, every row from the store
+    assert quiet == {"nodes": 6, "nodes_recounted": 0, "node_rows": 2,
+                     "node_rows_reused": 2, "templates": 2}
+    assert plain_one == dict.fromkeys(first, 0)  # never entered
+    # the plain pods landed on some nodes: those alone are recounted
+    assert 1 <= fourth["nodes_recounted"] <= 6
+    assert 1 <= after_binds["nodes_recounted"] <= 6
+    for stats in (fourth, after_binds):
+        assert stats["node_rows"] == stats["node_rows_reused"] == 2
+
+
 def test_dispatch_spans_carry_the_rings_queue_waits(burst_trace):
     events, dump, _sched = burst_trace
     dispatches = {d["stats"]["batch"]: d["stats"]

@@ -26,6 +26,13 @@ Prints ONE JSON line:
                          static_mask_compact for an 80-pod batch of one
                          signature at M nodes, built and then handed out
                          again by a MaskRowCache,
+   "family_pack_ms_{cold,tracked}":
+                         pack_spread_batch + pack_affinity_batch for a
+                         4,096-pod batch of 8 spread apps x 500 + 4
+                         anti apps x 250 (shuffled) at M nodes in 10
+                         zones with 5,480 resident pods: built from
+                         the whole snapshot, and from a FamilyFacts
+                         after every node has changed (a wave deleted),
    "reuse_check_ms_churn{0,1pct,100pct}":
                          the dispatch generation handshake (epoch compare
                          + changed-row content check) at the same churn,
@@ -359,6 +366,106 @@ def bench_node_state(num_nodes):
         static_mask_compact(pods, snap, nt, kept)
         out[f"static_mask_ms_{label}"] = (time.perf_counter() - t0) * 1000
     assert (kept.rows_built, kept.rows_reused) == (1, 1)
+    return out
+
+
+def bench_family_pack(num_nodes):
+    """The family packers on the constrained benchmark cell's shape: a
+    cold build from the whole snapshot against a build from kept facts
+    (ops/family_facts.py) in the window's worst case, every node
+    changed since the last constrained pack."""
+    import gc
+    import random
+
+    from kubernetes_tpu.cache.cache import SchedulerCache
+    from kubernetes_tpu.cache.snapshot import Snapshot
+    from kubernetes_tpu.ops.affinity import pack_affinity_batch
+    from kubernetes_tpu.ops.family_facts import FamilyFacts
+    from kubernetes_tpu.ops.host_masks import static_mask_compact
+    from kubernetes_tpu.ops.topology import pack_spread_batch
+    from kubernetes_tpu.tensors import NodeTensorCache
+    from kubernetes_tpu.testing import make_node, make_pod
+
+    host = "kubernetes.io/hostname"
+    cache = SchedulerCache()
+    for i in range(num_nodes):
+        cache.add_node(
+            make_node(f"fp-{i}")
+            .labels(zone=f"z{i % 10}", **{host: f"fp-{i}"})
+            .capacity(cpu="32", memory="64Gi", pods=110)
+            .obj()
+        )
+    resident = [
+        make_pod(f"ballast-{i}").uid(f"ballast-{i}").labels(app="ballast")
+        .node(f"fp-{i % min(640, num_nodes)}")
+        .container(cpu="100m", memory="128Mi").obj()
+        for i in range(4480)
+    ] + [
+        make_pod(f"init-{i}").uid(f"init-{i}").labels(app="init")
+        .node(f"fp-{(640 + i) % num_nodes}")
+        .container(cpu="100m", memory="128Mi").obj()
+        for i in range(1000)
+    ]
+    cache.add_pods(resident)
+
+    def wave(tag):
+        pods = []
+        for a in range(8):
+            app = f"{tag}-sp{a}"
+            pods += [
+                make_pod(f"{app}-{i}").labels(app=app)
+                .spread_constraint(1, "zone", match_labels={"app": app})
+                .container(cpu="100m", memory="128Mi").obj()
+                for i in range(500)
+            ]
+        for a in range(4):
+            app = f"{tag}-an{a}"
+            pods += [
+                make_pod(f"{app}-{i}").labels(app=app)
+                .pod_affinity(host, {"app": app}, anti=True)
+                .container(cpu="100m", memory="128Mi").obj()
+                for i in range(250)
+            ]
+        random.Random(7).shuffle(pods)
+        return pods[:4096]
+
+    snap = Snapshot()
+    tc = NodeTensorCache()
+    kept = FamilyFacts()
+
+    def pack(tag, facts):
+        cache.update_snapshot(snap)
+        nt = tc.update(snap)
+        pods = wave(tag)
+        # the dispatcher's mask stage comes first and leaves each pod's
+        # constraint signature on the pod
+        static_mask_compact(pods, snap, nt)
+        gc.disable()  # as the dispatcher's GCBatchGuard does
+        try:
+            t0 = time.perf_counter()
+            sp = pack_spread_batch(pods, snap, nt, facts)
+            af = pack_affinity_batch(pods, snap, nt, facts)
+            ms = (time.perf_counter() - t0) * 1000
+        finally:
+            gc.enable()
+        assert sp is not None and af is not None
+        return ms
+
+    out = {"family_pack_ms_cold": pack("w0", None)}
+    pack("w1", kept)  # fills the facts
+    # a wave bound and deleted: every node's pods changed and are back
+    churn = [
+        make_pod(f"churn-{i}").uid(f"churn-{i}").labels(app="w1-sp0")
+        .node(f"fp-{i}").container(cpu="100m", memory="128Mi").obj()
+        for i in range(num_nodes)
+    ]
+    cache.add_pods(churn)
+    cache.update_snapshot(snap)
+    cache.remove_pods(churn)
+    recounted, reused = kept.nodes_recounted, kept.node_rows_reused
+    out["family_pack_ms_tracked"] = pack("w2", kept)
+    assert kept.nodes_recounted - recounted == num_nodes
+    assert kept.node_rows_reused - reused == 9  # 8 zone rows, 1 hostname
     return out
 
 
@@ -1685,6 +1792,7 @@ def main() -> None:
     pack_ms = bench_pack(pods)
     gather_ms, assume_ms = bench_commit(pods, node_names)
     node_state = bench_node_state(args.nodes)
+    family = bench_family_pack(args.nodes)
     member = bench_membership_churn(args.nodes)
     mesh_delta = bench_mesh_delta(args.mesh_nodes, args.mesh_devices)
     mesh_pallas = bench_mesh_pallas(args.mesh_nodes, args.mesh_devices)
@@ -1715,6 +1823,7 @@ def main() -> None:
         "commit_assume_ms": round(assume_ms, 2),
     }
     record.update({k: round(v, 3) for k, v in node_state.items()})
+    record.update({k: round(v, 3) for k, v in family.items()})
     record.update(
         {
             k: (v if isinstance(v, int) else round(v, 3))
