@@ -42,13 +42,14 @@ from kubernetes_tpu.tensors.node_tensor import NUM_FIXED_DIMS, PODS
 
 NO_NODE = -1
 
-# lax.scan unroll knob. unroll > 1 multiplies compiled-program size and
-# GSPMD compile time (the 8-device CPU dryrun went 2.5min -> 5s at
-# unroll=1); its effect on solve time was last looked at on an earlier
-# machine and is not re-measured on this one. Default stays 1.
 import os as _os
 
-SCAN_UNROLL = int(_os.environ.get("KTPU_SCAN_UNROLL", "1"))
+# lax.scan unroll. unroll > 1 multiplies compiled-program size and
+# GSPMD compile time (the 8-device CPU dryrun went 2.5min -> 5s at
+# unroll=1); its effect on solve time was last looked at on an earlier
+# machine and is not re-measured on this one (PERF.md "Decisions to
+# re-measure").
+SCAN_UNROLL = 1
 
 _PODS_COL = PODS  # the pod-count dimension of the node tensor
 
@@ -349,9 +350,7 @@ def _unpack_buffer(buf: jnp.ndarray, layout: Tuple) -> dict:
     """Re-slice the single uploaded int32 buffer into named arrays
     (static offsets, free after fusion). ``kind`` restores dtypes: 'i'
     int32, 'b' bool, 'f' float32 (bitcast -- float tensors ride the
-    int32 buffer bit-exactly), 'h' int16 values packed two per int32
-    word (halves the link bytes for range-gated carry state; decoded
-    back to int32 values here); ``("Z*", fill)`` marks a ConstPiece
+    int32 buffer bit-exactly); ``("Z*", fill)`` marks a ConstPiece
     materialized on device as a free constant."""
     arrs = {}
     off = 0
@@ -364,15 +363,6 @@ def _unpack_buffer(buf: jnp.ndarray, layout: Tuple) -> dict:
         size = 1
         for d in shape:
             size *= d
-        if kind == "h":
-            nw = (size + 1) // 2
-            w = buf[off:off + nw]
-            lo = (w << 16) >> 16  # sign-extend the low half
-            hi = w >> 16  # arithmetic shift sign-extends the high half
-            a = jnp.stack([lo, hi], axis=1).reshape(-1)[:size]
-            arrs[name] = a.reshape(shape)
-            off += nw
-            continue
         a = buf[off:off + size].reshape(shape)
         if kind == "b":
             a = a.astype(bool)
@@ -430,22 +420,19 @@ def _apply_row_patches(arrs, alloc, valid, req_state, nzr_state, shard_local):
 
 @partial(
     jax.jit,
-    static_argnames=(
-        "layout", "config", "mode", "use_pallas", "caps", "compress",
-    ),
+    static_argnames=("layout", "config", "mode", "use_pallas", "caps"),
 )
 def _solve_packed_jit(
     buf: jnp.ndarray,  # [T] int32: every uploaded piece, concatenated
     alloc_in,  # [N, R] int32 device-resident, or None when in buf
     valid_in,  # [N] bool device-resident, or None when in buf
-    req_in,  # [N, R] int32/int16 carried device state, or None when in buf
-    nzr_in,  # [N, 2] int32/int16 carried device state, or None when in buf
+    req_in,  # [N, R] int32 carried device state, or None when in buf
+    nzr_in,  # [N, 2] int32 carried device state, or None when in buf
     layout: Tuple,  # static ((name, shape, kind), ...) describing buf slices
     config: GreedyConfig = GreedyConfig(),
     mode: str = "greedy",
     use_pallas: bool = False,
     caps=None,  # static pallas_constrained.Caps family specialization
-    compress: bool = False,  # int16 resident carry: widen in, narrow out
 ):
     """Solve from a SINGLE uploaded buffer.
 
@@ -464,24 +451,13 @@ def _solve_packed_jit(
     valid = arrs["valid"].astype(bool) if "valid" in arrs else valid_in
     req_state = arrs["req_state"] if "req_state" in arrs else req_in
     nzr_state = arrs["nzr_state"] if "nzr_state" in arrs else nzr_in
-    if req_state is not None:
-        # compressed carry normalizes to int32 at entry (lossless: the
-        # engage gate bounds every resident value to int16 range), so
-        # the solver kernels see ONE dtype regardless of how the state
-        # is held -- no kernel changes, no extra Pallas tile shapes
-        req_state = req_state.astype(jnp.int32)
-        nzr_state = nzr_state.astype(jnp.int32)
     alloc, valid, req_state, nzr_state = _apply_row_patches(
         arrs, alloc, valid, req_state, nzr_state, shard_local=False
     )
-    assignment, req_out, nzr_out, alloc, valid = _packed_solve_tail(
+    return _packed_solve_tail(
         arrs, alloc, valid, req_state, nzr_state, config, mode,
         use_pallas, caps,
     )
-    if compress:
-        req_out = req_out.astype(jnp.int16)
-        nzr_out = nzr_out.astype(jnp.int16)
-    return assignment, req_out, nzr_out, alloc, valid
 
 
 def _packed_solve_tail(
@@ -538,9 +514,7 @@ def _packed_solve_tail(
 #: one transfer instead of two. The 1 MiB cutoff was set on an earlier
 #: machine and is not re-measured on this one (PERF.md "Decisions to
 #: re-measure")
-MESH_MASK_SHARD_MIN_BYTES = int(
-    _os.environ.get("KTPU_MESH_MASK_SHARD_MIN_BYTES", 1 << 20)
-)
+MESH_MASK_SHARD_MIN_BYTES = 1 << 20
 
 
 def mesh_pallas_candidate(mode: str, n_cap: int, mesh) -> bool:
@@ -825,32 +799,12 @@ def apply_assignment_delta(
     this inside their own carry; this standalone jit keeps the carry
     warm when the assignments were produced OFF device (the host-greedy
     ladder tier), at an O(B*R) upload instead of a full [N, R]
-    re-upload next dispatch. Dtype-preserving: an int16 compressed
-    carry accumulates in int32 and narrows back (the engage gate keeps
-    the post-batch sums in range)."""
+    re-upload next dispatch."""
     idx = jnp.where(assignments < 0, req_state.shape[0], assignments)
-    req_out = req_state.astype(jnp.int32).at[idx].add(pod_req, mode="drop")
-    nzr_out = nzr_state.astype(jnp.int32).at[idx].add(pod_nzr, mode="drop")
     return (
-        req_out.astype(req_state.dtype),
-        nzr_out.astype(nzr_state.dtype),
+        req_state.at[idx].add(pod_req, mode="drop"),
+        nzr_state.at[idx].add(pod_nzr, mode="drop"),
     )
-
-
-@jax.jit
-def compress_carry(req_state, nzr_state):
-    """Narrow the device-resident carry to int16 in place (one tiny
-    fused kernel, no host round trip). Lossless under the engage gate's
-    range guarantee (scheduler/batch.py books the gate)."""
-    return req_state.astype(jnp.int16), nzr_state.astype(jnp.int16)
-
-
-@jax.jit
-def decompress_carry(req_state, nzr_state):
-    """Widen an int16 resident carry back to int32 before a dispatch
-    that needs the uncompressed signature (constrained ladder, range
-    gate tripped)."""
-    return req_state.astype(jnp.int32), nzr_state.astype(jnp.int32)
 
 
 class ConstPiece:
@@ -899,8 +853,6 @@ def _piece_kind(arr):
         return "f"
     if arr.dtype == _np.bool_:
         return "b"
-    if arr.dtype == _np.int16:
-        return "h"
     return "i"
 
 
@@ -1016,11 +968,9 @@ def solve_packed(
     mode: str = "greedy",
     allow_pallas: bool = True,
     mesh=None,
-    compress: bool = False,
 ):
     """Host-side companion of _solve_packed_jit: concatenates the pieces
-    (int32 / bool / float32 / packed int16 -- see _solve_packed_jit's
-    kind codes) and
+    (int32 / bool / float32 -- see _solve_packed_jit's kind codes) and
     dispatches one upload + one solve. The greedy mode runs the fused
     Pallas kernel on TPU backends (KTPU_PALLAS=0 opts out; batch shapes
     the kernel's SMEM chunking can't tile fall back to the XLA scan).
@@ -1081,16 +1031,6 @@ def solve_packed(
     def as_i32(arr):
         if arr.dtype == _np.float32:
             return _np.ascontiguousarray(arr).view(_np.int32)
-        if arr.dtype == _np.int16:
-            # pack two int16 values per int32 word (the 'h' layout
-            # kind): halves the link bytes; _unpack_buffer sign-extends
-            # the halves back on device
-            flat = arr.ravel().astype(_np.int32)
-            if flat.size % 2:
-                flat = _np.concatenate(
-                    [flat, _np.zeros(1, dtype=_np.int32)]
-                )
-            return (flat[0::2] & 0xFFFF) | (flat[1::2] << 16)
         if arr.dtype == _np.int32:
             return arr
         return arr.astype(_np.int32)
@@ -1153,7 +1093,7 @@ def solve_packed(
     return _solve_packed_jit(
         buf_d, alloc_in, valid_in, req_in, nzr_in,
         layout=layout, config=config, mode=mode,
-        use_pallas=use_pallas, caps=caps, compress=compress,
+        use_pallas=use_pallas, caps=caps,
     )
 
 
@@ -1544,44 +1484,6 @@ def greedy_assign_constrained(
         step, carry0, xs, unroll=SCAN_UNROLL
     )
     return assignments, req_out, nzr_out
-def make_sharded_solver(mesh: "jax.sharding.Mesh", config: GreedyConfig = GreedyConfig()):
-    """Build a node-axis-sharded greedy solver for a device mesh.
-
-    Sharding layout (SURVEY.md section 2.5: data parallelism over the node
-    axis, the TPU analogue of ParallelizeUntil's 16 goroutines): every
-    ``[N, ...]`` operand is split over the ``nodes`` mesh axis, pod-batch
-    operands are replicated, and XLA inserts the ICI collectives for the
-    cross-shard argmax inside the scan. N must be a multiple of the mesh
-    size (NodeTensorCache pads to 128 rows).
-
-    This is the raw stateless kernel (the dryrun drives it directly);
-    the production scheduler instead rides the DEVICE-RESIDENT CARRY
-    variant -- ``make_mesh_packed_solver`` -- where the sharded node
-    state stays on the mesh between batches and steady-state dispatch
-    ships only the fixed per-shard delta scatter.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    node = NamedSharding(mesh, P("nodes"))
-    node2d = NamedSharding(mesh, P("nodes", None))
-    batch_by_node = NamedSharding(mesh, P(None, "nodes"))
-    repl = NamedSharding(mesh, P())
-
-    def solve(allocatable, requested, nzr, valid, pod_requests, pod_nzr,
-              static_mask, active):
-        return greedy_assign(
-            allocatable, requested, nzr, valid,
-            pod_requests, pod_nzr, static_mask, active, config=config,
-        )
-
-    return jax.jit(
-        solve,
-        in_shardings=(
-            node2d, node2d, node2d, node,  # node-axis state
-            repl, repl, batch_by_node, repl,  # pod batch
-        ),
-        out_shardings=(repl, node2d, node2d),
-    )
 
 
 @partial(jax.jit, static_argnames=("config", "iters"))

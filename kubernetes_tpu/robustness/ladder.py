@@ -219,11 +219,6 @@ class SolverLadder:
     def record_sequential(self, count: int = 1) -> None:
         self.solves_by_tier[TIER_SEQUENTIAL] += count
 
-    def record_xla(self) -> None:
-        """A batch a plain XLA lowering solved outside ``run`` (the
-        legacy mesh path has no ladder of its own)."""
-        self.solves_by_tier[TIER_XLA] += 1
-
     @staticmethod
     def _next_tier_name(attempts, idx) -> str:
         if idx + 1 < len(attempts):

@@ -54,10 +54,7 @@ from kubernetes_tpu.ops.assignment import (
     GreedyConfig,
     NO_NODE,
     apply_assignment_delta,
-    compress_carry,
-    decompress_carry,
     greedy_assign_compact,
-    greedy_assign_constrained,
     mesh_shard_uses_kernel,
     sinkhorn_assign,
     solve_packed,
@@ -350,28 +347,9 @@ DELTA_ROW_BUCKET = 64
 _SHADOW_RING_CAP = MAX_INFLIGHT + 2
 
 
-#: int16 engage ceiling for the compressed carry: resident max + batch
-#: load + in-flight load must stay under this (a guard band below 32767
-#: absorbs row patches that land between the gate read and the solve)
-_CARRY_COMPRESS_CEILING = 24576
-
-
-def _batch_load16(req, nzr, b) -> int:
-    """Worst-case per-column load this batch can add to any node row
-    (every pod landing on one node): the range gate's per-dispatch
-    term."""
-    if not b:
-        return 0
-    return max(
-        int(req[:b].sum(axis=0, dtype=np.int64).max(initial=0)),
-        int(nzr[:b].sum(axis=0, dtype=np.int64).max(initial=0)),
-    )
-
-
 def _delta_slot_pieces(
     n_cap, r_dims, fix_rows=None, alloc_rows=None,
     node_requested=None, node_nzr=None, allocatable=None, valid=None,
-    compress=False,
 ):
     """The fixed `DELTA_ROW_BUCKET`-sized (indices, rows) scatter slots
     every steady-state dispatch carries in the single upload buffer.
@@ -385,16 +363,10 @@ def _delta_slot_pieces(
     ``svalid`` rides with the alloc scatter: membership churn retires /
     claims row slots in place, so the patched rows must also flip the
     device-resident valid mask (a retired slot with alloc zeroed is
-    still choosable by a zero-request pod unless valid drops).
-
-    ``compress`` ships the req/nzr delta rows packed int16 (the 'h'
-    layout kind) -- only the dispatch gate engages it, and only when
-    the row content is provably in range; the index/alloc slots stay
-    int32 (allocatable KiB routinely exceeds int16)."""
-    row_dt = np.int16 if compress else np.int32
+    still choosable by a zero-request pod unless valid drops)."""
     didx = np.full(DELTA_ROW_BUCKET, n_cap, dtype=np.int32)
-    dreq = np.zeros((DELTA_ROW_BUCKET, r_dims), dtype=row_dt)
-    dnzr = np.zeros((DELTA_ROW_BUCKET, 2), dtype=row_dt)
+    dreq = np.zeros((DELTA_ROW_BUCKET, r_dims), dtype=np.int32)
+    dnzr = np.zeros((DELTA_ROW_BUCKET, 2), dtype=np.int32)
     sidx = np.full(DELTA_ROW_BUCKET, n_cap, dtype=np.int32)
     salloc = np.zeros((DELTA_ROW_BUCKET, r_dims), dtype=np.int32)
     svalid = np.zeros(DELTA_ROW_BUCKET, dtype=np.int32)
@@ -559,26 +531,12 @@ class BatchScheduler(Scheduler):
         # same single-buffer + device-resident-carry + delta-scatter
         # machinery as the single-device path, through the sharded twin
         # (ops/assignment.make_mesh_packed_solver) with shard-local row
-        # scatters. KTPU_MESH_DELTA=0 restores the PR-5 counted
-        # full-upload fallback (the escape hatch the
-        # allow_scatter=False seam in _negotiate_device_state serves).
-        # Greedy mesh batches additionally solve on the shard_map'd
-        # PALLAS tier (PR 10, ops/assignment._mesh_shard_solver):
+        # scatters. Greedy mesh batches additionally solve on the
+        # shard_map'd PALLAS tier (PR 10, ops/assignment._mesh_shard_solver):
         # per-shard fused step + one best-of-shards combine per pod,
         # ladder [pallas, xla] with breaker fallback to the GSPMD twin;
         # KTPU_MESH_PALLAS=0 pins the twin-only behavior (predicate:
         # ops/assignment.mesh_pallas_candidate).
-        self.mesh_delta = (
-            mesh is not None
-            and os.environ.get("KTPU_MESH_DELTA", "1") != "0"
-        )
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            self._sh_node1 = NamedSharding(mesh, P("nodes"))
-            self._sh_node2 = NamedSharding(mesh, P("nodes", None))
-            self._sh_rows = NamedSharding(mesh, P(None, "nodes"))
-            self._sh_repl = NamedSharding(mesh, P())
         self.batches_solved = 0
         self.pods_solved_on_device = 0
         self.pods_fallback = 0
@@ -715,15 +673,6 @@ class BatchScheduler(Scheduler):
         self.max_inflight = MAX_INFLIGHT
         self.speculative_launches = 0
         self.speculative_rewinds = 0
-        # range-gated int16 carry compression (single-device basic
-        # solves): engaged per dispatch while every resident column sum
-        # provably stays inside the int16 guard band, so the narrowed
-        # carry is bit-exact. KTPU_CARRY_COMPRESS=0 pins the int32
-        # carry (the A/B knob).
-        self.carry_compress_enabled = (
-            mesh is None
-            and os.environ.get("KTPU_CARRY_COMPRESS", "1") != "0"
-        )
 
     # -- one batch ----------------------------------------------------------
 
@@ -1037,17 +986,6 @@ class BatchScheduler(Scheduler):
         with self._pending_cv:
             return any(not p.get("mirrored") for p in self._pending_q)
 
-    def _inflight_load16(self) -> int:
-        """Worst-case column load of every dispatched-but-unmirrored
-        batch: their deltas live in the device carry but not yet in the
-        shadow the compression range gate reads."""
-        with self._pending_cv:
-            return sum(
-                int(p.get("load16", 0))
-                for p in self._pending_q
-                if not p.get("mirrored")
-            )
-
     def _await_mirrors(self, timeout: float = 30.0) -> bool:
         """Block until every in-flight batch has mirrored its deltas
         into the shadow -- far cheaper than ``_drain_pending``, which
@@ -1095,7 +1033,6 @@ class BatchScheduler(Scheduler):
             return [TIER_PALLAS, TIER_XLA]
         if (
             self.mesh is not None
-            and self.mesh_delta
             and mesh_pallas_candidate(mode, n_cap, self.mesh)
         ):
             return [TIER_PALLAS, TIER_XLA]
@@ -1573,7 +1510,7 @@ class BatchScheduler(Scheduler):
 
     def _negotiate_device_state(
         self, nt, node_requested, node_nzr, overlaid,
-        allow_scatter, pending_exists, unmirrored_exists=None,
+        pending_exists, unmirrored_exists=None,
         assumed_seq=0,
     ):
         """Decide how this dispatch's node state reaches the device and
@@ -1594,9 +1531,7 @@ class BatchScheduler(Scheduler):
           not static_ok additionally re-uploads allocatable+valid.
 
         The mesh path rides the same scatters through the sharded twin
-        (each delta row lands on exactly one node shard);
-        ``allow_scatter=False`` is the KTPU_MESH_DELTA=0 escape hatch
-        that restores the PR-5 counted full-upload fallback.
+        (each delta row lands on exactly one node shard).
 
         ``unmirrored_exists`` is the speculative-chain relaxation: the
         membership-adopt and scatter-fix paths only need the device
@@ -1637,7 +1572,7 @@ class BatchScheduler(Scheduler):
                 member = self.tensor_cache.membership_rows_since(
                     ds.validated_epoch
                 )
-                if member.size and allow_scatter and unmirrored_exists:
+                if member.size and unmirrored_exists:
                     # churned slots cannot be reconciled while an
                     # UNMIRRORED batch is in flight: it may have placed
                     # onto a now-retired slot, and adopting host truth
@@ -1666,29 +1601,23 @@ class BatchScheduler(Scheduler):
                     and ds.req_dev is not None
                     and ds.req_shadow is not None
                 ):
-                    if member.size and not allow_scatter:
-                        carry = "dead"  # mesh: counted full upload
-                    else:
-                        if member.size:
-                            member_fix = self._adopt_membership_rows(
-                                member, node_requested, node_nzr
-                            )
-                        ok, div_rows, keep = self._explain_rows(
-                            nonmember, node_requested, node_nzr,
-                            assumed_seq,
+                    if member.size:
+                        member_fix = self._adopt_membership_rows(
+                            member, node_requested, node_nzr
                         )
-                        carry = "reuse" if ok else "diverged"
+                    ok, div_rows, keep = self._explain_rows(
+                        nonmember, node_requested, node_nzr,
+                        assumed_seq,
+                    )
+                    carry = "reuse" if ok else "diverged"
             static_full = (
-                not layout_ok
-                or alloc_rows.size > DELTA_ROW_BUCKET
-                or (alloc_rows.size > 0 and not allow_scatter)
+                not layout_ok or alloc_rows.size > DELTA_ROW_BUCKET
             )
             fix_rows = empty
             diverged = carry == "diverged"
             if diverged:
                 if (
-                    allow_scatter
-                    and not static_full
+                    not static_full
                     and div_rows.size <= DELTA_ROW_BUCKET
                     and keep == 0  # no pending delta touches a div row
                     and not unmirrored_exists
@@ -1781,55 +1710,6 @@ class BatchScheduler(Scheduler):
                 "sidx": empty,
                 "member": 0,
             }
-
-    def _compress_decision(
-        self, neg, constrained, overlaid, node_requested, node_nzr,
-        batch_load16,
-    ) -> bool:
-        """Engage the int16 carry for THIS dispatch only when it is
-        provably lossless: the largest resident column value (shadow
-        maxima post-negotiate, or the upload source on a cold path)
-        plus this batch's and every unmirrored in-flight batch's
-        worst-case column load must stay inside the int16 guard band.
-        Converts the resident carry on a mode flip (one tiny on-device
-        kernel each way, both warmed) and books the disengage reasons.
-        Constrained/overlaid dispatches always run uncompressed -- the
-        constrained ladder keeps its one-int32-signature contract."""
-        ds = self._dev
-        resident16 = (
-            ds.req_dev is not None
-            and getattr(ds.req_dev, "dtype", None) == jnp.int16
-        )
-        want = not constrained and not overlaid
-        if want:
-            with self._shadow_lock:
-                if neg["carry_ok"] and ds.req_shadow is not None:
-                    resident = max(
-                        int(ds.req_shadow.max(initial=0)),
-                        int(ds.nzr_shadow.max(initial=0)),
-                    )
-                else:
-                    resident = max(
-                        int(node_requested.max(initial=0)),
-                        int(node_nzr.max(initial=0)),
-                    )
-            load = batch_load16 + self._inflight_load16()
-            want = resident + load <= _CARRY_COMPRESS_CEILING
-            if not want and resident16:
-                metrics.carry_compress_disengages.inc(reason="range")
-        elif resident16:
-            metrics.carry_compress_disengages.inc(reason="mode")
-        if neg["carry_ok"] and ds.req_dev is not None:
-            if want and not resident16:
-                ds.req_dev, ds.nzr_dev = compress_carry(
-                    ds.req_dev, ds.nzr_dev
-                )
-            elif not want and resident16:
-                ds.req_dev, ds.nzr_dev = decompress_carry(
-                    ds.req_dev, ds.nzr_dev
-                )
-        metrics.carry_compressed.set(1.0 if want else 0.0)
-        return want
 
     def _dispatch_solve(
         self,
@@ -2365,7 +2245,6 @@ class BatchScheduler(Scheduler):
         ds = self._dev
         neg = self._negotiate_device_state(
             nt, node_requested, node_nzr, overlaid,
-            allow_scatter=self.mesh is None or self.mesh_delta,
             pending_exists=self._pending_exists(),
             unmirrored_exists=self._unmirrored_exists(),
             assumed_seq=assumed_seq,
@@ -2378,7 +2257,6 @@ class BatchScheduler(Scheduler):
             # ms) and renegotiate before paying a full pipeline drain
             retry = self._negotiate_device_state(
                 nt, node_requested, node_nzr, overlaid,
-                allow_scatter=self.mesh is None or self.mesh_delta,
                 pending_exists=self._pending_exists(),
                 unmirrored_exists=False,
                 assumed_seq=assumed_seq,
@@ -2417,470 +2295,291 @@ class BatchScheduler(Scheduler):
             "carry": carry_how,
             "carry_rows": delta_rows if carry_ok else int(nt.capacity),
         }
-        compress = False
-        batch_load16 = 0
-        if self.carry_compress_enabled:
-            batch_load16 = _batch_load16(req, nzr, b)
-            compress = self._compress_decision(
-                neg, constrained, overlaid, node_requested, node_nzr,
-                batch_load16,
-            )
-            if compress:
-                span.note(compressed=True)
-        if self.mesh is None or self.mesh_delta:
-            # single-buffer upload: the whole batch -- including a
-            # constrained batch's ~40 family count tensors -- rides ONE
-            # int32 buffer, re-sliced (and bitcast for float tensors)
-            # on device (ops/assignment.py solve_packed), so a dispatch
-            # is one host->device transfer instead of one per operand.
-            # Chosen on an earlier machine; one transfer versus many is
-            # not re-measured on this one (PERF.md "Decisions to
-            # re-measure"). On a mesh the buffer
-            # uploads replicated while the resident node state stays
-            # SHARDED over the node axis; the delta-scatter slots apply
-            # shard-locally in the sharded twin, so steady-state churn
-            # costs O(DELTA_ROW_BUCKET) on the link regardless of N
-            pieces = [
-                ("req", req),
-                ("nzr", nzr),
-                ("midx", midx),
-                ("active", active.astype(np.int32)),
-                # on a mesh the rows ship as a separate bool operand,
-                # column-sharded host-side (ops/host_masks.py) -- each
-                # shard uploads only its [U, N/P] mask columns
-                ("rows", mask_rows_upload(rows, self.mesh)),
-            ]
-            if not static_ok:
-                pieces.append(("alloc", nt.allocatable))
-                pieces.append(("valid", nt.valid.astype(np.int32)))
-            if not carry_ok:
-                if compress:
-                    # cold/refresh upload with the gate engaged: the
-                    # carry ships packed int16 ('h' kind, half the
-                    # link bytes) and stays int16 on device
-                    pieces.append(
-                        ("req_state", node_requested.astype(np.int16))
-                    )
-                    pieces.append(("nzr_state", node_nzr.astype(np.int16)))
-                else:
-                    pieces.append(("req_state", node_requested))
-                    pieces.append(("nzr_state", node_nzr))
-            else:
-                # steady state: the resident [N, R] tensors stay on
-                # device; only the changed-row scatter rides the buffer
-                pieces += _delta_slot_pieces(
-                    nt.capacity, nt.dims.num_dims,
-                    fix_rows=neg["didx"], alloc_rows=neg["sidx"],
-                    node_requested=node_requested, node_nzr=node_nzr,
-                    allocatable=nt.allocatable, valid=nt.valid,
-                    compress=compress,
-                )
-            if constrained:
-                from kubernetes_tpu.ops.assignment import ConstPiece
-
-                def fam_pieces(prefix, packed_arrs, noop_arrs):
-                    """Present families ride the buffer; absent ones
-                    become ConstPiece markers (free on-device constants
-                    instead of ~1MB of uploaded zeros/sentinels). On a
-                    MESH absent families ride as real zero arrays
-                    instead: every ConstPiece combo is its own layout
-                    (= its own multi-second GSPMD compile), and the
-                    mesh contract is ONE constrained jit signature per
-                    mesh shape -- the upload cost of the noop tensors
-                    is what the pre-delta mesh path always paid."""
-                    if packed_arrs is not None:
-                        for i, a in enumerate(packed_arrs):
-                            pieces.append((f"{prefix}{i}", np.asarray(a)))
-                    elif self.mesh is not None:
-                        for i, a in enumerate(noop_arrs):
-                            pieces.append((f"{prefix}{i}", np.asarray(a)))
-                    else:
-                        for i, a in enumerate(noop_arrs):
-                            pieces.append(
-                                (f"{prefix}{i}", ConstPiece.from_uniform(a))
-                            )
-
-                fam_pieces(
-                    "sp",
-                    pad_spread_tensors(spread, padded)
-                    if spread is not None else None,
-                    noop_spread_tensors(padded, nt.capacity),
-                )
-                fam_pieces(
-                    "af",
-                    pad_affinity_tensors(affinity, padded)
-                    if affinity is not None else None,
-                    noop_affinity_tensors(padded, nt.capacity),
-                )
-                fam_pieces(
-                    "sc",
-                    pad_score_tensors(score_batch, padded)
-                    if score_batch is not None else None,
-                    noop_score_tensors(padded, nt.capacity),
-                )
-            # pass None for pieces riding the buffer so the jit sees one
-            # stable signature per layout (a stale device ref would fork
-            # a needless compile variant)
-            solve_mode = "constrained" if constrained else self.solver_mode
-
-            def run_device(allow_pallas: bool):
-                if poison_key is not None:
-                    raise PoisonError(poison_key)
-                inj = get_injector()
-                if inj is not None:
-                    hang = inj.hang_seconds_maybe(
-                        FaultPoint.DEVICE_SOLVE_HANG
-                    )
-                    if hang > 0:
-                        time.sleep(hang)
-                    inj.raise_maybe(FaultPoint.DEVICE_SOLVE)
-                return solve_packed(
-                    pieces,
-                    ds.alloc_dev if static_ok else None,
-                    ds.valid_dev if static_ok else None,
-                    ds.req_dev if carry_ok else None,
-                    ds.nzr_dev if carry_ok else None,
-                    config=self.solver_config,
-                    mode=solve_mode,
-                    allow_pallas=allow_pallas,
-                    mesh=self.mesh,
-                    compress=compress,
-                )
-
-            def run_host_greedy():
-                if poison_key is not None:
-                    # the malformed row poisons the host replay too (it
-                    # packs from the same arrays); only the per-pod
-                    # sequential oracle fails it ALONE
-                    raise PoisonError(poison_key)
-                a, r_out, z_out = host_greedy_assign(
-                    nt.allocatable, node_requested, node_nzr, nt.valid,
-                    req, nzr, rows, midx, active,
-                    config=self.solver_config,
-                )
-                return a, r_out, z_out, None, None
-
-            attempts = [
-                (t, (lambda ap=(t == TIER_PALLAS): run_device(ap)))
-                for t in self._device_tiers(
-                    solve_mode, padded, nt.capacity, nt.dims.num_dims,
-                    u_padded,
-                )
-            ]
-            # the host tier needs host state that reflects EVERY
-            # placement; with batches in flight the device carry is
-            # ahead of node_requested, so the tier is only offered when
-            # nothing is pending (exhaustion with pending batches drains
-            # and redispatches from fresh host state instead)
-            if not constrained and not self._pending_exists():
-                attempts.append((TIER_HOST_GREEDY, run_host_greedy))
-            # pre-solve carry refs: the gang quorum fixup restores these
-            # to rewind a re-solved batch to its pre-batch device state
-            # without a re-upload (only exact when no row fixes rode
-            # this dispatch)
-            carry_in = (
-                (ds.req_dev, ds.nzr_dev)
-                if carry_ok and not neg["didx"].size
-                else None
-            )
-            try:
-                with flightrecorder.stage(
-                    "device_solve", span, totals, **carry_stats
-                ) as solving:
-                    tier, out = self.ladder.run(
-                        attempts, label=f"batch b={b}"
-                    )
-                    booked = self.ladder.booked_tier(tier)
-                    solving.set_metadata(tier=booked)
-                self._jit_watch.refresh()
-            except LadderExhausted as exhaust_err:
-                with self._shadow_lock:
-                    ds.invalidate_carry()
-                    # no jitted solve LANDED, so the booked upload /
-                    # scatter never became device state: un-book the
-                    # counters (a drain-and-redispatch would book the
-                    # batch again). A device tier that uploaded and then
-                    # failed still paid the link traffic; that cost is
-                    # attributed by solves_by_tier/breaker metrics, not
-                    # here -- state_uploads counts established state.
-                    if carry_ok:
-                        self.state_reuses -= 1
-                        self.delta_rows_uploaded -= int(
-                            neg["didx"].size + neg["sidx"].size
-                        )
-                        self.membership_row_patches -= neg["member"]
-                    else:
-                        self.state_uploads -= 1
-                    if neg["sidx"].size or not static_ok:
-                        # the alloc row patch / full static upload never
-                        # reached the device (no solve ran) but the
-                        # shadow already claims it: drop the resident
-                        # alloc so the next dispatch re-uploads instead
-                        # of trusting it
-                        ds.alloc_dev = None
-                        ds.valid_dev = None
-                if raise_on_exhaust:
-                    # bisection sub-solve: the caller owns this group's
-                    # disposition (split further or isolate)
-                    span.finish(routed="bisect_exhausted")
-                    raise
-                if self._pending_exists():
-                    # in-flight batches blocked the host tier: land them
-                    # (the committer's own recovery handles their
-                    # failures), then redo this dispatch from fresh host
-                    # state with the breakers now routing around the
-                    # sick tiers
-                    self._drain_pending()
-                    span.finish(routed="exhausted_redispatch")
-                    return self._dispatch_solve(
-                        solver_infos, pod_scheduling_cycle,
-                        inactive_uids=inactive_uids,
-                    )
-                return self._contain_exhausted_batch(
-                    solver_infos, pod_scheduling_cycle, span,
-                    inactive_uids,
-                    poisoned=isinstance(
-                        exhaust_err.__cause__, PoisonError
-                    ),
-                )
-            assignments_dev, req_out, nzr_out, alloc_out, valid_out = out
-            if tier == TIER_HOST_GREEDY:
-                # the host tier solved from host state and no jitted
-                # solve ran: undo any bookkeeping that assumed the
-                # device saw this dispatch (incl. the link-traffic
-                # counters -- no upload / row scatter actually happened)
-                with self._shadow_lock:
-                    if carry_ok:
-                        self.delta_rows_uploaded -= int(
-                            neg["didx"].size + neg["sidx"].size
-                        )
-                        self.membership_row_patches -= neg["member"]
-                    else:
-                        self.state_uploads -= 1
-                    if neg["sidx"].size or not static_ok:
-                        # alloc patch / full static upload never landed
-                        ds.alloc_dev = None
-                        ds.valid_dev = None
-                    if (
-                        carry_ok
-                        and not neg["didx"].size
-                        and not overlaid
-                        and ds.req_dev is not None
-                    ):
-                        # the host tier was only offered with nothing in
-                        # flight and a validated carry, so its input
-                        # state EQUALS the device carry: scatter-add its
-                        # own assignment output onto the resident state
-                        # (ops/assignment.apply_assignment_delta) and
-                        # keep the carry warm instead of dropping it
-                        ds.req_dev, ds.nzr_dev = apply_assignment_delta(
-                            ds.req_dev, ds.nzr_dev,
-                            np.asarray(
-                                assignments_dev, dtype=np.int32
-                            ),
-                            req, nzr,
-                        )
-                    else:
-                        ds.invalidate_carry()
-            else:
-                # a jitted solve LANDED: the booked upload / scatter is
-                # established device state -- mirror the internal
-                # counters into the (monotonic) Prometheus series now,
-                # when the booking is final (the host-tier / exhausted
-                # branches un-book the attributes and book nothing here)
-                if carry_ok:
-                    if neg["didx"].size or neg["sidx"].size:
-                        metrics.delta_rows_uploaded.inc(
-                            int(neg["didx"].size + neg["sidx"].size)
-                        )
-                else:
-                    metrics.state_uploads.inc()
-                    if self._device_lost_at is not None:
-                        self._note_device_rebuilt()
-                if compress:
-                    # link bytes the int16 packing kept off the wire
-                    # this dispatch (half of what the int32 form ships)
-                    metrics.carry_compress_bytes_saved.inc(
-                        2 * DELTA_ROW_BUCKET * (nt.dims.num_dims + 2)
-                        if carry_ok
-                        else 2 * (node_requested.size + node_nzr.size)
-                    )
-                if not static_ok:
-                    ds.alloc_dev, ds.valid_dev = alloc_out, valid_out
-                elif neg["sidx"].size:
-                    # the in-buffer scatter patched the resident alloc
-                    # (and, for membership churn, the valid mask); keep
-                    # the patched refs
-                    ds.alloc_dev, ds.valid_dev = alloc_out, valid_out
-                assignments_dev.copy_to_host_async()
-                if overlaid:
-                    ds.invalidate_carry()
-                else:
-                    ds.req_dev, ds.nzr_dev = req_out, nzr_out
-            if self.mesh is not None and tier == TIER_PALLAS:
-                self.mesh_shard_solves += 1
-            span.note(tier=booked)
-            return {
-                # the attempt's own name: its breaker guards the download
-                "tier": tier,
-                "carry_in": carry_in,
-                "span": span,
-                "solver_infos": list(solver_infos),
-                "has_required_anti": has_required_anti,
-                "has_ports": batch_ports,
-                "has_scoring_terms": has_scoring_terms,
-                "order": order,
-                "assignments_dev": assignments_dev,
-                "download": self._eager_download(assignments_dev),
-                "req": req,
-                "nzr": nzr,
-                "b": b,
-                "names": nt.names,
-                "num_nodes": nt.num_nodes,
-                "snapshot": snapshot,
-                "cycle": pod_scheduling_cycle,
-                "overlaid": overlaid,
-                "solve_timer": solve_timer,
-                "mask_rows": mask_rows,
-                "mask_index_solved": midx,
-                "load16": batch_load16,
-            }
-
-        # -- KTPU_MESH_DELTA=0 fallback: the PR-5 mesh path ----------------
-        # one batched host->device transfer for everything we must
-        # upload; every node-state change resolves as a counted full
-        # upload (allow_scatter=False above). Kept as the escape hatch
-        # for mesh shapes where the sharded-twin compile is suspect.
-        to_upload = [req, nzr, rows, midx, active]
-        shardings = None
-        if self.mesh is not None:
-            shardings = [
-                self._sh_repl, self._sh_repl, self._sh_rows,
-                self._sh_repl, self._sh_repl,
-            ]
+        # single-buffer upload: the whole batch -- including a
+        # constrained batch's ~40 family count tensors -- rides ONE
+        # int32 buffer, re-sliced (and bitcast for float tensors)
+        # on device (ops/assignment.py solve_packed), so a dispatch
+        # is one host->device transfer instead of one per operand.
+        # Chosen on an earlier machine; one transfer versus many is
+        # not re-measured on this one (PERF.md "Decisions to
+        # re-measure"). On a mesh the buffer
+        # uploads replicated while the resident node state stays
+        # SHARDED over the node axis; the delta-scatter slots apply
+        # shard-locally in the sharded twin, so steady-state churn
+        # costs O(DELTA_ROW_BUCKET) on the link regardless of N
+        pieces = [
+            ("req", req),
+            ("nzr", nzr),
+            ("midx", midx),
+            ("active", active.astype(np.int32)),
+            # on a mesh the rows ship as a separate bool operand,
+            # column-sharded host-side (ops/host_masks.py) -- each
+            # shard uploads only its [U, N/P] mask columns
+            ("rows", mask_rows_upload(rows, self.mesh)),
+        ]
         if not static_ok:
-            to_upload += [nt.allocatable, nt.valid]
-            if shardings is not None:
-                shardings += [self._sh_node2, self._sh_node1]
+            pieces.append(("alloc", nt.allocatable))
+            pieces.append(("valid", nt.valid.astype(np.int32)))
         if not carry_ok:
-            to_upload += [node_requested, node_nzr]
-            if shardings is not None:
-                shardings += [self._sh_node2, self._sh_node2]
-        if shardings is not None:
-            uploaded = jax.device_put(tuple(to_upload), tuple(shardings))
+            pieces.append(("req_state", node_requested))
+            pieces.append(("nzr_state", node_nzr))
         else:
-            uploaded = jax.device_put(tuple(to_upload))
-        it = iter(uploaded)
-        req_d, nzr_d, rows_d, midx_d, active_d = (
-            next(it), next(it), next(it), next(it), next(it)
-        )
-        if not static_ok:
-            ds.alloc_dev, ds.valid_dev = next(it), next(it)
-        if not carry_ok:
-            # shadow bookkeeping already reconciled by the handshake
-            # (_negotiate_device_state); the mesh path has no row-scatter
-            # variant, so every change resolves as a counted full upload
-            req_state_d, nzr_state_d = next(it), next(it)
-        else:
-            req_state_d, nzr_state_d = ds.req_dev, ds.nzr_dev
+            # steady state: the resident [N, R] tensors stay on
+            # device; only the changed-row scatter rides the buffer
+            pieces += _delta_slot_pieces(
+                nt.capacity, nt.dims.num_dims,
+                fix_rows=neg["didx"], alloc_rows=neg["sidx"],
+                node_requested=node_requested, node_nzr=node_nzr,
+                allocatable=nt.allocatable, valid=nt.valid,
+            )
+        if constrained:
+            from kubernetes_tpu.ops.assignment import ConstPiece
 
-        common_args = (
-            ds.alloc_dev, req_state_d, nzr_state_d, ds.valid_dev,
-            req_d, nzr_d, rows_d, midx_d, active_d,
-        )
-        try:
+            def fam_pieces(prefix, packed_arrs, noop_arrs):
+                """Present families ride the buffer; absent ones
+                become ConstPiece markers (free on-device constants
+                instead of ~1MB of uploaded zeros/sentinels). On a
+                MESH absent families ride as real zero arrays
+                instead: every ConstPiece combo is its own layout
+                (= its own multi-second GSPMD compile), and the
+                mesh contract is ONE constrained jit signature per
+                mesh shape -- the upload cost of the noop tensors
+                is what the pre-delta mesh path always paid."""
+                if packed_arrs is not None:
+                    for i, a in enumerate(packed_arrs):
+                        pieces.append((f"{prefix}{i}", np.asarray(a)))
+                elif self.mesh is not None:
+                    for i, a in enumerate(noop_arrs):
+                        pieces.append((f"{prefix}{i}", np.asarray(a)))
+                else:
+                    for i, a in enumerate(noop_arrs):
+                        pieces.append(
+                            (f"{prefix}{i}", ConstPiece.from_uniform(a))
+                        )
+
+            fam_pieces(
+                "sp",
+                pad_spread_tensors(spread, padded)
+                if spread is not None else None,
+                noop_spread_tensors(padded, nt.capacity),
+            )
+            fam_pieces(
+                "af",
+                pad_affinity_tensors(affinity, padded)
+                if affinity is not None else None,
+                noop_affinity_tensors(padded, nt.capacity),
+            )
+            fam_pieces(
+                "sc",
+                pad_score_tensors(score_batch, padded)
+                if score_batch is not None else None,
+                noop_score_tensors(padded, nt.capacity),
+            )
+        # pass None for pieces riding the buffer so the jit sees one
+        # stable signature per layout (a stale device ref would fork
+        # a needless compile variant)
+        solve_mode = "constrained" if constrained else self.solver_mode
+
+        def run_device(allow_pallas: bool):
             if poison_key is not None:
                 raise PoisonError(poison_key)
             inj = get_injector()
             if inj is not None:
-                inj.raise_maybe(FaultPoint.DEVICE_SOLVE)
-            with flightrecorder.stage(
-                "device_solve", span, totals, tier=TIER_XLA, **carry_stats
-            ):
-                assignments_dev, req_out, nzr_out = self._mesh_solve(
-                    common_args, spread, affinity, score_batch, padded, nt
+                hang = inj.hang_seconds_maybe(
+                    FaultPoint.DEVICE_SOLVE_HANG
                 )
+                if hang > 0:
+                    time.sleep(hang)
+                inj.raise_maybe(FaultPoint.DEVICE_SOLVE)
+            return solve_packed(
+                pieces,
+                ds.alloc_dev if static_ok else None,
+                ds.valid_dev if static_ok else None,
+                ds.req_dev if carry_ok else None,
+                ds.nzr_dev if carry_ok else None,
+                config=self.solver_config,
+                mode=solve_mode,
+                allow_pallas=allow_pallas,
+                mesh=self.mesh,
+            )
+
+        def run_host_greedy():
+            if poison_key is not None:
+                # the malformed row poisons the host replay too (it
+                # packs from the same arrays); only the per-pod
+                # sequential oracle fails it ALONE
+                raise PoisonError(poison_key)
+            a, r_out, z_out = host_greedy_assign(
+                nt.allocatable, node_requested, node_nzr, nt.valid,
+                req, nzr, rows, midx, active,
+                config=self.solver_config,
+            )
+            return a, r_out, z_out, None, None
+
+        attempts = [
+            (t, (lambda ap=(t == TIER_PALLAS): run_device(ap)))
+            for t in self._device_tiers(
+                solve_mode, padded, nt.capacity, nt.dims.num_dims,
+                u_padded,
+            )
+        ]
+        # the host tier needs host state that reflects EVERY
+        # placement; with batches in flight the device carry is
+        # ahead of node_requested, so the tier is only offered when
+        # nothing is pending (exhaustion with pending batches drains
+        # and redispatches from fresh host state instead)
+        if not constrained and not self._pending_exists():
+            attempts.append((TIER_HOST_GREEDY, run_host_greedy))
+        # pre-solve carry refs: the gang quorum fixup restores these
+        # to rewind a re-solved batch to its pre-batch device state
+        # without a re-upload (only exact when no row fixes rode
+        # this dispatch)
+        carry_in = (
+            (ds.req_dev, ds.nzr_dev)
+            if carry_ok and not neg["didx"].size
+            else None
+        )
+        try:
+            with flightrecorder.stage(
+                "device_solve", span, totals, **carry_stats
+            ) as solving:
+                tier, out = self.ladder.run(
+                    attempts, label=f"batch b={b}"
+                )
+                booked = self.ladder.booked_tier(tier)
+                solving.set_metadata(tier=booked)
             self._jit_watch.refresh()
-        except Exception as mesh_err:
+        except LadderExhausted as exhaust_err:
             with self._shadow_lock:
                 ds.invalidate_carry()
+                # no jitted solve LANDED, so the booked upload /
+                # scatter never became device state: un-book the
+                # counters (a drain-and-redispatch would book the
+                # batch again). A device tier that uploaded and then
+                # failed still paid the link traffic; that cost is
+                # attributed by solves_by_tier/breaker metrics, not
+                # here -- state_uploads counts established state.
+                if carry_ok:
+                    self.state_reuses -= 1
+                    self.delta_rows_uploaded -= int(
+                        neg["didx"].size + neg["sidx"].size
+                    )
+                    self.membership_row_patches -= neg["member"]
+                else:
+                    self.state_uploads -= 1
+                if neg["sidx"].size or not static_ok:
+                    # the alloc row patch / full static upload never
+                    # reached the device (no solve ran) but the
+                    # shadow already claims it: drop the resident
+                    # alloc so the next dispatch re-uploads instead
+                    # of trusting it
+                    ds.alloc_dev = None
+                    ds.valid_dev = None
             if raise_on_exhaust:
-                # bisection sub-solve on the legacy mesh path: the
-                # caller owns the group's disposition
+                # bisection sub-solve: the caller owns this group's
+                # disposition (split further or isolate)
                 span.finish(routed="bisect_exhausted")
                 raise
-            self._drain_pending()
-            if isinstance(mesh_err, PoisonError):
-                # the legacy mesh path has no ladder, but a typed
-                # poison must still reach containment instead of
-                # storming the sequential floor on every retry
-                return self._contain_exhausted_batch(
-                    solver_infos, pod_scheduling_cycle, span,
-                    inactive_uids, poisoned=True,
+            if self._pending_exists():
+                # in-flight batches blocked the host tier: land them
+                # (the committer's own recovery handles their
+                # failures), then redo this dispatch from fresh host
+                # state with the breakers now routing around the
+                # sick tiers
+                self._drain_pending()
+                span.finish(routed="exhausted_redispatch")
+                return self._dispatch_solve(
+                    solver_infos, pod_scheduling_cycle,
+                    inactive_uids=inactive_uids,
                 )
-            # untyped persistent mesh failure (ROADMAP item 6a): the
-            # FIRST fall for this batch keeps the transient-tolerant
-            # sequential floor below, but an identical batch falling
-            # again is a crash loop -- route it through the containment
-            # disposition (which books exhausted_crashloops and forces
-            # bisection / quarantine) instead of storming the floor on
-            # every retry
-            if self._note_exhaust_sig(solver_infos):
-                logger.warning(
-                    "legacy mesh solve failed repeatedly for the same "
-                    "%d-pod batch; engaging containment",
-                    len(solver_infos),
-                )
-                return self._contain_exhausted_batch(
-                    solver_infos, pod_scheduling_cycle, span,
-                    inactive_uids, poisoned=False,
-                )
-            # otherwise: no pallas/host tier distinction -- a failed
-            # sharded solve steps straight down to the sequential oracle
-            logger.exception("mesh solve failed; sequential fallback")
-            metrics.solver_fallbacks.inc(
-                tier=TIER_SEQUENTIAL, reason="mesh_solve_error"
+            return self._contain_exhausted_batch(
+                solver_infos, pod_scheduling_cycle, span,
+                inactive_uids,
+                poisoned=isinstance(
+                    exhaust_err.__cause__, PoisonError
+                ),
             )
-            flightrecorder.mark(
-                "fallback", tier=TIER_SEQUENTIAL,
-                reason="mesh_solve_error",
-            )
-            span.finish(tier=TIER_SEQUENTIAL, routed="mesh_solve_error")
-            self.ladder.record_sequential(len(solver_infos))
-            for pi in solver_infos:
-                self.pods_fallback += 1
-                self.attempt_schedule(pi)
-            return None
-        # no ladder ran, and the ledger still says what solved the batch
-        self.ladder.record_xla()
-        if not carry_ok:
-            metrics.state_uploads.inc()
-            if self._device_lost_at is not None:
-                self._note_device_rebuilt()
-        # start the result transfer now so it overlaps host commit work
-        assignments_dev.copy_to_host_async()
-        if overlaid:
-            # nominee reservations are virtual: the post-scan state
-            # includes them, so it must not become the carry
-            ds.invalidate_carry()
+        assignments_dev, req_out, nzr_out, alloc_out, valid_out = out
+        if tier == TIER_HOST_GREEDY:
+            # the host tier solved from host state and no jitted
+            # solve ran: undo any bookkeeping that assumed the
+            # device saw this dispatch (incl. the link-traffic
+            # counters -- no upload / row scatter actually happened)
+            with self._shadow_lock:
+                if carry_ok:
+                    self.delta_rows_uploaded -= int(
+                        neg["didx"].size + neg["sidx"].size
+                    )
+                    self.membership_row_patches -= neg["member"]
+                else:
+                    self.state_uploads -= 1
+                if neg["sidx"].size or not static_ok:
+                    # alloc patch / full static upload never landed
+                    ds.alloc_dev = None
+                    ds.valid_dev = None
+                if (
+                    carry_ok
+                    and not neg["didx"].size
+                    and not overlaid
+                    and ds.req_dev is not None
+                ):
+                    # the host tier was only offered with nothing in
+                    # flight and a validated carry, so its input
+                    # state EQUALS the device carry: scatter-add its
+                    # own assignment output onto the resident state
+                    # (ops/assignment.apply_assignment_delta) and
+                    # keep the carry warm instead of dropping it
+                    ds.req_dev, ds.nzr_dev = apply_assignment_delta(
+                        ds.req_dev, ds.nzr_dev,
+                        np.asarray(
+                            assignments_dev, dtype=np.int32
+                        ),
+                        req, nzr,
+                    )
+                else:
+                    ds.invalidate_carry()
         else:
-            ds.req_dev, ds.nzr_dev = req_out, nzr_out
-
-        span.note(tier=TIER_XLA)
+            # a jitted solve LANDED: the booked upload / scatter is
+            # established device state -- mirror the internal
+            # counters into the (monotonic) Prometheus series now,
+            # when the booking is final (the host-tier / exhausted
+            # branches un-book the attributes and book nothing here)
+            if carry_ok:
+                if neg["didx"].size or neg["sidx"].size:
+                    metrics.delta_rows_uploaded.inc(
+                        int(neg["didx"].size + neg["sidx"].size)
+                    )
+            else:
+                metrics.state_uploads.inc()
+                if self._device_lost_at is not None:
+                    self._note_device_rebuilt()
+            if not static_ok:
+                ds.alloc_dev, ds.valid_dev = alloc_out, valid_out
+            elif neg["sidx"].size:
+                # the in-buffer scatter patched the resident alloc
+                # (and, for membership churn, the valid mask); keep
+                # the patched refs
+                ds.alloc_dev, ds.valid_dev = alloc_out, valid_out
+            assignments_dev.copy_to_host_async()
+            if overlaid:
+                ds.invalidate_carry()
+            else:
+                ds.req_dev, ds.nzr_dev = req_out, nzr_out
+        if self.mesh is not None and tier == TIER_PALLAS:
+            self.mesh_shard_solves += 1
+        span.note(tier=booked)
         return {
-            "tier": TIER_XLA,  # mesh solves are plain XLA lowerings
-            "carry_in": (
-                (req_state_d, nzr_state_d) if carry_ok else None
-            ),
+            # the attempt's own name: its breaker guards the download
+            "tier": tier,
+            "carry_in": carry_in,
             "span": span,
-            "download": self._eager_download(assignments_dev),
-            # copy: the caller's list is cleared after dispatch returns
             "solver_infos": list(solver_infos),
             "has_required_anti": has_required_anti,
             "has_ports": batch_ports,
             "has_scoring_terms": has_scoring_terms,
             "order": order,
             "assignments_dev": assignments_dev,
+            "download": self._eager_download(assignments_dev),
             "req": req,
             "nzr": nzr,
             "b": b,
@@ -2899,10 +2598,7 @@ class BatchScheduler(Scheduler):
     def _note_exhaust_sig(self, solver_infos: List[PodInfo]) -> bool:
         """Track the exhausted-batch uid signature; True when the SAME
         batch has now fallen whole at least twice in a row (a retry
-        storm, not a transient). Shared by the ladder path and the
-        legacy KTPU_MESH_DELTA=0 mesh path (ROADMAP item 6a: an untyped
-        persistent mesh failure used to fall whole to the sequential
-        floor on EVERY retry without ever tripping the detector)."""
+        storm, not a transient)."""
         sig = frozenset(
             pi.pod.metadata.uid for pi in solver_infos
         )
@@ -3412,47 +3108,6 @@ class BatchScheduler(Scheduler):
             # the dispatcher/committer (measured ~10% slower end-to-end)
             return None
         return _EagerDownload(assignments_dev)
-
-    def _mesh_solve(
-        self, common_args, spread, affinity, score_batch, padded, nt
-    ):
-        """One sharded solve on the mesh (unconstrained or constrained);
-        factored out of _dispatch_solve so the caller can guard it."""
-        if spread is None and affinity is None and score_batch is None:
-            solver = (
-                sinkhorn_assign
-                if self.solver_mode == "sinkhorn"
-                else greedy_assign_compact
-            )
-            return solver(*common_args, config=self.solver_config)
-        # the packers saw the pods already in solve order
-        if spread is not None:
-            sp_tensors = pad_spread_tensors(spread, padded)
-        else:
-            sp_tensors = noop_spread_tensors(padded, nt.capacity)
-        if affinity is not None:
-            af_tensors = pad_affinity_tensors(affinity, padded)
-        else:
-            af_tensors = noop_affinity_tensors(padded, nt.capacity)
-        if score_batch is not None:
-            sc_tensors = pad_score_tensors(score_batch, padded)
-        else:
-            sc_tensors = noop_score_tensors(padded, nt.capacity)
-        # common_args carries (mask_rows, mask_index) in compact form;
-        # the constrained kernel takes the same layout
-        if self.mesh is not None:
-            # constraint tensors are small: replicate on the mesh
-            sp_dev, af_dev, sc_dev = jax.device_put(
-                (sp_tensors, af_tensors, sc_tensors), self._sh_repl
-            )
-        else:
-            sp_dev, af_dev, sc_dev = jax.device_put(
-                (sp_tensors, af_tensors, sc_tensors)
-            )
-        return greedy_assign_constrained(
-            *common_args, tuple(sp_dev), tuple(af_dev), tuple(sc_dev),
-            config=self.solver_config,
-        )
 
     def _complete_solve(self, p) -> None:
         """Download the assignments, mirror the scan's node-state deltas
@@ -4485,129 +4140,76 @@ class BatchScheduler(Scheduler):
     def _warmup_at(self, nt, padded: int, full: bool) -> None:
         n = nt.capacity
         r = nt.dims.num_dims
-        if self.mesh is not None and self.mesh_delta:
+        if self.mesh is not None:
             self._warmup_mesh_packed(nt, padded, full)
             return
-        host = (
+        common = jax.device_put((
             nt.allocatable, nt.requested, nt.non_zero_requested, nt.valid,
             np.zeros((padded, r), dtype=np.int32),
             np.zeros((padded, 2), dtype=np.int32),
             np.zeros((MASK_ROW_BUCKET, n), dtype=bool),
             np.zeros(padded, dtype=np.int32),
             np.zeros(padded, dtype=bool),
-        )
-        if self.mesh is not None:
-            common = jax.device_put(
-                host,
-                (
-                    self._sh_node2, self._sh_node2, self._sh_node2,
-                    self._sh_node1, self._sh_repl, self._sh_repl,
-                    self._sh_rows, self._sh_repl, self._sh_repl,
-                ),
-            )
-        else:
-            common = jax.device_put(host)
+        ))
         if self.solver_mode == "sinkhorn":
             out = sinkhorn_assign(*common, config=self.solver_config)
             jax.block_until_ready(out)
         out = greedy_assign_compact(*common, config=self.solver_config)
         jax.block_until_ready(out)
-        if self.mesh is None:
-            # compile every packed-upload layout the run loop can hit:
-            # cold (static+carry ride the buffer), carry-refresh, and
-            # steady-state carry-reuse
-            base = [
-                ("req", np.zeros((padded, r), dtype=np.int32)),
-                ("nzr", np.zeros((padded, 2), dtype=np.int32)),
-                ("midx", np.zeros(padded, dtype=np.int32)),
-                ("active", np.zeros(padded, dtype=np.int32)),
-                ("rows", np.zeros((MASK_ROW_BUCKET, n), dtype=np.int32)),
-            ]
-            static_pieces = [
-                ("alloc", np.zeros((n, r), dtype=np.int32)),
-                ("valid", np.zeros(n, dtype=np.int32)),
-            ]
-            carry_pieces = [
-                ("req_state", np.zeros((n, r), dtype=np.int32)),
-                ("nzr_state", np.zeros((n, 2), dtype=np.int32)),
-            ]
-            # steady-state dispatches always carry the (indices, rows)
-            # delta-scatter slots (empty slots drop on device), so the
-            # run loop hits exactly ONE steady signature per mode
-            delta_slots = _delta_slot_pieces(n, r)
-            cold = solve_packed(
-                base + static_pieces + carry_pieces, None, None, None, None,
-                config=self.solver_config, mode=self.solver_mode,
-            )
-            jax.block_until_ready(cold)
-            _, _, _, alloc_d, valid_d = cold
-            refresh = solve_packed(
-                base + carry_pieces, alloc_d, valid_d, None, None,
-                config=self.solver_config, mode=self.solver_mode,
-            )
-            jax.block_until_ready(refresh)
-            _, req_d, nzr_d, _, _ = refresh
-            steady = solve_packed(
+        # compile every packed-upload layout the run loop can hit:
+        # cold (static+carry ride the buffer), carry-refresh, and
+        # steady-state carry-reuse
+        base = [
+            ("req", np.zeros((padded, r), dtype=np.int32)),
+            ("nzr", np.zeros((padded, 2), dtype=np.int32)),
+            ("midx", np.zeros(padded, dtype=np.int32)),
+            ("active", np.zeros(padded, dtype=np.int32)),
+            ("rows", np.zeros((MASK_ROW_BUCKET, n), dtype=np.int32)),
+        ]
+        static_pieces = [
+            ("alloc", np.zeros((n, r), dtype=np.int32)),
+            ("valid", np.zeros(n, dtype=np.int32)),
+        ]
+        carry_pieces = [
+            ("req_state", np.zeros((n, r), dtype=np.int32)),
+            ("nzr_state", np.zeros((n, 2), dtype=np.int32)),
+        ]
+        # steady-state dispatches always carry the (indices, rows)
+        # delta-scatter slots (empty slots drop on device), so the
+        # run loop hits exactly ONE steady signature per mode
+        delta_slots = _delta_slot_pieces(n, r)
+        cold = solve_packed(
+            base + static_pieces + carry_pieces, None, None, None, None,
+            config=self.solver_config, mode=self.solver_mode,
+        )
+        jax.block_until_ready(cold)
+        _, _, _, alloc_d, valid_d = cold
+        refresh = solve_packed(
+            base + carry_pieces, alloc_d, valid_d, None, None,
+            config=self.solver_config, mode=self.solver_mode,
+        )
+        jax.block_until_ready(refresh)
+        _, req_d, nzr_d, _, _ = refresh
+        steady = solve_packed(
+            base + delta_slots, alloc_d, valid_d, req_d, nzr_d,
+            config=self.solver_config, mode=self.solver_mode,
+        )
+        jax.block_until_ready(steady)
+        # measured per-pad solve cost (post-compile): feeds the
+        # AutoBatchController rung-ladder calibration, so the rungs
+        # reflect what THIS cluster shape actually pays per pad.
+        # Median of 3 -- a single sample absorbing a GC pause would
+        # prune a rung on one run and keep it on the next, making
+        # the ladder (and the controller trajectory) nondeterministic
+        samples = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(solve_packed(
                 base + delta_slots, alloc_d, valid_d, req_d, nzr_d,
                 config=self.solver_config, mode=self.solver_mode,
-            )
-            jax.block_until_ready(steady)
-            # measured per-pad solve cost (post-compile): feeds the
-            # AutoBatchController rung-ladder calibration, so the rungs
-            # reflect what THIS cluster shape actually pays per pad.
-            # Median of 3 -- a single sample absorbing a GC pause would
-            # prune a rung on one run and keep it on the next, making
-            # the ladder (and the controller trajectory) nondeterministic
-            samples = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                jax.block_until_ready(solve_packed(
-                    base + delta_slots, alloc_d, valid_d, req_d, nzr_d,
-                    config=self.solver_config, mode=self.solver_mode,
-                ))
-                samples.append(time.perf_counter() - t0)
-            self.pad_solve_seconds[padded] = sorted(samples)[1]
-            if self.carry_compress_enabled:
-                # compressed-carry signatures (ISSUE 18): the range
-                # gate can engage/disengage mid-run, so the int16
-                # variants of the cold/refresh/steady basic layouts --
-                # plus the on-device convert kernels the mode flips run
-                # -- must all be warm, or the first engage pays a
-                # mid-run compile the jit-cache watchdog would flag
-                carry16 = [
-                    ("req_state", np.zeros((n, r), dtype=np.int16)),
-                    ("nzr_state", np.zeros((n, 2), dtype=np.int16)),
-                ]
-                delta16 = _delta_slot_pieces(n, r, compress=True)
-                cold16 = solve_packed(
-                    base + static_pieces + carry16, None, None, None,
-                    None, config=self.solver_config,
-                    mode=self.solver_mode, compress=True,
-                )
-                jax.block_until_ready(cold16)
-                refresh16 = solve_packed(
-                    base + carry16, alloc_d, valid_d, None, None,
-                    config=self.solver_config, mode=self.solver_mode,
-                    compress=True,
-                )
-                jax.block_until_ready(refresh16)
-                _, req16, nzr16, _, _ = refresh16
-                steady16 = solve_packed(
-                    base + delta16, alloc_d, valid_d, req16, nzr16,
-                    config=self.solver_config, mode=self.solver_mode,
-                    compress=True,
-                )
-                jax.block_until_ready(steady16)
-                jax.block_until_ready(compress_carry(req_d, nzr_d))
-                jax.block_until_ready(decompress_carry(req16, nzr16))
-                # the host-greedy tier's carry keep-warm with an int16
-                # resident carry (dtype-preserving delta apply)
-                jax.block_until_ready(apply_assignment_delta(
-                    req16, nzr16,
-                    np.full(padded, NO_NODE, dtype=np.int32),
-                    np.zeros((padded, r), dtype=np.int32),
-                    np.zeros((padded, 2), dtype=np.int32),
-                ))
+            ))
+            samples.append(time.perf_counter() - t0)
+        self.pad_solve_seconds[padded] = sorted(samples)[1]
         if not full:
             # extra (latency-rung) pads warm the basic path only
             return
@@ -4616,53 +4218,45 @@ class BatchScheduler(Scheduler):
             noop_affinity_tensors(padded, n),
             noop_score_tensors(padded, n),
         )
-        if self.mesh is not None:
-            sp_dev, af_dev, sc_dev = jax.device_put(noops, self._sh_repl)
-            out = greedy_assign_constrained(
-                *common, tuple(sp_dev), tuple(af_dev), tuple(sc_dev),
-                config=self.solver_config,
-            )
-            jax.block_until_ready(out)
-        else:
-            if n > CONSTRAINED_NODE_CAP:
-                return  # constrained batches route to the host path
-            # compile the packed constrained layouts the run loop can hit
-            # (cold / carry-refresh / steady), mirroring the basic-path
-            # variants above -- a first constrained batch must not pay a
-            # multi-second XLA compile inside the measured window
-            fam = (
-                [(f"sp{i}", np.asarray(a)) for i, a in enumerate(noops[0])]
-                + [(f"af{i}", np.asarray(a)) for i, a in enumerate(noops[1])]
-                + [(f"sc{i}", np.asarray(a)) for i, a in enumerate(noops[2])]
-            )
-            c_cold = solve_packed(
-                base + static_pieces + carry_pieces + fam,
-                None, None, None, None,
+        if n > CONSTRAINED_NODE_CAP:
+            return  # constrained batches route to the host path
+        # compile the packed constrained layouts the run loop can hit
+        # (cold / carry-refresh / steady), mirroring the basic-path
+        # variants above -- a first constrained batch must not pay a
+        # multi-second XLA compile inside the measured window
+        fam = (
+            [(f"sp{i}", np.asarray(a)) for i, a in enumerate(noops[0])]
+            + [(f"af{i}", np.asarray(a)) for i, a in enumerate(noops[1])]
+            + [(f"sc{i}", np.asarray(a)) for i, a in enumerate(noops[2])]
+        )
+        c_cold = solve_packed(
+            base + static_pieces + carry_pieces + fam,
+            None, None, None, None,
+            config=self.solver_config, mode="constrained",
+        )
+        jax.block_until_ready(c_cold)
+        c_refresh = solve_packed(
+            base + carry_pieces + fam, alloc_d, valid_d, None, None,
+            config=self.solver_config, mode="constrained",
+        )
+        jax.block_until_ready(c_refresh)
+        c_steady = solve_packed(
+            base + delta_slots + fam, alloc_d, valid_d, req_d, nzr_d,
+            config=self.solver_config, mode="constrained",
+        )
+        jax.block_until_ready(c_steady)
+        # family-combo layouts: warm the steady-carry variant of
+        # every combo a measured phase can hit (the triple is
+        # already warmed by c_cold/refresh/steady)
+        fam_groups = {"sp": noops[0], "af": noops[1], "sc": noops[2]}
+        for live in _FAMILY_COMBOS[:-1]:
+            out_one = solve_packed(
+                base + delta_slots + _family_pieces(fam_groups, live),
+                alloc_d, valid_d, req_d, nzr_d,
                 config=self.solver_config, mode="constrained",
             )
-            jax.block_until_ready(c_cold)
-            c_refresh = solve_packed(
-                base + carry_pieces + fam, alloc_d, valid_d, None, None,
-                config=self.solver_config, mode="constrained",
-            )
-            jax.block_until_ready(c_refresh)
-            c_steady = solve_packed(
-                base + delta_slots + fam, alloc_d, valid_d, req_d, nzr_d,
-                config=self.solver_config, mode="constrained",
-            )
-            jax.block_until_ready(c_steady)
-            # family-combo layouts: warm the steady-carry variant of
-            # every combo a measured phase can hit (the triple is
-            # already warmed by c_cold/refresh/steady)
-            fam_groups = {"sp": noops[0], "af": noops[1], "sc": noops[2]}
-            for live in _FAMILY_COMBOS[:-1]:
-                out_one = solve_packed(
-                    base + delta_slots + _family_pieces(fam_groups, live),
-                    alloc_d, valid_d, req_d, nzr_d,
-                    config=self.solver_config, mode="constrained",
-                )
-                jax.block_until_ready(out_one)
-            self._pallas_canary(nt, padded, fam_groups)
+            jax.block_until_ready(out_one)
+        self._pallas_canary(nt, padded, fam_groups)
 
     def _pallas_canary(self, nt, padded: int, fam_groups: dict) -> None:
         """Hold every Pallas specialization warm-up just compiled to the

@@ -1,9 +1,8 @@
 """Placement of JAX's persistent compilation cache.
 
 Warm-up compiles dozens of solver signatures (cold / refresh / steady
-layouts x int32 / int16 carry x pad rungs x constrained family combos),
-and a fresh process otherwise pays all of them again. Every entry point
-calls ``configure_compile_cache()`` before its first compile:
+layouts x pad rungs x family combos), and a fresh process pays them all
+again. Every entry point calls ``configure_compile_cache()`` first:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself and
   the cache lives there; no directory is set in code.

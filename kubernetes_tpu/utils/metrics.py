@@ -748,9 +748,7 @@ taint_evictions = registry.register(Counter(
 
 # pipelined speculative dispatch (ISSUE 18): batch N+1 solves against
 # the committer's shadow expectation while batch N is still committing;
-# a commit-divergence rewinds only the divergent batch. The carry
-# compression families book the int16 resident-carry A/B
-# (KTPU_CARRY_COMPRESS=0 pins the int32 behavior)
+# a commit-divergence rewinds only the divergent batch
 speculative_launches = registry.register(Counter(
     "scheduler_speculative_launches_total",
     "Solves dispatched speculatively against the shadow-expected carry "
@@ -764,23 +762,6 @@ speculative_rewinds = registry.register(Counter(
     "mirror_wait = the dispatcher paused for in-flight mirrors before "
     "renegotiating; drain = the chain fell back to a full pipeline "
     "drain + redispatch.",
-    ("reason",),
-))
-carry_compressed = registry.register(Gauge(
-    "scheduler_tpu_carry_compressed",
-    "1 while the device-resident req/nzr carry is held int16 (the "
-    "range-gated lossless compression engaged), else 0.",
-))
-carry_compress_bytes_saved = registry.register(Counter(
-    "scheduler_tpu_carry_compress_bytes_saved_total",
-    "Host-to-device link bytes saved by shipping req/nzr state and "
-    "row deltas packed int16 instead of int32.",
-))
-carry_compress_disengages = registry.register(Counter(
-    "scheduler_tpu_carry_compress_disengages_total",
-    "Compressed-carry disengagements, by reason: range = a column sum "
-    "approached the int16 ceiling; mode = the dispatch needed an "
-    "uncompressed variant (constrained ladder, mesh, host tier).",
     ("reason",),
 ))
 

@@ -50,7 +50,6 @@ def _cluster(n):
 
 def _negotiate(sched, nt, **kw):
     kw.setdefault("overlaid", False)
-    kw.setdefault("allow_scatter", True)
     kw.setdefault("pending_exists", False)
     return sched._negotiate_device_state(
         nt, nt.requested, nt.non_zero_requested, **kw
@@ -377,25 +376,6 @@ class TestHandshake:
         neg = _negotiate(sched, nt)
         assert not neg["static_ok"] and not neg["carry_ok"]
         assert sched.state_uploads == 2
-
-    def test_mesh_mode_full_upload_fallback(self, sched_stack):
-        """allow_scatter=False (the multichip path): any change resolves
-        as a counted full upload, never a scatter."""
-        sched = sched_stack
-        cache, snap = _cluster(5)
-        pod = make_pod("m").node("hs-0").container(cpu="1").obj()
-        cache.add_pod(pod)
-        cache.update_snapshot(snap)
-        nt = sched.tensor_cache.update(snap)
-        _prime(sched, nt)
-        cache.remove_pod(pod)
-        cache.update_snapshot(snap)
-        nt = sched.tensor_cache.update(snap)
-        neg = _negotiate(sched, nt, allow_scatter=False)
-        assert not neg["carry_ok"]
-        assert neg["didx"].size == 0 and neg["sidx"].size == 0
-        assert sched.state_uploads == 2
-        assert sched.carry_divergences == 1
 
 
 class TestTensorDeltaMembership:

@@ -1,7 +1,7 @@
 """Tier-1 perf guard (fast smoke): the device path must carry a basic
 burst AND a CSI-PV burst with ZERO host fallbacks, so a host-path cliff
 (the 54 pods/s SchedulingCSIPVs regression shape) fails CI loudly
-instead of silently degrading BENCHMARKS.json."""
+instead of silently degrading the perf matrix."""
 
 import time
 

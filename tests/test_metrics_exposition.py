@@ -211,15 +211,11 @@ class TestMetricsEndpointE2E:
         assert "scheduler_bind_ack_suspect_nodes_tainted_total" in body
         assert "scheduler_node_heartbeat_lapses_total" in body
         assert "scheduler_taint_evictions_total" in body
-        # pipelined speculative dispatch + carry compression (ISSUE 18):
-        # the rewind ledger and the compression engage/disengage state
+        # pipelined speculative dispatch (ISSUE 18): the rewind ledger
         # must be scrapeable even at zero samples (HELP/TYPE emit
         # unconditionally) so dashboards can alert on rewind storms
         assert "scheduler_speculative_launches_total" in body
         assert "scheduler_speculative_rewinds_total" in body
-        assert "scheduler_tpu_carry_compressed" in body
-        assert "scheduler_tpu_carry_compress_bytes_saved_total" in body
-        assert "scheduler_tpu_carry_compress_disengages_total" in body
         # and the quantile gauge carries a real estimate post-burst
         p99 = metrics.pod_to_bind_quantile.value(q="0.99")
         assert p99 > 0.0

@@ -11,9 +11,8 @@ committing. This suite pins the whole contract:
   rewind ledger (``speculative_rewinds``) stays bounded, and the
   uid-keyed watch-history replay proves exactly-once binds per
   incarnation (zero double-binds);
-- the int16 carry-compression differential: a cluster sized inside the
-  lossless range gate places bit-identically with
-  KTPU_CARRY_COMPRESS=1 and =0, and matches the oracle.
+- a cluster of pods small enough to fit int16 still rides the int32
+  resident carry and matches the oracle.
 """
 
 import random
@@ -208,61 +207,35 @@ def test_one_bind_conflict_bounded_rewinds_exactly_once_binds():
     assert len(bind_count) == len(pods)
 
 
-class TestCarryCompressionDifferential:
-    """Randomized placement-parity differential for the int16 resident
-    carry: a cluster whose per-node KiB/milliCPU totals sit inside the
-    lossless range gate must place bit-identically with the compressed
-    carry, the int32 carry (KTPU_CARRY_COMPRESS=0), and the sequential
-    oracle."""
+def test_small_pod_carry_is_int32_and_matches_oracle():
+    """Pods small enough that a whole batch sums under 32,767 KiB (24 Mi
+    nodes, 512 Ki - 1 Mi pods, batches of 16) still ride the int32
+    resident carry, and land where the sequential oracle puts them."""
+    rng = random.Random(11)
+    specs = [
+        (rng.choice([50, 100, 150]), rng.choice([512, 1024]))
+        for _ in range(48)
+    ]
 
-    def _small_unit_pods(self, num, seed):
-        # 1Mi = 1024 KiB per pod: 24 pods saturate a 24Mi node at
-        # exactly the 24576 ceiling, so the gate stays engaged for the
-        # whole run and compression is lossless by construction
-        rng = random.Random(seed)
-        out = []
-        for i in range(num):
-            out.append(
-                make_pod(f"c{i}")
-                .creation_timestamp(float(i))
-                .container(
-                    cpu=f"{rng.choice([50, 100, 150])}m",
-                    memory=f"{rng.choice([512, 1024])}Ki",
-                )
-                .obj()
-            )
-        return out
+    def mk():
+        return [
+            make_pod(f"c{i}")
+            .creation_timestamp(float(i))
+            .container(cpu=f"{cpu}m", memory=f"{mem}Ki")
+            .obj()
+            for i, (cpu, mem) in enumerate(specs)
+        ]
 
-    def _run_mode(self, pods, monkeypatch, flag):
-        # max_batch=16: the range gate bounds a batch by its TOTAL load
-        # (any assignment is possible), so 16 x 1024 KiB stays inside
-        # the 24576 ceiling and the early batches run compressed; the
-        # gate then disengages as the resident carry fills, which
-        # exercises the lossless mode-flip conversion too
-        monkeypatch.setenv("KTPU_CARRY_COMPRESS", flag)
-        return _run(
-            pods, batch=True, nodes=40, node_cpu="4",
-            node_mem="24Mi", max_batch=16, slow_commit=0.01,
-        )
-
-    def test_placement_parity_compressed_vs_int32_vs_oracle(
-        self, monkeypatch
-    ):
-        mk = lambda: self._small_unit_pods(300, seed=11)  # noqa: E731
-        want, _o, _ = _run(
-            mk(), batch=False, nodes=40, node_cpu="4", node_mem="24Mi",
-        )
-        assert all(want.values())
-
-        on, sched_on, _ = self._run_mode(mk(), monkeypatch, "1")
-        off, sched_off, _ = self._run_mode(mk(), monkeypatch, "0")
-
-        assert sched_on.carry_compress_enabled
-        assert not sched_off.carry_compress_enabled
-        assert on == want, "compressed carry diverged from the oracle"
-        assert off == want, "int32 carry diverged from the oracle"
-        assert sched_on.carry_divergences == 0
-        assert sched_on.pods_fallback == 0
-        # the compressed run actually ran compressed (bytes were saved)
-        # -- a silently-disengaged gate would pass parity trivially
-        assert metrics.carry_compress_bytes_saved.value() > 0
+    cluster = dict(nodes=40, node_cpu="4", node_mem="24Mi")
+    want, _o, _ = _run(mk(), batch=False, **cluster)
+    assert all(want.values())
+    got, sched, _ = _run(
+        mk(), batch=True, max_batch=16, chunk=16, slow_commit=0.01,
+        **cluster,
+    )
+    assert got == want
+    assert sched.carry_divergences == 0
+    assert sched.pods_fallback == 0
+    ds = sched._dev
+    assert ds.req_dev.dtype == np.int32
+    assert ds.nzr_dev.dtype == np.int32

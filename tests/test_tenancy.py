@@ -706,79 +706,6 @@ class TestQuarantineRelist:
             informers.stop()
 
 
-class TestLegacyMeshCrashLoop:
-    def test_untyped_persistent_mesh_failure_trips_detector(
-        self, monkeypatch
-    ):
-        """ROADMAP item 6a: on the KTPU_MESH_DELTA=0 legacy mesh path,
-        an untyped persistent mesh failure falls whole to the
-        sequential floor ONCE; the identical batch failing again trips
-        the crash-loop detector and routes to containment (bisection /
-        quarantine) instead of storming the floor on every retry."""
-        import jax
-        from jax.sharding import Mesh
-
-        from kubernetes_tpu.framework.interface import PodInfo
-        from kubernetes_tpu.utils import metrics
-
-        monkeypatch.setenv("KTPU_MESH_DELTA", "0")
-        server = APIServer()
-        client = Client(server)
-        informers = InformerFactory(server)
-        mesh = Mesh(
-            np.array(jax.devices()[:1]), axis_names=("nodes",)
-        )
-        sched = new_scheduler(
-            client, informers, batch=True, max_batch=64, mesh=mesh
-        )
-        assert sched.mesh_delta is False
-        client.create_node(
-            make_node("n0").capacity(cpu="1", memory="1Gi").obj()
-        )
-        try:
-            informers.start()
-            informers.wait_for_cache_sync()
-            sched.queue.run()
-
-            def boom(*_a, **_k):
-                raise RuntimeError("persistent untyped mesh failure")
-
-            monkeypatch.setattr(sched, "_mesh_solve", boom)
-            # two pods that also fail the sequential oracle (no
-            # capacity), so the same batch re-enters
-            infos = [
-                PodInfo(
-                    _pod_in("default", f"m{i}", cpu="8000m"), float(i)
-                )
-                for i in range(2)
-            ]
-            for pi in infos:
-                client.create_pod(pi.pod)
-            informers.pump()
-            seq0 = metrics.solver_fallbacks.value(
-                tier="sequential", reason="mesh_solve_error"
-            )
-            loops0 = metrics.exhausted_crashloops.value()
-            # first fall: the transient-tolerant sequential floor
-            assert sched._dispatch_solve(list(infos), 0) is None
-            assert metrics.solver_fallbacks.value(
-                tier="sequential", reason="mesh_solve_error"
-            ) == seq0 + 1
-            assert metrics.exhausted_crashloops.value() == loops0
-            # the identical batch falling again is a crash loop:
-            # containment takes it (bisection isolates the members into
-            # quarantine holds), the floor is NOT hit a second time
-            assert sched._dispatch_solve(list(infos), 0) is None
-            assert metrics.exhausted_crashloops.value() >= loops0 + 1
-            assert metrics.solver_fallbacks.value(
-                tier="sequential", reason="mesh_solve_error"
-            ) == seq0 + 1
-            assert sched.quarantine.isolations >= 1
-        finally:
-            sched.stop()
-            informers.stop()
-
-
 class _HalfCoord:
     """Stub partition coordinator owning an explicit node set (queue-
     side responsibility stays open: these tests only exercise the

@@ -106,18 +106,14 @@ Prints ONE JSON line:
                          raw per-span / per-mark op costs the tier-1
                          self-time guard multiplies out,
    "spec_{serial,pipelined}_ms" / "spec_overlap_x" / "spec_launches" /
-   "spec_conflict_rewinds" / "spec_conflict_rewind_rate" /
-   "carry_full_bytes_{i32,i16}" / "carry_delta_bytes_{i32,i16}" /
-   "carry_link_ratio_x":
+   "spec_conflict_rewinds" / "spec_conflict_rewind_rate":
                          the ISSUE-18 pipelined speculative dispatch:
                          an identical seeded burst at 5k nodes through
                          the RETIRED serial solve->commit path vs the
                          double-buffered pipeline (committer overlapped
-                         with the next speculative solve), the rewind
-                         rate under a seeded bind-conflict sprinkle,
-                         and the resident-carry link/HBM payload int32
-                         vs packed int16 (full upload + steady delta
-                         slot)}
+                         with the next speculative solve) and the
+                         rewind rate under a seeded bind-conflict
+                         sprinkle}
 
 Usage: python tools/bench_hotpath.py [bench_speculative]
        [--pods 10000] [--nodes 5000]
@@ -1604,11 +1600,7 @@ def bench_speculative(num_nodes: int = 5000, num_pods: int = 2000):
       carry);
     - conflict sprinkle: the pipelined path under seeded BIND_CONFLICT
       faults -- reports how many speculative links the divergences
-      rewound (the cheap row-patch re-solve, not a drain).
-
-    Plus the carry-compression link/HBM payload at this node scale:
-    the int32 resident carry vs the packed-int16 'h' piece, for the
-    cold full upload and the steady DELTA_ROW_BUCKET slot."""
+      rewound (the cheap row-patch re-solve, not a drain)."""
     import random as _random
     import time as _time
 
@@ -1622,7 +1614,6 @@ def bench_speculative(num_nodes: int = 5000, num_pods: int = 2000):
         PointConfig,
         install_injector,
     )
-    from kubernetes_tpu.scheduler.batch import DELTA_ROW_BUCKET
     from kubernetes_tpu.scheduler.scheduler import new_scheduler
     from kubernetes_tpu.testing import make_node, make_pod
 
@@ -1690,16 +1681,6 @@ def bench_speculative(num_nodes: int = 5000, num_pods: int = 2000):
     pipe_ms, launches, _ = run_arm(serial=False, conflicts=False)
     _, c_launches, c_rewinds = run_arm(serial=False, conflicts=True)
 
-    # carry payloads: what the host-device link ships (and HBM holds) per
-    # variant. int16 packs two values per int32 word ('h' piece), so
-    # the byte count is exactly half at even sizes
-    from kubernetes_tpu.tensors.node_tensor import ResourceDims
-
-    r = ResourceDims().num_dims
-    full_i32 = num_nodes * (r + 2) * 4
-    full_i16 = num_nodes * (r + 2) * 2
-    delta_i32 = DELTA_ROW_BUCKET * (r + 2) * 4
-    delta_i16 = DELTA_ROW_BUCKET * (r + 2) * 2
     return {
         "spec_serial_ms": serial_ms,
         "spec_pipelined_ms": pipe_ms,
@@ -1710,11 +1691,6 @@ def bench_speculative(num_nodes: int = 5000, num_pods: int = 2000):
         "spec_conflict_rewind_rate": (
             c_rewinds / c_launches if c_launches else 0.0
         ),
-        "carry_full_bytes_i32": full_i32,
-        "carry_full_bytes_i16": full_i16,
-        "carry_delta_bytes_i32": delta_i32,
-        "carry_delta_bytes_i16": delta_i16,
-        "carry_link_ratio_x": full_i32 / full_i16,
     }
 
 
