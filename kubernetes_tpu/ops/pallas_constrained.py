@@ -4,10 +4,12 @@ The XLA lowering of ops/assignment.greedy_assign_constrained executes a
 large fused-op chain per pod step (spread skew checks, three affinity
 count families, five score families with per-step normalizes), most of
 it per-op dispatch (VERDICT r3 weak #2: PodAntiAffinity far slower than
-basic). This kernel fuses the ENTIRE constrained step
+basic). This kernel fuses the whole constrained step
 into one pallas_call: every count tensor lives in VMEM for the whole
 batch, and a fori_loop runs fit + spread + affinity + all score families
-+ masked argmax + every replay update with no per-op dispatch.
++ masked argmax + every replay update with no per-op dispatch, one step
+a pod up to the batch's last active slot (pallas_solver.live_steps) and
+none for the padding behind it.
 
 Key design moves (vs the value-space XLA formulation):
 
@@ -56,7 +58,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kubernetes_tpu.ops.assignment import GreedyConfig, row_node_values
-from kubernetes_tpu.ops.pallas_solver import COMPILER_PARAMS
+from kubernetes_tpu.ops.pallas_solver import (
+    COMPILER_PARAMS,
+    chunk_steps,
+    live_steps,
+    no_node_behind,
+)
 from kubernetes_tpu.ops.scores import MAX_NODE_SCORE, _EPS
 from kubernetes_tpu.tensors.node_tensor import NUM_FIXED_DIMS, PODS
 
@@ -598,7 +605,8 @@ def _constrained_kernel(
             ipaw_ref[:, :] = ipaw_ref[:, :] + ipa_bump * vi_ok * same_v
         return 0
 
-    jax.lax.fori_loop(0, chunk, body, 0)
+    # flags[6]: live_steps of the whole batch
+    jax.lax.fori_loop(0, chunk_steps(flags_ref[6], chunk), body, 0)
 
 
 def _dense_limit(slot_groups, slot_skew, slot_self, g_cap):
@@ -881,11 +889,13 @@ def pallas_constrained_solve(
     put("sel_match", sc_pod_sel_match, g_sel)
 
     ipa_live = (sc_ipa_node_value[:rp or 1] >= 0).any() if rp else False
+    n_live = live_steps(active)
     flags = jnp.concatenate(
         [
             sc_weights[:5].astype(jnp.int32),
             jnp.asarray(ipa_live, dtype=jnp.int32)[None],
-            jnp.zeros((2,), dtype=jnp.int32),
+            n_live,
+            jnp.zeros((1,), dtype=jnp.int32),
         ]
     )
 
@@ -1020,7 +1030,7 @@ def pallas_constrained_solve(
         name="pallas_constrained_solve",
         interpret=interpret,
     )(*args)
-    asg = outs[oidx["asg"]]
+    asg = no_node_behind(outs[oidx["asg"]], n_live)
     req_out_t = outs[oidx["req"]]
     nzr_out_t = outs[oidx["nzr"]]
     return asg, req_out_t.T, nzr_out_t.T

@@ -3,9 +3,11 @@
 
 The XLA lax.scan lowering of the solver executes ~10 separate vector
 ops per pod step, while the actual VPU work per step is a few [R, N]
-passes. This kernel runs the ENTIRE solve as ONE pallas_call: node
-state lives in VMEM for the whole batch and a fori_loop fuses fit +
-score + masked argmax + state update per step with no per-op dispatch.
+passes. This kernel runs the solve as ONE pallas_call: node state
+lives in VMEM for the whole batch and a fori_loop fuses fit + score +
+masked argmax + state update per step with no per-op dispatch. The loop
+runs one step a pod up to the batch's last active slot (``live_steps``)
+and no step for the padding behind it.
 
 Layouts are transposed to [R, N] / [2, N] / [1, B] so the lane axis is
 the node/pod axis (128-multiple by construction: NodeTensor capacity
@@ -141,7 +143,32 @@ def _step_fit_score_argmax(
     return feasible, best, choice
 
 
+def live_steps(active: jnp.ndarray) -> jnp.ndarray:
+    """[1] int32: the index of the last True of ``active`` plus one, 0
+    when there is none. The packer writes a batch's pods as a prefix of
+    the padded arrays, so every slot from here on is padding: its step
+    would place nothing and leave the state as it was, and the kernels
+    do not run it."""
+    slots = jnp.arange(1, active.shape[0] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(active, slots, 0), keepdims=True)
+
+
+def chunk_steps(n_live, chunk: int):
+    """In a kernel: the steps of this grid step's chunk that lie inside
+    the live prefix. A chunk wholly past it runs none, and leaves its
+    block of the assignments unwritten (``no_node_behind``)."""
+    return jnp.clip(n_live - pl.program_id(0) * chunk, 0, chunk)
+
+
+def no_node_behind(assignments: jnp.ndarray, n_live: jnp.ndarray):
+    """NO_NODE from the live prefix's end on: an SMEM block that no step
+    wrote holds whatever was there."""
+    slots = jnp.arange(assignments.shape[0], dtype=jnp.int32)
+    return jnp.where(slots < n_live, assignments, -1)
+
+
 def _solver_kernel(
+    nlive_ref,     # SMEM [1] int32: live_steps of the WHOLE batch
     midx_ref,      # SMEM [B] int32: static-mask row per pod
     podreq_ref,    # SMEM [B*R] int32 (per-pod scalars, row-major flat)
     podnzr_ref,    # SMEM [B*2] int32
@@ -208,7 +235,7 @@ def _solver_kernel(
             )
         return 0
 
-    jax.lax.fori_loop(0, chunk, body, 0)
+    jax.lax.fori_loop(0, chunk_steps(nlive_ref[0], chunk), body, 0)
 
 
 def _shard_candidate_kernel(
@@ -365,6 +392,7 @@ def pallas_greedy_solve(
     def whole(i):
         return (0, 0)
 
+    n_live = live_steps(active)
     asg, req_out_t, nzr_out_t = pl.pallas_call(
         kernel,
         grid=grid,
@@ -374,6 +402,7 @@ def pallas_greedy_solve(
             jax.ShapeDtypeStruct((2, n), jnp.int32),
         ),
         in_specs=[
+            pl.BlockSpec((1,), lambda i: (0,), memory_space=pltpu.SMEM),
             pl.BlockSpec((chunk,), chunk_1d, memory_space=pltpu.SMEM),
             pl.BlockSpec((chunk * r,), chunk_1d, memory_space=pltpu.SMEM),
             pl.BlockSpec((chunk * 2,), chunk_1d, memory_space=pltpu.SMEM),
@@ -397,6 +426,7 @@ def pallas_greedy_solve(
         name="pallas_greedy_solve",
         interpret=interpret,
     )(
+        n_live,
         mask_index.astype(jnp.int32),
         pod_requests.astype(jnp.int32).reshape(-1),
         pod_nzr.astype(jnp.int32).reshape(-1),
@@ -407,4 +437,4 @@ def pallas_greedy_solve(
         valid.astype(jnp.int32)[None, :],
         mask_rows.astype(jnp.int32),
     )
-    return asg, req_out_t.T, nzr_out_t.T
+    return no_node_behind(asg, n_live), req_out_t.T, nzr_out_t.T

@@ -2294,6 +2294,9 @@ class BatchScheduler(Scheduler):
             "devices": 1 if self.mesh is None else int(self.mesh.devices.size),
             "carry": carry_how,
             "carry_rows": delta_rows if carry_ok else int(nt.capacity),
+            # the pods of the batch: the steps a one-chip kernel runs of
+            # the ``padded`` that ``sched/dispatch`` says
+            "steps": int(b),
         }
         # single-buffer upload: the whole batch -- including a
         # constrained batch's ~40 family count tensors -- rides ONE

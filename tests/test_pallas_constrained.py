@@ -16,6 +16,7 @@ from kubernetes_tpu.ops.affinity import (
     pad_affinity_tensors,
 )
 from kubernetes_tpu.ops.assignment import (
+    NO_NODE,
     GreedyConfig,
     greedy_assign_constrained,
 )
@@ -33,6 +34,7 @@ from kubernetes_tpu.ops.topology import (
 )
 from kubernetes_tpu.tensors import NodeTensorCache, pack_pod_batch
 from kubernetes_tpu.testing import make_node, make_pod
+from test_pallas_solver import PARTIAL_BATCHES, last_active, partial_active
 
 MASK_ROW_BUCKET = 8
 POD_BUCKET = 64
@@ -122,14 +124,14 @@ def _batch(rng, b=24):
     return out
 
 
-def _packed_problem(seed):
+def _packed_problem(seed, b=24, n_nodes=24):
     """Mirror batch.py _dispatch_solve's packing for a constrained batch
     (no nominees, no gangs)."""
     rng = random.Random(seed)
-    existing, nodes = _cluster(rng)
+    existing, nodes = _cluster(rng, n_nodes)
     snap = new_snapshot(existing, nodes)
     nt = NodeTensorCache().update(snap)
-    pods = _batch(rng)
+    pods = _batch(rng, b)
 
     batch = pack_pod_batch(pods, nt.dims)
     mask_rows, mask_index = static_mask_compact(pods, snap, nt)
@@ -267,3 +269,60 @@ def test_constrained_kernel_zero_caps_matches_basic():
     np.testing.assert_array_equal(np.asarray(a1), np.asarray(a2))
     np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
     np.testing.assert_array_equal(np.asarray(z1), np.asarray(z2))
+
+
+# -- partial batches: the step loop ends at the last active slot -------------
+# (the cases are tests/test_pallas_solver.py's)
+
+
+@pytest.fixture(scope="module")
+def full_batches():
+    """One packed batch a size, every slot a real pod with its family
+    rows: a case keeps a prefix of it active, so what a skipped step
+    would have read is a pod's data and not zeros."""
+    return {b: _packed_problem(3, b=b, n_nodes=96) for b in (256, 4096)}
+
+
+@pytest.mark.parametrize("b,prefix,cleared", PARTIAL_BATCHES)
+def test_partial_batch_matches_xla(full_batches, b, prefix, cleared):
+    common, sp_t, af_t, sc_t = full_batches[b]
+    active = partial_active(b, prefix, cleared)
+    n_live = last_active(active)
+    common = common[:8] + (active,)
+    caps = _derive_caps(sp_t, af_t, sc_t)
+    a1, r1, z1 = greedy_assign_constrained(
+        *common, sp_t, af_t, sc_t, config=GreedyConfig()
+    )
+    a2, r2, z2 = pallas_constrained_solve(
+        *common, sp_t, af_t, sc_t, config=GreedyConfig(),
+        interpret=True, caps=caps,
+    )
+    a2 = np.asarray(a2)
+    np.testing.assert_array_equal(np.asarray(a1), a2)
+    assert (a2[n_live:] == NO_NODE).all()
+    assert (a2[~active] == NO_NODE).all()
+    assert (a2[:n_live] != NO_NODE).any() or n_live == 0
+    np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
+    np.testing.assert_array_equal(np.asarray(z1), np.asarray(z2))
+    if n_live == 0:  # all padding: the state it was given
+        np.testing.assert_array_equal(np.asarray(r2), common[1])
+        np.testing.assert_array_equal(np.asarray(z2), common[2])
+
+
+def test_one_program_a_shape_whatever_the_batch_holds(full_batches):
+    """Where the batch ends is read on the device from ``active``: it is
+    no argument of the jitted solve, so a shape compiles once."""
+    common, sp_t, af_t, sc_t = full_batches[4096]
+    caps = _derive_caps(sp_t, af_t, sc_t)
+
+    def solve(n_live):
+        pallas_constrained_solve(
+            *common[:8], partial_active(4096, n_live, ()), sp_t, af_t, sc_t, config=GreedyConfig(),
+            interpret=True, caps=caps,
+        )
+
+    solve(4096)
+    programs = pallas_constrained_solve._cache_size()
+    for n_live in (0, 1, 42, 1023, 1024, 1025, 4095):
+        solve(n_live)
+    assert pallas_constrained_solve._cache_size() == programs

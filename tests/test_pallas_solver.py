@@ -9,7 +9,11 @@ lowering with bit-identical outputs.
 import numpy as np
 import pytest
 
-from kubernetes_tpu.ops.assignment import GreedyConfig, greedy_assign_compact
+from kubernetes_tpu.ops.assignment import (
+    NO_NODE,
+    GreedyConfig,
+    greedy_assign_compact,
+)
 from kubernetes_tpu.ops.pallas_solver import pallas_greedy_solve
 
 
@@ -135,3 +139,78 @@ def test_shard_candidate_kernel_matches_jnp_step(seed, cfg):
             )
         else:
             assert float(best_k) == best_t == float("-inf")
+
+
+# -- partial batches: the step loop ends at the last active slot -------------
+
+#: (b, live prefix, slots cleared inside it): the chunk edges of 1,024
+#: crossed from both sides at the cells' 4,096, the gang fix-up's cleared
+#: slots (the last of them trailing, so the loop ends before the prefix
+#: does), and the binary's default batch
+PARTIAL_BATCHES = [
+    pytest.param(4096, n_live, (), id=f"b4096-live{n_live}")
+    for n_live in (0, 1, 42, 1023, 1024, 1025, 4095, 4096)
+] + [
+    pytest.param(4096, 1500, (0, 7, 1023, 1024, 1400, 1498, 1499),
+                 id="b4096-live1500-cleared"),
+    pytest.param(256, 42, (), id="b256-live42"),
+]
+
+
+def partial_active(b, prefix, cleared):
+    active = np.zeros(b, bool)
+    active[:prefix] = True
+    active[list(cleared)] = False
+    return active
+
+
+def last_active(active):
+    return int(np.flatnonzero(active).max()) + 1 if active.any() else 0
+
+
+@pytest.mark.parametrize("b,prefix,cleared", PARTIAL_BATCHES)
+def test_partial_batch_matches_xla_scan(b, prefix, cleared):
+    args = list(_random_problem(5, n=128, b=b, r=4))
+    active = args[8] = partial_active(b, prefix, cleared)
+    n_live = last_active(active)
+    a1, r1, z1 = greedy_assign_compact(*args, config=GreedyConfig())
+    a2, r2, z2 = pallas_greedy_solve(
+        *args, config=GreedyConfig(), interpret=True
+    )
+    a2 = np.asarray(a2)
+    assert np.array_equal(np.asarray(a1), a2)
+    assert (a2[n_live:] == NO_NODE).all()
+    assert (a2[~active] == NO_NODE).all()
+    assert (a2[:n_live] != NO_NODE).any() or n_live == 0
+    assert np.array_equal(np.asarray(r1), np.asarray(r2))
+    assert np.array_equal(np.asarray(z1), np.asarray(z2))
+    if n_live == 0:  # all padding: the state it was given
+        assert np.array_equal(np.asarray(r2), args[1])
+        assert np.array_equal(np.asarray(z2), args[2])
+
+
+def test_one_program_a_shape_whatever_the_batch_holds():
+    """Where the batch ends is read on the device from ``active``: it is
+    no argument of the jitted solve, so a shape compiles once."""
+    from kubernetes_tpu.ops.assignment import jit_cache_sizes, solve_packed
+
+    args = list(_random_problem(5, n=128, b=4096, r=4))
+    names = ("alloc", "req_state", "nzr_state", "valid", "req", "nzr",
+             "rows", "midx", "active")
+
+    def solve(n_live):
+        args[8] = partial_active(4096, n_live, ())
+        pallas_greedy_solve(*args, config=GreedyConfig(), interpret=True)
+        # the packed solve around the kernel (on a CPU its XLA tier)
+        solve_packed(
+            [(k, np.asarray(a)) for k, a in zip(names, args)],
+            None, None, None, None,
+        )
+
+    solve(4096)
+    kernel_programs = pallas_greedy_solve._cache_size()
+    packed_programs = jit_cache_sizes()
+    for n_live in (0, 1, 42, 1023, 1024, 1025, 4095):
+        solve(n_live)
+    assert pallas_greedy_solve._cache_size() == kernel_programs
+    assert jit_cache_sizes() == packed_programs

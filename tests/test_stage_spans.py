@@ -432,6 +432,21 @@ def test_dispatch_spans_carry_the_rings_queue_waits(burst_trace):
     assert sum(s["pods"] for s in dispatches.values()) == 120
 
 
+def test_solve_dispatch_says_how_many_steps_the_batch_needs(burst_trace):
+    """``steps`` is the batch's pod count: what a one-chip kernel runs of
+    the ``padded`` its ``sched/dispatch`` span says, beside how the carry
+    was brought up to date."""
+    events, dump, _sched = burst_trace
+    solves = {s["stats"]["batch"]: s["stats"]
+              for s in named(events, "sched/solve_dispatch")}
+    assert len(solves) == len(dump["spans"])
+    for span in dump["spans"]:
+        stats = solves[span["batch_id"]]
+        assert stats["steps"] == span["size"] <= span["padded"]
+        assert {"tier", "devices", "carry", "carry_rows"} <= set(stats)
+    assert sum(s["steps"] for s in solves.values()) == 120
+
+
 def test_stage_seconds_keeps_its_keys_and_gains_the_new(burst_trace):
     _events, dump, sched = burst_trace
     seconds = sched.stage_seconds

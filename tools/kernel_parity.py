@@ -17,7 +17,12 @@ installed compiler:
 
 With every constraint family a no-op the constrained kernels must
 reproduce the basic scan exactly, so one cheap XLA reference judges
-every specialization. One JSON line per case; exits 1 if any case
+every specialization. Each case is solved twice by the one compiled
+program: the whole batch, and a partial one whose pods end a step past a
+quarter of it (``partial_live``: one step into the second SMEM chunk at
+b = 4,096, so the kernel's step loop ends inside a chunk and skips the
+chunks behind it; PR 30), where every slot from there on must answer
+NO_NODE. One JSON line per case; exits 1 if any case
 disagrees or fails to compile. The run loop's own guard is the warm-up
 canary (scheduler/batch.py ``_pallas_canary``), which applies the same
 comparison to the shapes a scheduler actually warms.
@@ -104,13 +109,27 @@ def main() -> int:
         nonlocal failures
         case = {"kernel": kernel, "case": tag, "n": args[0].shape[0],
                 "b": args[4].shape[0], "est_mib": round(est / (1 << 20), 2)}
+        b = case["b"]
+        live = case["partial_live"] = b // 4 + 1
+        partial = args[:8] + (np.arange(b) < live,)
         try:
-            got = np.asarray(jax.block_until_ready(solve(args))[0])
-            want = np.asarray(greedy_assign_compact(*args, config=config)[0])
-            case["mismatch"] = int((got != want).sum())
+            for key, problem_args in (
+                ("mismatch", args), ("partial_mismatch", partial),
+            ):
+                got = np.asarray(
+                    jax.block_until_ready(solve(problem_args))[0]
+                )
+                want = np.asarray(
+                    greedy_assign_compact(*problem_args, config=config)[0]
+                )
+                case[key] = int((got != want).sum())
+            case["partial_mismatch"] += int((got[live:] != -1).sum())
         except Exception as e:  # noqa: BLE001 - a refusal is a result
             case["error"] = f"{type(e).__name__}: {str(e)[:200]}"
-        failures += bool(case.get("mismatch") or case.get("error"))
+        failures += bool(
+            case.get("mismatch") or case.get("partial_mismatch")
+            or case.get("error")
+        )
         print(json.dumps(case), flush=True)
 
     # -- the basic kernel at its gate's edge, three (r, u) shapes --------
