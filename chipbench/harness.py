@@ -8,7 +8,10 @@ The program is driven as an operator drives it: ``load_config_from_dict``
 called: the operator's binary does not call it, so set-up here is what a
 cold start costs an operator. What the run reads from the program are
 its counters (``stage_seconds``, ``batches_solved``, ``solves_by_tier``,
-``pods_fallback`` and the like) and nothing else.
+``pods_fallback``, the preemptor's and the like) and nothing else.
+What decides ``correct`` is the comparisons the configuration names
+(``check.py``), found by name under ``checks/`` as generators and
+readers are.
 """
 
 from __future__ import annotations
@@ -39,6 +42,24 @@ HOST_KEY = "kubernetes.io/hostname"
 CREATE_CHUNK = 256
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TIERS = ("pallas", "xla", "host_greedy", "sequential")  # best first
+#: the keys of a pod class that ``Run.make_pods`` reads; a named
+#: comparison adds its own (``POD_CLASS_KEYS`` of its module)
+POD_CLASS_KEYS = ("cpu_milli", "memory_mib", "spread", "anti_affinity",
+                  "priority")
+#: the preemptor's counters that every run's ``Run.counters`` carries
+PREEMPTOR_COUNTERS = ("device_preemptions", "host_preemptions",
+                      "preempt_waves", "budget_denials")
+#: the program's tier ledgers by the name a configuration's
+#: ``expect_tiers`` gives them (``batch`` is the one ``expect_tier``
+#: names): where the ledger lives, and the counter of ``Run.counters``,
+#: if any, of the pods that fell through every tier of it
+LEDGERS = {
+    "batch": (lambda sched: sched.ladder.solves_by_tier, None),
+    "preempt_wave": (
+        lambda sched: sched.preemptor.ladder.solves_by_tier,
+        "host_preemptions",  # the host oracle books no tier
+    ),
+}
 
 
 class BenchError(Exception):
@@ -101,6 +122,7 @@ def load_cell(root: Path, workload: str, rehearsal: bool) -> dict:
     if rehearsal:
         config = _overlay(config, config.get("rehearsal", {}))
         mix = _overlay(mix, mix.get("rehearsal", {}))
+    check.validate(config)
 
     def applies(metric: dict) -> bool:
         return workload in metric.get("workloads", [workload])
@@ -147,6 +169,12 @@ class Run:
         #: replay's own source of truth
         self.created: dict = {}
         self.prebound: set = set()  # created bound; never scheduled
+        #: names this harness deleted itself (``delete``); a pod the
+        #: watch saw deleted that is not here left otherwise (``evicted``)
+        self.harness_deleted: set = set()
+        #: this run's counters as the window opened; the tier comparison
+        #: reads every ledger against them
+        self.window_counters: dict = {}
         self.due: dict = {}  # name -> perf_counter instant it was due
         self.issued: dict = {}  # name -> instant its create call began
         self.phases: list = []  # (name, start, end) host clock
@@ -274,6 +302,8 @@ class Run:
                     cls["anti_affinity"]["topology_key"], {"app": app},
                     anti=True,
                 )
+            if "priority" in cls:
+                w = w.priority(int(cls["priority"]))
             if selector:
                 w = w.node_selector(**selector)
             if node is not None:
@@ -283,16 +313,18 @@ class Run:
         return pods
 
     def create(self, pods: list, due: float = None, threads: int = 1,
-               chunk: int = CREATE_CHUNK) -> None:
+               chunk: int = CREATE_CHUNK, timed: bool = True) -> None:
         """Create ``pods`` through the API in bulk chunks. ``due`` is
         the instant they were due (default: now); each pod is timed from
-        it, not from its own create call."""
+        it, not from its own create call. ``timed=False`` keeps them out
+        of the window's pods: what a generator puts back between waves
+        and no user waits for."""
         start = time.perf_counter()
         dues = np.broadcast_to(start if due is None else due, (len(pods),))
         chunks = [pods[i:i + chunk] for i in range(0, len(pods), chunk)]
         for pod, t in zip(pods, dues.tolist()):
             self.due[pod.metadata.name] = t
-        if self.in_window:
+        if self.in_window and timed:
             self.window_names.extend(p.metadata.name for p in pods)
         lock = threading.Lock()
         errors = []
@@ -343,16 +375,17 @@ class Run:
         return snap
 
     def delete(self, names, timeout_s: float) -> None:
-        """Delete pods in bulk and wait until the scheduler's own cache
-        no longer counts them."""
+        """Delete pods in bulk and wait until the watch has seen each of
+        them go, by name, and the scheduler's own cache no longer counts
+        them."""
         before = self.sched.cache.pod_count()
-        seen = self.watcher.deleted
+        self.harness_deleted.update(names)  # before the first event
         keys = [("default", n) for n in names]
         gone = 0
         for i in range(0, len(keys), 1024):
             gone += self.client.delete_pods_bulk(keys[i:i + 1024])
         deadline = time.perf_counter() + timeout_s
-        self.watcher.wait_deleted(seen + gone, deadline)
+        self.watcher.wait_deleted(names, deadline)
         while self.sched.cache.pod_count() > before - gone:
             if time.perf_counter() > deadline:
                 raise BenchError(
@@ -360,6 +393,15 @@ class Run:
                     f"{timeout_s}s"
                 )
             time.sleep(0.005)
+
+    def evicted(self) -> dict:
+        """name -> instant the watch saw it deleted, of every pod that
+        left and that this harness did not delete: the client's side of
+        an eviction, with no counter of the program trusted for it."""
+        return {
+            name: t for name, t in list(self.watcher.deleted_time.items())
+            if name not in self.harness_deleted
+        }
 
     def latencies_ms(self) -> list:
         """due -> bind event of every pod of the window that was bound."""
@@ -393,12 +435,19 @@ class Run:
 
     def counters(self) -> dict:
         sched = self.sched
+        preemptor = sched.preemptor
         return {
             "t": time.perf_counter(),
             "stage_seconds": dict(sched.stage_seconds),
             "batches": int(sched.batches_solved),
-            "tiers": dict(sched.ladder.solves_by_tier),
+            "tiers": {name: dict(ledger(sched))
+                      for name, (ledger, _) in LEDGERS.items()},
             "pods_fallback": int(sched.pods_fallback),
+            # the preemptor's, in every run (0 where nothing preempts)
+            "device_preemptions": int(preemptor.device_preemptions),
+            "host_preemptions": int(preemptor.host_preemptions),
+            "preempt_waves": int(preemptor.waves),
+            "budget_denials": int(preemptor.budget_denials),
             "state_uploads": int(sched.state_uploads),
             "delta_rows_uploaded": int(sched.delta_rows_uploaded),
             "speculative_rewinds": int(sched.speculative_rewinds),
@@ -520,6 +569,12 @@ def print_window_notes(run: Run, start: dict, end: dict, pauses: list) -> None:
             "state_uploads", "delta_rows_uploaded", "speculative_rewinds",
             "conflict_requeues",
         )), flush=True)
+    preemptor = {key: end[key] - start[key] for key in PREEMPTOR_COUNTERS}
+    if any(preemptor.values()):
+        print("preemptor over the window: " + ", ".join(
+            f"{key} {value}" for key, value in preemptor.items()
+        ) + f", {len(run.evicted())} pods left that the harness did not "
+            "delete", flush=True)
 
 
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
@@ -576,7 +631,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                 )
 
         gc.callbacks.append(on_gc)
-        start = run.counters()
+        start = run.window_counters = run.counters()
         setup_s = time.perf_counter() - process_start
         run.in_window = True
         if slicer is not None:
@@ -596,28 +651,29 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         values, attempted, unbound = end_to_end(run, setup_s)
         fallback = end["pods_fallback"] - start["pods_fallback"]
         failed = unbound + fallback
+        below_all = ""
+        for ledger in check.expected_tiers(run.config):
+            # the configuration expects this ledger's work on the device:
+            # a pod that fell through every tier of it (the preemption
+            # wave's host oracle) got a slower answer, not the same one
+            floor = LEDGERS[ledger][1]
+            if floor is not None:
+                failed += end[floor] - start[floor]
+                below_all += f"{floor} {end[floor] - start[floor]}, "
         print_window_notes(run, start, end, pauses)
         print(f"window: {window_s:.3f}s, {attempted} pods due, {unbound} "
               f"not bound by their deadline, pods_fallback {fallback}, "
+              f"{below_all}"
               f"batches {end['batches'] - start['batches']}; " + ", ".join(
                   f"{name} {value:.1f}" for name, value in values.items()
               ), flush=True)
 
         with run.phase("check"):
             correct = check.run_checks(run, control)
-        after = run.counters()
         memory = memory_peak_bytes()
     finally:
         run.stop()
-
-    tiers = {t: after["tiers"].get(t, 0) - start["tiers"].get(t, 0)
-             for t in TIERS}
-    expect = cell["config"]["expect_tier"]
-    below = sum(tiers[t] for t in TIERS[TIERS.index(expect) + 1:])
-    tier_ok = tiers[expect] > 0 and below == 0
-    print(f"compare tier: batches by tier {tiers}, below {expect!r}: "
-          f"{below} (limit 0) -> {'ok' if tier_ok else 'FAILED'}", flush=True)
-    correct = correct and tier_ok and attempted > 0
+    correct = correct and attempted > 0
 
     device = dict(device, memory_peak_bytes=memory)
     line = {"correct": bool(correct), "attempted": attempted,

@@ -43,7 +43,7 @@ def main() -> int:
         for line in lines:
             if line.startswith(("compare", "control", "set-up", "window",
                                 "arrivals", "chipbench:", "device:", "gc in", "waves", "slow wave", "counters", "programs compiled",
-                                "check wave")):
+                                "check wave", "preempt", "refill")):
                 print("   " + line[:400])
         last = lines[-1] if lines else ""
         try:

@@ -4,7 +4,9 @@ create, reading bind events as the API serves them.
 Copied from ``bench.py``'s ``BindWatcher`` (PERF.md lists the original
 for deletion) and changed in what it records: the node of every bind and
 how many times each pod was bound, so that the check can hold "bound
-exactly once, on the node the watch reported".
+exactly once, on the node the watch reported"; and when each pod was
+seen deleted, by name, so that a comparison can tell the pods the
+harness deleted from those that left otherwise (evictions).
 """
 
 from __future__ import annotations
@@ -22,7 +24,7 @@ class BindWatcher:
         self.bind_time: dict = {}  # name -> perf_counter at the event
         self.bind_node: dict = {}  # name -> node of the first bind
         self.rebinds: list = []  # (name, first node, later node)
-        self.deleted = 0
+        self.deleted_time: dict = {}  # name -> perf_counter at DELETED
         self._cond = threading.Condition()
         self._stop = False
         self._thread = threading.Thread(
@@ -44,6 +46,12 @@ class BindWatcher:
                     for pod in pods:
                         if pod.spec.node_name:
                             self._note(pod, now)
+                    # a DELETED event the gap swallowed: a pod the watch
+                    # saw bound that the list no longer holds
+                    listed = {pod.metadata.name for pod in pods}
+                    for name in self.bind_time:
+                        if name not in listed:
+                            self.deleted_time.setdefault(name, now)
                     self._cond.notify_all()
                 continue
             if not events:
@@ -55,7 +63,9 @@ class BindWatcher:
                         if ev.object.spec.node_name:
                             self._note(ev.object, now)
                     elif ev.type == "DELETED":
-                        self.deleted += 1
+                        self.deleted_time.setdefault(
+                            ev.object.metadata.name, now
+                        )
                 self._cond.notify_all()
 
     def _note(self, pod, now: float) -> None:
@@ -80,14 +90,23 @@ class BindWatcher:
                 pending = [n for n in pending if n not in self.bind_time]
             return True
 
-    def wait_deleted(self, count: int, deadline: float) -> bool:
+    def wait_deleted(self, names, deadline: float) -> bool:
+        """True once every name has a DELETED event; by name, so that a
+        deletion of some other pod (an eviction) ends no wait early."""
+        names = list(names)
+        done = 0  # every name before this index has its event
         with self._cond:
-            while self.deleted < count:
+            while True:
+                # each name is looked up once, however often the watch
+                # wakes this wait: it holds the lock the watch needs
+                while done < len(names) and names[done] in self.deleted_time:
+                    done += 1
+                if done == len(names):
+                    return True
                 remaining = deadline - time.perf_counter()
                 if remaining <= 0:
                     return False
                 self._cond.wait(min(remaining, 0.25))
-            return True
 
     def stop(self) -> None:
         self._stop = True

@@ -6,6 +6,8 @@ copy that has gained a cell and metrics, so a rule that an addition
 cannot keep without editing what was there fails here, in the PR that
 writes it."""
 
+import contextlib
+import fcntl
 import json
 import re
 from pathlib import Path
@@ -26,6 +28,19 @@ NEW = [
 ]
 FIRST_CELLS = ["basic-5000.burst-10k", "spread-anti-5000.burst-5k",
                "basic-5000.arrivals-steady"]
+
+
+@contextlib.contextmanager
+def one_traced_run_at_a_time(root: Path):
+    """A traced run keeps its slice under ``<root>/.chipbench_trace/<cell>``
+    and clears that directory as it starts: right for the benchmark, which
+    runs one cell at a time in a checkout, and a collision for two test
+    files that trace one cell in the repo's own root from two workers.
+    They take this lock around the run."""
+    (root / ".chipbench_trace").mkdir(exist_ok=True)
+    with open(root / ".chipbench_trace" / "lock", "w") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        yield
 
 
 def line_ok(text) -> bool:
@@ -49,6 +64,14 @@ def on_chip_only(root: Path, names) -> set:
     read device events of a kernel or a collective, which only the chip's
     trace has, so a CPU rehearsal leaves them out of its line."""
     return {n for n in names if spec_of(root, n).get("on_chip_only") is True}
+
+
+def needs_something(root: Path, names) -> set:
+    """Those of ``names`` whose file says what a cell must have to
+    report them (``"needs": "family_pods"``): a cell without it gives
+    the reader nothing, so an addition joins their lists only where it
+    has it."""
+    return {n for n in names if "needs" in spec_of(root, n)}
 
 
 def top_level_shape(bench: dict, root: Path) -> None:
@@ -124,6 +147,8 @@ def metrics(bench: dict, root: Path) -> None:
             assert spec[key] == m[key], (m["name"], key)
         assert (root / "chipbench/readers" / f"{spec['reader']}.py").is_file()
         assert isinstance(spec.get("on_chip_only", False), bool)
+        if "needs" in spec:  # not every cell has it: the cells are named
+            assert line_ok(spec["needs"]) and "workloads" in m, m["name"]
         if m["name"].endswith("_roofline"):
             assert m["unit"] == "%"
     for m in bench["end_to_end"] + bench["per_layer"]:
@@ -136,8 +161,28 @@ def metrics(bench: dict, root: Path) -> None:
         assert any(cell in cells_of(bench, m) for m in bench["per_layer"])
 
 
+def comparisons(bench: dict, root: Path) -> None:
+    """Every comparison a configuration names (or gets for naming none)
+    has its file, at the cell's own size and at the rehearsal's, and
+    every tier ledger it names is one the harness knows."""
+    from chipbench import check, harness
+
+    for c in bench["configs"]:
+        body = json.loads((root / c["file"]).read_text())
+        for config in (body, harness._overlay(body, body["rehearsal"])):
+            names = check.names_of(config)
+            assert names[-1] == check.LAST_CHECK and len(set(names)) == len(names)
+            for name in names:
+                assert NAME.match(name), name
+                assert (root / "chipbench/checks" / f"{name}.py").is_file(), (
+                    c["file"], name)
+            for ledger, tier in check.expected_tiers(config).items():
+                assert ledger in harness.LEDGERS, (c["file"], ledger)
+                assert tier in harness.TIERS, (c["file"], tier)
+
+
 #: the rules of the file's structure, each ``rule(bench, root)``
-STRUCTURE = (top_level_shape, configs, workloads, metrics)
+STRUCTURE = (top_level_shape, configs, workloads, metrics, comparisons)
 
 
 def declared_since_pr24(bench: dict, root: Path, name: str) -> None:

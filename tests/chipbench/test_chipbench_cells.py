@@ -24,10 +24,11 @@ LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
 def run_cell(capsys, workload, trace, seed=2**31 + 7, extra=()):
-    rc = harness.main([
-        "--workload", workload, "--seed", str(seed), "--seconds", "1",
-        "--trace", str(trace), "--rehearsal", *extra,
-    ])
+    with rules.one_traced_run_at_a_time(ROOT):
+        rc = harness.main([
+            "--workload", workload, "--seed", str(seed), "--seconds", "1",
+            "--trace", str(trace), "--rehearsal", *extra,
+        ])
     out = capsys.readouterr().out.strip().splitlines()
     assert rc == 0, out[-20:]
     return json.loads(out[-1]), out
@@ -179,9 +180,11 @@ def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
     generator, a reader, a cell on four chips (a rehearsal does not look
     for chips), and two per-layer metrics appended at the end, of which
     one reads a kernel that only the chip's trace has. The cell joins
-    every ``workloads`` list but those of the one-chip kernels' metrics,
-    which it cannot report. The copy then has to keep every rule the
-    real file is held to, run, and hold no edited file."""
+    every ``workloads`` list but those of the one-chip kernels' metrics
+    and of metrics whose file names what a cell must have (``needs``:
+    family pods, which this plain cell has not), which it cannot report.
+    The copy then has to keep every rule the real file is held to, run,
+    and hold no edited file."""
     cell = "tiny-40.one-wave"
     copy = tmp_path / "checkout"
     copy.mkdir()
@@ -218,9 +221,9 @@ def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
             json.dumps(dict(metric, **rest))
         )
     bench = json.loads((copy / "BENCHMARK.json").read_text())
-    one_chip_kernels = rules.on_chip_only(
-        copy, [m["name"] for m in bench["per_layer"]]
-    )
+    listed = [m["name"] for m in bench["per_layer"]]
+    one_chip_kernels = rules.on_chip_only(copy, listed)
+    not_for_plain = one_chip_kernels | rules.needs_something(copy, listed)
     bench["configs"].append({
         "name": "tiny-40", "source": config["source"],
         "file": "chipbench/configs/tiny-40.json", "reduced": [], "why": "test",
@@ -230,7 +233,7 @@ def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
         "why": "test",
     })
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m and m["name"] not in one_chip_kernels:
+        if "workloads" in m and m["name"] not in not_for_plain:
             m["workloads"].append(cell)
     bench["per_layer"] += [counted, kernel]
     (copy / "BENCHMARK.json").write_text(json.dumps(bench))
@@ -252,7 +255,7 @@ def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
     assert line["metrics"]["waves_run"] == {"value": 1.0, "unit": "count"}
     declared = {m["name"] for m in bench["per_layer"]
                 if cell in rules.cells_of(bench, m)}
-    assert declared & one_chip_kernels == set()
+    assert declared & not_for_plain == set()
     assert set(line["metrics"]) == declared - {kernel["name"]}
     assert "window against the reference: not compared" in proc.stdout
     for path, body in before.items():  # no file that was there was edited

@@ -246,7 +246,10 @@ def test_solve_dispatch_says_how_the_carry_was_brought_up_to_date(
         "--workload", CELL, "--seed", str(2**31 + 5), "--seconds", "1",
         "--trace", "1", "--rehearsal",
     ])
-    rc = harness.run_one(args, time.perf_counter(), keep_trace=str(tmp_path))
+    with rules.one_traced_run_at_a_time(ROOT):
+        rc = harness.run_one(
+            args, time.perf_counter(), keep_trace=str(tmp_path)
+        )
     out = capsys.readouterr().out.strip().splitlines()
     assert rc == 0, out[-20:]
     line = json.loads(out[-1])
