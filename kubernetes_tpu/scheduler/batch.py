@@ -3673,22 +3673,33 @@ class BatchScheduler(Scheduler):
             )
         for prof, items in by_prof.values():
             victim_uids: Optional[List[str]] = []
+            self.preemptor.last_wave = {}
+
+            def wave_stats() -> dict:
+                # the wave's numbers: on its span for the trace's
+                # readers, and on the ring's mark beside it
+                return dict(
+                    self.preemptor.last_wave,  # searched, nodes, v_max, pack
+                    nominated=sum(1 for n in nominated if n),
+                    victims=len(victim_uids or ()),
+                    tier=getattr(self.preemptor, "wave_solver_tier", ""),
+                )
+
             try:
                 with flightrecorder.stage(
-                    "preempt_wave", totals=self.stage_totals
-                ):
+                    "preempt_wave", totals=self.stage_totals,
+                    pods=len(items),
+                ) as wave:
                     nominated, victim_uids = self.preemptor.preempt_batch(
                         prof, [(pi.pod, fe) for pi, fe, _ in items]
                     )
+                    wave.set_metadata(**wave_stats())
             except Exception:
                 logger.exception("batched device preemption failed")
                 nominated = [""] * len(items)
             evict_ok = victim_uids is not None
             flightrecorder.mark(
-                "preemption_wave", pods=len(items),
-                nominated=sum(1 for n in nominated if n),
-                victims=len(victim_uids or ()),
-                tier=getattr(self.preemptor, "wave_solver_tier", ""),
+                "preemption_wave", pods=len(items), **wave_stats()
             )
             # wait (bounded) for the evictions to propagate from the
             # watch into the cache: the nominated pods retry WITHOUT
@@ -3699,8 +3710,9 @@ class BatchScheduler(Scheduler):
             # victims would waste a scheduling cycle
             if victim_uids:
                 with flightrecorder.stage(
-                    "victim_wait", totals=self.stage_totals
-                ):
+                    "victim_wait", totals=self.stage_totals,
+                    victims=len(victim_uids),
+                ) as waiting:
                     deadline = time.monotonic() + 0.5
                     pending = list(victim_uids)
                     while pending and time.monotonic() < deadline:
@@ -3710,8 +3722,11 @@ class BatchScheduler(Scheduler):
                         ]
                         if pending:
                             time.sleep(0.002)
+                    # victims the cache still held when the wait gave up
+                    waiting.set_metadata(timed_out=len(pending))
             with flightrecorder.stage(
-                "preempt_requeue", totals=self.stage_totals
+                "preempt_requeue", totals=self.stage_totals,
+                pods=len(items),
             ):
                 for (pi, fe, cycle), node in zip(items, nominated):
                     if self.cache.has_pod_uid(pi.pod.metadata.uid):

@@ -21,7 +21,11 @@ from typing import Dict, List, Optional, Tuple
 
 from kubernetes_tpu.api.selectors import labels_match_selector
 from kubernetes_tpu.api.types import Pod, PodDisruptionBudget
-from kubernetes_tpu.cache.node_info import NodeInfo, pod_host_ports
+from kubernetes_tpu.cache.node_info import (
+    NodeInfo,
+    pod_host_ports,
+    pod_hot_info,
+)
 from kubernetes_tpu.framework.interface import (
     CycleState,
     FitError,
@@ -213,6 +217,17 @@ class Preemptor:
         self.budget_denials = 0
         self.victims_slow_death = 0
         self.wave_solver_tier = ""
+        # the scheduler's always-on stage totals, when it has them: the
+        # wave's children (pack_build, pack_wait, solve) add to them
+        self.stage_totals = None
+        # what the newest wave's ``sched/preempt_wave`` span says of it:
+        # live preemptors sent to the device, the pack's node rows and
+        # victim slots, and whether the pack was at hand or built
+        self.last_wave: Dict[str, object] = {}
+        # why a preemptor went through the victim search AGAIN (it came
+        # back holding the nomination an earlier wave gave it): see
+        # ``_why_searched_again``
+        self.searched_again: Dict[str, int] = {}
         # drain planning reads CURRENT cache truth through a private
         # snapshot (the scheduler's own snapshot is pre-batch: it lags
         # the newest commits by one dispatch, and an idle scheduler
@@ -436,7 +451,9 @@ class Preemptor:
                 self._plan_pack if self._plan_pack_key == key else None
             )
             if pack is None:
-                with flightrecorder.stage("preempt_wave.pack_build"):
+                with flightrecorder.stage(
+                    "preempt_wave.pack_build", totals=self.stage_totals
+                ):
                     pack = pack_preemption_state(snapshot, nt, pdbs)
                 self._plan_pack = pack
                 self._plan_pack_key = key
@@ -448,7 +465,7 @@ class Preemptor:
                 nt = self._tensor_cache.update(snapshot)
             key = self._pack_cache_key(snapshot, pdbs)
             with flightrecorder.stage(
-                "preempt_wave.pack_wait"
+                "preempt_wave.pack_wait", totals=self.stage_totals
             ), self._pack_cv:
                 # a prewarm in flight is about to deliver this exact
                 # pack: wait for it instead of duplicating ~0.3s of
@@ -461,12 +478,21 @@ class Preemptor:
                 ):
                     self._pack_cv.wait(0.05)
                 pack = self._pack if self._pack_key == key else None
-            if pack is None:
-                with flightrecorder.stage("preempt_wave.pack_build"):
+            built = pack is None
+            if built:
+                with flightrecorder.stage(
+                    "preempt_wave.pack_build", totals=self.stage_totals
+                ):
                     pack = pack_preemption_state(snapshot, nt, pdbs)
                 with self._pack_cv:
                     self._pack = pack
                     self._pack_key = key
+            self.last_wave = {
+                "searched": len(pods),
+                "nodes": len(pack.node_names),
+                "v_max": int(pack.v_max),
+                "pack": "built" if built else "reused",
+            }
         n = len(pack.node_names)
         b = len(pods)
 
@@ -573,7 +599,9 @@ class Preemptor:
         if wave_pallas_eligible(pack, pack_num_pdbs(pack)):
             attempts.append((TIER_PALLAS, _tier_thunk("pallas")))
         attempts.append((TIER_XLA, _tier_thunk("xla")))
-        with flightrecorder.stage("preempt_wave.solve"):
+        with flightrecorder.stage(
+            "preempt_wave.solve", totals=self.stage_totals
+        ):
             tier, (chosen, victims, viol, nviol) = self.ladder.run(
                 attempts, label="preempt_wave"
             )
@@ -831,6 +859,11 @@ class Preemptor:
             live.append(k)
             live_pods.append(pod)
             potentials.append(potential)
+            if pod.status.nominated_node_name:
+                why = self._why_searched_again(pod)
+                self.searched_again[why] = (
+                    self.searched_again.get(why, 0) + 1
+                )
         if not live_pods:
             return results, []
         try:
@@ -922,6 +955,34 @@ class Preemptor:
                     waiting.reject("preemption", "preempted")
             return results, evicted_now
         return results, []
+
+    def _why_searched_again(self, pod: Pod) -> str:
+        """Why a pod that holds an earlier wave's nomination is in the
+        search again, its retry having failed: ``already_placed`` (the
+        scheduler's cache holds the pod itself, assumed or bound: the
+        retry batch carried a second record of it, which took the room,
+        and this record failed beside it), else by what the wave's
+        snapshot shows of the nominated node: ``room_free`` (the pod
+        would fit there: the failed solve had not seen the evictions),
+        ``room_taken`` (other pods hold what was freed for it) or
+        ``node_gone``. Cpu, memory and pod count, which is all a
+        device-eligible pod asks for."""
+        cache = getattr(self.algorithm, "cache", None)
+        if cache is not None and cache.has_pod_uid(pod.metadata.uid):
+            return "already_placed"
+        ni = self.algorithm.snapshot.get_node_info(
+            pod.status.nominated_node_name
+        )
+        if ni is None or ni.node is None:
+            return "node_gone"
+        milli, mem_b = pod_hot_info(pod)[:2]
+        used, cap = ni.requested, ni.allocatable
+        fits = (
+            used.milli_cpu + milli <= cap.milli_cpu
+            and used.memory + mem_b <= cap.memory
+            and len(ni.pods) + 1 <= cap.allowed_pod_number
+        )
+        return "room_free" if fits else "room_taken"
 
     def _charge_victims(
         self, victims: List[Pod], already_paid=frozenset()

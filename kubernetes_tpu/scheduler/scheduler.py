@@ -850,6 +850,7 @@ def new_scheduler(
         from kubernetes_tpu.robustness.ladder import SolverLadder
 
         sched.preemptor.ladder = SolverLadder(sched.ladder.config)
+        sched.preemptor.stage_totals = sched.stage_totals
     sched.event_broadcaster = broadcaster
     # the bind-ack ledger must exist BEFORE handler registration: the
     # eventhandlers capture it once and feed it the Running-ack frames
