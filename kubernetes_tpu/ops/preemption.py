@@ -64,7 +64,7 @@ class PreemptionPack:
         "node_names", "node_index", "pods_by_node", "alloc",
         "base_requested", "prio", "start_rel", "req", "active",
         "pdb_match", "pdb_allowed", "v_max", "generation", "dev",
-        "last_adims",
+        "last_adims", "made", "why", "nodes_kept", "nodes_repacked",
     )
 
 

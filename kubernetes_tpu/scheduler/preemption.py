@@ -31,6 +31,7 @@ from kubernetes_tpu.framework.interface import (
     FitError,
     StatusCode,
 )
+from kubernetes_tpu.ops.preempt_facts import PreemptFacts, pdb_key
 from kubernetes_tpu.robustness.faults import FaultPoint, get_injector
 from kubernetes_tpu.robustness.ladder import (
     TIER_PALLAS,
@@ -199,6 +200,11 @@ class Preemptor:
         from kubernetes_tpu.tensors import NodeTensorCache
 
         self._tensor_cache = NodeTensorCache()
+        # the victim pack's rows, kept per node and advanced by the
+        # snapshot's change log (ops/preempt_facts.py); the wave and the
+        # prewarm thread advance the one store
+        self._facts = PreemptFacts()
+        self._prewarm_nt_cache = None  # the prewarm thread's sibling
         self._pack = None
         self._pack_key = None
         self._pack_cv = threading.Condition()
@@ -222,7 +228,10 @@ class Preemptor:
         self.stage_totals = None
         # what the newest wave's ``sched/preempt_wave`` span says of it:
         # live preemptors sent to the device, the pack's node rows and
-        # victim slots, and whether the pack was at hand or built
+        # victim slots, whether the pack was at hand (``reused``), made
+        # from the kept rows (``advanced``) or ``built`` whole, and how
+        # the pack it used was made: node rows taken over and repacked
+        # (and why the kept rows were given up, where they were)
         self.last_wave: Dict[str, object] = {}
         # why a preemptor went through the victim search AGAIN (it came
         # back holding the nomination an earlier wave gave it): see
@@ -468,8 +477,8 @@ class Preemptor:
                 "preempt_wave.pack_wait", totals=self.stage_totals
             ), self._pack_cv:
                 # a prewarm in flight is about to deliver this exact
-                # pack: wait for it instead of duplicating ~0.3s of
-                # packing work
+                # pack: wait for it instead of queueing behind it at
+                # the store's lock
                 deadline = time.monotonic() + 2.0
                 while (
                     self._prewarm_busy
@@ -483,7 +492,7 @@ class Preemptor:
                 with flightrecorder.stage(
                     "preempt_wave.pack_build", totals=self.stage_totals
                 ):
-                    pack = pack_preemption_state(snapshot, nt, pdbs)
+                    pack = self._facts.pack(snapshot, nt, pdbs)
                 with self._pack_cv:
                     self._pack = pack
                     self._pack_key = key
@@ -491,8 +500,12 @@ class Preemptor:
                 "searched": len(pods),
                 "nodes": len(pack.node_names),
                 "v_max": int(pack.v_max),
-                "pack": "built" if built else "reused",
+                "pack": pack.made if built else "reused",
+                "pack_nodes_kept": pack.nodes_kept,
+                "pack_nodes_repacked": pack.nodes_repacked,
             }
+            if pack.why:
+                self.last_wave["pack_rebuilt"] = pack.why
         n = len(pack.node_names)
         b = len(pods)
 
@@ -627,24 +640,14 @@ class Preemptor:
         return out, tier
 
     def _pack_cache_key(self, snapshot, pdbs):
-        return (
-            snapshot.generation,
-            tuple(
-                (
-                    pdb.metadata.namespace, pdb.metadata.name,
-                    pdb.metadata.resource_version,
-                    pdb.status.disruptions_allowed,
-                )
-                for pdb in pdbs
-            ),
-        )
+        return (snapshot.generation, pdb_key(pdbs))
 
     def prewarm_pack_async(self, adims=None) -> None:
-        """Speculatively build + upload the victim-search pack for the
+        """Speculatively advance + upload the victim-search pack for the
         CURRENT snapshot on a helper thread. The BatchScheduler calls
         this when a dispatched batch's demand exceeds the cluster's free
-        capacity -- preemption is then likely, and the ~0.25s host pack
-        plus the ~5MB device upload overlap the failing solve instead of
+        capacity -- preemption is then likely, and the host pack plus
+        the device upload overlap the failing solve instead of
         serializing into the wave."""
         with self._pack_cv:
             if self._prewarm_busy:
@@ -666,10 +669,7 @@ class Preemptor:
                 with self._pack_cv:
                     if self._pack_key == key:
                         return
-                from kubernetes_tpu.ops.preemption import (
-                    pack_preemption_state,
-                    upload_pack,
-                )
+                from kubernetes_tpu.ops.preemption import upload_pack
                 from kubernetes_tpu.tensors import NodeTensorCache
 
                 # own cache INSTANCE (update mutates arrays in place and
@@ -677,13 +677,18 @@ class Preemptor:
                 # but the SHARED dims/topology schema: a fresh
                 # ResourceDims could order resource columns differently
                 # and silently misalign the wave's pod packing against
-                # this pack
+                # this pack. It persists, as ``_plan_nt_cache`` does:
+                # the change log is read by cursor, so this thread's
+                # updates are O(changed rows) too (one prewarm runs at
+                # a time: ``_prewarm_busy``)
                 with self._nt_lock:
-                    nt = NodeTensorCache(
-                        dims=self._tensor_cache.dims,
-                        topology_encoder=self._tensor_cache.topology,
-                    ).update(snapshot)
-                pack = pack_preemption_state(snapshot, nt, pdbs)
+                    if self._prewarm_nt_cache is None:
+                        self._prewarm_nt_cache = NodeTensorCache(
+                            dims=self._tensor_cache.dims,
+                            topology_encoder=self._tensor_cache.topology,
+                        )
+                    nt = self._prewarm_nt_cache.update(snapshot)
+                pack = self._facts.pack(snapshot, nt, pdbs)
                 if adims is not None and not pdbs and pack.v_max <= 32:
                     # start the slim device upload too (async): the
                     # ~1.6MB transfer rides the link before the wave.

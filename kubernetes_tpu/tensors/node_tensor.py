@@ -787,6 +787,49 @@ def _pack_gather_py(
     return new_keys
 
 
+def pod_request_rows(
+    pods_l: List[Pod], dims: ResourceDims
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """``(requests [B, R], non_zero_requests [B, 2], priorities [B],
+    unsatisfiable [B])`` of a non-empty list of pods, in its order: the
+    gather over the ``_packrow`` memos and one schema encode a distinct
+    request row. What ``pack_pod_batch`` packs before it orders the
+    batch; a packer with an order of its own (the victim pack's kept
+    rows, ops/preempt_facts.py) takes the rows alone."""
+    b = len(pods_l)
+    row_cache: Dict[Tuple, int] = {}
+    idx = np.empty(b, dtype=np.int32)
+    nzr = np.empty((b, 2), dtype=np.int32)
+    prio = np.empty(b, dtype=np.int32)
+    gather, expected = _native.ingest_fn("pack_gather")
+    if gather is not None:
+        new_keys = gather(pods_l, stamp_pack_row, row_cache, idx, nzr, prio)
+    else:
+        if expected:
+            _metrics.ingest_native_fallbacks.inc(site="pack-gather")
+        new_keys = _pack_gather_py(
+            pods_l, stamp_pack_row, row_cache, idx, nzr, prio
+        )
+    # encode each DISTINCT request row once and gather vectorized
+    uniq_rows: List[np.ndarray] = []
+    uniq_unknown: List[bool] = []
+    for req_items, vc in new_keys:
+        row, unknown = dims.encode_requests(dict(req_items), grow=False)
+        row[PODS] = 1
+        for name, qty in vc:
+            col = dims.existing_column(name)
+            if col is not None:
+                # unregistered names (a nominee classified by an older
+                # scheduler instance) are skipped: the overlay
+                # under-reserves rather than shape-mismatching
+                row[col] += qty
+        uniq_rows.append(row)
+        uniq_unknown.append(unknown)
+    requests = np.stack(uniq_rows)[idx]
+    unsatisfiable = np.asarray(uniq_unknown, dtype=bool)[idx]
+    return requests, nzr, prio, unsatisfiable
+
+
 def pack_pod_batch(
     pods: List[Pod],
     dims: ResourceDims,
@@ -817,37 +860,8 @@ def pack_pod_batch(
             order=np.arange(0, dtype=np.int32),
             unsatisfiable=np.zeros(0, dtype=bool),
         )
-    row_cache: Dict[Tuple, int] = {}
-    idx = np.empty(b, dtype=np.int32)
-    nzr = np.empty((b, 2), dtype=np.int32)
-    prio = np.empty(b, dtype=np.int32)
     pods_l = pods if isinstance(pods, list) else list(pods)
-    gather, expected = _native.ingest_fn("pack_gather")
-    if gather is not None:
-        new_keys = gather(pods_l, stamp_pack_row, row_cache, idx, nzr, prio)
-    else:
-        if expected:
-            _metrics.ingest_native_fallbacks.inc(site="pack-gather")
-        new_keys = _pack_gather_py(
-            pods_l, stamp_pack_row, row_cache, idx, nzr, prio
-        )
-    # encode each DISTINCT request row once and gather vectorized
-    uniq_rows: List[np.ndarray] = []
-    uniq_unknown: List[bool] = []
-    for req_items, vc in new_keys:
-        row, unknown = dims.encode_requests(dict(req_items), grow=False)
-        row[PODS] = 1
-        for name, qty in vc:
-            col = dims.existing_column(name)
-            if col is not None:
-                # unregistered names (a nominee classified by an older
-                # scheduler instance) are skipped: the overlay
-                # under-reserves rather than shape-mismatching
-                row[col] += qty
-        uniq_rows.append(row)
-        uniq_unknown.append(unknown)
-    requests = np.stack(uniq_rows)[idx]
-    unsatisfiable = np.asarray(uniq_unknown, dtype=bool)[idx]
+    requests, nzr, prio, unsatisfiable = pod_request_rows(pods_l, dims)
     ts = timestamps or [pod.metadata.creation_timestamp for pod in pods_l]
     # pop_batch already drains the activeQ in comparator order (priority
     # desc, enqueue time asc) -- detect the sorted common case and skip

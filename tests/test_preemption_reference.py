@@ -216,22 +216,28 @@ def test_a_run_with_the_rule_broken_underneath_is_not_correct(
     import json
 
     from chipbench import harness
-    from kubernetes_tpu.ops import preemption as ops
+    from kubernetes_tpu.ops.preempt_facts import PreemptFacts
 
-    real = ops.pack_preemption_state
+    # every pack the wave searches comes through the kept store's
+    # ``pack``, the whole build it starts from and the rows it advances
+    # alike: both read the priorities crooked
+    real = PreemptFacts.pack
+    made = []
 
-    def crooked(snapshot, nt, pdbs):
+    def crooked(self, snapshot, nt, pdbs):
         mids = [p for ni in snapshot.list_node_infos() for p in ni.pods
                 if p.spec.priority == 10]
         for p in mids:
             p.spec.priority = -1
         try:
-            return real(snapshot, nt, pdbs)
+            pack = real(self, snapshot, nt, pdbs)
+            made.append(pack.made)
+            return pack
         finally:
             for p in mids:
                 p.spec.priority = 10
 
-    monkeypatch.setattr(ops, "pack_preemption_state", crooked)
+    monkeypatch.setattr(PreemptFacts, "pack", crooked)
     rc = harness.main([
         "--workload", "priority-tiers-5000.preempt-1k", "--seed",
         str(2**31 + 32), "--seconds", "1", "--trace", "0", "--rehearsal",
@@ -244,3 +250,4 @@ def test_a_run_with_the_rule_broken_underneath_is_not_correct(
     assert [l.split(":")[0] for l in failed] == [
         "compare window against the reference"]
     assert any(" mid" in l for l in out if l.startswith("victims a wave"))
+    assert made[0] == "built" and "advanced" in made[1:], made
