@@ -369,6 +369,58 @@ class Framework:
             return Status(StatusCode.WAIT, f"one or more plugins asked to wait")
         return None
 
+    def permit_batchable(self, pod: Pod) -> bool:
+        """True when every Permit plugin that is relevant to ``pod``
+        can decide pods that were assumed together in one call
+        (``permit_batch``): the batch commit then assumes such pods in
+        bulk and runs Permit once a batch."""
+        for pl, relevant in self._relevance["permit"]:
+            if (relevant is None or relevant(pod)) and not hasattr(
+                pl, "permit_batch"
+            ):
+                return False
+        return True
+
+    def run_permit_plugins_batch(
+        self, pods: List[Pod], node_names: List[str]
+    ) -> List[Optional[Status]]:
+        """``run_permit_plugins`` for pods that were assumed together
+        and are all ``permit_batchable``: one ``permit_batch`` call a
+        plugin, the same combination of the plugins' answers a pod, and
+        a pod that has to wait parked in the waiting-pods map."""
+        n = len(pods)
+        out: List[Optional[Status]] = [None] * n
+        timeouts: List[Optional[Dict[str, float]]] = [None] * n
+        for pl in self._by_point["permit"]:
+            answers = self._record(
+                pl, "permit", pl.permit_batch, pods, node_names
+            )
+            for i, (status, timeout) in enumerate(answers):
+                if is_success(status) or (
+                    out[i] is not None and out[i].code != StatusCode.WAIT
+                ):
+                    continue
+                if status.code == StatusCode.WAIT:
+                    if timeouts[i] is None:
+                        timeouts[i] = {}
+                    timeouts[i][pl.name()] = min(
+                        timeout or MAX_TIMEOUT_SECONDS, MAX_TIMEOUT_SECONDS
+                    )
+                    out[i] = Status(
+                        StatusCode.WAIT, "one or more plugins asked to wait"
+                    )
+                elif status.is_unschedulable():
+                    out[i] = status
+                else:
+                    out[i] = Status.error(
+                        f"error running Permit plugin {pl.name()}: "
+                        f"{status.message()}"
+                    )
+        for i, status in enumerate(out):
+            if status is not None and status.code == StatusCode.WAIT:
+                self.waiting_pods.add(WaitingPod(pods[i], timeouts[i]))
+        return out
+
     def wait_on_permit(self, pod: Pod) -> Optional[Status]:
         wp = self.waiting_pods.get(pod.metadata.uid)
         if wp is None:

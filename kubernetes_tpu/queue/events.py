@@ -12,6 +12,11 @@ UnschedulableTimeout = "UnschedulableTimeout"
 AssignedPodAdd = "AssignedPodAdd"
 AssignedPodUpdate = "AssignedPodUpdate"
 AssignedPodDelete = "AssignedPodDelete"
+# an assumed pod gave its node back without ever being bound (a Permit
+# wait that ended in a rejection or a timeout, a failed bind): room is
+# free as after a delete (not in the reference, whose parked pods wait
+# for the periodic flush)
+AssumedPodForget = "AssumedPodForget"
 PvAdd = "PvAdd"
 PvUpdate = "PvUpdate"
 PvcAdd = "PvcAdd"

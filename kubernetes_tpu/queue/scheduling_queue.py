@@ -692,6 +692,30 @@ class PriorityQueue:
         with self._lock:
             return list(self.unschedulable_q.values())
 
+    def take(self, keys) -> List[PodInfo]:
+        """Pull the pods of ``keys`` (``Pod.key()``) that are queued
+        anywhere (active, backing off, parked unschedulable) out of the
+        queue, as a pop hands them out: the batch scheduler completes a
+        gang whose other members it has just popped. A key that is in no
+        queue is skipped."""
+        out: List[PodInfo] = []
+        with self._cond:
+            for key in keys:
+                pi = self.active_q.get_by_key(key)
+                if pi is not None:
+                    self.active_q.delete_by_key(key)
+                else:
+                    pi = self.pod_backoff_q.get_by_key(key)
+                    if pi is not None:
+                        self.pod_backoff_q.delete_by_key(key)
+                    else:
+                        pi = self.unschedulable_q.pop(key, None)
+                if pi is not None:
+                    pi.attempts += 1
+                    out.append(pi)
+            self.scheduling_cycle += len(out)
+        return out
+
     # -- targeted assigned-pod wakeups (reference :508-:525) ----------------
 
     def _pods_with_matching_affinity_term(self, pod: Pod) -> List[PodInfo]:

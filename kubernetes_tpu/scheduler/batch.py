@@ -49,6 +49,7 @@ from kubernetes_tpu.framework.interface import (
     FitError,
     PodInfo,
     Status,
+    StatusCode,
 )
 from kubernetes_tpu.ops.assignment import (
     GreedyConfig,
@@ -189,6 +190,30 @@ def _mirror_scatter(assignments, b, req, nzr, req_shadow, nzr_shadow):
         metrics.ingest_native_fallbacks.inc(site="mirror-scatter")
     return _mirror_scatter_py(
         assignments, b, req, nzr, req_shadow, nzr_shadow
+    )
+
+
+def _gang_contiguous(order: np.ndarray, gangs) -> np.ndarray:
+    """``order`` with every gang's members together, at the place of the
+    gang's first member and in the order they had: a gang is then
+    solved as one run of steps, so the first gang a pass leaves short
+    saw everything the gangs before it left. A gang's members share
+    their priority wherever a job controller made them; where they do
+    not, the run stands at its first member's place."""
+    runs: dict = {}
+    out = []
+    for i in order.tolist():
+        key = gangs[i]
+        if key is None:
+            out.append((i,))
+            continue
+        run = runs.get(key)
+        if run is None:
+            run = runs[key] = []
+            out.append(run)
+        run.append(i)
+    return np.fromiter(
+        (i for run in out for i in run), dtype=order.dtype, count=len(order)
     )
 
 
@@ -711,6 +736,8 @@ class BatchScheduler(Scheduler):
                     else self.batch_window),
             totals=self.stage_totals,
         )
+        if batch_infos:
+            batch_infos = self._with_gang_siblings(batch_infos, size)
         # the first span this drain dispatches claims the pop timings
         self._pop_note = (
             self.queue.last_pop_work_seconds,
@@ -809,17 +836,30 @@ class BatchScheduler(Scheduler):
     ) -> None:
         """Synchronous solve: dispatch + download + commit in one call,
         with the gang quorum fixup between solve and commit."""
-        pending = self._dispatch_solve(solver_infos, pod_scheduling_cycle)
+        gangs = self._gang_keys(solver_infos)
+        try:
+            if gangs is None:
+                pending = self._dispatch_solve(
+                    solver_infos, pod_scheduling_cycle
+                )
+            else:
+                pending = self._gang_solve(
+                    solver_infos, pod_scheduling_cycle, gangs
+                )
+        except SchedulerCrashed:
+            self._simulate_crash()  # no recovery: the process "died"
+            return
+        except Exception:
+            # a pass of the gang fixup failed (a download that timed
+            # out): the batch's pods go round again, none is stranded
+            logger.exception("gang solve failed")
+            self._recover_failed_batch({
+                "solver_infos": solver_infos, "cycle": pod_scheduling_cycle,
+            })
+            return
         if pending is None:
             return
         try:
-            if any(
-                pi.pod.metadata.labels.get(POD_GROUP_LABEL)
-                for pi in solver_infos
-            ):
-                pending = self._gang_fixup(solver_infos, pending)
-                if pending is None:
-                    return
             self._complete_solve(pending)
         except SchedulerCrashed:
             self._simulate_crash()  # no recovery: the process "died"
@@ -832,47 +872,327 @@ class BatchScheduler(Scheduler):
 
     # -- gang all-or-nothing group masks (SURVEY stage 6) --------------------
 
-    def _gang_fixup(self, solver_infos: List[PodInfo], pending):
-        """All-or-nothing placement for PodGroups inside the solver: a
-        group whose placed + potential outside members can't reach
-        min_member is masked inactive and the batch re-solves, so a
-        half-fitting gang reserves NOTHING (no Permit-timeout churn).
-        Permit remains the cross-batch completion gate for groups that
-        can still assemble (framework/v1alpha1/interface.go:384).
+    def _with_gang_siblings(
+        self, batch_infos: List[PodInfo], size: int
+    ) -> List[PodInfo]:
+        """A gang is decided whole or not at all, so a batch that holds
+        some of a gang's members takes the others that are queued (at
+        the back of the active queue, backing off, parked) with it,
+        while the batch has room: the gang's members that have just
+        arrived find the ones an earlier batch sent away for want of
+        them, and no part of a gang holds nodes at Permit for a part
+        that is queued behind other gangs. A batch without a gang
+        member pays one label read a pod."""
+        inside: dict = {}
+        for pi in batch_infos:
+            group = pi.pod.metadata.labels.get(POD_GROUP_LABEL)
+            if group:
+                inside.setdefault(
+                    (pi.pod.metadata.namespace, group), set()
+                ).add(pi.pod.metadata.uid)
+        if not inside:
+            return batch_infos
+        cos = self._coscheduling(batch_infos)
+        if cos is None:
+            return batch_infos
+        room = size - len(batch_infos)
+        for key, uids in inside.items():
+            if room <= 0:
+                break
+            known, holding = cos.members(*key)
+            missing = [
+                pod_key for uid, pod_key in known.items()
+                if uid not in uids and uid not in holding
+            ]
+            if missing and len(missing) <= room:
+                took = self.queue.take(missing)
+                batch_infos.extend(took)
+                room -= len(took)
+        return batch_infos
 
-        Outside members (held or still pending) count optimistically --
-        the same knowledge horizon as Coscheduling's PreFilter fail-fast
-        (total known members vs min_member), sharpened with this batch's
-        actual capacity outcome."""
-        inactive: set = set()
-        for _attempt in range(2):
-            assignments = self._pending_assignments(pending)
-            failed = self._gang_quorum_failures(pending, assignments)
-            failed -= inactive
-            if not failed:
-                pending["gang_failed_uids"] = inactive
-                return pending
-            inactive |= failed
-            self.gang_resolves += 1
-            self._rewind_carry(pending)
-            pending = self._dispatch_solve(
-                solver_infos, pending["cycle"], inactive_uids=inactive
+    def _gang_keys(self, solver_infos: List[PodInfo]):
+        """``(namespace, group)`` of every pod of the batch, None for a
+        pod outside any gang; None for a batch that holds no gang
+        member, or whose profile runs no Coscheduling (the group label
+        then carries no gang semantics: never mask)."""
+        keys = [
+            (pi.pod.metadata.namespace, g) if g else None
+            for pi in solver_infos
+            for g in (pi.pod.metadata.labels.get(POD_GROUP_LABEL),)
+        ]
+        if not any(keys) or self._coscheduling(solver_infos) is None:
+            return None
+        return keys
+
+    def _coscheduling(self, solver_infos: List[PodInfo]):
+        prof = self.profiles.get(solver_infos[0].pod.spec.scheduler_name)
+        return (
+            prof.plugin_instance("Coscheduling") if prof is not None else None
+        )
+
+    def _gang_solve(
+        self, solver_infos: List[PodInfo], pod_scheduling_cycle: int, gangs
+    ):
+        """All-or-nothing placement for PodGroups inside the solver, and
+        work-conserving: a gang is masked (parked, reserving NOTHING: no
+        Permit-timeout churn) only if it cannot fit what the gangs
+        before it in the solve order left. Permit remains the
+        cross-batch completion gate for gangs that can still assemble
+        (framework/v1alpha1/interface.go:384).
+
+        The solve order keeps a gang's members together
+        (``_gang_contiguous``), so after a pass the FIRST gang short of
+        its quorum is a true failure: every gang before it was placed
+        whole and it saw everything they left. Gangs after it failed,
+        if they did, beside the members it placed in vain, and are not
+        masked for that. Where the batch's pods carry no constraint
+        between pods and the failed gang's pods are identical, the
+        members it did place count what the leftover holds of such
+        pods, so every later gang of the same pods that needs more than
+        is then left is masked with it (``_gang_census``). The rest is
+        solved again against the rewound carry, until a pass fails no
+        gang.
+
+        A pass masks the first failure with every gang of its pods that
+        cannot fit, so the passes a batch needs are one a distinct
+        template of its gangs (gangs without one share a pass) and one
+        clean pass; they are bounded by that count plus one, for a gang
+        the count took for placed and that then failed. At the bound
+        what still fails is left out of the commit and sent round again
+        through the active queue, not parked: the next batch masks at
+        least its first failure, so the round ends.
+
+        A gang is decided when every member that holds no node yet is
+        in the batch (``_gang_quorums``)."""
+        cos = self._coscheduling(solver_infos)
+        totals = self.stage_totals
+        groups = len({k for k in gangs if k is not None})
+        stats = {
+            "passes": 0, "masked_groups": 0, "masked_pods": 0,
+            "requeued_pods": 0, "carry": "",
+        }
+        with flightrecorder.stage(
+            "gang_fixup", totals=totals, pods=len(solver_infos),
+            groups=groups,
+        ) as fixup:
+            pending = self._gang_passes(
+                solver_infos, pod_scheduling_cycle, gangs, cos, stats
             )
-            if pending is None:
-                return None  # packers routed the batch to the host path
-        # leftover failures after the final pass are committed as
-        # NO_NODE without a re-solve: their capacity stays reserved in
-        # the device output, so drop the carry
-        assignments = self._pending_assignments(pending)
-        leftover = self._gang_quorum_failures(pending, assignments)
-        if leftover - inactive:
-            inactive |= leftover
-            with self._shadow_lock:
-                self._dev.invalidate_carry()
-        pending["gang_failed_uids"] = inactive
+            fixup.set_metadata(**stats)
+        self.gang_resolves += max(0, stats["passes"] - 1)
         return pending
 
-    def _rewind_carry(self, pending) -> None:
+    def _gang_passes(
+        self, solver_infos, pod_scheduling_cycle, gangs, cos, stats
+    ):
+        totals = self.stage_totals
+        uid_of = [pi.pod.metadata.uid for pi in solver_infos]
+        members: dict = {}  # gang -> indices into solver_infos
+        for i, key in enumerate(gangs):
+            if key is not None:
+                members.setdefault(key, []).append(i)
+        with flightrecorder.stage("gang_fixup.census", totals=totals):
+            quorum = self._gang_quorums(solver_infos, members, cos)
+        # a gang that the batch's members cannot bring to its quorum
+        # whatever the solve finds fails before the first pass
+        masked = {
+            key: "members" for key, idx in members.items()
+            if quorum[key] > len(idx)
+        }
+        taken: set = set()  # gangs a count took for placed
+        requeue: set = set()  # gangs sent round again, not parked
+        templates = at = None
+        bound = 2  # until the first pass has shown the batch's templates
+        pending = None
+        while True:
+            inactive = {
+                uid_of[i] for key in masked for i in members[key]
+            }
+            if pending is not None:
+                with flightrecorder.stage(
+                    "gang_fixup.resolve", totals=totals,
+                    masked_pods=len(inactive),
+                ):
+                    stats["carry"] = self._rewind_carry(pending)
+                    if len(inactive) == len(solver_infos):
+                        # nothing is left to place: the rewound carry
+                        # is the batch's result
+                        self._void_assignments(pending)
+                        break
+                    pending = self._dispatch_solve(
+                        solver_infos, pod_scheduling_cycle,
+                        inactive_uids=inactive, gangs=gangs,
+                    )
+            else:
+                pending = self._dispatch_solve(
+                    solver_infos, pod_scheduling_cycle,
+                    inactive_uids=inactive or None, gangs=gangs,
+                )
+            if pending is None:
+                return None  # packers routed the batch to the host path
+            stats["passes"] += 1
+            with flightrecorder.stage(
+                "gang_fixup.download", totals=totals
+            ):
+                assignments = self._pending_assignments(pending)
+            with flightrecorder.stage("gang_fixup.census", totals=totals):
+                if templates is None:
+                    # the solve order is the same in every pass
+                    order = np.asarray(pending["order"])
+                    where = np.empty(len(order), dtype=np.int64)
+                    where[order] = np.arange(len(order))
+                    at = {key: where[idx] for key, idx in members.items()}
+                    templates = self._gang_templates(pending, at)
+                    own = set(templates.values())
+                    bound = (
+                        sum(1 for t in own if t >= 0)
+                        + any(t < 0 for t in own) + 1
+                    )
+                failed, certain = self._gang_census(
+                    pending, assignments, at, masked, taken, quorum,
+                    templates,
+                )
+            if not failed:
+                break
+            if stats["passes"] > bound:
+                # the pass bound: commit what was placed whole, leave
+                # out what failed. Its capacity stays reserved in the
+                # device output, so the carry drops
+                for key in failed:
+                    masked[key] = "bound"
+                requeue.update(failed)
+                with self._shadow_lock:
+                    self._dev.invalidate_carry()
+                stats["carry"] = "dropped"
+                flightrecorder.mark(
+                    "gang_starved", groups=len(failed),
+                    passes=stats["passes"],
+                )
+                break
+            if not taken.isdisjoint(failed):
+                # a gang the count took for placed has failed: what was
+                # masked by that count was masked on a wrong premise
+                requeue.update(
+                    key for key, why in masked.items() if why == "count"
+                )
+            masked.update(certain)
+        for key in requeue:
+            masked[key] = "requeue"
+        inactive = {uid_of[i] for key in masked for i in members[key]}
+        pending["gang_failed_uids"] = inactive
+        pending["gang_requeue_uids"] = {
+            uid_of[i] for key in requeue for i in members[key]
+        }
+        stats["masked_groups"] = len(masked)
+        stats["masked_pods"] = len(inactive)
+        stats["requeued_pods"] = len(pending["gang_requeue_uids"])
+        # members of a masked gang that wait at Permit from an earlier
+        # batch wait for members that are not coming: their nodes are
+        # given back now, not at the timeout
+        for ns, group in masked:
+            cos.reject_waiting(
+                ns, group, "the rest of the pod group was not placed"
+            )
+        return pending
+
+    def _gang_quorums(self, solver_infos, members, cos) -> dict:
+        """gang -> how many of the batch's members have to be placed for
+        the gang to reach ``min_member``. Members outside the batch
+        count for it while they hold a node. Members that do not are
+        queued behind this batch or still arriving (``_with_gang_siblings``
+        took the ones it had room for): they count only for a gang too
+        large to be whole in any batch, which can assemble no other way
+        than part by part at Permit. Any other gang waits for them, so
+        that no part of it holds nodes for a part that may not fit."""
+        need = {}
+        for key, idx in members.items():
+            min_member = cos.min_member(solver_infos[idx[0]].pod, key[1])
+            known, holding = cos.members(*key)
+            inside = {solver_infos[i].pod.metadata.uid for i in idx}
+            held = sum(1 for uid in holding if uid not in inside)
+            elsewhere = sum(
+                1 for uid in known
+                if uid not in inside and uid not in holding
+            )
+            if len(idx) + elsewhere > self.max_batch:
+                held += elsewhere
+            need[key] = min_member - held
+        return need
+
+    @staticmethod
+    def _gang_templates(pending, at) -> dict:
+        """gang -> the one template its pods of the batch share (what a
+        pod asks of a node: its request rows and its static mask row),
+        or a value of its own where they differ or the batch holds
+        constraints between pods, under which the pods a gang placed
+        say nothing of what another would have. ``at`` is gang -> its
+        members' places in the solve order."""
+        b = pending["b"]
+        rows = np.concatenate(
+            [pending["req"][:b], pending["nzr"][:b],
+             np.asarray(pending["mask_index_solved"][:b])[:, None]],
+            axis=1,
+        )
+        _, ids = np.unique(rows, axis=0, return_inverse=True)
+        ids = ids.reshape(-1)
+        out = {}
+        for n, (key, places) in enumerate(at.items()):
+            own = set(ids[places].tolist())
+            if pending["constrained"] or len(own) != 1:
+                out[key] = -1 - n
+            else:
+                out[key] = own.pop()
+        return out
+
+    @staticmethod
+    def _gang_census(
+        pending, assignments, at, masked, taken, quorum, templates
+    ):
+        """One pass read: ``failed``, the gangs that were active and
+        short of their quorum, and ``certain``, those of them to mask
+        before the next pass with why: the first in the solve order
+        (``first``) and, where it has a template, every later active
+        gang of that template that needs more such pods than the
+        leftover holds once the gangs between them took theirs
+        (``count``); the gangs the count takes for placed join
+        ``taken``. ``at`` is gang -> its members' places in the solve
+        order."""
+        placed_at = np.asarray(assignments[: pending["b"]]) != NO_NODE
+        active = sorted(
+            (int(places.min()), key) for key, places in at.items()
+            if key not in masked
+        )
+        placed = {key: int(placed_at[at[key]].sum()) for _, key in active}
+        failed = [key for _, key in active if placed[key] < quorum[key]]
+        if not failed:
+            return failed, {}
+        first = failed[0]
+        certain = {first: "first"}
+        template = templates[first]
+        if template >= 0:
+            left = placed[first]
+            after = False
+            for _, key in active:
+                if key == first:
+                    after = True
+                elif after and templates[key] == template:
+                    if quorum[key] > left:
+                        certain[key] = "count"
+                    else:
+                        left -= min(left, len(at[key]))
+                        taken.add(key)
+        return failed, certain
+
+    @staticmethod
+    def _void_assignments(pending) -> None:
+        """Every pod of the batch is masked: the batch places nothing,
+        whatever its last pass found."""
+        pending["assignments_dev"] = np.full(
+            len(pending["mask_index_solved"]), NO_NODE, dtype=np.int32
+        )
+        pending["download"] = None
+
+    def _rewind_carry(self, pending) -> str:
         """Rewind the device carry to the given batch's pre-solve state:
         the gang quorum fixup re-solves the same batch, which must not
         see the first attempt's reservations. When the dispatch reused
@@ -883,8 +1203,9 @@ class BatchScheduler(Scheduler):
         with self._shadow_lock:
             if ci is not None and self._dev.req_dev is not None:
                 self._dev.req_dev, self._dev.nzr_dev = ci
-            else:
-                self._dev.invalidate_carry()
+                return "rewound"
+            self._dev.invalidate_carry()
+            return "dropped"
 
     def _pending_assignments(self, p):
         """The batch's downloaded assignments for the gang fixup: await
@@ -913,48 +1234,6 @@ class BatchScheduler(Scheduler):
             if breaker is not None:
                 breaker.force_open()
             raise
-
-    def _gang_quorum_failures(self, pending, assignments) -> set:
-        """UIDs of every member of a group that cannot reach min_member:
-        placed-in-batch + ALL outside known members (held or pending)
-        falls short."""
-        solver_infos = pending["solver_infos"]
-        order = pending["order"]
-        b = pending["b"]
-        groups = {}
-        for k in range(b):
-            pod = solver_infos[int(order[k])].pod
-            g = pod.metadata.labels.get(POD_GROUP_LABEL)
-            if g:
-                groups.setdefault(
-                    (pod.metadata.namespace, g), []
-                ).append(k)
-        if not groups:
-            return set()
-        prof = self.profiles.get(
-            solver_infos[0].pod.spec.scheduler_name
-        )
-        cos = (
-            prof.plugin_instance("Coscheduling") if prof is not None else None
-        )
-        if cos is None:
-            # no Coscheduling plugin: the group label carries no gang
-            # semantics in this profile -- never mask
-            return set()
-        failed: set = set()
-        for (ns, g), ks in groups.items():
-            pod0 = solver_infos[int(order[ks[0]])].pod
-            min_member, total = cos.group_quorum_info(pod0, g)
-            in_batch_uids = {
-                solver_infos[int(order[k])].pod.metadata.uid for k in ks
-            }
-            placed = sum(
-                1 for k in ks if int(assignments[k]) != NO_NODE
-            )
-            outside = max(0, total - len(in_batch_uids))
-            if placed + outside < min_member:
-                failed |= in_batch_uids
-        return failed
 
     def _pending_exists(self) -> bool:
         with self._pending_cv:
@@ -1717,15 +1996,18 @@ class BatchScheduler(Scheduler):
         pod_scheduling_cycle: int,
         inactive_uids=None,
         raise_on_exhaust: bool = False,
+        gangs=None,
     ):
         """``_dispatch_batch`` as one ``sched/dispatch`` span of a
-        profiler trace, which carries the batch's size and queue waits."""
+        profiler trace, which carries the batch's size and queue waits.
+        ``gangs`` is ``_gang_keys`` of a gang batch: the solve order
+        then keeps every gang's members together."""
         with flightrecorder.stage(
             "dispatch", pods=len(solver_infos)
         ) as dispatch:
             return self._dispatch_batch(
                 dispatch, solver_infos, pod_scheduling_cycle,
-                inactive_uids, raise_on_exhaust,
+                inactive_uids, raise_on_exhaust, gangs,
             )
 
     def _dispatch_batch(
@@ -1735,6 +2017,7 @@ class BatchScheduler(Scheduler):
         pod_scheduling_cycle: int,
         inactive_uids,
         raise_on_exhaust: bool,
+        gangs=None,
     ):
         """Pack + upload + dispatch one solver batch. Returns a pending
         record for _complete_solve, or None when the batch was routed to
@@ -2101,6 +2384,8 @@ class BatchScheduler(Scheduler):
 
                 tt.refresh_capacity(nt)
                 order = fair_order(order, pods, batch.priorities, tt)
+            if gangs is not None:
+                order = _gang_contiguous(order, gangs)
             req = np.zeros((padded, nt.dims.num_dims), dtype=np.int32)
             nzr = np.zeros((padded, 2), dtype=np.int32)
             midx = np.zeros(padded, dtype=np.int32)
@@ -2493,7 +2778,7 @@ class BatchScheduler(Scheduler):
                 span.finish(routed="exhausted_redispatch")
                 return self._dispatch_solve(
                     solver_infos, pod_scheduling_cycle,
-                    inactive_uids=inactive_uids,
+                    inactive_uids=inactive_uids, gangs=gangs,
                 )
             return self._contain_exhausted_batch(
                 solver_infos, pod_scheduling_cycle, span,
@@ -2578,6 +2863,9 @@ class BatchScheduler(Scheduler):
             "span": span,
             "solver_infos": list(solver_infos),
             "has_required_anti": has_required_anti,
+            # constraints between pods (spread, affinity, score
+            # families): the gang fixup counts no slots under them
+            "constrained": constrained,
             "has_ports": batch_ports,
             "has_scoring_terms": has_scoring_terms,
             "order": order,
@@ -3240,6 +3528,7 @@ class BatchScheduler(Scheduler):
                 p["num_nodes"], p["snapshot"], p["cycle"],
                 mask_info=(p.get("mask_rows"), p.get("mask_index_solved")),
                 gang_failed_uids=p.get("gang_failed_uids"),
+                gang_requeue_uids=p.get("gang_requeue_uids"),
                 span=fspan,
             )
         # every pod this batch placed is in the cache now: a snapshot
@@ -3270,6 +3559,7 @@ class BatchScheduler(Scheduler):
         pod_scheduling_cycle: int,
         mask_info=None,
         gang_failed_uids=None,
+        gang_requeue_uids=None,
         span=None,
     ) -> None:
         """Post-solve pipeline for the whole batch: Reserve -> assume ->
@@ -3307,6 +3597,7 @@ class BatchScheduler(Scheduler):
         plain_pis: List[PodInfo] = []  # placed pods on the bulk path ...
         clones: List = []  # ... their assumed clones ...
         hosts: List[str] = []  # ... and target nodes (parallel lists)
+        permit_at: List[int] = []  # ... those of them that Permit reads
         slow: List[Tuple[PodInfo, int, int]] = []  # (pod_info, choice, k)
 
         # -- fused fast path: when no per-pod gate can fire (default
@@ -3371,14 +3662,20 @@ class BatchScheduler(Scheduler):
                 ):
                     # quorum-masked gang member: no placement, no
                     # preemption (the group chose not to place; a
-                    # PodGroupMemberAdd wakeup retries once the group
-                    # can assemble)
+                    # PodGroupMemberAdd wakeup, or the wakeup of
+                    # capacity given back, retries once the group can
+                    # assemble). A member of a gang the fixup could not
+                    # decide goes round again at once
                     metrics.schedule_attempts.inc(result="unschedulable")
                     span.bump("gang_masked")
                     self.record_scheduling_failure(
                         prof, pi,
                         "pod group cannot reach minMember this cycle",
                         "Unschedulable", "", pod_scheduling_cycle,
+                        skip_backoff=bool(
+                            gang_requeue_uids
+                            and pi.pod.metadata.uid in gang_requeue_uids
+                        ),
                     )
                     self.pods_solved_on_device += 1
                     continue
@@ -3387,23 +3684,28 @@ class BatchScheduler(Scheduler):
                     continue
                 pod = pi.pod
                 if (
-                    bulk_ok
-                    and not (
+                    not bulk_ok
+                    or (
                         reserve_maybe
                         and prof.plugins_relevant("reserve", pod)
                     )
-                    and not (
-                        permit_maybe
-                        and prof.plugins_relevant("permit", pod)
-                    )
-                    and not (
+                    or (
                         binder_extenders
                         and any(
                             e.is_interested(pod) for e in binder_extenders
                         )
                     )
                 ):
+                    slow.append((pi, choice, k))
+                elif not (
+                    permit_maybe and prof.plugins_relevant("permit", pod)
+                ):
                     plain.append((pi, names[choice]))
+                elif prof.permit_batchable(pod):
+                    # assumed in bulk with the plain pods, then Permit
+                    # once for all of them (a gang at a time)
+                    plain.append((pi, names[choice]))
+                    permit_at.append(len(plain) - 1)
                 else:
                     slow.append((pi, choice, k))
             if plain:
@@ -3456,6 +3758,11 @@ class BatchScheduler(Scheduler):
                 ]
             self.pods_solved_on_device += len(plain_pis)
             span.bump("placed", len(plain_pis))
+            if permit_at:
+                bulk = self._permit_assumed(
+                    prof, bulk, permit_at, errs, snapshot,
+                    pod_scheduling_cycle, span,
+                )
 
         failed_group: List[Tuple[PodInfo, FitError]] = []
         cluster_anti = None
@@ -3655,6 +3962,126 @@ class BatchScheduler(Scheduler):
                 prof_d, state_d, pi_d, assumed_d, host_d,
                 pod_scheduling_cycle,
             )
+
+    def _permit_assumed(
+        self, prof, bulk, permit_at, errs, snapshot, pod_scheduling_cycle,
+        span,
+    ) -> List[Tuple]:
+        """Permit for the pods of a commit that were assumed in bulk and
+        that a Permit plugin reads (gang members), in one call a plugin.
+        ``permit_at`` indexes the commit's bulk-assumed pods, ``errs``
+        their assume errors. Returns ``bulk`` less the pods that do not
+        go on to the bulk bind: one that has to wait takes a binding
+        cycle of its own, which waits for its gang at Permit; one that
+        was refused gives its node back."""
+        # ``bulk`` lost the pods whose assume failed: index it as the
+        # commit's bulk-assumed pods were
+        at = {}
+        n = 0
+        for i, err in enumerate(errs):
+            if err is None:
+                at[i] = n
+                n += 1
+        rows = [at[i] for i in permit_at if i in at]
+        with flightrecorder.stage(
+            "commit.permit", totals=self.stage_totals, batch=span.batch_id,
+            pods=len(rows),
+        ) as permit:
+            waiting_before = len(prof.waiting_pods)
+            statuses = prof.run_permit_plugins_batch(
+                [bulk[r][3] for r in rows], [bulk[r][4] for r in rows]
+            )
+            drop = set()
+            parked: dict = {}  # gang -> its members that have to wait
+            waiting = rejected = 0
+            for r, status in zip(rows, statuses):
+                if status is None or status.is_success():
+                    continue
+                drop.add(r)
+                _prof, _state, pi, assumed, host = bulk[r]
+                state = CycleState()
+                state.write(SNAPSHOT_STATE_KEY, snapshot)
+                if status.code == StatusCode.WAIT:
+                    waiting += 1
+                    parked.setdefault(
+                        assumed.metadata.labels.get(POD_GROUP_LABEL), []
+                    ).append((prof, state, pi, assumed, host))
+                    continue
+                rejected += 1
+                self._permit_refused(
+                    prof, state, pi, assumed, host, status,
+                    pod_scheduling_cycle,
+                )
+            for items in parked.values():
+                # a thread a gang, not one of the bind pool's a pod: a
+                # pool whose threads all wait at Permit binds nothing,
+                # the gangs that were let through included
+                with self._inflight_lock:
+                    self._inflight_binds += 1
+                threading.Thread(
+                    target=self._permit_wait_cycle, name="permit-wait",
+                    args=(prof, items, pod_scheduling_cycle, snapshot),
+                    daemon=True,
+                ).start()
+            permit.set_metadata(
+                groups=len({
+                    bulk[r][3].metadata.labels.get(POD_GROUP_LABEL)
+                    for r in rows
+                }),
+                waiting=waiting, rejected=rejected,
+                # members of earlier batches that this batch's let go
+                released=max(
+                    0, waiting_before + waiting - len(prof.waiting_pods)
+                ),
+            )
+        if not drop:
+            return bulk
+        return [item for r, item in enumerate(bulk) if r not in drop]
+
+    def _permit_refused(
+        self, prof, state, pi, assumed, host, status, pod_scheduling_cycle
+    ) -> None:
+        """Permit refused the assumed pod, at once or after a wait: it
+        gives its node back and takes a failure record."""
+        self._forget(assumed)
+        prof.run_unreserve_plugins(state, assumed, host)
+        self.record_scheduling_failure(
+            prof, pi, status.message(),
+            "Unschedulable" if status.is_unschedulable()
+            else "SchedulerError", "", pod_scheduling_cycle,
+        )
+
+    def _permit_wait_cycle(
+        self, prof, items, pod_scheduling_cycle, snapshot
+    ) -> None:
+        """The binding cycle of one gang's members that a commit parked
+        at Permit together: wait for each (they are let go, rejected or
+        timed out together), give back the nodes of those that were not
+        let through, and bind the rest in one transaction."""
+        try:
+            ready = []
+            for item in items:
+                _prof, state, pi, assumed, host = item
+                status = prof.wait_on_permit(assumed)
+                if status is None or status.is_success():
+                    ready.append(item)
+                    continue
+                self._permit_refused(
+                    prof, state, pi, assumed, host, status,
+                    pod_scheduling_cycle,
+                )
+            if ready:
+                self._bulk_binding_cycle(
+                    ready, pod_scheduling_cycle, snapshot
+                )
+        except SchedulerCrashed:
+            self._simulate_crash()
+        except Exception:
+            logger.exception("permit wait cycle crashed")
+        finally:
+            with self._inflight_lock:
+                self._inflight_binds -= 1
+                self._inflight_lock.notify_all()
 
     def _flush_deferred_preemptions(self) -> None:
         """Run one preemption wave for every parked failure, grouped by

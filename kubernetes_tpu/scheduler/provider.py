@@ -70,7 +70,11 @@ def default_plugins() -> Plugins:
         # (scheduler.go:693 bindVolumes); this build routes it through the
         # PreBind extension point of the same plugin (volumes.py docstring)
         reserve=PluginSet(enabled=[P("NodeResourcesNumaAligned")]),
-        unreserve=PluginSet(enabled=[P("NodeResourcesNumaAligned")]),
+        # Coscheduling: a member that gives its node back leaves its
+        # gang's count of holders (no-op without a pod-group label)
+        unreserve=PluginSet(
+            enabled=[P("NodeResourcesNumaAligned"), P("Coscheduling")]
+        ),
         pre_bind=PluginSet(enabled=[P("VolumeBinding")]),
         # gang scheduling: the out-of-tree coscheduling pattern, enabled by
         # default in this build (no-op for pods without a pod-group label)

@@ -31,6 +31,7 @@ from kubernetes_tpu.framework.interface import (
 from kubernetes_tpu.framework.registry import Registry
 from kubernetes_tpu.framework.runtime import Framework
 from kubernetes_tpu.plugins import new_in_tree_registry
+from kubernetes_tpu.queue import events
 from kubernetes_tpu.queue.scheduling_queue import PriorityQueue
 from kubernetes_tpu.robustness.circuit import RetryPolicy
 from kubernetes_tpu.robustness.faults import (
@@ -173,6 +174,18 @@ class Scheduler:
         # re-charges at its next pop): ``used`` stays bound + in-flight,
         # and the refund's headroom event may wake quota-parked peers
         self._quota_refund(pod, "requeue")
+        informers = prof.informers
+        if informers is not None:
+            live = informers.pods().get(
+                pod.metadata.namespace, pod.metadata.name
+            )
+            if live is None or live.metadata.uid != pod.metadata.uid:
+                # deleted while it was being scheduled: its DELETED event
+                # found it in no queue, and requeueing it now would keep
+                # a pod that no longer exists going round, taking room in
+                # every solve it joins (factory.go MakeDefaultErrorFunc:
+                # "pod doesn't exist in informer cache")
+                return
         prof.recorder.eventf(
             pod, "Warning", "FailedScheduling", err_msg
         )  # scheduler.go:378
@@ -680,6 +693,12 @@ class Scheduler:
             self.cache.forget_pod(assumed)
         except Exception:
             logger.exception("forgetting pod %s", assumed.key())
+        # the node it held is free again, as after a bound pod's delete:
+        # what is parked for want of room retries (a gang that was masked
+        # while members of another waited at Permit in vain)
+        self.queue.move_all_to_active_or_backoff_queue(
+            events.AssumedPodForget
+        )
 
     def wait_for_inflight_binds(self, timeout: float = 30.0) -> bool:
         """Test/bench helper: block until async binding cycles drain."""
