@@ -49,6 +49,24 @@ def _strip_memos(obj: Any) -> None:
     for k in _ALL_MEMOS:
         d.pop(k, None)
 
+
+def _cow_copy(old: Any) -> Any:
+    """The clone an arbitrary update mutates (guaranteed_update's
+    copy-on-write): the object and its metadata / spec / status copied
+    one level deep, every scheduler memo dropped."""
+    cow_attrs = tuple(a for a in _POD_COW_ATTRS if hasattr(old, a))
+    if _cow_clone is not None:
+        obj = _cow_clone(old, cow_attrs)
+    else:
+        import copy as _copy
+
+        obj = _copy.copy(old)
+        for attr in cow_attrs:
+            setattr(obj, attr, _copy.copy(getattr(old, attr)))
+    _strip_memos(obj)
+    return obj
+
+
 ADDED = "ADDED"
 MODIFIED = "MODIFIED"
 DELETED = "DELETED"
@@ -511,21 +529,10 @@ class APIServer:
         gets this for free from serialization; mutators must not mutate
         nested collections in place.
         """
-        import copy as _copy
-
         _api_unavailable_maybe()
         with self._lock:
             old = self.get(kind, namespace, name)
-            cow_attrs = tuple(
-                a for a in _POD_COW_ATTRS if hasattr(old, a)
-            )
-            if _cow_clone is not None:
-                obj = _cow_clone(old, cow_attrs)
-            else:
-                obj = _copy.copy(old)
-                for attr in cow_attrs:
-                    setattr(obj, attr, _copy.copy(getattr(old, attr)))
-            _strip_memos(obj)
+            obj = _cow_copy(old)
             mutate(obj)
             obj.metadata.resource_version = self._next_rv()
             self._stores[kind][(namespace, name)] = obj
@@ -975,3 +982,40 @@ class APIServer:
             mutate(p)
 
         return self.guaranteed_update("Pod", namespace, name, wrap)
+
+    def update_pod_status_bulk(
+        self, updates: List[Tuple[str, str, Callable[[Pod], None]]]
+    ) -> List[Tuple[int, Exception]]:
+        """Many pods' status writes, ``(namespace, name, mutate)`` each,
+        as ONE transaction: one store lock hold and one bulk watch
+        fan-out, so a watcher takes the echoes as one frame. Per pod it
+        is ``update_pod_status``: a copy-on-write clone, the mutate, a
+        resource version and a MODIFIED event of its own. Returns only
+        the failed slots as (index, error), ``bind_assumed_bulk``'s
+        shape: a pod that is gone (or whose mutate raised) fails its
+        slot and leaves the others written."""
+        _api_unavailable_maybe()
+        errors: List[Tuple[int, Exception]] = []
+        events: List[WatchEvent] = []
+        with self._lock:
+            store = self._stores["Pod"]
+            for i, (namespace, name, mutate) in enumerate(updates):
+                old = store.get((namespace, name))
+                if old is None:
+                    errors.append(
+                        (i, NotFound(f"Pod {namespace}/{name} not found"))
+                    )
+                    continue
+                pod = _cow_copy(old)
+                try:
+                    mutate(pod)
+                except Exception as e:  # noqa: BLE001 - per-slot result
+                    errors.append((i, e))
+                    continue
+                pod.metadata.resource_version = self._next_rv()
+                store[(namespace, name)] = pod
+                events.append(
+                    WatchEvent(MODIFIED, pod, pod.metadata.resource_version)
+                )
+            self._broadcast_many("Pod", events)
+        return errors

@@ -71,6 +71,14 @@ class Client:
     ) -> Pod:
         return self._server.update_pod_status(namespace, name, mutate)
 
+    def update_pod_status_bulk(
+        self, updates: List[Tuple[str, str, Callable[[Pod], None]]]
+    ):
+        """One transaction writing many pods' status, ``(namespace,
+        name, mutate)`` each; returns only the failed slots as (index,
+        error)."""
+        return self._server.update_pod_status_bulk(updates)
+
     def unbind_pod(
         self, namespace: str, name: str,
         expect_uid: Optional[str] = None,

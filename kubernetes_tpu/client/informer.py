@@ -87,7 +87,8 @@ class ResourceEventHandler:
         # optional whole-frame handler: receives [(type, old, new)] raw
         # (unfiltered) and replaces the per-event dispatch -- lets hot
         # consumers (cache/queue bridges) amortize their locks over a
-        # watch frame; the handler applies filter semantics itself
+        # watch frame; the handler applies filter semantics itself, and
+        # may return a dict of stats for the frame's ingest span
         self.on_batch = on_batch
         # the consumer's stage totals: the informer adds each frame it
         # applies (store update through this handler's return) as
@@ -196,10 +197,12 @@ class Informer:
         with flightrecorder.stage(
             "ingest", totals=self._stage_totals,
             kind=self.kind, events=len(evs),
-        ):
-            self._apply_batch_inner(evs)
+        ) as ingest:
+            stats = self._apply_batch_inner(evs)
+            if stats:
+                ingest.set_metadata(**stats)
 
-    def _apply_batch_inner(self, evs: List[WatchEvent]) -> None:
+    def _apply_batch_inner(self, evs: List[WatchEvent]) -> dict:
         fn, expected = _native.ingest_fn("ingest_apply")
         with self._lock:
             if fn is not None:
@@ -210,15 +213,20 @@ class Informer:
                         site="informer-apply"
                     )
                 dispatch = _apply_events_py(self._store, evs)
-        self._dispatch(dispatch)
+        return self._dispatch(dispatch)
 
-    def _dispatch(self, dispatch: List) -> None:
+    def _dispatch(self, dispatch: List) -> dict:
+        """Hand a frame to every handler; what the whole-frame handlers
+        return (a dict of stats about the frame, or nothing) goes on the
+        frame's ``sched/ingest`` span."""
+        stats: dict = {}
         for h in self._handlers:
             if h.on_batch is not None:
-                h.on_batch(dispatch)
+                stats.update(h.on_batch(dispatch) or ())
             else:
                 for etype, old, obj in dispatch:
                     h.handle(etype, old, obj)
+        return stats
 
     def _relist(self) -> None:
         """Relist-on-watch-error (reference Reflector ListAndWatch

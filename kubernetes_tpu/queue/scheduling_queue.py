@@ -193,6 +193,10 @@ class PriorityQueue:
         # the pod when a PARKED entry is released by a real spec
         # update, so the owner can clear the PodQuarantined condition
         self.on_quarantine_release = None
+        # status echoes that ``update`` ignored (a pod in none of the
+        # maps, nothing but status changed); the metric
+        # scheduler_queue_echoes_ignored_total counts the same
+        self.echoes_ignored = 0
         self.nominated_pods = _NominatedPodMap()
 
         self.scheduling_cycle = 0
@@ -380,19 +384,65 @@ class PriorityQueue:
                 self.active_q.add(pi)
                 self._cond.notify()
                 return
-            pi.timestamp = self._now()
-            if self.move_request_cycle >= pod_scheduling_cycle:
-                self.pod_backoff_q.add(pi)
-            else:
-                self.unschedulable_q[key] = pi
-            self.nominated_pods.add(pi.pod, "")
+            self._park_failed_locked(pi, key, pod_scheduling_cycle)
+            self._cond.notify()
+
+    def _park_failed_locked(
+        self, pi: PodInfo, key: str, pod_scheduling_cycle: int
+    ) -> None:
+        """A failed pod's place when it must wait: the backoffQ if a
+        move request came during its attempt, else the unschedulableQ."""
+        pi.timestamp = self._now()
+        if self.move_request_cycle >= pod_scheduling_cycle:
+            self.pod_backoff_q.add(pi)
+        else:
+            self.unschedulable_q[key] = pi
+        self.nominated_pods.add(pi.pod, "")
+
+    def add_unschedulable_many(self, entries) -> None:
+        """A preemption wave's failed pods back into the queue as ONE
+        transaction: one lock hold and one wakeup, so a dispatcher that
+        waits in ``pop_batch`` finds the whole wave and not its first
+        few hundred. ``entries`` are ``(pod_info, pod_scheduling_cycle,
+        skip_backoff, nominated_node)``; each pod goes where
+        ``add_unschedulable_if_not_present`` would send it, a pod that
+        is already queued stays as it is (the per-pod call's KeyError),
+        and a nominated node is set in the nomination map as
+        ``update_nominated_pod_for_node`` does, queued or not."""
+        if not entries:
+            return
+        with self._cond:
+            to_active: Dict[str, PodInfo] = {}
+            for pi, pod_scheduling_cycle, skip_backoff, node in entries:
+                key = _info_key(pi)
+                if not (
+                    key in to_active
+                    or key in self.unschedulable_q
+                    or key in self.active_q
+                    or key in self.pod_backoff_q
+                ):
+                    if skip_backoff:
+                        # the original enqueue timestamp and no touch of
+                        # the nomination map, as in the per-pod call
+                        to_active[key] = pi
+                    else:
+                        self._park_failed_locked(
+                            pi, key, pod_scheduling_cycle
+                        )
+                if node:
+                    self.nominated_pods.add(pi.pod, node)
+            self.active_q.add_bulk(
+                list(to_active.values()), list(to_active)
+            )
             self._cond.notify()
 
     def update(self, old_pod: Optional[Pod], new_pod: Pod) -> None:
         """Reference :417: in active/backoff -> update in place; in
         unschedulableQ -> move to activeQ if the update may make it
         schedulable (we conservatively always move, matching
-        isPodUpdated=true paths)."""
+        isPodUpdated=true paths). A pod in none of the queue's maps is
+        added only by a real change (``_is_pod_updated``): the echo of a
+        status write for a pod the scheduler holds adds nothing."""
         with self._cond:
             key = _pod_key(new_pod)
             existing = self.active_q.get_by_key(key)
@@ -475,6 +525,17 @@ class PriorityQueue:
                         self.on_quarantine_release(pi.pod)
                     except Exception:
                         pass  # releasing must never fail on bookkeeping
+                return
+            if not _is_pod_updated(old_pod, new_pod):
+                # in none of the queue's maps and a status-only change:
+                # the scheduler holds the pod (popped into a batch,
+                # parked for a preemption wave, waiting at Permit) or it
+                # has just bound, and this is the echo of a status write
+                # -- most often the scheduler's own failure record.
+                # Adding it would schedule the pod a second time beside
+                # the record the scheduler holds
+                self.echoes_ignored += 1
+                metrics.queue_echoes_ignored.inc()
                 return
             self.add(new_pod)
 

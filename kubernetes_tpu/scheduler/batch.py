@@ -4154,21 +4154,33 @@ class BatchScheduler(Scheduler):
             with flightrecorder.stage(
                 "preempt_requeue", totals=self.stage_totals,
                 pods=len(items),
-            ):
-                for (pi, fe, cycle), node in zip(items, nominated):
-                    if self.cache.has_pod_uid(pi.pod.metadata.uid):
-                        # stale parked record: the pod bound during the
-                        # deferral window (an informer update re-added
-                        # it); requeueing it would double-place a
-                        # running pod
-                        continue
-                    self.record_scheduling_failure(
-                        prof, pi, str(fe), "Unschedulable", node, cycle,
+            ) as requeue:
+                # the wave's failure records as ONE hand-back: the
+                # dispatcher wakes to all of them and retries them as
+                # one batch, the informer takes their status echoes as
+                # one frame
+                records = [
+                    (
+                        pi, str(fe), node, cycle,
                         # no-backoff retry only when the wave actually
                         # evicted: otherwise the failure is persistent
                         # and the 1s backoff must damp it
-                        skip_backoff=bool(node) and evict_ok,
+                        bool(node) and evict_ok,
                     )
+                    for (pi, fe, cycle), node in zip(items, nominated)
+                    # stale parked record: the pod is in the cache,
+                    # assumed or bound, so another record of it was
+                    # placed during the deferral window (a real update
+                    # re-added it: the queue ignores a status echo for
+                    # a pod it does not hold); requeueing it would
+                    # double-place a running pod
+                    if not self.cache.has_pod_uid(pi.pod.metadata.uid)
+                ]
+                stats = self.record_scheduling_failures(
+                    prof, records, "Unschedulable"
+                )
+                stats["stale"] += len(items) - len(records)
+                requeue.set_metadata(**stats)
             if any(nominated):
                 # once these preemptors bind, the cluster is full again:
                 # refresh the victim pack so the NEXT contention wave

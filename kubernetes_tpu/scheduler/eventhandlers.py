@@ -9,7 +9,7 @@ event strings.
 from __future__ import annotations
 
 import logging
-from typing import TYPE_CHECKING
+from typing import TYPE_CHECKING, Optional
 
 from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.client.informer import InformerFactory, ResourceEventHandler
@@ -261,7 +261,7 @@ def add_all_event_handlers(
         elif _responsible_for_pod(sched, pod):
             delete_pod_from_queue(pod)
 
-    def pods_batch(frame) -> None:
+    def pods_batch(frame) -> Optional[dict]:
         """One classification pass builds per-side ordered op-run lists;
         execution then replays the WHOLE cache side before the queue side
         -- exactly the old two-filtered-handler order (assigned handler
@@ -376,6 +376,7 @@ def add_all_event_handlers(
                 elif new.spec.scheduler_name in profiles:
                     queue_runs.append(("del_one", new))
 
+        echoes_before = sched.queue.echoes_ignored
         # cache phase (whole frame), then queue phase
         for kind, payload in cache_runs:
             if kind == "adds":
@@ -447,6 +448,10 @@ def add_all_event_handlers(
                 update_pod_in_queue(*payload)
             else:
                 delete_pod_from_queue(payload)
+        # status echoes of pods the scheduler holds, which the queue
+        # ignored: a stat of the frame's ingest span when there were any
+        echoes = sched.queue.echoes_ignored - echoes_before
+        return {"echoes_ignored": echoes} if echoes else None
 
     pods.add_event_handler(
         ResourceEventHandler(

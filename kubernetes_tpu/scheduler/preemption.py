@@ -1320,14 +1320,14 @@ class Preemptor:
         ``delete_victims=False``
         lets preempt_batch evict the whole group's victims in one
         transaction afterwards. ``write_status=False`` skips the API
-        nominatedNodeName write: the batched path defers it to
-        record_scheduling_failure's condition write, which happens
-        immediately after the pod is requeued -- the watch ECHO of a
-        status write arrives as a pod update, and an update for a pod
-        that is in no queue re-adds it to the activeQ
-        (scheduling_queue.update), so a write issued while the pod is
-        still parked for the wave creates a DUPLICATE scheduling of the
-        same pod (phantom demand, cascading over-eviction)."""
+        nominatedNodeName write: the batched path defers it to the
+        wave's bulk failure record (record_scheduling_failures), which
+        writes every preemptor's condition and nomination in ONE status
+        transaction right after the wave is requeued. The watch ECHO of
+        such a write no longer re-adds a pod that is in no queue
+        (scheduling_queue.update ignores a status-only update for a pod
+        the scheduler holds: no DUPLICATE scheduling, no phantom
+        demand), so the deferral saves a write a pod, nothing more."""
         self.queue.update_nominated_pod_for_node(pod, node_name)
         if self.client is not None and write_status:
             try:

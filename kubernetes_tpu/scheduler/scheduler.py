@@ -47,6 +47,30 @@ from kubernetes_tpu.utils import flightrecorder, metrics
 logger = logging.getLogger(__name__)
 
 
+def _failure_condition(
+    reason: str, err_msg: str, nominated_node: str
+) -> Callable[[Pod], None]:
+    """The status write of one failure record (scheduler.go:381), as a
+    mutate for ``update_pod_status``: PodScheduled=False with the reason
+    and the message, and the nominated node where the pod has one."""
+
+    def set_condition(p: Pod) -> None:
+        p.status.conditions = [
+            c for c in p.status.conditions if c.type != "PodScheduled"
+        ] + [
+            PodCondition(
+                type="PodScheduled",
+                status="False",
+                reason=reason,
+                message=err_msg,
+            )
+        ]
+        if nominated_node:
+            p.status.nominated_node_name = nominated_node
+
+    return set_condition
+
+
 class Scheduler:
     def __init__(
         self,
@@ -199,25 +223,79 @@ class Scheduler:
             self.queue.update_nominated_pod_for_node(pod, nominated_node)
         if self.client is not None:
             try:
-                def set_condition(p: Pod) -> None:
-                    p.status.conditions = [
-                        c for c in p.status.conditions if c.type != "PodScheduled"
-                    ] + [
-                        PodCondition(
-                            type="PodScheduled",
-                            status="False",
-                            reason=reason,
-                            message=err_msg,
-                        )
-                    ]
-                    if nominated_node:
-                        p.status.nominated_node_name = nominated_node
-
                 self.client.update_pod_status(
-                    pod.metadata.namespace, pod.metadata.name, set_condition
+                    pod.metadata.namespace, pod.metadata.name,
+                    _failure_condition(reason, err_msg, nominated_node),
                 )
             except Exception:
                 logger.exception("updating pod condition for %s", pod.key())
+
+    def record_scheduling_failures(
+        self, prof: Framework, records, reason: str
+    ) -> dict:
+        """``record_scheduling_failure`` for a preemption wave's pods as
+        one hand-back: ``records`` are ``(pod_info, err_msg,
+        nominated_node, pod_scheduling_cycle, skip_backoff)``. Per pod
+        it does what the per-pod call does (quota refund, the informer's
+        liveness check, the FailedScheduling event, the queue insert
+        under the pod's own ``skip_backoff``, the nomination, the
+        PodScheduled=False condition and nominatedNodeName through the
+        API), but the queue takes the wave in ONE transaction and the
+        API in ONE: the dispatcher wakes to the whole wave and retries
+        it as one batch, and the informer takes the status echoes as one
+        frame. Queue first, status second, as in the per-pod call.
+        Returns the hand-back's stats: ``records`` (conditions written),
+        ``stale`` (pods deleted meanwhile, dropped) and ``transactions``
+        (status transactions made)."""
+        pods = prof.informers.pods() if prof.informers is not None else None
+        live = []
+        for rec in records:
+            pod = rec[0].pod
+            self._quota_refund(pod, "requeue")
+            if pods is not None:
+                seen = pods.get(pod.metadata.namespace, pod.metadata.name)
+                if seen is None or seen.metadata.uid != pod.metadata.uid:
+                    continue  # deleted while it was being scheduled
+            live.append(rec)
+        stats = {
+            "records": 0, "stale": len(records) - len(live),
+            "transactions": 0,
+        }
+        if not live:
+            return stats
+        prof.recorder.eventf_many([
+            (pi.pod, "Warning", "FailedScheduling", err_msg)
+            for pi, err_msg, _node, _cycle, _skip in live
+        ])
+        self.queue.add_unschedulable_many([
+            (pi, cycle, skip_backoff, node)
+            for pi, _msg, node, cycle, skip_backoff in live
+        ])
+        if self.client is None:
+            return stats
+        stats["transactions"] = 1
+        try:
+            errors = self.client.update_pod_status_bulk([
+                (
+                    pi.pod.metadata.namespace, pi.pod.metadata.name,
+                    _failure_condition(reason, err_msg, node),
+                )
+                for pi, err_msg, node, _cycle, _skip in live
+            ])
+        except Exception:
+            # the pods stay requeued, as after the per-pod call's except
+            logger.exception(
+                "updating pod conditions for %d pods", len(live)
+            )
+            return stats
+        stats["records"] = len(live) - len(errors)
+        if errors:
+            slot, err = errors[0]
+            logger.warning(
+                "updating pod conditions: %d of %d failed, first %s: %s",
+                len(errors), len(live), live[slot][0].pod.key(), err,
+            )
+        return stats
 
     # -- multi-tenant quota gate (controllers/quota.py) ----------------------
 

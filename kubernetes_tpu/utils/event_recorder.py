@@ -198,3 +198,6 @@ class NullRecorder:
 
     def eventf(self, obj, event_type, reason, message) -> None:
         return None
+
+    def eventf_many(self, items) -> None:
+        return None

@@ -434,6 +434,14 @@ ingest_native_fallbacks = registry.register(Counter(
     "the configured path and books nothing here.",
     ("site",),
 ))
+queue_echoes_ignored = registry.register(Counter(
+    "scheduler_queue_echoes_ignored_total",
+    "Pod updates that reached the scheduling queue for a pod in none of "
+    "its maps and changed nothing but status: the echo of a status write "
+    "for a pod the scheduler holds (popped, parked for a preemption wave, "
+    "at Permit) or that has just bound. Ignored, where it used to add a "
+    "second record of the pod.",
+))
 commit_join_timeouts = registry.register(Counter(
     "scheduler_commit_thread_join_timeouts_total",
     "Committer threads that failed to join at shutdown.",

@@ -646,6 +646,149 @@ def test_high_priority_tail_guard():
         informers.stop()
 
 
+# -- a wave hands its preemptors back as one retry batch ---------------------
+
+
+@pytest.mark.parametrize("echoes", ["before", "during", "after"])
+def test_wave_hands_its_preemptors_back_as_one_retry_batch(echoes):
+    """A full cluster and N preemptors in one burst: one failing batch,
+    ONE program wave that searches each preemptor once and evicts N
+    residents, ONE retry batch that binds every preemptor exactly once.
+    The watch's echoes of the wave's status writes reach the queue
+    ``before`` the dispatcher pops the retry batch (the pods are in the
+    activeQ), ``after`` it (the scheduler holds them: the queue must add
+    no second record) or as the threads happen to run (``during``)."""
+    n = 16
+    server, client, informers, sched = _e2e(8, "4", max_batch=256)
+    pods_informer = informers.pods()
+    echo_gate = threading.Event()   # set: status echoes may be ingested
+    pop_gate = threading.Event()    # set: the dispatcher may pop
+    first_pop = threading.Event()   # the burst's failing batch was popped
+    retry_pop = threading.Event()   # the retry batch was popped
+    pop_gate.set()
+    if echoes != "after":
+        echo_gate.set()
+
+    apply_batch = pods_informer._apply_batch
+
+    def gated_apply(evs):
+        if any(
+            ev.type == "MODIFIED"
+            and ev.object.metadata.name.startswith("high-")
+            and not ev.object.spec.node_name
+            for ev in evs
+        ):
+            assert echo_gate.wait(60), "the echoes were never released"
+        apply_batch(evs)
+
+    pods_informer._apply_batch = gated_apply
+    pop_batch = sched.queue.pop_batch
+
+    def gated_pop(*args, **kwargs):
+        assert pop_gate.wait(60), "the dispatcher was never released"
+        batch = pop_batch(*args, **kwargs)
+        if any(pi.pod.metadata.name.startswith("high-") for pi in batch):
+            if not first_pop.is_set():
+                first_pop.set()
+                if echoes == "before":
+                    pop_gate.clear()  # hold the pop of the retry batch
+            else:
+                retry_pop.set()
+        return batch
+
+    sched.queue.pop_batch = gated_pop
+    informers.start()
+    informers.wait_for_cache_sync()
+    sched.queue.run()
+    try:
+        low_names = [f"low-{i}" for i in range(32)]
+        client.create_pods_bulk([
+            make_pod(nm).container(cpu="1", memory="128Mi")
+            .priority(0).obj()
+            for nm in low_names
+        ])
+        sched.start()
+        assert _wait_named_bound(client, low_names, 120), (
+            "the residents never filled the cluster"
+        )
+        sched.wait_for_inflight_binds(timeout=60)
+        batches0 = sched.batches_solved
+        assert sched.preemptor.waves == 0
+
+        high_names = [f"high-{i}" for i in range(n)]
+        client.create_pods_bulk([
+            make_pod(nm).container(cpu="1", memory="128Mi")
+            .priority(100).obj()
+            for nm in high_names
+        ])
+        if echoes == "before":
+            # the echoes land while the retry batch waits in the activeQ
+            deadline = time.time() + 60
+            while not all(
+                (p := pods_informer.get("default", nm)) is not None
+                and p.status.conditions
+                for nm in high_names
+            ):
+                assert time.time() < deadline, "echoes never ingested"
+                time.sleep(0.01)
+            assert not retry_pop.is_set()
+            pop_gate.set()
+        elif echoes == "after":
+            # ... or only once the scheduler holds every preemptor
+            assert retry_pop.wait(60), "the retry batch was never popped"
+            echo_gate.set()
+        assert _wait_named_bound(client, high_names, 120), (
+            "the preemptors did not all bind"
+        )
+        sched.wait_for_inflight_binds(timeout=60)
+        # let a second record, if the queue made one, fail and be parked
+        time.sleep(0.5)
+
+        pre = sched.preemptor
+        assert pre.waves == 1
+        assert pre.device_preemptions == n and pre.host_preemptions == 0
+        assert pre.searched_again == {}
+        assert sum(pre.ladder.solves_by_tier.values()) == 1
+        assert sum(pre.victims_by_tier.values()) == n
+        # the failing batch and ONE retry batch
+        assert sched.batches_solved - batches0 == 2
+        pods, _ = client.list_pods()
+        left = {p.metadata.name for p in pods}
+        assert len(set(low_names) - left) == n, "N victims and no more"
+        assert set(high_names) <= left
+        transitions = _bind_transitions_by_uid(server)
+        assert all(
+            transitions.get(p.metadata.uid) == 1
+            for p in pods if p.metadata.name in high_names
+        )
+        assert sched.queue.num_pending() == {
+            "active": 0, "backoff": 0, "unschedulable": 0,
+        }
+        for p in pods:
+            if p.metadata.name in high_names:
+                # the record is still written for every preemptor
+                (cond,) = [
+                    c for c in p.status.conditions
+                    if c.type == "PodScheduled"
+                ]
+                assert (cond.status, cond.reason) == (
+                    "False", "Unschedulable"
+                )
+                assert p.status.nominated_node_name == p.spec.node_name
+        ignored = sched.queue.echoes_ignored
+        if echoes == "before":
+            assert ignored == 0
+        elif echoes == "after":
+            assert ignored == n
+        else:
+            assert 0 <= ignored <= n
+    finally:
+        pop_gate.set()
+        echo_gate.set()
+        sched.stop()
+        informers.stop()
+
+
 # -- preemption-chaos e2e --------------------------------------------------
 
 

@@ -1,3 +1,5 @@
+import pytest
+
 from kubernetes_tpu.framework.interface import PodInfo
 from kubernetes_tpu.queue import events
 from kubernetes_tpu.queue.heap import Heap
@@ -151,3 +153,107 @@ def test_update_in_unschedulable_moves_to_active():
     updated = make_pod("p1").labels(v="2").obj()
     q.update(pi.pod, updated)
     assert q.num_pending()["active"] == 1
+
+
+# -- update() for a pod by where the queue holds it -------------------------
+
+# what an update changes: the first two nothing that _is_pod_updated
+# reads (a status write's echo), the others something it does
+_STATUS_ONLY = ("condition", "nominated_node_name")
+_CHANGES = _STATUS_ONLY + (
+    "spec", "labels", "annotations", "deletion_timestamp",
+    "owner_references", "old_none",
+)
+
+
+def _changed(old, change):
+    import copy
+
+    from kubernetes_tpu.api.types import OwnerReference, PodCondition
+
+    new = copy.deepcopy(old)
+    new.metadata.resource_version = old.metadata.resource_version + 1
+    if change == "condition":
+        new.status.conditions.append(
+            PodCondition(
+                type="PodScheduled", status="False", reason="Unschedulable"
+            )
+        )
+    elif change == "nominated_node_name":
+        new.status.nominated_node_name = "n1"
+    elif change == "spec":
+        new.spec.priority = old.spec.priority + 1
+    elif change == "labels":
+        new.metadata.labels["v"] = "2"
+    elif change == "annotations":
+        new.metadata.annotations["note"] = "x"
+    elif change == "deletion_timestamp":
+        new.metadata.deletion_timestamp = 12.0
+    elif change == "owner_references":
+        new.metadata.owner_references.append(
+            OwnerReference(kind="ReplicaSet", name="rs", uid="u1")
+        )
+    else:
+        assert change == "old_none"
+    return (None if change == "old_none" else old), new
+
+
+@pytest.mark.parametrize("change", _CHANGES)
+@pytest.mark.parametrize(
+    "place", ["held", "active", "backoff", "unschedulable"]
+)
+def test_update_by_place_and_change(place, change):
+    """``update`` for a pod in each of the queue's places. A pod in NONE
+    of its maps is held by the scheduler (popped into a batch, parked
+    for a preemption wave, at Permit) or has just bound: the echo of a
+    status write adds nothing and is counted, a real change (or no old
+    object to compare with) adds the pod as before. A queued pod
+    behaves as it always has."""
+    from kubernetes_tpu.utils import metrics
+
+    now = [0.0]
+    q = _pq(now)
+    q.add(make_pod("p1").obj())
+    if place != "active":
+        pi = q.pop()
+        if place == "backoff":
+            # a move request during the attempt sends it to the backoffQ
+            q.move_all_to_active_or_backoff_queue(events.NodeAdd)
+        if place != "held":
+            q.add_unschedulable_if_not_present(pi, q.scheduling_cycle)
+    now[0] = 5.0  # past the 1 s backoff: a woken pod goes to the activeQ
+    before = dict(active=0, backoff=0, unschedulable=0)
+    if place != "held":
+        before[place] = 1
+    assert q.num_pending() == before
+    counted = metrics.queue_echoes_ignored.value()
+    old = (
+        q.active_q.get_by_key("default/p1")
+        or q.pod_backoff_q.get_by_key("default/p1")
+        or q.unschedulable_q.get("default/p1")
+        or pi
+    ).pod
+    old, new = _changed(old, change)
+
+    q.update(old, new)
+
+    status_only = change in _STATUS_ONLY
+    ignored = int(place == "held" and status_only)
+    assert q.echoes_ignored == ignored
+    assert metrics.queue_echoes_ignored.value() - counted == ignored
+    after = dict(before)
+    if place == "held" and not status_only:
+        after["active"] = 1
+    elif place == "unschedulable" and not status_only:
+        after = dict(active=1, backoff=0, unschedulable=0)
+    assert q.num_pending() == after
+    if place != "held":
+        # a queued pod's record follows the newest object in every case
+        held = (
+            q.active_q.get_by_key("default/p1")
+            or q.pod_backoff_q.get_by_key("default/p1")
+            or q.unschedulable_q.get("default/p1")
+        )
+        assert held.pod is new
+    elif not status_only:
+        assert q.active_q.get_by_key("default/p1").pod is new
