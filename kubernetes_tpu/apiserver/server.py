@@ -20,8 +20,9 @@ mutating (the same contract client-go informer caches impose).
 from __future__ import annotations
 
 import threading
+import time
 from bisect import bisect_right
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from kubernetes_tpu.api.types import POD_PENDING, POD_RUNNING, Binding, Node, Pod
@@ -129,6 +130,12 @@ class WatchEvent:
     #: every later cursor draining the same per-kind event log -- N
     #: partitioned informer sets decode each apiserver transaction once
     decoded: Any = None
+    #: ``time.perf_counter()`` at the broadcast, on the first event of
+    #: each transaction (0.0 on the rest): a watcher's drain takes the
+    #: whole log under the kind's condition, so a frame's first event
+    #: is a transaction's first and says how long the frame waited.
+    #: A stamp, not content: two events of the same content are equal
+    t: float = field(default=0.0, compare=False)
 
 
 class Watch:
@@ -408,6 +415,7 @@ class APIServer:
 
     def _broadcast(self, kind: str, event: WatchEvent) -> None:
         cond = self._kind_conds[kind]
+        event.t = time.perf_counter()
         with cond:
             hist = self._history[kind]
             hist.append(event)
@@ -423,6 +431,7 @@ class APIServer:
         if not events:
             return
         cond = self._kind_conds[kind]
+        events[0].t = time.perf_counter()
         with cond:
             hist = self._history[kind]
             hist.extend(events)

@@ -197,6 +197,9 @@ class Informer:
         with flightrecorder.stage(
             "ingest", totals=self._stage_totals,
             kind=self.kind, events=len(evs),
+            # how long the frame's oldest event waited, from the
+            # apiserver's broadcast, for this thread
+            **flightrecorder.handoff_wait(evs[0].t),
         ) as ingest:
             stats = self._apply_batch_inner(evs)
             if stats:
@@ -329,6 +332,7 @@ class Informer:
             self._initial_sync()
 
         def run() -> None:
+            flightrecorder.name_thread()
             while not self._stop.is_set():
                 evs = self._next_events(0.1)
                 if evs:

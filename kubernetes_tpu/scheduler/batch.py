@@ -859,6 +859,8 @@ class BatchScheduler(Scheduler):
             return
         if pending is None:
             return
+        # completed by this thread, at once: the hand-off waits nothing
+        pending["handed_off"] = time.perf_counter()
         try:
             self._complete_solve(pending)
         except SchedulerCrashed:
@@ -1594,6 +1596,7 @@ class BatchScheduler(Scheduler):
         dispatcher thread (which is already packing the next batch). A
         batch stays at the queue head until fully committed so
         _drain_pending and the dispatch-time pending checks see it."""
+        flightrecorder.name_thread()
         while True:
             with self._pending_cv:
                 while not self._pending_q and not self._committer_stop:
@@ -1661,8 +1664,15 @@ class BatchScheduler(Scheduler):
             return
         self._ensure_committer()
         with self._pending_cv:
-            while len(self._pending_q) >= self.max_inflight:
-                self._pending_cv.wait()
+            if len(self._pending_q) >= self.max_inflight:
+                # the pipeline is full: the dispatcher waits for the
+                # committer (which holds solve_wait or commit open)
+                with flightrecorder.stage(
+                    "inflight_wait", totals=self.stage_totals,
+                    batch=pending["span"].batch_id,
+                ):
+                    while len(self._pending_q) >= self.max_inflight:
+                        self._pending_cv.wait()
             if self._pending_q:
                 # the solve launched against the shadow-EXPECTED state
                 # of still-uncommitted batches: a speculative link in
@@ -1670,6 +1680,9 @@ class BatchScheduler(Scheduler):
                 # row-patch path instead of a drain)
                 self.speculative_launches += 1
                 metrics.speculative_launches.inc()
+            # the hand-off to the committer: solve_wait says how long
+            # the batch waited for that thread
+            pending["handed_off"] = time.perf_counter()
             self._pending_q.append(pending)
             self._pending_cv.notify_all()
 
@@ -2124,16 +2137,27 @@ class BatchScheduler(Scheduler):
             )
             nominated_by_node = self.queue.all_nominated_pods_by_node()
 
-            def drained(reason_predicate: bool) -> bool:
-                """Land every in-flight batch when the predicate holds, then
-                rebuild the drain-sensitive inputs (nominee overlay source;
-                callers refresh the snapshot themselves when they hold one).
-                Returns True when a drain happened."""
+            batch_id = span.batch_id
+
+            def drain_inflight(reason: str) -> None:
+                # the dispatcher waits, inside pack, for the batches in
+                # flight: a wait with a name of its own
+                with flightrecorder.stage(
+                    "pack.drain", totals=totals, batch=batch_id,
+                    reason=reason,
+                ):
+                    self._drain_pending()
+
+            def drained(reason: str) -> bool:
+                """Land every in-flight batch when there is a reason to,
+                then rebuild the drain-sensitive inputs (nominee overlay
+                source; callers refresh the snapshot themselves when they
+                hold one). Returns True when a drain happened."""
                 nonlocal nominated_by_node
-                if not reason_predicate or not self._pending_exists():
+                if not reason or not self._pending_exists():
                     return False
                 self.pipeline_drains += 1
-                self._drain_pending()
+                drain_inflight(reason)
                 # the drain can assume previously nominated pods (dropping
                 # their nomination) and nominate new ones via preemption --
                 # rebuild the overlay source from the post-drain state
@@ -2149,32 +2173,34 @@ class BatchScheduler(Scheduler):
                 if nominated_by_node else set()
             )
             drained(
-                has_hard_spread or has_affinity_terms or score_dynamic
+                "spread" if has_hard_spread
+                else "affinity" if has_affinity_terms
+                else "dynamic_score" if score_dynamic
                 # a port batch must see in-flight PORT placements committed
                 # into the static mask; port-free in-flight batches cannot
                 # conflict, so they don't force the drain
-                or (batch_ports and self._pending_has_ports())
+                else "ports" if batch_ports and self._pending_has_ports()
                 # an in-flight batch carrying required anti-affinity or
                 # scoring-relevant terms imposes symmetric constraints this
                 # batch can only see once its placements are committed
-                or self._pending_has_required_anti()
-                or self._pending_has_scoring_terms()
+                else "anti" if self._pending_has_required_anti()
+                else "dynamic_score" if self._pending_has_scoring_terms()
                 # a batch RETRYING preemption nominees must see the fully
                 # committed post-eviction state, or in-flight placements
                 # race it onto the freed capacity and cascade re-preemption
                 # (the old answer -- drain while ANY nomination lived --
                 # serialized every post-preemption dispatch; this drains
                 # only the nominees' own retry batches)
-                or any(
+                else "nominees" if any(
                     pi.pod.metadata.uid in nominee_uids
                     for pi in solver_infos
                 )
+                else ""
             )
 
             snapshot = self.algorithm.snapshot
-            # pack's parts: totals and trace only, the ring keeps the
-            # one ``pack``
-            batch_id = span.batch_id
+            # pack's parts (``batch_id`` on each): totals and trace
+            # only, the ring keeps the one ``pack``
 
             assumed_seq = 0
 
@@ -2202,7 +2228,7 @@ class BatchScheduler(Scheduler):
             ):
                 has_affinity = True
                 has_affinity_terms = True
-                if drained(True):
+                if drained("anti"):
                     refresh_snapshot()
             # existing pods with symmetric scoring terms make EVERY batch's
             # preferred-affinity family live (scoring.go:111): the in-flight
@@ -2212,7 +2238,7 @@ class BatchScheduler(Scheduler):
             )
             if not score_dynamic and cluster_ipa:
                 score_dynamic = True
-                if drained(True):
+                if drained("dynamic_score"):
                     refresh_snapshot()
                     cluster_ipa = cluster_has_affinity_scoring(snapshot)
             if nominated_by_node and (
@@ -2239,7 +2265,7 @@ class BatchScheduler(Scheduler):
                 # (generic_scheduler.go:535) -- take it for this rare
                 # combination (active nominations + constraints on either
                 # side).
-                self._drain_pending()
+                drain_inflight("nominees")
                 self.nominee_constrained_fallbacks += 1
                 span.finish(
                     tier=TIER_SEQUENTIAL, routed="nominee_constrained"
@@ -2253,13 +2279,9 @@ class BatchScheduler(Scheduler):
             ) as state:
                 # the stage's wall clock holds whatever the other
                 # threads did under the GIL meanwhile: its own work is
-                # the rows it repacked and this thread's CPU time
-                cpu0 = time.thread_time()
+                # the rows it repacked and the span's ``cpu_ms``
                 nt = self.tensor_cache.update(snapshot)
-                state.set_metadata(
-                    rows=int(nt.delta.changed_rows.size),
-                    cpu_ms=round((time.thread_time() - cpu0) * 1e3, 3),
-                )
+                state.set_metadata(rows=int(nt.delta.changed_rows.size))
             with flightrecorder.stage(
                 "pack.pods", totals=totals, batch=batch_id
             ):
@@ -2483,7 +2505,7 @@ class BatchScheduler(Scheduler):
                 reason, drain = routed
                 self.envelope_fallbacks += 1
                 if drain:
-                    self._drain_pending()
+                    drain_inflight(reason)
                 span.finish(tier=TIER_SEQUENTIAL, routed=reason)
                 for pi in solver_infos:
                     self.pods_fallback += 1
@@ -3440,8 +3462,10 @@ class BatchScheduler(Scheduler):
 
         fspan = p.get("span") or flightrecorder.NULL_SPAN
         totals = self.stage_totals
+        # how long the dispatched batch waited for this thread
+        waited = flightrecorder.handoff_wait(p.get("handed_off", 0.0))
         try:
-            with flightrecorder.stage("download", fspan, totals):
+            with flightrecorder.stage("download", fspan, totals, **waited):
                 assignments = self.ladder.watchdog.call(
                     download, timeout, tier=tier
                 )
@@ -3955,7 +3979,7 @@ class BatchScheduler(Scheduler):
                 self._inflight_binds += 1
             self._bind_pool.submit(
                 self._bulk_binding_cycle_safe, bulk, pod_scheduling_cycle,
-                snapshot, span,
+                snapshot, span, time.perf_counter(),
             )
         for prof_d, state_d, pi_d, assumed_d, host_d in deferred:
             self._binding_cycle(
@@ -4291,11 +4315,15 @@ class BatchScheduler(Scheduler):
 
     def _bulk_binding_cycle_safe(
         self, items, pod_scheduling_cycle, snapshot=None,
-        span=flightrecorder.NULL_SPAN,
+        span=flightrecorder.NULL_SPAN, submitted: float = 0.0,
     ) -> None:
+        """On a bind-pool thread; ``submitted`` is when the committer
+        handed the bulk to the pool."""
         try:
+            # how long the bulk waited for a thread of the pool
+            waited = flightrecorder.handoff_wait(submitted)
             self._bulk_binding_cycle(
-                items, pod_scheduling_cycle, snapshot, span
+                items, pod_scheduling_cycle, snapshot, span, waited
             )
         except SchedulerCrashed:
             # simulated process death: halt with NO cleanup (the items
@@ -4310,7 +4338,7 @@ class BatchScheduler(Scheduler):
 
     def _bulk_binding_cycle(
         self, items, pod_scheduling_cycle, snapshot=None,
-        span=flightrecorder.NULL_SPAN,
+        span=flightrecorder.NULL_SPAN, waited=None,
     ) -> None:
         """One API transaction commits the batch (the pipelined bulk
         analogue of BindingREST.Create, storage.go:142). PreBind still
@@ -4445,7 +4473,7 @@ class BatchScheduler(Scheduler):
         # the events
         totals = self.stage_totals
         with flightrecorder.stage(
-            "bind", span, totals, pods=len(ready)
+            "bind", span, totals, pods=len(ready), **(waited or {})
         ) as binding:
             assumed_list = [t[3] for t in ready]
             bind_timer = metrics.SinceTimer(metrics.binding_duration)

@@ -14,6 +14,7 @@ from typing import TYPE_CHECKING, Optional
 from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.client.informer import InformerFactory, ResourceEventHandler
 from kubernetes_tpu.queue import events
+from kubernetes_tpu.utils import flightrecorder
 
 if TYPE_CHECKING:
     from kubernetes_tpu.scheduler.scheduler import Scheduler
@@ -275,6 +276,11 @@ def add_all_event_handlers(
         profiles = sched.profiles
         cache_runs = []  # ("adds"|"dels", [pods]) | ("update", (old,new))
         queue_runs = []  # ("adds"|"dels", [pods]) | per-event kinds
+        # what the frame's work was for, on its ingest span: pending
+        # pods that entered the queue, MODIFIED events that confirm a
+        # bind on a node of ours, DELETED events (local increments; the
+        # stats themselves are built only under a profiler session)
+        adds = bind_echoes = deletes = 0
 
         for etype, old, new in frame:
             # cache membership = bound AND (partitioned) on an owned
@@ -290,6 +296,7 @@ def add_all_event_handlers(
                         cache_runs.append(("update", (old, new)))
                     else:
                         # bind echo: cache confirm + queue leave
+                        bind_echoes += 1
                         if cache_runs and cache_runs[-1][0] == "adds":
                             cache_runs[-1][1].append(new)
                         else:
@@ -366,6 +373,7 @@ def add_all_event_handlers(
                     else:
                         queue_runs.append(("adds", [new]))
             elif etype == "DELETED":
+                deletes += 1
                 if new_a:
                     if cache_runs and cache_runs[-1][0] == "dels":
                         cache_runs[-1][1].append(new)
@@ -434,6 +442,7 @@ def add_all_event_handlers(
                     for pod in payload:
                         _classify_safe(pod)
                 sched.queue.add_many(payload)
+                adds += len(payload)
             elif kind == "dels":
                 sched.queue.delete_many(payload)
                 # bound-pod echoes almost never have Permit waiters --
@@ -444,14 +453,21 @@ def add_all_event_handlers(
                             fw.reject_waiting_pod(pod.metadata.uid)
             elif kind == "add_one":
                 add_pod_to_queue(payload)
+                adds += 1
             elif kind == "update":
                 update_pod_in_queue(*payload)
             else:
                 delete_pod_from_queue(payload)
-        # status echoes of pods the scheduler holds, which the queue
-        # ignored: a stat of the frame's ingest span when there were any
-        echoes = sched.queue.echoes_ignored - echoes_before
-        return {"echoes_ignored": echoes} if echoes else None
+        if not flightrecorder.tracing():
+            return None  # stats of a span that only a session builds
+        # with the status echoes of pods the scheduler holds, which the
+        # queue ignored: stats of the frame's ingest span, each when
+        # there were any
+        stats = {
+            "adds": adds, "bind_echoes": bind_echoes, "deletes": deletes,
+            "echoes_ignored": sched.queue.echoes_ignored - echoes_before,
+        }
+        return {k: v for k, v in stats.items() if v} or None
 
     pods.add_event_handler(
         ResourceEventHandler(

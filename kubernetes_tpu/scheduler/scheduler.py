@@ -91,7 +91,10 @@ class Scheduler:
         self.preemptor = preemptor  # set by stage-7 wiring
         self.async_binding = async_binding
         self._bind_pool = (
-            ThreadPoolExecutor(max_workers=bind_workers, thread_name_prefix="bind")
+            ThreadPoolExecutor(
+                max_workers=bind_workers, thread_name_prefix="bind",
+                initializer=flightrecorder.name_thread,
+            )
             if async_binding
             else None
         )
@@ -796,7 +799,11 @@ class Scheduler:
             self.schedule_one(timeout=0.5)
 
     def start(self) -> threading.Thread:
-        t = threading.Thread(target=self.run, name="scheduler", daemon=True)
+        def loop() -> None:
+            flightrecorder.name_thread()
+            self.run()
+
+        t = threading.Thread(target=loop, name="scheduler", daemon=True)
         t.start()
         return t
 

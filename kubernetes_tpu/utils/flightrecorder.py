@@ -30,16 +30,19 @@ instead of grepping logs.
 ``stage`` is the one way a stage of the hot path is timed: one ``with``
 adds the seconds to the always-on totals (``StageTotals``, what
 ``BatchScheduler.stage_seconds`` reads), records them on the batch's
-span, and is a ``jax.profiler.TraceAnnotation("sched/<name>")`` for its
-whole duration, so a profiler session (``jax.profiler.trace``) shows the
-scheduler's stages on the device trace's clock, on the line of the
-thread that did the work. ``mark`` lands there too, as a zero-length
+span, and while a profiler session runs (``jax.profiler.trace``) is a
+``jax.profiler.TraceAnnotation("sched/<name>")`` for its whole duration,
+so the session shows the scheduler's stages on the device trace's clock,
+on the line of the thread that did the work, each with its ``cpu_ms``:
+the thread's own CPU time. ``mark`` lands there too, as a zero-length
 ``sched/mark/<kind>``. The totals and the annotations do not depend on
 ``KTPU_FLIGHTRECORDER``: only the ring does.
 """
 
 from __future__ import annotations
 
+import ctypes
+import functools
 import json
 import logging
 import os
@@ -285,6 +288,30 @@ def mark(kind: str, /, **fields) -> None:
     RECORDER.mark(kind, **fields)
 
 
+def name_thread() -> None:
+    """Give the calling thread its Python name at the OS too (Linux
+    keeps 15 bytes): the profiler names a trace's host line after the
+    OS thread, and all of them read ``python3`` otherwise. Called once
+    where the informer, dispatcher, committer and bind-pool threads
+    start; never for the main thread, whose name is the process's."""
+    thread = threading.current_thread()
+    if thread is threading.main_thread():
+        return
+    try:
+        _prctl()(15, thread.name.encode()[:15], 0, 0, 0)  # PR_SET_NAME
+    except (OSError, AttributeError):  # no libc or no prctl: a label only
+        pass
+
+
+@functools.lru_cache(maxsize=None)
+def _prctl():
+    prctl = ctypes.CDLL(None).prctl
+    prctl.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                      ctypes.c_ulong, ctypes.c_ulong]
+    prctl.restype = ctypes.c_int
+    return prctl
+
+
 def dump_on_degraded(reason: str) -> Optional[str]:
     """Called wherever a component sets the degraded-health gauge: the
     moment something goes degraded is exactly when the last-K record is
@@ -310,18 +337,22 @@ class StageTotals:
         self._dicts: List[dict] = []
 
     def add(self, name: str, seconds: float) -> None:
+        try:
+            rec = self._local.d[name]
+        except (AttributeError, KeyError):
+            rec = self._first(name)
+        rec[0] += seconds
+        rec[1] += 1
+
+    def _first(self, name: str) -> list:
+        """This thread's first add, or its first of this stage."""
         d = getattr(self._local, "d", None)
         if d is None:
-            d = {}
-            self._local.d = d
+            d = self._local.d = {}
             with self._lock:
                 self._dicts.append(d)
-        rec = d.get(name)
-        if rec is None:
-            d[name] = [seconds, 1]
-        else:
-            rec[0] += seconds
-            rec[1] += 1
+        rec = d[name] = [0.0, 0]
+        return rec
 
     def _merged(self, field: int) -> dict:
         # list() of a dict's items is atomic under the GIL, so a
@@ -352,35 +383,89 @@ _SPAN_NAMES = {
     "download": "solve_wait",
 }
 
+#: does a profiler session run? What only a session reads (a span, its
+#: stats, ``cpu_ms``, ``waited_ms``) is built only then
+tracing = TraceAnnotation.is_enabled
+_clock = time.perf_counter
+_cpu_clock = time.thread_time
 
-class stage(TraceAnnotation):
+
+def handoff_wait(since: float) -> dict:
+    """The stat a receiving thread puts on its span for what another
+    thread handed it at ``since`` (``time.perf_counter()``; 0.0: not
+    stamped): how long the item waited for this thread. Nothing with no
+    session, which alone would read it; the sender's stamp is one clock
+    read a batch or transaction (0.09 us), no dearer than asking."""
+    if not since or not tracing():
+        return {}
+    return {"waited_ms": round((_clock() - since) * 1e3, 3)}
+
+
+class stage:
     """``with stage("pack", span, totals, **stats):`` times one stage,
-    once: the seconds go to ``totals`` (when given) and to the batch's
-    ``span`` (when given), and the block is a ``sched/<name>`` span of
-    any running profiler session (``_SPAN_NAMES`` renames three), with
-    the span's ``batch`` id and ``stats`` on it (``set_metadata`` adds
-    what is known only later).
+    once: the wall clock's seconds go to ``totals`` (when given) and to
+    the batch's ``span`` (when given), and while a profiler session runs
+    the block is a ``sched/<name>`` span of it
+    (``jax.profiler.TraceAnnotation``; ``_SPAN_NAMES`` renames three),
+    with the span's ``batch`` id and ``stats`` on it (``set_metadata``
+    adds what is known only later). With no session nothing of the
+    annotation is built.
+
+    While a session runs, a stage that is given ``totals`` is clocked a
+    second time, on its thread's own CPU clock (``time.thread_time``),
+    and the span carries the difference as ``cpu_ms``, so a stage tells
+    its work from its waiting. Only while a session runs, because that
+    clock is a system call of 5.7 us on the chip's host (0.3 on a
+    developer's): read twice a stage, always, it cost the open-loop cell
+    4 % of its median pod-to-bind (PERF.md section 6, PR 37). So the
+    span is the only place the CPU time goes: a sum over a window is a
+    sum of ``cpu_ms`` over the trace's spans. A stage without totals
+    (the trace-only ``dispatch`` and ``commit.gather`` / ``.clone`` /
+    ``.assume``) is never clocked: that is the rule, and no site takes
+    the clock by hand.
     ``seconds`` holds the duration after exit. Spans are per batch, per
     informer frame, per bulk bind and per collection, never per pod."""
 
+    __slots__ = ("name", "span", "totals", "seconds",
+                 "_stats", "_trace", "_t0", "_c0")
+
     def __init__(self, name: str, span=NULL_SPAN,
                  totals: Optional[StageTotals] = None, **stats) -> None:
-        if span:
-            stats["batch"] = span.batch_id
-        super().__init__("sched/" + _SPAN_NAMES.get(name, name), **stats)
         self.name = name
         self.span = span
         self.totals = totals
+        self._stats = stats
+        self._trace = None
         self.seconds = 0.0
 
     def __enter__(self) -> "stage":
-        super().__enter__()
-        self._t0 = time.perf_counter()
+        if tracing():
+            name, span, stats = self.name, self.span, self._stats
+            if span is not NULL_SPAN:
+                stats["batch"] = span.batch_id
+            self._trace = TraceAnnotation(
+                "sched/" + _SPAN_NAMES.get(name, name), **stats
+            ).__enter__()
+            if self.totals is not None:
+                self._c0 = _cpu_clock()
+        self._t0 = _clock()
         return self
 
-    def __exit__(self, *exc) -> None:
-        self.seconds = seconds = time.perf_counter() - self._t0
-        super().__exit__(*exc)
-        if self.totals is not None:
-            self.totals.add(self.name, seconds)
-        self.span.stage(self.name, seconds)
+    def set_metadata(self, **stats) -> None:
+        if self._trace is not None:
+            self._trace.set_metadata(**stats)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.seconds = seconds = _clock() - self._t0
+        trace, totals = self._trace, self.totals
+        if trace is not None:
+            if totals is not None:
+                trace.set_metadata(
+                    cpu_ms=round((_cpu_clock() - self._c0) * 1e3, 3)
+                )
+            trace.__exit__(exc_type, exc, tb)
+        # the totals last: who waits for a total finds its span closed
+        if totals is not None:
+            totals.add(self.name, seconds)
+        if self.span is not NULL_SPAN:
+            self.span.stage(self.name, seconds)

@@ -5,6 +5,10 @@ the scheduler's stages on the lines of the threads that did the work."""
 
 import gc
 import glob
+import os
+import pathlib
+import subprocess
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -67,6 +71,13 @@ def named(events, name):
     return [ev for ev in events if ev["name"] == name]
 
 
+def own_stats(ev):
+    """A span's stats less the two every clocked span may carry: the
+    primitive's ``cpu_ms`` and a hand-off's ``waited_ms``."""
+    return {k: v for k, v in ev["stats"].items()
+            if k not in ("cpu_ms", "waited_ms")}
+
+
 def _stack(num_nodes=16, max_batch=64):
     server = APIServer()
     client = Client(server)
@@ -113,7 +124,8 @@ def test_stage_writes_total_ring_and_trace_once_each(tmp_path):
     assert st.seconds >= 0.002
     assert span.stages == {"pack": st.seconds}
     (ev,) = named(events, "sched/pack")
-    assert ev["stats"] == {"batch": span.batch_id, "pods": 3, "padded": 8}
+    assert own_stats(ev) == {"batch": span.batch_id, "pods": 3, "padded": 8}
+    assert 0 <= ev["stats"]["cpu_ms"] <= st.seconds * 1e3 + TICK_MS
     assert (ev["end"] - ev["start"]) / 1e9 == pytest.approx(
         st.seconds, abs=1e-3
     )
@@ -221,7 +233,9 @@ def test_an_informer_frame_is_one_ingest_span(tmp_path):
     assert len(seen) == 3
     assert totals.calls() == {"ingest": 1}
     (ev,) = named(events, "sched/ingest")
-    assert ev["stats"] == {"kind": "Pod", "events": 3}
+    # no ``waited_ms``: these events went through no broadcast
+    assert ev["stats"].keys() == {"kind", "events", "cpu_ms"}
+    assert own_stats(ev) == {"kind": "Pod", "events": 3}
 
 
 # -- on a small burst --------------------------------------------------------
@@ -528,3 +542,352 @@ def test_a_fresh_schedulers_totals_are_its_own():
     assert "pack" in sched1.stage_seconds
     assert sched2.stage_seconds.get("ingest", 0.0) == base
     assert "pack" not in sched2.stage_seconds
+
+
+# -- a stage tells its work from its waiting ---------------------------------
+
+#: the stages that are given no totals, which the primitive does not clock
+UNCLOCKED = {"sched/dispatch", "sched/commit.gather", "sched/commit.clone",
+             "sched/commit.assume"}
+TICK_MS = 10.0  # the coarsest CPU clock a host of ours has
+
+
+def _spin(seconds):
+    until = time.thread_time() + seconds
+    while time.thread_time() < until:
+        pass
+
+
+@pytest.mark.parametrize("body,cpu_ms,share", [
+    # (the body, its CPU time in ms, its share of the wall clock): a
+    # spin's share is near 1 on an idle host; beside five other test
+    # workers the wall clock stretches, so it is held to a quarter
+    (lambda: time.sleep(0.05), (0.0, 10.0), (0.0, 0.2)),
+    (lambda: _spin(0.05), (50.0, 80.0), (0.25, 1.05)),
+], ids=["sleeps", "spins"])
+def test_the_primitive_tells_a_body_that_sleeps_from_one_that_spins(
+    tmp_path, body, cpu_ms, share
+):
+    totals = flightrecorder.StageTotals()
+    with profiled(tmp_path) as events:
+        with flightrecorder.stage("commit", totals=totals) as st:
+            body()
+        with flightrecorder.stage("commit.gather") as bare:
+            body()
+    (ev,) = named(events, "sched/commit")
+    wall_ms = (ev["end"] - ev["start"]) / 1e6
+    assert cpu_ms[0] <= ev["stats"]["cpu_ms"] <= cpu_ms[1]
+    assert share[0] <= ev["stats"]["cpu_ms"] / wall_ms <= share[1]
+    # the span is the only place the CPU time goes: the totals keep
+    # the wall clock's seconds and the calls
+    assert totals.seconds()["commit"] == st.seconds
+    assert totals.calls() == {"commit": 1}
+    # no totals, no CPU clock: the rule is the primitive's own
+    (gather,) = named(events, "sched/commit.gather")
+    assert "cpu_ms" not in gather["stats"] and bare.seconds > 0
+
+
+def test_without_a_session_the_cpu_clock_is_not_read(monkeypatch):
+    """``time.thread_time`` is a system call of 5.7 us on the chip's
+    host: the primitive pays it only for a span somebody will read, and
+    builds no hand-off stat either."""
+    def read():
+        raise AssertionError("the CPU clock was read with no session")
+
+    monkeypatch.setattr(flightrecorder, "_cpu_clock", read)
+    totals = flightrecorder.StageTotals()
+    with flightrecorder.stage("pack", totals=totals) as st:
+        _spin(0.01)
+        st.set_metadata(rows=3)  # nothing to put it on: no error
+    assert st.seconds >= 0.01
+    assert totals.seconds()["pack"] == st.seconds
+    assert totals.calls() == {"pack": 1}
+    assert flightrecorder.handoff_wait(time.perf_counter() - 1.0) == {}
+
+
+def test_every_clocked_span_of_a_burst_carries_cpu_ms(burst_trace):
+    events, _dump, sched = burst_trace
+    clocked = [ev for ev in events if ev["name"] not in UNCLOCKED
+               and not ev["name"].startswith("sched/mark/")]
+    assert named(events, "sched/dispatch")
+    assert len({ev["name"] for ev in clocked}) >= 15
+    for ev in clocked:
+        wall_ms = (ev["end"] - ev["start"]) / 1e6
+        assert 0 <= ev["stats"]["cpu_ms"] <= wall_ms + TICK_MS, ev
+    for ev in events:
+        if ev["name"] in UNCLOCKED:
+            assert "cpu_ms" not in ev["stats"], ev
+    # every stage with an always-on total is a clocked span of the
+    # trace, so a window's CPU time is a sum over the trace's spans
+    spans = {ev["name"] for ev in clocked}
+    for name in sched.stage_seconds:
+        renamed = flightrecorder._SPAN_NAMES.get(name, name)
+        assert "sched/" + renamed in spans, name
+    # waiting for pods is no work
+    waits = named(events, "sched/pop_wait")
+    assert sum(ev["stats"]["cpu_ms"] for ev in waits) < 0.5 * sum(
+        (ev["end"] - ev["start"]) / 1e6 for ev in waits
+    )
+
+
+def test_the_stages_threads_carry_their_names_at_the_os(burst_trace):
+    """The profiler names a trace's host line after the OS thread, so the
+    informer, dispatcher, committer and bind-pool threads take their
+    Python names there as they start (``flightrecorder.name_thread``)."""
+    _events, _dump, _sched = burst_trace
+    names = {p.read_text().strip()
+             for p in pathlib.Path("/proc/self/task").glob("*/comm")}
+    assert {"scheduler", "batch-committer", "informer-Pod", "bind_0"} <= names
+    assert "informer-Persis" in names  # of 15 bytes, which Linux keeps
+
+
+def test_a_fresh_processs_trace_names_the_line_after_the_thread(tmp_path):
+    """In a process of its own: the profiler keeps a dead thread's
+    record, name included, for the next thread that traces, so in a
+    process that has traced before (this one) a line may carry a dead
+    thread's name. The benchmark traces once a process."""
+    script = (
+        "import sys, threading, jax\n"
+        "from kubernetes_tpu.utils import flightrecorder as fr\n"
+        "def work():\n"
+        "    fr.name_thread()\n"
+        "    with fr.stage('ingest'): pass\n"
+        "jax.profiler.start_trace(sys.argv[1])\n"
+        "t = threading.Thread(target=work, name='informer-Pod')\n"
+        "t.start(); t.join()\n"
+        "jax.profiler.stop_trace()\n"
+    )
+    subprocess.run(
+        [sys.executable, "-c", script, str(tmp_path)], check=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=120,
+        cwd=pathlib.Path(__file__).resolve().parents[1],
+    )
+    (path,) = glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    lines = [line.name for plane in
+             jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines
+             if any(ev.name == "sched/ingest" for ev in line.events)]
+    assert lines == ["informer-Pod"]
+
+
+def test_ingest_spans_say_what_a_pod_frame_was_for(burst_trace):
+    events, _dump, _sched = burst_trace
+    frames = [ev["stats"] for ev in named(events, "sched/ingest")
+              if ev["stats"]["kind"] == "Pod"]
+    assert sum(f.get("adds", 0) for f in frames) == 120
+    assert sum(f.get("bind_echoes", 0) for f in frames) == 120
+    assert sum(f.get("deletes", 0) for f in frames) == 0
+    assert sum(f["events"] for f in frames) == 240
+    # through the apiserver's broadcast: every frame says how long its
+    # oldest event waited for the informer's thread
+    assert all(f["waited_ms"] >= 0 for f in frames)
+
+
+def _pods_batch(informers):
+    (handler,) = [h for h in informers.pods()._handlers
+                  if h.on_batch is not None and h.stage_totals is not None]
+    return handler.on_batch
+
+
+def test_pods_batch_counts_a_mixed_frame_and_nothing_of_an_empty_one(
+    monkeypatch
+):
+    _server, _client, informers, sched = _stack(num_nodes=2)
+    # the counts are stats of a span, returned while a session runs
+    monkeypatch.setattr(flightrecorder, "tracing", lambda: True)
+    try:
+        pods_batch = _pods_batch(informers)
+        pending = make_pod("mixed").container(cpu="10m", memory="16Mi").obj()
+        bound = make_pod("mixed").container(cpu="10m", memory="16Mi").obj()
+        bound.metadata.uid = pending.metadata.uid
+        bound.spec.node_name = "node-0"
+        other = make_pod("other").container(cpu="10m", memory="16Mi").obj()
+        assert pods_batch([]) is None
+        assert pods_batch([
+            ("ADDED", None, pending), ("ADDED", None, other),
+            ("MODIFIED", pending, bound), ("DELETED", None, bound),
+        ]) == {"adds": 2, "bind_echoes": 1, "deletes": 1}
+        assert sched.queue.num_pending()["active"] == 1  # ``other``
+        # a frame that is all one thing says that alone
+        assert pods_batch([("DELETED", None, other)]) == {"deletes": 1}
+        # and with no session there is no span to put them on
+        monkeypatch.setattr(flightrecorder, "tracing", lambda: False)
+        assert pods_batch([("ADDED", None, other)]) is None
+        assert sched.queue.num_pending()["active"] == 1
+    finally:
+        informers.stop()
+
+
+def _held_stack(max_inflight=None):
+    """A stack whose dispatcher the test drives by hand and whose
+    committer waits for ``release`` before it completes a batch."""
+    server, client, informers, sched = _stack()
+    if max_inflight is not None:
+        sched.max_inflight = max_inflight
+    sched.queue.run()
+    release = threading.Event()
+    complete = sched._complete_solve
+
+    def held(p):
+        release.wait(10)
+        complete(p)
+
+    sched._complete_solve = held
+    return client, informers, sched, release
+
+
+def _release_once_the_dispatcher_waits(sched, release):
+    """Let the held committer go 0.1 s after the dispatcher began to
+    wait on the pipeline's condition (the committer is held elsewhere,
+    so a waiter there is the dispatcher)."""
+    def run():
+        deadline = time.time() + 60
+        while not sched._pending_cv._waiters and time.time() < deadline:
+            time.sleep(0.002)
+        time.sleep(0.1)
+        release.set()
+
+    threading.Thread(target=run, daemon=True).start()
+
+
+def _dispatch(client, sched, pods):
+    client.create_pods_bulk(pods)
+    deadline = time.time() + 30
+    while len(sched.queue.pending_pods()) < len(pods):
+        assert time.time() < deadline, "the pods never queued"
+        time.sleep(0.01)
+    assert sched.schedule_batch(timeout=1.0, pipeline=True) == len(pods)
+
+
+def _plain(tag, count=4):
+    return [make_pod(f"{tag}-{i}").container(cpu="10m", memory="16Mi").obj()
+            for i in range(count)]
+
+
+def test_a_batch_that_must_drain_shows_the_wait_inside_its_pack(tmp_path):
+    client, informers, sched, release = _held_stack()
+    spread = [
+        make_pod(f"sp-{i}").labels(app="sp")
+        .spread_constraint(1, "zone", match_labels={"app": "sp"})
+        .container(cpu="10m", memory="16Mi").obj() for i in range(3)
+    ]
+    try:
+        with profiled(tmp_path) as events:
+            _dispatch(client, sched, _plain("first"))  # in flight, held
+            _release_once_the_dispatcher_waits(sched, release)
+            _dispatch(client, sched, spread)  # hard spread: has to drain
+            sched._drain_pending()
+            sched.wait_for_inflight_binds()
+    finally:
+        sched.stop()
+        informers.stop()
+    first, second = sorted(named(events, "sched/pack"),
+                           key=lambda ev: ev["start"])
+    (drain,) = named(events, "sched/pack.drain")
+    assert drain["stats"]["reason"] == "spread"
+    assert drain["stats"]["batch"] == second["stats"]["batch"]
+    assert drain["line"] == second["line"]
+    assert second["start"] <= drain["start"] and drain["end"] <= second["end"]
+    assert drain["end"] - drain["start"] >= 0.09e9  # held 0.1 s
+    assert drain["stats"]["cpu_ms"] <= 0.5 * (drain["end"] - drain["start"]) \
+        / 1e6  # a wait, not work
+    # the port-free plain batch before it drained nothing
+    assert not any(first["start"] <= ev["start"] < first["end"]
+                   for ev in named(events, "sched/pack.drain"))
+    assert sched.stage_totals.calls()["pack.drain"] == 1
+    assert sched.pipeline_drains == 1
+
+
+def test_a_full_pipeline_shows_the_dispatcher_blocked_on_its_depth(tmp_path):
+    client, informers, sched, release = _held_stack(max_inflight=1)
+    try:
+        with profiled(tmp_path) as events:
+            _dispatch(client, sched, _plain("first"))  # fills the pipeline
+            assert "inflight_wait" not in sched.stage_totals.calls()
+            _release_once_the_dispatcher_waits(sched, release)
+            _dispatch(client, sched, _plain("second"))  # blocks on the depth
+            sched._drain_pending()
+            sched.wait_for_inflight_binds()
+    finally:
+        sched.stop()
+        informers.stop()
+    (wait,) = named(events, "sched/inflight_wait")
+    assert wait["end"] - wait["start"] >= 0.09e9  # held 0.1 s
+    (pack,) = [ev for ev in named(events, "sched/pack")
+               if ev["stats"]["batch"] == wait["stats"]["batch"]]
+    # after the batch's dispatch, on the dispatcher's line, in no pack
+    assert wait["line"] == pack["line"] and wait["start"] >= pack["end"]
+    assert sched.stage_totals.calls()["inflight_wait"] == 1
+    assert not named(events, "sched/pack.drain")
+
+
+HOLD_S = 0.05
+
+
+@pytest.mark.parametrize("held,span_name", [
+    ("_complete_solve", "sched/solve_wait"),
+    ("_bulk_binding_cycle_safe", "sched/bind"),
+], ids=["committer", "bind-pool"])
+def test_a_hand_off_says_how_long_it_waited_for_the_thread(
+    tmp_path, held, span_name
+):
+    """The receiving thread is held 50 ms before it takes what it was
+    handed: the span's ``waited_ms`` reads at least that."""
+    _server, client, informers, sched = _stack()
+    taken = getattr(sched, held)
+
+    def late(*args):
+        time.sleep(HOLD_S)
+        taken(*args)
+
+    setattr(sched, held, late)
+    sched.start()
+    try:
+        with profiled(tmp_path) as events:
+            _burst(client, sched, 20, tag="late")
+    finally:
+        sched.stop()
+        informers.stop()
+    spans = named(events, span_name)
+    assert spans
+    for ev in spans:
+        assert ev["stats"]["waited_ms"] >= HOLD_S * 1e3
+
+
+def test_an_ingest_frame_says_how_long_it_waited_for_the_informer(tmp_path):
+    server = APIServer()
+    client = Client(server)
+    entered, release = threading.Event(), threading.Event()
+
+    def on_batch(frame):
+        if frame and not entered.is_set():  # the initial list is empty
+            entered.set()
+            release.wait(10)
+
+    totals = flightrecorder.StageTotals()
+    informer = Informer(server, "Pod")
+    informer.add_event_handler(ResourceEventHandler(
+        on_batch=on_batch, stage_totals=totals
+    ))
+    informer.start()
+    try:
+        with profiled(tmp_path) as events:
+            client.create_pod(make_pod("held").obj())
+            assert entered.wait(10)  # the informer's thread is in frame 1
+            client.create_pods_bulk([make_pod(f"late-{i}").obj()
+                                     for i in range(3)])
+            time.sleep(HOLD_S)
+            release.set()
+            deadline = time.time() + 10
+            while totals.calls().get("ingest", 0) < 2:
+                assert time.time() < deadline
+                time.sleep(0.01)
+    finally:
+        informer.stop()
+    first, second = sorted(named(events, "sched/ingest"),
+                           key=lambda ev: ev["start"])
+    assert first["stats"]["events"] == 1 and second["stats"]["events"] == 3
+    assert second["stats"]["waited_ms"] >= HOLD_S * 1e3
+    assert 0 <= first["stats"]["waited_ms"] < second["stats"]["waited_ms"]

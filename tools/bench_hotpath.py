@@ -1545,9 +1545,9 @@ def bench_trace_overhead(num_pods: int = 1000, num_nodes: int = 200):
 HOT_STAGES = ("pop_batch", "pack", "device_solve", "download", "commit")
 #: every flightrecorder.stage one batch passes through
 BATCH_STAGES = (
-    "pop_wait", "pop_batch", "dispatch", "pack", "pack.snapshot",
-    "pack.state", "pack.pods", "pack.masks", "pack.families",
-    "device_solve", "download", "commit",
+    "pop_wait", "pop_batch", "dispatch", "pack", "pack.drain",
+    "pack.snapshot", "pack.state", "pack.pods", "pack.masks",
+    "pack.families", "device_solve", "inflight_wait", "download", "commit",
     "commit.gather", "commit.clone", "commit.assume", "bind", "bind.api",
 )
 
