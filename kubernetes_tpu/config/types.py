@@ -154,6 +154,8 @@ class TPUSolverConfiguration:
     enabled: bool = True
     max_batch: int = 256
     solver_mode: str = "greedy"  # "greedy" | "sinkhorn"
+    # the longest a pod waits in the queue for company, from its own
+    # arrival (queue/scheduling_queue.py pop_batch)
     batch_window_seconds: float = 0.01
     mesh_devices: int = 0  # 0 = single device (no mesh)
 

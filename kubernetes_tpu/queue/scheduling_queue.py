@@ -11,6 +11,7 @@ instead of one -- the activeQ drain *is* the batch (SURVEY.md section 2.1).
 
 from __future__ import annotations
 
+import operator
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -48,6 +49,9 @@ def _is_pod_updated(old: Optional[Pod], new: Pod) -> bool:
 
 def _info_key(pi: PodInfo) -> str:
     return _pod_key(pi.pod)
+
+
+_timestamp = operator.attrgetter("timestamp")
 
 
 def _queue_shape_py(pods: List[Pod]):
@@ -575,18 +579,30 @@ class PriorityQueue:
         totals: Optional[flightrecorder.StageTotals] = None,
     ) -> List[PodInfo]:
         """TPU batch drain: block for the first pod, then take up to
-        ``max_size``. With ``window > 0``, wait up to that long for more
-        arrivals before returning a partial batch -- amortizes the fixed
-        per-solve cost (device transfer + dispatch) during a burst at the
-        price of a bounded latency add for the first pods.
+        ``max_size``. With ``window > 0``, a partial batch waits for more
+        arrivals -- amortizes the fixed per-solve cost (device transfer
+        + dispatch) during a burst at the price of a bounded latency add
+        for the first pods. ``window`` is the longest a pod waits for
+        company, from its own arrival: the deadline is the OLDEST
+        drained pod's ``timestamp`` (what its queue wait is measured
+        from, on this queue's clock) plus the window, not the instant
+        this pop found it. A pod that arrives at a waiting pop waits the
+        whole window; pods that arrived while the dispatcher was away
+        have spent theirs in part or whole and wait what is left, at
+        once if nothing is (a nominee requeued with its original
+        timestamp, a backed-off pod with its parking time). Arrivals
+        during the wait are younger and do not move the deadline; a full
+        batch leaves before the oldest is looked for.
+        ``scheduler_queue_window_spent_pops_total`` of
+        ``scheduler_queue_pops_total`` says how often that was so.
 
         ``window`` may be a CALLABLE returning the current window (the
         SLO-adaptive controller mutates it while a drain is waiting).
-        The window deadline is re-read at every wakeup but can only
-        move EARLIER: a mid-window controller shrink applies
-        immediately, while a grow never extends an already-armed
-        deadline -- the pods already in the batch were promised the
-        window in force when they were drained.
+        The window deadline is re-read at every wakeup, from the same
+        oldest pod, but can only move EARLIER: a mid-window controller
+        shrink applies immediately, while a grow never extends an
+        already-armed deadline -- the pods already in the batch were
+        promised the window in force when they were drained.
 
         Priority bands (``band_threshold``): when the batch holds a pod
         at or above the threshold -- drained on entry or arriving during
@@ -614,8 +630,12 @@ class PriorityQueue:
         THIS call spent in each (first pod + window waits; single
         dispatcher thread; stats only). Window waits cut short by a band
         arrival still count only the time actually waited -- the split
-        stays honest under band-aware drains."""
-        deadline = None if timeout is None else self._now() + timeout
+        stays honest under band-aware drains. In a profiler session a
+        ``pop_wait`` span says which wait it is: ``waits_for`` reads
+        ``first_pod`` or ``company``, the latter with ``window_left_ms``,
+        what was left of the window when the wait began."""
+        began = self._now()
+        deadline = None if timeout is None else began + timeout
         window_fn = window if callable(window) else None
         band = self.band_threshold
         batch: List[PodInfo] = []
@@ -623,13 +643,22 @@ class PriorityQueue:
         has_high = False
         work = flightrecorder.stage("pop_batch", totals=totals).__enter__()
 
-        def cond_wait(seconds: Optional[float]) -> None:
+        def cond_wait(
+            seconds: Optional[float], company: bool = False
+        ) -> None:
             # the work stage closes for the wait and a new one opens
             # after it, so a trace shows the two side by side
             nonlocal waited, worked, work
             work.__exit__(None, None, None)
             worked += work.seconds
-            with flightrecorder.stage("pop_wait", totals=totals) as idle:
+            stats = {}
+            if flightrecorder.tracing():
+                stats["waits_for"] = "company" if company else "first_pod"
+                if company:
+                    stats["window_left_ms"] = round(seconds * 1e3, 3)
+            with flightrecorder.stage(
+                "pop_wait", totals=totals, **stats
+            ) as idle:
                 self._cond.wait(seconds)
             waited += idle.seconds
             work = flightrecorder.stage(
@@ -655,10 +684,7 @@ class PriorityQueue:
                             and len(self.active_q) == 0
                         ):
                             return batch
-                window_start = self._now()
-                window_deadline = window_start + (
-                    window_fn() if window_fn is not None else window
-                )
+                anchor = None
                 while True:
                     drained = self.active_q.pop_bulk(max_size - len(batch))
                     if drained:
@@ -680,21 +706,35 @@ class PriorityQueue:
                         # window exists to amortize bulk work, not to
                         # tax the latency band
                         break
-                    if window_fn is not None:
+                    if anchor is None:
+                        # the window belongs to the oldest pod aboard,
+                        # from its own arrival, not to this pop: what it
+                        # waited while the dispatcher was away is spent.
+                        # Read once: later arrivals are younger
+                        anchor = min(map(_timestamp, batch))
+                        armed = (
+                            window_fn() if window_fn is not None else window
+                        )
+                        window_deadline = anchor + armed
+                        if armed > 0 and anchor < began:
+                            metrics.queue_window_spent_pops.inc()
+                    elif window_fn is not None:
                         # adaptive window: shrink applies mid-wait, a
                         # grow never extends the armed deadline
                         window_deadline = min(
-                            window_deadline, window_start + window_fn()
+                            window_deadline, anchor + window_fn()
                         )
                     remaining = window_deadline - self._now()
                     if remaining <= 0:
                         break
-                    cond_wait(remaining)
+                    cond_wait(remaining, company=True)
             return batch
         finally:
             work.__exit__(None, None, None)
             self.last_pop_wait_seconds = waited
             self.last_pop_work_seconds = worked + work.seconds
+            if batch:
+                metrics.queue_pops.inc()
 
     @staticmethod
     def _observe_band_waits(

@@ -442,6 +442,19 @@ queue_echoes_ignored = registry.register(Counter(
     "at Permit) or that has just bound. Ignored, where it used to add a "
     "second record of the pod.",
 ))
+queue_pops = registry.register(Counter(
+    "scheduler_queue_pops_total",
+    "Calls of the scheduling queue's pop_batch that handed out pods.",
+))
+queue_window_spent_pops = registry.register(Counter(
+    "scheduler_queue_window_spent_pops_total",
+    "Of scheduler_queue_pops_total, the pops that waited on a batch window "
+    "and found their oldest pod already aged: it had arrived before the "
+    "pop began (the dispatcher was away), so the window, which runs from "
+    "the oldest pod's own arrival, was spent in part or whole before the "
+    "pop began. Full batches and batches cut by a high-band pod never "
+    "look: they wait on no window.",
+))
 commit_join_timeouts = registry.register(Counter(
     "scheduler_commit_thread_join_timeouts_total",
     "Committer threads that failed to join at shutdown.",

@@ -27,7 +27,7 @@ from kubernetes_tpu.plugins.queuesort import PrioritySort
 from kubernetes_tpu.queue.scheduling_queue import PriorityQueue
 from kubernetes_tpu.scheduler.scheduler import new_scheduler
 from kubernetes_tpu.testing import make_node, make_pod
-from kubernetes_tpu.utils import flightrecorder
+from kubernetes_tpu.utils import flightrecorder, metrics
 from kubernetes_tpu.utils.gc_tuning import GCBatchGuard
 
 
@@ -98,6 +98,10 @@ def _burst(client, sched, count, tag="p"):
         make_pod(f"{tag}-{i}").container(cpu="10m", memory="16Mi").obj()
         for i in range(count)
     ])
+    _wait_bound(client, sched, count)
+
+
+def _wait_bound(client, sched, count):
     deadline = time.time() + 60
     while time.time() < deadline:
         pods, _ = client.list_pods()
@@ -218,6 +222,67 @@ def test_pop_wait_and_pop_work_are_side_by_side(tmp_path):
         )
 
 
+def test_the_queue_says_which_pops_found_their_window_spent(
+    tmp_path, monkeypatch
+):
+    """Four pops, two of which found their oldest pod aged: the two
+    counters say so, and a ``pop_wait`` span says whether it waited for
+    a first pod or for company, and then what was left of the window."""
+    queue = PriorityQueue(PrioritySort().queue_sort_less)
+    window = 0.2
+
+    def pods(tag, count=1):
+        return [make_pod(f"{tag}-{i}").obj() for i in range(count)]
+
+    def pop(size=8):
+        return [pi.pod.metadata.name
+                for pi in queue.pop_batch(size, timeout=5.0, window=window)]
+
+    pops0 = metrics.queue_pops.value()
+    spent0 = metrics.queue_window_spent_pops.value()
+    timer = threading.Timer(0.1, queue.add_many, args=(pods("idle"),))
+    with profiled(tmp_path) as events:
+        timer.start()
+        assert pop() == ["idle-0"]  # arrives at a waiting pop
+        timer.join()
+        queue.add_many(pods("aged"))
+        time.sleep(window / 2)
+        assert pop() == ["aged-0"]  # half of its window spent
+        queue.add_many(pods("old"))
+        time.sleep(window + 0.05)
+        assert pop() == ["old-0"]  # all of it
+        assert queue.last_pop_wait_seconds == 0.0
+        queue.add_many(pods("full", 2))
+        time.sleep(0.02)
+        assert len(pop(size=2)) == 2  # a full batch waits on no window
+        assert queue.pop_batch(8, timeout=0.01, window=window) == []
+    assert metrics.queue_pops.value() - pops0 == 4  # the empty one is none
+    assert metrics.queue_window_spent_pops.value() - spent0 == 2
+    waits = sorted(named(events, "sched/pop_wait"), key=lambda ev: ev["start"])
+    first = [ev for ev in waits if ev["stats"]["waits_for"] == "first_pod"]
+    company = [ev for ev in waits if ev["stats"]["waits_for"] == "company"]
+    assert len(first) + len(company) == len(waits)
+    assert len(first) == 2 and len(company) == 2  # the last found no pod
+    assert all("window_left_ms" not in ev["stats"] for ev in first)
+    whole, half = (ev["stats"]["window_left_ms"] for ev in company)
+    assert 0.75 * window * 1e3 < whole <= window * 1e3
+    assert 0 < half <= 0.5 * window * 1e3
+    for ev in company:  # the window's cost is a sum over these spans
+        assert (ev["end"] - ev["start"]) / 1e6 == pytest.approx(
+            ev["stats"]["window_left_ms"], abs=50
+        )
+    # with no session nothing is built for the span
+    built = []
+    stage = flightrecorder.stage
+    monkeypatch.setattr(
+        flightrecorder, "stage",
+        lambda name, **kw: built.append(kw) or stage(name, **kw),
+    )
+    queue.add_many(pods("plain"))
+    assert pop() == ["plain-0"]
+    assert built and all(set(kw) == {"totals"} for kw in built)
+
+
 def test_an_informer_frame_is_one_ingest_span(tmp_path):
     totals = flightrecorder.StageTotals()
     seen = []
@@ -307,15 +372,15 @@ def test_packs_children_lie_inside_their_parent(burst_trace):
         assert pack["end"] <= dispatch["end"]
 
 
-def test_packs_children_say_how_much_was_incremental(burst_trace):
-    events, _dump, _sched = burst_trace
+def _packs_children_of_a_plain_burst(events):
+    """What a plain burst's packs say whatever the order of a pack and
+    the commit of the batch ahead of it; the snapshot refreshes and the
+    row repacks by start, for what does depend on that order."""
     by_start = lambda ev: ev["start"]
     refreshes = sorted(named(events, "sched/pack.snapshot"), key=by_start)
     for refresh in refreshes:
         assert refresh["stats"]["nodes"] == 16
         assert 0 <= refresh["stats"]["nodes_refreshed"] <= 16
-    # the first batch's commit changed nodes, so the second refreshed some
-    assert refreshes[-1]["stats"]["nodes_refreshed"] >= 1
     masks = sorted(named(events, "sched/pack.masks"), key=by_start)
     assert len(masks) >= 2
     for mask in masks:  # plain pods: one signature a batch
@@ -330,7 +395,101 @@ def test_packs_children_say_how_much_was_incremental(burst_trace):
         assert 0 <= state["stats"]["rows"] <= 16
         assert 0 <= state["stats"]["cpu_ms"] <= \
             (state["end"] - state["start"]) / 1e6 + 1.0
+    return refreshes, states
+
+
+def _refreshes_follow_the_commits_that_landed(events, refreshes, states):
+    """The pipeline packs a batch without waiting for the one ahead of
+    it to commit, so a refresh reads the commits that ended before it
+    began and nothing of a burst none of whose commits had begun.
+    Returns how many refreshes after the first were of each kind."""
+    commits = named(events, "sched/commit")
+    after, ahead = 0, 0
+    for before, refresh, state in zip(refreshes, refreshes[1:], states[1:]):
+        if any(before["end"] <= commit["start"]
+               and commit["end"] <= refresh["start"] for commit in commits):
+            assert refresh["stats"]["nodes_refreshed"] >= 1
+            assert state["stats"]["rows"] >= 1
+            after += 1
+        elif all(refresh["end"] <= commit["start"] for commit in commits):
+            assert refresh["stats"]["nodes_refreshed"] == 0
+            ahead += 1
+    return after, ahead
+
+
+def test_packs_children_say_how_much_was_incremental(burst_trace):
+    """One burst. Its tail batch is packed after the first batch's
+    commit where it waited for company meanwhile (its pods younger than
+    the batch window when the pop came back), and ahead of it where the
+    first dispatch outlasted the window (a cold compile): the window
+    runs from the oldest pod's arrival, so the aged tail leaves at once.
+    Either way the refresh says what had landed."""
+    events, _dump, _sched = burst_trace
+    refreshes, states = _packs_children_of_a_plain_burst(events)
+    _refreshes_follow_the_commits_that_landed(events, refreshes, states)
+
+
+def test_a_burst_created_once_the_one_ahead_bound_refreshes_its_nodes(
+    tmp_path
+):
+    """Two bursts, the second created once the first has bound, so its
+    pack follows the first's commits whatever the pipeline and the batch
+    window made of the first's own batches."""
+    _server, client, informers, sched = _stack()
+    sched.start()
+    try:
+        with profiled(tmp_path) as events:
+            _burst(client, sched, 100)
+            _burst(client, sched, 20, tag="q")
+    finally:
+        sched.stop()
+        informers.stop()
+    refreshes, states = _packs_children_of_a_plain_burst(events)
+    # the first burst's commits changed nodes, so the last refreshed some
+    assert refreshes[-1]["stats"]["nodes_refreshed"] >= 1
     assert states[-1]["stats"]["rows"] >= 1
+    after, _ahead = _refreshes_follow_the_commits_that_landed(
+        events, refreshes, states
+    )
+    assert after >= 1
+
+
+def test_an_aged_tail_batch_is_dispatched_without_a_wait_for_company(
+    tmp_path
+):
+    """A burst that aged past the batch window before the dispatcher
+    came to it (here the scheduler starts late; on the chip a dispatch
+    outlasts the window): the full batch leaves at once as ever, and the
+    partial batch behind it no longer waits a window for company that
+    cannot come, so nothing makes it wait for the batch ahead to
+    commit. The pipeline packs it against the carry either way."""
+    _server, client, informers, sched = _stack()
+    try:
+        with profiled(tmp_path) as events:
+            client.create_pods_bulk([
+                make_pod(f"p-{i}").container(cpu="10m", memory="16Mi").obj()
+                for i in range(120)
+            ])
+            deadline = time.time() + 60
+            while sched.queue.active_count() < 120:
+                assert time.time() < deadline, "the burst never queued"
+                time.sleep(0.01)
+            time.sleep(5 * sched.batch_window)
+            spent0 = metrics.queue_window_spent_pops.value()
+            sched.start()
+            _wait_bound(client, sched, 120)
+    finally:
+        sched.stop()
+        informers.stop()
+    dispatches = sorted(named(events, "sched/dispatch"),
+                        key=lambda ev: ev["start"])
+    assert [d["stats"]["pods"] for d in dispatches] == [64, 56]
+    assert metrics.queue_window_spent_pops.value() - spent0 == 1
+    for wait in named(events, "sched/pop_wait"):
+        if wait["start"] < dispatches[-1]["start"]:
+            assert wait["stats"]["waits_for"] == "first_pod"
+    refreshes, states = _packs_children_of_a_plain_burst(events)
+    _refreshes_follow_the_commits_that_landed(events, refreshes, states)
 
 
 def _constrained(tag, cpu):
