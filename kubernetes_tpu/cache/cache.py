@@ -302,7 +302,8 @@ class SchedulerCache:
 
     # -- node events --------------------------------------------------------
 
-    def add_node(self, node: Node) -> None:
+    def add_node(self, node: Node) -> bool:
+        """Returns whether the node-spec epoch moved."""
         with self._lock:
             ni = self._nodes.get(node.metadata.name)
             if ni is None:
@@ -316,13 +317,17 @@ class SchedulerCache:
             if csi is not None and not ni.csi_volume_limits:
                 ni.set_csi_node(csi)
             self._touch(node.metadata.name)
-            if _node_spec_changed(prev, node):
+            moved = _node_spec_changed(prev, node)
+            if moved:
                 self._node_spec_epoch = next(_node_spec_epochs)
+            return moved
 
-    def update_node(self, old: Node, new: Node) -> None:
-        self.add_node(new)
+    def update_node(self, old: Node, new: Node) -> bool:
+        return self.add_node(new)
 
-    def remove_node(self, node: Node) -> None:
+    def remove_node(self, node: Node) -> bool:
+        """Returns whether the node-spec epoch moved: whether the cache
+        held the node."""
         with self._lock:
             name = node.metadata.name
             ni = self._nodes.pop(name, None)
@@ -353,6 +358,7 @@ class SchedulerCache:
                 state.node_removed = True
                 if state.binding_finished:
                     state.deadline = now
+            return ni is not None
 
     # -- CSINode events (attachable-volume limits) --------------------------
 

@@ -759,9 +759,11 @@ class PriorityQueue:
 
     # -- move machinery -----------------------------------------------------
 
-    def move_all_to_active_or_backoff_queue(self, event: str) -> None:
-        """Reference :494: wake everything in unschedulableQ."""
+    def move_all_to_active_or_backoff_queue(self, event: str) -> int:
+        """Reference :494: wake everything in unschedulableQ. Returns
+        how many pods that moved."""
         with self._cond:
+            woke = len(self.unschedulable_q)
             for key, pi in list(self.unschedulable_q.items()):
                 if self._is_backing_off(pi):
                     self.pod_backoff_q.add(pi)
@@ -770,6 +772,7 @@ class PriorityQueue:
                 del self.unschedulable_q[key]
             self.move_request_cycle = self.scheduling_cycle
             self._cond.notify_all()
+        return woke
 
     def move_pods_to_active_or_backoff_queue(
         self, pod_infos: List[PodInfo], event: str

@@ -583,6 +583,8 @@ class BatchScheduler(Scheduler):
         # add/remove rows patched onto the resident state without a
         # layout move, an upload, or a divergence)
         self.membership_row_patches = 0
+        #: the node-spec epoch the last batch packed against
+        self._packed_node_epoch = 0
         self._dev = _DeviceNodeState()
         self._shadow_lock = threading.Lock()
         # pipelined batches flow dispatcher -> committer through this
@@ -2088,7 +2090,7 @@ class BatchScheduler(Scheduler):
             queue_wait_max_ms=round(max(waits, default=0.0) * 1e3, 3),
         )
         totals = self.stage_totals
-        with flightrecorder.stage("pack", span, totals):
+        with flightrecorder.stage("pack", span, totals) as packing:
             pods = [pi.pod for pi in solver_infos]
             # poison manifestation: any stamped pod in the dispatch fails
             # every ladder tier (PoisonError), driving the exhaustion the
@@ -2203,15 +2205,21 @@ class BatchScheduler(Scheduler):
             # only, the ring keeps the one ``pack``
 
             assumed_seq = 0
+            # a batch in flight when the snapshot is refreshed may be
+            # missing from this pack even if it has committed by the
+            # time the handshake looks: the pack may then repair rows
+            # of the resident carry, never become it (the upload)
+            in_flight_at_pack = False
 
             def refresh_snapshot() -> None:
-                nonlocal assumed_seq
+                nonlocal assumed_seq, in_flight_at_pack
                 with flightrecorder.stage(
                     "pack.snapshot", totals=totals, batch=batch_id
                 ) as refresh:
                     # read BEFORE the refresh: commits up to here are
                     # in what it reads
                     assumed_seq = self._assumed_seq
+                    in_flight_at_pack = self._pending_exists()
                     self.cache.update_snapshot(snapshot)
                     refresh.set_metadata(
                         nodes_refreshed=snapshot.last_refreshed,
@@ -2274,6 +2282,14 @@ class BatchScheduler(Scheduler):
                     self.pods_fallback += 1
                     self.attempt_schedule(pi)
                 return None
+            # whether this batch packs against another node-spec epoch
+            # than the last one did: what is kept per epoch (the static
+            # mask rows, the family packers' node rows) is built again
+            epoch = snapshot.node_spec_epoch
+            packing.set_metadata(
+                node_epoch_moved=int(epoch != self._packed_node_epoch)
+            )
+            self._packed_node_epoch = epoch
             with flightrecorder.stage(
                 "pack.state", totals=totals, batch=batch_id
             ) as state:
@@ -2550,13 +2566,24 @@ class BatchScheduler(Scheduler):
         # shadow bookkeeping on the assumption that the decided upload /
         # scatter actually reaches the device this dispatch.
         ds = self._dev
+        unmirrored = self._unmirrored_exists()
         neg = self._negotiate_device_state(
             nt, node_requested, node_nzr, overlaid,
-            pending_exists=self._pending_exists(),
-            unmirrored_exists=self._unmirrored_exists(),
+            pending_exists=in_flight_at_pack or self._pending_exists(),
+            unmirrored_exists=unmirrored,
             assumed_seq=assumed_seq,
         )
-        if neg is None and self._await_mirrors():
+        mirrored = False
+        if neg is None and unmirrored:
+            # the dispatcher waits, outside pack, until every batch in
+            # flight has mirrored: a wait with a name of its own. With
+            # nothing unmirrored the answer cannot change (a pack that
+            # predates a commit stays one): straight to the drain
+            with flightrecorder.stage(
+                "mirror_wait", totals=totals, batch=span.batch_id
+            ):
+                mirrored = self._await_mirrors()
+        if mirrored:
             # the blocked path (membership adopt / divergence repair)
             # only needs the carry to equal the shadow, which holds the
             # moment every in-flight batch has MIRRORED -- so wait for
@@ -2564,7 +2591,7 @@ class BatchScheduler(Scheduler):
             # ms) and renegotiate before paying a full pipeline drain
             retry = self._negotiate_device_state(
                 nt, node_requested, node_nzr, overlaid,
-                pending_exists=self._pending_exists(),
+                pending_exists=in_flight_at_pack or self._pending_exists(),
                 unmirrored_exists=False,
                 assumed_seq=assumed_seq,
             )
@@ -2601,6 +2628,9 @@ class BatchScheduler(Scheduler):
             "devices": 1 if self.mesh is None else int(self.mesh.devices.size),
             "carry": carry_how,
             "carry_rows": delta_rows if carry_ok else int(nt.capacity),
+            # of them, the slots a node joined or left since the last
+            # batch (membership churn rides the same scatter)
+            "member_rows": int(neg["member"]),
             # the pods of the batch: the steps a one-chip kernel runs of
             # the ``padded`` that ``sched/dispatch`` says
             "steps": int(b),

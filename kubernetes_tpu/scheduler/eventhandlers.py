@@ -487,33 +487,62 @@ def add_all_event_handlers(
         coord = sched.partition_coordinator
         return coord is None or coord.owns_node_obj(node)
 
+    # Each handler is one ``sched/node_event`` span inside its frame's
+    # ``sched/ingest``: ``kind``, whether the node-spec epoch moved
+    # (``spec_changed``: a kubelet's status report must not) and the pods
+    # the event moved out of the unschedulable map (``woke``). A node
+    # that is not this stack's carries the kind alone.
+    def node_event(kind: str):
+        return flightrecorder.stage(
+            "node_event", totals=stage_totals, kind=kind
+        )
+
+    def handled(timed, moved, woke: int) -> None:
+        timed.set_metadata(spec_changed=int(bool(moved)), woke=woke)
+
     def add_node(node: Node) -> None:
-        # capacity feed runs BEFORE the ownership gate: the DRF
-        # denominator is the whole cluster, not this stack's slice
-        if note_node_cap is not None:
-            note_node_cap(node)
-        if not _node_ours(node):
-            return
-        sched.cache.add_node(node)
-        sched.queue.move_all_to_active_or_backoff_queue(events.NodeAdd)
+        with node_event("add") as timed:
+            # capacity feed runs BEFORE the ownership gate: the DRF
+            # denominator is the whole cluster, not this stack's slice
+            if note_node_cap is not None:
+                note_node_cap(node)
+            if not _node_ours(node):
+                return
+            moved = sched.cache.add_node(node)
+            woke = sched.queue.move_all_to_active_or_backoff_queue(
+                events.NodeAdd
+            )
+            handled(timed, moved, woke)
 
     def update_node(old: Node, new: Node) -> None:
-        if note_node_cap is not None:
-            note_node_cap(new)
-        if not _node_ours(new):
-            return
-        sched.cache.update_node(old, new)
-        event = _node_scheduling_properties_changed(old, new)
-        if event:
-            sched.queue.move_all_to_active_or_backoff_queue(event)
+        with node_event("update") as timed:
+            if note_node_cap is not None:
+                note_node_cap(new)
+            if not _node_ours(new):
+                return
+            moved = sched.cache.update_node(old, new)
+            event = _node_scheduling_properties_changed(old, new)
+            woke = 0
+            if event:
+                woke = sched.queue.move_all_to_active_or_backoff_queue(event)
+            handled(timed, moved, woke)
 
     def delete_node(node: Node) -> None:
+        with node_event("delete") as timed:
+            moved, woke = _delete_node(node)
+            if moved is not None:
+                handled(timed, moved, woke)
+
+    def _delete_node(node: Node):
+        """(whether the epoch moved, pods woken); (None, 0) for a node
+        that is not this stack's."""
         if note_node_gone is not None:
             note_node_gone(node.metadata.name)
         coord = sched.partition_coordinator
         if coord is not None and not coord.owns_node(node.metadata.name):
-            return
-        sched.cache.remove_node(node)
+            return None, 0
+        moved = bool(sched.cache.remove_node(node))
+        woke = 0
         # a nomination pointing at the dead node is a reservation on
         # capacity that no longer exists: clear it (or the next batch's
         # nominee overlay and the host oracle keep honoring a phantom
@@ -561,9 +590,10 @@ def add_all_event_handlers(
                         logger.exception(
                             "clearing nominatedNodeName for %s", p.key()
                         )
-                sched.queue.move_all_to_active_or_backoff_queue(
+                woke = sched.queue.move_all_to_active_or_backoff_queue(
                     events.NodeDelete
                 )
+        return moved, woke
 
     nodes.add_event_handler(
         ResourceEventHandler(

@@ -15,10 +15,12 @@ import numpy as np
 import pytest
 
 from kubernetes_tpu.api.types import (
+    ContainerImage,
     CSINode,
     CSINodeDriver,
     NodeCondition,
     ObjectMeta,
+    Taint,
 )
 from kubernetes_tpu.apiserver.server import APIServer
 from kubernetes_tpu.cache.cache import SchedulerCache
@@ -1234,6 +1236,78 @@ def test_a_status_write_keeps_the_rows_and_the_facts(write):
     assert (kept.rows_built, kept.rows_reused) == (8, 12)
 
 
+def _roll_write(kind, cache, nodes, name="m4"):
+    """One write of a rolled node's life, as ``chipbench``'s ``NodeOps``
+    makes it: a new Node object handed to the cache's handler."""
+    old = nodes.get(name)
+    if kind == "delete":
+        cache.remove_node(nodes.pop(name))
+        return
+    if kind == "join":  # registers not Ready, with no image yet
+        nodes[name] = _node(name, "z1").taint(
+            "node.kubernetes.io/not-ready", "", "NoSchedule").obj()
+        cache.add_node(nodes[name])
+        return
+    new = old.deepcopy()
+    if kind == "cordon":
+        new.spec.unschedulable = True
+    elif kind == "ready":
+        new.spec.taints = [t for t in new.spec.taints
+                           if t.key != "node.kubernetes.io/not-ready"]
+    elif kind == "first_image":
+        new.status.images = [
+            ContainerImage(names=["registry.example/app:v2"],
+                           size_bytes=50 << 20)]
+    elif kind == "status_report":
+        new.status.conditions = [NodeCondition("Ready", "True")]
+    nodes[name] = new
+    cache.update_node(old, new)
+
+
+def test_each_write_of_a_roll_moves_the_epoch_once_and_a_status_report_never():
+    """``rolling-upgrade-5000``'s node writes against the one node-spec
+    epoch and the static mask rows kept under it: cordon, delete, join,
+    Ready and the first image each move the epoch once and have the rows
+    built again once, right; a kubelet's status report before, between
+    and after them moves nothing and keeps every row."""
+    cache, nodes = _mask_cluster()
+    snap = Snapshot()
+    tc = NodeTensorCache()
+    kept = MaskRowCache()
+    pods = [p for p in _mask_pods() if not pod_host_ports(p)]
+    rows = len({host_masks._constraint_signature(p) for p in pods})
+
+    def pack():
+        cache.update_snapshot(snap)
+        nt = tc.update(snap)
+        got = static_mask_compact(pods, snap, nt, kept)
+        want = static_mask_twin(pods, snap, nt)
+        assert np.array_equal(got[0], want[0])
+        assert np.array_equal(got[1], want[1])
+        return snap.node_spec_epoch, kept.rows_built, kept.rows_reused
+
+    epoch, built, reused = pack()
+    assert (built, reused) == (rows, 0)
+    for kind in ("cordon", "delete", "join", "ready", "first_image"):
+        for other in ("m1", "m7"):  # kubelets elsewhere, and the node's own
+            _roll_write("status_report", cache, nodes, other)
+        if kind in ("ready", "first_image"):
+            _roll_write("status_report", cache, nodes, "m4")
+        assert pack() == (epoch, built, reused + rows), kind
+        reused += rows
+        _roll_write(kind, cache, nodes)
+        now, built_now, reused_now = pack()
+        assert now != epoch, kind  # moved ...
+        assert (built_now, reused_now) == (built + rows, reused), kind
+        epoch, built = now, built_now
+        assert pack() == (epoch, built, reused + rows), kind  # ... once
+        reused += rows
+    # the rolled node is back: schedulable, and in every row it was in
+    node = nodes["m4"]
+    assert not node.spec.unschedulable and node.spec.taints == []
+    assert node.status.images[0].size_bytes == 50 << 20
+
+
 def test_a_host_port_signature_is_never_kept_and_the_rest_are_bounded():
     cache, _nodes = _mask_cluster()
     snap = Snapshot()
@@ -1735,6 +1809,59 @@ def test_family_packs_equal_the_walks_under_churn(seed):
     kept = c.facts
     assert kept.node_rows_reused > 0
     assert kept.nodes_recounted < kept.nodes  # not every node every time
+
+
+def _cordon(c, name):
+    old = c.nodes[name]
+    new = old.deepcopy()
+    new.spec.unschedulable = True
+    c.nodes[name] = new
+    c.cache.update_node(old, new)
+
+
+def _delete(c, name):
+    c.remove_node(name)  # its pods go with it, as a drain has them
+
+
+def _rejoin(c, name):
+    """Gone, packed without, and back under its own name, not Ready."""
+    labels = c.nodes[name].metadata.labels
+    c.remove_node(name)
+    c.check(_wave(c.rng, "gone"))
+    node = c._node_obj(name, labels["zone"], "rack" in labels, POOL in labels)
+    node.spec.taints = [Taint("node.kubernetes.io/not-ready", "",
+                              "NoSchedule")]
+    c.nodes[name] = node
+    c.cache.add_node(node)
+
+
+@pytest.mark.parametrize("write", [_cordon, _delete, _rejoin],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_family_node_rows_are_built_again_once_after_a_rolled_nodes_write(
+        write):
+    """The store ``rolling-upgrade-5000``'s plain pods cannot reach
+    (``ops/family_facts.py``), held on the CPU until a deployment reaches
+    it: after a cordon, a delete and a re-join under the same name the
+    node-value rows kept per node-spec epoch are built again, once, and
+    every array equals the walk's (``check``)."""
+    c = Cluster(seed=11)
+    for app in APPS:
+        for _ in range(6):
+            c.add_pod(app=app)
+    c.check(_wave(c.rng, "w0"))
+    c.check(_wave(c.rng, "w1"))
+    facts = c.facts
+    asked, reused = facts.node_rows, facts.node_rows_reused
+    name = sorted(c.nodes)[3]
+    write(c, name)
+    asked, reused = facts.node_rows, facts.node_rows_reused
+    c.check(_wave(c.rng, "after"))
+    built = (facts.node_rows - asked) - (facts.node_rows_reused - reused)
+    assert built > 0  # the epoch moved: the rows were given up ...
+    asked, reused = facts.node_rows, facts.node_rows_reused
+    c.status_write(sorted(c.nodes)[0])  # a kubelet's report keeps them
+    c.check(_wave(c.rng, "again"))
+    assert facts.node_rows - asked == facts.node_rows_reused - reused  # once
 
 
 @pytest.mark.parametrize("lands_on", ["some nodes", "every node"])

@@ -315,6 +315,61 @@ def test_the_whole_build_is_taken_where_the_rows_cannot_be_seen_to_hold(
     assert (pack.made, pack.why, pack.nodes_repacked) == ("advanced", "", 1)
 
 
+def _drain(c, name):
+    for pod in c.on(name):
+        c.remove(pod.metadata.name)
+
+
+def cordon(c, name):
+    old = c.nodes[name]
+    new = old.deepcopy()
+    new.spec.unschedulable = True
+    c.cache.update_node(old, new)
+    c.nodes[name] = new
+
+
+def delete(c, name):
+    _drain(c, name)
+    c.cache.remove_node(c.nodes.pop(name))
+
+
+def rejoin(c, name):
+    """Gone, packed without, and back under its own name, not Ready."""
+    delete(c, name)
+    assert c.refresh().why == "membership"
+    node = make_node(name).capacity(cpu="32", memory="64Gi", pods=110).taint(
+        "node.kubernetes.io/not-ready", "", "NoSchedule").obj()
+    c.cache.add_node(node)
+    c.nodes[name] = node
+
+
+@pytest.mark.parametrize("write, made", [
+    (cordon, "advanced"), (delete, "built"), (rejoin, "built"),
+], ids=["cordon", "delete", "rejoin"])
+def test_the_victim_pack_is_right_after_a_rolled_nodes_write(write, made):
+    """The store ``rolling-upgrade-5000`` cannot reach (nothing preempts
+    there), held on the CPU until a deployment reaches it: after a
+    cordon, a delete and a re-join under the same name the pack equals
+    the whole build (``refresh`` holds it array for array); a change of
+    membership builds it whole, once, and the next small change is
+    advanced again."""
+    c = Cluster(9)
+    c.refresh()
+    c.remove(c.on("n0")[3].metadata.name)
+    assert c.refresh().made == "advanced"
+    write(c, "n5")
+    pack = c.refresh()
+    assert pack.made == made
+    assert ("n5" in pack.node_index) == (write is not delete)
+    if made == "built":
+        assert pack.why == "membership"
+        assert (pack.nodes_kept, pack.nodes_repacked) == (
+            0, len(pack.node_names))
+    c.add(resident("after", "n0", start=NOW - 2))
+    pack = c.refresh()
+    assert (pack.made, pack.why, pack.nodes_repacked) == ("advanced", "", 1)
+
+
 def test_a_start_time_ahead_of_the_clock_keeps_nothing(still_clock):
     """A pod without a start time sorts before one that starts
     tomorrow, and after it the day after: rows sorted by an earlier

@@ -14,6 +14,7 @@ import time
 from contextlib import contextmanager
 
 import jax
+import numpy as np
 import pytest
 
 from kubernetes_tpu.apiserver.server import ADDED, APIServer, WatchEvent
@@ -99,6 +100,16 @@ def _burst(client, sched, count, tag="p"):
         for i in range(count)
     ])
     _wait_bound(client, sched, count)
+
+
+def _report_status(server, name):
+    """A kubelet's status write: conditions, nothing of the spec."""
+    from kubernetes_tpu.api.types import NodeCondition
+
+    def mutate(node):
+        node.status.conditions = [NodeCondition("Ready", "True")]
+
+    server.guaranteed_update("Node", "", name, mutate)
 
 
 def _wait_bound(client, sched, count):
@@ -314,6 +325,9 @@ def burst_trace(tmp_path):
     try:
         with profiled(tmp_path) as events:
             _burst(client, sched, 120)
+            # a kubelet's status report: the node handlers' stage has a
+            # total since set-up's node adds, so it has a span here too
+            _report_status(server, "node-0")
             # the run loop's idle point, 0.5 s after the last pop, makes
             # one full collection. Its span is in the trace only once it
             # has ENDED, and in a worker whose heap earlier test files
@@ -1050,3 +1064,237 @@ def test_an_ingest_frame_says_how_long_it_waited_for_the_informer(tmp_path):
     assert first["stats"]["events"] == 1 and second["stats"]["events"] == 3
     assert second["stats"]["waited_ms"] >= HOLD_S * 1e3
     assert 0 <= first["stats"]["waited_ms"] < second["stats"]["waited_ms"]
+
+
+# -- nodes that change (PR 41) -------------------------------------------------
+
+
+def _wait_for(predicate, what, seconds=30.0):
+    deadline = time.time() + seconds
+    while not predicate():
+        assert time.time() < deadline, what
+        time.sleep(0.005)
+
+
+def test_every_node_handler_is_a_node_event_span_with_its_stats(tmp_path):
+    """``sched/node_event``: one a handler call, inside its frame's
+    ``sched/ingest`` on the Node informer's line, with ``kind``,
+    ``spec_changed`` (whether the node-spec epoch moved) and ``woke``
+    (pods the event moved out of the unschedulable map)."""
+    server, client, informers, sched = _stack(num_nodes=4)
+    sched.start()
+    calls0 = sched.stage_totals.calls().get("node_event", 0)
+    assert calls0 == 4  # set-up's four adds, with no session
+    # a pod no node holds waits in the unschedulable map for an event
+    client.create_pods_bulk([
+        make_pod("huge").container(cpu="64", memory="16Mi").obj()])
+    _wait_for(lambda: sched.queue.unschedulable_pods(),
+              "the pod that fits nowhere was never parked")
+
+    def cordon(node):
+        node.spec.unschedulable = True
+
+    try:
+        with profiled(tmp_path) as events:
+            _report_status(server, "node-1")  # conditions: wakes, no epoch
+            _report_status(server, "node-1")  # the same again: nothing
+            server.guaranteed_update("Node", "", "node-2", cordon)
+            client.delete_node("node-3")
+            client.create_node(
+                make_node("node-3")
+                .capacity(cpu="32", memory="64Gi", pods=110).obj())
+            _wait_for(
+                lambda: sched.stage_totals.calls()["node_event"] == calls0 + 5,
+                "the node informer did not see the five writes")
+    finally:
+        sched.stop()
+        informers.stop()
+    spans = sorted(named(events, "sched/node_event"),
+                   key=lambda ev: ev["start"])
+    assert [own_stats(ev)["kind"] for ev in spans] == [
+        "update", "update", "update", "delete", "add"]
+    assert [own_stats(ev)["spec_changed"] for ev in spans] == [0, 0, 1, 1, 1]
+    for ev in spans:
+        assert set(own_stats(ev)) == {"kind", "spec_changed", "woke"}
+        assert "cpu_ms" in ev["stats"]  # a clocked stage: it has a total
+        (frame,) = [f for f in named(events, "sched/ingest")
+                    if f["line"] == ev["line"]
+                    and f["start"] <= ev["start"] and ev["end"] <= f["end"]]
+        assert frame["stats"]["kind"] == "Node"
+    # the first report changed the conditions and woke the parked pod;
+    # the second changed nothing and moved nobody
+    assert own_stats(spans[0])["woke"] == 1
+    assert own_stats(spans[1])["woke"] == 0
+
+
+def test_pack_says_whether_the_node_epoch_moved_and_the_solve_its_member_rows(
+        tmp_path):
+    """``node_epoch_moved`` on ``sched/pack``: this batch packed against
+    another node-spec epoch than the last. ``member_rows`` on
+    ``sched/solve_dispatch``, beside ``carry_rows``: the slots a node
+    joined or left since the last batch, which ride the carry's scatter."""
+    server, client, informers, sched = _stack(num_nodes=8)
+    sched.start()
+
+    def cordon(node):
+        node.spec.unschedulable = True
+
+    try:
+        with profiled(tmp_path) as events:
+            _burst(client, sched, 10, tag="a")  # the first batch: upload
+            _burst(client, sched, 10, tag="b")  # nothing moved
+            _report_status(server, "node-1")
+            _wait_for(lambda: sched.stage_totals.calls()["node_event"] == 9,
+                      "the status report was not seen")
+            _burst(client, sched, 10, tag="c")  # a status report: nothing
+            server.guaranteed_update("Node", "", "node-2", cordon)
+            _wait_for(lambda: sched.stage_totals.calls()["node_event"] == 10,
+                      "the cordon was not seen")
+            _burst(client, sched, 10, tag="d")  # the epoch moved
+            uploads = sched.state_uploads
+            # a node no pod was bound to (the cordoned one) leaves, a batch
+            # packs without it, and it joins again under its own name: its
+            # slot is retired and claimed again. (With no batch between
+            # the two the slot's name never leaves the tensor: a changed
+            # row, no membership.)
+            client.delete_node("node-2")
+            _wait_for(lambda: sched.stage_totals.calls()["node_event"] == 11,
+                      "the delete was not seen")
+            _burst(client, sched, 10, tag="e")
+            client.create_node(
+                make_node("node-2")
+                .capacity(cpu="32", memory="64Gi", pods=110).obj())
+            _wait_for(lambda: sched.stage_totals.calls()["node_event"] == 12,
+                      "the replacement was not seen")
+            _burst(client, sched, 10, tag="f")
+            sched._drain_pending()
+    finally:
+        sched.stop()
+        informers.stop()
+    packs = sorted(named(events, "sched/pack"), key=lambda ev: ev["start"])
+    moved = [ev["stats"]["node_epoch_moved"] for ev in packs]
+    assert len(packs) >= 6 and set(moved) <= {0, 1}
+    # the first batch (no epoch packed before it), the cordon's, the
+    # delete's and the join's; the status report's batch is not among them
+    assert sum(moved) == 4, moved
+    solves = sorted(named(events, "sched/solve_dispatch"),
+                    key=lambda ev: ev["start"])
+    members = [ev["stats"]["member_rows"] for ev in solves]
+    assert sum(members) == 2 and max(members) == 1, members
+    for ev in solves:
+        if ev["stats"]["member_rows"]:
+            assert ev["stats"]["carry"] == "scatter"
+            assert ev["stats"]["carry_rows"] >= ev["stats"]["member_rows"]
+    assert sched.state_uploads == uploads  # the scatter took it, no upload
+    assert sched.membership_row_patches == 2
+
+
+def test_the_wait_for_mirrors_has_a_span_of_its_own(tmp_path):
+    """A membership change with a batch in flight: the dispatcher waits,
+    outside ``sched/pack``, until the batch has mirrored
+    (``sched/mirror_wait``), then scatters the rows."""
+    client, informers, sched, release = _held_stack()
+    try:
+        with profiled(tmp_path) as events:
+            _dispatch(client, sched, _plain("first"))  # in flight, held
+            calls = sched.stage_totals.calls()["node_event"]
+            client.create_node(
+                make_node("late")
+                .capacity(cpu="32", memory="64Gi", pods=110).obj())
+            _wait_for(
+                lambda: sched.stage_totals.calls()["node_event"] == calls + 1,
+                "the new node was not seen")
+            _release_once_the_dispatcher_waits(sched, release)
+            _dispatch(client, sched, _plain("second"))
+            sched._drain_pending()
+            sched.wait_for_inflight_binds()
+    finally:
+        sched.stop()
+        informers.stop()
+    (wait,) = named(events, "sched/mirror_wait")
+    first, second = sorted(named(events, "sched/pack"),
+                           key=lambda ev: ev["start"])
+    assert wait["stats"]["batch"] == second["stats"]["batch"]
+    assert wait["line"] == second["line"] and wait["start"] >= second["end"]
+    assert sched.stage_totals.calls()["mirror_wait"] == 1
+    (solve,) = [ev for ev in named(events, "sched/solve_dispatch")
+                if ev["stats"]["batch"] == second["stats"]["batch"]]
+    assert solve["stats"]["member_rows"] == 1
+
+
+def test_a_pack_that_predates_a_commit_never_becomes_the_carry():
+    """What ``rolling-upgrade-5000`` showed on the chip as re-joined nodes
+    filled twice: a batch packs while another is in flight, the handshake
+    has to replace the carry (more rows changed than the scatter takes),
+    the dispatcher waits for the mirrors, and by the time it looks again
+    the other batch has committed and nothing is pending. The pack's host
+    arrays predate that commit, so uploading them would drop its pods from
+    the resident state and the next batch would place around pods that
+    are there. The dispatch has to start again from a fresh pack."""
+    from kubernetes_tpu.cache.snapshot import Snapshot
+    from kubernetes_tpu.tensors import NodeTensorCache
+
+    server, client, informers, sched = _stack(num_nodes=80, max_batch=128)
+    sched.queue.run()
+    release = threading.Event()
+    release.set()
+    complete = sched._complete_solve
+
+    def held(p):
+        release.wait(10)
+        complete(p)
+
+    sched._complete_solve = held
+    # the dispatcher is slow to wake: by the time it looks again the
+    # batch it waited for has committed whole
+    await_mirrors = sched._await_mirrors
+
+    def slow_to_wake(timeout=30.0):
+        woke = await_mirrors(timeout)
+        sched._drain_pending()
+        return woke
+
+    sched._await_mirrors = slow_to_wake
+
+    def plain(tag, count):
+        return [make_pod(f"{tag}-{i}").container(cpu="10m", memory="16Mi")
+                .obj() for i in range(count)]
+
+    def cached(count):
+        _wait_for(lambda: sched.cache.pod_count() == count,
+                  f"the cache never held {count} pods")
+
+    try:
+        # a carry, then more rows changed from outside than a scatter takes
+        _dispatch(client, sched, plain("first", 80))
+        sched._drain_pending()
+        sched.wait_for_inflight_binds()
+        cached(80)
+        client.delete_pods_bulk([("default", f"first-{i}") for i in range(80)])
+        cached(0)
+        release.clear()
+        _dispatch(client, sched, plain("a", 1))  # uploads; in flight, held
+        client.create_pods_bulk([
+            make_pod(f"there-{i}").node(f"node-{i}")
+            .container(cpu="10m", memory="16Mi").obj() for i in range(80)])
+        cached(80)
+        _release_once_the_dispatcher_waits(sched, release)
+        _dispatch(client, sched, plain("b", 1))
+        sched._drain_pending()
+        sched.wait_for_inflight_binds()
+        cached(82)
+    finally:
+        release.set()
+        sched.stop()
+        informers.stop()
+    # "b" was dispatched twice: the second time from a fresh pack
+    routed = [sp["routed"] for sp in flightrecorder.RECORDER.dump()["spans"]]
+    assert routed.count("drain_redispatch") == 1
+    # the resident state's shadow holds every pod the cache holds, "a" too
+    snap = Snapshot()
+    sched.cache.update_snapshot(snap)
+    truth = NodeTensorCache().update(snap)
+    shadow = sched._dev.req_shadow
+    assert int(shadow.sum()) == int(truth.requested.sum())
+    assert np.array_equal(np.sort(shadow.sum(axis=1)),
+                          np.sort(truth.requested.sum(axis=1)))
