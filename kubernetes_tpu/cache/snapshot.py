@@ -11,7 +11,9 @@ from __future__ import annotations
 
 import threading
 from bisect import bisect_right
-from typing import Dict, Iterable, List, Optional, Set, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Set, Tuple
+
+import numpy as np
 
 from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.cache.node_info import NodeInfo, pod_has_affinity_constraints
@@ -26,6 +28,15 @@ CHANGE_TRACK_MIN = 4096
 
 def _entry_seq(entry: Tuple[int, str]) -> int:
     return entry[0]
+
+
+class ImageHolders(NamedTuple):
+    """One image of ``Snapshot.image_holders``: the nodes that hold it."""
+
+    positions: np.ndarray  # [count] int64, into node_info_list, ascending
+    sizes: np.ndarray  # [count] float64, the image's bytes on that node
+    count: int  # ImageStateSummary.NumNodes
+    largest: int  # the most bytes any one node reports for it
 
 
 class Snapshot:
@@ -51,10 +62,13 @@ class Snapshot:
         #: NodeInfos the last refresh cloned
         self.last_refreshed = 0
         self._list_pos: Optional[Dict[str, int]] = None
-        self._image_num_nodes: Optional[Dict[str, int]] = None
-        #: the score packer's node-side facts (ops/scoring.py), None
-        #: until taken and again whenever ``node_spec_epoch`` moves
-        self.score_facts: Optional[Tuple[bool, bool, bool]] = None
+        self._image_holders: Optional[Dict[str, ImageHolders]] = None
+        #: the score packer's node-side facts (ops/scoring.py) with the
+        #: node-spec epoch and the change-log cursor they were taken at;
+        #: None until taken
+        self.score_facts: Optional[
+            Tuple[int, int, Tuple[bool, bool, bool]]
+        ] = None
         # -- change tracking (epoch plumbing for the tensor packer) ---------
         # update_snapshot notes every name it re-clones in an APPEND-ONLY
         # sequence-stamped log so any NodeTensorCache can repack O(changed)
@@ -183,8 +197,7 @@ class Snapshot:
     def set_node_spec_epoch(self, epoch: int) -> None:
         if epoch != self.node_spec_epoch:
             self.node_spec_epoch = epoch
-            self._image_num_nodes = None
-            self.score_facts = None
+            self._image_holders = None
 
     def refresh_lists(self) -> None:
         old = self.node_info_list
@@ -202,22 +215,41 @@ class Snapshot:
         self.have_pods_with_affinity_list = [
             ni for ni in self.node_info_list if ni.pods_with_affinity
         ]
-        self._image_num_nodes = None
-        self.score_facts = None
+        self._image_holders = None
         self._list_pos = None
 
-    def image_num_nodes(self) -> Dict[str, int]:
-        """image name -> number of nodes holding it; computed once per
-        snapshot refresh (reference ImageStateSummary.NumNodes,
-        snapshot.go:124 createImageStates)."""
-        cached = self._image_num_nodes
-        if cached is None:
-            cached = {}
-            for ni in self.node_info_list:
-                for image in ni.image_states:
-                    cached[image] = cached.get(image, 0) + 1
-            self._image_num_nodes = cached
-        return cached
+    def image_holders(self) -> Dict[str, ImageHolders]:
+        """image name -> the nodes that hold it (reference
+        ImageStateSummary, snapshot.go:124 createImageStates, which keeps
+        the count alone). One walk of every node's ``image_states`` for
+        each of the snapshot's node-spec epochs: a node's images are part
+        of what moves the epoch, and ``refresh_lists`` drops the index
+        with the positions it holds. A snapshot no cache feeds (epoch 0)
+        cannot tell when its nodes change, and walks at every call."""
+        index = self._image_holders if self.node_spec_epoch else None
+        if index is None:
+            held: Dict[str, Tuple[List[int], List[int]]] = {}
+            for pos, states in [
+                (pos, ni.image_states)
+                for pos, ni in enumerate(self.node_info_list)
+                if ni.image_states
+            ]:
+                for image, size in states.items():
+                    entry = held.get(image)
+                    if entry is None:
+                        entry = held[image] = ([], [])
+                    entry[0].append(pos)
+                    entry[1].append(size)
+            index = self._image_holders = {
+                image: ImageHolders(
+                    np.array(positions, dtype=np.int64),
+                    np.array(sizes, dtype=np.float64),
+                    len(positions),
+                    max(sizes),
+                )
+                for image, (positions, sizes) in held.items()
+            }
+        return index
 
 
 def new_snapshot(pods: Iterable[Pod], nodes: Iterable[Node]) -> Snapshot:

@@ -68,9 +68,12 @@ UNSCOPED: Tuple = ((), ())
 #: (``add_host_port_rows``); no label key is a tuple
 ROW_INDEX = ("<row>",)
 
-#: the cumulative counters ``tally`` returns, in its order
+#: the cumulative counters ``tally`` returns, in its order; the last
+#: three are ``pack_score_batch``'s (ops/scoring.py), which keeps its
+#: facts on the snapshot and only counts here
 TALLY = ("nodes", "nodes_recounted", "node_rows", "node_rows_reused",
-         "templates")
+         "templates", "score_live", "score_image_sigs",
+         "score_image_sigs_live")
 
 #: one term of a group: (namespaces, selector, selector signature)
 Term = Tuple[Tuple[str, ...], Optional[LabelSelector], Tuple]
@@ -215,6 +218,9 @@ class FamilyFacts:
         self.node_rows = 0
         self.node_rows_reused = 0
         self.templates = 0
+        self.score_live = 0
+        self.score_image_sigs = 0
+        self.score_image_sigs_live = 0
 
     def tally(self) -> Tuple[int, ...]:
         return tuple(getattr(self, name) for name in TALLY)

@@ -18,7 +18,11 @@ the sequential path:
   and PreferAvoidPods are final values (no normalize); NodeAffinity and
   TaintToleration ship RAW and are normalized per scan step over the
   step's feasible set, because the reference normalizes over the filtered
-  node list (helper/normalize_score.go).
+  node list (helper/normalize_score.go). ImageLocality is decided and
+  built from the images' side (``Snapshot.image_holders``, one walk a
+  node-spec epoch): a batch none of whose image lists can score a node
+  above 0 carries no row for it, and a live row is written at the
+  holders alone; the three others ask every node for each signature.
 - **selector spread** (DefaultPodTopologySpread,
   default_pod_topology_spread.go:107) -- per combined-selector-group
   match counts per node, zone-blended (2/3) at normalize; counts replay
@@ -71,6 +75,7 @@ from kubernetes_tpu.tensors.node_tensor import (
     NodeTensor,
     value_capacity as _value_capacity_shared,
 )
+from kubernetes_tpu.utils import metrics
 
 MAX_SCORE_SIGS = 16
 SIG_BUCKET = 4
@@ -287,30 +292,111 @@ def _soft_constraints(pod: Pod):
     ]
 
 
+def _holds_image(ni) -> bool:
+    return bool(ni.image_states)
+
+
+def _soft_tainted(ni) -> bool:
+    return ni.node is not None and any(
+        t.effect == TAINT_EFFECT_PREFER_NO_SCHEDULE
+        for t in ni.node.spec.taints
+    )
+
+
+def _asks_to_be_avoided(ni) -> bool:
+    return (
+        ni.node is not None
+        and AVOID_ANNOTATION in ni.node.metadata.annotations
+    )
+
+
+_NODE_SIDE_FACTS = (_holds_image, _soft_tainted, _asks_to_be_avoided)
+
+
 def _node_side_facts(snapshot: Snapshot) -> Tuple[bool, bool, bool]:
     """(any node holds an image, any carries a PreferNoSchedule taint,
     any carries the avoid-pods annotation): facts of the Node objects
     alone, so taken once for each of the snapshot's node-spec epochs and
-    not at every batch. A snapshot no cache feeds (epoch 0) cannot tell
-    when its nodes change, and is walked at every call."""
-    facts = snapshot.score_facts if snapshot.node_spec_epoch else None
-    if facts is None:
-        infos = snapshot.list_node_infos()
-        facts = snapshot.score_facts = (
-            any(ni.image_states for ni in infos),
-            any(
-                t.effect == TAINT_EFFECT_PREFER_NO_SCHEDULE
-                for ni in infos
-                if ni.node is not None
-                for t in ni.node.spec.taints
-            ),
-            any(
-                ni.node is not None
-                and AVOID_ANNOTATION in ni.node.metadata.annotations
-                for ni in infos
-            ),
-        )
+    not at every batch, and kept on the snapshot with the epoch and the
+    change-log cursor they were taken at. When the epoch has moved, a
+    fact that did not hold can have come to hold only on a node that a
+    refresh has cloned since (``Snapshot.changes_since``: every node
+    whose object was written is among them), so those alone are asked; a
+    fact that held is looked for again on every node, up to the first
+    that has it. A truncated log asks every node, as first use does. A
+    snapshot no cache feeds (epoch 0) cannot tell when its nodes change,
+    and is walked at every call."""
+    epoch = snapshot.node_spec_epoch
+    kept = snapshot.score_facts if epoch else None
+    if kept is not None and kept[0] == epoch:
+        return kept[2]
+    infos = snapshot.list_node_infos()
+    held, changed = (True, True, True), None
+    if kept is None:
+        cursor = snapshot.change_cursor()
+    else:
+        names, _membership, cursor = snapshot.changes_since(kept[1])
+        if names is not None:
+            held = kept[2]
+            info_map = snapshot.node_info_map
+            changed = [info_map[name] for name in names if name in info_map]
+    facts = tuple(
+        any(map(fact, infos if was else changed))
+        for fact, was in zip(_NODE_SIDE_FACTS, held)
+    )
+    snapshot.score_facts = (epoch, cursor, facts)
     return facts
+
+
+def _image_scores(
+    pods: List[Pod], snapshot: Snapshot
+) -> Tuple[int, Dict[Tuple[str, ...], Tuple[np.ndarray, np.ndarray]]]:
+    """ImageLocality for the batch, from the images' side
+    (``Snapshot.image_holders``): how many distinct container image lists
+    of ``pods`` name an image, and for each list that scores some node
+    above 0 the (positions in ``node_info_list``, scores there) of those
+    that hold any of its images; every other node, and every node of a
+    list left out, scores 0 (image_locality.go:60-76).
+
+    A list is first held to the most any node could have: every image of
+    it at the largest size reported, on the share of nodes that hold it.
+    A node's own sum takes a subset of those terms, in the same order,
+    with sizes no larger, and float addition and ``calculatePriority``
+    are monotonic, so a bound of 0 is every node's 0 exactly: no array is
+    touched for an image no node holds, or one too small or on too few
+    nodes to pass the plugin's threshold. Past the bound each holder's
+    sum is taken over the containers in order, in float64, and put
+    through the plugin's own ``calculatePriority``: the plugin's value
+    for that node, bit for bit."""
+    holders = snapshot.image_holders()
+    total_nodes = snapshot.num_nodes()
+    named = 0
+    live: Dict[Tuple[str, ...], Tuple[np.ndarray, np.ndarray]] = {}
+    seen = set()
+    for p in pods:
+        images = tuple([c.image for c in p.spec.containers])
+        if images in seen:
+            continue
+        seen.add(images)
+        named += any(images)
+        held = [h for h in map(holders.get, images) if h is not None]
+        bound = 0.0
+        for h in held:
+            bound += h.largest * (h.count / total_nodes)
+        if not ImageLocality._calculate_priority(bound):
+            continue
+        sums = np.zeros(total_nodes, dtype=np.float64)
+        for h in held:
+            sums[h.positions] += h.sizes * (h.count / total_nodes)
+        positions = np.nonzero(sums)[0]
+        distinct, which = np.unique(sums[positions], return_inverse=True)
+        scores = np.array(
+            [ImageLocality._calculate_priority(float(x)) for x in distinct],
+            dtype=np.int64,
+        )[which]
+        if scores.any():
+            live[images] = (positions, scores)
+    return named, live
 
 
 def pack_score_batch(
@@ -322,21 +408,34 @@ def pack_score_batch(
     hard_pod_affinity_weight: int = 1,
     cluster_affinity_scoring: Optional[bool] = None,
     admissions=None,
+    facts=None,
 ) -> Optional[ScoreBatch]:
     """Returns None when no non-resource scorer can influence ranking for
     this batch (the common fast path); raises ScoreEnvelopeExceeded when
     the batch needs the host path. ``admissions`` are the pods'
-    admission records (scheduler/admission.py), in any order."""
+    admission records (scheduler/admission.py), in any order. ``facts``
+    is the dispatcher's ``FamilyFacts``, whose tally takes what this call
+    found: ``score_image_sigs`` (distinct image lists the batch names,
+    counted where some node holds an image), ``score_image_sigs_live``
+    (those that score some node above 0) and ``score_live`` (a
+    ``ScoreBatch`` was returned)."""
     infos = snapshot.list_node_infos()
     n_cap = nt.capacity
     b = len(pods)
 
     any_images, any_soft_taints, any_avoid = _node_side_facts(snapshot)
+    # ImageLocality is live where some image list of the batch scores
+    # some node above 0: a row of zeros ranks as no row does
+    w_img = float(weights.get("ImageLocality", 0))
+    image_sigs, image_scores = 0, {}
+    if any_images and w_img:
+        image_sigs, image_scores = _image_scores(pods, snapshot)
+    need_images = bool(image_scores)
+    if facts is not None:
+        facts.score_image_sigs += image_sigs
+        facts.score_image_sigs_live += len(image_scores)
     # per pod: the admission record's bits where it has them (the
     # dispatcher classified every pod at ingest), the walk where not
-    need_images = any_images and any(
-        c.image for p in pods for c in p.spec.containers
-    )
     if admissions is not None:
         need_nodeaff = any(a.node_pref for a in admissions)
         need_soft = any(a.score_soft for a in admissions)
@@ -390,9 +489,11 @@ def pack_score_batch(
         need_images or need_nodeaff or need_avoid or need_taint
         or need_soft or need_sel or need_ipa
     ):
+        metrics.score_family_batches.inc(live="false")
         return None
 
-    node_rows = nt.rows_for(infos).tolist()
+    info_rows = nt.rows_for(infos)
+    node_rows = info_rows.tolist()
     # ---- static rows ------------------------------------------------------
     sig_ids: Dict[Tuple, int] = {}
     pod_sig = np.zeros(b, dtype=np.int32)
@@ -413,58 +514,51 @@ def pack_score_batch(
     nodeaff_rows = np.zeros((u_count, n_cap), dtype=np.int32)
     taint_rows = np.zeros((u_count, n_cap), dtype=np.int32)
 
-    w_img = float(weights.get("ImageLocality", 0))
     w_avoid = float(weights.get("NodePreferAvoidPods", 0))
-    total_nodes = snapshot.num_nodes()
-    image_counts = snapshot.image_num_nodes() if need_images else {}
-
-    for u, p in enumerate(sig_pods):
-        na = (
-            p.spec.affinity.node_affinity.preferred_during_scheduling
-            if (
-                p.spec.affinity is not None
-                and p.spec.affinity.node_affinity is not None
+    if need_images:
+        for u, p in enumerate(sig_pods):
+            scored = image_scores.get(
+                tuple([c.image for c in p.spec.containers])
             )
-            else []
-        )
-        for j, ni in zip(node_rows, infos):
-            node = ni.node
-            if node is None:
-                continue
-            if need_images:
-                score_sum = 0.0
-                for c in p.spec.containers:
-                    size = ni.image_states.get(c.image)
-                    if size is None:
-                        continue
-                    spread = (
-                        image_counts.get(c.image, 0) / total_nodes
-                        if total_nodes
-                        else 0.0
+            if scored is not None:
+                positions, scores = scored
+                direct_rows[u, info_rows[positions]] = w_img * scores
+    # the families no image index serves: every node, for each signature
+    if need_avoid or need_nodeaff or need_taint:
+        for u, p in enumerate(sig_pods):
+            na = (
+                p.spec.affinity.node_affinity.preferred_during_scheduling
+                if (
+                    p.spec.affinity is not None
+                    and p.spec.affinity.node_affinity is not None
+                )
+                else []
+            )
+            for j, ni in zip(node_rows, infos):
+                node = ni.node
+                if node is None:
+                    continue
+                if need_avoid:
+                    direct_rows[u, j] += w_avoid * _avoid_score(p, node)
+                if need_nodeaff:
+                    count = 0
+                    for term in na:
+                        if term.weight and match_node_selector_term(
+                            node.metadata.labels,
+                            term.preference,
+                            {"metadata.name": node.metadata.name},
+                        ):
+                            count += term.weight
+                    nodeaff_rows[u, j] = count
+                if need_taint:
+                    taint_rows[u, j] = sum(
+                        1
+                        for t in node.spec.taints
+                        if t.effect == TAINT_EFFECT_PREFER_NO_SCHEDULE
+                        and not any(
+                            tol.tolerates(t) for tol in p.spec.tolerations
+                        )
                     )
-                    score_sum += size * spread
-                direct_rows[u, j] += w_img * ImageLocality._calculate_priority(
-                    score_sum
-                )
-            if need_avoid:
-                direct_rows[u, j] += w_avoid * _avoid_score(p, node)
-            if need_nodeaff:
-                count = 0
-                for term in na:
-                    if term.weight and match_node_selector_term(
-                        node.metadata.labels,
-                        term.preference,
-                        {"metadata.name": node.metadata.name},
-                    ):
-                        count += term.weight
-                nodeaff_rows[u, j] = count
-            if need_taint:
-                taint_rows[u, j] = sum(
-                    1
-                    for t in node.spec.taints
-                    if t.effect == TAINT_EFFECT_PREFER_NO_SCHEDULE
-                    and not any(tol.tolerates(t) for tol in p.spec.tolerations)
-                )
 
     u_padded = SIG_BUCKET * max(1, -(-u_count // SIG_BUCKET))
     direct_rows = np.concatenate(
@@ -730,6 +824,9 @@ def pack_score_batch(
         ],
         dtype=np.float32,
     )
+    metrics.score_family_batches.inc(live="true")
+    if facts is not None:
+        facts.score_live += 1
     return ScoreBatch(
         direct_rows=direct_rows,
         nodeaff_rows=nodeaff_rows,

@@ -27,7 +27,7 @@ class ImageLocality(Plugin):
         if ni is None or ni.node is None:
             return 0, Status.error(f"node {node_name} not in snapshot")
         total_nodes = snapshot.num_nodes()
-        image_counts = snapshot.image_num_nodes()
+        holders = snapshot.image_holders()
         # image spread factor: images on many nodes contribute more
         # (image_locality.go:76 scaledImageScore).
         score_sum = 0.0
@@ -35,7 +35,8 @@ class ImageLocality(Plugin):
             size = ni.image_states.get(container.image)
             if size is None:
                 continue
-            spread = image_counts.get(container.image, 0) / total_nodes if total_nodes else 0.0
+            held = holders.get(container.image)
+            spread = held.count / total_nodes if held is not None else 0.0
             score_sum += size * spread
         return self._calculate_priority(score_sum), None
 

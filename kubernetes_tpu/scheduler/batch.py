@@ -2478,7 +2478,7 @@ class BatchScheduler(Scheduler):
                         if prof0 is not None else {},
                         hard_pod_affinity_weight=hard_w,
                         cluster_affinity_scoring=cluster_ipa,
-                        admissions=adms,
+                        admissions=adms, facts=facts,
                     )
                 except ScoreEnvelopeExceeded:
                     # the sequential path filters against the host

@@ -455,6 +455,18 @@ queue_window_spent_pops = registry.register(Counter(
     "pop began. Full batches and batches cut by a high-band pod never "
     "look: they wait on no window.",
 ))
+score_family_batches = registry.register(Counter(
+    "scheduler_score_family_batches_total",
+    "Batches the score packer looked at (ops/scoring.pack_score_batch), by "
+    "live: true where a non-resource scorer could change some node's rank "
+    "for the batch, which then carries the score family's rows to the "
+    "constrained kernel; false where every such score is 0 for every node "
+    "(no preferred terms, no PreferNoSchedule taint, no avoid-pods "
+    "annotation, and no image of the batch's pods held by enough nodes at "
+    "enough bytes to pass ImageLocality's threshold). A batch past the "
+    "family's envelope goes to the host path and is not counted.",
+    ("live",),
+))
 commit_join_timeouts = registry.register(Counter(
     "scheduler_commit_thread_join_timeouts_total",
     "Committer threads that failed to join at shutdown.",

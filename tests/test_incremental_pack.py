@@ -56,7 +56,9 @@ from kubernetes_tpu.ops.host_masks import (
     static_mask_compact,
 )
 from kubernetes_tpu.ops.family_facts import FamilyFacts
+from kubernetes_tpu.framework.interface import CycleState
 from kubernetes_tpu.ops.scoring import pack_score_batch
+from kubernetes_tpu.plugins.imagelocality import ImageLocality
 from kubernetes_tpu.ops.topology import (
     MAX_CONSTRAINTS_PER_POD,
     MAX_GROUPS,
@@ -1306,6 +1308,23 @@ def test_each_write_of_a_roll_moves_the_epoch_once_and_a_status_report_never():
     node = nodes["m4"]
     assert not node.spec.unschedulable and node.spec.taints == []
     assert node.status.images[0].size_bytes == 50 << 20
+    # its image is in the snapshot's index at the place it re-joined at,
+    # and the score family stays out of the batch: the pods name
+    # ``pause``, which no node holds, and had they named the node's own
+    # image, 50 MiB on one node of nine is 5.6 MiB of ImageLocality's 23
+    nt = tc.update(snap)
+    held = snap.image_holders()
+    assert list(held) == ["registry.example/app:v2"]
+    (at,) = held["registry.example/app:v2"].positions
+    assert snap.node_info_list[at].node_name == "m4"
+    assert held["registry.example/app:v2"][2:] == (1, 50 << 20)
+    named = [make_pod("v2").container(
+        cpu="100m", image="registry.example/app:v2").obj()]
+    facts = FamilyFacts()
+    for batch in (pods, named):
+        assert pack_score_batch(
+            batch, snap, nt, None, WEIGHTS, facts=facts) is None
+    assert facts.tally()[-3:] == (0, 2, 0)  # live, image lists, live lists
 
 
 def test_a_host_port_signature_is_never_kept_and_the_rest_are_bounded():
@@ -1399,6 +1418,7 @@ def _gain(kind, w):
 
 WEIGHTS = {"ImageLocality": 1, "NodePreferAvoidPods": 10000,
            "TaintToleration": 1, "NodeAffinity": 1, "InterPodAffinity": 1}
+MIB = 1024 * 1024
 
 
 @pytest.mark.parametrize("kind", ["image", "soft_taint", "avoid"])
@@ -1430,6 +1450,208 @@ def test_score_pack_sees_what_a_node_gains(kind):
                  "weights"):
         assert np.array_equal(getattr(got, name), getattr(want, name)), name
     assert got.direct_rows.any() or got.taint_rows.any()
+    _relabel(cache, nodes, "m3", lambda w: w)  # and loses it again
+    got, want = packed()
+    assert got is None and want is None
+
+
+def test_a_moved_epoch_asks_the_changed_nodes_for_facts_that_did_not_hold(
+        monkeypatch):
+    """The node-side facts after an epoch move: one that did not hold is
+    asked of the nodes refreshed since alone, one that held of every
+    node up to the first that has it."""
+    from kubernetes_tpu.ops import scoring
+
+    asked = []
+
+    def counting(fact):
+        def ask(ni):
+            asked.append((fact.__name__, ni.node_name))
+            return fact(ni)
+        return ask
+
+    monkeypatch.setattr(scoring, "_NODE_SIDE_FACTS", tuple(
+        counting(fact) for fact in scoring._NODE_SIDE_FACTS))
+    cache, nodes = _mask_cluster()
+    snap = Snapshot()
+    tc = NodeTensorCache()
+    pods = [make_pod("s").container(cpu="100m").obj()]
+
+    def facts_after(name, change):
+        _relabel(cache, nodes, name, change)
+        cache.update_snapshot(snap)
+        del asked[:]
+        pack_score_batch(pods, snap, tc.update(snap), None, WEIGHTS)
+        by_fact = {}
+        for fact, node in asked:
+            by_fact.setdefault(fact, []).append(node)
+        return snap.score_facts[2], by_fact
+
+    every = [f"m{i}" for i in range(9)]
+    # first use: every node, for each fact
+    facts, by_fact = facts_after("m1", lambda w: w)
+    assert facts == (False, False, False)
+    assert list(by_fact.values()) == [every] * 3
+    # nothing held: the one node written is asked, and gains a taint
+    facts, by_fact = facts_after("m3", lambda w: _gain("soft_taint", w))
+    assert facts == (False, True, False)
+    assert list(by_fact.values()) == [["m3"]] * 3
+    # the taint held: looked for from the first node to the one that has
+    # it; the other two ask the node written
+    facts, by_fact = facts_after("m5", lambda w: w.labels(tier="b"))
+    assert facts == (False, True, False)
+    assert by_fact == {"_holds_image": ["m5"],
+                       "_soft_tainted": every[:4],
+                       "_asks_to_be_avoided": ["m5"]}
+    # and is lost: every node says no
+    facts, by_fact = facts_after("m3", lambda w: w)
+    assert facts == (False, False, False)
+    assert by_fact["_soft_tainted"] == every
+    # a status report moves no epoch and asks nobody
+    _roll_write("status_report", cache, nodes, "m2")
+    cache.update_snapshot(snap)
+    del asked[:]
+    pack_score_batch(pods, snap, tc.update(snap), None, WEIGHTS)
+    assert asked == []
+    # a node that joins is among the changed
+    cache.add_node(_node("m-new", "z1").image("pause", 900 * MIB).obj())
+    cache.update_snapshot(snap)
+    del asked[:]
+    assert pack_score_batch(
+        pods, snap, tc.update(snap), None, WEIGHTS) is not None
+    assert snap.score_facts[2] == (True, False, False)
+    # (with the node whose status report a refresh cloned)
+    assert {"m-new"} <= {node for _fact, node in asked} <= {"m-new", "m2"}
+
+
+def _image_cluster():
+    cache, nodes = _mask_cluster()
+    snap = Snapshot()
+    tc = NodeTensorCache()
+    pods = [make_pod(f"s{i}").container(cpu="100m").obj() for i in range(5)]
+    pods.append(make_pod("two").container(cpu="100m").container(
+        cpu="100m", image="sidecar").obj())
+
+    def packed():
+        """The pack of the kept snapshot, checked against one of a
+        snapshot no cache feeds and against the host plugin."""
+        cache.update_snapshot(snap)
+        nt = tc.update(snap)
+        got = pack_score_batch(pods, snap, nt, None, WEIGHTS)
+        foreign = new_snapshot([], [ni.node for ni in snap.node_info_list])
+        fresh = NodeTensorCache().update(foreign)
+        want = pack_score_batch(pods, foreign, fresh, None, WEIGHTS)
+        assert (got is None) == (want is None)
+        state = CycleState()
+        state.write("__snapshot__", snap)
+        plugin = ImageLocality()
+        rows = nt.rows_for(snap.node_info_list)
+        for i, p in enumerate(pods):
+            scores = [plugin.score(state, p, ni.node_name)[0]
+                      for ni in snap.node_info_list]
+            if got is None:
+                assert not any(scores)
+            else:
+                assert got.direct_rows[got.pod_sig[i]][rows].tolist() == scores
+                assert np.array_equal(
+                    got.direct_rows[:, rows],
+                    want.direct_rows[:, fresh.rows_for(foreign.node_info_list)])
+        # the index holds each image at the places its holders stand at
+        index = snap.image_holders()
+        assert {
+            image: [(snap.node_info_list[i].node_name, int(size))
+                    for i, size in zip(held.positions, held.sizes)]
+            for image, held in index.items()
+        } == {
+            image: [(ni.node_name, ni.image_states[image])
+                    for ni in snap.node_info_list if image in ni.image_states]
+            for ni in snap.node_info_list for image in ni.image_states
+        }
+        for held in index.values():
+            assert held.count == len(held.positions)
+            assert held.largest == held.sizes.max()
+        return got
+
+    return cache, nodes, snap, tc, packed
+
+
+def test_the_image_index_follows_an_image_gained_and_lost():
+    cache, nodes, snap, tc, packed = _image_cluster()
+    assert packed() is None and snap.image_holders() == {}
+    index = snap.image_holders()
+    assert snap.image_holders() is index  # kept while the epoch stands
+    _relabel(cache, nodes, "m3", lambda w: w.image("pause", 500 * MIB))
+    got = packed()  # 500 MiB on one node of nine: 55.6 MiB, a score of 3
+    assert snap.image_holders() is not index
+    assert got is not None and got.direct_rows.max() == 3.0
+    assert np.count_nonzero(got.direct_rows) == 2  # m3, for both lists
+    _relabel(cache, nodes, "m3", lambda w: w)  # the kubelet collected it
+    assert packed() is None and snap.image_holders() == {}
+
+
+def test_an_image_crosses_the_threshold_as_more_nodes_report_it():
+    """60 MiB on k of nine nodes is 6.7 k MiB a holder: ImageLocality's
+    first point lies at 23 MiB + 1 % of 977, which the fifth holder
+    passes. Under it no row; over it one, at every holder."""
+    cache, nodes, snap, tc, packed = _image_cluster()
+    for k in range(1, 8):
+        _relabel(cache, nodes, f"m{k}", lambda w: w.image("pause", 60 * MIB))
+        got = packed()
+        assert snap.image_holders()["pause"].count == k
+        if k < 5:
+            assert got is None, k
+        else:
+            assert np.count_nonzero(got.direct_rows[got.pod_sig[0]]) == k
+    facts = FamilyFacts()
+    assert pack_score_batch(
+        [make_pod("s").container(cpu="100m").obj()], snap, tc.update(snap),
+        None, WEIGHTS, facts=facts) is not None
+    assert facts.tally()[-3:] == (1, 1, 1)
+
+
+def test_a_node_that_rejoins_under_its_name_keeps_no_place_in_the_index():
+    cache, nodes, snap, tc, packed = _image_cluster()
+    for name in ("m2", "m3", "m6"):
+        _relabel(cache, nodes, name, lambda w: w.image("pause", 900 * MIB))
+    packed()
+    at = [ni.node_name for ni in snap.node_info_list].index("m3")
+    assert at in snap.image_holders()["pause"].positions
+    cache.remove_node(nodes.pop("m3"))
+    got = packed()  # every place after m3's moved up by one
+    assert snap.image_holders()["pause"].count == 2
+    assert snap.num_nodes() == 8 and got is not None
+    nodes["m3"] = _node("m3", "z0").obj()  # joins again, with no image yet
+    cache.add_node(nodes["m3"])
+    got = packed()
+    back = [ni.node_name for ni in snap.node_info_list].index("m3")
+    assert back not in snap.image_holders()["pause"].positions
+    assert got.direct_rows[got.pod_sig[0]][tc.update(snap).row("m3")] == 0.0
+    _relabel(cache, nodes, "m3", lambda w: w.image("pause", 900 * MIB))
+    packed()
+    assert back in snap.image_holders()["pause"].positions
+
+
+def test_a_snapshot_no_cache_feeds_builds_its_image_index_at_every_call():
+    nodes = [_node(f"f{i}", "z0").image("pause", 800 * MIB).obj()
+             for i in range(3)]
+    foreign = new_snapshot([], nodes)
+    assert foreign.node_spec_epoch == 0
+    first = foreign.image_holders()
+    assert first["pause"].count == 3
+    # edited where it stands, which an epoch-0 snapshot cannot be told
+    del foreign.node_info_list[0].image_states["pause"]
+    foreign.node_info_list[1].image_states["other"] = 7
+    again = foreign.image_holders()
+    assert again is not first and again["pause"].count == 2
+    assert again["pause"].positions.tolist() == [1, 2]
+    assert again["other"][2:] == (1, 7)
+    # the host plugin reads the same store: two of three nodes hold it now
+    state = CycleState()
+    state.write("__snapshot__", foreign)
+    pod = make_pod("p").container(cpu="100m").obj()
+    want = ImageLocality._calculate_priority(800 * MIB * (2 / 3))
+    assert [ImageLocality().score(state, pod, f"f{i}")[0]
+            for i in range(3)] == [0, want, want]
 
 
 # -- 5. the arrays handed to solve_packed ---------------------------------------

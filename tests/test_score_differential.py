@@ -13,14 +13,22 @@ score-all) is the oracle; the batch path must place identically.
 import random
 import time
 
+import numpy as np
 import pytest
 
 from kubernetes_tpu.api.types import ObjectMeta, Service
 from kubernetes_tpu.apiserver.server import APIServer
+from kubernetes_tpu.cache.cache import SchedulerCache
+from kubernetes_tpu.cache.snapshot import Snapshot
 from kubernetes_tpu.client.client import Client
 from kubernetes_tpu.client.informer import InformerFactory
+from kubernetes_tpu.framework.interface import CycleState
+from kubernetes_tpu.ops.scoring import pack_score_batch
+from kubernetes_tpu.plugins.imagelocality import ImageLocality
 from kubernetes_tpu.scheduler.scheduler import new_scheduler
+from kubernetes_tpu.tensors.node_tensor import NodeTensorCache
 from kubernetes_tpu.testing import make_node, make_pod
+from kubernetes_tpu.utils import metrics
 
 
 class _KeepFirstRng:
@@ -229,3 +237,155 @@ def test_spread_with_node_selector_batch_matches_sequential(seed):
     assert got_batch == got_seq
     # the coupling solves ON DEVICE now (no solver_supported carve-out)
     assert fallback == 0
+
+
+# -- ImageLocality from the snapshot's image index ---------------------------
+#
+# ``pack_score_batch`` decides whether ImageLocality is live, and where it
+# is builds its rows, from ``Snapshot.image_holders`` (the image's side);
+# the host plugin walks the pod's containers on one node (the node's
+# side). Entry for entry the two are the same number.
+
+MIB = 1024 * 1024
+IMAGES = ["registry/a:1", "registry/b:2", "registry/c:3", "registry/d:4"]
+GHOST = "registry/ghost:0"  # an image no node holds
+#: the share of nodes that hold each image (None: exactly one node) and
+#: the most MiB it takes there
+HOLDING = {"none": (0.0, 2048), "one_node": (None, 2048),
+           "one_small": (None, 200), "third": (1 / 3, 2048),
+           "most": (0.85, 2048)}
+
+
+def _image_nodes(rng, holding, count):
+    """``count`` nodes of distinct shapes; each image on the share of
+    them that ``holding`` says, at a size of its own on every node, from
+    10 MiB up."""
+    nodes = [
+        make_node(f"n{i}")
+        .labels(zone=f"z{i % 3}")
+        .capacity(cpu=str(8 + i % 23), memory=f"{16 + (i * 7) % 41}Gi",
+                  pods=110)
+        for i in range(count)
+    ]
+    share, largest = HOLDING[holding]
+    for image in IMAGES:
+        lucky = rng.randrange(count)
+        for i, w in enumerate(nodes):
+            holds = i == lucky if share is None else rng.random() < share
+            if holds:
+                w.image(image, rng.randint(10 * MIB, largest * MIB))
+    return [w.obj() for w in nodes]
+
+
+def _image_pods(rng, count=16):
+    """Pods of one to three containers: an image repeated, an image no
+    node holds, a container with no image, and plain mixes."""
+    out = []
+    for i in range(count):
+        roll = i % 6
+        if roll == 0:
+            images = [rng.choice(IMAGES)] * 2  # repeated
+        elif roll == 1:
+            images = [GHOST]
+        elif roll == 2:
+            images = [rng.choice(IMAGES), GHOST, ""]
+        else:
+            images = rng.sample(IMAGES, rng.randint(1, 3))
+        w = make_pod(f"m{i}").creation_timestamp(float(i))
+        for image in images:
+            w.container(cpu=f"{rng.choice([100, 300, 700])}m",
+                        memory=f"{rng.choice([128, 384])}Mi", image=image)
+        out.append(w.obj())
+    return out
+
+
+@pytest.mark.parametrize("weight", [1, 3])
+@pytest.mark.parametrize("holding", sorted(HOLDING))
+@pytest.mark.parametrize("seed", [5, 11, 42])
+def test_image_rows_equal_the_host_plugins_scores(seed, holding, weight):
+    rng = random.Random(1000 * seed + len(holding))
+    cache = SchedulerCache()
+    for node in _image_nodes(rng, holding, rng.randint(40, 200)):
+        cache.add_node(node)
+    snap = cache.update_snapshot(Snapshot())
+    nt = NodeTensorCache().update(snap)
+    pods = _image_pods(rng)
+    got = pack_score_batch(pods, snap, nt, None, {"ImageLocality": weight})
+
+    state = CycleState()
+    state.write("__snapshot__", snap)
+    plugin = ImageLocality()
+    infos = snap.list_node_infos()
+    want = np.array([
+        [plugin.score(state, p, ni.node.metadata.name)[0] for ni in infos]
+        for p in pods
+    ])
+    # one holder in 40 or more of 200 MiB at most is 5 MiB an image: under
+    # the plugin's 23 MiB whatever the list; 2 GiB on a third of the nodes
+    # is over; one holder of 2 GiB falls on either side
+    if holding != "one_node":
+        assert want.any() == (holding in ("third", "most"))
+    if not want.any():
+        assert got is None  # every score 0 and no other family: no rows
+        return
+    assert got is not None
+    rows = nt.rows_for(infos)
+    for i, p in enumerate(pods):
+        row = got.direct_rows[got.pod_sig[i]]
+        assert np.array_equal(row[rows], weight * want[i].astype(np.float32)), (
+            p.metadata.name
+        )
+        rest = np.ones(row.shape[0], dtype=bool)
+        rest[rows] = False
+        assert not row[rest].any()  # slots no node fills
+    assert not got.nodeaff_rows.any() and not got.taint_rows.any()
+    assert not got.dynamic
+
+
+def _run_images(seed, holding, batch):
+    rng = random.Random(seed)
+    server = APIServer()
+    client = Client(server)
+    informers = InformerFactory(server)
+    sched = new_scheduler(
+        client, informers, batch=batch, max_batch=64,
+        percentage_of_nodes_to_score=100, rng=_KeepFirstRng(),
+    )
+    for node in _image_nodes(rng, holding, 48):
+        client.create_node(node)
+    informers.start()
+    informers.wait_for_cache_sync()
+    sched.queue.run()
+    for p in _image_pods(rng):
+        client.create_pod(p)
+    sched.start()
+    pods = _wait_decided(client, sched, 16)
+    sched.stop()
+    informers.stop()
+    placed = {p.metadata.name: p.spec.node_name for p in pods}
+    assert all(placed.values())
+    if not batch:
+        return placed, None
+    assert sched.pods_fallback == 0
+    return placed, sched.family_facts.score_live
+
+
+@pytest.mark.parametrize("holding,live", [("one_small", False), ("most", True)])
+@pytest.mark.parametrize("seed", [3, 29])
+def test_image_batches_place_as_the_host_oracle_does(seed, holding, live):
+    """Both regimes through ``BatchScheduler``: where no image list of
+    the batch can score a node above 0 the batch takes the basic layout
+    (``score_live`` never counted), where one can it carries the rows;
+    either way the placements are the sequential path's."""
+    counted = {
+        flag: metrics.score_family_batches.value(live=flag)
+        for flag in ("true", "false")
+    }
+    batch, score_live = _run_images(seed, holding, batch=True)
+    flag = "true" if live else "false"
+    other = "false" if live else "true"
+    assert metrics.score_family_batches.value(live=flag) > counted[flag]
+    assert metrics.score_family_batches.value(live=other) == counted[other]
+    assert bool(score_live) == live
+    sequential, _ = _run_images(seed, holding, batch=False)
+    assert batch == sequential

@@ -1189,6 +1189,79 @@ def test_pack_says_whether_the_node_epoch_moved_and_the_solve_its_member_rows(
     assert sched.membership_row_patches == 2
 
 
+@pytest.mark.parametrize("image,live", [
+    ("pause", 0),  # no node holds it
+    ("registry.example/app:v2", 1),  # seven of the eight nodes do
+], ids=["an_image_no_node_holds", "an_image_most_nodes_hold"])
+def test_pack_families_says_whether_the_score_family_is_live(
+        tmp_path, monkeypatch, image, live):
+    """A rolled cluster at rehearsal size: seven of eight nodes have
+    reported the image their kubelet pulled, 50 MiB. Pods that name
+    another image find every ImageLocality score 0: ``score_live`` 0 on
+    every ``sched/pack.families`` span, the basic layout, and the
+    always-on counter counts ``live="false"``. Pods that name that image
+    (43.75 MiB a holder: a score of 2) make the family live:
+    ``score_live`` 1, every image list of the batch a live one, the
+    constrained layout."""
+    from kubernetes_tpu.api.types import ContainerImage
+    from kubernetes_tpu.scheduler import batch as batch_module
+
+    server, client, informers, sched = _stack(num_nodes=8)
+    sched.queue.run()
+    modes = []
+    solve_packed = batch_module.solve_packed
+
+    def spy(*args, **kwargs):
+        modes.append(kwargs["mode"])
+        return solve_packed(*args, **kwargs)
+
+    monkeypatch.setattr(batch_module, "solve_packed", spy)
+
+    def report_image(node):
+        node.status.images = [ContainerImage(
+            names=["registry.example/app:v2"], size_bytes=50 << 20)]
+
+    for i in range(7):
+        server.guaranteed_update("Node", "", f"node-{i}", report_image)
+    _wait_for(lambda: sched.stage_totals.calls()["node_event"] == 15,
+              "the image reports were not seen")
+    counted = {flag: metrics.score_family_batches.value(live=flag)
+               for flag in ("true", "false")}
+
+    def batch(tag, lists):
+        pods = []
+        for i, images in enumerate(lists):
+            w = make_pod(f"{tag}-{i}")
+            for name in images:
+                w.container(cpu="10m", memory="16Mi", image=name)
+            pods.append(w.obj())
+        client.create_pods_bulk(pods)
+        _wait_for(lambda: len(sched.queue.pending_pods()) == len(pods),
+                  "the pods never queued")
+        assert sched.schedule_batch(timeout=1.0) == len(pods)
+        sched.wait_for_inflight_binds()
+
+    try:
+        with profiled(tmp_path) as events:
+            batch("a", [[image]] * 6)
+            batch("b", [[image]] * 3 + [[image, "sidecar"]] * 3)
+            sched._drain_pending()
+    finally:
+        sched.stop()
+        informers.stop()
+    assert sched.pods_fallback == 0
+    first, second = sorted(named(events, "sched/pack.families"),
+                           key=lambda ev: ev["start"])
+    for ev, lists in ((first, 1), (second, 2)):
+        assert ev["stats"]["score_live"] == live
+        assert ev["stats"]["score_image_sigs"] == lists
+        assert ev["stats"]["score_image_sigs_live"] == lists * live
+    assert modes == ["constrained" if live else "greedy"] * 2
+    flag, other = ("true", "false") if live else ("false", "true")
+    assert metrics.score_family_batches.value(live=flag) == counted[flag] + 2
+    assert metrics.score_family_batches.value(live=other) == counted[other]
+
+
 def test_the_wait_for_mirrors_has_a_span_of_its_own(tmp_path):
     """A membership change with a batch in flight: the dispatcher waits,
     outside ``sched/pack``, until the batch has mirrored
