@@ -649,6 +649,9 @@ def run_workload(wl: Dict[str, Any], defaults: Dict[str, Any]) -> Dict[str, Any]
     server = APIServer()
     client = Client(server)
     informers = InformerFactory(server)
+    # a row's ``solver:`` overrides the profile's own resource score rule
+    # (the profile decides it on the operator's path; these rows build no
+    # profile, so they say the weights themselves)
     solver_cfg = GreedyConfig(**wl["solver"]) if wl.get("solver") else None
     # workload-scoped node-axis mesh (the sharded delta path). A row
     # that asks for more devices than this process has still runs, on a

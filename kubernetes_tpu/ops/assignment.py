@@ -76,19 +76,67 @@ def _fits(free: jnp.ndarray, pod_req: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(all_zero, dim_ok[:, _PODS_COL], dim_ok.all(axis=-1))
 
 
+#: the profile's score plugins that the device models, by the field of
+#: ``GreedyConfig`` that carries each one's weight
+RESOURCE_SCORE_PLUGINS = {
+    "NodeResourcesLeastAllocated": "least_allocated_weight",
+    "NodeResourcesBalancedAllocation": "balanced_allocation_weight",
+    "NodeResourcesMostAllocated": "most_allocated_weight",
+}
+#: resource scorers no device tier models: the pods of a profile that
+#: scores with one keep the host path (scheduler/batch.py), so that
+#: they are never scored as something else
+UNMODELLED_RESOURCE_SCORE_PLUGINS = (
+    "RequestedToCapacityRatio", "NodeResourceLimits",
+)
+
+
 @dataclass(frozen=True)
 class GreedyConfig:
-    """Device resource-scorer weights (LeastAllocated/BalancedAllocation
-    at the default provider's weight 1, MostAllocated for bin-packing
-    profiles). The label-dependent scorers (ImageLocality, preferred
-    NodeAffinity, TaintToleration PreferNoSchedule, SelectorSpread, soft
-    spread, NodePreferAvoidPods) ride the ``scoring`` tensors of
-    greedy_assign_constrained (ops/scoring.py) with the profile's own
-    weights."""
+    """Device resource-scorer weights, the same in every tier (Pallas,
+    the XLA scan, the mesh, ``host_greedy``). They come from the pod's
+    PROFILE: ``from_score_weights`` reads the weights of the profile's
+    enabled ``NodeResourcesLeastAllocated``, ``...BalancedAllocation``
+    and ``...MostAllocated`` (``plugins.score`` of the
+    KubeSchedulerConfiguration), and ``BatchScheduler`` asks it of each
+    profile, so the default provider gives least 1, balanced 1, most 0
+    and a bin-packing profile most 1 alone. A driver that passes
+    ``new_scheduler(solver_config=...)`` overrides every profile
+    (``benchmarks/runner.py``'s ``solver:`` rows). The label-dependent
+    scorers (ImageLocality, preferred NodeAffinity, TaintToleration
+    PreferNoSchedule, SelectorSpread, soft spread, NodePreferAvoidPods)
+    ride the ``scoring`` tensors of greedy_assign_constrained
+    (ops/scoring.py) with the profile's own weights."""
 
     least_allocated_weight: int = 1
     balanced_allocation_weight: int = 1
     most_allocated_weight: int = 0
+
+    @classmethod
+    def from_score_weights(cls, weights) -> Optional["GreedyConfig"]:
+        """The config of a profile whose enabled score plugins and
+        weights are ``weights`` (``Framework.score_plugin_weights``);
+        None where it enables a resource scorer the device does not
+        model."""
+        if any(weights.get(name) for name in UNMODELLED_RESOURCE_SCORE_PLUGINS):
+            return None
+        return cls(**{
+            field: int(weights.get(name, 0))
+            for name, field in RESOURCE_SCORE_PLUGINS.items()
+        })
+
+    def label(self) -> str:
+        """The rule in a few letters, for a metric's label:
+        ``least+balanced``, ``most``, ``leastx2+balanced``, ``none``."""
+        parts = [
+            name if weight == 1 else f"{name}x{weight}"
+            for name, weight in (
+                ("least", self.least_allocated_weight),
+                ("balanced", self.balanced_allocation_weight),
+                ("most", self.most_allocated_weight),
+            ) if weight
+        ]
+        return "+".join(parts) or "none"
 
 
 def _combined_score(caps, nzr_state, p_nzr, config) -> jnp.ndarray:

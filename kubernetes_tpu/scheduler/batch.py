@@ -8,6 +8,17 @@ the NodeInfo snapshot becomes an incrementally-updated NodeTensor, the
 Filter/Score plugins become the device mask/score matrices + host static
 mask, and selectHost becomes the argmax inside the assignment scan.
 
+The score weights are the PROFILE's, on the device as on the host: a
+batch is one profile's pods, its resource score rule (``GreedyConfig``)
+is that profile's enabled NodeResourcesLeastAllocated / BalancedAllocation
+/ MostAllocated and their weights in every tier
+(``BatchScheduler.solver_config``), and its label scorers take the same
+profile's weights (``prof0.score_plugin_weights()``). A profile that
+scores with a resource scorer no tier models (RequestedToCapacityRatio,
+NodeResourceLimits) sends its pods down the host path, counted in
+``pods_fallback``. Only a driver that passes ``solver_config`` overrides
+the profiles.
+
 The scheduling-framework contract stays intact: Reserve, Permit
 (gang-scheduling hook), PreBind, Bind and the failure/Unreserve paths run
 through the same Framework pipeline per pod (finish_schedule). Required
@@ -54,6 +65,7 @@ from kubernetes_tpu.framework.interface import (
 from kubernetes_tpu.ops.assignment import (
     GreedyConfig,
     NO_NODE,
+    UNMODELLED_RESOURCE_SCORE_PLUGINS,
     apply_assignment_delta,
     greedy_assign_compact,
     mesh_shard_uses_kernel,
@@ -504,7 +516,7 @@ class BatchScheduler(Scheduler):
         self,
         *args,
         max_batch: int = 256,
-        solver_config: GreedyConfig = GreedyConfig(),
+        solver_config: Optional[GreedyConfig] = None,
         tensor_cache: Optional[NodeTensorCache] = None,
         batch_window: float = 0.01,
         solver_mode: str = "greedy",
@@ -525,7 +537,16 @@ class BatchScheduler(Scheduler):
         section 2.5)."""
         super().__init__(*args, **kwargs)
         self.max_batch = max_batch
-        self.solver_config = solver_config
+        self.solver_config = solver_config  # the property below
+        for name in self._host_scored_profiles:
+            logger.warning(
+                "profile %r enables a resource scorer the device does not "
+                "model (%s): its pods are scheduled on the host path",
+                name, ", ".join(
+                    p for p in UNMODELLED_RESOURCE_SCORE_PLUGINS
+                    if self.profiles[name].score_plugin_weights().get(p)
+                ),
+            )
         self.tensor_cache = tensor_cache or NodeTensorCache()
         # static mask rows kept from batch to batch (ops/host_masks.py)
         self.mask_row_cache = MaskRowCache()
@@ -701,6 +722,42 @@ class BatchScheduler(Scheduler):
         self.speculative_launches = 0
         self.speculative_rewinds = 0
 
+    # -- the resource score rule ----------------------------------------------
+
+    @property
+    def solver_config(self) -> GreedyConfig:
+        """The resource score rule of the batches: the driver's override
+        where one was passed or set, else the first profile's own."""
+        if self._solver_override is not None:
+            return self._solver_override
+        return self.solver_configs()[0]
+
+    @solver_config.setter
+    def solver_config(self, override: Optional[GreedyConfig]) -> None:
+        """The rule of each profile's batches, the same in every tier:
+        ``override`` for all of them, or with None each profile's own
+        enabled resource scorers and weights. A profile that scores with
+        a resource scorer no tier models (RequestedToCapacityRatio,
+        NodeResourceLimits) has no rule: its pods keep the host path,
+        counted in ``pods_fallback``, where the framework's own plugins
+        score them."""
+        self._solver_override = override
+        self._profile_rules = {
+            name: override
+            or GreedyConfig.from_score_weights(fw.score_plugin_weights())
+            for name, fw in self.profiles.items()
+        }
+        self._host_scored_profiles = frozenset(
+            name for name, rule in self._profile_rules.items()
+            if rule is None
+        )
+
+    def solver_configs(self) -> List[GreedyConfig]:
+        """The distinct rules this scheduler's batches are solved
+        under (warm-up compiles each)."""
+        rules = [r for r in self._profile_rules.values() if r is not None]
+        return list(dict.fromkeys(rules)) or [GreedyConfig()]
+
     # -- one batch ----------------------------------------------------------
 
     def schedule_batch(
@@ -786,6 +843,7 @@ class BatchScheduler(Scheduler):
         profiling = self.profile_stages
         inj = get_injector()
         quota_gate = self.quota
+        host_scored = self._host_scored_profiles  # empty but for RTCR
         for pi in batch_infos:
             if self._skip_pod_schedule(pi.pod):
                 continue
@@ -811,8 +869,12 @@ class BatchScheduler(Scheduler):
                 )
             else:
                 adm = self._admission_of(pi.pod)
-            if adm.device_ok:
-                # one profile per solver batch: score weights and owner
+            if adm.device_ok and not (
+                host_scored
+                and pi.pod.spec.scheduler_name in host_scored
+            ):
+                # one profile per solver batch: score weights (the
+                # resource scorers' too, ``_profile_rules``) and owner
                 # lookups are profile-scoped (the sequential path resolves
                 # them per pod, scheduler.go:741)
                 if solver_infos and (
@@ -2113,6 +2175,11 @@ class BatchScheduler(Scheduler):
             has_affinity = has_affinity_terms or batch_ports
             has_required_anti = any(a.required_anti for a in adms)
             prof0 = self.profiles.get(pods[0].spec.scheduler_name)
+            # the batch's resource score rule is its profile's
+            config = (
+                self._profile_rules.get(pods[0].spec.scheduler_name)
+                or self.solver_config
+            )
             # gated on the profile actually scoring with InterPodAffinity --
             # otherwise the ipa family packs nothing and draining for it
             # would serialize the pipeline for free
@@ -2300,11 +2367,12 @@ class BatchScheduler(Scheduler):
                 state.set_metadata(rows=int(nt.delta.changed_rows.size))
             with flightrecorder.stage(
                 "pack.pods", totals=totals, batch=batch_id
-            ):
+            ) as packed_pods:
                 batch = pack_pod_batch(
                     pods, nt.dims,
                     timestamps=[pi.timestamp for pi in solver_infos],
                 )
+                packed_pods.set_metadata(templates=batch.templates)
             with flightrecorder.stage(
                 "pack.masks", totals=totals, batch=batch_id
             ) as masks:
@@ -2634,6 +2702,11 @@ class BatchScheduler(Scheduler):
             # the pods of the batch: the steps a one-chip kernel runs of
             # the ``padded`` that ``sched/dispatch`` says
             "steps": int(b),
+            # the call's resource columns (four fixed, one an extended
+            # resource the nodes advertise) and whether its profile
+            # scores MostAllocated
+            "r_dims": int(nt.dims.num_dims),
+            "score_most": int(bool(config.most_allocated_weight)),
         }
         # single-buffer upload: the whole batch -- including a
         # constrained batch's ~40 family count tensors -- rides ONE
@@ -2737,7 +2810,7 @@ class BatchScheduler(Scheduler):
                 ds.valid_dev if static_ok else None,
                 ds.req_dev if carry_ok else None,
                 ds.nzr_dev if carry_ok else None,
-                config=self.solver_config,
+                config=config,
                 mode=solve_mode,
                 allow_pallas=allow_pallas,
                 mesh=self.mesh,
@@ -2752,7 +2825,7 @@ class BatchScheduler(Scheduler):
             a, r_out, z_out = host_greedy_assign(
                 nt.allocatable, node_requested, node_nzr, nt.valid,
                 req, nzr, rows, midx, active,
-                config=self.solver_config,
+                config=config,
             )
             return a, r_out, z_out, None, None
 
@@ -2789,6 +2862,7 @@ class BatchScheduler(Scheduler):
                 booked = self.ladder.booked_tier(tier)
                 solving.set_metadata(tier=booked)
             self._jit_watch.refresh()
+            metrics.solves_by_resource_score.inc(score=config.label())
         except LadderExhausted as exhaust_err:
             with self._shadow_lock:
                 ds.invalidate_carry()
@@ -4637,8 +4711,13 @@ class BatchScheduler(Scheduler):
             int(p) for p in self._warmup_pads
             if p and int(p) != self.max_batch
         )
-        for padded in [self.max_batch] + extra:
-            self._warmup_at(nt, padded, full=padded == self.max_batch)
+        # every profile's resource score rule is a program of its own
+        for config in self.solver_configs():
+            for padded in [self.max_batch] + extra:
+                self._warmup_at(
+                    nt, padded, full=padded == self.max_batch,
+                    config=config,
+                )
         # seal the jit-cache watchdog: every signature compiled from
         # here on is a mid-run recompile (counted AND flight-recorded)
         self._jit_watch.seal()
@@ -4652,11 +4731,13 @@ class BatchScheduler(Scheduler):
             # above, so a rung switch never pays JIT mid-run
             self.autobatch.calibrate(dict(self.pad_solve_seconds))
 
-    def _warmup_at(self, nt, padded: int, full: bool) -> None:
+    def _warmup_at(
+        self, nt, padded: int, full: bool, config: GreedyConfig
+    ) -> None:
         n = nt.capacity
         r = nt.dims.num_dims
         if self.mesh is not None:
-            self._warmup_mesh_packed(nt, padded, full)
+            self._warmup_mesh_packed(nt, padded, full, config)
             return
         common = jax.device_put((
             nt.allocatable, nt.requested, nt.non_zero_requested, nt.valid,
@@ -4667,9 +4748,9 @@ class BatchScheduler(Scheduler):
             np.zeros(padded, dtype=bool),
         ))
         if self.solver_mode == "sinkhorn":
-            out = sinkhorn_assign(*common, config=self.solver_config)
+            out = sinkhorn_assign(*common, config=config)
             jax.block_until_ready(out)
-        out = greedy_assign_compact(*common, config=self.solver_config)
+        out = greedy_assign_compact(*common, config=config)
         jax.block_until_ready(out)
         # compile every packed-upload layout the run loop can hit:
         # cold (static+carry ride the buffer), carry-refresh, and
@@ -4695,19 +4776,19 @@ class BatchScheduler(Scheduler):
         delta_slots = _delta_slot_pieces(n, r)
         cold = solve_packed(
             base + static_pieces + carry_pieces, None, None, None, None,
-            config=self.solver_config, mode=self.solver_mode,
+            config=config, mode=self.solver_mode,
         )
         jax.block_until_ready(cold)
         _, _, _, alloc_d, valid_d = cold
         refresh = solve_packed(
             base + carry_pieces, alloc_d, valid_d, None, None,
-            config=self.solver_config, mode=self.solver_mode,
+            config=config, mode=self.solver_mode,
         )
         jax.block_until_ready(refresh)
         _, req_d, nzr_d, _, _ = refresh
         steady = solve_packed(
             base + delta_slots, alloc_d, valid_d, req_d, nzr_d,
-            config=self.solver_config, mode=self.solver_mode,
+            config=config, mode=self.solver_mode,
         )
         jax.block_until_ready(steady)
         # measured per-pad solve cost (post-compile): feeds the
@@ -4721,7 +4802,7 @@ class BatchScheduler(Scheduler):
             t0 = time.perf_counter()
             jax.block_until_ready(solve_packed(
                 base + delta_slots, alloc_d, valid_d, req_d, nzr_d,
-                config=self.solver_config, mode=self.solver_mode,
+                config=config, mode=self.solver_mode,
             ))
             samples.append(time.perf_counter() - t0)
         self.pad_solve_seconds[padded] = sorted(samples)[1]
@@ -4747,17 +4828,17 @@ class BatchScheduler(Scheduler):
         c_cold = solve_packed(
             base + static_pieces + carry_pieces + fam,
             None, None, None, None,
-            config=self.solver_config, mode="constrained",
+            config=config, mode="constrained",
         )
         jax.block_until_ready(c_cold)
         c_refresh = solve_packed(
             base + carry_pieces + fam, alloc_d, valid_d, None, None,
-            config=self.solver_config, mode="constrained",
+            config=config, mode="constrained",
         )
         jax.block_until_ready(c_refresh)
         c_steady = solve_packed(
             base + delta_slots + fam, alloc_d, valid_d, req_d, nzr_d,
-            config=self.solver_config, mode="constrained",
+            config=config, mode="constrained",
         )
         jax.block_until_ready(c_steady)
         # family-combo layouts: warm the steady-carry variant of
@@ -4768,12 +4849,14 @@ class BatchScheduler(Scheduler):
             out_one = solve_packed(
                 base + delta_slots + _family_pieces(fam_groups, live),
                 alloc_d, valid_d, req_d, nzr_d,
-                config=self.solver_config, mode="constrained",
+                config=config, mode="constrained",
             )
             jax.block_until_ready(out_one)
-        self._pallas_canary(nt, padded, fam_groups)
+        self._pallas_canary(nt, padded, fam_groups, config)
 
-    def _pallas_canary(self, nt, padded: int, fam_groups: dict) -> None:
+    def _pallas_canary(
+        self, nt, padded: int, fam_groups: dict, config: GreedyConfig
+    ) -> None:
         """Hold every Pallas specialization warm-up just compiled to the
         XLA scan, on a seeded non-trivial problem, before the run loop
         trusts it. That a kernel compiles does not make it right: on the
@@ -4818,7 +4901,7 @@ class BatchScheduler(Scheduler):
         active = np.ones(padded, dtype=bool)
         reference = np.asarray(greedy_assign_compact(
             nt.allocatable, requested, nzr_state, nt.valid,
-            req, pod_nzr, rows, midx, active, config=self.solver_config,
+            req, pod_nzr, rows, midx, active, config=config,
         )[0])
         state = jax.device_put(
             (nt.allocatable, nt.valid, requested, nzr_state)
@@ -4832,7 +4915,7 @@ class BatchScheduler(Scheduler):
         def agrees(mode: str, fam: list) -> bool:
             out = solve_packed(
                 pieces + fam, *state,
-                config=self.solver_config, mode=mode,
+                config=config, mode=mode,
             )
             return np.array_equal(np.asarray(out[0]), reference)
 
@@ -4865,10 +4948,12 @@ class BatchScheduler(Scheduler):
             for fam in probes.values():
                 jax.block_until_ready(solve_packed(
                     pieces + fam, *state,
-                    config=self.solver_config, mode=mode,
+                    config=config, mode=mode,
                 ))
 
-    def _warmup_mesh_packed(self, nt, padded: int, full: bool) -> None:
+    def _warmup_mesh_packed(
+        self, nt, padded: int, full: bool, config: GreedyConfig
+    ) -> None:
         """Sharded-twin warmup: compile every packed-upload layout the
         MESH run loop can hit -- cold (static+carry ride the replicated
         buffer, resharded once on device), carry-refresh, and
@@ -4913,7 +4998,7 @@ class BatchScheduler(Scheduler):
         alloc_d = valid_d = req_d = nzr_d = None
         for allow_pallas in tiers:
             kw = dict(
-                config=self.solver_config, mode=self.solver_mode,
+                config=config, mode=self.solver_mode,
                 mesh=self.mesh, allow_pallas=allow_pallas,
             )
             cold = solve_packed(
@@ -4960,7 +5045,7 @@ class BatchScheduler(Scheduler):
             + [(f"sc{i}", np.asarray(a)) for i, a in enumerate(noops[2])]
         )
         ckw = dict(
-            config=self.solver_config, mode="constrained", mesh=self.mesh,
+            config=config, mode="constrained", mesh=self.mesh,
         )
         jax.block_until_ready(solve_packed(
             base + static_pieces + carry_pieces + fam,

@@ -845,7 +845,11 @@ def new_scheduler(
 ) -> Scheduler:
     """Build a fully wired scheduler (reference scheduler.go:223 New +
     factory.go create). ``batch=True`` selects the TPU batch-solver loop
-    (the out-of-tree ``tpu-jax`` profile of the north star)."""
+    (the out-of-tree ``tpu-jax`` profile of the north star).
+    ``solver_config`` is a driver's override of the device's resource
+    score rule; without it every profile's ``plugins.score`` decides its
+    own (NodeResourcesLeastAllocated / BalancedAllocation /
+    MostAllocated and their weights), as it does on the host path."""
     registry = new_in_tree_registry()
     registry.merge(out_of_tree_registry)
 
@@ -910,7 +914,6 @@ def new_scheduler(
     algorithm.nominated_pods_lister = queue
 
     if batch:
-        from kubernetes_tpu.ops.assignment import GreedyConfig
         from kubernetes_tpu.scheduler.batch import BatchScheduler
 
         sched: Scheduler = BatchScheduler(
@@ -921,7 +924,9 @@ def new_scheduler(
             client=client,
             async_binding=async_binding,
             max_batch=max_batch,
-            solver_config=solver_config or GreedyConfig(),
+            # None: each profile's enabled resource scorers and weights
+            # are the device's (ops/assignment.GreedyConfig)
+            solver_config=solver_config,
             solver_mode=solver_mode,
             mesh=mesh,
             robustness_config=robustness_config,

@@ -730,6 +730,7 @@ class PodBatch:
     priorities: np.ndarray  # [B] int32
     order: np.ndarray  # [B] int32: solve order (priority desc, FIFO)
     unsatisfiable: np.ndarray  # [B] bool: requests a resource no node has
+    templates: int = 0  # the distinct request rows among ``requests``
 
     @property
     def size(self) -> int:
@@ -789,11 +790,11 @@ def _pack_gather_py(
 
 def pod_request_rows(
     pods_l: List[Pod], dims: ResourceDims
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int]:
     """``(requests [B, R], non_zero_requests [B, 2], priorities [B],
-    unsatisfiable [B])`` of a non-empty list of pods, in its order: the
-    gather over the ``_packrow`` memos and one schema encode a distinct
-    request row. What ``pack_pod_batch`` packs before it orders the
+    unsatisfiable [B], distinct request rows)`` of a non-empty list of
+    pods, in its order: the gather over the ``_packrow`` memos and one
+    schema encode a distinct request row. What ``pack_pod_batch`` packs before it orders the
     batch; a packer with an order of its own (the victim pack's kept
     rows, ops/preempt_facts.py) takes the rows alone."""
     b = len(pods_l)
@@ -827,7 +828,7 @@ def pod_request_rows(
         uniq_unknown.append(unknown)
     requests = np.stack(uniq_rows)[idx]
     unsatisfiable = np.asarray(uniq_unknown, dtype=bool)[idx]
-    return requests, nzr, prio, unsatisfiable
+    return requests, nzr, prio, unsatisfiable, len(uniq_rows)
 
 
 def pack_pod_batch(
@@ -861,7 +862,9 @@ def pack_pod_batch(
             unsatisfiable=np.zeros(0, dtype=bool),
         )
     pods_l = pods if isinstance(pods, list) else list(pods)
-    requests, nzr, prio, unsatisfiable = pod_request_rows(pods_l, dims)
+    requests, nzr, prio, unsatisfiable, templates = pod_request_rows(
+        pods_l, dims
+    )
     ts = timestamps or [pod.metadata.creation_timestamp for pod in pods_l]
     # pop_batch already drains the activeQ in comparator order (priority
     # desc, enqueue time asc) -- detect the sorted common case and skip
@@ -887,4 +890,5 @@ def pack_pod_batch(
         priorities=prio,
         order=order,
         unsatisfiable=unsatisfiable,
+        templates=templates,
     )

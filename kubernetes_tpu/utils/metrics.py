@@ -467,6 +467,18 @@ score_family_batches = registry.register(Counter(
     "family's envelope goes to the host path and is not counted.",
     ("live",),
 ))
+solves_by_resource_score = registry.register(Counter(
+    "scheduler_solves_by_resource_score_total",
+    "Solver batches, by the resource score rule they were solved under: "
+    "the batch's profile's enabled NodeResourcesLeastAllocated, "
+    "NodeResourcesBalancedAllocation and NodeResourcesMostAllocated and "
+    "their weights (ops/assignment.GreedyConfig.label: least+balanced for "
+    "the default provider, most for a bin-packing profile), or the "
+    "driver's override. A profile that scores with a resource scorer the "
+    "device does not model solves nothing here: its pods take the host "
+    "path.",
+    ("score",),
+))
 commit_join_timeouts = registry.register(Counter(
     "scheduler_commit_thread_join_timeouts_total",
     "Committer threads that failed to join at shutdown.",

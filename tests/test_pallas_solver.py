@@ -214,3 +214,98 @@ def test_one_program_a_shape_whatever_the_batch_holds():
         solve(n_live)
     assert pallas_greedy_solve._cache_size() == kernel_programs
     assert jit_cache_sizes() == packed_programs
+
+
+# -- bin-packing: every tier against the plain reference ----------------------
+
+
+def _binpack_problem(seed, r, n=128, zones=4):
+    """Eight-unit nodes that hold 0-8 units, and pods of 1, 2, 4 and 8
+    units, one size a zone, in seeded order. At ``r`` 5 a unit is a GPU
+    and the fifth column alone refuses a ninth unit (cpu and memory
+    would take it); at ``r`` 4 the node has cpu for eight and no GPUs."""
+    rng = np.random.default_rng(seed)
+    mib = 1 << 20
+    alloc = np.zeros((n, r), np.int32)
+    alloc[:, 0] = 32000 if r > 4 else 28000
+    alloc[:, 1] = 64 * 1024 * 1024  # KiB
+    alloc[:, 3] = 110
+    unit = np.zeros(r, np.int32)
+    unit[:2] = 3500, 7000 * 1024
+    if r > 4:
+        alloc[:, 4] = 8
+        unit[4] = 1
+    held = rng.integers(0, 9, n)
+    requested = held[:, None].astype(np.int32) * unit[None, :]
+    requested[:, 3] = held
+    nzr = np.ascontiguousarray(requested[:, :2])
+    zone = np.arange(n) % zones
+    sizes = [1, 2, 4, 8][:zones]
+    pods = [(z, s) for z, s in enumerate(sizes)
+            for _ in range(int(rng.integers(4, 40)))]
+    pods = [pods[int(k)] for k in rng.permutation(len(pods))]
+    b = 256
+    pod_req = np.zeros((b, r), np.int32)
+    midx = np.zeros(b, np.int32)
+    active = np.zeros(b, bool)
+    for k, (z, s) in enumerate(pods):
+        pod_req[k] = unit * s
+        pod_req[k, 3] = 1
+        midx[k] = z
+        active[k] = True
+    rows = np.zeros((8, n), bool)
+    for z in range(zones):
+        rows[z] = zone == z
+    device = (alloc, requested, nzr, np.ones(n, bool), pod_req,
+              np.ascontiguousarray(pod_req[:, :2]), rows, midx, active)
+    # the same problem in the reference's columns: bytes, no ephemeral
+    keep = [0, 1, 3] + ([4] if r > 4 else [])
+    scale = np.array([1, 1024, 1] + ([1] if r > 4 else []), np.int64)
+    return device, pods, sizes, zone, unit[keep] * scale, (
+        alloc[:, keep].astype(np.int64) * scale,
+        requested[:, keep].astype(np.int64) * scale,
+    )
+
+
+def _tier(name):
+    from kubernetes_tpu.robustness.ladder import host_greedy_assign
+
+    if name == "pallas":
+        return lambda *a, config: pallas_greedy_solve(
+            *a, config=config, interpret=True)
+    if name == "xla":
+        return greedy_assign_compact
+    return host_greedy_assign
+
+
+@pytest.mark.parametrize("seed", [43, 4343])
+@pytest.mark.parametrize("r", [4, 5])
+@pytest.mark.parametrize("tier", ["pallas", "xla", "host_greedy"])
+def test_every_tier_packs_as_the_binpack_reference(tier, r, seed):
+    """MostAllocated alone, with node-selector mask rows: each tier's
+    placements are the plain reference's (``chipbench/
+    binpack_reference.py``: both break ties by the lowest index, and
+    pools are disjoint, so node by node)."""
+    from chipbench import binpack_reference
+
+    device, pods, sizes, zone, unit, (cap, used) = _binpack_problem(seed, r)
+    packing = GreedyConfig(0, 0, 1)
+    assigned = np.asarray(_tier(tier)(*device, config=packing)[0])
+    n = cap.shape[0]
+    nodes = binpack_reference.Nodes(cap, used)
+    for z, size in enumerate(sizes):
+        mine = [k for k, (pz, _) in enumerate(pods) if pz == z]
+        got = np.bincount(
+            assigned[mine][assigned[mine] != NO_NODE], minlength=n
+        )
+        pod = unit * size
+        pod[2] = 1
+        want, unplaced = binpack_reference.schedule(
+            nodes, pod, len(mine), zone == z
+        )
+        assert np.array_equal(got, want), (tier, r, z)
+        assert int((assigned[mine] == NO_NODE).sum()) == unplaced
+        assert binpack_reference.unexplained(
+            nodes, pod, len(mine), got, zone == z) == 0
+        if r > 4:  # eight GPUs a node, whatever cpu and memory allow
+            assert ((used[:, 3] + got * size) <= 8).all()
