@@ -24,9 +24,15 @@ from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+from kubernetes_tpu import native as _native
 from kubernetes_tpu.api.types import Node, Pod
-from kubernetes_tpu.cache.node_info import NodeInfo, next_generation
+from kubernetes_tpu.cache.node_info import (
+    NodeInfo,
+    next_generation,
+    node_info_clones_py,
+)
 from kubernetes_tpu.cache.snapshot import Snapshot
+from kubernetes_tpu.utils import metrics as _metrics
 
 DEFAULT_ASSUME_TTL_SECONDS = 30.0  # reference scheduler.go:240
 
@@ -65,6 +71,21 @@ class _PodState:
     # sweeper pass instead of waiting out the assume TTL -- the sweeper
     # routes the pod by apiserver truth either way
     node_removed: bool = False
+
+
+def _clones(
+    infos: List[NodeInfo], prevs: List[Optional[NodeInfo]]
+) -> Tuple[List[NodeInfo], int, bool, int]:
+    """``(clones, shared, affinity, transitions)`` of a refresh's
+    changed NodeInfos and the snapshot's NodeInfos they take the place
+    of (``node_info_clones_py`` has the meaning): one native loop, or
+    its twin where the extension did not build."""
+    fn, expected = _native.ingest_fn("node_info_clones")
+    if fn is not None:
+        return fn(infos, prevs)
+    if expected:
+        _metrics.ingest_native_fallbacks.inc(site="snapshot-clone")
+    return node_info_clones_py(infos, prevs)
 
 
 class SchedulerCache:
@@ -471,33 +492,46 @@ class SchedulerCache:
                 )
                 and bool(info_map.keys() - nodes.keys())
             )
-            changed: List[Tuple[str, NodeInfo]] = []
+            names: List[str] = []
+            infos: List[NodeInfo] = []
             for name in reversed(self._gen_order):
                 ni = nodes[name]
                 if ni.generation <= snap_gen:
                     break
-                changed.append((name, ni))
-                prev = info_map.get(name)
-                if prev is None or (prev.node is None) != (ni.node is None):
-                    # a new map entry, or a node-object transition,
-                    # moves node_info_list membership/row identity
-                    membership = True
+                names.append(name)
+                infos.append(ni)
+            prevs = list(map(info_map.get, names))
             snapshot.source = self
             snapshot.removals_seen = self._removals
             snapshot.set_node_spec_epoch(self._node_spec_epoch)
-            snapshot.last_refreshed = len(changed)
-            if membership or not snapshot.replace_in_place(
-                [(name, ni.clone()) for name, ni in changed]
-            ):
-                self._update_snapshot_full(snapshot)
-            elif changed:
-                snapshot.generation = changed[0][1].generation
+            snapshot.last_refreshed = len(names)
+            snapshot.last_shared = 0
+            # a new map entry moves node_info_list's membership and the
+            # rows' identity, as does a node-object transition
+            membership = membership or None in prevs
+            made: Dict[str, NodeInfo] = {}
+            if names and not membership:
+                clones, shared, affinity, transitions = _clones(infos, prevs)
+                if not transitions and snapshot.replace_in_place(
+                    names, prevs, clones, affinity
+                ):
+                    snapshot.last_shared = shared
+                    snapshot.generation = infos[0].generation
+                else:
+                    membership = True
+                    made = dict(zip(names, clones))
+            if membership:
+                self._update_snapshot_full(snapshot, made)
             return snapshot
 
-    def _update_snapshot_full(self, snapshot: Snapshot) -> None:
+    def _update_snapshot_full(
+        self, snapshot: Snapshot, made: Dict[str, NodeInfo]
+    ) -> None:
         """The walk over every node: for a snapshot another cache fed,
         or when nodes joined or left. New names enter the map in
-        ``_nodes``' order, and ``refresh_lists`` rebuilds from it."""
+        ``_nodes``' order, and ``refresh_lists`` rebuilds from it.
+        ``made`` holds the clones the in-place refresh had made of
+        this walk's nodes before it found it could not place them."""
         max_gen = snapshot.generation
         changed = False
         for name, ni in self._nodes.items():
@@ -507,7 +541,11 @@ class SchedulerCache:
                     ni.node is None
                 ):
                     snapshot.note_membership_change()
-                snapshot.node_info_map[name] = ni.clone()
+                clone = snapshot.node_info_map[name] = (
+                    made.get(name) or ni.clone()
+                )
+                if prev is not None and clone.shares_fixed_parts(prev):
+                    snapshot.last_shared += 1
                 snapshot.note_changed(name)
                 changed = True
                 if ni.generation > max_gen:

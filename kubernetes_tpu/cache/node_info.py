@@ -70,9 +70,12 @@ def next_generation() -> int:
     return next(_generation)
 
 
-@dataclass
+@dataclass(slots=True)
 class Resource:
-    """Aggregated resource vector (reference node_info.go:143)."""
+    """Aggregated resource vector (reference node_info.go:143). Slotted,
+    as ``HostPortInfo`` and ``NodeInfo`` are: the snapshot refresh's
+    native walks (native/_hotpath.c, "snapshot refresh spine") read and
+    copy the fields where they lie."""
 
     milli_cpu: int = 0
     memory: int = 0
@@ -186,6 +189,8 @@ class HostPortInfo:
     protocols are equal and either ip is 0.0.0.0 or the ips are equal.
     """
 
+    __slots__ = ("ports",)
+
     def __init__(self) -> None:
         self.ports: Set[Tuple[str, str, int]] = set()
 
@@ -211,6 +216,12 @@ class HostPortInfo:
 
 class NodeInfo:
     """Aggregated per-node state (reference node_info.go:47)."""
+
+    __slots__ = (
+        "node", "pods", "pods_with_affinity", "used_ports", "requested",
+        "non_zero_requested", "allocatable", "image_states",
+        "csi_volume_limits", "volume_in_use", "generation",
+    )
 
     def __init__(self, node: Optional[Node] = None) -> None:
         self.node: Optional[Node] = node
@@ -370,6 +381,14 @@ class NodeInfo:
     # -- snapshot support ---------------------------------------------------
 
     def clone(self) -> "NodeInfo":
+        """The copy a snapshot holds. What a pod event moves is copied;
+        ``node``, ``allocatable``, ``image_states`` and
+        ``csi_volume_limits`` are kept by reference: ``set_node`` and
+        ``set_csi_node`` replace those objects and nothing writes into
+        them, so a clone that shares them with the NodeInfo it takes
+        the place of differs from it in its pods alone
+        (``shares_fixed_parts``, which the node tensor's row repack
+        reads the same way)."""
         ni = NodeInfo.__new__(NodeInfo)
         ni.node = self.node
         ni.pods = list(self.pods)
@@ -377,15 +396,51 @@ class NodeInfo:
         ni.used_ports = self.used_ports.clone()
         ni.requested = self.requested.clone()
         ni.non_zero_requested = self.non_zero_requested.clone()
-        ni.allocatable = self.allocatable.clone()
-        ni.image_states = dict(self.image_states)
-        ni.csi_volume_limits = dict(self.csi_volume_limits)
+        ni.allocatable = self.allocatable
+        ni.image_states = self.image_states
+        ni.csi_volume_limits = self.csi_volume_limits
         ni.volume_in_use = dict(self.volume_in_use)
         ni.generation = self.generation
         return ni
+
+    def shares_fixed_parts(self, other: "NodeInfo") -> bool:
+        """Whether the four parts a pod event cannot change are the
+        very objects ``other`` holds."""
+        return (
+            self.node is other.node
+            and self.allocatable is other.allocatable
+            and self.image_states is other.image_states
+            and self.csi_volume_limits is other.csi_volume_limits
+        )
 
     def __repr__(self) -> str:  # pragma: no cover
         return (
             f"NodeInfo(node={self.node_name!r}, pods={len(self.pods)}, "
             f"requested=cpu:{self.requested.milli_cpu}m mem:{self.requested.memory})"
         )
+
+
+def node_info_clones_py(
+    infos: List[NodeInfo], prevs: List[Optional[NodeInfo]]
+) -> Tuple[List[NodeInfo], int, bool, int]:
+    """Pure-Python twin of native ``node_info_clones`` (identical
+    semantics; tests/test_native_refresh.py runs the two on the same
+    inputs): ``(clones, shared, affinity, transitions)`` with
+    ``clones[k]`` the clone of ``infos[k]``, ``shared`` the number of
+    clones whose fixed parts are the objects of ``prevs[k]`` (the
+    NodeInfo the clone takes the place of, or None), ``affinity``
+    whether any clone or predecessor has pods with affinity,
+    ``transitions`` the number of pairs of which one has a node object
+    and the other none."""
+    clones = [ni.clone() for ni in infos]
+    shared = transitions = 0
+    affinity = False
+    for clone, prev in zip(clones, prevs):
+        if clone.pods_with_affinity:
+            affinity = True
+        if prev is not None:
+            shared += clone.shares_fixed_parts(prev)
+            transitions += (clone.node is None) != (prev.node is None)
+            if prev.pods_with_affinity:
+                affinity = True
+    return clones, shared, affinity, transitions

@@ -59,8 +59,11 @@ class Snapshot:
         #: images differ; 0 while no cache has fed the snapshot. What
         #: depends on those alone is kept while it stands.
         self.node_spec_epoch = 0
-        #: NodeInfos the last refresh cloned
+        #: NodeInfos the last refresh cloned, and how many of the clones
+        #: hold the fixed parts (``NodeInfo.shares_fixed_parts``) of the
+        #: NodeInfo they took the place of: only their pods moved
         self.last_refreshed = 0
+        self.last_shared = 0
         self._list_pos: Optional[Dict[str, int]] = None
         self._image_holders: Optional[Dict[str, ImageHolders]] = None
         #: the score packer's node-side facts (ops/scoring.py) with the
@@ -109,11 +112,9 @@ class Snapshot:
     def note_changed_many(self, names: Iterable[str]) -> None:
         with self._change_lock:
             log = self._change_log
-            seq = self._change_seq
-            for name in names:
-                seq += 1
-                log.append((seq, name))
-            self._change_seq = seq
+            first = len(log)
+            log.extend(enumerate(names, self._change_seq + 1))
+            self._change_seq += len(log) - first
             if len(log) > max(CHANGE_TRACK_MIN, 2 * len(self.node_info_map)):
                 # drop the older half; a cursor behind it takes the
                 # full walk, one that reads at every refresh never is
@@ -155,13 +156,21 @@ class Snapshot:
             names = {n for _s, n in self._change_log[i:]}
             return names, membership_moved, self._change_seq
 
-    def replace_in_place(self, clones: List[Tuple[str, NodeInfo]]) -> bool:
-        """Put each ``(name, clone)`` where its predecessor stands in the
-        map and the lists: the refresh of a snapshot whose node set has
-        not moved. Nothing is touched and False returned when the
-        position index does not hold (the caller then takes the full
-        walk). The list is copied before it is written: a reader on
-        another thread that holds the old one (the preemptor, the
+    def replace_in_place(
+        self,
+        names: List[str],
+        prevs: List[Optional[NodeInfo]],
+        clones: List[NodeInfo],
+        affinity_moved: bool,
+    ) -> bool:
+        """Put each clone where its predecessor stands in the map and
+        the lists: the refresh of a snapshot whose node set has not
+        moved. ``prevs[k]`` is what the map holds under ``names[k]``,
+        ``affinity_moved`` whether any clone or predecessor has pods
+        with affinity. Nothing is touched and False returned when
+        the position index does not hold (the caller then takes the
+        full walk). The list is copied before it is written: a reader
+        on another thread that holds the old one (the preemptor, the
         prewarm thread) keeps seeing one refresh's state whole."""
         if not clones:
             return True
@@ -171,28 +180,31 @@ class Snapshot:
             pos = self._list_pos = {
                 ni.node.metadata.name: i for i, ni in enumerate(lst)
             }
-        info_map = self.node_info_map
-        for name, clone in clones:
-            if clone.node is not None:
-                i = pos.get(name)
-                if i is None or lst[i] is not info_map[name]:
-                    return False
         lst = list(lst)
-        affinity_moved = False
-        for name, clone in clones:
-            prev = info_map[name]
-            info_map[name] = clone
-            if clone.node is not None:
-                lst[pos[name]] = clone
-            if prev.pods_with_affinity or clone.pods_with_affinity:
-                affinity_moved = True
+        for i, prev, clone in zip(map(pos.get, names), prevs, clones):
+            if i is None:
+                # a NodeInfo with no node object is in the map alone
+                if prev.node is not None:
+                    return False
+            elif lst[i] is not prev:
+                return False
+            else:
+                lst[i] = clone
+        self.node_info_map.update(zip(names, clones))
         self.node_info_list = lst
         if affinity_moved:
             self.have_pods_with_affinity_list = [
                 ni for ni in lst if ni.pods_with_affinity
             ]
-        self.note_changed_many(name for name, _clone in clones)
+        self.note_changed_many(names)
         return True
+
+    def refresh_stats(self) -> Dict[str, int]:
+        """The last refresh as the ``sched/pack.snapshot`` span says it."""
+        return {
+            "nodes_refreshed": self.last_refreshed,
+            "nodes_shared": self.last_shared,
+        }
 
     def set_node_spec_epoch(self, epoch: int) -> None:
         if epoch != self.node_spec_epoch:

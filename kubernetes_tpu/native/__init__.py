@@ -128,7 +128,8 @@ assume_clones = getattr(hotpath, "assume_clones", None)
 bind_assumed_bulk = getattr(hotpath, "bind_assumed_bulk", None)
 commit_gather = getattr(hotpath, "commit_gather", None)
 
-# -- the ingest plane (see _hotpath.c "ingest spine") ---------------------
+# -- the ingest plane (see _hotpath.c "ingest spine") and the snapshot
+# -- refresh's two walks ("snapshot refresh spine") -----------------------
 #
 # Gated separately from the commit-path loops by KTPU_NATIVE_INGEST
 # (default on): =0 forces the pure-Python twins at every ingest call
@@ -141,6 +142,7 @@ _INGEST_FNS = {
     for name in (
         "ingest_decode", "ingest_apply", "ingest_stamp",
         "pack_gather", "queue_shape", "mirror_scatter",
+        "node_info_clones", "node_rows_gather",
     )
 }
 

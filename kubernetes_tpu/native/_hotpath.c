@@ -1728,6 +1728,456 @@ out:
     return ret;
 }
 
+/* -- snapshot refresh spine ----------------------------------------------
+ *
+ * After a full batch and a wave's deletes some thousands of NodeInfos
+ * have advanced their generation. The dispatcher refreshes each on its
+ * own thread: cache.update_snapshot clones it, and the node tensor
+ * gathers its fixed-column integers for the row repack. Both walks are
+ * attribute reads and allocations with no Python-level semantics, so
+ * they live here as single C loops: node_info_clones() and
+ * node_rows_gather(). Twins: cache/node_info.py node_info_clones_py
+ * (NodeInfo.clone a node) and tensors/node_tensor.py
+ * _node_rows_gather_py; tests/test_native_refresh.py differentially
+ * exercises native vs twin on the same inputs.
+ */
+
+static PyObject *str_node = NULL;
+static PyObject *str_pods = NULL;
+static PyObject *str_pods_with_affinity = NULL;
+static PyObject *str_used_ports = NULL;
+static PyObject *str_requested = NULL;
+static PyObject *str_non_zero_requested = NULL;
+static PyObject *str_allocatable = NULL;
+static PyObject *str_image_states = NULL;
+static PyObject *str_csi_volume_limits = NULL;
+static PyObject *str_volume_in_use = NULL;
+static PyObject *str_generation = NULL;
+static PyObject *str_milli_cpu = NULL;
+static PyObject *str_memory = NULL;
+static PyObject *str_ephemeral_storage = NULL;
+static PyObject *str_allowed_pod_number = NULL;
+static PyObject *str_scalar = NULL;
+
+/* NodeInfo, Resource and HostPortInfo are slotted (cache/node_info.py):
+ * a field is a pointer at an offset its member descriptor names. A
+ * layout holds one type's offsets in the order of the name list it was
+ * resolved with; an object of another type resolves it anew, and a
+ * type that is not slotted so is a TypeError. Reading the slots where
+ * they lie is what the two loops are for: with PyObject_GetAttr /
+ * SetAttr a field the clone walk costs hardly less than NodeInfo.clone
+ * does in the interpreter, whose slot loads are specialised (PERF.md
+ * section 6, PR 44, the forms of the walks). */
+#define MAX_FIELDS 11
+#ifndef Py_T_OBJECT_EX /* the spelling before Python 3.12 */
+#include <structmember.h>
+#define Py_T_OBJECT_EX T_OBJECT_EX
+#endif
+
+typedef struct {
+    PyTypeObject *tp;
+    Py_ssize_t off[MAX_FIELDS];
+} layout_t;
+
+enum { N_NODE, N_PODS, N_AFFINITY, N_PORTS, N_REQUESTED, N_NZR,
+       N_ALLOCATABLE, N_IMAGES, N_CSI, N_VOLUMES, N_GENERATION, N_FIELDS };
+enum { R_CPU, R_MEMORY, R_EPHEMERAL, R_PODS, R_SCALAR, R_FIELDS };
+
+static PyObject **node_names[N_FIELDS] = {
+    &str_node, &str_pods, &str_pods_with_affinity, &str_used_ports,
+    &str_requested, &str_non_zero_requested, &str_allocatable,
+    &str_image_states, &str_csi_volume_limits, &str_volume_in_use,
+    &str_generation,
+};
+static PyObject **resource_names[R_FIELDS] = {
+    &str_milli_cpu, &str_memory, &str_ephemeral_storage,
+    &str_allowed_pod_number, &str_scalar,
+};
+static PyObject **ports_names[1] = {&str_ports};
+
+static layout_t node_layout, resource_layout, ports_layout;
+
+static int
+resolve_layout(layout_t *lay, PyObject *obj, PyObject ***names, int n)
+{
+    PyTypeObject *tp = Py_TYPE(obj);
+    if (lay->tp == tp)
+        return 0;
+    Py_ssize_t off[MAX_FIELDS];
+    for (int j = 0; j < n; j++) {
+        /* a slot's descriptor is what its type holds under the name */
+        PyObject *descr = PyObject_GetAttr((PyObject *)tp, *names[j]);
+        int is_slot =
+            descr != NULL && Py_TYPE(descr) == &PyMemberDescr_Type &&
+            ((PyMemberDescrObject *)descr)->d_member->type == Py_T_OBJECT_EX;
+        if (is_slot)
+            off[j] = ((PyMemberDescrObject *)descr)->d_member->offset;
+        Py_XDECREF(descr);
+        if (!is_slot) {
+            PyErr_Clear(); /* the type holds nothing under the name */
+            PyErr_Format(PyExc_TypeError, "%.100s.%U is not a slot",
+                         tp->tp_name, *names[j]);
+            return -1;
+        }
+    }
+    Py_XSETREF(lay->tp, (PyTypeObject *)Py_NewRef((PyObject *)tp));
+    memcpy(lay->off, off, n * sizeof(off[0]));
+    return 0;
+}
+
+#define SLOT(obj, lay, j) (*(PyObject **)((char *)(obj) + (lay).off[j]))
+
+/* obj's field j, borrowed; an unset slot is an AttributeError */
+static PyObject *
+field(PyObject *obj, layout_t *lay, PyObject ***names, int j)
+{
+    PyObject *v = SLOT(obj, *lay, j);
+    if (v == NULL)
+        PyErr_SetObject(PyExc_AttributeError, *names[j]);
+    return v;
+}
+
+/* A bare instance of type(obj) (no __init__) with the first n fields of
+ * obj by reference; obj's layout is resolved. */
+static PyObject *
+shared_copy(PyObject *obj, layout_t *lay, PyObject ***names, int n)
+{
+    PyTypeObject *tp = Py_TYPE(obj);
+    PyObject *new = tp->tp_alloc(tp, 0);
+    if (new == NULL)
+        return NULL;
+    for (int j = 0; j < n; j++) {
+        PyObject *v = field(obj, lay, names, j);
+        if (v == NULL) {
+            Py_DECREF(new);
+            return NULL;
+        }
+        SLOT(new, *lay, j) = Py_NewRef(v);
+    }
+    return new;
+}
+
+/* new's field j replaced by ``copy`` (reference stolen; NULL is the
+ * error it carries) */
+static int
+replace_field(PyObject *new, layout_t *lay, int j, PyObject *copy)
+{
+    if (copy == NULL)
+        return -1;
+    Py_XSETREF(SLOT(new, *lay, j), copy);
+    return 0;
+}
+
+static PyObject *
+checked_copy(PyObject *v, int as_list)
+{
+    if (as_list ? PyList_Check(v) : PyDict_Check(v))
+        return as_list ? PyList_GetSlice(v, 0, PyList_GET_SIZE(v))
+                       : PyDict_Copy(v);
+    PyErr_SetString(PyExc_TypeError,
+                    as_list ? "NodeInfo: not a list" : "NodeInfo: not a dict");
+    return NULL;
+}
+
+/* Resource.clone(): the four integers by reference, ``scalar`` copied */
+static PyObject *
+resource_clone(PyObject *res)
+{
+    if (resolve_layout(&resource_layout, res, resource_names, R_FIELDS) < 0)
+        return NULL;
+    PyObject *new = shared_copy(res, &resource_layout, resource_names,
+                                R_FIELDS);
+    if (new != NULL &&
+        replace_field(new, &resource_layout, R_SCALAR,
+                      checked_copy(SLOT(new, resource_layout, R_SCALAR),
+                                   0)) < 0)
+        Py_CLEAR(new);
+    return new;
+}
+
+/* HostPortInfo.clone(): the ``ports`` set copied */
+static PyObject *
+ports_clone(PyObject *hp)
+{
+    if (resolve_layout(&ports_layout, hp, ports_names, 1) < 0)
+        return NULL;
+    PyObject *new = shared_copy(hp, &ports_layout, ports_names, 1);
+    if (new != NULL &&
+        replace_field(new, &ports_layout, 0,
+                      PySet_New(SLOT(new, ports_layout, 0))) < 0)
+        Py_CLEAR(new);
+    return new;
+}
+
+/* NodeInfo.clone() */
+static PyObject *
+node_info_clone(PyObject *ni)
+{
+    if (resolve_layout(&node_layout, ni, node_names, N_FIELDS) < 0)
+        return NULL;
+    PyObject *c = shared_copy(ni, &node_layout, node_names, N_FIELDS);
+    if (c == NULL)
+        return NULL;
+#define FIELD(j) SLOT(c, node_layout, j)
+    if (replace_field(c, &node_layout, N_PODS,
+                      checked_copy(FIELD(N_PODS), 1)) < 0 ||
+        replace_field(c, &node_layout, N_AFFINITY,
+                      checked_copy(FIELD(N_AFFINITY), 1)) < 0 ||
+        replace_field(c, &node_layout, N_PORTS,
+                      ports_clone(FIELD(N_PORTS))) < 0 ||
+        replace_field(c, &node_layout, N_REQUESTED,
+                      resource_clone(FIELD(N_REQUESTED))) < 0 ||
+        replace_field(c, &node_layout, N_NZR,
+                      resource_clone(FIELD(N_NZR))) < 0 ||
+        replace_field(c, &node_layout, N_VOLUMES,
+                      checked_copy(FIELD(N_VOLUMES), 0)) < 0)
+        Py_CLEAR(c);
+#undef FIELD
+    return c;
+}
+
+static PyObject *
+node_info_clones(PyObject *self, PyObject *args)
+{
+    /* node_info_clones(infos, prevs)
+     *   -> (clones, shared, affinity, transitions)
+     *
+     * clones[k] is NodeInfo.clone() of infos[k]: what a pod event moves
+     * (pods, pods_with_affinity, used_ports, requested,
+     * non_zero_requested, volume_in_use) copied, what the cache only
+     * ever replaces (node, allocatable, image_states,
+     * csi_volume_limits) and the generation kept by reference.
+     * prevs[k] is the NodeInfo the clone takes the place of, or None:
+     * ``shared`` counts the clones whose four kept parts are the very
+     * objects of their predecessor, ``affinity`` says whether any
+     * clone or predecessor has pods with affinity, ``transitions``
+     * counts the pairs of which one has a node object and the other
+     * none. Nothing the caller holds is written. */
+    PyObject *infos, *prevs;
+    if (!PyArg_ParseTuple(args, "O!O!", &PyList_Type, &infos,
+                          &PyList_Type, &prevs))
+        return NULL;
+    Py_ssize_t n = PyList_GET_SIZE(infos);
+    if (PyList_GET_SIZE(prevs) != n) {
+        PyErr_SetString(PyExc_ValueError, "infos/prevs length mismatch");
+        return NULL;
+    }
+    PyObject *out = PyList_New(n);
+    if (out == NULL)
+        return NULL;
+    Py_ssize_t shared = 0, transitions = 0;
+    int affinity = 0;
+    for (Py_ssize_t k = 0; k < n; k++) {
+        PyObject *prev = PyList_GET_ITEM(prevs, k);
+        PyObject *c = node_info_clone(PyList_GET_ITEM(infos, k));
+        if (c == NULL)
+            goto fail;
+        PyList_SET_ITEM(out, k, c);
+        if (PyList_GET_SIZE(SLOT(c, node_layout, N_AFFINITY)))
+            affinity = 1;
+        if (prev == Py_None)
+            continue;
+        if (Py_TYPE(prev) != node_layout.tp) {
+            PyErr_SetString(PyExc_TypeError,
+                            "a predecessor of another type than its clone");
+            goto fail;
+        }
+        PyObject *prev_node = field(prev, &node_layout, node_names, N_NODE);
+        PyObject *prev_aff =
+            field(prev, &node_layout, node_names, N_AFFINITY);
+        if (prev_node == NULL || prev_aff == NULL)
+            goto fail;
+        PyObject *node = SLOT(c, node_layout, N_NODE);
+        transitions += (node == Py_None) != (prev_node == Py_None);
+        shared += node == prev_node &&
+                  SLOT(c, node_layout, N_ALLOCATABLE) ==
+                      SLOT(prev, node_layout, N_ALLOCATABLE) &&
+                  SLOT(c, node_layout, N_IMAGES) ==
+                      SLOT(prev, node_layout, N_IMAGES) &&
+                  SLOT(c, node_layout, N_CSI) ==
+                      SLOT(prev, node_layout, N_CSI);
+        if (!affinity) {
+            Py_ssize_t held = PyObject_Size(prev_aff);
+            if (held < 0)
+                goto fail;
+            affinity = held > 0;
+        }
+    }
+    return Py_BuildValue("(NnOn)", out, shared,
+                         affinity ? Py_True : Py_False, transitions);
+fail:
+    Py_DECREF(out);
+    return NULL;
+}
+
+/* a Resource's field j as a C integer; *err set when it is none or too
+ * large */
+static long long
+resource_ll(PyObject *res, int j, int *err)
+{
+    if (*err)
+        return 0;
+    PyObject *v = field(res, &resource_layout, resource_names, j);
+    if (v == NULL) {
+        *err = 1;
+        return 0;
+    }
+    long long x = PyLong_AsLongLong(v);
+    if (x == -1 && PyErr_Occurred())
+        *err = 1;
+    return x;
+}
+
+/* Python's ``b // 1024`` and ``-(-b // 1024)`` */
+static long long
+kib_floor(long long b)
+{
+    return (b >= 0) ? b / 1024 : -((-b + 1023) / 1024);
+}
+
+static long long
+kib_ceil(long long b)
+{
+    return (b >= 0) ? (b + 1023) / 1024 : -((-b) / 1024);
+}
+
+static PyObject *
+node_rows_gather(PyObject *self, PyObject *args)
+{
+    /* node_rows_gather(infos, rows, generations, row_node, row_alloc,
+     *                  row_csi, ints) -> (full, extras, odd)
+     *
+     * ints[k] takes infos[k]'s ten fixed-column integers in the node
+     * tensor's units (tensors/node_tensor.py _node_ints): allocatable
+     * with bytes floored to KiB, requested with bytes ceiled and the
+     * pod count, non-zero requested. ``full`` lists the k whose node,
+     * allocatable or csi_volume_limits is not the object slot rows[k]
+     * was last packed from (row_node / row_alloc / row_csi): those take
+     * the whole row, the others their requested columns alone.
+     * ``extras`` lists the k whose requested.scalar or volume_in_use
+     * holds a name. ``odd`` lists the k the caller has no row to pack
+     * for: a NodeInfo with no node object, or one whose generation is
+     * the one slot rows[k] holds (``generations``). Nothing but
+     * ``ints`` is written. */
+    PyObject *infos, *rows, *gens, *row_node, *row_alloc, *row_csi;
+    Py_buffer ints_buf;
+    if (!PyArg_ParseTuple(args, "O!O!O!O!O!O!w*", &PyList_Type, &infos,
+                          &PyList_Type, &rows, &PyList_Type, &gens,
+                          &PyList_Type, &row_node, &PyList_Type, &row_alloc,
+                          &PyList_Type, &row_csi, &ints_buf))
+        return NULL;
+    Py_ssize_t n = PyList_GET_SIZE(infos);
+    Py_ssize_t slots = PyList_GET_SIZE(row_node);
+    PyObject *full = NULL, *extras = NULL, *odd = NULL, *ret = NULL;
+    if (PyList_GET_SIZE(rows) != n || ints_buf.len < n * 40 ||
+        PyList_GET_SIZE(gens) != slots ||
+        PyList_GET_SIZE(row_alloc) != slots ||
+        PyList_GET_SIZE(row_csi) != slots) {
+        PyErr_SetString(PyExc_ValueError,
+                        "node_rows_gather shape mismatch");
+        goto out;
+    }
+    full = PyList_New(0);
+    extras = PyList_New(0);
+    odd = PyList_New(0);
+    if (full == NULL || extras == NULL || odd == NULL)
+        goto out;
+    int32_t *ints = (int32_t *)ints_buf.buf;
+    for (Py_ssize_t k = 0; k < n; k++) {
+        Py_ssize_t i = PyLong_AsSsize_t(PyList_GET_ITEM(rows, k));
+        if (i == -1 && PyErr_Occurred())
+            goto out;
+        if (i < 0 || i >= slots) {
+            PyErr_SetString(PyExc_IndexError,
+                            "node_rows_gather row out of range");
+            goto out;
+        }
+        /* all borrowed: the NodeInfo holds them while we look */
+        PyObject *ni = PyList_GET_ITEM(infos, k);
+        if (resolve_layout(&node_layout, ni, node_names, N_FIELDS) < 0)
+            goto out;
+        PyObject *part[N_FIELDS];
+        for (int j = 0; j < N_FIELDS; j++) {
+            part[j] = field(ni, &node_layout, node_names, j);
+            if (part[j] == NULL)
+                goto out;
+        }
+        PyObject *alloc = part[N_ALLOCATABLE], *req = part[N_REQUESTED];
+        PyObject *nzr = part[N_NZR];
+        if (resolve_layout(&resource_layout, alloc, resource_names,
+                           R_FIELDS) < 0)
+            goto out;
+        if (Py_TYPE(req) != resource_layout.tp ||
+            Py_TYPE(nzr) != resource_layout.tp) {
+            PyErr_SetString(PyExc_TypeError,
+                            "a NodeInfo's Resources of more than one type");
+            goto out;
+        }
+        int err = 0;
+        long long v[10];
+        v[0] = resource_ll(alloc, R_CPU, &err);
+        v[1] = kib_floor(resource_ll(alloc, R_MEMORY, &err));
+        v[2] = kib_floor(resource_ll(alloc, R_EPHEMERAL, &err));
+        v[3] = resource_ll(alloc, R_PODS, &err);
+        v[4] = resource_ll(req, R_CPU, &err);
+        v[5] = kib_ceil(resource_ll(req, R_MEMORY, &err));
+        v[6] = kib_ceil(resource_ll(req, R_EPHEMERAL, &err));
+        Py_ssize_t n_pods = err ? 0 : PyObject_Size(part[N_PODS]);
+        v[7] = (long long)n_pods;
+        v[8] = resource_ll(nzr, R_CPU, &err);
+        v[9] = kib_ceil(resource_ll(nzr, R_MEMORY, &err));
+        PyObject *scalar =
+            err ? NULL : field(req, &resource_layout, resource_names,
+                               R_SCALAR);
+        if (scalar == NULL || n_pods < 0)
+            goto out;
+        Py_ssize_t named = PyObject_Size(scalar);
+        if (named == 0)
+            named = PyObject_Size(part[N_VOLUMES]);
+        if (named < 0)
+            goto out;
+        int is_extra = named > 0;
+        int is_full = part[N_NODE] != PyList_GET_ITEM(row_node, i) ||
+                      alloc != PyList_GET_ITEM(row_alloc, i) ||
+                      part[N_CSI] != PyList_GET_ITEM(row_csi, i);
+        int is_odd = part[N_NODE] == Py_None;
+        if (!is_odd) {
+            is_odd = PyObject_RichCompareBool(
+                part[N_GENERATION], PyList_GET_ITEM(gens, i), Py_EQ);
+            if (is_odd < 0)
+                goto out;
+        }
+        for (int j = 0; j < 10; j++) {
+            /* the twin's numpy int32 array raises OverflowError on an
+             * out-of-range value; wrapping here would part the two */
+            if (v[j] < INT32_MIN || v[j] > INT32_MAX) {
+                PyErr_SetString(PyExc_OverflowError,
+                                "node integer out of int32 range");
+                goto out;
+            }
+            ints[10 * k + j] = (int32_t)v[j];
+        }
+        if (is_full || is_extra || is_odd) {
+            PyObject *idx = PyLong_FromSsize_t(k);
+            if (idx == NULL ||
+                (is_full && PyList_Append(full, idx) < 0) ||
+                (is_extra && PyList_Append(extras, idx) < 0) ||
+                (is_odd && PyList_Append(odd, idx) < 0)) {
+                Py_XDECREF(idx);
+                goto out;
+            }
+            Py_DECREF(idx);
+        }
+    }
+    ret = Py_BuildValue("(OOO)", full, extras, odd);
+out:
+    Py_XDECREF(full);
+    Py_XDECREF(extras);
+    Py_XDECREF(odd);
+    PyBuffer_Release(&ints_buf);
+    return ret;
+}
+
 static PyMethodDef methods[] = {
     {"match_compiled", match_compiled, METH_VARARGS,
      "match_compiled(labels, compiled) -> bool"},
@@ -1761,6 +2211,12 @@ static PyMethodDef methods[] = {
     {"mirror_scatter", mirror_scatter, METH_VARARGS,
      "mirror_scatter(a, req, nzr, req_shadow, nzr_shadow, rows_out, "
      "req_out, nzr_out) -> placed count k"},
+    {"node_info_clones", node_info_clones, METH_VARARGS,
+     "node_info_clones(infos, prevs) -> (clones, shared, affinity, "
+     "transitions)"},
+    {"node_rows_gather", node_rows_gather, METH_VARARGS,
+     "node_rows_gather(infos, rows, generations, row_node, row_alloc, "
+     "row_csi, ints) -> (full, extras, odd)"},
     {NULL, NULL, 0, NULL},
 };
 
@@ -1812,6 +2268,22 @@ PyInit__hotpath(void)
     str_req_memo = PyUnicode_InternFromString("_req_memo");
     str_nzr_memo = PyUnicode_InternFromString("_nzr_memo");
     str_hot_memo = PyUnicode_InternFromString("_hot_memo");
+    str_node = PyUnicode_InternFromString("node");
+    str_pods = PyUnicode_InternFromString("pods");
+    str_pods_with_affinity = PyUnicode_InternFromString("pods_with_affinity");
+    str_used_ports = PyUnicode_InternFromString("used_ports");
+    str_requested = PyUnicode_InternFromString("requested");
+    str_non_zero_requested = PyUnicode_InternFromString("non_zero_requested");
+    str_allocatable = PyUnicode_InternFromString("allocatable");
+    str_image_states = PyUnicode_InternFromString("image_states");
+    str_csi_volume_limits = PyUnicode_InternFromString("csi_volume_limits");
+    str_volume_in_use = PyUnicode_InternFromString("volume_in_use");
+    str_generation = PyUnicode_InternFromString("generation");
+    str_milli_cpu = PyUnicode_InternFromString("milli_cpu");
+    str_memory = PyUnicode_InternFromString("memory");
+    str_ephemeral_storage = PyUnicode_InternFromString("ephemeral_storage");
+    str_allowed_pod_number = PyUnicode_InternFromString("allowed_pod_number");
+    str_scalar = PyUnicode_InternFromString("scalar");
     if (str_dict == NULL || str_spec == NULL || str_node_name == NULL ||
         str_metadata == NULL || str_namespace == NULL ||
         str_name == NULL || str_uid == NULL || str_resource_version == NULL ||
@@ -1828,7 +2300,23 @@ PyInit__hotpath(void)
         str_host_port == NULL || str_packrow == NULL ||
         str_band_priority == NULL || str_admission == NULL ||
         str_req_memo == NULL || str_nzr_memo == NULL ||
-        str_hot_memo == NULL)
+        str_hot_memo == NULL ||
+        str_node == NULL ||
+        str_pods == NULL ||
+        str_pods_with_affinity == NULL ||
+        str_used_ports == NULL ||
+        str_requested == NULL ||
+        str_non_zero_requested == NULL ||
+        str_allocatable == NULL ||
+        str_image_states == NULL ||
+        str_csi_volume_limits == NULL ||
+        str_volume_in_use == NULL ||
+        str_generation == NULL ||
+        str_milli_cpu == NULL ||
+        str_memory == NULL ||
+        str_ephemeral_storage == NULL ||
+        str_allowed_pod_number == NULL ||
+        str_scalar == NULL)
         return NULL;
     return PyModule_Create(&moduledef);
 }

@@ -2289,7 +2289,7 @@ class BatchScheduler(Scheduler):
                     in_flight_at_pack = self._pending_exists()
                     self.cache.update_snapshot(snapshot)
                     refresh.set_metadata(
-                        nodes_refreshed=snapshot.last_refreshed,
+                        **snapshot.refresh_stats(),
                         nodes=len(snapshot.node_info_list),
                     )
 
@@ -2364,7 +2364,7 @@ class BatchScheduler(Scheduler):
                 # threads did under the GIL meanwhile: its own work is
                 # the rows it repacked and the span's ``cpu_ms``
                 nt = self.tensor_cache.update(snapshot)
-                state.set_metadata(rows=int(nt.delta.changed_rows.size))
+                state.set_metadata(**nt.delta.row_stats())
             with flightrecorder.stage(
                 "pack.pods", totals=totals, batch=batch_id
             ) as packed_pods:

@@ -95,6 +95,41 @@ def _node_ints(ni: NodeInfo) -> Tuple[int, ...]:
     )
 
 
+def _node_rows_gather_py(
+    infos: List[NodeInfo], rows: List[int], generations: List[int],
+    row_node: List, row_alloc: List, row_csi: List, ints: np.ndarray,
+) -> Tuple[List[int], List[int], List[int]]:
+    """Pure-Python twin of native ``node_rows_gather`` (identical
+    semantics; tests/test_native_refresh.py runs the two on the same
+    inputs): ``ints[k]`` takes ``_node_ints(infos[k])``; returned are
+    the positions ``k`` whose node, allocatable or csi_volume_limits is
+    not the object slot ``rows[k]`` was last packed from (those take
+    the whole row, the others their requested columns alone), the
+    positions whose ``requested.scalar`` or ``volume_in_use`` holds a
+    name, and the positions the caller has no row to pack for: a
+    NodeInfo with no node object, or one at the generation its slot
+    holds. Nothing but ``ints`` is written."""
+    if infos:
+        ints[: len(infos)] = np.array(
+            [_node_ints(ni) for ni in infos], dtype=np.int32
+        )
+    full: List[int] = []
+    extras: List[int] = []
+    odd: List[int] = []
+    for k, (i, ni) in enumerate(zip(rows, infos)):
+        if (
+            ni.node is not row_node[i]
+            or ni.allocatable is not row_alloc[i]
+            or ni.csi_volume_limits is not row_csi[i]
+        ):
+            full.append(k)
+        if ni.requested.scalar or ni.volume_in_use:
+            extras.append(k)
+        if ni.node is None or ni.generation == generations[i]:
+            odd.append(k)
+    return full, extras, odd
+
+
 def _kib_floor(b: int) -> int:
     return b // 1024
 
@@ -233,6 +268,17 @@ class TensorDelta:
     membership_rows: np.ndarray = field(
         default_factory=lambda: np.zeros(0, dtype=np.int64)
     )
+    #: how many of ``changed_rows`` had their requested columns alone
+    #: written: rows whose node object, allocatable and volume limits
+    #: were the ones the slot was last packed from
+    pods_only_rows: int = 0
+
+    def row_stats(self) -> Dict[str, int]:
+        """This update as the ``sched/pack.state`` span says it."""
+        return {
+            "rows": int(self.changed_rows.size),
+            "rows_pods_only": self.pods_only_rows,
+        }
 
 
 @dataclass
@@ -314,6 +360,14 @@ class NodeTensorCache:
         self.topology = topology_encoder or TopologyEncoder()
         self._row_of: Dict[str, int] = {}
         self._generations: List[int] = []
+        # what each slot's fixed columns (allocatable, topology, volume
+        # limits) were last packed from: the cache replaces these three
+        # objects of a NodeInfo and never writes into them, so a
+        # NodeInfo that still holds all three moved in its pods alone
+        self._row_node: List[Optional[object]] = []
+        self._row_alloc: List[Optional[object]] = []
+        self._row_csi: List[Optional[object]] = []
+        self._pods_only = 0  # rows of this update packed as pods-only
         self._names: List[str] = []  # slot -> name, "" = free slot
         self._free_rows: List[int] = []  # min-heap of retired slots
         self._node_count = 0
@@ -351,51 +405,101 @@ class NodeTensorCache:
 
     # -- packing rows --------------------------------------------------------
 
-    def _pack_rows(self, rows: List[int], infos: List[NodeInfo]) -> None:
+    def _gather_rows(
+        self, rows: List[int], infos: List[NodeInfo]
+    ) -> Tuple[np.ndarray, List[int], List[int], List[int]]:
+        """``(ints, full, extras, odd)`` of ``infos`` bound for the
+        slots ``rows`` (``_node_rows_gather_py`` has the meaning): one
+        native loop, or its twin where the extension did not build."""
+        ints = np.empty((len(infos), 10), dtype=np.int32)
+        slots = (
+            self._generations, self._row_node, self._row_alloc,
+            self._row_csi,
+        )
+        fn, expected = _native.ingest_fn("node_rows_gather")
+        if fn is not None:
+            return (ints, *fn(infos, rows, *slots, ints))
+        if expected:
+            _metrics.ingest_native_fallbacks.inc(site="node-gather")
+        return (ints, *_node_rows_gather_py(infos, rows, *slots, ints))
+
+    def _pack_rows(
+        self, rows: List[int], infos: List[NodeInfo], gathered=None
+    ) -> None:
         """Encode ``infos`` into the slots ``rows`` (distinct): the
-        integers are gathered into one list and each array is written
-        once, whatever the number of rows."""
+        integers are gathered into one array (``gathered``, taken here
+        unless the caller has) and each array is written once, whatever
+        the number of rows. A row whose NodeInfo holds the node object,
+        the allocatable and the volume limits its slot was last packed
+        from is a pods-only row: its requested, non-zero requested,
+        generation and epoch are written, and its allocatable, topology
+        and volume limits stand as they are."""
         n = len(rows)
         if not n:
             return
         dims = self.dims
         at = np.asarray(rows, dtype=np.int64)
-        ints = np.array([_node_ints(ni) for ni in infos], dtype=np.int32)
-        alloc = np.zeros((n, dims.num_dims), dtype=np.int32)
+        ints, full, extras, _odd = gathered or self._gather_rows(rows, infos)
         req = np.zeros((n, dims.num_dims), dtype=np.int32)
-        alloc[:, :NUM_FIXED_DIMS] = ints[:, :4]
         req[:, :NUM_FIXED_DIMS] = ints[:, 4:8]
         vol_cols = dims.volume_columns()
-        for k, ni in enumerate(infos):
+        for k in extras:
+            ni = infos[k]
+            for name, qty in ni.requested.scalar.items():
+                req[k, dims.column(name)] = qty
+            # attachable-volume columns: requested = additive in-use
+            # count from resident pods (cache/node_info.py). Volume-free
+            # pods skip these dims in the fit scan (zero request).
+            viu = ni.volume_in_use
+            for name, col in vol_cols.items():
+                req[k, col] = viu.get(name, 0)
+        self._req[at] = req
+        self._nzr[at] = ints[:, 8:]
+        if full:
+            self._pack_fixed_parts(
+                [rows[k] for k in full], [infos[k] for k in full],
+                ints[full, :NUM_FIXED_DIMS],
+            )
+        self._pods_only += n - len(full)
+        generations = self._generations
+        for i, ni in zip(rows, infos):
+            generations[i] = ni.generation
+        self._occupied[at] = True
+        self._row_epoch[at] = self._epoch
+
+    def _pack_fixed_parts(
+        self, rows: List[int], infos: List[NodeInfo], ints: np.ndarray
+    ) -> None:
+        """The columns a pod event cannot move, of the rows whose node
+        object, allocatable or volume limits are new to their slot:
+        allocatable (``ints`` its fixed columns), topology, and the
+        three references the next pack compares."""
+        dims = self.dims
+        at = np.asarray(rows, dtype=np.int64)
+        alloc = np.zeros((len(rows), dims.num_dims), dtype=np.int32)
+        alloc[:, :NUM_FIXED_DIMS] = ints
+        vol_cols = dims.volume_columns()
+        row_node, row_alloc, row_csi = (
+            self._row_node, self._row_alloc, self._row_csi
+        )
+        for k, (i, ni) in enumerate(zip(rows, infos)):
             if ni.allocatable.scalar:
                 for name, qty in ni.allocatable.scalar.items():
                     alloc[k, dims.column(name)] = qty
-            if ni.requested.scalar:
-                for name, qty in ni.requested.scalar.items():
-                    req[k, dims.column(name)] = qty
-            if vol_cols:
-                # attachable-volume columns: allocatable = CSINode limit /
-                # in-tree default / unlimited; requested = additive in-use
-                # count from resident pods (cache/node_info.py). Volume-free
-                # pods skip these dims in the fit scan (zero request).
-                viu = ni.volume_in_use
-                for name, col in vol_cols.items():
-                    alloc[k, col] = ni.volume_limit(name)
-                    req[k, col] = viu.get(name, 0)
+            # attachable-volume columns: allocatable = CSINode limit /
+            # in-tree default / unlimited
+            for name, col in vol_cols.items():
+                alloc[k, col] = ni.volume_limit(name)
+            row_node[i] = ni.node
+            row_alloc[i] = ni.allocatable
+            row_csi[i] = ni.csi_volume_limits
         self._alloc[at] = alloc
-        self._req[at] = req
-        self._nzr[at] = ints[:, 8:]
         if self.topology.keys:
             encode = self.topology.encode_node_labels
             self._topo[at] = [
                 encode(ni.node.metadata.labels if ni.node else {})
                 for ni in infos
             ]
-        generations = self._generations
-        for i, ni in zip(rows, infos):
-            generations[i] = ni.generation
-        self._occupied[at] = True
-        self._row_epoch[at] = self._epoch
 
     def _pack_row(self, i: int, ni: NodeInfo) -> None:
         self._pack_rows([i], [ni])
@@ -425,6 +529,7 @@ class NodeTensorCache:
         if self._topo.shape[1]:
             self._topo[i] = 0
         self._generations[i] = 0
+        self._row_node[i] = self._row_alloc[i] = self._row_csi[i] = None
         self._occupied[i] = False
         self._row_epoch[i] = self._epoch
         self._row_member_epoch[i] = self._epoch
@@ -443,6 +548,9 @@ class NodeTensorCache:
             return None
         self._names.append("")
         self._generations.append(0)
+        self._row_node.append(None)
+        self._row_alloc.append(None)
+        self._row_csi.append(None)
         return i
 
     # -- epoch handshake support --------------------------------------------
@@ -473,19 +581,23 @@ class NodeTensorCache:
             self._row_member_epoch[: len(self._names)] > epoch
         )
 
-    def _register_columns(self, ni: NodeInfo) -> None:
+    def _register_columns(self, ni: NodeInfo, pods_only=False) -> None:
+        """Give every resource name ``ni`` holds a column; with
+        ``pods_only`` those of the parts a pod event moves alone."""
         if not (
             ni.allocatable.scalar or ni.requested.scalar
             or ni.csi_volume_limits or ni.volume_in_use
         ):
             return  # the common node: fixed columns only
         dims = self.dims
-        for name in ni.allocatable.scalar:
-            dims.column(name)
+        if not pods_only:
+            for name in ni.allocatable.scalar:
+                dims.column(name)
         for name in ni.requested.scalar:
             dims.column(name)
-        for name in ni.csi_volume_limits:
-            dims.volume_column(name)
+        if not pods_only:
+            for name in ni.csi_volume_limits:
+                dims.volume_column(name)
         for name in ni.volume_in_use:
             dims.volume_column(name)
 
@@ -526,6 +638,7 @@ class NodeTensorCache:
         a free or headroom slot). Foreign snapshots (tests, tools) take
         the full generation walk -- same result, O(N) int compares."""
         self._epoch += 1
+        self._pods_only = 0
         tracked = None
         membership_hint = True
         if snapshot is self._last_snapshot:
@@ -559,38 +672,44 @@ class NodeTensorCache:
         compared/repacked. Returns None when the notes turn out to need
         the full walk (unknown name, node-object transition, schema or
         topology growth)."""
-        changed_infos = []
-        row_of = self._row_of
-        info_map = snapshot.node_info_map
-        for name in tracked:
-            i = row_of.get(name)
-            ni = info_map.get(name)
-            if i is None or ni is None or ni.node is None:
+        names = list(tracked)
+        rows = list(map(self._row_of.get, names))
+        moved = list(map(snapshot.node_info_map.get, names))
+        if None in rows or None in moved:
+            return None  # membership drift the hint missed
+        ints, full, extras, odd = self._gather_rows(rows, moved)
+        if odd:
+            if any(moved[k].node is None for k in odd):
                 return None  # membership drift the hint missed
-            changed_infos.append((i, ni))
-        for _i, ni in changed_infos:
-            self._register_columns(ni)
+            # a row at its NodeInfo's generation was packed from it
+            keep = sorted(set(range(len(rows))).difference(odd))
+            place = {k: at for at, k in enumerate(keep)}
+            rows = [rows[k] for k in keep]
+            moved = [moved[k] for k in keep]
+            ints = ints[keep]
+            full = [place[k] for k in full if k in place]
+            extras = [place[k] for k in extras if k in place]
+        gathered = (ints, full, extras, [])
+        for k in full:
+            self._register_columns(moved[k])
+        for k in extras:
+            # a pods-only row can still bring a name the dims do not
+            # know: a pod's extended resource or volume type
+            self._register_columns(moved[k], pods_only=True)
         if (
             self.dims.version != self._dims_version
             or self.topology.version != self._topo_version
         ):
             return None  # schema grew: full repack
-        generations = self._generations
-        moved = [
-            pair for pair in changed_infos
-            if generations[pair[0]] != pair[1].generation
-        ]
-        changed_rows = [i for i, _ni in moved]
-        self._pack_rows(changed_rows, [ni for _i, ni in moved])
-        self.rows_repacked += len(moved)
+        self._pack_rows(rows, moved, gathered)
+        self.rows_repacked += len(rows)
         return self._build_tensor(
             TensorDelta(
                 epoch=self._epoch,
                 layout_epoch=self._layout_epoch,
-                changed_rows=np.sort(
-                    np.asarray(changed_rows, dtype=np.int64)
-                ),
+                changed_rows=np.sort(np.asarray(rows, dtype=np.int64)),
                 full=False,
+                pods_only_rows=self._pods_only,
             ),
         )
 
@@ -631,6 +750,9 @@ class NodeTensorCache:
             self._names = list(names_now)
             self._row_of = {n: i for i, n in enumerate(names_now)}
             self._generations = [0] * len(infos)
+            self._row_node = [None] * len(infos)
+            self._row_alloc = [None] * len(infos)
+            self._row_csi = [None] * len(infos)
             self._free_rows = []
             self._node_count = len(infos)
             self._grow(len(infos))
@@ -716,6 +838,7 @@ class NodeTensorCache:
                 membership_rows=np.asarray(
                     sorted(member_rows), dtype=np.int64
                 ),
+                pods_only_rows=self._pods_only,
             ),
         )
 

@@ -158,7 +158,7 @@ def pack_row_twin(tc: NodeTensorCache, i: int, ni) -> None:
 
 
 class RowByRowTensorCache(NodeTensorCache):
-    def _pack_rows(self, rows, infos):
+    def _pack_rows(self, rows, infos, gathered=None):
         for i, ni in zip(rows, infos):
             pack_row_twin(self, i, ni)
 
@@ -918,8 +918,8 @@ def test_incremental_refresh_equals_the_full_walk(seed):
     follower = NodeTensorCache()
     walks = []
     real_walk = churn.cache._update_snapshot_full
-    churn.cache._update_snapshot_full = lambda snapshot: (
-        walks.append(snapshot), real_walk(snapshot)
+    churn.cache._update_snapshot_full = lambda snapshot, made: (
+        walks.append(snapshot), real_walk(snapshot, made)
     )
     for step in range(150):
         for _ in range(rng.randrange(1, 4)):
@@ -948,7 +948,14 @@ def test_incremental_refresh_equals_the_full_walk(seed):
     assert 0 < len(walks) < 150
 
 
-def test_a_quiet_refresh_visits_no_node_and_a_busy_one_only_the_changed():
+@pytest.mark.parametrize("native_walk", ["native", "twin"])
+def test_a_quiet_refresh_visits_no_node_and_a_busy_one_only_the_changed(
+    native_walk, monkeypatch
+):
+    """The twin's refresh clones through ``NodeInfo.clone``, which the
+    test counts; the native loop's is told by which entries are new."""
+    if native_walk == "twin":
+        monkeypatch.setenv("KTPU_NATIVE_INGEST", "0")
     churn = Churn(11, nodes=40)
     snap = Snapshot()
     churn.cache.update_snapshot(snap)
@@ -974,7 +981,13 @@ def test_a_quiet_refresh_visits_no_node_and_a_busy_one_only_the_changed():
         churn.cache.update_snapshot(snap)
     finally:
         type(before[0]).clone = real_clone
-    assert sorted(visited) == names and snap.last_refreshed == 3
+    if native_walk == "twin":
+        assert sorted(visited) == names
+    assert snap.last_refreshed == 3 and snap.last_shared == 3
+    assert sorted(
+        new.node_name
+        for old, new in zip(before, snap.node_info_list) if old is not new
+    ) == names
     # the list a reader held is not written under it
     assert [ni.requested.milli_cpu for ni in before] == [0] * 40
     assert sum(ni.requested.milli_cpu for ni in snap.node_info_list) == 3000

@@ -1189,6 +1189,55 @@ def test_pack_says_whether_the_node_epoch_moved_and_the_solve_its_member_rows(
     assert sched.membership_row_patches == 2
 
 
+def test_pack_says_which_nodes_and_rows_moved_in_their_pods_alone(tmp_path):
+    """``nodes_shared`` beside ``nodes_refreshed`` on
+    ``sched/pack.snapshot``: the clones that hold the node object, the
+    allocatable, the images and the volume limits of the NodeInfo they
+    took the place of. ``rows_pods_only`` beside ``rows`` on
+    ``sched/pack.state``: the rows whose requested columns alone were
+    written. Binds move pods and nothing else; a node write makes that
+    node's clone and row whole ones, and no other's."""
+    server, client, informers, sched = _stack(num_nodes=8)
+    sched.start()
+    try:
+        with profiled(tmp_path) as events:
+            _burst(client, sched, 12, tag="a")  # the first pack: all whole
+            _burst(client, sched, 12, tag="b")  # binds alone since
+            _report_status(server, "node-1")
+            _wait_for(lambda: sched.stage_totals.calls()["node_event"] == 9,
+                      "the status report was not seen")
+            _burst(client, sched, 12, tag="c")  # node-1 written, and binds
+            _burst(client, sched, 12, tag="d")  # binds alone again
+            sched._drain_pending()
+    finally:
+        sched.stop()
+        informers.stop()
+    refreshes = sorted(named(events, "sched/pack.snapshot"),
+                       key=lambda ev: ev["start"])
+    states = sorted(named(events, "sched/pack.state"),
+                    key=lambda ev: ev["start"])
+    assert len(refreshes) == len(states) >= 4
+    for refresh, state in zip(refreshes, states):
+        assert {"nodes_refreshed", "nodes_shared", "nodes"} <= set(
+            refresh["stats"])
+        assert own_stats(state).keys() == {"batch", "rows", "rows_pods_only"}
+        assert refresh["stats"]["nodes_shared"] <= (
+            refresh["stats"]["nodes_refreshed"])
+        assert state["stats"]["rows_pods_only"] <= state["stats"]["rows"]
+    # the first pack writes every row whole: no slot has held a node yet
+    assert states[0]["stats"]["rows"] == 8
+    assert states[0]["stats"]["rows_pods_only"] == 0
+    whole_nodes = [ev["stats"]["nodes_refreshed"] - ev["stats"]["nodes_shared"]
+                   for ev in refreshes[1:]]
+    whole_rows = [ev["stats"]["rows"] - ev["stats"]["rows_pods_only"]
+                  for ev in states[1:]]
+    # exactly the written node, once, in the batch after the write
+    assert sum(whole_nodes) == 1 and sum(whole_rows) == 1
+    assert whole_nodes.index(1) == whole_rows.index(1)
+    assert sum(ev["stats"]["rows"] for ev in states[1:]) > 1
+    assert sum(ev["stats"]["nodes_refreshed"] for ev in refreshes[1:]) > 1
+
+
 @pytest.mark.parametrize("image,live", [
     ("pause", 0),  # no node holds it
     ("registry.example/app:v2", 1),  # seven of the eight nodes do
