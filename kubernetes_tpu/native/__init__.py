@@ -128,8 +128,9 @@ assume_clones = getattr(hotpath, "assume_clones", None)
 bind_assumed_bulk = getattr(hotpath, "bind_assumed_bulk", None)
 commit_gather = getattr(hotpath, "commit_gather", None)
 
-# -- the ingest plane (see _hotpath.c "ingest spine") and the snapshot
-# -- refresh's two walks ("snapshot refresh spine") -----------------------
+# -- the ingest plane (see _hotpath.c "ingest spine"), the snapshot
+# -- refresh's two walks ("snapshot refresh spine") and what a bound pod
+# -- leaves behind -------------------------------------------------------
 #
 # Gated separately from the commit-path loops by KTPU_NATIVE_INGEST
 # (default on): =0 forces the pure-Python twins at every ingest call
@@ -143,6 +144,7 @@ _INGEST_FNS = {
         "ingest_decode", "ingest_apply", "ingest_stamp",
         "pack_gather", "queue_shape", "mirror_scatter",
         "node_info_clones", "node_rows_gather",
+        "p2_fold", "scheduled_events",
     )
 }
 

@@ -2178,6 +2178,287 @@ out:
     return ret;
 }
 
+/* -- what a bound pod leaves behind ---------------------------------------
+ *
+ * After the API transaction a bound pod leaves an observation in the
+ * pod-to-bind sketch and a Scheduled event: interpreter work a pod
+ * under the scheduler's one GIL, one call a batch here. p2_fold() is
+ * P2Quantile.observe (utils/quantiles.py) over a list of values, float
+ * for float; scheduled_events() is the body of EventBroadcaster.
+ * _emit_loop (utils/event_recorder.py) for the items scheduled_many
+ * enqueues, field for field. The public C API alone;
+ * tests/test_native_sketch.py and tests/test_events.py hold each to
+ * its twin.
+ */
+
+/* an estimator's heights, positions, desired positions, increments */
+typedef struct {
+    double v[4][5];
+} p2_t;
+
+/* the twin's doubles in the twin's order of operations, and on no host
+ * contracted to FMA (GCC's attribute; the pragma where it is honoured) */
+#if defined(__GNUC__) && !defined(__clang__)
+__attribute__((optimize("fp-contract=off")))
+#endif
+static void
+p2_observe(p2_t *s, double x)
+{
+#pragma STDC FP_CONTRACT OFF
+    double *h = s->v[0], *n = s->v[1], *want = s->v[2], *incr = s->v[3];
+    int k = 0;
+    /* locate the cell; extreme observations move the end markers */
+    if (x < h[0]) {
+        h[0] = x;
+    } else if (x >= h[4]) {
+        h[4] = x;
+        k = 3;
+    } else {
+        while (k < 3 && !(h[k] <= x && x < h[k + 1]))
+            k++;
+    }
+    for (int i = k + 1; i < 5; i++)
+        n[i] += 1.0;
+    for (int i = 0; i < 5; i++)
+        want[i] += incr[i];
+    /* adjust the three interior markers toward their desired spots */
+    for (int i = 1; i <= 3; i++) {
+        double d = want[i] - n[i];
+        if (!((d >= 1.0 && n[i + 1] - n[i] > 1.0) ||
+              (d <= -1.0 && n[i - 1] - n[i] < -1.0)))
+            continue;
+        double step = d >= 1.0 ? 1.0 : -1.0;
+        double cand = h[i] + step / (n[i + 1] - n[i - 1]) * (
+            (n[i] - n[i - 1] + step) * (h[i + 1] - h[i]) / (n[i + 1] - n[i])
+            + (n[i + 1] - n[i] - step) * (h[i] - h[i - 1]) / (n[i] - n[i - 1])
+        );
+        if (h[i - 1] < cand && cand < h[i + 1]) {
+            h[i] = cand;
+        } else {
+            int j = i + (int)step;
+            h[i] = h[i] + step * (h[j] - h[i]) / (n[j] - n[i]);
+        }
+        n[i] += step;
+    }
+}
+
+/* state: three lists of five markers, written back, and the five
+ * increments (a list or a tuple) */
+static int
+p2_read(PyObject *state, p2_t *s)
+{
+    if (!PyTuple_Check(state) || PyTuple_GET_SIZE(state) != 4)
+        goto bad;
+    for (int j = 0; j < 4; j++) {
+        PyObject *seq = PyTuple_GET_ITEM(state, j);
+        if (!(PyList_Check(seq) || (j == 3 && PyTuple_Check(seq))) ||
+            PySequence_Fast_GET_SIZE(seq) != 5)
+            goto bad;
+        for (int i = 0; i < 5; i++) {
+            s->v[j][i] = PyFloat_AsDouble(PySequence_Fast_GET_ITEM(seq, i));
+            if (s->v[j][i] == -1.0 && PyErr_Occurred())
+                return -1;
+        }
+    }
+    return 0;
+bad:
+    PyErr_SetString(PyExc_TypeError, "p2_fold: a state is three lists of "
+                    "five markers and their five increments");
+    return -1;
+}
+
+static PyObject *
+p2_fold(PyObject *self, PyObject *args)
+{
+    /* p2_fold(states, values, start) -> None
+     *
+     * states: a tuple of (heights, positions, desired, increments), one
+     * an estimator past its first five observations; each observes
+     * values[start:], in order. Everything is read before anything is
+     * written: a value that is no number leaves them as they were. */
+    PyObject *states, *values, *ret = NULL;
+    Py_ssize_t start;
+    if (!PyArg_ParseTuple(args, "O!O!n", &PyTuple_Type, &states,
+                          &PyList_Type, &values, &start))
+        return NULL;
+    Py_ssize_t m = PyTuple_GET_SIZE(states);
+    Py_ssize_t n = PyList_GET_SIZE(values) - start;
+    if (start < 0 || n < 0) {
+        PyErr_SetString(PyExc_ValueError, "p2_fold: start out of range");
+        return NULL;
+    }
+    double *xs = PyMem_Malloc((n + 1) * sizeof(double));
+    p2_t *est = PyMem_Malloc((m + 1) * sizeof(p2_t));
+    if (xs == NULL || est == NULL) {
+        PyErr_NoMemory();
+        goto done;
+    }
+    for (Py_ssize_t i = 0; i < n; i++) {
+        xs[i] = PyFloat_AsDouble(PyList_GET_ITEM(values, start + i));
+        if (xs[i] == -1.0 && PyErr_Occurred())
+            goto done;
+    }
+    for (Py_ssize_t e = 0; e < m; e++)
+        if (p2_read(PyTuple_GET_ITEM(states, e), &est[e]) < 0)
+            goto done;
+    for (Py_ssize_t e = 0; e < m; e++) {
+        for (Py_ssize_t i = 0; i < n; i++)
+            p2_observe(&est[e], xs[i]);
+        for (int j = 0; j < 3; j++) {
+            PyObject *markers = PyTuple_GET_ITEM(PyTuple_GET_ITEM(states, e), j);
+            for (int i = 0; i < 5; i++) {
+                PyObject *f = PyFloat_FromDouble(est[e].v[j][i]);
+                if (f == NULL || PyList_SetItem(markers, i, f) < 0)
+                    goto done; /* SetItem steals f */
+            }
+        }
+    }
+    ret = Py_NewRef(Py_None);
+done:
+    PyMem_Free(xs);
+    PyMem_Free(est);
+    return ret;
+}
+
+/* ("kind", "Scheduled", "Event", "", 0, 1, ()), made at module init */
+static PyObject *event_consts;
+enum { C_KIND, C_SCHEDULED, C_EVENT, C_EMPTY, C_ZERO, C_ONE, C_NO_ARGS };
+#define EVENT_CONST(i) PyTuple_GET_ITEM(event_consts, i)
+
+/* What tp.__new__(tp) gives with its fields ``names`` set to ``values``
+ * one PyObject_SetAttr each, in __init__'s order: laid out in memory,
+ * and seen by the cyclic collector, as the one __init__ makes. The
+ * values are borrowed; a NULL among them is the error it carries. */
+static PyObject *
+fielded(PyTypeObject *tp, PyObject *names, PyObject *values[])
+{
+    Py_ssize_t n = PyTuple_GET_SIZE(names);
+    for (Py_ssize_t j = 0; j < n; j++)
+        if (values[j] == NULL)
+            return NULL;
+    PyObject *new = tp->tp_new(tp, EVENT_CONST(C_NO_ARGS), NULL);
+    for (Py_ssize_t j = 0; new != NULL && j < n; j++)
+        if (PyObject_SetAttr(new, PyTuple_GET_ITEM(names, j), values[j]) < 0)
+            Py_CLEAR(new);
+    return new;
+}
+
+static PyObject *
+scheduled_events(PyObject *self, PyObject *args)
+{
+    /* scheduled_events(items, start, seq, now, aggregate, fresh,
+     *     ((Event, fields), (ObjectMeta, fields),
+     *      (ObjectReference, fields))) -> stop
+     *
+     * For items[start:stop], each (source, pod, type, "Scheduled",
+     * None) with a key (uid, reason, message) that ``aggregate`` does
+     * not hold, the Event the broadcaster's loop builds is appended to
+     * ``fresh`` (the k-th built is named "<pod>.<seq + k:x>") and its
+     * key stored. ``stop`` is the first item that is not such a one
+     * (another reason, a message, a repeat of a stored key): the
+     * caller's loop takes it. ``now`` is every event's first_timestamp
+     * and its metadata's creation_timestamp. ``fields`` are a type's
+     * own, in its __init__'s order (dataclasses.fields): the values
+     * below are theirs by position, so a type that gained or lost a
+     * field is an error here and not an event without it. */
+    PyObject *items, *now, *aggregate, *fresh, *names[3], *none = Py_None;
+    PyTypeObject *tp[3];
+    Py_ssize_t at;
+    unsigned long long seq;
+    if (!PyArg_ParseTuple(args, "O!nKOO!O!((O!O!)(O!O!)(O!O!))",
+                          &PyList_Type, &items, &at, &seq, &now,
+                          &PyDict_Type, &aggregate, &PyList_Type, &fresh,
+                          &PyType_Type, &tp[0], &PyTuple_Type, &names[0],
+                          &PyType_Type, &tp[1], &PyTuple_Type, &names[1],
+                          &PyType_Type, &tp[2], &PyTuple_Type, &names[2]))
+        return NULL;
+    if (at < 0 || PyTuple_GET_SIZE(names[0]) != 9 ||
+        PyTuple_GET_SIZE(names[1]) != 9 || PyTuple_GET_SIZE(names[2]) != 4) {
+        PyErr_SetString(PyExc_ValueError, "scheduled_events: start < 0, or "
+                        "the types' fields are not the ones built here");
+        return NULL;
+    }
+    enum { T_META, T_SPEC, T_NS, T_NAME, T_UID, T_HOST, T_MESSAGE, T_KEY,
+           T_EVENT_NAME, T_KIND, T_LABELS, T_ANNOTATIONS, T_OWNERS,
+           T_OBJECT_META, T_REFERENCE, T_EVENT, T_STORED, T_N };
+    PyObject *t[T_N] = {NULL};
+    int failed = 0;
+    for (; !failed && at < PyList_GET_SIZE(items); at++) {
+        PyObject *item = PyList_GET_ITEM(items, at);
+        if (!PyTuple_Check(item) || PyTuple_GET_SIZE(item) != 5 ||
+            PyTuple_GET_ITEM(item, 4) != none)
+            break;
+        PyObject *pod = PyTuple_GET_ITEM(item, 1);
+        PyObject *reason = PyTuple_GET_ITEM(item, 3);
+        int mine = PyObject_RichCompareBool(reason, EVENT_CONST(C_SCHEDULED),
+                                            Py_EQ);
+        if (mine <= 0) {
+            failed = mine < 0;
+            break;
+        }
+        failed = 1; /* until the event is stored */
+        int repeat = 0;
+        if ((t[T_META] = PyObject_GetAttr(pod, str_metadata)) == NULL ||
+            (t[T_SPEC] = PyObject_GetAttr(pod, str_spec)) == NULL ||
+            (t[T_NS] = PyObject_GetAttr(t[T_META], str_namespace)) == NULL ||
+            (t[T_NAME] = PyObject_GetAttr(t[T_META], str_name)) == NULL ||
+            (t[T_UID] = PyObject_GetAttr(t[T_META], str_uid)) == NULL ||
+            (t[T_HOST] = PyObject_GetAttr(t[T_SPEC], str_node_name)) == NULL ||
+            (t[T_MESSAGE] = PyUnicode_FromFormat(
+                 "Successfully assigned %S/%S to %S", t[T_NS], t[T_NAME],
+                 t[T_HOST])) == NULL ||
+            (t[T_KEY] = PyTuple_Pack(3, t[T_UID], reason,
+                                     t[T_MESSAGE])) == NULL)
+            goto next;
+        if (PyDict_GetItemWithError(aggregate, t[T_KEY]) != NULL) {
+            repeat = 1; /* bumps the stored event's count: the caller's */
+            failed = 0;
+            goto next;
+        }
+        if (PyErr_Occurred())
+            goto next;
+        t[T_KIND] = PyObject_GetAttr(pod, EVENT_CONST(C_KIND));
+        if (t[T_KIND] == NULL) { /* getattr(obj, "kind", "") */
+            if (!PyErr_ExceptionMatches(PyExc_AttributeError))
+                goto next;
+            PyErr_Clear();
+            t[T_KIND] = Py_NewRef(EVENT_CONST(C_EMPTY));
+        }
+        t[T_EVENT_NAME] = PyUnicode_FromFormat("%S.%llx", t[T_NAME], seq + 1);
+        t[T_LABELS] = PyDict_New();
+        t[T_ANNOTATIONS] = PyDict_New();
+        t[T_OWNERS] = PyList_New(0);
+        /* name, namespace, uid, labels, annotations, resource_version,
+         * creation_timestamp, owner_references, deletion_timestamp */
+        t[T_OBJECT_META] = fielded(tp[1], names[1], (PyObject *[]){
+            t[T_EVENT_NAME], t[T_NS], EVENT_CONST(C_EMPTY), t[T_LABELS],
+            t[T_ANNOTATIONS], EVENT_CONST(C_ZERO), now, t[T_OWNERS], none});
+        /* kind, namespace, name, uid */
+        t[T_REFERENCE] = fielded(tp[2], names[2], (PyObject *[]){
+            t[T_KIND], t[T_NS], t[T_NAME], t[T_UID]});
+        /* metadata, involved_object, reason, message, type, source,
+         * count, first_timestamp, kind */
+        t[T_EVENT] = fielded(tp[0], names[0], (PyObject *[]){
+            t[T_OBJECT_META], t[T_REFERENCE], reason, t[T_MESSAGE],
+            PyTuple_GET_ITEM(item, 2), PyTuple_GET_ITEM(item, 0),
+            EVENT_CONST(C_ONE), now, EVENT_CONST(C_EVENT)});
+        if (t[T_EVENT] == NULL ||
+            (t[T_STORED] = PyTuple_Pack(2, t[T_NS],
+                                        t[T_EVENT_NAME])) == NULL ||
+            PyList_Append(fresh, t[T_EVENT]) < 0 ||
+            PyDict_SetItem(aggregate, t[T_KEY], t[T_STORED]) < 0)
+            goto next;
+        seq++;
+        failed = 0;
+    next:
+        for (int j = 0; j < T_N; j++)
+            Py_CLEAR(t[j]);
+        if (repeat)
+            break;
+    }
+    return failed ? NULL : PyLong_FromSsize_t(at);
+}
+
 static PyMethodDef methods[] = {
     {"match_compiled", match_compiled, METH_VARARGS,
      "match_compiled(labels, compiled) -> bool"},
@@ -2217,6 +2498,12 @@ static PyMethodDef methods[] = {
     {"node_rows_gather", node_rows_gather, METH_VARARGS,
      "node_rows_gather(infos, rows, generations, row_node, row_alloc, "
      "row_csi, ints) -> (full, extras, odd)"},
+    {"p2_fold", p2_fold, METH_VARARGS,
+     "p2_fold(states, values, start) -> None"},
+    {"scheduled_events", scheduled_events, METH_VARARGS,
+     "scheduled_events(items, start, seq, now, aggregate, fresh, "
+     "((Event, fields), (ObjectMeta, fields), (ObjectReference, fields)))"
+     " -> stop"},
     {NULL, NULL, 0, NULL},
 };
 
@@ -2317,6 +2604,10 @@ PyInit__hotpath(void)
         str_ephemeral_storage == NULL ||
         str_allowed_pod_number == NULL ||
         str_scalar == NULL)
+        return NULL;
+    event_consts = Py_BuildValue("(ssssii())", "kind", "Scheduled", "Event",
+                                 "", 0, 1);
+    if (event_consts == NULL)
         return NULL;
     return PyModule_Create(&moduledef);
 }

@@ -960,6 +960,9 @@ def new_scheduler(
 
         sched.preemptor.ladder = SolverLadder(sched.ladder.config)
         sched.preemptor.stage_totals = sched.stage_totals
+        if broadcaster is not None:
+            # a frame of events is a stage among the scheduler's
+            broadcaster.stage_totals = sched.stage_totals
     sched.event_broadcaster = broadcaster
     # the bind-ack ledger must exist BEFORE handler registration: the
     # eventhandlers capture it once and feed it the Running-ack frames

@@ -13,15 +13,25 @@ instead of writing a new object (events_cache aggregation).
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, Optional, Tuple
 
+from kubernetes_tpu import native as _native
 from kubernetes_tpu.api.types import Event, ObjectMeta, ObjectReference
+from kubernetes_tpu.utils import flightrecorder, metrics
 
 logger = logging.getLogger(__name__)
+
+#: the three types a Scheduled event is made of, each with its own
+#: fields in its __init__'s order: the batch build sets them by position
+_EVENT_SHAPE = tuple(
+    (tp, tuple(f.name for f in dataclasses.fields(tp)))
+    for tp in (Event, ObjectMeta, ObjectReference)
+)
 
 
 class EventBroadcaster:
@@ -35,6 +45,10 @@ class EventBroadcaster:
         self._seq = 0
         # (involved uid, reason, message) -> stored event key
         self._aggregate: Dict[Tuple, Tuple[str, str]] = {}
+        #: where a frame's ``events`` stage is totalled: the
+        #: broadcaster's own, or the scheduler's once it is handed one
+        #: (scheduler.new_scheduler), as the preemptor's are
+        self.stage_totals = flightrecorder.StageTotals()
         self._thread = threading.Thread(
             target=self._run, name="event-broadcaster", daemon=True
         )
@@ -60,6 +74,7 @@ class EventBroadcaster:
     COALESCE_SECONDS = 0.2
 
     def _run(self) -> None:
+        flightrecorder.name_thread()  # its line of a trace
         while True:
             with self._cond:
                 while not self._q and not self._stop:
@@ -86,13 +101,54 @@ class EventBroadcaster:
         bumps ride per-object updates (rare). ObjectReference/Event
         construction happens HERE, off the scheduling threads, and event
         metadata skips uid generation (events are never referenced by
-        uid)."""
-        fresh = []
-        now = time.time()
-        for item in items:
-            source, obj, event_type, reason, message = item
+        uid).
+
+        A frame is a stage of its own (``sched/events``, on this
+        thread's line of a trace). The items ``scheduled_many`` enqueued
+        are built by one native call a run of them
+        (``scheduled_events``: field for field what ``_emit_loop``
+        builds, tests/test_events.py); every other item, and every item
+        where the extension did not build, takes ``_emit_loop``. Events
+        are stored in the frame's order either way."""
+        with flightrecorder.stage(
+            "events", totals=self.stage_totals, events=len(items)
+        ) as emitting:
+            fresh: list = []
+            now = time.time()
+            build, expected = _native.ingest_fn("scheduled_events")
+            if build is None and expected:
+                metrics.ingest_native_fallbacks.inc(site="scheduled-events")
+            at, scheduled = 0, 0
+            while at < len(items):
+                if build is not None:
+                    before = len(fresh)
+                    at = build(
+                        items, at, self._seq, now, self._aggregate, fresh,
+                        _EVENT_SHAPE,
+                    )
+                    built = len(fresh) - before
+                    self._seq += built
+                    scheduled += built
+                at = self._emit_loop(items, at, now, fresh, build is not None)
+            emitting.set_metadata(scheduled=scheduled)
+            if fresh:
+                self._server.create_bulk(fresh)
+            if len(self._aggregate) > 10_000:
+                # bounded memory, like cache eviction
+                self._aggregate.clear()
+
+    def _emit_loop(self, items, start, now, fresh, batch_built) -> int:
+        """The frame's items from ``start`` on, one at a time: each adds
+        its Event to ``fresh`` or bumps the stored one's count. With
+        ``batch_built`` it stops at the first Scheduled item after
+        ``start`` that ``scheduled_many`` enqueued, which the batch
+        build takes from there; it returns where it stopped."""
+        for at in range(start, len(items)):
+            source, obj, event_type, reason, message = items[at]
             meta = obj.metadata
             if message is None and reason == "Scheduled":
+                if batch_built and at > start:
+                    return at
                 # deferred formatting: the commit hot path enqueues the
                 # bare (pod, host) and the message f-string renders HERE,
                 # off the scheduling threads (host rides spec.node_name)
@@ -117,7 +173,8 @@ class EventBroadcaster:
             fresh.append(
                 Event(
                     metadata=ObjectMeta(
-                        name=name, namespace=meta.namespace, uid=""
+                        name=name, namespace=meta.namespace, uid="",
+                        creation_timestamp=now,
                     ),
                     involved_object=ObjectReference(
                         kind=getattr(obj, "kind", ""),
@@ -134,10 +191,7 @@ class EventBroadcaster:
                 )
             )
             self._aggregate[key] = (meta.namespace, name)
-        if fresh:
-            self._server.create_bulk(fresh)
-        if len(self._aggregate) > 10_000:
-            self._aggregate.clear()  # bounded memory, like cache eviction
+        return len(items)
 
     def flush(self, timeout: float = 5.0) -> None:
         """Block until the queue drains (tests / shutdown)."""

@@ -479,13 +479,16 @@ class TestTraceOverheadGuard:
             len(flightrecorder.RECORDER.dump()["marks"]) - marks_before
         )
         calls = sched.stage_totals.calls()
-        # one stage each: an informer frame, a collection, and (set-up's
-        # node adds) a call of a node handler
+        # one stage each: an informer frame, a collection, (set-up's
+        # node adds) a call of a node handler, and a frame of the
+        # broadcaster's events
         n_frames = (calls["ingest"] + calls.get("gc", 0)
-                    + calls.get("node_event", 0))
+                    + calls.get("node_event", 0) + calls.get("events", 0))
         assert n_spans > 0 and hot_s > 0 and n_frames > 0
         # every stage a batch passed through is among those costed
-        assert set(calls) - {"ingest", "gc", "node_event"} <= set(BATCH_STAGES)
+        assert set(calls) - {"ingest", "gc", "node_event", "events"} <= set(
+            BATCH_STAGES
+        )
 
         rec = flightrecorder.FlightRecorder()
         links = [(f"uid-{i}", 0.001, 1) for i in range(256)]

@@ -325,6 +325,12 @@ def burst_trace(tmp_path):
     try:
         with profiled(tmp_path) as events:
             _burst(client, sched, 120)
+            # the broadcaster emits a frame 0.2 s after its first bind:
+            # every pod's event is stored before the session ends
+            deadline = time.time() + 60
+            while len(client.list_events()[0]) < 120:
+                assert time.time() < deadline, "the events never came"
+                time.sleep(0.02)
             # a kubelet's status report: the node handlers' stage has a
             # total since set-up's node adds, so it has a span here too
             _report_status(server, "node-0")
@@ -640,7 +646,7 @@ def test_stage_seconds_keeps_its_keys_and_gains_the_new(burst_trace):
     old = {"pop_batch", "pop_wait", "pack", "device_solve", "download",
            "commit"}
     new = {"ingest", "bind", "bind.api", "gc", "pack.state", "pack.pods",
-           "pack.masks", "pack.snapshot", "pack.families"}
+           "pack.masks", "pack.snapshot", "pack.families", "events"}
     assert old | new <= set(seconds)
     assert "classify" not in seconds  # per pod: only under profile_stages
     assert all(v >= 0 for v in seconds.values())
@@ -803,14 +809,78 @@ def test_every_clocked_span_of_a_burst_carries_cpu_ms(burst_trace):
     )
 
 
+def test_a_frame_of_events_is_a_span_on_the_broadcasters_line(burst_trace):
+    """What follows a bulk bind off its thread: one ``sched/events`` span
+    a frame the broadcaster drained, with the frame's size, how many of
+    its items took the batch build, and the thread's CPU time."""
+    events, _dump, sched = burst_trace
+    frames = named(events, "sched/events")
+    assert len(frames) == sched.stage_totals.calls()["events"] >= 1
+    assert sum(ev["stats"]["events"] for ev in frames) == 120
+    from kubernetes_tpu import native
+
+    for ev in frames:
+        # the burst emits Scheduled events alone: every item of a frame
+        # is the batch build's where the extension built
+        assert ev["stats"]["scheduled"] == (
+            ev["stats"]["events"] if native.hotpath is not None else 0
+        )
+        wall_ms = (ev["end"] - ev["start"]) / 1e6
+        assert 0 <= ev["stats"]["cpu_ms"] <= wall_ms + TICK_MS
+        assert set(own_stats(ev)) == {"events", "scheduled"}
+    binds = named(events, "sched/bind")
+    assert min(ev["start"] for ev in frames) >= min(ev["end"] for ev in binds)
+    # a thread of its own: not the bind pool's, the committer's or the
+    # dispatcher's line
+    (line,) = {ev["line"] for ev in frames}
+    others = {ev["line"] for name in ("sched/bind", "sched/commit",
+                                      "sched/pack", "sched/ingest")
+              for ev in named(events, name)}
+    assert line not in others
+    assert sched.stage_seconds["events"] == pytest.approx(
+        sum(ev["end"] - ev["start"] for ev in frames) / 1e9, rel=0.05,
+        abs=2e-3,
+    )
+
+
+def test_without_a_session_a_frame_of_events_builds_no_annotation(
+    monkeypatch,
+):
+    """The primitive's rule holds on the broadcaster's thread: with no
+    session no annotation is built and no CPU clock is read; the always-on
+    total is still written."""
+    from kubernetes_tpu.utils.event_recorder import EventBroadcaster
+
+    def refuse(*_args, **_kwargs):
+        raise AssertionError("built for a session that does not run")
+
+    monkeypatch.setattr(flightrecorder, "_cpu_clock", refuse)
+    monkeypatch.setattr(flightrecorder, "TraceAnnotation", refuse)
+    server = APIServer()
+    broadcaster = EventBroadcaster(server)
+    broadcaster.stop()
+    pods = [make_pod(f"e-{i}").container(cpu="10m").obj() for i in range(9)]
+    broadcaster._emit_batch(
+        [("default-scheduler", pod, "Normal", "Scheduled", None)
+         for pod in pods]
+        + [("default-scheduler", pods[0], "Warning", "FailedScheduling",
+            "0/0 nodes are available")]
+    )
+    assert len(server.list("Event")[0]) == 10
+    assert broadcaster.stage_totals.calls() == {"events": 1}
+    assert broadcaster.stage_totals.seconds()["events"] > 0
+
+
 def test_the_stages_threads_carry_their_names_at_the_os(burst_trace):
     """The profiler names a trace's host line after the OS thread, so the
-    informer, dispatcher, committer and bind-pool threads take their
-    Python names there as they start (``flightrecorder.name_thread``)."""
+    informer, dispatcher, committer, bind-pool and broadcaster threads
+    take their Python names there as they start
+    (``flightrecorder.name_thread``)."""
     _events, _dump, _sched = burst_trace
     names = {p.read_text().strip()
              for p in pathlib.Path("/proc/self/task").glob("*/comm")}
-    assert {"scheduler", "batch-committer", "informer-Pod", "bind_0"} <= names
+    assert {"scheduler", "batch-committer", "informer-Pod", "bind_0",
+            "event-broadcast"} <= names
     assert "informer-Persis" in names  # of 15 bytes, which Linux keeps
 
 
