@@ -577,7 +577,7 @@ def phase_mesh(sizes: Sizes, rng, expect_tier: str = "pallas") -> dict:
             sched.mesh_solver_tier == "pallas",
             f"mesh: tier {sched.mesh_solver_tier!r}",
         )
-        carry = sched._dev.req_dev
+        carry = sched.device_state.req_dev
         check(carry is not None, "mesh: no resident carry after the burst")
         shards = carry.addressable_shards
         devices = {s.device for s in shards}

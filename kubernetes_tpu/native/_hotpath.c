@@ -1650,8 +1650,8 @@ mirror_scatter(PyObject *self, PyObject *args)
      * the int32 shadow expectation, replacing the committer's
      * fancy-index + two np.add.at passes. Every index is validated
      * BEFORE any buffer is mutated so a failure here can always fall
-     * back to the Python twin (scheduler/batch.py _mirror_scatter_py)
-     * without double-applying. Layout contract (all C-contiguous):
+     * back to the Python twin (scheduler/device_state.py
+     * _mirror_scatter_py) without double-applying. Layout contract (all C-contiguous):
      * a int32[b], req int32[b,r], nzr int32[b,2], req_shadow int32[n,r]
      * (writable), nzr_shadow int32[n,2] (writable), rows_out int64[b],
      * req_out int32[b,r], nzr_out int32[b,2]. */

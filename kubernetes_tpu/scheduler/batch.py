@@ -45,7 +45,6 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from kubernetes_tpu.api.types import Binding, POD_GROUP_LABEL, Pod
 from kubernetes_tpu.apiserver.server import Conflict as ApiConflict
@@ -54,6 +53,10 @@ from kubernetes_tpu.scheduler.admission import (
     Admission,
     classify_pod as _classify_pod,
     solver_unsupported_reason,
+)
+from kubernetes_tpu.scheduler.device_state import (
+    DeviceNodeState,
+    delta_slot_pieces,
 )
 from kubernetes_tpu.framework.interface import (
     CycleState,
@@ -66,7 +69,6 @@ from kubernetes_tpu.ops.assignment import (
     GreedyConfig,
     NO_NODE,
     UNMODELLED_RESOURCE_SCORE_PLUGINS,
-    apply_assignment_delta,
     greedy_assign_compact,
     mesh_shard_uses_kernel,
     sinkhorn_assign,
@@ -150,59 +152,6 @@ def _commit_gather_py(solver_infos, order, assigns, names):
         clones.append(assumed)
         hosts.append(host)
     return pis, clones, hosts
-
-
-def _mirror_scatter_py(assignments, b, req, nzr, req_shadow, nzr_shadow):
-    """Pure-Python twin of native mirror_scatter: compact the batch's
-    placed rows and scatter-add them into the shadow expectation.
-    Returns (rows [K] int64, req_rows [K, R], nzr_rows [K, 2]) or None
-    when nothing placed -- identical semantics to the C loop
-    (differentially tested in tests/test_native_mirror.py)."""
-    placed = assignments[:b] != NO_NODE
-    if not placed.any():
-        return None
-    rows_placed = assignments[:b][placed].astype(np.int64)
-    req_rows = req[:b][placed]
-    nzr_rows = nzr[:b][placed]
-    np.add.at(req_shadow, rows_placed, req_rows)
-    np.add.at(nzr_shadow, rows_placed, nzr_rows)
-    return rows_placed, req_rows, nzr_rows
-
-
-def _mirror_scatter(assignments, b, req, nzr, req_shadow, nzr_shadow):
-    """The bind-echo -> shadow-mirror hot loop: one C pass
-    (native/_hotpath.c mirror_scatter) over the batch's assignments
-    compacts the placed rows AND applies the scatter-add, replacing
-    three fancy-index materializations plus two np.add.at passes per
-    batch on the committer thread. The C side validates every index
-    BEFORE mutating, so a native failure can always fall back to the
-    twin without double-applying."""
-    from kubernetes_tpu import native as _native
-
-    fn, expected = _native.ingest_fn("mirror_scatter")
-    if fn is not None:
-        try:
-            a = np.ascontiguousarray(assignments[:b], dtype=np.int32)
-            req_b = np.ascontiguousarray(req[:b], dtype=np.int32)
-            nzr_b = np.ascontiguousarray(nzr[:b], dtype=np.int32)
-            rows_out = np.empty(b, dtype=np.int64)
-            req_out = np.empty((b, req_b.shape[1]), dtype=np.int32)
-            nzr_out = np.empty((b, 2), dtype=np.int32)
-            k = fn(
-                a, req_b, nzr_b, req_shadow, nzr_shadow,
-                rows_out, req_out, nzr_out,
-            )
-            if k == 0:
-                return None
-            return rows_out[:k], req_out[:k], nzr_out[:k]
-        except Exception:
-            logger.exception("native mirror_scatter failed")
-            metrics.ingest_native_fallbacks.inc(site="mirror-scatter")
-    elif expected:
-        metrics.ingest_native_fallbacks.inc(site="mirror-scatter")
-    return _mirror_scatter_py(
-        assignments, b, req, nzr, req_shadow, nzr_shadow
-    )
 
 
 def _gang_contiguous(order: np.ndarray, gangs) -> np.ndarray:
@@ -372,143 +321,11 @@ def solver_supported(pod: Pod) -> bool:
     return not solver_unsupported_reason(pod)
 
 
-#: padded row count of the (indices, rows) delta-scatter slot riding the
-#: steady-state upload buffer: one fixed bucket keeps the steady solve at
-#: ONE jit signature regardless of churn; more than this many changed
-#: rows per dispatch escalates to a (counted) full upload
-DELTA_ROW_BUCKET = 64
-#: per-batch expected-delta ring bound: the host can trail the device by
-#: at most the in-flight batches plus the mirror/assume window. Overflow
-#: drops the oldest delta, which at worst turns a later handshake into a
-#: counted divergence (full upload) -- never a silent mismatch.
+#: per-batch expected-delta ring bound of the device-resident state
+#: (scheduler/device_state.py), which is handed it: the host can trail the
+#: device by at most the in-flight batches plus the mirror/assume window
 _SHADOW_RING_CAP = MAX_INFLIGHT + 2
 
-
-def _delta_slot_pieces(
-    n_cap, r_dims, fix_rows=None, alloc_rows=None,
-    node_requested=None, node_nzr=None, allocatable=None, valid=None,
-):
-    """The fixed `DELTA_ROW_BUCKET`-sized (indices, rows) scatter slots
-    every steady-state dispatch carries in the single upload buffer.
-    Shapes/dtypes/padding here ARE the jit signature the warmup
-    precompiles -- the dispatch path and `_maybe_warm` must build them
-    through this one helper or they fork a second signature and the
-    first production batch pays the compile the warmup was built to
-    prevent. Empty slots carry index ``n_cap`` (out of bounds) and drop
-    on device.
-
-    ``svalid`` rides with the alloc scatter: membership churn retires /
-    claims row slots in place, so the patched rows must also flip the
-    device-resident valid mask (a retired slot with alloc zeroed is
-    still choosable by a zero-request pod unless valid drops)."""
-    didx = np.full(DELTA_ROW_BUCKET, n_cap, dtype=np.int32)
-    dreq = np.zeros((DELTA_ROW_BUCKET, r_dims), dtype=np.int32)
-    dnzr = np.zeros((DELTA_ROW_BUCKET, 2), dtype=np.int32)
-    sidx = np.full(DELTA_ROW_BUCKET, n_cap, dtype=np.int32)
-    salloc = np.zeros((DELTA_ROW_BUCKET, r_dims), dtype=np.int32)
-    svalid = np.zeros(DELTA_ROW_BUCKET, dtype=np.int32)
-    if fix_rows is not None and fix_rows.size:
-        didx[: fix_rows.size] = fix_rows
-        dreq[: fix_rows.size] = node_requested[fix_rows]
-        dnzr[: fix_rows.size] = node_nzr[fix_rows]
-    if alloc_rows is not None and alloc_rows.size:
-        sidx[: alloc_rows.size] = alloc_rows
-        salloc[: alloc_rows.size] = allocatable[alloc_rows]
-        svalid[: alloc_rows.size] = valid[alloc_rows]
-    return [
-        ("didx", didx), ("dreq", dreq), ("dnzr", dnzr),
-        ("sidx", sidx), ("salloc", salloc), ("svalid", svalid),
-    ]
-
-
-def _audit_checksum_host(arr: np.ndarray) -> Tuple[int, int]:
-    """Order-independent wrapping checksum pair (plain sum + row-weighted
-    sum, both mod 2^32) of a host array. Must match
-    ``_audit_checksum_dev`` bit-for-bit: both sides compute in int32
-    with C wrap semantics, and wrapped +/* form a ring, so reduction
-    order never matters."""
-    a = np.asarray(arr)
-    if a.dtype != np.int32:
-        a = a.astype(np.int32)
-    if a.ndim == 1:
-        a = a[:, None]
-    w = (np.arange(a.shape[0], dtype=np.int32) + 1)[:, None]
-    s = int(a.sum(dtype=np.int32))
-    ws = int((a * w).sum(dtype=np.int32))
-    return s, ws
-
-
-def _audit_checksum_dev(arr):
-    """Device twin of ``_audit_checksum_host``: two O(N*R) int32
-    reductions ON the device -- the cheap per-sweep cost of the carry
-    audit; the full [N, R] download happens only on mismatch. Returns
-    device scalars (the caller converts once, batching the sync)."""
-    a = arr.astype(jnp.int32)
-    if a.ndim == 1:
-        a = a[:, None]
-    w = (jnp.arange(a.shape[0], dtype=jnp.int32) + 1)[:, None]
-    return jnp.sum(a, dtype=jnp.int32), jnp.sum(a * w, dtype=jnp.int32)
-
-
-class _DeviceNodeState:
-    """Device-resident node tensors + the generation-handshake
-    bookkeeping that validates their reuse.
-
-    Every host->device transfer pays a round trip
-    (SURVEY.md section 7 "hardest parts (e)"), so the solver keeps node
-    state ON DEVICE between batches: the scan already returns the
-    post-batch (requested, nzr) on device, and the host mirrors the same
-    integer updates into ``req_shadow``/``nzr_shadow`` at commit time.
-
-    Reuse validation is a GENERATION HANDSHAKE, not an array sweep: the
-    NodeTensorCache stamps every repacked row with a monotonic epoch, so
-    at dispatch only ``rows_changed_since(validated_epoch)`` need a
-    content compare against the expectation -- O(changed rows), while the
-    old design re-swept the full [N, R] arrays against every shadow
-    generation. The committer may trail the dispatcher by several
-    batches; ``pending_deltas`` holds each mirrored batch's per-row adds
-    so a host state that trails the shadow by a suffix of them still
-    validates. Changed rows the expectation does NOT explain (node churn,
-    bind failures) are divergences: they are scatter-patched onto the
-    resident state as (indices, rows) -- or, with work in flight or too
-    many rows, resolved by a counted full upload. Never silently wrong.
-    """
-
-    def __init__(self) -> None:
-        self.alloc_dev = None
-        self.valid_dev = None
-        self.req_dev = None
-        self.nzr_dev = None
-        # -- handshake bookkeeping ---------------------------------------
-        # the NodeTensorCache layout epoch the device buffers were built
-        # against: row identity is only comparable while it stands
-        self.layout_epoch = -1
-        # the cache update epoch the shadows were last reconciled to
-        self.validated_epoch = -1
-        # expected host state: alloc mirrors the packed allocatable
-        # (patched row-wise); req/nzr mirror the packed requested state
-        # plus every mirrored (committed) batch
-        self.alloc_shadow: Optional[np.ndarray] = None
-        self.valid_shadow: Optional[np.ndarray] = None
-        self.req_shadow: Optional[np.ndarray] = None
-        self.nzr_shadow: Optional[np.ndarray] = None
-        # per-batch expected row deltas the host pack may not have shown
-        # yet: (node_rows [K], req_rows [K, R], nzr_rows [K, 2], seq),
-        # newest last (replaces the retired full-array shadow_gens
-        # ring). ``seq`` is the batch's mirror sequence number: once
-        # the scheduler's ``_assumed_seq`` has reached it the batch is
-        # in the host cache, and a pack made after that can no longer
-        # be said to trail it (_explain_rows)
-        self.pending_deltas: "collections.deque" = collections.deque(
-            maxlen=_SHADOW_RING_CAP
-        )
-
-    def invalidate_carry(self) -> None:
-        self.req_dev = None
-        self.nzr_dev = None
-        self.req_shadow = None
-        self.nzr_shadow = None
-        self.pending_deltas.clear()
 
 
 class BatchScheduler(Scheduler):
@@ -592,22 +409,11 @@ class BatchScheduler(Scheduler):
         self.pipeline_drains = 0  # constrained dispatch drained the pipeline
         self.gang_resolves = 0  # quorum-failure re-solves (_gang_fixup)
         self.nominee_constrained_fallbacks = 0  # nominees + constraints
-        self.state_reuses = 0
-        self.state_uploads = 0
-        # generation-handshake visibility: total changed node rows shipped
-        # as (indices, rows) scatters instead of full [N, R] uploads, and
-        # handshake mismatches (host state not explained by our own
-        # mirrored placements -- node churn, bind failures)
-        self.delta_rows_uploaded = 0
-        self.carry_divergences = 0
-        # membership churn absorbed as in-place slot scatters (node
-        # add/remove rows patched onto the resident state without a
-        # layout move, an upload, or a divergence)
-        self.membership_row_patches = 0
         #: the node-spec epoch the last batch packed against
         self._packed_node_epoch = 0
-        self._dev = _DeviceNodeState()
-        self._shadow_lock = threading.Lock()
+        # what the chip holds between batches, its lock and its counters
+        # (state_uploads and the rest, forwarded below)
+        self.device_state = DeviceNodeState(_SHADOW_RING_CAP)
         # pipelined batches flow dispatcher -> committer through this
         # bounded FIFO; the committer thread owns download + commit so the
         # dispatcher never blocks on a device round trip
@@ -698,10 +504,6 @@ class BatchScheduler(Scheduler):
         # containment path over another identical full-batch retry)
         self._last_exhaust_sig: Optional[frozenset] = None
         self._exhaust_repeats = 0
-        # carry integrity audit bookkeeping: the dispatch sequence lets
-        # an audit detect that a dispatch/commit raced its checksum
-        # window (bumped per dispatch AND per shadow mirror)
-        self._dispatch_seq = 0
         # batches the mesh's shard_map tier solved, with or without its
         # kernel (mesh_solver_tier)
         self.mesh_shard_solves = 0
@@ -709,8 +511,6 @@ class BatchScheduler(Scheduler):
         # has finished (its pods assumed into the cache); written by
         # the committer alone, read by the dispatcher before a refresh
         self._assumed_seq = 0
-        self.carry_audits = 0
-        self.carry_audit_heals = 0
         # device-loss rebuild: perf_counter at loss detection; cleared
         # (and metered into device_rebuild_ms) when the next jitted
         # solve lands on fully re-uploaded state
@@ -721,6 +521,22 @@ class BatchScheduler(Scheduler):
         self.max_inflight = MAX_INFLIGHT
         self.speculative_launches = 0
         self.speculative_rewinds = 0
+
+    # -- the device state's counters, read where they always were ------------
+
+    state_uploads = property(lambda self: self.device_state.state_uploads)
+    state_reuses = property(lambda self: self.device_state.state_reuses)
+    delta_rows_uploaded = property(
+        lambda self: self.device_state.delta_rows_uploaded
+    )
+    membership_row_patches = property(
+        lambda self: self.device_state.membership_row_patches
+    )
+    carry_divergences = property(
+        lambda self: self.device_state.carry_divergences
+    )
+    carry_audits = property(lambda self: self.device_state.audits)
+    carry_audit_heals = property(lambda self: self.device_state.audit_heals)
 
     # -- the resource score rule ----------------------------------------------
 
@@ -1079,7 +895,9 @@ class BatchScheduler(Scheduler):
                     "gang_fixup.resolve", totals=totals,
                     masked_pods=len(inactive),
                 ):
-                    stats["carry"] = self._rewind_carry(pending)
+                    stats["carry"] = self.device_state.rewind(
+                        pending.get("carry_in")
+                    )
                     if len(inactive) == len(solver_infos):
                         # nothing is left to place: the rewound carry
                         # is the batch's result
@@ -1127,8 +945,7 @@ class BatchScheduler(Scheduler):
                 for key in failed:
                     masked[key] = "bound"
                 requeue.update(failed)
-                with self._shadow_lock:
-                    self._dev.invalidate_carry()
+                self.device_state.invalidate()
                 stats["carry"] = "dropped"
                 flightrecorder.mark(
                     "gang_starved", groups=len(failed),
@@ -1258,21 +1075,6 @@ class BatchScheduler(Scheduler):
         )
         pending["download"] = None
 
-    def _rewind_carry(self, pending) -> str:
-        """Rewind the device carry to the given batch's pre-solve state:
-        the gang quorum fixup re-solves the same batch, which must not
-        see the first attempt's reservations. When the dispatch reused
-        the carry, its pre-solve device refs are still alive
-        (``carry_in``) and the rewind uploads nothing;
-        otherwise the carry drops and the re-dispatch re-uploads."""
-        ci = pending.get("carry_in")
-        with self._shadow_lock:
-            if ci is not None and self._dev.req_dev is not None:
-                self._dev.req_dev, self._dev.nzr_dev = ci
-                return "rewound"
-            self._dev.invalidate_carry()
-            return "dropped"
-
     def _pending_assignments(self, p):
         """The batch's downloaded assignments for the gang fixup: await
         the eager copy when one is in flight, else convert now -- under
@@ -1305,21 +1107,17 @@ class BatchScheduler(Scheduler):
         with self._pending_cv:
             return bool(self._pending_q)
 
-    def _pending_head(self):
+    def _in_flight(self):
+        """How many batches are in flight, and the first pending record
+        whose commit has NOT passed the shadow-mutation point (the
+        mirror in ``_complete_solve``). Mirrors land in FIFO order, so
+        this record's ``carry_in`` is the one snapshot that still equals
+        the host shadows -- the under-load carry audit's comparand."""
         with self._pending_cv:
-            return self._pending_q[0] if self._pending_q else None
-
-    def _pending_first_unmirrored(self):
-        """First pending record whose commit has NOT passed the
-        shadow-mutation point (the mirror in ``_complete_solve``).
-        Mirrors land in FIFO order, so this record's ``carry_in`` is
-        the one snapshot that still equals the host shadows -- the
-        under-load carry audit's comparand."""
-        with self._pending_cv:
-            for p in self._pending_q:
-                if not p.get("mirrored"):
-                    return p
-        return None
+            head = next(
+                (p for p in self._pending_q if not p.get("mirrored")), None
+            )
+            return len(self._pending_q), head
 
     def _unmirrored_exists(self) -> bool:
         """Any dispatched batch whose shadow mirror has NOT landed yet?
@@ -1328,8 +1126,7 @@ class BatchScheduler(Scheduler):
         and the mirror is the only shadow writer), so the handshake can
         negotiate row-exact repairs with commits still in flight -- the
         speculative chain's cheap-rewind precondition."""
-        with self._pending_cv:
-            return any(not p.get("mirrored") for p in self._pending_q)
+        return self._in_flight()[1] is not None
 
     def _await_mirrors(self, timeout: float = 30.0) -> bool:
         """Block until every in-flight batch has mirrored its deltas
@@ -1687,8 +1484,7 @@ class BatchScheduler(Scheduler):
         pod not already assumed goes back through the failure path
         (requeue with backoff + condition), and the device carry is
         dropped since the batch's true placements are unknown."""
-        with self._shadow_lock:
-            self._dev.invalidate_carry()
+        self.device_state.invalidate()
         try:
             if self._deferred_preempt:
                 self._flush_deferred_preemptions()
@@ -1766,307 +1562,6 @@ class BatchScheduler(Scheduler):
             while self._pending_q:
                 self._pending_cv.wait()
 
-    # -- device-state generation handshake ----------------------------------
-
-    def _explain_rows(self, changed, host_req, host_nzr, assumed_seq=0):
-        """Under ``_shadow_lock``: is every changed row's host content
-        explained by the shadow expectation at some committer-trail
-        depth? The host may trail the shadow by a suffix of
-        ``pending_deltas`` (batches mirrored but whose cache assume the
-        host pack predates) -- peel them newest-first until the changed
-        rows match. Only batches past ``assumed_seq`` may be peeled:
-        the commits up to it had assumed their pods into the cache
-        before this dispatch refreshed its snapshot, so the pack holds
-        them, and a host row that equals the shadow less such a batch
-        is not lagging -- the batch's pods were bound and have since
-        been DELETED (a closed wave no larger than the ring would
-        otherwise read as a lagging host for ever, and the device
-        would go on placing around pods that are gone).
-        Returns ``(ok, divergent_rows, keep)``: on a match
-        ``keep`` is the number of newest deltas still unconfirmed; on a
-        mismatch ``divergent_rows`` holds the depth-0 mismatches and
-        ``keep`` is 0 when NO pending delta touches them (the mismatch
-        is genuinely external, so a row scatter-fix is exact -- the
-        device carry always equals the shadow once every dispatched
-        batch has mirrored) or None when one does (the row may merely
-        be host-lagging; only a full resync is safe)."""
-        ds = self._dev
-        if changed.size == 0:
-            # no repacked rows: nothing to confirm, keep every delta
-            return True, None, len(ds.pending_deltas)
-        exp_req = ds.req_shadow[changed]
-        exp_nzr = ds.nzr_shadow[changed]
-        h_req = host_req[changed]
-        h_nzr = host_nzr[changed]
-        row_ok = np.all(exp_req == h_req, axis=1) & np.all(
-            exp_nzr == h_nzr, axis=1
-        )
-        if row_ok.all():
-            return True, None, 0
-        div_rows = changed[~row_ok]
-        pos = {int(r): j for j, r in enumerate(changed)}
-        keep = 0
-        trailing = [d for d in ds.pending_deltas if d[3] > assumed_seq]
-        for rows, req_rows, nzr_rows, _seq in reversed(trailing):
-            keep += 1
-            for j, r in enumerate(rows.tolist()):
-                jj = pos.get(int(r))
-                if jj is not None:
-                    exp_req[jj] -= req_rows[j]
-                    exp_nzr[jj] -= nzr_rows[j]
-            if (
-                np.all(exp_req == h_req, axis=1)
-                & np.all(exp_nzr == h_nzr, axis=1)
-            ).all():
-                return True, None, keep
-        div_set = set(div_rows.tolist())
-        lagging = any(
-            int(r) in div_set
-            for rows, _req_rows, _nzr_rows, _seq in trailing
-            for r in rows
-        )
-        return False, div_rows, (None if lagging else 0)
-
-    def _adopt_membership_rows(self, member, host_req, host_nzr):
-        """Under ``_shadow_lock``, with nothing in flight (so the device
-        carry equals the shadow): adopt host truth for churned row slots
-        into the shadow expectation and scrub them from the pending
-        ring (their pre-churn deltas can never be confirmed -- the slot
-        belongs to a different node now). Returns the subset whose
-        device content (== pre-adoption shadow) actually differs and
-        therefore must ride the didx scatter."""
-        ds = self._dev
-        diff = ~(
-            np.all(ds.req_shadow[member] == host_req[member], axis=1)
-            & np.all(ds.nzr_shadow[member] == host_nzr[member], axis=1)
-        )
-        fix = member[diff]
-        ds.req_shadow[member] = host_req[member]
-        ds.nzr_shadow[member] = host_nzr[member]
-        if ds.pending_deltas:
-            mset = set(member.tolist())
-            scrubbed = collections.deque(
-                maxlen=ds.pending_deltas.maxlen
-            )
-            for rows, req_rows, nzr_rows, seq in ds.pending_deltas:
-                keepm = np.fromiter(
-                    (int(r) not in mset for r in rows),
-                    dtype=bool, count=len(rows),
-                )
-                if keepm.all():
-                    scrubbed.append((rows, req_rows, nzr_rows, seq))
-                elif keepm.any():
-                    scrubbed.append((
-                        rows[keepm], req_rows[keepm], nzr_rows[keepm], seq,
-                    ))
-                # entries fully on churned slots drop: nothing left to
-                # confirm
-            ds.pending_deltas = scrubbed
-        return fix
-
-    def _negotiate_device_state(
-        self, nt, node_requested, node_nzr, overlaid,
-        pending_exists, unmirrored_exists=None,
-        assumed_seq=0,
-    ):
-        """Decide how this dispatch's node state reaches the device and
-        reconcile the handshake bookkeeping. Returns None when in-flight
-        batches block the decision (caller drains and redispatches), else
-        ``{"static_ok", "carry_ok", "didx", "sidx", "member"}``:
-
-        - carry_ok + empty deltas: pure reuse, nothing node-sized rides
-          the link.
-        - carry_ok + didx/sidx rows: reuse, with externally changed rows
-          (divergences / allocatable updates) patched onto the resident
-          state by the in-buffer scatter (ops/assignment.py). Membership
-          churn (node add/remove claiming/retiring slots in place, see
-          NodeTensorCache) rides the same scatter -- sidx patches alloc
-          AND valid, didx resets the slot's requested state -- and is an
-          EXPECTED reset, never counted as a divergence.
-        - not carry_ok: full [N, R] requested upload (``state_uploads``);
-          not static_ok additionally re-uploads allocatable+valid.
-
-        The mesh path rides the same scatters through the sharded twin
-        (each delta row lands on exactly one node shard).
-
-        ``unmirrored_exists`` is the speculative-chain relaxation: the
-        membership-adopt and scatter-fix paths only need the device
-        carry to EQUAL the shadow, which holds as soon as every
-        in-flight batch has mirrored -- commits may still be running.
-        Only the full-upload path (which takes HOST truth as the new
-        carry, so every placement must have landed in the cache) still
-        gates on ``pending_exists``. Defaults to ``pending_exists``
-        (the conservative pre-pipelining behavior) when not given.
-
-        ``assumed_seq``: the newest batch whose commit had finished
-        before this dispatch refreshed the snapshot it packed from
-        (``_explain_rows`` says what that forbids).
-        """
-        if unmirrored_exists is None:
-            unmirrored_exists = pending_exists
-        ds = self._dev
-        d = nt.delta
-        empty = np.zeros(0, dtype=np.int64)
-        with self._shadow_lock:
-            layout_ok = (
-                d is not None
-                and ds.alloc_dev is not None
-                and ds.alloc_shadow is not None
-                and ds.layout_epoch == d.layout_epoch
-                and ds.alloc_shadow.shape == nt.allocatable.shape
-            )
-            alloc_rows = empty
-            member = empty
-            member_fix = empty
-            carry = "dead"
-            div_rows = None
-            keep = 0
-            if layout_ok:
-                changed = self.tensor_cache.rows_changed_since(
-                    ds.validated_epoch
-                )
-                member = self.tensor_cache.membership_rows_since(
-                    ds.validated_epoch
-                )
-                if member.size and unmirrored_exists:
-                    # churned slots cannot be reconciled while an
-                    # UNMIRRORED batch is in flight: it may have placed
-                    # onto a now-retired slot, and adopting host truth
-                    # under it would desync the mirror. Once every
-                    # in-flight batch has mirrored the carry equals the
-                    # shadow and the adopt+scatter is exact, so the
-                    # caller only needs to await mirrors (cheap), not a
-                    # full drain.
-                    return None
-                nonmember = changed
-                if member.size:
-                    nonmember = np.setdiff1d(changed, member)
-                if nonmember.size:
-                    diff = ~np.all(
-                        nt.allocatable[nonmember]
-                        == ds.alloc_shadow[nonmember],
-                        axis=1,
-                    )
-                    alloc_rows = nonmember[diff]
-                if member.size:
-                    # membership rows always ride the static scatter:
-                    # alloc content AND validity flip with slot identity
-                    alloc_rows = np.union1d(alloc_rows, member)
-                if (
-                    not overlaid
-                    and ds.req_dev is not None
-                    and ds.req_shadow is not None
-                ):
-                    if member.size:
-                        member_fix = self._adopt_membership_rows(
-                            member, node_requested, node_nzr
-                        )
-                    ok, div_rows, keep = self._explain_rows(
-                        nonmember, node_requested, node_nzr,
-                        assumed_seq,
-                    )
-                    carry = "reuse" if ok else "diverged"
-            static_full = (
-                not layout_ok or alloc_rows.size > DELTA_ROW_BUCKET
-            )
-            fix_rows = empty
-            diverged = carry == "diverged"
-            if diverged:
-                if (
-                    not static_full
-                    and div_rows.size <= DELTA_ROW_BUCKET
-                    and keep == 0  # no pending delta touches a div row
-                    and not unmirrored_exists
-                ):
-                    # resolvable in place: with every in-flight batch
-                    # mirrored the carry equals the shadow, so setting
-                    # the divergent rows to host truth on device is
-                    # exact even with commits still running -- the
-                    # speculative chain's cheap rewind (a bind
-                    # conflict / quota refund / conflict-requeue
-                    # re-solves only against these patched rows)
-                    fix_rows = div_rows
-                else:
-                    carry = "dead"  # resolve by full upload (or drain)
-            didx_rows = member_fix
-            if fix_rows.size:
-                didx_rows = np.union1d(member_fix, fix_rows)
-            if didx_rows.size > DELTA_ROW_BUCKET:
-                # too many row patches: full upload. `diverged` keeps
-                # its value -- a genuine divergence resolved by this
-                # upload must still be counted, even when the overflow
-                # came from the membership rows
-                carry = "dead"
-                fix_rows = empty
-                didx_rows = empty
-            reusable = not static_full and (
-                carry == "reuse" or fix_rows.size > 0
-            )
-            if pending_exists and not reusable:
-                # the device carry is ahead of the host by the in-flight
-                # placements; uploading host state now would re-place
-                # them. Land everything first, then redo the dispatch.
-                return None
-            if reusable:
-                # the fix path requires an empty ring, so keep is only
-                # meaningful (a match depth) on the pure-reuse path
-                for _ in range(len(ds.pending_deltas) - (keep or 0)):
-                    ds.pending_deltas.popleft()
-                if alloc_rows.size:
-                    ds.alloc_shadow[alloc_rows] = nt.allocatable[alloc_rows]
-                    if ds.valid_shadow is not None:
-                        ds.valid_shadow[alloc_rows] = nt.valid[alloc_rows]
-                if fix_rows.size:
-                    ds.req_shadow[fix_rows] = node_requested[fix_rows]
-                    ds.nzr_shadow[fix_rows] = node_nzr[fix_rows]
-                    self.carry_divergences += 1
-                    metrics.carry_divergences.inc()
-                    if pending_exists:
-                        # the expected deltas diverged under an active
-                        # speculative chain and the carry was repaired
-                        # in place: the cheap rewind, not a drain
-                        self.speculative_rewinds += 1
-                        metrics.speculative_rewinds.inc(
-                            reason="row_patch"
-                        )
-                if member.size:
-                    self.membership_row_patches += int(member.size)
-                ds.validated_epoch = d.epoch
-                self.state_reuses += 1
-                self.delta_rows_uploaded += int(
-                    alloc_rows.size + didx_rows.size
-                )
-                return {
-                    "static_ok": True,
-                    "carry_ok": True,
-                    "didx": didx_rows,
-                    "sidx": alloc_rows,
-                    "member": int(member.size),
-                }
-            # upload path
-            if diverged:
-                self.carry_divergences += 1
-                metrics.carry_divergences.inc()
-            static_ok = not static_full and alloc_rows.size == 0
-            if not static_ok:
-                ds.layout_epoch = (
-                    d.layout_epoch if d is not None else -1
-                )
-                ds.alloc_shadow = nt.allocatable.copy()
-                ds.valid_shadow = np.array(nt.valid, dtype=bool)
-            ds.req_shadow = node_requested.copy()
-            ds.nzr_shadow = node_nzr.copy()
-            ds.pending_deltas.clear()
-            ds.validated_epoch = d.epoch if d is not None else -1
-            self.state_uploads += 1
-            return {
-                "static_ok": static_ok,
-                "carry_ok": False,
-                "didx": empty,
-                "sidx": empty,
-                "member": 0,
-            }
-
     def _dispatch_solve(
         self,
         solver_infos: List[PodInfo],
@@ -2113,10 +1608,7 @@ class BatchScheduler(Scheduler):
                 FaultPoint.DEVICE_LOST
             ):
                 self._on_device_lost()
-        with self._shadow_lock:
-            # under the lock: the committer bumps this too, and a lost
-            # increment would blind the carry audit's race detector
-            self._dispatch_seq += 1
+        self.device_state.begin_dispatch()
         # -- flight-recorder span: one per dispatch (a gang re-solve or
         # drain-redispatch is honestly its own span), with the per-pod
         # linkage (uid -> batch id, queue-wait, attempts) that makes a
@@ -2629,18 +2121,21 @@ class BatchScheduler(Scheduler):
                 self.attempt_schedule(pi)
             return None
 
-        # -- device-state generation handshake (see _DeviceNodeState) -------
+        # -- device-state generation handshake (scheduler/device_state.py) ---
         # Runs after every route-to-host bail-out above: it reconciles the
-        # shadow bookkeeping on the assumption that the decided upload /
+        # state's bookkeeping on the assumption that the decided upload /
         # scatter actually reaches the device this dispatch.
-        ds = self._dev
+        state = self.device_state
+
+        def negotiate(unmirrored: bool):
+            return state.negotiate(
+                nt, self.tensor_cache, node_requested, node_nzr, overlaid,
+                in_flight=in_flight_at_pack or self._pending_exists(),
+                unmirrored=unmirrored, assumed_seq=assumed_seq,
+            )
+
         unmirrored = self._unmirrored_exists()
-        neg = self._negotiate_device_state(
-            nt, node_requested, node_nzr, overlaid,
-            pending_exists=in_flight_at_pack or self._pending_exists(),
-            unmirrored_exists=unmirrored,
-            assumed_seq=assumed_seq,
-        )
+        neg = negotiate(unmirrored)
         mirrored = False
         if neg is None and unmirrored:
             # the dispatcher waits, outside pack, until every batch in
@@ -2653,20 +2148,14 @@ class BatchScheduler(Scheduler):
                 mirrored = self._await_mirrors()
         if mirrored:
             # the blocked path (membership adopt / divergence repair)
-            # only needs the carry to equal the shadow, which holds the
-            # moment every in-flight batch has MIRRORED -- so wait for
+            # only needs the carry to equal the expectation, which holds
+            # the moment every in-flight batch has MIRRORED -- so wait for
             # the mirrors (the committer signals them; typically a few
             # ms) and renegotiate before paying a full pipeline drain
-            retry = self._negotiate_device_state(
-                nt, node_requested, node_nzr, overlaid,
-                pending_exists=in_flight_at_pack or self._pending_exists(),
-                unmirrored_exists=False,
-                assumed_seq=assumed_seq,
-            )
-            if retry is not None:
+            neg = negotiate(False)
+            if neg is not None:
                 self.speculative_rewinds += 1
                 metrics.speculative_rewinds.inc(reason="mirror_wait")
-            neg = retry
         if neg is None:
             # the handshake needs an upload but the device carry is ahead
             # of the host by the in-flight batches (node churn, bind
@@ -2681,24 +2170,21 @@ class BatchScheduler(Scheduler):
                 solver_infos, pod_scheduling_cycle,
                 inactive_uids=inactive_uids,
             )
-        static_ok = neg["static_ok"]
-        carry_ok = neg["carry_ok"]
+        if neg.row_patch_rewind:
+            self.speculative_rewinds += 1
+            metrics.speculative_rewinds.inc(reason="row_patch")
         # how the resident state is brought up to date and the rows sent
         # for it, in the ring and on the profiler's
         # ``sched/solve_dispatch`` span: a carry that is reused where it
         # should have been uploaded shows here and nowhere else
-        delta_rows = int(neg["didx"].size + neg["sidx"].size)
-        carry_how = (
-            "upload" if not carry_ok else "scatter" if delta_rows else "reuse"
-        )
-        span.note(carry=carry_how, delta_rows=delta_rows)
+        span.note(carry=neg.carry, delta_rows=neg.delta_rows)
         carry_stats = {
             "devices": 1 if self.mesh is None else int(self.mesh.devices.size),
-            "carry": carry_how,
-            "carry_rows": delta_rows if carry_ok else int(nt.capacity),
+            "carry": neg.carry,
+            "carry_rows": neg.carry_rows,
             # of them, the slots a node joined or left since the last
             # batch (membership churn rides the same scatter)
-            "member_rows": int(neg["member"]),
+            "member_rows": neg.member_rows,
             # the pods of the batch: the steps a one-chip kernel runs of
             # the ``padded`` that ``sched/dispatch`` says
             "steps": int(b),
@@ -2730,18 +2216,18 @@ class BatchScheduler(Scheduler):
             # shard uploads only its [U, N/P] mask columns
             ("rows", mask_rows_upload(rows, self.mesh)),
         ]
-        if not static_ok:
+        if not neg.static_ok:
             pieces.append(("alloc", nt.allocatable))
             pieces.append(("valid", nt.valid.astype(np.int32)))
-        if not carry_ok:
+        if not neg.carry_ok:
             pieces.append(("req_state", node_requested))
             pieces.append(("nzr_state", node_nzr))
         else:
             # steady state: the resident [N, R] tensors stay on
             # device; only the changed-row scatter rides the buffer
-            pieces += _delta_slot_pieces(
+            pieces += delta_slot_pieces(
                 nt.capacity, nt.dims.num_dims,
-                fix_rows=neg["didx"], alloc_rows=neg["sidx"],
+                fix_rows=neg.fix_rows, alloc_rows=neg.alloc_rows,
                 node_requested=node_requested, node_nzr=node_nzr,
                 allocatable=nt.allocatable, valid=nt.valid,
             )
@@ -2788,9 +2274,6 @@ class BatchScheduler(Scheduler):
                 if score_batch is not None else None,
                 noop_score_tensors(padded, nt.capacity),
             )
-        # pass None for pieces riding the buffer so the jit sees one
-        # stable signature per layout (a stale device ref would fork
-        # a needless compile variant)
         solve_mode = "constrained" if constrained else self.solver_mode
 
         def run_device(allow_pallas: bool):
@@ -2806,10 +2289,7 @@ class BatchScheduler(Scheduler):
                 inj.raise_maybe(FaultPoint.DEVICE_SOLVE)
             return solve_packed(
                 pieces,
-                ds.alloc_dev if static_ok else None,
-                ds.valid_dev if static_ok else None,
-                ds.req_dev if carry_ok else None,
-                ds.nzr_dev if carry_ok else None,
+                *state.operands(neg),
                 config=config,
                 mode=solve_mode,
                 allow_pallas=allow_pallas,
@@ -2843,15 +2323,6 @@ class BatchScheduler(Scheduler):
         # and redispatches from fresh host state instead)
         if not constrained and not self._pending_exists():
             attempts.append((TIER_HOST_GREEDY, run_host_greedy))
-        # pre-solve carry refs: the gang quorum fixup restores these
-        # to rewind a re-solved batch to its pre-batch device state
-        # without a re-upload (only exact when no row fixes rode
-        # this dispatch)
-        carry_in = (
-            (ds.req_dev, ds.nzr_dev)
-            if carry_ok and not neg["didx"].size
-            else None
-        )
         try:
             with flightrecorder.stage(
                 "device_solve", span, totals, **carry_stats
@@ -2864,31 +2335,7 @@ class BatchScheduler(Scheduler):
             self._jit_watch.refresh()
             metrics.solves_by_resource_score.inc(score=config.label())
         except LadderExhausted as exhaust_err:
-            with self._shadow_lock:
-                ds.invalidate_carry()
-                # no jitted solve LANDED, so the booked upload /
-                # scatter never became device state: un-book the
-                # counters (a drain-and-redispatch would book the
-                # batch again). A device tier that uploaded and then
-                # failed still paid the link traffic; that cost is
-                # attributed by solves_by_tier/breaker metrics, not
-                # here -- state_uploads counts established state.
-                if carry_ok:
-                    self.state_reuses -= 1
-                    self.delta_rows_uploaded -= int(
-                        neg["didx"].size + neg["sidx"].size
-                    )
-                    self.membership_row_patches -= neg["member"]
-                else:
-                    self.state_uploads -= 1
-                if neg["sidx"].size or not static_ok:
-                    # the alloc row patch / full static upload never
-                    # reached the device (no solve ran) but the
-                    # shadow already claims it: drop the resident
-                    # alloc so the next dispatch re-uploads instead
-                    # of trusting it
-                    ds.alloc_dev = None
-                    ds.valid_dev = None
+            state.nothing_landed(neg)
             if raise_on_exhaust:
                 # bisection sub-solve: the caller owns this group's
                 # disposition (split further or isolate)
@@ -2913,79 +2360,20 @@ class BatchScheduler(Scheduler):
                     exhaust_err.__cause__, PoisonError
                 ),
             )
-        assignments_dev, req_out, nzr_out, alloc_out, valid_out = out
+        assignments_dev, *resident = out
         if tier == TIER_HOST_GREEDY:
-            # the host tier solved from host state and no jitted
-            # solve ran: undo any bookkeeping that assumed the
-            # device saw this dispatch (incl. the link-traffic
-            # counters -- no upload / row scatter actually happened)
-            with self._shadow_lock:
-                if carry_ok:
-                    self.delta_rows_uploaded -= int(
-                        neg["didx"].size + neg["sidx"].size
-                    )
-                    self.membership_row_patches -= neg["member"]
-                else:
-                    self.state_uploads -= 1
-                if neg["sidx"].size or not static_ok:
-                    # alloc patch / full static upload never landed
-                    ds.alloc_dev = None
-                    ds.valid_dev = None
-                if (
-                    carry_ok
-                    and not neg["didx"].size
-                    and not overlaid
-                    and ds.req_dev is not None
-                ):
-                    # the host tier was only offered with nothing in
-                    # flight and a validated carry, so its input
-                    # state EQUALS the device carry: scatter-add its
-                    # own assignment output onto the resident state
-                    # (ops/assignment.apply_assignment_delta) and
-                    # keep the carry warm instead of dropping it
-                    ds.req_dev, ds.nzr_dev = apply_assignment_delta(
-                        ds.req_dev, ds.nzr_dev,
-                        np.asarray(
-                            assignments_dev, dtype=np.int32
-                        ),
-                        req, nzr,
-                    )
-                else:
-                    ds.invalidate_carry()
+            state.host_solved(neg, assignments_dev, req, nzr, overlaid)
         else:
-            # a jitted solve LANDED: the booked upload / scatter is
-            # established device state -- mirror the internal
-            # counters into the (monotonic) Prometheus series now,
-            # when the booking is final (the host-tier / exhausted
-            # branches un-book the attributes and book nothing here)
-            if carry_ok:
-                if neg["didx"].size or neg["sidx"].size:
-                    metrics.delta_rows_uploaded.inc(
-                        int(neg["didx"].size + neg["sidx"].size)
-                    )
-            else:
-                metrics.state_uploads.inc()
-                if self._device_lost_at is not None:
-                    self._note_device_rebuilt()
-            if not static_ok:
-                ds.alloc_dev, ds.valid_dev = alloc_out, valid_out
-            elif neg["sidx"].size:
-                # the in-buffer scatter patched the resident alloc
-                # (and, for membership churn, the valid mask); keep
-                # the patched refs
-                ds.alloc_dev, ds.valid_dev = alloc_out, valid_out
+            if state.landed(neg, resident, overlaid):
+                self._note_device_rebuilt()
             assignments_dev.copy_to_host_async()
-            if overlaid:
-                ds.invalidate_carry()
-            else:
-                ds.req_dev, ds.nzr_dev = req_out, nzr_out
         if self.mesh is not None and tier == TIER_PALLAS:
             self.mesh_shard_solves += 1
         span.note(tier=booked)
         return {
             # the attempt's own name: its breaker guards the download
             "tier": tier,
-            "carry_in": carry_in,
+            "carry_in": neg.carry_in,
             "span": span,
             "solver_infos": list(solver_infos),
             "has_required_anti": has_required_anti,
@@ -3293,180 +2681,11 @@ class BatchScheduler(Scheduler):
     # -- carry integrity audit + device-loss rebuild -------------------------
 
     def audit_carry(self) -> str:
-        """One carry-integrity sweep: checksum the device-resident
-        req/nzr (and alloc/valid when resident) against the host shadow
-        with two cheap on-device int32 reductions per array; the full
-        [N, R] download happens only on mismatch. Corruption heals
-        through the counted-upload path (carry drop -> next dispatch
-        re-uploads), never silently. Runs from the
-        ControlPlaneReconciler sweep; safe to call from any thread.
-
-        Returns the disposition: "idle" (nothing resident), "busy"
-        (in-flight state with no auditable snapshot), "raced" (a
-        dispatch/commit moved the state mid-sweep), "clean", or
-        "mismatch" (healed).
-
-        A SATURATED pipeline no longer defers the audit to quiescence:
-        while batches are in flight, the FIRST UNMIRRORED pending
-        record's ``carry_in`` refs are audited instead of the live
-        carry. Those refs are immutable device arrays (dispatch
-        REASSIGNS ``ds.req_dev``, never mutates it) snapshotting the
-        device state that record's solve consumed -- which must equal
-        the host shadows exactly until that record's own commit passes
-        the shadow-mutation point (the mirror, flagged ``mirrored``
-        under this lock), because the committer lands batches in FIFO
-        order and the req/nzr shadows mutate ONLY at the mirror. The
-        coarse ``committing`` flag is deliberately NOT the gate: the
-        committer raises it the instant it grabs the head, long before
-        the mirror (the whole device download sits between), and gating
-        on it would answer "busy" for nearly every sweep under
-        saturation. Staleness is therefore bounded by pipeline depth,
-        not by the arrival rate ever pausing: corruption stamped into
-        the newest resident carry is seen when the batch that consumed
-        it reaches the front of the unmirrored window, at most
-        MAX_INFLIGHT commits later. Only req/nzr are audited under
-        load (the alloc row patch CAN land on the resident alloc while
-        batches are in flight); "busy" remains only for windows whose
-        front record has no carry reuse (cold uploads, row-fix
-        dispatches) or whose every record has already mirrored."""
-        ds = self._dev
-        under_load = False
-        head = None
-        seq = 0
-        alloc_dev = valid_dev = None
-        shadow_ref = None
-        with self._shadow_lock:
-            if ds.req_dev is None or ds.req_shadow is None:
-                metrics.carry_audit_sweeps.inc(disposition="idle")
-                return "idle"
-            if self._pending_exists():
-                head = self._pending_first_unmirrored()
-                carry = (
-                    head.get("carry_in") if head is not None else None
-                )
-                if head is None or carry is None:
-                    metrics.carry_audit_sweeps.inc(disposition="busy")
-                    return "busy"
-                under_load = True
-                shadow_ref = ds.req_shadow
-                req_dev, nzr_dev = carry
-                host = {
-                    "req": _audit_checksum_host(ds.req_shadow),
-                    "nzr": _audit_checksum_host(ds.nzr_shadow),
-                }
-            else:
-                seq = self._dispatch_seq
-                req_dev, nzr_dev = ds.req_dev, ds.nzr_dev
-                alloc_dev, valid_dev = ds.alloc_dev, ds.valid_dev
-                # host checksums under the lock: the shadows mutate in
-                # place at commit time
-                host = {
-                    "req": _audit_checksum_host(ds.req_shadow),
-                    "nzr": _audit_checksum_host(ds.nzr_shadow),
-                }
-                if alloc_dev is not None and ds.alloc_shadow is not None:
-                    host["alloc"] = _audit_checksum_host(ds.alloc_shadow)
-                if valid_dev is not None and ds.valid_shadow is not None:
-                    host["valid"] = _audit_checksum_host(ds.valid_shadow)
-        self.carry_audits += 1
-        # device reductions OUTSIDE the lock (the refs are immutable
-        # arrays; a racing dispatch reassigns, never mutates)
-        dev_handles = {"req": _audit_checksum_dev(req_dev),
-                       "nzr": _audit_checksum_dev(nzr_dev)}
-        if "alloc" in host:
-            dev_handles["alloc"] = _audit_checksum_dev(alloc_dev)
-        if "valid" in host:
-            dev_handles["valid"] = _audit_checksum_dev(valid_dev)
-        dev = {
-            name: (int(np.asarray(s)), int(np.asarray(ws)))
-            for name, (s, ws) in dev_handles.items()
-        }
-        with self._shadow_lock:
-            if under_load:
-                # the snapshot is comparable until OUR record's mirror
-                # lands (the only in-order in-place writer of the
-                # req/nzr shadows) or a cold upload reassigns the
-                # shadow arrays -- both happen under this lock, so
-                # either landing mid-reduction is caught here. The
-                # coarse ``committing`` flag is irrelevant: the whole
-                # download phase is audit-safe.
-                raced = (
-                    head.get("mirrored")
-                    or ds.req_shadow is not shadow_ref
-                )
-            else:
-                raced = (
-                    self._dispatch_seq != seq
-                    or self._pending_exists()
-                    or ds.req_dev is not req_dev
-                )
-            if raced:
-                metrics.carry_audit_sweeps.inc(disposition="raced")
-                return "raced"
-            mismatched = [n for n in dev if dev[n] != host[n]]
-            if not mismatched:
-                metrics.carry_audit_sweeps.inc(disposition="clean")
-                return "clean"
-            # full compare only on mismatch: name the divergent rows
-            # for the flight record, then heal
-            rows: List[int] = []
-            try:
-                if "req" in mismatched:
-                    diff = ~np.all(
-                        np.asarray(req_dev) == ds.req_shadow, axis=1
-                    )
-                    rows = np.flatnonzero(diff)[:16].tolist()
-                elif "nzr" in mismatched:
-                    diff = ~np.all(
-                        np.asarray(nzr_dev) == ds.nzr_shadow, axis=1
-                    )
-                    rows = np.flatnonzero(diff)[:16].tolist()
-            except Exception:  # noqa: BLE001 - row detail is best-effort
-                logger.exception("carry audit row compare failed")
-            for name in mismatched:
-                metrics.carry_audit_mismatches.inc(array=name)
-            flightrecorder.mark(
-                "carry_audit", arrays=",".join(sorted(mismatched)),
-                rows=rows, in_flight=len(self._pending_q),
-            )
-            if "req" in mismatched or "nzr" in mismatched:
-                ds.invalidate_carry()
-            if "alloc" in mismatched or "valid" in mismatched:
-                ds.alloc_dev = None
-                ds.valid_dev = None
-            metrics.carry_audit_heals.inc()
-            self.carry_audit_heals += 1
-        metrics.carry_audit_sweeps.inc(disposition="mismatch")
-        logger.warning(
-            "carry integrity audit: device-resident %s diverged from "
-            "the host shadow (rows %s); healed via the counted-upload "
-            "path", ",".join(sorted(mismatched)), rows,
-        )
-        return "mismatch"
-
-    def _corrupt_carry_row(self) -> None:
-        """CARRY_CORRUPT fired: flip bits in one device-resident carry
-        row WITHOUT touching the host shadow -- silent corruption only
-        the integrity audit can see (the generation handshake compares
-        host state against the shadow, never the device)."""
-        inj = get_injector()
-        with self._shadow_lock:
-            ds = self._dev
-            if ds.req_dev is None:
-                return
-            n = int(ds.req_dev.shape[0])
-            if n == 0:
-                return
-            fired = (
-                inj.fired_count(FaultPoint.CARRY_CORRUPT)
-                if inj is not None else 1
-            )
-            row = (fired * 131) % n
-            ds.req_dev = ds.req_dev.at[row, 0].add(1 << 20)
-        flightrecorder.mark("carry_corrupt", row=row)
-        logger.warning(
-            "injected carry corruption on resident row %d", row
-        )
+        """One carry-integrity sweep of the device-resident state
+        (``DeviceNodeState.audit`` says what it compares and returns).
+        Runs from the ControlPlaneReconciler sweep; safe to call from
+        any thread."""
+        return self.device_state.audit(self._in_flight)
 
     def _on_device_lost(self) -> None:
         """DEVICE_LOST fired: every device-resident buffer is gone.
@@ -3487,14 +2706,7 @@ class BatchScheduler(Scheduler):
         with self._pending_cv:
             for p in self._pending_q:
                 p["device_lost"] = True
-        with self._shadow_lock:
-            ds = self._dev
-            ds.alloc_dev = None
-            ds.valid_dev = None
-            ds.alloc_shadow = None
-            ds.valid_shadow = None
-            ds.layout_epoch = -1
-            ds.invalidate_carry()
+        self.device_state.lost()
         self._drain_pending()
 
     def _note_device_rebuilt(self) -> None:
@@ -3616,40 +2828,16 @@ class BatchScheduler(Scheduler):
         p["solve_timer"].observe()
         b = p["b"]
         metrics.batch_size.observe(b)
-        ds = self._dev
-        with self._shadow_lock:
-            # the audit race-detector: a commit moving the shadow (or
-            # landing a batch) invalidates any checksum window spanning
-            # this moment. ``mirrored`` marks THIS record as past the
-            # shadow-mutation point -- the under-load audit compares the
-            # first unmirrored record's carry_in against the shadows,
-            # so the flag must flip under the same lock as the mirror.
-            self._dispatch_seq += 1
-            p["mirrored"] = True
-            if not p["overlaid"] and ds.req_shadow is not None:
-                # mirror the batch's own placements into the running
-                # expectation (same int32 arithmetic as the scan carry)
-                # and remember the per-row delta: the dispatcher's
-                # handshake subtracts it while the host cache still
-                # trails this commit. O(B*R) in-place -- the retired
-                # shadow_gens ring copied the full [N, R] per batch.
-                # The compact+scatter hot loop runs in native
-                # _hotpath.c (mirror_scatter; numpy twin behind
-                # KTPU_NATIVE_INGEST=0, differentially tested).
-                delta = _mirror_scatter(
-                    assignments, b, p["req"], p["nzr"],
-                    ds.req_shadow, ds.nzr_shadow,
-                )
-                if delta is not None:
-                    ds.pending_deltas.append((*delta, self._dispatch_seq))
-            mirror_seq = self._dispatch_seq
+        mirror_seq = self.device_state.mirror(
+            p, assignments, b, p["req"], p["nzr"], p["overlaid"]
+        )
         # wake dispatchers parked in _await_mirrors at MIRROR time: the
         # commit/bind API transactions below can be hundreds of ms away,
         # and the speculative renegotiation only needs the mirror
         with self._pending_cv:
             self._pending_cv.notify_all()
         if inj is not None and inj.should_fire(FaultPoint.CARRY_CORRUPT):
-            self._corrupt_carry_row()
+            self.device_state.corrupt_row()
         with flightrecorder.stage("commit", fspan, totals):
             self._commit_batch(
                 p["solver_infos"], p["order"], assignments, p["names"],
@@ -4773,7 +3961,7 @@ class BatchScheduler(Scheduler):
         # steady-state dispatches always carry the (indices, rows)
         # delta-scatter slots (empty slots drop on device), so the
         # run loop hits exactly ONE steady signature per mode
-        delta_slots = _delta_slot_pieces(n, r)
+        delta_slots = delta_slot_pieces(n, r)
         cold = solve_packed(
             base + static_pieces + carry_pieces, None, None, None, None,
             config=config, mode=self.solver_mode,
@@ -4910,7 +4098,7 @@ class BatchScheduler(Scheduler):
             ("req", req), ("nzr", pod_nzr), ("midx", midx),
             ("active", active.astype(np.int32)),
             ("rows", rows.astype(np.int32)),
-        ] + _delta_slot_pieces(n, r)
+        ] + delta_slot_pieces(n, r)
 
         def agrees(mode: str, fam: list) -> bool:
             out = solve_packed(
@@ -4991,7 +4179,7 @@ class BatchScheduler(Scheduler):
             ("req_state", np.zeros((n, r), dtype=np.int32)),
             ("nzr_state", np.zeros((n, 2), dtype=np.int32)),
         ]
-        delta_slots = _delta_slot_pieces(n, r)
+        delta_slots = delta_slot_pieces(n, r)
         tiers = [False]  # the GSPMD twin always warms (breaker target)
         if mesh_pallas_candidate(self.solver_mode, n, self.mesh):
             tiers.insert(0, True)

@@ -353,16 +353,13 @@ class ControlPlaneReconciler:
     # -- carry integrity audit (blast-radius containment, ISSUE 14) ---------
 
     def audit_carry_once(self) -> str:
-        """Run the batch scheduler's device-carry integrity audit
-        (BatchScheduler.audit_carry): cheap on-device checksums of the
-        resident req/nzr/alloc/valid state against the host shadow,
-        full compare + counted-upload heal only on mismatch. A plain
-        (non-batch) scheduler has no carry; returns "unsupported"
-        then."""
-        audit = getattr(self.sched, "audit_carry", None)
-        if audit is None:
-            return "unsupported"
-        return audit()
+        """Run the scheduler's device-carry integrity audit
+        (Scheduler.audit_carry; scheduler/device_state.py does it for
+        the batch scheduler): cheap on-device checksums of the resident
+        req/nzr/alloc/valid state against the host shadow, full compare
+        + counted-upload heal only on mismatch. A plain (non-batch)
+        scheduler has no carry and answers "unsupported"."""
+        return self.sched.audit_carry()
 
     # -- the loop ------------------------------------------------------------
 

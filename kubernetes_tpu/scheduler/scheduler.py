@@ -807,6 +807,11 @@ class Scheduler:
         t.start()
         return t
 
+    def audit_carry(self) -> str:
+        """The ControlPlaneReconciler's carry-integrity sweep. The
+        sequential scheduler keeps nothing on a device."""
+        return "unsupported"
+
     def stop(self) -> None:
         self._stop.set()
         self.queue.close()

@@ -556,7 +556,7 @@ class TestDeviceStateDifferential:
             "the external delete must surface as a counted divergence"
         )
 
-        ds = sched._dev
+        ds = sched.device_state
         assert ds.req_dev is not None, "device carry was dropped"
         dev_req = np.asarray(ds.req_dev)
         dev_nzr = np.asarray(ds.nzr_dev)

@@ -3,8 +3,8 @@
 The dispatcher no longer validates device-state reuse with full [N, R]
 ``np.array_equal`` sweeps: ``NodeTensorCache.update`` returns a
 ``TensorDelta`` (changed rows + monotonic epochs) and
-``BatchScheduler._negotiate_device_state`` reconciles O(changed rows)
-against the committer-mirrored expectation. These tests drive the
+``DeviceNodeState.negotiate`` (scheduler/device_state.py) reconciles
+O(changed rows) against the committer-mirrored expectation. These tests drive the
 handshake directly: the ahead-by-K committer-lag case, divergence
 scatter-fix, ring-overflow degradation, and the order-insensitive row
 remap.
@@ -21,6 +21,10 @@ from kubernetes_tpu.cache.snapshot import Snapshot
 from kubernetes_tpu.client.client import Client
 from kubernetes_tpu.client.informer import InformerFactory
 from kubernetes_tpu.scheduler.batch import _SHADOW_RING_CAP
+from kubernetes_tpu.scheduler.device_state import (
+    DELTA_ROW_BUCKET,
+    DeviceNodeState,
+)
 from kubernetes_tpu.scheduler.scheduler import new_scheduler
 from kubernetes_tpu.tensors import NodeTensorCache
 from kubernetes_tpu.testing import make_node, make_pod
@@ -48,44 +52,41 @@ def _cluster(n):
     return cache, snap
 
 
-def _negotiate(sched, nt, **kw):
-    kw.setdefault("overlaid", False)
-    kw.setdefault("pending_exists", False)
-    return sched._negotiate_device_state(
-        nt, nt.requested, nt.non_zero_requested, **kw
+def _negotiate(sched, nt, in_flight=False, assumed_seq=0):
+    """One dispatch's handshake with a jitted solve that lands: fake the
+    device refs the solve would have produced (content is irrelevant to
+    the handshake). ``in_flight`` batches are unmirrored ones."""
+    ds = sched.device_state
+    neg = ds.negotiate(
+        nt, sched.tensor_cache, nt.requested, nt.non_zero_requested,
+        False, in_flight=in_flight, unmirrored=in_flight,
+        assumed_seq=assumed_seq,
     )
+    if neg is not None:
+        ds.landed(neg, (object(), object(), object(), object()), False)
+    return neg
 
 
 def _prime(sched, nt):
-    """First dispatch: full upload route; fake the device refs the solve
-    would have produced (content is irrelevant to the handshake)."""
+    """First dispatch: full upload route."""
     neg = _negotiate(sched, nt)
-    assert neg == {
-        "static_ok": False,
-        "carry_ok": False,
-        "didx": neg["didx"],
-        "sidx": neg["sidx"],
-        "member": 0,
-    }
-    ds = sched._dev
-    ds.alloc_dev = object()
-    ds.valid_dev = object()
-    ds.req_dev = object()
-    ds.nzr_dev = object()
+    assert not neg.static_ok and not neg.carry_ok
+    assert neg.carry == "upload" and neg.carry_rows == nt.capacity
+    assert not neg.fix_rows.size and not neg.alloc_rows.size
+    assert neg.member_rows == 0 and neg.carry_in is None
     return neg
 
 
 def _mirror(sched, rows, req_rows, nzr_rows):
-    """What _complete_solve does when a batch commits: scatter-add the
-    placements into the running shadow and remember the per-row delta."""
-    ds = sched._dev
-    with sched._shadow_lock:
-        sched._dispatch_seq += 1
+    """What ``DeviceNodeState.mirror`` does when a batch commits:
+    scatter-add the placements into the running shadow and remember the
+    per-row delta."""
+    ds = sched.device_state
+    with ds._lock:
+        ds.seq += 1
         np.add.at(ds.req_shadow, rows, req_rows)
         np.add.at(ds.nzr_shadow, rows, nzr_rows)
-        ds.pending_deltas.append(
-            (rows, req_rows, nzr_rows, sched._dispatch_seq)
-        )
+        ds.pending_deltas.append((rows, req_rows, nzr_rows, ds.seq))
 
 
 def _pod_rows(nt, k):
@@ -110,8 +111,8 @@ class TestHandshake:
         assert sched.state_uploads == 1
         nt = sched.tensor_cache.update(snap)
         neg = _negotiate(sched, nt)
-        assert neg["carry_ok"] and neg["static_ok"]
-        assert neg["didx"].size == 0 and neg["sidx"].size == 0
+        assert neg.carry_ok and neg.static_ok
+        assert neg.fix_rows.size == 0 and neg.alloc_rows.size == 0
         assert sched.state_reuses == 1
         assert sched.delta_rows_uploaded == 0
 
@@ -132,10 +133,10 @@ class TestHandshake:
         nt = sched.tensor_cache.update(snap)
         assert nt.delta.changed_rows.tolist() == [2]
         neg = _negotiate(sched, nt)
-        assert neg["carry_ok"]
-        assert neg["didx"].size == 0
+        assert neg.carry_ok
+        assert neg.fix_rows.size == 0
         assert sched.state_uploads == 1
-        assert len(sched._dev.pending_deltas) == 0  # confirmed
+        assert len(sched.device_state.pending_deltas) == 0  # confirmed
 
     def test_ahead_by_k_committer_lag(self, sched_stack):
         """Regression for the ahead-by-K carry case: K batches mirrored
@@ -149,10 +150,10 @@ class TestHandshake:
         for i in range(k):
             _mirror(sched, *_pod_rows(nt, i % 5))
         nt = sched.tensor_cache.update(snap)  # host saw NOTHING yet
-        neg = _negotiate(sched, nt, pending_exists=True)
-        assert neg is not None and neg["carry_ok"]
+        neg = _negotiate(sched, nt, in_flight=True)
+        assert neg is not None and neg.carry_ok
         # nothing confirmed: the ring still holds all K deltas
-        assert len(sched._dev.pending_deltas) == k
+        assert len(sched.device_state.pending_deltas) == k
         assert sched.state_uploads == 1
 
     @pytest.mark.parametrize("assumed", [False, True])
@@ -172,7 +173,7 @@ class TestHandshake:
         nt = sched.tensor_cache.update(snap)
         _prime(sched, nt)
         _mirror(sched, *_pod_rows(nt, 2))
-        seq = sched._dev.pending_deltas[-1][3]
+        seq = sched.device_state.pending_deltas[-1][3]
         pod = make_pod("gone").node("hs-2").container(cpu="500m").obj()
         cache.add_pod(pod)
         cache.remove_pod(pod)
@@ -181,14 +182,14 @@ class TestHandshake:
         assert nt.delta.changed_rows.tolist() == [2]
         assert not nt.requested[2].any()
         neg = _negotiate(sched, nt, assumed_seq=seq if assumed else 0)
-        assert neg["carry_ok"] and sched.state_uploads == 1
+        assert neg.carry_ok and sched.state_uploads == 1
         if assumed:
-            assert neg["didx"].tolist() == [2]
-            assert not sched._dev.req_shadow[2].any()
+            assert neg.fix_rows.tolist() == [2]
+            assert not sched.device_state.req_shadow[2].any()
             assert sched.carry_divergences == 1
         else:
-            assert neg["didx"].size == 0
-            assert len(sched._dev.pending_deltas) == 1  # still trailing
+            assert neg.fix_rows.size == 0
+            assert len(sched.device_state.pending_deltas) == 1  # still trailing
 
     def test_ring_overflow_degrades_to_counted_upload(self, sched_stack):
         """More unobserved mirrors than the ring holds: the oldest delta
@@ -200,7 +201,7 @@ class TestHandshake:
         _prime(sched, nt)
         for i in range(_SHADOW_RING_CAP + 2):
             _mirror(sched, *_pod_rows(nt, i % 5))
-        assert len(sched._dev.pending_deltas) == _SHADOW_RING_CAP
+        assert len(sched.device_state.pending_deltas) == _SHADOW_RING_CAP
         # host now shows NONE of them; commits land in the cache so the
         # rows repack with host-side content the shadow can't explain
         for i in range(5):
@@ -212,7 +213,7 @@ class TestHandshake:
         cache.update_snapshot(snap)
         nt = sched.tensor_cache.update(snap)
         neg = _negotiate(sched, nt)
-        assert not neg["carry_ok"]
+        assert not neg.carry_ok
         assert sched.state_uploads == 2
         assert sched.carry_divergences >= 1
 
@@ -231,14 +232,14 @@ class TestHandshake:
         cache.update_snapshot(snap)
         nt = sched.tensor_cache.update(snap)
         neg = _negotiate(sched, nt)
-        assert neg["carry_ok"]
-        assert neg["didx"].tolist() == [3]
+        assert neg.carry_ok
+        assert neg.fix_rows.tolist() == [3]
         assert sched.carry_divergences == 1
         assert sched.delta_rows_uploaded == 1
         assert sched.state_uploads == 1  # no second full upload
         # shadow reconciled to host truth
         assert np.array_equal(
-            sched._dev.req_shadow[3], nt.requested[3]
+            sched.device_state.req_shadow[3], nt.requested[3]
         )
 
     def test_divergence_with_inflight_batches_drains(self, sched_stack):
@@ -254,7 +255,7 @@ class TestHandshake:
         cache.remove_pod(pod)
         cache.update_snapshot(snap)
         nt = sched.tensor_cache.update(snap)
-        assert _negotiate(sched, nt, pending_exists=True) is None
+        assert _negotiate(sched, nt, in_flight=True) is None
 
     def test_allocatable_change_rides_scatter(self, sched_stack):
         """A node's capacity update (same membership) patches the
@@ -269,11 +270,11 @@ class TestHandshake:
         cache.update_snapshot(snap)
         nt = sched.tensor_cache.update(snap)
         neg = _negotiate(sched, nt)
-        assert neg["carry_ok"] and neg["static_ok"]
-        assert neg["sidx"].tolist() == [4]
+        assert neg.carry_ok and neg.static_ok
+        assert neg.alloc_rows.tolist() == [4]
         assert sched.delta_rows_uploaded == 1
         assert np.array_equal(
-            sched._dev.alloc_shadow[4], nt.allocatable[4]
+            sched.device_state.alloc_shadow[4], nt.allocatable[4]
         )
 
     def test_node_add_rides_membership_scatter(self, sched_stack):
@@ -293,16 +294,16 @@ class TestHandshake:
         new_row = nt.row("hs-new")
         assert nt.delta.membership_rows.tolist() == [new_row]
         neg = _negotiate(sched, nt)
-        assert neg["static_ok"] and neg["carry_ok"]
-        assert neg["sidx"].tolist() == [new_row]
-        assert neg["member"] == 1
+        assert neg.static_ok and neg.carry_ok
+        assert neg.alloc_rows.tolist() == [new_row]
+        assert neg.member_rows == 1
         assert sched.state_uploads == 1  # still only the cold upload
         assert sched.state_reuses == 1
         assert sched.membership_row_patches == 1
         assert sched.carry_divergences == 0
         # the shadow adopted the new slot's host truth
         assert np.array_equal(
-            sched._dev.req_shadow[new_row], nt.requested[new_row]
+            sched.device_state.req_shadow[new_row], nt.requested[new_row]
         )
 
     def test_node_remove_rides_membership_scatter(self, sched_stack):
@@ -328,15 +329,15 @@ class TestHandshake:
         assert nt.names[row3] == ""
         assert not nt.valid[row3]
         neg = _negotiate(sched, nt)
-        assert neg["static_ok"] and neg["carry_ok"]
-        assert neg["sidx"].tolist() == [row3]
+        assert neg.static_ok and neg.carry_ok
+        assert neg.alloc_rows.tolist() == [row3]
         # the slot carried requested content on device: the didx scatter
         # must reset it (free slots are infeasible like padding)
-        assert neg["didx"].tolist() == [row3]
+        assert neg.fix_rows.tolist() == [row3]
         assert sched.state_uploads == 1
         assert sched.carry_divergences == 0
         assert sched.membership_row_patches == 1
-        assert (sched._dev.req_shadow[row3] == 0).all()
+        assert (sched.device_state.req_shadow[row3] == 0).all()
 
     def test_membership_with_inflight_batches_drains(self, sched_stack):
         """Membership churn while batches are in flight cannot be
@@ -350,7 +351,7 @@ class TestHandshake:
         )
         cache.update_snapshot(snap)
         nt = sched.tensor_cache.update(snap)
-        assert _negotiate(sched, nt, pending_exists=True) is None
+        assert _negotiate(sched, nt, in_flight=True) is None
 
     def test_headroom_exhaustion_full_repacks_once(self, sched_stack):
         """Adds past the pre-allocated slot headroom force ONE counted
@@ -374,8 +375,312 @@ class TestHandshake:
         assert tc.full_repacks == 2
         assert nt.capacity > cap
         neg = _negotiate(sched, nt)
-        assert not neg["static_ok"] and not neg["carry_ok"]
+        assert not neg.static_ok and not neg.carry_ok
         assert sched.state_uploads == 2
+
+
+# -- the module's own table: no scheduler, bare arrays and a tensor cache ----
+
+
+class _Table:
+    """A cluster, its ``NodeTensorCache`` and a bare ``DeviceNodeState``
+    whose first dispatch (the cold upload) has landed."""
+
+    def __init__(self, n=5, pods_on=()):
+        self.cache, self.snap = _cluster(n)
+        self.pods = {}
+        for k in pods_on:
+            self.add_pod(k)
+        self.tc = NodeTensorCache()
+        self.ds = DeviceNodeState(_SHADOW_RING_CAP)
+        self.repack()
+        self.land(self.negotiate())
+
+    def add_pod(self, k, cpu="500m"):
+        pod = make_pod(f"t-{k}").node(f"hs-{k}").container(cpu=cpu).obj()
+        # match the mirror's arithmetic: nzr defaults differ, so pin them
+        pod.__dict__["_nzr_memo"] = (500, 128 * 1024)
+        self.cache.add_pod(pod)
+        self.pods[k] = pod
+
+    def remove_pod(self, k):
+        self.cache.remove_pod(self.pods.pop(k))
+
+    def repack(self):
+        self.cache.update_snapshot(self.snap)
+        self.nt = self.tc.update(self.snap)
+        return self.nt
+
+    def negotiate(self, in_flight=False, unmirrored=False, assumed_seq=0,
+                  overlaid=False):
+        nt = self.nt
+        return self.ds.negotiate(
+            nt, self.tc, nt.requested, nt.non_zero_requested, overlaid,
+            in_flight=in_flight, unmirrored=unmirrored,
+            assumed_seq=assumed_seq,
+        )
+
+    def land(self, hs, overlaid=False):
+        """A jitted solve that placed nothing: the carry it returns is
+        the state it was handed."""
+        import jax.numpy as jnp
+
+        nt = self.nt
+        return self.ds.landed(hs, (
+            jnp.asarray(nt.requested), jnp.asarray(nt.non_zero_requested),
+            jnp.asarray(nt.allocatable), jnp.asarray(nt.valid),
+        ), overlaid)
+
+    def mirror(self, k):
+        """A batch that placed one 500m pod on row ``k`` commits."""
+        _rows, req_rows, nzr_rows = _pod_rows(self.nt, k)
+        record = {}
+        seq = self.ds.mirror(
+            record, np.asarray([k], dtype=np.int32), 1, req_rows, nzr_rows,
+            False,
+        )
+        assert record["mirrored"]
+        return seq
+
+    def counters(self):
+        ds = self.ds
+        return (
+            ds.state_uploads, ds.state_reuses, ds.delta_rows_uploaded,
+            ds.membership_row_patches,
+        )
+
+
+def _remove_node(t, k):
+    from kubernetes_tpu.api.types import Node, ObjectMeta
+
+    t.cache.remove_node(Node(metadata=ObjectMeta(name=f"hs-{k}")))
+
+
+def _nothing_moved(t):
+    return {}, dict(carry="reuse", carry_in=True)
+
+
+def _a_node_resized(t):
+    t.cache.add_node(
+        make_node("hs-4").capacity(cpu="32", memory="64Gi").obj()
+    )
+    return {}, dict(carry="scatter", alloc=[4], carry_in=True)
+
+
+def _an_external_delete(t):
+    t.remove_pod(3)  # never mirrored
+    return {}, dict(carry="scatter", fix=[3], divergences=1)
+
+
+def _a_divergence_under_a_pending_delta(t):
+    t.mirror(2)
+    t.add_pod(2, cpu="250m")  # not what the mirrored batch placed
+    return {}, dict(carry="upload", divergences=1, uploads=2, ring=0)
+
+
+def _a_node_joins_under_an_unmirrored_batch(t):
+    t.cache.add_node(
+        make_node("hs-new").capacity(cpu="8", memory="16Gi").obj()
+    )
+    return dict(in_flight=True, unmirrored=True), None
+
+
+def _a_node_leaves_with_its_delta_in_the_ring(t):
+    t.mirror(3)
+    _remove_node(t, 3)
+    return dict(in_flight=True), dict(
+        carry="scatter", alloc=[3], fix=[3], member=1, ring=0,
+    )
+
+
+def _more_divergent_rows_than_the_bucket(t):
+    for k in range(DELTA_ROW_BUCKET + 2):
+        t.remove_pod(k)
+    return {}, dict(carry="upload", divergences=1, uploads=2)
+
+
+def _the_layout_moved(t):
+    for i in range(t.nt.capacity - 5 + 1):  # one past the slot headroom
+        t.cache.add_node(
+            make_node(f"hs-x{i}").capacity(cpu="8", memory="16Gi").obj()
+        )
+    return {}, dict(carry="upload", static_ok=False, uploads=2)
+
+
+def _pr27_a_bound_and_deleted_batch_is_a_divergence(t):
+    seq = t.mirror(2)
+    t.add_pod(2)
+    t.remove_pod(2)  # bound, then deleted: the row is back where it was
+    return dict(assumed_seq=seq), dict(
+        carry="scatter", fix=[2], divergences=1, ring=0,
+    )
+
+
+def _a_batch_past_assumed_seq_is_a_lag(t):
+    t.mirror(2)
+    t.add_pod(2)
+    t.remove_pod(2)  # the same row, read by a pack that may trail it
+    return dict(assumed_seq=0), dict(carry="reuse", carry_in=True, ring=1)
+
+
+def _pr41_an_in_flight_pack_may_repair_rows(t):
+    t.remove_pod(3)
+    return dict(in_flight=True), dict(
+        carry="scatter", fix=[3], divergences=1, rewind=True,
+    )
+
+
+def _pr41_an_in_flight_pack_never_becomes_the_carry(t):
+    # what would be uploaded with nothing in flight (the case above)
+    t.mirror(2)
+    t.add_pod(2, cpu="250m")
+    return dict(in_flight=True), None
+
+
+_NEGOTIATIONS = [
+    _nothing_moved,
+    _a_node_resized,
+    _an_external_delete,
+    _a_divergence_under_a_pending_delta,
+    _a_node_joins_under_an_unmirrored_batch,
+    _a_node_leaves_with_its_delta_in_the_ring,
+    _more_divergent_rows_than_the_bucket,
+    _the_layout_moved,
+    _pr27_a_bound_and_deleted_batch_is_a_divergence,
+    _a_batch_past_assumed_seq_is_a_lag,
+    _pr41_an_in_flight_pack_may_repair_rows,
+    _pr41_an_in_flight_pack_never_becomes_the_carry,
+]
+
+
+@pytest.mark.parametrize(
+    "arrange", _NEGOTIATIONS, ids=lambda f: f.__name__.strip("_")
+)
+def test_negotiation_table(arrange):
+    """Each way a dispatch's node state can reach the device, from bare
+    arrays: what ``negotiate`` decides, what it leaves in the ring and
+    the shadows, and what the landed outcome books."""
+    big = arrange is _more_divergent_rows_than_the_bucket
+    n = DELTA_ROW_BUCKET + 6 if big else 5
+    t = _Table(n, pods_on=range(DELTA_ROW_BUCKET + 2) if big else (3,))
+    ds = t.ds
+    assert t.counters() == (1, 0, 0, 0)
+    kwargs, want = arrange(t)
+    nt = t.repack()
+    before = (t.counters(), len(ds.pending_deltas), ds.req_shadow.copy())
+    resident = (ds.req_dev, ds.nzr_dev)
+    hs = t.negotiate(**kwargs)
+    if want is None:
+        # blocked: the caller waits or drains, and nothing was touched
+        assert hs is None
+        assert t.counters() == before[0]
+        assert len(ds.pending_deltas) == before[1]
+        assert np.array_equal(ds.req_shadow, before[2])
+        return
+    assert hs.carry == want["carry"]
+    assert hs.static_ok == want.get("static_ok", True)
+    assert hs.carry_ok == (want["carry"] != "upload")
+    assert hs.fix_rows.tolist() == want.get("fix", [])
+    assert hs.alloc_rows.tolist() == want.get("alloc", [])
+    assert hs.member_rows == want.get("member", 0)
+    assert hs.row_patch_rewind == want.get("rewind", False)
+    rows = len(want.get("fix", [])) + len(want.get("alloc", []))
+    assert hs.carry_rows == (nt.capacity if not hs.carry_ok else rows)
+    # the pre-solve refs serve a rewind only where no row fix rode
+    if want.get("carry_in"):
+        assert hs.carry_in[0] is resident[0]
+        assert hs.carry_in[1] is resident[1]
+    else:
+        assert hs.carry_in is None
+    assert ds.carry_divergences == want.get("divergences", 0)
+    if "ring" in want:
+        assert len(ds.pending_deltas) == want["ring"]
+    # the expectation now holds host truth, for the rows it was shown
+    s = len(nt.names)
+    if "ring" not in want or want["ring"] == 0:
+        assert np.array_equal(ds.req_shadow[:s], nt.requested[:s])
+        assert np.array_equal(ds.alloc_shadow[:s], nt.allocatable[:s])
+    # nothing is booked until the outcome is known
+    assert t.counters() == before[0]
+    assert t.land(hs) == (not hs.carry_ok)
+    uploads = want.get("uploads", 1)
+    assert t.counters() == (
+        uploads, 2 - uploads, rows if hs.carry_ok else 0,
+        want.get("member", 0),
+    )
+
+
+def _host_placed(t, k):
+    """The host tier's answer: one 500m pod on row ``k``."""
+    _rows, req_rows, nzr_rows = _pod_rows(t.nt, k)
+    return np.asarray([k], dtype=np.int32), req_rows, nzr_rows
+
+
+@pytest.mark.parametrize("booked", ["reuse", "alloc-patch", "static-upload"])
+def test_nothing_landed_books_nothing(booked):
+    """The ladder was exhausted: the counters read what they read before
+    ``negotiate``, the carry drops, and the resident alloc is distrusted
+    exactly where the shadow claims a patch or an upload that never
+    reached the device."""
+    t = _Table()
+    ds = t.ds
+    if booked == "alloc-patch":
+        _a_node_resized(t)
+    elif booked == "static-upload":
+        _the_layout_moved(t)
+    t.repack()
+    before = t.counters()
+    hs = t.negotiate()
+    assert hs.static_ok == (booked != "static-upload")
+    ds.nothing_landed(hs)
+    assert t.counters() == before
+    assert ds.req_dev is None and ds.req_shadow is None
+    assert (ds.alloc_dev is None) == (booked != "reuse")
+    assert (ds.valid_dev is None) == (booked != "reuse")
+    # the next dispatch uploads what is missing, and is counted
+    t.repack()
+    hs = t.negotiate()
+    assert not hs.carry_ok and hs.static_ok == (booked == "reuse")
+    t.land(hs)
+    assert t.counters() == (before[0] + 1, *before[1:])
+
+
+@pytest.mark.parametrize("broken", [
+    None, "row-fix", "overlaid", "carry-lost", "upload",
+])
+def test_the_host_tier_keeps_the_carry_warm_under_four_conditions(broken):
+    """The host tier solved from host state: its own placements are
+    added to the resident carry only where that carry equals what it
+    solved from -- a reused carry, no row fix riding the dispatch, no
+    overlay, and the carry still resident. No link traffic is booked."""
+    t = _Table(pods_on=(3,))
+    ds = t.ds
+    overlaid = broken == "overlaid"
+    if broken == "row-fix":
+        t.remove_pod(3)
+    elif broken == "upload":
+        ds.invalidate()
+    t.repack()
+    before = t.counters()
+    hs = t.negotiate(overlaid=overlaid)
+    assert hs.carry_ok == (broken not in ("upload", "overlaid"))
+    if broken == "carry-lost":
+        ds.invalidate()  # a committer's recovery, mid-dispatch
+    carry = ds.req_dev
+    assignments, req_rows, nzr_rows = _host_placed(t, 1)
+    ds.host_solved(hs, assignments, req_rows, nzr_rows, overlaid)
+    assert t.counters() == (
+        before[0], before[1] + int(hs.carry_ok), before[2], before[3]
+    )
+    if broken is None:
+        grown = np.asarray(ds.req_dev) - np.asarray(carry)
+        assert grown[1].tolist() == req_rows[0].tolist()
+        assert not np.delete(grown, 1, axis=0).any()
+        assert ds.req_shadow is not None
+    else:
+        assert ds.req_dev is None and ds.req_shadow is None
+    # no alloc patch or static upload was booked: the alloc is trusted
+    assert ds.alloc_dev is not None and ds.valid_dev is not None
 
 
 class TestTensorDeltaMembership:
@@ -618,10 +923,10 @@ class TestRandomizedMembershipChurn:
             # -- handshake: carry must stay warm (scatters only) --------
             neg = _negotiate(sched, nt)
             assert neg is not None, f"step {step}: drain demanded"
-            assert neg["carry_ok"], f"step {step}: carry dropped"
+            assert neg.carry_ok, f"step {step}: carry dropped"
             s = len(nt.names)
             assert np.array_equal(
-                sched._dev.req_shadow[:s], nt.requested[:s]
+                sched.device_state.req_shadow[:s], nt.requested[:s]
             ), f"step {step}: shadow != host"
 
             # -- tensor content: equal to a fresh full pack per name ----
@@ -719,7 +1024,7 @@ class TestHostTierAllocBookkeeping:
                 if sched.schedule_batch(timeout=0.2):
                     break
             sched.wait_for_inflight_binds(timeout=30)
-            assert sched._dev.alloc_dev is not None
+            assert sched.device_state.alloc_dev is not None
             assert sched.state_uploads == 1
 
             # layout change: a node joins (full static upload booked)
@@ -752,11 +1057,11 @@ class TestHostTierAllocBookkeeping:
                 if sched.schedule_batch(timeout=0.2):
                     break
             sched.wait_for_inflight_binds(timeout=30)
-            assert sched._dev.alloc_dev is None, (
+            assert sched.device_state.alloc_dev is None, (
                 "stale device alloc survived a host-tier solve that "
                 "never uploaded the new layout"
             )
-            assert sched._dev.valid_dev is None
+            assert sched.device_state.valid_dev is None
 
             # device tier back: the next dispatch re-uploads in full
             # and places correctly against the 4-node layout

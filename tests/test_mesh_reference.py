@@ -9,7 +9,7 @@ deployment ``basic-50000`` at its rehearsal sizes.
 Closed waves with a bulk delete after each: a wave that binds and is
 deleted whole returns every node row to what it held before the wave,
 which the carry's handshake used to read as a host that had not yet
-seen the wave's commits (``BatchScheduler._explain_rows``), and the
+seen the wave's commits (``DeviceNodeState._explain_rows``), and the
 device went on placing around pods that were gone. Every wave's
 placements are compared with ``reference.bands`` at limit 0."""
 
@@ -21,7 +21,7 @@ from chipbench.generators import waves
 from chipbench.proving.shards import shard_counts
 # a wave's deletes change this many rows at most before the handshake
 # uploads the whole state instead of scattering rows
-from kubernetes_tpu.scheduler.batch import DELTA_ROW_BUCKET
+from kubernetes_tpu.scheduler.device_state import DELTA_ROW_BUCKET
 
 CELL = "basic-50000.mesh-burst-20k"
 DEVICES = 4

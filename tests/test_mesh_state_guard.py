@@ -291,7 +291,7 @@ def test_mesh_event_stream_differential_sharded_carry(monkeypatch):
             break
     sched.wait_for_inflight_binds(timeout=60)
 
-    ds = sched._dev
+    ds = sched.device_state
     assert ds.req_dev is not None, "sharded carry was dropped"
     # the resident state actually lives sharded over the node axis
     shard_rows = ds.req_dev.addressable_shards[0].data.shape[0]

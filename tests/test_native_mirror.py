@@ -3,8 +3,8 @@
 The bind-echo -> shadow-mirror hot loop (native/_hotpath.c
 mirror_scatter) compacts a batch's placed rows and scatter-adds their
 demand into the committer's shadow expectation in one C pass. Its
-pure-Python twin is scheduler/batch._mirror_scatter_py; the randomized
-suite here drives both over seeded assignment batches (NO_NODE
+pure-Python twin is scheduler/device_state._mirror_scatter_py; the
+randomized suite here drives both over seeded assignment batches (NO_NODE
 sprinkle, duplicate targets, empty batches) and asserts bit-equal
 shadows AND compacted outputs. The validate-before-mutate contract is
 pinned separately: an out-of-range assignment must raise before ANY
@@ -17,7 +17,10 @@ import pytest
 
 from kubernetes_tpu import native
 from kubernetes_tpu.ops.assignment import NO_NODE
-from kubernetes_tpu.scheduler.batch import _mirror_scatter, _mirror_scatter_py
+from kubernetes_tpu.scheduler.device_state import (
+    _mirror_scatter,
+    _mirror_scatter_py,
+)
 
 needs_native = pytest.mark.skipif(
     native.hotpath is None or native.hotpath.mirror_scatter is None,

@@ -236,6 +236,6 @@ def test_small_pod_carry_is_int32_and_matches_oracle():
     assert got == want
     assert sched.carry_divergences == 0
     assert sched.pods_fallback == 0
-    ds = sched._dev
+    ds = sched.device_state
     assert ds.req_dev.dtype == np.int32
     assert ds.nzr_dev.dtype == np.int32

@@ -1486,7 +1486,7 @@ def test_a_pack_that_predates_a_commit_never_becomes_the_carry():
     snap = Snapshot()
     sched.cache.update_snapshot(snap)
     truth = NodeTensorCache().update(snap)
-    shadow = sched._dev.req_shadow
+    shadow = sched.device_state.req_shadow
     assert int(shadow.sum()) == int(truth.requested.sum())
     assert np.array_equal(np.sort(shadow.sum(axis=1)),
                           np.sort(truth.requested.sum(axis=1)))

@@ -565,7 +565,7 @@ def bench_mesh_delta(num_nodes: int, mesh_devices: int):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from kubernetes_tpu.ops.assignment import shard_local_row_set
-    from kubernetes_tpu.scheduler.batch import DELTA_ROW_BUCKET
+    from kubernetes_tpu.scheduler.device_state import DELTA_ROW_BUCKET
 
     devs = jax.devices()
     n_dev = max(1, min(mesh_devices, len(devs)))
@@ -696,10 +696,8 @@ def bench_mesh_pallas(num_nodes: int, mesh_devices: int):
         solve_packed,
     )
     from kubernetes_tpu.ops.host_masks import mask_rows_upload
-    from kubernetes_tpu.scheduler.batch import (
-        MASK_ROW_BUCKET,
-        _delta_slot_pieces,
-    )
+    from kubernetes_tpu.scheduler.batch import MASK_ROW_BUCKET
+    from kubernetes_tpu.scheduler.device_state import delta_slot_pieces
 
     devs = jax.devices()
     n_dev = max(1, min(mesh_devices, len(devs)))
@@ -734,7 +732,7 @@ def bench_mesh_pallas(num_nodes: int, mesh_devices: int):
         ("alloc", alloc), ("valid", valid),
         ("req_state", requested), ("nzr_state", nzr),
     ]
-    delta_slots = _delta_slot_pieces(n, r)
+    delta_slots = delta_slot_pieces(n, r)
     eligible = mesh_pallas_candidate("greedy", n, mesh)
 
     def setup_tier(allow_pallas: bool):
