@@ -17,6 +17,7 @@ import numpy as np
 
 from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.cache.node_info import NodeInfo, pod_has_affinity_constraints
+from kubernetes_tpu.utils import flightrecorder
 
 #: the change log holds at least this many names, and twice the node
 #: count where that is more: one refresh notes each node at most once,
@@ -237,31 +238,37 @@ class Snapshot:
         each of the snapshot's node-spec epochs: a node's images are part
         of what moves the epoch, and ``refresh_lists`` drops the index
         with the positions it holds. A snapshot no cache feeds (epoch 0)
-        cannot tell when its nodes change, and walks at every call."""
+        cannot tell when its nodes change, and walks at every call. The
+        walk is a ``sched/pack.image_index`` span of a profiler's trace,
+        with the ``images`` and the (node, image) ``pairs`` it indexed."""
         index = self._image_holders if self.node_spec_epoch else None
         if index is None:
-            held: Dict[str, Tuple[List[int], List[int]]] = {}
-            for pos, states in [
-                (pos, ni.image_states)
-                for pos, ni in enumerate(self.node_info_list)
-                if ni.image_states
-            ]:
-                for image, size in states.items():
-                    entry = held.get(image)
-                    if entry is None:
-                        entry = held[image] = ([], [])
-                    entry[0].append(pos)
-                    entry[1].append(size)
-            index = self._image_holders = {
-                image: ImageHolders(
-                    np.array(positions, dtype=np.int64),
-                    np.array(sizes, dtype=np.float64),
-                    len(positions),
-                    max(sizes),
+            with flightrecorder.stage("pack.image_index") as building:
+                index = self._image_holders = self._build_image_holders()
+                building.set_metadata(
+                    images=len(index),
+                    pairs=sum(h.count for h in index.values()),
                 )
-                for image, (positions, sizes) in held.items()
-            }
         return index
+
+    def _build_image_holders(self) -> Dict[str, ImageHolders]:
+        held: Dict[str, Tuple[List[int], List[int]]] = {}
+        for pos, ni in enumerate(self.node_info_list):
+            for image, size in (ni.image_states or {}).items():
+                entry = held.get(image)
+                if entry is None:
+                    entry = held[image] = ([], [])
+                entry[0].append(pos)
+                entry[1].append(size)
+        return {
+            image: ImageHolders(
+                np.array(positions, dtype=np.int64),
+                np.array(sizes, dtype=np.float64),
+                len(positions),
+                max(sizes),
+            )
+            for image, (positions, sizes) in held.items()
+        }
 
 
 def new_snapshot(pods: Iterable[Pod], nodes: Iterable[Node]) -> Snapshot:

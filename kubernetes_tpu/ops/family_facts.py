@@ -69,11 +69,11 @@ UNSCOPED: Tuple = ((), ())
 ROW_INDEX = ("<row>",)
 
 #: the cumulative counters ``tally`` returns, in its order; the last
-#: three are ``pack_score_batch``'s (ops/scoring.py), which keeps its
+#: four are ``pack_score_batch``'s (ops/scoring.py), which keeps its
 #: facts on the snapshot and only counts here
 TALLY = ("nodes", "nodes_recounted", "node_rows", "node_rows_reused",
-         "templates", "score_live", "score_image_sigs",
-         "score_image_sigs_live")
+         "templates", "score_sigs", "score_live",
+         "score_image_sigs", "score_image_sigs_live")
 
 #: one term of a group: (namespaces, selector, selector signature)
 Term = Tuple[Tuple[str, ...], Optional[LabelSelector], Tuple]
@@ -221,6 +221,7 @@ class FamilyFacts:
         self.score_live = 0
         self.score_image_sigs = 0
         self.score_image_sigs_live = 0
+        self.score_sigs = 0
 
     def tally(self) -> Tuple[int, ...]:
         return tuple(getattr(self, name) for name in TALLY)

@@ -75,9 +75,15 @@ from kubernetes_tpu.tensors.node_tensor import (
     NodeTensor,
     value_capacity as _value_capacity_shared,
 )
-from kubernetes_tpu.utils import metrics
+from kubernetes_tpu.utils import flightrecorder, metrics
 
-MAX_SCORE_SIGS = 16
+#: the static rows of a LIVE score family, always: one shape, so what
+#: warm-up compiles is what every live batch runs, whatever it names.
+#: Three ``[64, n]`` operands are 4.3 MB of VMEM and of upload at 5,632
+#: node slots (``pallas_constrained.constrained_vmem_bytes`` counts them)
+MAX_SCORE_SIGS = 64
+#: the rows of an ABSENT family's placeholders: constants on the device,
+#: operands of the constrained kernel all the same, so kept small
 SIG_BUCKET = 4
 MAX_SEL_GROUPS = 8
 MAX_ZONES = 64
@@ -185,6 +191,18 @@ class ScoreEnvelopeExceeded(Exception):
     """Batch exceeds the device scoring envelope: fall back to host."""
 
 
+class ScoreSignatureCap(ScoreEnvelopeExceeded):
+    """The batch brings more than ``MAX_SCORE_SIGS`` static score rows.
+    ``fit`` is how many of its pods, in the order given, come before the
+    one that asked for the row past the cap: a batch of those is inside
+    it, so the dispatcher cuts there and keeps both parts on the device
+    (scheduler/batch.py ``ScoreSignatureCut``)."""
+
+    def __init__(self, fit: int) -> None:
+        super().__init__("too many score signatures")
+        self.fit = fit
+
+
 @dataclass
 class ScoreBatch:
     """Packed score state (greedy_assign_constrained ``scoring`` operand).
@@ -255,11 +273,25 @@ def _combined_sig(cs: CombinedSelector) -> Tuple:
     )
 
 
-def _static_sig(pod: Pod) -> Tuple:
-    images = tuple(sorted(c.image for c in pod.spec.containers if c.image))
+def _static_sig(
+    pod: Pod, image_scores: Dict, nodeaff: bool, taints: bool, avoid: bool
+) -> Tuple:
+    """What decides the pod's static score rows, and no more: a part
+    whose family is not live for the batch is left out, so pods share a
+    row whatever they differ in there. The container images (in the
+    plugin's order: its sum is taken in it) where that list scores some
+    node above 0 (``image_scores``, of ``_image_scores``); the preferred
+    node affinity where some pod of the batch has one; the tolerations
+    where some node carries a PreferNoSchedule taint; the controller
+    where some node asks to be avoided."""
+    images = None
+    if image_scores:
+        images = tuple([c.image for c in pod.spec.containers])
+        if images not in image_scores:
+            images = None
     aff = ()
     a = pod.spec.affinity
-    if a is not None and a.node_affinity is not None:
+    if nodeaff and a is not None and a.node_affinity is not None:
         aff = tuple(
             (
                 t.weight,
@@ -276,11 +308,13 @@ def _static_sig(pod: Pod) -> Tuple:
         )
     tols = tuple(
         (t.key, t.operator, t.value, t.effect) for t in pod.spec.tolerations
-    )
-    controller = next(
-        (r for r in pod.metadata.owner_references if r.controller), None
-    )
-    ctrl = (controller.kind, controller.uid) if controller else None
+    ) if taints else ()
+    ctrl = None
+    if avoid:
+        controller = next(
+            (r for r in pod.metadata.owner_references if r.controller), None
+        )
+        ctrl = (controller.kind, controller.uid) if controller else None
     return (images, aff, tols, ctrl)
 
 
@@ -417,8 +451,11 @@ def pack_score_batch(
     is the dispatcher's ``FamilyFacts``, whose tally takes what this call
     found: ``score_image_sigs`` (distinct image lists the batch names,
     counted where some node holds an image), ``score_image_sigs_live``
-    (those that score some node above 0) and ``score_live`` (a
-    ``ScoreBatch`` was returned)."""
+    (those that score some node above 0), ``score_live`` (a
+    ``ScoreBatch`` was returned) and ``score_sigs`` (the static rows its
+    pods asked for, ``_static_sig``). A batch that asks for more than
+    ``MAX_SCORE_SIGS`` rows raises ``ScoreSignatureCap`` with where to
+    cut it."""
     infos = snapshot.list_node_infos()
     n_cap = nt.capacity
     b = len(pods)
@@ -429,7 +466,8 @@ def pack_score_batch(
     w_img = float(weights.get("ImageLocality", 0))
     image_sigs, image_scores = 0, {}
     if any_images and w_img:
-        image_sigs, image_scores = _image_scores(pods, snapshot)
+        with flightrecorder.stage("pack.score.images"):
+            image_sigs, image_scores = _image_scores(pods, snapshot)
     need_images = bool(image_scores)
     if facts is not None:
         facts.score_image_sigs += image_sigs
@@ -499,20 +537,23 @@ def pack_score_batch(
     pod_sig = np.zeros(b, dtype=np.int32)
     sig_pods: List[Pod] = []
     for i, p in enumerate(pods):
-        sig = _static_sig(p)
+        sig = _static_sig(
+            p, image_scores, need_nodeaff, need_taint, need_avoid
+        )
         u = sig_ids.get(sig)
         if u is None:
             if len(sig_pods) >= MAX_SCORE_SIGS:
-                raise ScoreEnvelopeExceeded("too many score signatures")
+                raise ScoreSignatureCap(i)
             u = len(sig_pods)
             sig_ids[sig] = u
             sig_pods.append(p)
         pod_sig[i] = u
 
+    # one shape whatever the batch names: the rows past ``u_count`` stay 0
     u_count = len(sig_pods)
-    direct_rows = np.zeros((u_count, n_cap), dtype=np.float32)
-    nodeaff_rows = np.zeros((u_count, n_cap), dtype=np.int32)
-    taint_rows = np.zeros((u_count, n_cap), dtype=np.int32)
+    direct_rows = np.zeros((MAX_SCORE_SIGS, n_cap), dtype=np.float32)
+    nodeaff_rows = np.zeros((MAX_SCORE_SIGS, n_cap), dtype=np.int32)
+    taint_rows = np.zeros((MAX_SCORE_SIGS, n_cap), dtype=np.int32)
 
     w_avoid = float(weights.get("NodePreferAvoidPods", 0))
     if need_images:
@@ -560,34 +601,24 @@ def pack_score_batch(
                         )
                     )
 
-    u_padded = SIG_BUCKET * max(1, -(-u_count // SIG_BUCKET))
-    direct_rows = np.concatenate(
-        [direct_rows, np.zeros((u_padded - u_count, n_cap), np.float32)]
-    )
-    nodeaff_rows = np.concatenate(
-        [nodeaff_rows, np.zeros((u_padded - u_count, n_cap), np.int32)]
-    )
-    taint_rows = np.concatenate(
-        [taint_rows, np.zeros((u_padded - u_count, n_cap), np.int32)]
-    )
-
     # ---- zones ------------------------------------------------------------
-    zone_ids: Dict[str, int] = {}
-    zone_id = np.full(n_cap, -1, dtype=np.int32)
-    for j, ni in zip(node_rows, infos):
-        zk = get_zone_key(ni.node)
-        if not zk:
-            continue
-        z = zone_ids.get(zk)
-        if z is None:
-            if len(zone_ids) >= MAX_ZONES:
-                raise ScoreEnvelopeExceeded("too many zones")
-            z = len(zone_ids)
-            zone_ids[zk] = z
-        zone_id[j] = z
-    zone_onehot = np.zeros((n_cap, MAX_ZONES), dtype=bool)
-    present = zone_id >= 0
-    zone_onehot[np.nonzero(present)[0], zone_id[present]] = True
+    with flightrecorder.stage("pack.score.zones"):
+        zone_ids: Dict[str, int] = {}
+        zone_id = np.full(n_cap, -1, dtype=np.int32)
+        for j, ni in zip(node_rows, infos):
+            zk = get_zone_key(ni.node)
+            if not zk:
+                continue
+            z = zone_ids.get(zk)
+            if z is None:
+                if len(zone_ids) >= MAX_ZONES:
+                    raise ScoreEnvelopeExceeded("too many zones")
+                z = len(zone_ids)
+                zone_ids[zk] = z
+            zone_id[j] = z
+        zone_onehot = np.zeros((n_cap, MAX_ZONES), dtype=bool)
+        present = zone_id >= 0
+        zone_onehot[np.nonzero(present)[0], zone_id[present]] = True
 
     # ---- selector spread groups ------------------------------------------
     sel_counts = np.zeros((MAX_SEL_GROUPS, n_cap), dtype=np.int32)
@@ -827,6 +858,7 @@ def pack_score_batch(
     metrics.score_family_batches.inc(live="true")
     if facts is not None:
         facts.score_live += 1
+        facts.score_sigs += u_count
     return ScoreBatch(
         direct_rows=direct_rows,
         nodeaff_rows=nodeaff_rows,
@@ -884,12 +916,19 @@ def _avoid_score(pod: Pod, node) -> float:
     return 100.0
 
 
-def noop_score_tensors(padded: int, n_cap: int) -> Tuple[np.ndarray, ...]:
-    """All-inactive scoring tensors, in kernel argument order."""
+def noop_score_tensors(
+    padded: int, n_cap: int, live_shape: bool = False
+) -> Tuple[np.ndarray, ...]:
+    """All-inactive scoring tensors, in kernel argument order: the
+    placeholders of an absent family (``SIG_BUCKET`` static rows) or,
+    with ``live_shape``, what a live batch that scores nothing would
+    upload (``MAX_SCORE_SIGS`` rows), which is the shape warm-up
+    compiles the family at."""
+    sig_rows = MAX_SCORE_SIGS if live_shape else SIG_BUCKET
     return (
-        np.zeros((SIG_BUCKET, n_cap), dtype=np.float32),
-        np.zeros((SIG_BUCKET, n_cap), dtype=np.int32),
-        np.zeros((SIG_BUCKET, n_cap), dtype=np.int32),
+        np.zeros((sig_rows, n_cap), dtype=np.float32),
+        np.zeros((sig_rows, n_cap), dtype=np.int32),
+        np.zeros((sig_rows, n_cap), dtype=np.int32),
         np.zeros(padded, dtype=np.int32),
         np.zeros((MAX_SEL_GROUPS, n_cap), dtype=np.int32),
         np.zeros((n_cap, MAX_ZONES), dtype=bool),

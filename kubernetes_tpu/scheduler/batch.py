@@ -89,6 +89,7 @@ from kubernetes_tpu.ops.host_masks import (
 )
 from kubernetes_tpu.ops.scoring import (
     ScoreEnvelopeExceeded,
+    ScoreSignatureCap,
     batch_selector_spread_live,
     cluster_has_affinity_scoring,
     noop_score_tensors,
@@ -289,18 +290,39 @@ _FAMILY_COMBOS = (
 )
 
 
-def _family_pieces(fam_groups: dict, live) -> list:
-    """Packed pieces for one family combo: the ``live`` families ride
-    the buffer as real arrays, absent ones as ConstPiece device
-    constants (the single-device dispatch's ``fam_pieces`` contract)."""
+def _family_pieces(
+    padded: int, n: int, live, const_absent: bool = True
+) -> list:
+    """The no-op pieces of one family combo, as warm-up sends them: the
+    ``live`` families ride the buffer as real arrays in the shape of a
+    live batch's, absent ones as ConstPiece device constants (the
+    single-device dispatch's ``fam_pieces`` contract) or, on a mesh
+    (``const_absent`` false), as real arrays as well."""
     from kubernetes_tpu.ops.assignment import ConstPiece
 
+    groups = {
+        "sp": noop_spread_tensors(padded, n),
+        "af": noop_affinity_tensors(padded, n),
+        "sc": noop_score_tensors(padded, n, live_shape="sc" in live),
+    }
     return [
-        (f"{prefix}{i}", np.asarray(a)) if prefix in live
+        (f"{prefix}{i}", np.asarray(a))
+        if prefix in live or not const_absent
         else (f"{prefix}{i}", ConstPiece.from_uniform(a))
-        for prefix, arrs in fam_groups.items()
+        for prefix, arrs in groups.items()
         for i, a in enumerate(arrs)
     ]
+
+
+class ScoreSignatureCut(Exception):
+    """A batch asked for more static score rows than a live score family
+    carries (``ScoreSignatureCap``): ``head`` is inside the cap, ``tail``
+    is what came after it in the solve order. ``schedule_batch`` solves
+    the one, then the other, both on the device."""
+
+    def __init__(self, head: List[PodInfo], tail: List[PodInfo]) -> None:
+        super().__init__("batch cut at the score signature cap")
+        self.head, self.tail = head, tail
 
 
 def solver_supported(pod: Pod) -> bool:
@@ -406,6 +428,10 @@ class BatchScheduler(Scheduler):
         # perf-matrix visibility (VERDICT r2: the drain cliff and the
         # envelope fallbacks were unmetered)
         self.envelope_fallbacks = 0  # whole batches sent to host by packers
+        # batches past the score family's signature cap: cut in two on
+        # the device / sent whole to the host path (a gang's, a bisection's)
+        self.score_signature_cuts = 0
+        self.score_signature_host = 0
         self.pipeline_drains = 0  # constrained dispatch drained the pipeline
         self.gang_resolves = 0  # quorum-failure re-solves (_gang_fixup)
         self.nominee_constrained_fallbacks = 0  # nominees + constraints
@@ -642,13 +668,21 @@ class BatchScheduler(Scheduler):
         solver_infos: List[PodInfo] = []
 
         def flush() -> None:
-            if solver_infos:
-                if pipeline:
-                    self._solve_pipelined(solver_infos, pod_scheduling_cycle)
-                else:
-                    self._solve_and_commit(solver_infos, pod_scheduling_cycle)
+            runs = [list(solver_infos)] if solver_infos else []
+            solver_infos.clear()
+            while runs:
+                run = runs.pop(0)
+                try:
+                    if pipeline:
+                        self._solve_pipelined(run, pod_scheduling_cycle)
+                    else:
+                        self._solve_and_commit(run, pod_scheduling_cycle)
+                except ScoreSignatureCut as cut:
+                    # both parts next, in their order: no pod leaves the
+                    # dispatcher, none goes to the host path
+                    runs[:0] = [cut.head, cut.tail]
+                    continue
                 self.batches_solved += 1
-                solver_infos.clear()
 
         # admission is a precomputed-field read here: the classifier ran
         # at informer ingest (eventhandlers), so the hot loop does one
@@ -729,6 +763,8 @@ class BatchScheduler(Scheduler):
         except SchedulerCrashed:
             self._simulate_crash()  # no recovery: the process "died"
             return
+        except ScoreSignatureCut:
+            raise  # schedule_batch solves the two parts
         except Exception:
             # a pass of the gang fixup failed (a download that timed
             # out): the batch's pods go round again, none is stranded
@@ -2030,16 +2066,25 @@ class BatchScheduler(Scheduler):
             ) as families:
                 facts = self.family_facts
                 tally0 = facts.tally()
+                cut_at = None
                 try:
-                    score_batch = pack_score_batch(
-                        ordered_pods, snapshot, nt,
-                        prof0.informers if prof0 is not None else None,
-                        prof0.score_plugin_weights()
-                        if prof0 is not None else {},
-                        hard_pod_affinity_weight=hard_w,
-                        cluster_affinity_scoring=cluster_ipa,
-                        admissions=adms, facts=facts,
-                    )
+                    with flightrecorder.stage("pack.score"):
+                        score_batch = pack_score_batch(
+                            ordered_pods, snapshot, nt,
+                            prof0.informers if prof0 is not None else None,
+                            prof0.score_plugin_weights()
+                            if prof0 is not None else {},
+                            hard_pod_affinity_weight=hard_w,
+                            cluster_affinity_scoring=cluster_ipa,
+                            admissions=adms, facts=facts,
+                        )
+                except ScoreSignatureCap as cap:
+                    # cut where the cap is met, unless the batch has to
+                    # stay whole (a gang's passes, a bisection's halves);
+                    # routed either way, so no other family is packed
+                    if not (gangs or inactive_uids or raise_on_exhaust):
+                        cut_at = cap.fit
+                    routed = ("score_signatures", True)
                 except ScoreEnvelopeExceeded:
                     # the sequential path filters against the host
                     # cache, which must include every in-flight placement
@@ -2076,9 +2121,18 @@ class BatchScheduler(Scheduler):
                         family_facts.TALLY, facts.tally(), tally0
                     )
                 })
+            if cut_at is not None:
+                self.score_signature_cuts += 1
+                metrics.score_signature_caps.inc(action="cut")
+                span.finish(routed="score_signature_cut")
+                in_order = [solver_infos[int(i)] for i in order]
+                raise ScoreSignatureCut(in_order[:cut_at], in_order[cut_at:])
             if routed is not None:
                 # envelope exceeded: the host path keeps full correctness
                 reason, drain = routed
+                if reason == "score_signatures":
+                    self.score_signature_host += 1
+                    metrics.score_signature_caps.inc(action="host")
                 self.envelope_fallbacks += 1
                 if drain:
                     drain_inflight(reason)
@@ -2193,6 +2247,12 @@ class BatchScheduler(Scheduler):
             # scores MostAllocated
             "r_dims": int(nt.dims.num_dims),
             "score_most": int(bool(config.most_allocated_weight)),
+            # the static rows of the score family the call carries: 0
+            # where the family is not live
+            "score_sig_rows": (
+                0 if score_batch is None
+                else int(score_batch.direct_rows.shape[0])
+            ),
         }
         # single-buffer upload: the whole batch -- including a
         # constrained batch's ~40 family count tensors -- rides ONE
@@ -2241,9 +2301,11 @@ class BatchScheduler(Scheduler):
                 MESH absent families ride as real zero arrays
                 instead: every ConstPiece combo is its own layout
                 (= its own multi-second GSPMD compile), and the
-                mesh contract is ONE constrained jit signature per
-                mesh shape -- the upload cost of the noop tensors
-                is what the pre-delta mesh path always paid."""
+                mesh contract is TWO constrained jit signatures per
+                mesh shape, the score family absent (its few
+                placeholder rows) and live -- the upload cost of the
+                noop tensors is what the pre-delta mesh path always
+                paid."""
                 if packed_arrs is not None:
                     for i, a in enumerate(packed_arrs):
                         pieces.append((f"{prefix}{i}", np.asarray(a)))
@@ -3997,22 +4059,15 @@ class BatchScheduler(Scheduler):
         if not full:
             # extra (latency-rung) pads warm the basic path only
             return
-        noops = (
-            noop_spread_tensors(padded, n),
-            noop_affinity_tensors(padded, n),
-            noop_score_tensors(padded, n),
-        )
         if n > CONSTRAINED_NODE_CAP:
             return  # constrained batches route to the host path
         # compile the packed constrained layouts the run loop can hit
         # (cold / carry-refresh / steady), mirroring the basic-path
         # variants above -- a first constrained batch must not pay a
-        # multi-second XLA compile inside the measured window
-        fam = (
-            [(f"sp{i}", np.asarray(a)) for i, a in enumerate(noops[0])]
-            + [(f"af{i}", np.asarray(a)) for i, a in enumerate(noops[1])]
-            + [(f"sc{i}", np.asarray(a)) for i, a in enumerate(noops[2])]
-        )
+        # multi-second XLA compile inside the measured window. A live
+        # score family has one shape (ops/scoring.MAX_SCORE_SIGS static
+        # rows), so these layouts are every one a live batch can take
+        fam = _family_pieces(padded, n, _FAMILY_COMBOS[-1])
         c_cold = solve_packed(
             base + static_pieces + carry_pieces + fam,
             None, None, None, None,
@@ -4032,18 +4087,17 @@ class BatchScheduler(Scheduler):
         # family-combo layouts: warm the steady-carry variant of
         # every combo a measured phase can hit (the triple is
         # already warmed by c_cold/refresh/steady)
-        fam_groups = {"sp": noops[0], "af": noops[1], "sc": noops[2]}
         for live in _FAMILY_COMBOS[:-1]:
             out_one = solve_packed(
-                base + delta_slots + _family_pieces(fam_groups, live),
+                base + delta_slots + _family_pieces(padded, n, live),
                 alloc_d, valid_d, req_d, nzr_d,
                 config=config, mode="constrained",
             )
             jax.block_until_ready(out_one)
-        self._pallas_canary(nt, padded, fam_groups, config)
+        self._pallas_canary(nt, padded, config)
 
     def _pallas_canary(
-        self, nt, padded: int, fam_groups: dict, config: GreedyConfig
+        self, nt, padded: int, config: GreedyConfig
     ) -> None:
         """Hold every Pallas specialization warm-up just compiled to the
         XLA scan, on a seeded non-trivial problem, before the run loop
@@ -4111,7 +4165,7 @@ class BatchScheduler(Scheduler):
             # per combo, the family pieces riding the buffer (the basic
             # modes have none)
             probes = {mode: []} if mode != "constrained" else {
-                "+".join(live): _family_pieces(fam_groups, live)
+                "+".join(live): _family_pieces(padded, n, live)
                 for live in _FAMILY_COMBOS
             }
             bad = [
@@ -4148,14 +4202,15 @@ class BatchScheduler(Scheduler):
         steady-state delta-scatter -- for BOTH mesh tiers (the
         shard_map'd Pallas tier the ladder attempts first when
         mesh_pallas_candidate holds, and the GSPMD XLA twin the
-        breakers fall back to), plus the single constrained layout.
+        breakers fall back to), plus the constrained layouts.
         Absent families ride as real zero tensors on the mesh
-        (fam_pieces), so the constrained dispatch has exactly ONE
-        signature per (state-variant, mesh shape): the multichip
-        dryrun's zero-recompile probe (mesh_packed_cache_size) pins
-        that the steady phase never compiles past this set -- the probe
-        covers the Pallas-tier signatures too, since both tiers share
-        the one jitted mesh solver. The steady solve is re-run timed
+        (fam_pieces), so the constrained dispatch has exactly TWO
+        signatures per (state-variant, mesh shape), the score family
+        absent and live: the multichip dryrun's zero-recompile probe
+        (mesh_packed_cache_size) pins that the steady phase never
+        compiles past this set -- the probe covers the Pallas-tier
+        signatures too, since both tiers share the one jitted mesh
+        solver. The steady solve is re-run timed
         post-compile (pad_solve_seconds, on the tier dispatch will
         actually use) for the AutoBatchController rung ladder."""
         from kubernetes_tpu.ops.assignment import mesh_pallas_candidate
@@ -4222,31 +4277,26 @@ class BatchScheduler(Scheduler):
             # latency rungs warm the basic path only; over the
             # constrained node cap every constrained batch routes host
             return
-        noops = (
-            noop_spread_tensors(padded, n),
-            noop_affinity_tensors(padded, n),
-            noop_score_tensors(padded, n),
-        )
-        fam = (
-            [(f"sp{i}", np.asarray(a)) for i, a in enumerate(noops[0])]
-            + [(f"af{i}", np.asarray(a)) for i, a in enumerate(noops[1])]
-            + [(f"sc{i}", np.asarray(a)) for i, a in enumerate(noops[2])]
-        )
         ckw = dict(
             config=config, mode="constrained", mesh=self.mesh,
         )
-        jax.block_until_ready(solve_packed(
-            base + static_pieces + carry_pieces + fam,
-            None, None, None, None, **ckw,
-        ))
-        jax.block_until_ready(solve_packed(
-            base + carry_pieces + fam, alloc_d, valid_d, None, None,
-            **ckw,
-        ))
-        jax.block_until_ready(solve_packed(
-            base + delta_slots + fam, alloc_d, valid_d, req_d, nzr_d,
-            **ckw,
-        ))
+        # one signature a state variant where the score family is absent
+        # (its placeholders' few static rows, as real arrays) and one
+        # where it is live (a live batch's rows, ops/scoring.py)
+        for live in ((), ("sc",)):
+            fam = _family_pieces(padded, n, live, const_absent=False)
+            jax.block_until_ready(solve_packed(
+                base + static_pieces + carry_pieces + fam,
+                None, None, None, None, **ckw,
+            ))
+            jax.block_until_ready(solve_packed(
+                base + carry_pieces + fam, alloc_d, valid_d, None, None,
+                **ckw,
+            ))
+            jax.block_until_ready(solve_packed(
+                base + delta_slots + fam, alloc_d, valid_d, req_d, nzr_d,
+                **ckw,
+            ))
 
     # -- loop ---------------------------------------------------------------
 

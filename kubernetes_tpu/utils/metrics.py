@@ -467,6 +467,15 @@ score_family_batches = registry.register(Counter(
     "family's envelope goes to the host path and is not counted.",
     ("live",),
 ))
+score_signature_caps = registry.register(Counter(
+    "scheduler_score_signature_cap_total",
+    "Batches that asked for more static score rows than the device "
+    "carries (ops/scoring.MAX_SCORE_SIGS), by action: cut where the batch "
+    "was cut at the pod that asked for the row past the cap and both parts "
+    "stayed on the device, host where it could not be cut (a gang's batch, "
+    "a bisection's) and went whole to the host path.",
+    ("action",),
+))
 solves_by_resource_score = registry.register(Counter(
     "scheduler_solves_by_resource_score_total",
     "Solver batches, by the resource score rule they were solved under: "

@@ -727,7 +727,9 @@ def test_a_fresh_schedulers_totals_are_its_own():
 
 #: the stages that are given no totals, which the primitive does not clock
 UNCLOCKED = {"sched/dispatch", "sched/commit.gather", "sched/commit.clone",
-             "sched/commit.assume"}
+             "sched/commit.assume", "sched/pack.score",
+             "sched/pack.score.images", "sched/pack.score.zones",
+             "sched/pack.image_index"}
 TICK_MS = 10.0  # the coarsest CPU clock a host of ours has
 
 

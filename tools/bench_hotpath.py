@@ -1545,7 +1545,8 @@ HOT_STAGES = ("pop_batch", "pack", "device_solve", "download", "commit")
 BATCH_STAGES = (
     "pop_wait", "pop_batch", "dispatch", "pack", "pack.drain",
     "pack.snapshot", "pack.state", "pack.pods", "pack.masks",
-    "pack.families", "device_solve", "inflight_wait", "download", "commit",
+    "pack.families", "pack.score", "device_solve", "inflight_wait",
+    "download", "commit",
     "commit.gather", "commit.clone", "commit.assume", "bind", "bind.api",
 )
 
