@@ -27,6 +27,20 @@ for as long as the code can see that it still holds, and no longer:
   constraints)``; the packers build each template's rows once and
   write them for all its pods with one indexed numpy write.
 
+The score packer (ops/scoring.py ``pack_score_batch``) keeps its own
+node-side rows here too, and builds them itself:
+
+- **the zone rows**: every node row's zone and the one-hot of it, or
+  the verdict that the nodes name more zones than the tensors hold.
+  They read the Node objects' labels and the row map alone, so they
+  stand as the node-value rows do.
+- **the image rows**: for a container image list, ImageLocality's
+  weighted score on every node row, or the verdict that the list
+  scores 0 everywhere. They are built from ``Snapshot.image_holders()``
+  and stand while that index is the same object (the snapshot drops it
+  when the epoch moves and when ``refresh_lists`` moves the positions
+  it holds) and the slot list is.
+
 The dispatcher owns one ``FamilyFacts`` beside its ``MaskRowCache`` and
 hands it to the packers. Without one, or on a snapshot no cache feeds
 (``node_spec_epoch`` 0), ``attach`` hands out a fresh object that is
@@ -38,7 +52,9 @@ family pods; no cache write, commit or ingest path knows of it.
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Dict, FrozenSet, List, NamedTuple, Optional, Sequence, Tuple
+from typing import (
+    Callable, Dict, FrozenSet, List, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import numpy as np
 
@@ -55,8 +71,12 @@ from kubernetes_tpu.tensors.node_tensor import NodeTensor, value_capacity
 
 #: node-value rows kept, least recently used out first (a row is five
 #: bytes a node slot); selector memos kept, oldest out first; template
-#: keys held so that the pods of one template share one key object
+#: keys held so that the pods of one template share one key object;
+#: image lists whose score row is kept, least recently used out first (a
+#: live list's row is four bytes a node slot, 48 lists of a 5,632-slot
+#: tensor 1 MB; a list that scores nothing keeps its verdict alone)
 ROWS_KEPT = 64
+IMAGE_ROWS_KEPT = 256
 SELECTORS_KEPT = 256
 TEMPLATES_KEPT = 4096
 
@@ -69,11 +89,17 @@ UNSCOPED: Tuple = ((), ())
 ROW_INDEX = ("<row>",)
 
 #: the cumulative counters ``tally`` returns, in its order; the last
-#: four are ``pack_score_batch``'s (ops/scoring.py), which keeps its
-#: facts on the snapshot and only counts here
+#: six are ``pack_score_batch``'s (ops/scoring.py): ``score_node_rows``
+#: the node-side rows it asked for (one for the zones, one for each
+#: distinct image list it looked at), ``score_node_rows_reused`` those
+#: it did not have to build
 TALLY = ("nodes", "nodes_recounted", "node_rows", "node_rows_reused",
          "templates", "score_sigs", "score_live",
-         "score_image_sigs", "score_image_sigs_live")
+         "score_image_sigs", "score_image_sigs_live",
+         "score_node_rows", "score_node_rows_reused")
+
+#: the zone rows' slot before the first build (None is a verdict)
+_UNBUILT = object()
 
 #: one term of a group: (namespaces, selector, selector signature)
 Term = Tuple[Tuple[str, ...], Optional[LabelSelector], Tuple]
@@ -198,6 +224,14 @@ class FamilyFacts:
         # node-value rows (None: more values than slots)
         self._rows: "OrderedDict[Tuple, Optional[NodeValues]]" = OrderedDict()
         self._incomplete: Dict[str, bool] = {}
+        # the score packer's rows: (zone_id, zone_onehot), None where the
+        # zones are too many; image list -> weighted row, None where the
+        # list scores nothing, for ``_image_index`` alone
+        self._zones: object = _UNBUILT
+        self._image_index: Optional[Dict] = None
+        self._image_rows: "OrderedDict[Tuple, Optional[np.ndarray]]" = (
+            OrderedDict()
+        )
         # the census
         self._cursor: Optional[int] = None  # None: recount every node
         self._counted = False  # the census is this attach's snapshot's
@@ -222,6 +256,8 @@ class FamilyFacts:
         self.score_image_sigs = 0
         self.score_image_sigs_live = 0
         self.score_sigs = 0
+        self.score_node_rows = 0
+        self.score_node_rows_reused = 0
 
     def tally(self) -> Tuple[int, ...]:
         return tuple(getattr(self, name) for name in TALLY)
@@ -235,6 +271,9 @@ class FamilyFacts:
             self._epoch = epoch
             self._rows.clear()
             self._incomplete.clear()
+            self._zones = _UNBUILT
+            self._image_index = None
+            self._image_rows.clear()
         if moved:
             self._snapshot = snapshot
             self._names = nt.names
@@ -323,6 +362,41 @@ class FamilyFacts:
                 for ni in self.infos
             )
         return v
+
+    # -- the score packer's rows ---------------------------------------------
+
+    def score_zones(
+        self, build: Callable[[], Optional[Tuple]]
+    ) -> Optional[Tuple]:
+        """The zone rows: ``build()``'s answer, asked for once while the
+        node-value rows stand."""
+        self.score_node_rows += 1
+        if self._zones is _UNBUILT:
+            self._zones = build()
+        else:
+            self.score_node_rows_reused += 1
+        return self._zones
+
+    def score_image_row(
+        self, index: Dict, key: Tuple,
+        build: Callable[[], Optional[np.ndarray]],
+    ) -> Optional[np.ndarray]:
+        """The row of the image list ``key``: ``build()``'s answer, asked
+        for once while ``index`` (``Snapshot.image_holders()``, held here
+        so that no later index can take its identity) is the snapshot's."""
+        rows = self._image_rows
+        if index is not self._image_index:
+            self._image_index = index
+            rows.clear()
+        self.score_node_rows += 1
+        if key in rows:
+            rows.move_to_end(key)
+            self.score_node_rows_reused += 1
+            return rows[key]
+        row = rows[key] = build()
+        if len(rows) > IMAGE_ROWS_KEPT:
+            rows.popitem(last=False)
+        return row
 
     # -- the census -----------------------------------------------------------
 

@@ -21,8 +21,10 @@ the sequential path:
   node list (helper/normalize_score.go). ImageLocality is decided and
   built from the images' side (``Snapshot.image_holders``, one walk a
   node-spec epoch): a batch none of whose image lists can score a node
-  above 0 carries no row for it, and a live row is written at the
-  holders alone; the three others ask every node for each signature.
+  above 0 carries no row for it, and a live list's row is built at the
+  holders alone, once for as long as that index and the tensor's slot
+  list stand (kept, with the zone rows, in the dispatcher's
+  ``FamilyFacts``); the three others ask every node for each signature.
 - **selector spread** (DefaultPodTopologySpread,
   default_pod_topology_spread.go:107) -- per combined-selector-group
   match counts per node, zone-blended (2/3) at normalize; counts replay
@@ -58,6 +60,7 @@ from kubernetes_tpu.api.types import (
     TAINT_EFFECT_PREFER_NO_SCHEDULE,
 )
 from kubernetes_tpu.cache.snapshot import Snapshot
+from kubernetes_tpu.ops import family_facts
 from kubernetes_tpu.plugins.imagelocality import ImageLocality
 from kubernetes_tpu.plugins.nodeaffinity import match_node_selector_term
 from kubernetes_tpu.plugins.nodepreferavoidpods import (
@@ -382,30 +385,24 @@ def _node_side_facts(snapshot: Snapshot) -> Tuple[bool, bool, bool]:
     return facts
 
 
-def _image_scores(
-    pods: List[Pod], snapshot: Snapshot
-) -> Tuple[int, Dict[Tuple[str, ...], Tuple[np.ndarray, np.ndarray]]]:
+def _image_rows(
+    pods: List[Pod], snapshot: Snapshot, nt: NodeTensor, w_img: float,
+    kept: family_facts.FamilyFacts,
+) -> Tuple[int, Dict[Tuple[str, ...], np.ndarray]]:
     """ImageLocality for the batch, from the images' side
     (``Snapshot.image_holders``): how many distinct container image lists
     of ``pods`` name an image, and for each list that scores some node
-    above 0 the (positions in ``node_info_list``, scores there) of those
-    that hold any of its images; every other node, and every node of a
-    list left out, scores 0 (image_locality.go:60-76).
-
-    A list is first held to the most any node could have: every image of
-    it at the largest size reported, on the share of nodes that hold it.
-    A node's own sum takes a subset of those terms, in the same order,
-    with sizes no larger, and float addition and ``calculatePriority``
-    are monotonic, so a bound of 0 is every node's 0 exactly: no array is
-    touched for an image no node holds, or one too small or on too few
-    nodes to pass the plugin's threshold. Past the bound each holder's
-    sum is taken over the containers in order, in float64, and put
-    through the plugin's own ``calculatePriority``: the plugin's value
-    for that node, bit for bit."""
+    above 0 its row ``[n_cap]`` float32, ``w_img`` times the plugin's
+    score at the tensor row of every node that holds any of its images
+    and 0 elsewhere; every node of a list left out scores 0
+    (image_locality.go:60-76). A list's row, or its being left out,
+    depends on the image index, the node -> row map and the weight
+    alone, so ``kept`` holds it for as long as the first two stand
+    (``FamilyFacts.score_image_row``) and it is built where it is not
+    there. The rows are shared between batches and not writeable."""
     holders = snapshot.image_holders()
-    total_nodes = snapshot.num_nodes()
     named = 0
-    live: Dict[Tuple[str, ...], Tuple[np.ndarray, np.ndarray]] = {}
+    live: Dict[Tuple[str, ...], np.ndarray] = {}
     seen = set()
     for p in pods:
         images = tuple([c.image for c in p.spec.containers])
@@ -413,24 +410,82 @@ def _image_scores(
             continue
         seen.add(images)
         named += any(images)
-        held = [h for h in map(holders.get, images) if h is not None]
-        bound = 0.0
-        for h in held:
-            bound += h.largest * (h.count / total_nodes)
-        if not ImageLocality._calculate_priority(bound):
-            continue
-        sums = np.zeros(total_nodes, dtype=np.float64)
-        for h in held:
-            sums[h.positions] += h.sizes * (h.count / total_nodes)
-        positions = np.nonzero(sums)[0]
-        distinct, which = np.unique(sums[positions], return_inverse=True)
-        scores = np.array(
-            [ImageLocality._calculate_priority(float(x)) for x in distinct],
-            dtype=np.int64,
-        )[which]
-        if scores.any():
-            live[images] = (positions, scores)
+        row = kept.score_image_row(
+            holders, (w_img, images),
+            lambda: _image_row(images, holders, snapshot, nt, w_img),
+        )
+        if row is not None:
+            live[images] = row
     return named, live
+
+
+def _image_row(
+    images: Tuple[str, ...], holders: Dict, snapshot: Snapshot,
+    nt: NodeTensor, w_img: float,
+) -> Optional[np.ndarray]:
+    """One image list's weighted row, or None where it scores 0 on
+    every node.
+
+    The list is first held to the most any node could have: every image
+    of it at the largest size reported, on the share of nodes that hold
+    it. A node's own sum takes a subset of those terms, in the same
+    order, with sizes no larger, and float addition and
+    ``calculatePriority`` are monotonic, so a bound of 0 is every node's
+    0 exactly: no array is touched for an image no node holds, or one too
+    small or on too few nodes to pass the plugin's threshold. Past the
+    bound each holder's sum is taken over the containers in order, in
+    float64, and put through the plugin's own ``calculatePriority``: the
+    plugin's value for that node, bit for bit."""
+    total_nodes = snapshot.num_nodes()
+    held = [h for h in map(holders.get, images) if h is not None]
+    bound = 0.0
+    for h in held:
+        bound += h.largest * (h.count / total_nodes)
+    if not ImageLocality._calculate_priority(bound):
+        return None
+    sums = np.zeros(total_nodes, dtype=np.float64)
+    for h in held:
+        sums[h.positions] += h.sizes * (h.count / total_nodes)
+    positions = np.nonzero(sums)[0]
+    distinct, which = np.unique(sums[positions], return_inverse=True)
+    scores = np.array(
+        [ImageLocality._calculate_priority(float(x)) for x in distinct],
+        dtype=np.int64,
+    )[which]
+    if not scores.any():
+        return None
+    row = np.zeros(nt.capacity, dtype=np.float32)
+    row[nt.rows_for(snapshot.list_node_infos())[positions]] = w_img * scores
+    row.flags.writeable = False
+    return row
+
+
+def _zone_rows(
+    infos, node_rows: List[int], n_cap: int
+) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """``(zone_id [n_cap] int32, zone_onehot [n_cap, MAX_ZONES] bool)``
+    of the nodes ``infos`` at the tensor rows ``node_rows``, zones
+    interned first-seen in that order; None where the nodes name more
+    than ``MAX_ZONES`` zones. Shared between batches and not writeable."""
+    zone_ids: Dict[str, int] = {}
+    zone_id = np.full(n_cap, -1, dtype=np.int32)
+    for j, ni in zip(node_rows, infos):
+        zk = get_zone_key(ni.node)
+        if not zk:
+            continue
+        z = zone_ids.get(zk)
+        if z is None:
+            if len(zone_ids) >= MAX_ZONES:
+                return None
+            z = len(zone_ids)
+            zone_ids[zk] = z
+        zone_id[j] = z
+    zone_onehot = np.zeros((n_cap, MAX_ZONES), dtype=bool)
+    present = zone_id >= 0
+    zone_onehot[np.nonzero(present)[0], zone_id[present]] = True
+    zone_id.flags.writeable = False
+    zone_onehot.flags.writeable = False
+    return zone_id, zone_onehot
 
 
 def pack_score_batch(
@@ -465,9 +520,15 @@ def pack_score_batch(
     # some node above 0: a row of zeros ranks as no row does
     w_img = float(weights.get("ImageLocality", 0))
     image_sigs, image_scores = 0, {}
+    # where the node-side rows are kept: ``facts`` made valid for this
+    # snapshot and tensor, or an object dropped with the batch
+    kept = None
     if any_images and w_img:
+        kept = family_facts.attach(facts, snapshot, nt)
         with flightrecorder.stage("pack.score.images"):
-            image_sigs, image_scores = _image_scores(pods, snapshot)
+            image_sigs, image_scores = _image_rows(
+                pods, snapshot, nt, w_img, kept
+            )
     need_images = bool(image_scores)
     if facts is not None:
         facts.score_image_sigs += image_sigs
@@ -558,12 +619,11 @@ def pack_score_batch(
     w_avoid = float(weights.get("NodePreferAvoidPods", 0))
     if need_images:
         for u, p in enumerate(sig_pods):
-            scored = image_scores.get(
+            row = image_scores.get(
                 tuple([c.image for c in p.spec.containers])
             )
-            if scored is not None:
-                positions, scores = scored
-                direct_rows[u, info_rows[positions]] = w_img * scores
+            if row is not None:
+                direct_rows[u] = row
     # the families no image index serves: every node, for each signature
     if need_avoid or need_nodeaff or need_taint:
         for u, p in enumerate(sig_pods):
@@ -603,22 +663,12 @@ def pack_score_batch(
 
     # ---- zones ------------------------------------------------------------
     with flightrecorder.stage("pack.score.zones"):
-        zone_ids: Dict[str, int] = {}
-        zone_id = np.full(n_cap, -1, dtype=np.int32)
-        for j, ni in zip(node_rows, infos):
-            zk = get_zone_key(ni.node)
-            if not zk:
-                continue
-            z = zone_ids.get(zk)
-            if z is None:
-                if len(zone_ids) >= MAX_ZONES:
-                    raise ScoreEnvelopeExceeded("too many zones")
-                z = len(zone_ids)
-                zone_ids[zk] = z
-            zone_id[j] = z
-        zone_onehot = np.zeros((n_cap, MAX_ZONES), dtype=bool)
-        present = zone_id >= 0
-        zone_onehot[np.nonzero(present)[0], zone_id[present]] = True
+        if kept is None:
+            kept = family_facts.attach(facts, snapshot, nt)
+        zones = kept.score_zones(lambda: _zone_rows(infos, node_rows, n_cap))
+        if zones is None:
+            raise ScoreEnvelopeExceeded("too many zones")
+        zone_id, zone_onehot = zones
 
     # ---- selector spread groups ------------------------------------------
     sel_counts = np.zeros((MAX_SEL_GROUPS, n_cap), dtype=np.int32)

@@ -342,7 +342,9 @@ def test_image_rows_equal_the_host_plugins_scores(seed, holding, weight):
     assert not got.dynamic
 
 
-def _run_images(seed, holding, batch):
+def _run_images(seed, holding, batch, waves=1):
+    """``waves`` batches of ``_image_pods``, each created once the one
+    before it is decided."""
     rng = random.Random(seed)
     server = APIServer()
     client = Client(server)
@@ -356,10 +358,13 @@ def _run_images(seed, holding, batch):
     informers.start()
     informers.wait_for_cache_sync()
     sched.queue.run()
-    for p in _image_pods(rng):
-        client.create_pod(p)
-    sched.start()
-    pods = _wait_decided(client, sched, 16)
+    for wave in range(waves):
+        for p in _image_pods(rng):
+            p.metadata.name = f"w{wave}-{p.metadata.name}"
+            client.create_pod(p)
+        if not wave:
+            sched.start()
+        pods = _wait_decided(client, sched, 16 * (wave + 1))
     sched.stop()
     informers.stop()
     placed = {p.metadata.name: p.spec.node_name for p in pods}
@@ -367,7 +372,16 @@ def _run_images(seed, holding, batch):
     if not batch:
         return placed, None
     assert sched.pods_fallback == 0
-    return placed, sched.family_facts.score_live
+    if waves == 1:
+        return placed, sched.family_facts.score_live
+    # the zone rows and every image list built once, whatever the batches
+    facts = sched.family_facts
+    lists = {tuple(c.image for c in p.spec.containers) for p in pods}
+    assert (
+        facts.score_node_rows - facts.score_node_rows_reused
+        == 1 + len(lists)
+    )
+    return placed, facts
 
 
 @pytest.mark.parametrize("holding,live", [("one_small", False), ("most", True)])
@@ -388,4 +402,19 @@ def test_image_batches_place_as_the_host_oracle_does(seed, holding, live):
     assert metrics.score_family_batches.value(live=other) == counted[other]
     assert bool(score_live) == live
     sequential, _ = _run_images(seed, holding, batch=False)
+    assert batch == sequential
+
+
+@pytest.mark.parametrize("seed", [3, 29])
+def test_a_batch_served_from_the_kept_rows_places_as_the_host_oracle_does(
+    seed,
+):
+    """Two live batches, one after the other, through ``BatchScheduler``:
+    the second finds the zone rows and its image lists' rows in the
+    dispatcher's ``FamilyFacts`` (the first one's binds move no Node
+    object), and both place as the sequential path does."""
+    batch, facts = _run_images(seed, "most", batch=True, waves=2)
+    assert facts.score_live >= 2
+    assert facts.score_node_rows_reused > facts.score_live - 1
+    sequential, _ = _run_images(seed, "most", batch=False, waves=2)
     assert batch == sequential

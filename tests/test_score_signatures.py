@@ -256,6 +256,54 @@ def test_the_batch_past_the_cap_is_cut_and_stays_on_the_device():
     assert placed == oracle
 
 
+def _run_twice(seed, apps, batch):
+    """Two batches of ``apps`` image lists, two pods each, the second
+    created when the first is decided."""
+    rng = random.Random(seed)
+    server = APIServer()
+    client = Client(server)
+    informers = InformerFactory(server)
+    sched = new_scheduler(
+        client, informers, batch=batch, max_batch=256,
+        percentage_of_nodes_to_score=100, rng=_KeepFirstRng(),
+    )
+    for node in _nodes(rng, apps):
+        client.create_node(node)
+    informers.start()
+    informers.wait_for_cache_sync()
+    sched.queue.run()
+    for wave in range(2):
+        for p in _pods(rng, apps):
+            p.metadata.name = f"w{wave}-{p.metadata.name}"
+            client.create_pod(p)
+        if not wave:
+            sched.start()
+        done = _wait_decided(client, sched, 2 * apps * (wave + 1))
+    sched.stop()
+    informers.stop()
+    placed = {p.metadata.name: p.spec.node_name for p in done}
+    assert all(placed.values())
+    return placed, sched
+
+
+def test_a_second_batch_takes_its_rows_from_the_first_and_places_as_the_oracle():
+    """The kept node-side rows through the dispatcher: the first batch
+    builds the zone rows and its 48 lists' rows, its binds move no Node
+    object, and every later batch is served from the store; placements
+    are the sequential path's, pod for pod."""
+    apps = 48
+    placed, sched = _run_twice(23, apps, batch=True)
+    assert sched.pods_fallback == 0
+    facts = sched.family_facts
+    assert facts.score_live == sched.batches_solved >= 2
+    assert facts.score_image_sigs_live >= 2 * apps
+    # nothing was built twice: the zones and each list once
+    assert facts.score_node_rows - facts.score_node_rows_reused == 1 + apps
+    assert facts.score_node_rows_reused >= 1 + apps
+    oracle, _ = _run_twice(23, apps, batch=False)
+    assert placed == oracle
+
+
 def _snapshot(seed, apps, soft_taints=False):
     rng = random.Random(seed)
     cache = SchedulerCache()
