@@ -198,8 +198,9 @@ class SchedulerApp:
             # event-driven headroom/release loop
             self.quota_controller.sync_all()
             self.quota_controller.start()
-        # Freeze the synced cluster graph out of cyclic-GC scanning
-        # (utils/gc_tuning.py rationale).
+        # Freeze the synced cluster graph out of cyclic-GC scanning. The
+        # walk before it times itself: the dispatcher's guard holds its
+        # own walks of the whole heap to that (utils/gc_tuning.py).
         from kubernetes_tpu.utils.gc_tuning import freeze_steady_state_graph
 
         freeze_steady_state_graph()

@@ -28,7 +28,7 @@ from kubernetes_tpu.plugins.queuesort import PrioritySort
 from kubernetes_tpu.queue.scheduling_queue import PriorityQueue
 from kubernetes_tpu.scheduler.scheduler import new_scheduler
 from kubernetes_tpu.testing import make_node, make_pod
-from kubernetes_tpu.utils import flightrecorder, metrics
+from kubernetes_tpu.utils import flightrecorder, gc_tuning, metrics
 from kubernetes_tpu.utils.gc_tuning import GCBatchGuard
 
 
@@ -189,20 +189,25 @@ def test_mark_lands_in_the_trace_and_in_the_ring(tmp_path):
     assert [m["kind"] for m in marks] == ["jit_recompile"]
 
 
-def test_the_guards_collections_are_gc_stages(tmp_path):
+def test_the_guards_collections_are_gc_stages(tmp_path, monkeypatch):
     totals = flightrecorder.StageTotals()
     guard = GCBatchGuard(totals)
+    # a whole walk on record that no idle collection of this test pays for
+    monkeypatch.setattr(gc_tuning, "_whole_walk_seconds", 3600.0)
     try:
         with profiled(tmp_path) as events:
             guard.active()
             guard._last_collect -= 2 * guard.ACTIVE_COLLECT_INTERVAL_S
             guard.active()  # overdue under sustained load: a gen-1 pass
-            guard.idle()  # active -> idle: the full pass
+            guard.idle()  # active -> idle: the full pass, survivors frozen
+            guard.close()  # the whole heap, and nothing left frozen
     finally:
+        gc.unfreeze()
         gc.enable()
-    assert totals.calls() == {"gc": 2}
-    assert [ev["stats"]["generation"] for ev in named(events, "sched/gc")] \
-        == [1, 2]
+    assert totals.calls() == {"gc": 3}
+    assert [(ev["stats"]["generation"], ev["stats"]["whole"])
+            for ev in named(events, "sched/gc")] == [(1, 0), (2, 0), (2, 1)]
+    assert (guard.freezes, guard.whole_walks) == (1, 1)
 
 
 def test_pop_wait_and_pop_work_are_side_by_side(tmp_path):
