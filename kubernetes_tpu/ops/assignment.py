@@ -911,7 +911,7 @@ def caps_for_families(sp_t, af_t, sc_t, sp_present, af_present, sc_present):
     for the rare escalation past DEFAULT_LIVE."""
     import numpy as _np
 
-    from kubernetes_tpu.ops.pallas_constrained import live_caps
+    from kubernetes_tpu.ops.pallas_constrained import _RP, live_caps
 
     def max_plus_one(a):
         a = _np.asarray(a)
@@ -934,10 +934,14 @@ def caps_for_families(sp_t, af_t, sc_t, sp_present, af_present, sc_present):
             max_plus_one(rp_rows),
             max_plus_one(sc_t[7]),
         )
+        # the packer's wide shape (ops/scoring.WIDE_IPA_ROWS)
+        sc_wide = sc_t[13].shape[0] > _RP
     else:
         sc_used = (0, 0, 0)
+        sc_wide = False
     return live_caps(
-        sp_present, af_present, sc_present, sp_used, af_used, sc_used
+        sp_present, af_present, sc_present, sp_used, af_used, sc_used,
+        sc_wide,
     )
 
 

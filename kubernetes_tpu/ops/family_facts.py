@@ -41,6 +41,19 @@ node-side rows here too, and builds them itself:
   when the epoch moves and when ``refresh_lists`` moves the positions
   it holds) and the slot list is.
 
+Its dynamic sections (selector spread, soft topology spread, preferred
+inter-pod affinity) read the census and the node-value rows as the hard
+families do, and one fact more:
+
+- **the term owners**: for every distinct scoring term some resident
+  carries (``interpodaffinity/scoring.go`` processExistingPod: preferred
+  affinity +w, preferred anti-affinity -w, required affinity times the
+  profile's hardPodAffinityWeight), the signed weight its owners put on
+  each node row. Counted from ``NodeInfo.pods_with_affinity`` at the
+  first ``term_owners()`` and with the census after that, node by node:
+  what the change log does not name is not walked again, and a
+  dispatcher whose batches never score by such terms counts none.
+
 The dispatcher owns one ``FamilyFacts`` beside its ``MaskRowCache`` and
 hands it to the packers. Without one, or on a snapshot no cache feeds
 (``node_spec_epoch`` 0), ``attach`` hands out a fresh object that is
@@ -89,14 +102,22 @@ UNSCOPED: Tuple = ((), ())
 ROW_INDEX = ("<row>",)
 
 #: the cumulative counters ``tally`` returns, in its order; the last
-#: six are ``pack_score_batch``'s (ops/scoring.py): ``score_node_rows``
+#: ten are ``pack_score_batch``'s (ops/scoring.py): ``score_node_rows``
 #: the node-side rows it asked for (one for the zones, one for each
 #: distinct image list it looked at), ``score_node_rows_reused`` those
-#: it did not have to build
+#: it did not have to build; of a batch whose dynamic families are live,
+#: ``score_dynamic_rows`` the selector-spread groups plus the
+#: preferred-affinity rows it carried (each kind is on its own span,
+#: ``pack.score.selectors`` ``groups`` and ``pack.score.ipa`` ``rows``),
+#: ``score_dynamic_cuts`` the batches a dynamic envelope cut in two,
+#: ``score_census_nodes`` the node rows the census answered for and
+#: ``score_census_recounted`` those of them it had to count again
 TALLY = ("nodes", "nodes_recounted", "node_rows", "node_rows_reused",
          "templates", "score_sigs", "score_live",
          "score_image_sigs", "score_image_sigs_live",
-         "score_node_rows", "score_node_rows_reused")
+         "score_node_rows", "score_node_rows_reused",
+         "score_dynamic_rows", "score_dynamic_cuts",
+         "score_census_nodes", "score_census_recounted")
 
 #: the zone rows' slot before the first build (None is a verdict)
 _UNBUILT = object()
@@ -213,6 +234,61 @@ class _PodClass:
         self.terminating: Dict[int, int] = {}  # of which terminating
 
 
+def _preferred(pod: Pod, anti: bool) -> List:
+    a = pod.spec.affinity
+    side = None if a is None else (
+        a.pod_anti_affinity if anti else a.pod_affinity
+    )
+    return [] if side is None else side.preferred_during_scheduling
+
+
+def scoring_terms(pod: Pod) -> Tuple:
+    """What ``pod`` scores every incoming pod with once it is placed
+    (scoring.go:111 processExistingPod), as ``(term signature, term,
+    signed preferred weight, required-affinity count)``: preferred
+    affinity +w, preferred anti-affinity -w, and each required affinity
+    term once, to be multiplied by the profile's hardPodAffinityWeight.
+    A pod's affinity does not change, so the tuple is kept on the pod."""
+    memo = pod.__dict__.get("_scoring_terms_memo")
+    if memo is None:
+        out = []
+        if pod.spec.affinity is not None:
+            for t in required_affinity(pod):
+                out.append((term_sig(pod, t), t, 0.0, 1))
+            for wt in _preferred(pod, anti=False):
+                t = wt.pod_affinity_term
+                out.append((term_sig(pod, t), t, float(wt.weight), 0))
+            for wt in _preferred(pod, anti=True):
+                t = wt.pod_affinity_term
+                out.append((term_sig(pod, t), t, -float(wt.weight), 0))
+        memo = pod.__dict__["_scoring_terms_memo"] = tuple(out)
+    return memo
+
+
+class TermOwners:
+    """The residents that carry one scoring term, by node row."""
+
+    __slots__ = ("sig", "selector", "preferred", "required")
+
+    def __init__(self, sig: Tuple, term: PodAffinityTerm) -> None:
+        self.sig = sig  # (namespaces, selector signature, topology key)
+        self.selector = term.label_selector
+        self.preferred: Dict[int, float] = {}  # node row -> signed weight
+        self.required: Dict[int, int] = {}  # node row -> owners
+
+    def mass(self, hard_weight: int) -> Tuple[np.ndarray, np.ndarray]:
+        """``(node rows, signed mass)`` at ``hard_weight`` a required
+        affinity term."""
+        at = dict(self.preferred)
+        if hard_weight > 0:
+            for j, count in self.required.items():
+                at[j] = at.get(j, 0.0) + float(hard_weight) * count
+        return (
+            np.fromiter(at.keys(), dtype=np.int64, count=len(at)),
+            np.fromiter(at.values(), dtype=np.float64, count=len(at)),
+        )
+
+
 class FamilyFacts:
     def __init__(self) -> None:
         self.keeps = True
@@ -238,6 +314,11 @@ class FamilyFacts:
         self._row_of: Dict[str, int] = {}
         self._classes: Dict[str, Dict[FrozenSet, _PodClass]] = {}
         self._on_row: Dict[int, List[_PodClass]] = {}
+        # the term owners: signature -> owners, and what each row gave;
+        # counted from the first ``term_owners()`` on
+        self._owners_kept = False
+        self._owners: Dict[Tuple, TermOwners] = {}
+        self._owned_on_row: Dict[int, List[Tuple]] = {}
         self._matches: "OrderedDict[Tuple, Dict[_PodClass, bool]]" = (
             OrderedDict()
         )
@@ -258,6 +339,10 @@ class FamilyFacts:
         self.score_sigs = 0
         self.score_node_rows = 0
         self.score_node_rows_reused = 0
+        self.score_dynamic_rows = 0
+        self.score_dynamic_cuts = 0
+        self.score_census_nodes = 0
+        self.score_census_recounted = 0
 
     def tally(self) -> Tuple[int, ...]:
         return tuple(getattr(self, name) for name in TALLY)
@@ -436,6 +521,8 @@ class FamilyFacts:
             self._row_of = {ni.node_name: j for j, ni in zip(rows, infos)}
             self._classes = {}
             self._on_row = {}
+            self._owners = {}
+            self._owned_on_row = {}
             pairs = list(zip(rows, infos))
         self._recount(pairs)
 
@@ -445,12 +532,15 @@ class FamilyFacts:
         on_row = self._on_row
         classes = self._classes
         emptied: List[_PodClass] = []
+        owners_kept = self._owners_kept
         for j, ni in pairs:
             for cls in on_row.pop(j, ()):
                 del cls.pods[j]
                 cls.terminating.pop(j, None)
                 if not cls.pods:
                     emptied.append(cls)
+            if owners_kept:
+                self._recount_owners(j, ni)
             if not ni.pods:
                 continue
             here: List[_PodClass] = []
@@ -479,6 +569,74 @@ class FamilyFacts:
             if not cls.pods and by_labels.get(cls.key) is cls:
                 del by_labels[cls.key]
 
+    def _recount_owners(self, j: int, ni: NodeInfo) -> None:
+        """The scoring terms the pods of row ``j`` carry, anew."""
+        if not ni.pods_with_affinity and j not in self._owned_on_row:
+            return
+        owners = self._owners
+        for sig in self._owned_on_row.pop(j, ()):
+            held = owners[sig]
+            held.preferred.pop(j, None)
+            held.required.pop(j, None)
+            if not held.preferred and not held.required:
+                del owners[sig]
+        here: List[Tuple] = []
+        for p in ni.pods_with_affinity:
+            for sig, term, weight, required in scoring_terms(p):
+                held = owners.get(sig)
+                if held is None:
+                    held = owners[sig] = TermOwners(sig, term)
+                if j not in held.preferred and j not in held.required:
+                    here.append(sig)
+                if required:
+                    held.required[j] = held.required.get(j, 0) + required
+                else:
+                    held.preferred[j] = held.preferred.get(j, 0.0) + weight
+        if here:
+            self._owned_on_row[j] = here
+
+    def term_owners(self) -> List[TermOwners]:
+        """Every scoring term some resident carries, with its owners'
+        weight by node row, in no order a caller may rest on. The first
+        call walks every node; the census keeps them from then on."""
+        if not self._counted:
+            self._advance()
+        if not self._owners_kept:
+            self._owners_kept = True
+            for j, ni in zip(self.info_rows(), self.infos):
+                self._recount_owners(j, ni)
+        return list(self._owners.values())
+
+    def matching_in(
+        self, namespace: str, sig: Tuple, matches: Callable[[Dict], bool]
+    ) -> List[_PodClass]:
+        """The resident classes of ``namespace`` whose labels ``matches``
+        accepts; ``sig`` names the predicate, and each (predicate, class)
+        pair is asked once while both live."""
+        if not self._counted:
+            self._advance()
+        by_labels = self._classes.get(namespace)
+        if not by_labels:
+            return []
+        memo = self._memo(sig)
+        out: List[_PodClass] = []
+        for cls in by_labels.values():
+            hit = memo.get(cls)
+            if hit is None:
+                hit = memo[cls] = bool(matches(cls.labels))
+            if hit:
+                out.append(cls)
+        return out
+
+    def _memo(self, sig: Tuple) -> Dict[_PodClass, bool]:
+        memos = self._matches
+        memo = memos.get(sig)
+        if memo is None:
+            memo = memos[sig] = {}
+            if len(memos) > SELECTORS_KEPT:
+                memos.popitem(last=False)
+        return memo
+
     def matching(self, terms: Sequence[Term]) -> List[_PodClass]:
         """The resident classes that match every one of ``terms``
         (PodMatchesTermsNamespaceAndSelector, topologies.go:40), each
@@ -500,12 +658,7 @@ class FamilyFacts:
         namespaces, selector, sel_sig = term
         if cls.namespace not in namespaces:
             return False
-        memos = self._matches
-        memo = memos.get(sel_sig)
-        if memo is None:
-            memo = memos[sel_sig] = {}
-            if len(memos) > SELECTORS_KEPT:
-                memos.popitem(last=False)
+        memo = self._memo(sel_sig)
         hit = memo.get(cls)
         if hit is None:
             hit = memo[cls] = labels_match_selector(cls.labels, selector)

@@ -80,6 +80,10 @@ _RE = 64        # affinity.MAX_EXIST_ROWS
 _GT = 16        # scoring.MAX_SOFT_GROUPS
 _RP = 16        # scoring.MAX_IPA_ROWS
 _G_SEL = 8      # scoring.MAX_SEL_GROUPS
+# the score packer's wide shape (scoring.WIDE_IPA_ROWS, WIDE_SEL_GROUPS):
+# a batch packed at it runs the specialization that holds all of it
+_RP_WIDE = 64
+_G_SEL_WIDE = 64
 
 
 class Caps(NamedTuple):
@@ -112,11 +116,16 @@ def live_caps(
     sp_used: int = 0,
     af_used: Tuple[int, int, int] = (0, 0, 0),
     sc_used: Tuple[int, int, int] = (0, 0, 0),
+    sc_wide: bool = False,
 ) -> Caps:
     """Caps for a batch: per packer family, absent -> 0 rows, present ->
     the DEFAULT_LIVE sizes, escalated to the packer maxima when usage
     exceeds them (usage beyond the maxima never reaches the solver --
-    the packers route such pods to the host path)."""
+    the packers route such pods to the host path). ``sc_wide``: the
+    score family came packed at its wide shape, which the score packer
+    chooses where the selector groups or the preferred-affinity rows are
+    past the maxima; those two then take the whole of it, and the soft
+    groups are sized by their own usage."""
     d = DEFAULT_LIVE
     if not sp_present:
         g_sp = 0
@@ -132,6 +141,9 @@ def live_caps(
         ra, rt, re = _RA, _RT, _RE
     if not sc_present:
         gt = rp = g_sel = 0
+    elif sc_wide:
+        gt = d.gt if sc_used[0] <= d.gt else _GT
+        rp, g_sel = _RP_WIDE, _G_SEL_WIDE
     elif (
         sc_used[0] <= d.gt and sc_used[1] <= d.rp
         and sc_used[2] <= d.g_sel
@@ -828,8 +840,6 @@ def pallas_constrained_solve(
     """Drop-in for ops/assignment.greedy_assign_constrained, fused into
     one Pallas kernel. Same family tuples, same return shape. ``caps``
     selects the family specialization (None = the packer maximums)."""
-    if caps is None:
-        caps = FULL_CAPS
     (sp_counts0, sp_value_valid, sp_node_value,
      sp_pod_groups, sp_pod_max_skew, sp_pod_self, sp_pod_match) = spread
     (af_node_value, af_counts_aff0, af_row_key_aff, af_pod_aff_rows,
@@ -845,6 +855,11 @@ def pallas_constrained_solve(
      sc_pod_ipa_weight, sc_pod_ipa_match, sc_pod_ipa_bump,
      sc_weights) = scoring
 
+    if caps is None:
+        # every row of the score family as it came packed
+        caps = FULL_CAPS._replace(
+            rp=sc_ipa_counts0.shape[0], g_sel=sc_sel_counts0.shape[0]
+        )
     b, r = pod_requests.shape
     n = allocatable.shape[0]
     assert sp_counts0.shape[0] == _G_SP, "spread group cap drifted"
@@ -852,8 +867,11 @@ def pallas_constrained_solve(
     assert af_counts_anti0.shape[0] == _RT
     assert af_counts_exist0.shape[0] == _RE
     assert sc_soft_counts0.shape[0] == _GT
-    assert sc_ipa_counts0.shape[0] == _RP
-    assert sc_sel_counts0.shape[0] == _G_SEL
+    assert (sc_ipa_counts0.shape[0], sc_sel_counts0.shape[0]) in (
+        (_RP, _G_SEL), (_RP_WIDE, _G_SEL_WIDE)
+    ), "score family shape drifted"
+    assert caps.rp <= sc_ipa_counts0.shape[0]
+    assert caps.g_sel <= sc_sel_counts0.shape[0]
 
     # -- prologue (XLA): node-space initial counts + dense pod params ---
     g_sp, ra, rt, re, gt, rp, g_sel = caps

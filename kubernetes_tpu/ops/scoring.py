@@ -45,12 +45,25 @@ the sequential path:
   placed pod bumps counts it matches and contributes its own terms'
   mass), normalized per step [min,max] -> [0,100] over the feasible set
   with zero-seeded extremes (scoring.go:294).
+
+The three dynamic families read what the dispatcher's ``FamilyFacts``
+keeps (ops/family_facts.py): the pod census (a group's or a term's
+matching residents by node row, advanced by the snapshot's change log),
+the node-value rows (a topology key's value on every node row), the term
+owners (the residents' signed weight by node row, counted with the
+census) and, for the batch's own side, one ``default_selector`` and one
+set of rows a pod TEMPLATE, not a pod. Their rows come in two shapes:
+``MAX_SEL_GROUPS`` / ``MAX_IPA_ROWS`` where batch and cluster fit them,
+``WIDE_SEL_GROUPS`` / ``WIDE_IPA_ROWS`` (a cluster's worth of Services
+and of charts' soft anti-affinity terms) where not; a batch whose pods
+ask for more than the wide shape is cut where the row past it is asked
+for (``ScoreEnvelopeCut``), as a batch past the static rows is.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -91,10 +104,16 @@ SIG_BUCKET = 4
 MAX_SEL_GROUPS = 8
 MAX_ZONES = 64
 MAX_SOFT_GROUPS = 16
-MAX_SOFT_VALUES = 128  # floor; grows to node capacity (hostname keys)
 MAX_SOFT_CONSTRAINTS = 4
 MAX_IPA_ROWS = 16
-MAX_IPA_VALUES = 128  # floor; tensors.node_tensor.value_capacity grows it
+#: the wide shape of the selector-spread and preferred-affinity rows: a
+#: batch (with the residents' terms, which make a row each whatever the
+#: batch holds) that needs more than the two maxima above carries these
+#: instead. At 5,632 node slots the constrained kernel holds them beside
+#: ``SIG_BUCKET`` static rows inside its VMEM gate, and not beside
+#: ``MAX_SCORE_SIGS`` (``pallas_constrained.constrained_vmem_bytes``)
+WIDE_SEL_GROUPS = 64
+WIDE_IPA_ROWS = 64
 
 
 def _preferred_aff_terms(pod: Pod):
@@ -125,11 +144,7 @@ def cluster_has_affinity_scoring(snapshot: Snapshot) -> bool:
     clusters need the preferred-affinity tensors for every batch."""
     for ni in snapshot.have_pods_with_affinity_list:
         for p in ni.pods_with_affinity:
-            if (
-                _required_aff_terms(p)
-                or _preferred_aff_terms(p)
-                or _preferred_anti_terms(p)
-            ):
+            if family_facts.scoring_terms(p):
                 return True
     return False
 
@@ -139,12 +154,7 @@ def batch_has_scoring_terms(pods: List[Pod]) -> bool:
     for later pods (preferred terms, or required affinity terms via
     hardPodAffinityWeight) -- an in-flight batch with such pods must
     land before a later batch packs its ipa tensors."""
-    return any(
-        _preferred_aff_terms(p)
-        or _preferred_anti_terms(p)
-        or _required_aff_terms(p)
-        for p in pods
-    )
+    return any(family_facts.scoring_terms(p) for p in pods)
 
 
 def batch_score_dynamic(
@@ -183,27 +193,51 @@ def batch_selector_spread_live(pods: List[Pod], informers) -> bool:
         )
     ):
         return False
-    return any(
-        not p.spec.topology_spread_constraints
-        and not default_selector(p, informers).empty
-        for p in pods
-    )
+    asked = set()
+    for p in pods:
+        if p.spec.topology_spread_constraints:
+            continue
+        # the selector reads the namespace and the labels alone
+        meta = p.metadata
+        key = (meta.namespace, frozenset(meta.labels.items()))
+        if key in asked:
+            continue
+        asked.add(key)
+        if not default_selector(p, informers).empty:
+            return True
+    return False
 
 
 class ScoreEnvelopeExceeded(Exception):
-    """Batch exceeds the device scoring envelope: fall back to host."""
+    """Batch exceeds the device scoring envelope: fall back to host.
+    ``reason`` names the envelope, and every one raised is counted under
+    it (``metrics.score_envelope_exceeded``)."""
+
+    def __init__(self, reason: str) -> None:
+        super().__init__(f"score envelope exceeded: {reason}")
+        self.reason = reason
+        metrics.score_envelope_exceeded.inc(reason=reason)
 
 
-class ScoreSignatureCap(ScoreEnvelopeExceeded):
-    """The batch brings more than ``MAX_SCORE_SIGS`` static score rows.
-    ``fit`` is how many of its pods, in the order given, come before the
-    one that asked for the row past the cap: a batch of those is inside
-    it, so the dispatcher cuts there and keeps both parts on the device
-    (scheduler/batch.py ``ScoreSignatureCut``)."""
+class ScoreEnvelopeCut(ScoreEnvelopeExceeded):
+    """The batch's own pods ask for more rows than the envelope
+    ``reason`` holds. ``fit`` (above 0) is how many of them, in the
+    order given, come before the one that asked for the row past it: a
+    batch of those is inside it, so the dispatcher cuts there and keeps
+    both parts on the device (scheduler/batch.py ``ScoreSignatureCut``)."""
 
-    def __init__(self, fit: int) -> None:
-        super().__init__("too many score signatures")
+    def __init__(self, reason: str, fit: int) -> None:
+        super().__init__(reason)
         self.fit = fit
+
+
+def _past(reason: str, fit: int) -> ScoreEnvelopeExceeded:
+    """The exception for a row asked for past the envelope ``reason`` by
+    the pod at ``fit``: a cut where pods come before it, else (the first
+    pod alone is past it) the host path."""
+    if fit > 0:
+        return ScoreEnvelopeCut(reason, fit)
+    return ScoreEnvelopeExceeded(reason)
 
 
 @dataclass
@@ -488,6 +522,229 @@ def _zone_rows(
     return zone_id, zone_onehot
 
 
+class _Templates(NamedTuple):
+    """A batch's pods by what the dynamic families read of them."""
+
+    index: np.ndarray  # [B] int64: the template of pod i
+    firsts: List[Pod]  # a template's first pod, in first-pod order
+    first_at: List[int]  # where in the batch that pod is
+
+
+def _score_template_key(pod: Pod) -> Tuple:
+    """Pods with equal keys get equal rows from the selector-spread and
+    preferred-affinity sections: namespace, labels, whether the pod has
+    spread constraints of its own (DefaultPodTopologySpread then skips
+    it) and its scoring terms with their weights. Kept on the pod."""
+    key = pod.__dict__.get("_score_tpl_memo")
+    if key is None:
+        meta = pod.metadata
+        key = pod.__dict__["_score_tpl_memo"] = (
+            meta.namespace, frozenset(meta.labels.items()),
+            bool(pod.spec.topology_spread_constraints),
+            tuple([
+                (sig, weight, required)
+                for sig, _term, weight, required
+                in family_facts.scoring_terms(pod)
+            ]),
+        )
+    return key
+
+
+def _score_templates(pods: List[Pod]) -> _Templates:
+    seen: Dict[Tuple, int] = {}
+    firsts: List[Pod] = []
+    first_at: List[int] = []
+    index = np.empty(len(pods), dtype=np.int64)
+    for i, pod in enumerate(pods):
+        key = _score_template_key(pod)
+        t = seen.get(key)
+        if t is None:
+            t = seen[key] = len(firsts)
+            firsts.append(pod)
+            first_at.append(i)
+        index[i] = t
+    return _Templates(index, firsts, first_at)
+
+
+def _selector_rows(
+    templates: _Templates,
+    tpl_selectors: List[Optional[CombinedSelector]],
+    kept: family_facts.FamilyFacts,
+    n_cap: int,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Selector spread (default_pod_topology_spread.go:78): the groups
+    ``(namespace, combined selector)`` of the batch's templates, each
+    with the live residents it matches by node row, from the census.
+    ``(counts [G, n_cap] int32, group of each template [T] int32 (-1:
+    none), templates a group matches [T, G] int32)``."""
+    firsts = templates.firsts
+    groups: Dict[Tuple, int] = {}
+    specs: List[Tuple[str, CombinedSelector, Tuple]] = []
+    tpl_group = np.full(len(firsts), -1, dtype=np.int32)
+    for t, cs in enumerate(tpl_selectors):
+        if cs is None:
+            continue
+        namespace = firsts[t].metadata.namespace
+        sig = ("combined", _combined_sig(cs))
+        g = groups.get((namespace, sig))
+        if g is None:
+            if len(specs) >= WIDE_SEL_GROUPS:
+                raise _past("selector_groups", templates.first_at[t])
+            g = groups[(namespace, sig)] = len(specs)
+            specs.append((namespace, cs, sig))
+        tpl_group[t] = g
+    own_row = kept.node_values(family_facts.ROW_INDEX).values
+    counts = np.zeros((len(specs), n_cap), dtype=np.int32)
+    tpl_match = np.zeros((len(firsts), len(specs)), dtype=np.int32)
+    for g, (namespace, cs, sig) in enumerate(specs):
+        classes = kept.matching_in(namespace, sig, cs.matches)
+        counts[g] = kept.counts(classes, own_row, live_only=True)[:n_cap]
+        for t, p in enumerate(firsts):
+            if p.metadata.namespace == namespace and cs.matches(
+                p.metadata.labels
+            ):
+                tpl_match[t, g] = 1
+    return counts, tpl_group, tpl_match
+
+
+def _soft_rows(
+    pods: List[Pod], kept: family_facts.FamilyFacts, n_cap: int, b: int
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Soft topology spread (podtopologyspread/scoring.go): the groups
+    ``(namespace, key, selector)`` of the batch's ScheduleAnyway
+    constraints, each with its key's node-value row and the live
+    residents it matches by value, from the census. ``(soft_counts,
+    soft_node_value, pod_soft_groups, pod_soft_match)``."""
+    v_soft = _value_capacity_shared(n_cap)
+    soft_counts = np.zeros((MAX_SOFT_GROUPS, v_soft), dtype=np.int32)
+    soft_node_value = np.full((MAX_SOFT_GROUPS, n_cap), -1, dtype=np.int32)
+    pod_soft_groups = np.full((b, MAX_SOFT_CONSTRAINTS), -1, dtype=np.int32)
+    pod_soft_match = np.zeros((b, MAX_SOFT_GROUPS), dtype=np.int32)
+    soft_specs: List[Tuple[str, str, object]] = []
+    soft_group_ids: Dict[Tuple, int] = {}
+    for i, p in enumerate(pods):
+        soft = _soft_constraints(p)
+        if len(soft) > MAX_SOFT_CONSTRAINTS:
+            raise _past("soft_constraints", i)
+        # per-pod node eligibility scoping (the pod's own
+        # nodeSelector/affinity, scoring.go:120) can't share group
+        # counts -- the caller routes such pods to the host path
+        for ci, c in enumerate(soft):
+            sig = (
+                p.metadata.namespace,
+                c.topology_key,
+                _selector_sig(c.label_selector),
+            )
+            g = soft_group_ids.get(sig)
+            if g is None:
+                if len(soft_specs) >= MAX_SOFT_GROUPS:
+                    raise _past("soft_groups", i)
+                g = len(soft_specs)
+                soft_group_ids[sig] = g
+                soft_specs.append(
+                    (p.metadata.namespace, c.topology_key, c.label_selector)
+                )
+            pod_soft_groups[i, ci] = g
+    for g, (ns, key, sel) in enumerate(soft_specs):
+        values = kept.node_values(key)
+        if values is None:
+            raise ScoreEnvelopeExceeded("soft_values")
+        soft_node_value[g] = values.values
+        classes = kept.matching(
+            [((ns,), sel, family_facts.selector_sig(sel))]
+        )
+        soft_counts[g] = kept.counts(classes, values.values, live_only=True)
+        for i, p in enumerate(pods):
+            if p.metadata.namespace == ns and labels_match_selector(
+                p.metadata.labels, sel
+            ):
+                pod_soft_match[i, g] = 1
+    return soft_counts, soft_node_value, pod_soft_groups, pod_soft_match
+
+
+def _ipa_rows(
+    templates: _Templates,
+    kept: family_facts.FamilyFacts,
+    n_cap: int,
+    hard_weight: int,
+) -> Tuple[np.ndarray, ...]:
+    """Preferred inter-pod affinity (scoring.go:110-268): a row for
+    every distinct term ``(namespaces, selector, key)`` that a resident
+    carries (the term owners, which score every incoming pod) or a pod
+    of the batch does, the residents' first, in an order their history
+    does not change. For each row: the key's node-value row, the
+    residents that MATCH the term by value (the incoming pod's own terms
+    gather these, family a) and the signed weight of the residents that
+    CARRY it by value (gathered where the incoming pod matches, family
+    c); for each template: its signed weights by row, the rows it
+    matches, and what it adds to the carried weight once placed.
+    ``(node values [R, n_cap], counts [R, V], wcounts [R, V], weight
+    [T, R], match [T, R], bump [T, R])``."""
+    firsts = templates.firsts
+    rows: List[Tuple] = []  # (namespaces, selector, selector sig, key)
+    row_ids: Dict[Tuple, int] = {}
+
+    def row_of(sig: Tuple, selector, at: int) -> int:
+        r = row_ids.get(sig)
+        if r is None:
+            if len(rows) >= WIDE_IPA_ROWS:
+                raise _past("preferred_affinity_rows", at)
+            r = row_ids[sig] = len(rows)
+            namespaces, sel_sig, key = sig
+            rows.append((namespaces, selector, sel_sig, key))
+        return r
+
+    owners = [
+        o for o in sorted(kept.term_owners(), key=lambda o: repr(o.sig))
+        if o.preferred or (o.required and hard_weight > 0)
+    ]
+    for o in owners:
+        row_of(o.sig, o.selector, 0)
+    tpl_weight = np.zeros((len(firsts), WIDE_IPA_ROWS), dtype=np.float32)
+    tpl_bump = np.zeros((len(firsts), WIDE_IPA_ROWS), dtype=np.float32)
+    for t, p in enumerate(firsts):
+        for sig, term, weight, required in family_facts.scoring_terms(p):
+            if required and hard_weight <= 0:
+                continue
+            r = row_of(sig, term.label_selector, templates.first_at[t])
+            if required:
+                # the pod's own symmetric contribution once placed
+                tpl_bump[t, r] += float(hard_weight)
+            else:
+                tpl_weight[t, r] += weight
+                tpl_bump[t, r] += weight
+    n_rows = len(rows)
+    v_ipa = _value_capacity_shared(n_cap)
+    node_value = np.full((n_rows, n_cap), -1, dtype=np.int32)
+    counts = np.zeros((n_rows, v_ipa), dtype=np.float32)
+    wcounts = np.zeros((n_rows, v_ipa), dtype=np.float32)
+    tpl_match = np.zeros((len(firsts), n_rows), dtype=np.float32)
+    for r, (namespaces, selector, sel_sig, key) in enumerate(rows):
+        values = kept.node_values(key)
+        if values is None:
+            raise ScoreEnvelopeExceeded("preferred_affinity_values")
+        node_value[r] = values.values
+        classes = kept.matching([(namespaces, selector, sel_sig)])
+        counts[r] = kept.counts(classes, values.values, live_only=False)
+        for t, p in enumerate(firsts):
+            if p.metadata.namespace in namespaces and labels_match_selector(
+                p.metadata.labels, selector
+            ):
+                tpl_match[t, r] = 1.0
+    for o in owners:
+        r = row_ids[o.sig]
+        at, mass = o.mass(hard_weight)
+        vals = node_value[r][at]
+        on = vals >= 0
+        wcounts[r] = np.bincount(
+            vals[on], weights=mass[on], minlength=v_ipa
+        ).astype(np.float32)
+    return (
+        node_value, counts, wcounts,
+        tpl_weight[:, :n_rows], tpl_match, tpl_bump[:, :n_rows],
+    )
+
+
 def pack_score_batch(
     pods: List[Pod],
     snapshot: Snapshot,
@@ -509,7 +766,7 @@ def pack_score_batch(
     (those that score some node above 0), ``score_live`` (a
     ``ScoreBatch`` was returned) and ``score_sigs`` (the static rows its
     pods asked for, ``_static_sig``). A batch that asks for more than
-    ``MAX_SCORE_SIGS`` rows raises ``ScoreSignatureCap`` with where to
+    ``MAX_SCORE_SIGS`` rows raises ``ScoreEnvelopeCut`` with where to
     cut it."""
     infos = snapshot.list_node_infos()
     n_cap = nt.capacity
@@ -554,8 +811,10 @@ def pack_score_batch(
     need_avoid = any_avoid
     need_taint = any_soft_taints
 
-    # combined selectors only exist when owner objects do
-    selectors: List[Optional[CombinedSelector]] = [None] * b
+    # combined selectors only exist when owner objects do; one a pod
+    # template (namespace, labels: all that ``default_selector`` reads)
+    templates: Optional[_Templates] = None
+    tpl_selectors: List[Optional[CombinedSelector]] = []
     need_sel = False
     if informers is not None and any(
         inf_list
@@ -566,14 +825,17 @@ def pack_score_batch(
             informers.stateful_sets().list(),
         )
     ):
-        for i, p in enumerate(pods):
-            if p.spec.topology_spread_constraints:
-                continue  # DefaultPodTopologySpread skips such pods
-            cs = default_selector(p, informers)
-            if not cs.empty:
-                selectors[i] = cs
-                need_sel = True
-
+        templates = _score_templates(pods)
+        for p in templates.firsts:
+            cs = None
+            # DefaultPodTopologySpread skips a pod with constraints
+            if not p.spec.topology_spread_constraints:
+                cs = default_selector(p, informers)
+                if cs.empty:
+                    cs = None
+                else:
+                    need_sel = True
+            tpl_selectors.append(cs)
     # preferred inter-pod affinity is live when any incoming pod carries
     # preferred terms OR any existing pod scores incoming pods
     # symmetrically (scoring.go:111; the caller may pass the cluster
@@ -594,27 +856,36 @@ def pack_score_batch(
     info_rows = nt.rows_for(infos)
     node_rows = info_rows.tolist()
     # ---- static rows ------------------------------------------------------
+    # where no static family is live every pod's signature is the same
+    # one, and its row of zeros ranks as no row does: the placeholders'
+    # ``SIG_BUCKET`` rows then, so that a batch the dynamic families
+    # alone make live leaves the kernel's VMEM to their rows
+    static_live = need_images or need_nodeaff or need_avoid or need_taint
+    sig_rows = MAX_SCORE_SIGS if static_live else SIG_BUCKET
     sig_ids: Dict[Tuple, int] = {}
     pod_sig = np.zeros(b, dtype=np.int32)
     sig_pods: List[Pod] = []
-    for i, p in enumerate(pods):
-        sig = _static_sig(
-            p, image_scores, need_nodeaff, need_taint, need_avoid
-        )
-        u = sig_ids.get(sig)
-        if u is None:
-            if len(sig_pods) >= MAX_SCORE_SIGS:
-                raise ScoreSignatureCap(i)
-            u = len(sig_pods)
-            sig_ids[sig] = u
-            sig_pods.append(p)
-        pod_sig[i] = u
+    if not static_live:
+        sig_pods = pods[:1]  # every pod at row 0
+    else:
+        for i, p in enumerate(pods):
+            sig = _static_sig(
+                p, image_scores, need_nodeaff, need_taint, need_avoid
+            )
+            u = sig_ids.get(sig)
+            if u is None:
+                if len(sig_pods) >= MAX_SCORE_SIGS:
+                    raise ScoreEnvelopeCut("score_signatures", i)
+                u = len(sig_pods)
+                sig_ids[sig] = u
+                sig_pods.append(p)
+            pod_sig[i] = u
 
     # one shape whatever the batch names: the rows past ``u_count`` stay 0
     u_count = len(sig_pods)
-    direct_rows = np.zeros((MAX_SCORE_SIGS, n_cap), dtype=np.float32)
-    nodeaff_rows = np.zeros((MAX_SCORE_SIGS, n_cap), dtype=np.int32)
-    taint_rows = np.zeros((MAX_SCORE_SIGS, n_cap), dtype=np.int32)
+    direct_rows = np.zeros((sig_rows, n_cap), dtype=np.float32)
+    nodeaff_rows = np.zeros((sig_rows, n_cap), dtype=np.int32)
+    taint_rows = np.zeros((sig_rows, n_cap), dtype=np.int32)
 
     w_avoid = float(weights.get("NodePreferAvoidPods", 0))
     if need_images:
@@ -667,233 +938,75 @@ def pack_score_batch(
             kept = family_facts.attach(facts, snapshot, nt)
         zones = kept.score_zones(lambda: _zone_rows(infos, node_rows, n_cap))
         if zones is None:
-            raise ScoreEnvelopeExceeded("too many zones")
+            raise ScoreEnvelopeExceeded("zones")
         zone_id, zone_onehot = zones
 
-    # ---- selector spread groups ------------------------------------------
-    sel_counts = np.zeros((MAX_SEL_GROUPS, n_cap), dtype=np.int32)
+    # ---- the dynamic families, from the kept facts ------------------------
+    dynamic = need_sel or need_soft or need_ipa
+    recounted0 = kept.nodes_recounted
+    if dynamic and templates is None:
+        templates = _score_templates(pods)
+    sel = soft = ipa = None
+    if dynamic:
+        with flightrecorder.stage("pack.score.dynamic"):
+            if need_sel:
+                with flightrecorder.stage("pack.score.selectors") as st:
+                    sel = _selector_rows(
+                        templates, tpl_selectors, kept, n_cap
+                    )
+                    st.set_metadata(groups=len(sel[0]))
+            if need_soft:
+                soft = _soft_rows(pods, kept, n_cap, b)
+            if need_ipa:
+                with flightrecorder.stage("pack.score.ipa") as st:
+                    ipa = _ipa_rows(
+                        templates, kept, n_cap, hard_pod_affinity_weight
+                    )
+                    st.set_metadata(rows=len(ipa[0]))
+    # one of two shapes: the wide rows where either family needs them
+    n_sel = 0 if sel is None else len(sel[0])
+    n_ipa = 0 if ipa is None else len(ipa[0])
+    wide = n_sel > MAX_SEL_GROUPS or n_ipa > MAX_IPA_ROWS
+    sel_cap = WIDE_SEL_GROUPS if wide else MAX_SEL_GROUPS
+    ipa_cap = WIDE_IPA_ROWS if wide else MAX_IPA_ROWS
+    v_cap = _value_capacity_shared(n_cap)
+
+    sel_counts = np.zeros((sel_cap, n_cap), dtype=np.int32)
     pod_sel_group = np.full(b, -1, dtype=np.int32)
-    pod_sel_match = np.zeros((b, MAX_SEL_GROUPS), dtype=np.int32)
-    sel_groups: Dict[Tuple, int] = {}
-    group_selectors: List[Tuple[str, CombinedSelector]] = []
-    if need_sel:
-        for i, cs in enumerate(selectors):
-            if cs is None:
-                continue
-            key = (pods[i].metadata.namespace, _combined_sig(cs))
-            g = sel_groups.get(key)
-            if g is None:
-                if len(group_selectors) >= MAX_SEL_GROUPS:
-                    raise ScoreEnvelopeExceeded("too many selector groups")
-                g = len(group_selectors)
-                sel_groups[key] = g
-                group_selectors.append((pods[i].metadata.namespace, cs))
-            pod_sel_group[i] = g
-        for g, (ns, cs) in enumerate(group_selectors):
-            for j, ni in zip(node_rows, infos):
-                count = 0
-                for p in ni.pods:
-                    if (
-                        p.metadata.namespace == ns
-                        and p.metadata.deletion_timestamp is None
-                        and cs.matches(p.metadata.labels)
-                    ):
-                        count += 1
-                sel_counts[g, j] = count
-            for i, p in enumerate(pods):
-                if p.metadata.namespace == ns and cs.matches(
-                    p.metadata.labels
-                ):
-                    pod_sel_match[i, g] = 1
+    pod_sel_match = np.zeros((b, sel_cap), dtype=np.int32)
+    if sel is not None:
+        counts, tpl_group, tpl_match = sel
+        sel_counts[:n_sel] = counts
+        pod_sel_group = tpl_group[templates.index]
+        pod_sel_match[:, :n_sel] = tpl_match[templates.index]
 
-    # ---- soft topology spread groups -------------------------------------
-    v_soft = _value_capacity_shared(n_cap, MAX_SOFT_VALUES)
-    soft_counts = np.zeros((MAX_SOFT_GROUPS, v_soft), dtype=np.int32)
-    soft_node_value = np.full((MAX_SOFT_GROUPS, n_cap), -1, dtype=np.int32)
-    pod_soft_groups = np.full((b, MAX_SOFT_CONSTRAINTS), -1, dtype=np.int32)
-    pod_soft_match = np.zeros((b, MAX_SOFT_GROUPS), dtype=np.int32)
-    if need_soft:
-        soft_specs: List[Tuple[str, str, object]] = []
-        soft_group_ids: Dict[Tuple, int] = {}
-        for i, p in enumerate(pods):
-            soft = _soft_constraints(p)
-            if len(soft) > MAX_SOFT_CONSTRAINTS:
-                raise ScoreEnvelopeExceeded("too many soft constraints")
-            # per-pod node eligibility scoping (the pod's own
-            # nodeSelector/affinity, scoring.go:120) can't share group
-            # counts -- the caller routes such pods to the host path
-            for ci, c in enumerate(soft):
-                sig = (
-                    p.metadata.namespace,
-                    c.topology_key,
-                    _selector_sig(c.label_selector),
-                )
-                g = soft_group_ids.get(sig)
-                if g is None:
-                    if len(soft_specs) >= MAX_SOFT_GROUPS:
-                        raise ScoreEnvelopeExceeded("too many soft groups")
-                    g = len(soft_specs)
-                    soft_group_ids[sig] = g
-                    soft_specs.append(
-                        (p.metadata.namespace, c.topology_key, c.label_selector)
-                    )
-                pod_soft_groups[i, ci] = g
-        for g, (ns, key, sel) in enumerate(soft_specs):
-            value_ids: Dict[str, int] = {}
-            for j, ni in zip(node_rows, infos):
-                node = ni.node
-                if node is None:
-                    continue
-                val = node.metadata.labels.get(key)
-                if val is None:
-                    continue
-                vid = value_ids.get(val)
-                if vid is None:
-                    if len(value_ids) >= v_soft:
-                        raise ScoreEnvelopeExceeded("too many soft values")
-                    vid = len(value_ids)
-                    value_ids[val] = vid
-                soft_node_value[g, j] = vid
-                count = 0
-                for p in ni.pods:
-                    if (
-                        p.metadata.deletion_timestamp is None
-                        and p.metadata.namespace == ns
-                        and labels_match_selector(p.metadata.labels, sel)
-                    ):
-                        count += 1
-                soft_counts[g, vid] += count
-            for i, p in enumerate(pods):
-                if p.metadata.namespace == ns and labels_match_selector(
-                    p.metadata.labels, sel
-                ):
-                    pod_soft_match[i, g] = 1
-
-    # ---- preferred inter-pod affinity (scoring.go:110-268) ----------------
-    v_ipa = _value_capacity_shared(n_cap, MAX_IPA_VALUES)
-    ipa_node_value = np.full((MAX_IPA_ROWS, n_cap), -1, dtype=np.int32)
-    ipa_counts = np.zeros((MAX_IPA_ROWS, v_ipa), dtype=np.float32)
-    ipa_wcounts = np.zeros((MAX_IPA_ROWS, v_ipa), dtype=np.float32)
-    pod_ipa_weight = np.zeros((b, MAX_IPA_ROWS), dtype=np.float32)
-    pod_ipa_match = np.zeros((b, MAX_IPA_ROWS), dtype=np.float32)
-    pod_ipa_bump = np.zeros((b, MAX_IPA_ROWS), dtype=np.float32)
-    if need_ipa:
-        from kubernetes_tpu.ops.affinity import (
-            _Matcher,
-            _selector_sig as _aff_sel_sig,
-            _term_namespaces,
+    if soft is None:
+        soft = (
+            np.zeros((MAX_SOFT_GROUPS, v_cap), dtype=np.int32),
+            np.full((MAX_SOFT_GROUPS, n_cap), -1, dtype=np.int32),
+            np.full((b, MAX_SOFT_CONSTRAINTS), -1, dtype=np.int32),
+            np.zeros((b, MAX_SOFT_GROUPS), dtype=np.int32),
         )
+    soft_counts, soft_node_value, pod_soft_groups, pod_soft_match = soft
 
-        matcher = _Matcher()
-        ipa_rows: List[Tuple] = []  # (namespaces, selector, sel_sig, key)
-        ipa_row_ids: Dict[Tuple, int] = {}
-        row_value_ids: List[Dict[str, int]] = []
-
-        def ipa_row(owner: Pod, term) -> int:
-            sig = (
-                _term_namespaces(owner, term),
-                _aff_sel_sig(term.label_selector),
-                term.topology_key,
-            )
-            r = ipa_row_ids.get(sig)
-            if r is None:
-                if len(ipa_rows) >= MAX_IPA_ROWS:
-                    raise ScoreEnvelopeExceeded(
-                        "too many preferred-affinity rows"
-                    )
-                r = len(ipa_rows)
-                ipa_row_ids[sig] = r
-                ipa_rows.append(
-                    (
-                        _term_namespaces(owner, term),
-                        term.label_selector,
-                        _aff_sel_sig(term.label_selector),
-                        term.topology_key,
-                    )
-                )
-                ids: Dict[str, int] = {}
-                row_value_ids.append(ids)
-                for j, ni in zip(node_rows, infos):
-                    node = ni.node
-                    if node is None:
-                        continue
-                    val = node.metadata.labels.get(term.topology_key)
-                    if val is None:
-                        continue
-                    vid = ids.get(val)
-                    if vid is None:
-                        if len(ids) >= v_ipa:
-                            raise ScoreEnvelopeExceeded(
-                                "too many preferred-affinity values"
-                            )
-                        vid = len(ids)
-                        ids[val] = vid
-                    ipa_node_value[r, j] = vid
-            return r
-
-        def signed_terms(pod: Pod):
-            """(term, signed_weight) for everything this pod contributes
-            as an EXISTING pod (processExistingPod :111): required
-            affinity x hard weight, preferred affinity +w, preferred
-            anti-affinity -w."""
-            out = []
-            if hard_pod_affinity_weight > 0:
-                for t in _required_aff_terms(pod):
-                    out.append((t, float(hard_pod_affinity_weight)))
-            for wt in _preferred_aff_terms(pod):
-                out.append((wt.pod_affinity_term, float(wt.weight)))
-            for wt in _preferred_anti_terms(pod):
-                out.append((wt.pod_affinity_term, -float(wt.weight)))
-            return out
-
-        # incoming pods' preferred terms (family a: count-gather rows)
-        for i, p in enumerate(pods):
-            for wt in _preferred_aff_terms(p):
-                r = ipa_row(p, wt.pod_affinity_term)
-                pod_ipa_weight[i, r] += float(wt.weight)
-            for wt in _preferred_anti_terms(p):
-                r = ipa_row(p, wt.pod_affinity_term)
-                pod_ipa_weight[i, r] -= float(wt.weight)
-            # the pod's own symmetric contributions once placed
-            for t, wgt in signed_terms(p):
-                r = ipa_row(p, t)
-                pod_ipa_bump[i, r] += wgt
-
-        node_of_pod = {}
-        for j, ni in zip(node_rows, infos):
-            for e in ni.pods:
-                node_of_pod[id(e)] = j
-
-        # existing pods' symmetric terms (family c: weighted mass at the
-        # owner's topology value)
-        for ni in snapshot.have_pods_with_affinity_list:
-            if ni.node is None:
-                continue
-            for e in ni.pods_with_affinity:
-                j = node_of_pod.get(id(e))
-                if j is None:
-                    continue
-                for t, wgt in signed_terms(e):
-                    r = ipa_row(e, t)
-                    v = ipa_node_value[r, j]
-                    if v >= 0:
-                        ipa_wcounts[r, v] += wgt
-
-        # family-a counts: matching EXISTING pods per row per value, and
-        # the per-pod match matrix (count replay + family-c gather)
-        for j, ni in zip(node_rows, infos):
-            if ni.node is None:
-                continue
-            for e in ni.pods:
-                for r, (nss, sel, sel_sig, _key) in enumerate(ipa_rows):
-                    if matcher.matches(e, nss, sel, sel_sig):
-                        v = ipa_node_value[r, j]
-                        if v >= 0:
-                            ipa_counts[r, v] += 1.0
-        for i, p in enumerate(pods):
-            for r, (nss, sel, sel_sig, _key) in enumerate(ipa_rows):
-                if matcher.matches(p, nss, sel, sel_sig):
-                    pod_ipa_match[i, r] = 1.0
+    ipa_node_value = np.full((ipa_cap, n_cap), -1, dtype=np.int32)
+    ipa_counts = np.zeros((ipa_cap, v_cap), dtype=np.float32)
+    ipa_wcounts = np.zeros((ipa_cap, v_cap), dtype=np.float32)
+    pod_ipa_weight = np.zeros((b, ipa_cap), dtype=np.float32)
+    pod_ipa_match = np.zeros((b, ipa_cap), dtype=np.float32)
+    pod_ipa_bump = np.zeros((b, ipa_cap), dtype=np.float32)
+    if ipa is not None:
+        values, counts, wcounts, tpl_weight, tpl_match, tpl_bump = ipa
+        ipa_node_value[:n_ipa] = values
+        ipa_counts[:n_ipa] = counts
+        ipa_wcounts[:n_ipa] = wcounts
+        pod_ipa_weight[:, :n_ipa] = tpl_weight[templates.index]
+        pod_ipa_match[:, :n_ipa] = tpl_match[templates.index]
+        pod_ipa_bump[:, :n_ipa] = tpl_bump[templates.index]
+    if facts is not None and dynamic:
+        facts.score_dynamic_rows += n_sel + n_ipa
+        facts.score_census_nodes += len(infos)
+        facts.score_census_recounted += kept.nodes_recounted - recounted0
 
     w = np.array(
         [
@@ -930,7 +1043,7 @@ def pack_score_batch(
         pod_ipa_match=pod_ipa_match,
         pod_ipa_bump=pod_ipa_bump,
         weights=w,
-        dynamic=need_sel or need_soft or need_ipa,
+        dynamic=dynamic,
     )
 
 
@@ -986,20 +1099,17 @@ def noop_score_tensors(
         np.full(padded, -1, dtype=np.int32),
         np.zeros((padded, MAX_SEL_GROUPS), dtype=np.int32),
         np.zeros(
-            (MAX_SOFT_GROUPS, _value_capacity_shared(n_cap, MAX_SOFT_VALUES)),
-            dtype=np.int32,
+            (MAX_SOFT_GROUPS, _value_capacity_shared(n_cap)), dtype=np.int32
         ),
         np.full((MAX_SOFT_GROUPS, n_cap), -1, dtype=np.int32),
         np.full((padded, MAX_SOFT_CONSTRAINTS), -1, dtype=np.int32),
         np.zeros((padded, MAX_SOFT_GROUPS), dtype=np.int32),
         np.full((MAX_IPA_ROWS, n_cap), -1, dtype=np.int32),
         np.zeros(
-            (MAX_IPA_ROWS, _value_capacity_shared(n_cap, MAX_IPA_VALUES)),
-            dtype=np.float32,
+            (MAX_IPA_ROWS, _value_capacity_shared(n_cap)), dtype=np.float32
         ),
         np.zeros(
-            (MAX_IPA_ROWS, _value_capacity_shared(n_cap, MAX_IPA_VALUES)),
-            dtype=np.float32,
+            (MAX_IPA_ROWS, _value_capacity_shared(n_cap)), dtype=np.float32
         ),
         np.zeros((padded, MAX_IPA_ROWS), dtype=np.float32),
         np.zeros((padded, MAX_IPA_ROWS), dtype=np.float32),

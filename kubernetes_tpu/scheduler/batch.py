@@ -88,8 +88,8 @@ from kubernetes_tpu.ops.host_masks import (
     static_mask_compact,
 )
 from kubernetes_tpu.ops.scoring import (
+    ScoreEnvelopeCut,
     ScoreEnvelopeExceeded,
-    ScoreSignatureCap,
     batch_selector_spread_live,
     cluster_has_affinity_scoring,
     noop_score_tensors,
@@ -315,10 +315,12 @@ def _family_pieces(
 
 
 class ScoreSignatureCut(Exception):
-    """A batch asked for more static score rows than a live score family
-    carries (``ScoreSignatureCap``): ``head`` is inside the cap, ``tail``
-    is what came after it in the solve order. ``schedule_batch`` solves
-    the one, then the other, both on the device."""
+    """A batch's pods asked for more rows than a live score family
+    carries (``ScoreEnvelopeCut``: the static rows, or the selector
+    groups and preferred-affinity rows of the wide shape): ``head`` is
+    inside the envelope, ``tail`` is what came after it in the solve
+    order. ``schedule_batch`` solves the one, then the other, both on
+    the device."""
 
     def __init__(self, head: List[PodInfo], tail: List[PodInfo]) -> None:
         super().__init__("batch cut at the score signature cap")
@@ -2078,13 +2080,16 @@ class BatchScheduler(Scheduler):
                             cluster_affinity_scoring=cluster_ipa,
                             admissions=adms, facts=facts,
                         )
-                except ScoreSignatureCap as cap:
-                    # cut where the cap is met, unless the batch has to
-                    # stay whole (a gang's passes, a bisection's halves);
-                    # routed either way, so no other family is packed
+                except ScoreEnvelopeCut as cap:
+                    # cut where the envelope is met, unless the batch has
+                    # to stay whole (a gang's passes, a bisection's
+                    # halves); routed either way, so no other family is
+                    # packed
                     if not (gangs or inactive_uids or raise_on_exhaust):
                         cut_at = cap.fit
-                    routed = ("score_signatures", True)
+                        if cap.reason != "score_signatures":
+                            facts.score_dynamic_cuts += 1
+                    routed = (cap.reason, True)
                 except ScoreEnvelopeExceeded:
                     # the sequential path filters against the host
                     # cache, which must include every in-flight placement
@@ -2122,9 +2127,12 @@ class BatchScheduler(Scheduler):
                     )
                 })
             if cut_at is not None:
-                self.score_signature_cuts += 1
-                metrics.score_signature_caps.inc(action="cut")
-                span.finish(routed="score_signature_cut")
+                if routed[0] == "score_signatures":
+                    self.score_signature_cuts += 1
+                    metrics.score_signature_caps.inc(action="cut")
+                    span.finish(routed="score_signature_cut")
+                else:
+                    span.finish(routed="score_dynamic_cut")
                 in_order = [solver_infos[int(i)] for i in order]
                 raise ScoreSignatureCut(in_order[:cut_at], in_order[cut_at:])
             if routed is not None:

@@ -467,6 +467,19 @@ score_family_batches = registry.register(Counter(
     "family's envelope goes to the host path and is not counted.",
     ("live",),
 ))
+score_envelope_exceeded = registry.register(Counter(
+    "scheduler_score_envelope_exceeded_total",
+    "Batches the score packer found past one of the score family's "
+    "envelopes (ops/scoring.ScoreEnvelopeExceeded), by reason, counted "
+    "where it is raised: score_signatures, selector_groups, "
+    "preferred_affinity_rows, soft_constraints and soft_groups are the "
+    "batch's own rows, and the dispatcher cuts such a batch where the "
+    "row past the envelope is asked for (a batch that must stay whole, "
+    "or whose first pod alone is past it, goes to the host path); zones, "
+    "soft_values and preferred_affinity_values are the cluster's, and "
+    "send the batch to the host path.",
+    ("reason",),
+))
 score_signature_caps = registry.register(Counter(
     "scheduler_score_signature_cap_total",
     "Batches that asked for more static score rows than the device "

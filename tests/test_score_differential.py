@@ -16,7 +16,13 @@ import time
 import numpy as np
 import pytest
 
-from kubernetes_tpu.api.types import ObjectMeta, Service
+from kubernetes_tpu.api.types import (
+    LabelSelector,
+    ObjectMeta,
+    OwnerReference,
+    ReplicaSet,
+    Service,
+)
 from kubernetes_tpu.apiserver.server import APIServer
 from kubernetes_tpu.cache.cache import SchedulerCache
 from kubernetes_tpu.cache.snapshot import Snapshot
@@ -418,3 +424,97 @@ def test_a_batch_served_from_the_kept_rows_places_as_the_host_oracle_does(
     assert facts.score_node_rows_reused > facts.score_live - 1
     sequential, _ = _run_images(seed, "most", batch=False, waves=2)
     assert batch == sequential
+
+
+# -- a cluster's worth of Services and soft anti-affinity (ISSUE 51) ----------
+
+SERVICES = 48
+
+
+def _service_pod(name, k, weight=100):
+    w = (
+        make_pod(name).labels(app=f"svc-{k}")
+        .container(cpu="100m", memory="128Mi")
+        .preferred_pod_affinity(
+            "kubernetes.io/hostname", {"app": f"svc-{k}"}, weight=weight,
+            anti=True,
+        )
+    )
+    pod = w.obj()
+    pod.metadata.owner_references = [OwnerReference(
+        kind="ReplicaSet", name=f"svc-{k}", uid=f"rs-{k}", controller=True)]
+    return w
+
+
+def _run_services(seed, batch):
+    """48 Services, each with a ReplicaSet, whose pods carry the chart's
+    soft anti-affinity; residents of every service, then a batch that
+    names all 48 selector groups and all 48 terms."""
+    rng = random.Random(seed)
+    server = APIServer()
+    client = Client(server)
+    informers = InformerFactory(server)
+    sched = new_scheduler(
+        client, informers, batch=batch, max_batch=128,
+        percentage_of_nodes_to_score=100, rng=_KeepFirstRng(),
+    )
+    for i in range(12):
+        client.create_node(
+            make_node(f"n{i}")
+            .labels(**{"topology.kubernetes.io/zone": f"z{i % 3}",
+                       "kubernetes.io/hostname": f"n{i}"})
+            .capacity(cpu=str(8 + 2 * i), memory=f"{16 + 5 * i}Gi").obj()
+        )
+    for k in range(SERVICES):
+        server.create(Service(
+            metadata=ObjectMeta(name=f"svc-{k}", namespace="default"),
+            selector={"app": f"svc-{k}"},
+        ))
+        server.create(ReplicaSet(
+            metadata=ObjectMeta(name=f"svc-{k}", namespace="default"),
+            selector=LabelSelector(match_labels={"app": f"svc-{k}"}),
+        ))
+    for k in range(SERVICES):
+        for e in range(1 + k % 3):
+            client.create_pod(
+                _service_pod(f"ex{k}-{e}", k).node(
+                    f"n{rng.randrange(12)}").obj()
+            )
+    informers.start()
+    informers.wait_for_cache_sync()
+    sched.queue.run()
+    ks = list(range(SERVICES)) + [
+        rng.randrange(SERVICES) for _ in range(40)
+    ]
+    rng.shuffle(ks)
+    for i, k in enumerate(ks):
+        client.create_pod(
+            _service_pod(f"m{i}", k, weight=rng.choice([100, 100, 30]))
+            .creation_timestamp(float(i)).obj()
+        )
+    # the sequential scheduler has neither counter
+    before = getattr(sched, "pods_fallback", 0)
+    sched.start()
+    residents = sum(1 + k % 3 for k in range(SERVICES))
+    pods = _wait_decided(client, sched, residents + len(ks))
+    fallback = getattr(sched, "pods_fallback", 0) - before
+    facts = getattr(sched, "family_facts", None)
+    rows = facts.score_dynamic_rows if facts else 0
+    sched.stop()
+    informers.stop()
+    return {
+        p.metadata.name: p.spec.node_name
+        for p in pods if p.metadata.name.startswith("m")
+    }, fallback, rows
+
+
+@pytest.mark.parametrize("seed", [4, 19, 51])
+def test_48_services_and_48_terms_stay_on_the_device_and_rank_as_sequential(
+    seed,
+):
+    placed, fallback, rows = _run_services(seed, batch=True)
+    assert fallback == 0
+    # the batches carried every group and every row
+    assert rows >= 2 * SERVICES
+    assert all(placed.values())
+    assert placed == _run_services(seed, batch=False)[0]

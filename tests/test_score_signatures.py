@@ -49,7 +49,7 @@ from kubernetes_tpu.ops.pallas_constrained import (
 from kubernetes_tpu.ops.scoring import (
     MAX_SCORE_SIGS,
     SIG_BUCKET,
-    ScoreSignatureCap,
+    ScoreEnvelopeCut,
     noop_score_tensors,
     pack_score_batch,
     pad_score_tensors,
@@ -316,7 +316,7 @@ def _snapshot(seed, apps, soft_taints=False):
 def test_the_packer_says_where_to_cut():
     rng, snap, nt = _snapshot(5, 70)
     pods = _pods(rng, 70, each=1)
-    with pytest.raises(ScoreSignatureCap) as cap:
+    with pytest.raises(ScoreEnvelopeCut) as cap:
         pack_score_batch(pods, snap, nt, None, WEIGHTS)
     assert cap.value.fit == MAX_SCORE_SIGS
     facts = FamilyFacts()
