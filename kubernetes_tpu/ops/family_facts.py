@@ -15,14 +15,28 @@ for as long as the code can see that it still holds, and no longer:
 - **the pod census**: for every node row, how many resident pods of
   each class ``(namespace, labels)`` it holds, the terminating ones
   told apart (topology spread skips them, filtering.go:255; the
-  affinity counts do not). It is advanced by the snapshot's change
-  log (``Snapshot.changes_since``, read by cursor and never consumed):
-  only the nodes the log names are recounted; a truncated log, a
-  membership move, another snapshot, another slot list or first use
-  recounts every node, through the same routine. A group's count row
-  is the sum, over the classes its selector matches, of the census
-  scattered through the group's node-value row. Memory is O(resident
-  pods), not classes x nodes.
+  affinity counts do not). It keeps, for every row it has counted, the
+  pod objects it counted there and the class it put each in, and is
+  advanced by the snapshot's change log (``Snapshot.changes_since``,
+  read by cursor and never consumed): on a row the log names, the
+  ``NodeInfo``'s pods now are held against the pods counted, by
+  identity, and the counts move by the pods that came and the pods
+  that went alone; a row whose pods are the ones counted is left as it
+  is, however many rows the log names. Identity is enough because no
+  writer of a cached pod edits it where it stands: the cache's
+  ``update_pod`` takes one object out of the ``NodeInfo`` and puts
+  another in, the apiserver's ``guaranteed_update`` copies before it
+  writes, and a pod's class is read once, when the pod comes. A pod is
+  held from the visit that counted it to the first visit of its row
+  that no longer finds it, which is how long the snapshot's
+  ``NodeInfo`` holds it anyway. First use, a truncated log, a
+  membership move (the log's own flag, or a name it gives that the rows
+  do not know), another snapshot and another slot list forget all of
+  it and count every node from nothing, through the same routine: every
+  pod of every row then "came". A group's count row is the sum, over
+  the classes its selector matches, of the census scattered through
+  the group's node-value row. Memory is O(resident pods), not classes
+  x nodes: two list slots a pod.
 - **pod templates**: a batch's pods by ``(namespace, labels,
   constraints)``; the packers build each template's rows once and
   write them for all its pods with one indexed numpy write.
@@ -49,10 +63,13 @@ families do, and one fact more:
   carries (``interpodaffinity/scoring.go`` processExistingPod: preferred
   affinity +w, preferred anti-affinity -w, required affinity times the
   profile's hardPodAffinityWeight), the signed weight its owners put on
-  each node row. Counted from ``NodeInfo.pods_with_affinity`` at the
-  first ``term_owners()`` and with the census after that, node by node:
-  what the change log does not name is not walked again, and a
-  dispatcher whose batches never score by such terms counts none.
+  each node row, and how many owners that is. Counted from
+  ``NodeInfo.pods_with_affinity`` at the first ``term_owners()`` and
+  with the census after that, pod by pod: a pod that comes adds its
+  terms to its row, one that goes takes them off, and a row leaves a
+  term when its last owner does (a +w and a -w owner that cancel keep
+  their 0.0). A dispatcher whose batches never score by such terms
+  counts none.
 
 The dispatcher owns one ``FamilyFacts`` beside its ``MaskRowCache`` and
 hands it to the packers. Without one, or on a snapshot no cache feeds
@@ -65,8 +82,10 @@ family pods; no cache write, commit or ingest path knows of it.
 from __future__ import annotations
 
 from collections import OrderedDict
+from operator import is_
 from typing import (
-    Callable, Dict, FrozenSet, List, NamedTuple, Optional, Sequence, Tuple,
+    Callable, Dict, FrozenSet, List, NamedTuple, Optional, Sequence, Set,
+    Tuple,
 )
 
 import numpy as np
@@ -101,23 +120,32 @@ UNSCOPED: Tuple = ((), ())
 #: (``add_host_port_rows``); no label key is a tuple
 ROW_INDEX = ("<row>",)
 
-#: the cumulative counters ``tally`` returns, in its order; the last
-#: ten are ``pack_score_batch``'s (ops/scoring.py): ``score_node_rows``
-#: the node-side rows it asked for (one for the zones, one for each
-#: distinct image list it looked at), ``score_node_rows_reused`` those
-#: it did not have to build; of a batch whose dynamic families are live,
-#: ``score_dynamic_rows`` the selector-spread groups plus the
-#: preferred-affinity rows it carried (each kind is on its own span,
-#: ``pack.score.selectors`` ``groups`` and ``pack.score.ipa`` ``rows``),
-#: ``score_dynamic_cuts`` the batches a dynamic envelope cut in two,
-#: ``score_census_nodes`` the node rows the census answered for and
-#: ``score_census_recounted`` those of them it had to count again
+#: the cumulative counters ``tally`` returns, in its order (read by
+#: position in tests/test_incremental_pack.py: new names go at the end).
+#: ``nodes_recounted`` is the node rows the census visited to advance
+#: (every row where it counted from nothing). The ten from
+#: ``score_sigs`` on are ``pack_score_batch``'s (ops/scoring.py):
+#: ``score_node_rows`` the node-side rows it asked for (one for the
+#: zones, one for each distinct image list it looked at),
+#: ``score_node_rows_reused`` those it did not have to build; of a batch
+#: whose dynamic families are live, ``score_dynamic_rows`` the
+#: selector-spread groups plus the preferred-affinity rows it carried
+#: (each kind is on its own span, ``pack.score.selectors`` ``groups``
+#: and ``pack.score.ipa`` ``rows``), ``score_dynamic_cuts`` the batches
+#: a dynamic envelope cut in two, ``score_census_nodes`` the node rows
+#: the census answered for and ``score_census_recounted`` those of them
+#: it visited to advance. The last two say what a visit costs:
+#: ``census_pods_held`` the pods resident on the rows visited, before
+#: the visit or after it, ``census_pods_moved`` those of them put into
+#: the count or taken out of it (all of them where it counted from
+#: nothing, the pods bound or deleted since the last batch otherwise)
 TALLY = ("nodes", "nodes_recounted", "node_rows", "node_rows_reused",
          "templates", "score_sigs", "score_live",
          "score_image_sigs", "score_image_sigs_live",
          "score_node_rows", "score_node_rows_reused",
          "score_dynamic_rows", "score_dynamic_cuts",
-         "score_census_nodes", "score_census_recounted")
+         "score_census_nodes", "score_census_recounted",
+         "census_pods_moved", "census_pods_held")
 
 #: the zone rows' slot before the first build (None is a verdict)
 _UNBUILT = object()
@@ -265,15 +293,29 @@ def scoring_terms(pod: Pod) -> Tuple:
     return memo
 
 
+def _one_less(counts: Dict[int, int], j: int) -> int:
+    """Row ``j`` of ``counts`` less one, the row gone at 0: what is left."""
+    left = counts[j] - 1
+    if left:
+        counts[j] = left
+    else:
+        del counts[j]
+    return left
+
+
 class TermOwners:
     """The residents that carry one scoring term, by node row."""
 
-    __slots__ = ("sig", "selector", "preferred", "required")
+    __slots__ = ("sig", "selector", "preferred", "preferred_owners",
+                 "required")
 
     def __init__(self, sig: Tuple, term: PodAffinityTerm) -> None:
         self.sig = sig  # (namespaces, selector signature, topology key)
         self.selector = term.label_selector
+        # weights are whole numbers carried as float64: a sum that loses
+        # an owner is the sum without it, exactly
         self.preferred: Dict[int, float] = {}  # node row -> signed weight
+        self.preferred_owners: Dict[int, int] = {}  # node row -> its owners
         self.required: Dict[int, int] = {}  # node row -> owners
 
     def mass(self, hard_weight: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -313,12 +355,14 @@ class FamilyFacts:
         self._counted = False  # the census is this attach's snapshot's
         self._row_of: Dict[str, int] = {}
         self._classes: Dict[str, Dict[FrozenSet, _PodClass]] = {}
-        self._on_row: Dict[int, List[_PodClass]] = {}
-        # the term owners: signature -> owners, and what each row gave;
-        # counted from the first ``term_owners()`` on
+        # node row -> (the pods counted there, the class of each), and
+        # the ``id`` of every held pod that was counted as terminating
+        self._held: Dict[int, Tuple[List[Pod], List[_PodClass]]] = {}
+        self._held_terminating: Set[int] = set()
+        # the term owners by signature, counted from the first
+        # ``term_owners()`` on
         self._owners_kept = False
         self._owners: Dict[Tuple, TermOwners] = {}
-        self._owned_on_row: Dict[int, List[Tuple]] = {}
         self._matches: "OrderedDict[Tuple, Dict[_PodClass, bool]]" = (
             OrderedDict()
         )
@@ -343,6 +387,8 @@ class FamilyFacts:
         self.score_dynamic_cuts = 0
         self.score_census_nodes = 0
         self.score_census_recounted = 0
+        self.census_pods_moved = 0
+        self.census_pods_held = 0
 
     def tally(self) -> Tuple[int, ...]:
         return tuple(getattr(self, name) for name in TALLY)
@@ -486,9 +532,10 @@ class FamilyFacts:
     # -- the census -----------------------------------------------------------
 
     def _advance(self) -> None:
-        """Bring the census up to the snapshot: recount the nodes its
-        change log names since the last read, or every node where that
-        cannot be told."""
+        """Bring the census up to the snapshot: visit the nodes its
+        change log names since the last read, however many, or count
+        every node from nothing where the log cannot be trusted to
+        name them."""
         self._counted = True
         snapshot = self._snapshot
         names = None
@@ -496,7 +543,7 @@ class FamilyFacts:
             cursor = snapshot.change_cursor()
         else:
             names, moved, cursor = snapshot.changes_since(self._cursor)
-            if moved or (names is not None and len(names) >= len(self._row_of)):
+            if moved:
                 names = None
         self._cursor = cursor
         pairs: Optional[List[Tuple[int, NodeInfo]]] = None
@@ -520,80 +567,128 @@ class FamilyFacts:
             rows = self.info_rows()
             self._row_of = {ni.node_name: j for j, ni in zip(rows, infos)}
             self._classes = {}
-            self._on_row = {}
+            self._held = {}
+            self._held_terminating = set()
             self._owners = {}
-            self._owned_on_row = {}
             pairs = list(zip(rows, infos))
-        self._recount(pairs)
+        self._visit(pairs)
 
-    def _recount(self, pairs: List[Tuple[int, NodeInfo]]) -> None:
-        """Count the pods of each ``(row, NodeInfo)`` anew."""
+    def _visit(self, pairs: List[Tuple[int, NodeInfo]]) -> None:
+        """Move the count of each ``(row, NodeInfo)`` from the pods held
+        for the row to the node's pods now, by the pods that differ."""
         self.nodes_recounted += len(pairs)
-        on_row = self._on_row
-        classes = self._classes
+        held_rows = self._held
         emptied: List[_PodClass] = []
-        owners_kept = self._owners_kept
+        moved = resident = 0
         for j, ni in pairs:
-            for cls in on_row.pop(j, ()):
-                del cls.pods[j]
-                cls.terminating.pop(j, None)
-                if not cls.pods:
-                    emptied.append(cls)
-            if owners_kept:
-                self._recount_owners(j, ni)
-            if not ni.pods:
+            pods = ni.pods
+            held = held_rows.get(j)
+            if held is None:
+                if pods:
+                    held_rows[j] = (list(pods), self._put(j, pods))
+                    moved += len(pods)
+                    resident += len(pods)
                 continue
-            here: List[_PodClass] = []
-            for p in ni.pods:
-                meta = p.metadata
-                by_labels = classes.get(meta.namespace)
-                if by_labels is None:
-                    by_labels = classes[meta.namespace] = {}
-                key = frozenset(meta.labels.items())
-                cls = by_labels.get(key)
-                if cls is None:
-                    cls = by_labels[key] = _PodClass(
-                        meta.namespace, key, meta.labels
-                    )
-                n = cls.pods.get(j)
-                if n is None:
-                    cls.pods[j] = 1
-                    here.append(cls)
-                else:
-                    cls.pods[j] = n + 1
-                if meta.deletion_timestamp is not None:
-                    cls.terminating[j] = cls.terminating.get(j, 0) + 1
-            on_row[j] = here
+            was, classes = held
+            resident += len(was)
+            if len(pods) >= len(was) and all(map(is_, was, pods)):
+                came = pods[len(was):]  # a bind appends; mostly nothing
+            else:
+                now = set(map(id, pods))
+                stayed: List[Pod] = []
+                stayed_in: List[_PodClass] = []
+                for p, cls in zip(was, classes):
+                    if id(p) in now:
+                        stayed.append(p)
+                        stayed_in.append(cls)
+                    else:
+                        self._drop(j, p, cls, emptied)
+                        moved += 1
+                came = []
+                if len(stayed) < len(pods):
+                    known = set(map(id, stayed))
+                    came = [p for p in pods if id(p) not in known]
+                if not stayed and not came:
+                    del held_rows[j]
+                    continue
+                was[:] = stayed
+                classes[:] = stayed_in
+            if came:
+                classes.extend(self._put(j, came))
+                was.extend(came)
+                moved += len(came)
+                resident += len(came)
+        self.census_pods_moved += moved
+        self.census_pods_held += resident
         for cls in emptied:  # a class no node holds any more leaves
-            by_labels = classes[cls.namespace]
+            by_labels = self._classes[cls.namespace]
             if not cls.pods and by_labels.get(cls.key) is cls:
                 del by_labels[cls.key]
 
-    def _recount_owners(self, j: int, ni: NodeInfo) -> None:
-        """The scoring terms the pods of row ``j`` carry, anew."""
-        if not ni.pods_with_affinity and j not in self._owned_on_row:
-            return
+    def _put(self, j: int, pods: Sequence[Pod]) -> List[_PodClass]:
+        """Count ``pods``, which came to row ``j``: the class of each."""
+        classes = self._classes
+        owners_kept = self._owners_kept
+        out: List[_PodClass] = []
+        for p in pods:
+            meta = p.metadata
+            by_labels = classes.get(meta.namespace)
+            if by_labels is None:
+                by_labels = classes[meta.namespace] = {}
+            key = frozenset(meta.labels.items())
+            cls = by_labels.get(key)
+            if cls is None:
+                cls = by_labels[key] = _PodClass(
+                    meta.namespace, key, meta.labels
+                )
+            cls.pods[j] = cls.pods.get(j, 0) + 1
+            if meta.deletion_timestamp is not None:
+                cls.terminating[j] = cls.terminating.get(j, 0) + 1
+                self._held_terminating.add(id(p))
+            if owners_kept and p.spec.affinity is not None:
+                self._own(j, p, True)
+            out.append(cls)
+        return out
+
+    def _drop(
+        self, j: int, pod: Pod, cls: _PodClass, emptied: List[_PodClass]
+    ) -> None:
+        """Take ``pod``, counted in ``cls`` on row ``j``, out again."""
+        if not _one_less(cls.pods, j) and not cls.pods:
+            emptied.append(cls)
+        terminating = self._held_terminating
+        if terminating and id(pod) in terminating:
+            terminating.discard(id(pod))
+            _one_less(cls.terminating, j)
+        if self._owners_kept and pod.spec.affinity is not None:
+            self._own(j, pod, False)
+
+    def _own(self, j: int, pod: Pod, came: bool) -> None:
+        """Put the scoring terms ``pod`` carries on row ``j``, or take
+        them off it. A row leaves a term with its last owner of a kind,
+        and a term leaves with its last row."""
         owners = self._owners
-        for sig in self._owned_on_row.pop(j, ()):
-            held = owners[sig]
-            held.preferred.pop(j, None)
-            held.required.pop(j, None)
-            if not held.preferred and not held.required:
-                del owners[sig]
-        here: List[Tuple] = []
-        for p in ni.pods_with_affinity:
-            for sig, term, weight, required in scoring_terms(p):
-                held = owners.get(sig)
+        for sig, term, weight, required in scoring_terms(pod):
+            held = owners.get(sig)
+            if came:
                 if held is None:
                     held = owners[sig] = TermOwners(sig, term)
-                if j not in held.preferred and j not in held.required:
-                    here.append(sig)
                 if required:
-                    held.required[j] = held.required.get(j, 0) + required
+                    held.required[j] = held.required.get(j, 0) + 1
                 else:
                     held.preferred[j] = held.preferred.get(j, 0.0) + weight
-        if here:
-            self._owned_on_row[j] = here
+                    held.preferred_owners[j] = (
+                        held.preferred_owners.get(j, 0) + 1
+                    )
+                continue
+            if required:
+                _one_less(held.required, j)
+            elif _one_less(held.preferred_owners, j):
+                held.preferred[j] -= weight
+            else:
+                del held.preferred[j]
+            if not held.preferred and not held.required:
+                del owners[sig]
 
     def term_owners(self) -> List[TermOwners]:
         """Every scoring term some resident carries, with its owners'
@@ -604,7 +699,8 @@ class FamilyFacts:
         if not self._owners_kept:
             self._owners_kept = True
             for j, ni in zip(self.info_rows(), self.infos):
-                self._recount_owners(j, ni)
+                for p in ni.pods_with_affinity:
+                    self._own(j, p, True)
         return list(self._owners.values())
 
     def matching_in(

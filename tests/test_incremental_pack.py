@@ -1896,16 +1896,23 @@ class Cluster:
     def remove_pod(self, pod):
         self.cache.remove_pod(self.pods.pop(pod.metadata.uid))
 
-    def terminate(self, pod):
-        """The pod gains a deletion timestamp and stays on its node."""
-        new = self.resident(pod.spec.node_name, pod.metadata.labels["app"])
+    def update_pod(self, pod, app=None, terminating=False):
+        """An update hands the cache another object for ``pod``, on its
+        node still: of another app, or with a deletion timestamp."""
+        new = self.resident(
+            pod.spec.node_name, app or pod.metadata.labels["app"])
         new.metadata.name = pod.metadata.name
         new.metadata.uid = pod.metadata.uid
         new.metadata.namespace = pod.metadata.namespace
         new.spec.affinity = pod.spec.affinity
-        new.metadata.deletion_timestamp = 1.0
+        if terminating:
+            new.metadata.deletion_timestamp = 1.0
         self.cache.update_pod(pod, new)
         self.pods[new.metadata.uid] = new
+
+    def terminate(self, pod):
+        """The pod gains a deletion timestamp and stays on its node."""
+        self.update_pod(pod, terminating=True)
 
     def assume_wave(self, count, app, anti=False, on=None):
         names = on or sorted(self.nodes)
@@ -2044,6 +2051,88 @@ def test_family_packs_equal_the_walks_under_churn(seed):
     kept = c.facts
     assert kept.node_rows_reused > 0
     assert kept.nodes_recounted < kept.nodes  # not every node every time
+
+
+CENSUS_STEPS = (
+    "pod_add", "pod_add", "pod_remove", "terminate", "relabel_pod", "twins",
+    "lose_a_twin", "empty_a_class", "assume", "forget", "confirm",
+    "anti_add", "node_add", "node_remove", "relabel", "wave_on_every_node",
+)
+
+
+@pytest.mark.parametrize("seed", [52, 53, 54, 55])
+def test_family_packs_equal_the_walks_as_the_census_moves_by_difference(seed):
+    """ISSUE 52: the census moves a named row's counts by the pods that
+    came and went. The hard families read it through ``matching`` and
+    ``counts``: over a pod relabelled into another app by an update, two
+    pods of one class on one node losing one, a class emptied and made
+    again, a wave that names every node, every array stays the walks'."""
+    c = Cluster(seed=seed)
+    rng = c.rng
+    assumed, twins, done = [], [], set()
+    refill = None
+    c.check(_wave(rng, "w0"))
+    for step in range(120):
+        kind = rng.choice(CENSUS_STEPS)
+        if refill is not None:
+            kind, app, refill = "refill", refill, None
+            c.add_pod(app=app)
+        elif kind == "pod_add":
+            c.add_pod()
+        elif kind == "anti_add":
+            c.add_pod(anti=True)
+        elif kind == "pod_remove" and c.pods:
+            c.remove_pod(c.pods[rng.choice(sorted(c.pods))])
+        elif kind == "terminate" and c.pods:
+            c.terminate(c.pods[rng.choice(sorted(c.pods))])
+        elif kind == "relabel_pod" and c.pods:
+            c.update_pod(c.pods[rng.choice(sorted(c.pods))],
+                         app=rng.choice(APPS))
+        elif kind == "twins":
+            app, node = rng.choice(APPS), rng.choice(sorted(c.nodes))
+            c.add_pod(node=node, app=app)
+            twins.append(c.add_pod(node=node, app=app))
+        elif kind == "lose_a_twin" and twins:
+            pod = twins.pop()
+            if pod.metadata.uid not in c.pods:
+                continue
+            c.remove_pod(c.pods[pod.metadata.uid])
+        elif kind == "empty_a_class" and c.pods:
+            app = c.pods[rng.choice(sorted(c.pods))].metadata.labels["app"]
+            for pod in [p for p in c.pods.values()
+                        if p.metadata.labels["app"] == app]:
+                c.remove_pod(pod)
+            refill = app
+        elif kind == "assume":
+            assumed += c.assume_wave(6, rng.choice(APPS),
+                                     anti=rng.random() < 0.3)
+        elif kind == "forget" and assumed:
+            c.cache.forget_pod(assumed.pop(rng.randrange(len(assumed))))
+        elif kind == "confirm" and assumed:
+            pod = assumed.pop(rng.randrange(len(assumed)))
+            c.pods[pod.metadata.uid] = pod
+            c.cache.add_pod(pod)
+        elif kind == "node_add":
+            c.add_node(rack=rng.random() < 0.7, pool=rng.random() < 0.4)
+        elif kind == "node_remove" and len(c.nodes) > 6:
+            name = rng.choice(sorted(c.nodes))
+            assumed = [p for p in assumed if p.spec.node_name != name]
+            c.remove_node(name)
+        elif kind == "relabel":
+            c.relabel(rng.choice(sorted(c.nodes)), rng.choice(ZONES))
+        elif kind == "wave_on_every_node":
+            # the log names as many nodes as the census has rows
+            for name in sorted(c.nodes):
+                c.add_pod(node=name)
+        else:
+            continue
+        done.add(kind)
+        c.check(_wave(rng, f"w{step}", ports=step % 7 == 0))
+    assert done >= set(CENSUS_STEPS) | {"refill"}
+    kept = c.facts
+    moved, held = kept.tally()[-2:]  # census_pods_moved, census_pods_held
+    assert (moved, held) == (kept.census_pods_moved, kept.census_pods_held)
+    assert 0 < moved < held
 
 
 def _cordon(c, name):

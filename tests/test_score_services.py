@@ -244,9 +244,7 @@ def test_kept_facts_equal_the_whole_build_over_every_event():
     assert c.facts.score_census_nodes == 2 * NODES
 
     def terminate():
-        gone = dataclasses.replace(held[7])
-        gone.metadata = dataclasses.replace(
-            held[7].metadata, deletion_timestamp=1.0)
+        gone = _replaced(held[7], deletion_timestamp=1.0)
         c.cache.update_pod(held[7], gone)
         held[7] = gone
 
@@ -337,7 +335,7 @@ def test_the_term_owners_are_counted_from_the_first_batch_that_asks():
     c.cache.add_pod(_svc_pod("late", 2, node="n3"))
     assert census_of("second")
     assert c.facts.nodes_recounted == NODES + 1
-    assert not c.facts._owners and not c.facts._owned_on_row
+    assert not c.facts._owners and not c.facts._owners_kept
     # the first batch that scores by them counts every owner, and the
     # change log keeps them after that (pack() holds both to a whole build)
     got = c.pack(_batch(6, "a"))
@@ -365,6 +363,346 @@ def test_a_snapshot_no_cache_feeds_keeps_nothing():
             _batch(5, stem), snap, nt, informers, WEIGHTS))
     # every node counted at every call
     assert facts.score_census_recounted == facts.score_census_nodes == 2 * NODES
+
+
+# -- (a') the census by difference (ISSUE 52) ---------------------------------
+# The census keeps the pods it counted on every row and moves the counts
+# by the pods that came and went; what it says has to stay, bit for bit,
+# what counting every node from nothing says.
+
+
+def _replaced(pod, **meta):
+    """``pod`` as an update hands it over: another object, same uid."""
+    new = dataclasses.replace(pod)
+    new.metadata = dataclasses.replace(pod.metadata, **meta)
+    return new
+
+
+class _Walk:
+    """A random walk over everything that moves a node's pods or the
+    node rows, ``pack`` after every step holding the kept census to one
+    that keeps nothing."""
+
+    SERVICES = 12
+    STEPS = (
+        "bind", "bind", "bind", "assume", "forget", "confirm", "delete",
+        "delete", "terminate", "relabel", "twins", "lose_a_twin",
+        "empty_a_class", "cancelling_owners", "hard_owner", "zone_label",
+        "node_added", "node_removed", "node_back", "nothing",
+    )
+
+    def __init__(self, seed):
+        self.rng = random.Random(seed)
+        self.c = _Cluster(self.SERVICES)
+        self.count = NODES
+        self.gone_nodes = []
+        self.held = {}  # uid -> the object the cache holds
+        self.assumed = []
+        self.twins = []
+        self.refill = None
+        self.seq = 0
+        self.done = set()
+        for p in _residents(self.SERVICES, 2, self.rng):
+            self.add(p)
+        self.c.pack(_batch(self.SERVICES, "w"))
+
+    def name(self):
+        self.seq += 1
+        return f"walk-{self.seq}"
+
+    def node(self):
+        return self.rng.choice(sorted(self.c.nodes))
+
+    def pod(self, k=None, node=None, **kw):
+        k = self.rng.randrange(self.SERVICES) if k is None else k
+        return _svc_pod(self.name(), k, node=node or self.node(),
+                        weight=100 if k % 3 else 7 + k, **kw)
+
+    def add(self, pod):
+        self.c.cache.add_pod(pod)
+        self.held[pod.metadata.uid] = pod
+
+    def some(self):
+        return self.held[self.rng.choice(sorted(self.held))]
+
+    def update(self, old, new):
+        self.c.cache.update_pod(old, new)
+        self.held[new.metadata.uid] = new
+
+    def step(self):
+        rng, c = self.rng, self.c
+        kind = rng.choice(self.STEPS)
+        if self.refill is not None:  # the class just emptied comes back
+            kind, k = "refill", self.refill
+            self.refill = None
+            self.add(self.pod(k))
+        elif kind == "bind":
+            self.add(self.pod())
+        elif kind == "assume":
+            pod = self.pod()
+            c.cache.assume_pod(pod)
+            self.assumed.append(pod)
+        elif kind == "forget" and self.assumed:
+            c.cache.forget_pod(
+                self.assumed.pop(rng.randrange(len(self.assumed))))
+        elif kind == "confirm" and self.assumed:
+            self.add(self.assumed.pop(rng.randrange(len(self.assumed))))
+        elif kind == "delete" and self.held:
+            c.cache.remove_pod(self.held.pop(self.some().metadata.uid))
+        elif kind == "terminate" and self.held:
+            old = self.some()
+            self.update(old, _replaced(old, deletion_timestamp=1.0))
+        elif kind == "relabel" and self.held:
+            # into another service: another class, the pod's term stays
+            old = self.some()
+            app = f"svc-{rng.randrange(self.SERVICES)}"
+            self.update(old, _replaced(
+                old, labels={**old.metadata.labels, "app": app}))
+        elif kind == "twins":
+            # two pods of one class on one node
+            k, node = rng.randrange(self.SERVICES), self.node()
+            first, second = self.pod(k, node), self.pod(k, node)
+            self.add(first)
+            self.add(second)
+            self.twins.append(second)
+        elif kind == "lose_a_twin" and self.twins:
+            pod = self.twins.pop()
+            if pod.metadata.uid not in self.held:
+                return self.step()
+            c.cache.remove_pod(self.held.pop(pod.metadata.uid))
+        elif kind == "empty_a_class" and self.held:
+            app = self.some().metadata.labels["app"]
+            for uid in [u for u, p in self.held.items()
+                        if p.metadata.labels["app"] == app]:
+                c.cache.remove_pod(self.held.pop(uid))
+            self.refill = int(app.split("-")[1])
+        elif kind == "cancelling_owners":
+            # +w beside -w on one row: the sum 0.0, the row an owners' row
+            k, node = rng.randrange(self.SERVICES), self.node()
+            self.add(self.pod(k, node, anti=True))
+            self.add(self.pod(k, node, anti=False))
+        elif kind == "hard_owner":
+            pod = make_pod(self.name()).labels(app="svc-1").node(
+                self.node()).container(cpu="100m").pod_affinity(
+                ZONE, {"app": "svc-0"}).obj()
+            self.add(pod)
+        elif kind == "zone_label":
+            i = int(self.node()[1:])
+            c.add(_node(i, zone=f"z{rng.randrange(6)}"))
+        elif kind == "node_added":
+            c.add(_node(self.count))
+            self.count += 1
+        elif kind == "node_removed" and len(c.nodes) > 14:
+            name = self.node()
+            c.remove(name)  # its pods stay in the cache, on no row
+            self.gone_nodes.append(name)
+        elif kind == "node_back" and self.gone_nodes:
+            c.add(_node(int(self.gone_nodes.pop()[1:])))
+        elif kind != "nothing":
+            return self.step()
+        self.done.add(kind)
+        return kind
+
+
+@pytest.mark.parametrize("seed", [52, 53, 54, 55])
+def test_the_census_by_difference_equals_the_whole_count_over_a_walk(seed):
+    w = _Walk(seed)
+    for n in range(250):
+        what = w.step()
+        try:
+            w.c.pack(_batch(w.SERVICES, f"s{n}"), hard=1 + n % 2)
+        except AssertionError as err:
+            raise AssertionError(f"step {n} ({what}): {err}") from err
+    assert w.done >= set(_Walk.STEPS) | {"refill"}
+    facts = w.c.facts
+    # it did advance by difference: fewer pods handled than resident on
+    # the rows it visited, and never more
+    assert facts.census_pods_moved < facts.census_pods_held
+
+
+def _census(c, facts=None):
+    """The census of ``c``'s snapshot as ``facts`` (the kept one, or one
+    that keeps nothing) has it."""
+    c.cache.update_snapshot(c.snap)
+    kept = attach(facts, c.snap, c.tc.update(c.snap))
+    kept.matching_in("default", ("test", "all"), lambda labels: True)
+    return kept
+
+
+def _class_ids(facts):
+    return {id(cls) for by in facts._classes.values() for cls in by.values()}
+
+
+def _census_read(kept):
+    classes = {
+        (ns, key): (dict(cls.pods), dict(cls.terminating))
+        for ns, by_labels in kept._classes.items()
+        for key, cls in by_labels.items()
+    }
+    owners = {
+        o.sig: (dict(o.preferred), dict(o.preferred_owners), dict(o.required))
+        for o in kept.term_owners()
+    }
+    return classes, owners
+
+
+def test_a_bind_moves_one_pod_of_the_rows_it_visits():
+    c = _Cluster(services=3)
+    five = [_svc_pod(f"r{e}", e % 3, node="n4") for e in range(5)]
+    for p in five + [_svc_pod("elsewhere", 0, node="n9")]:
+        c.cache.add_pod(p)
+    c.pack(_batch(3, "a"))
+    facts = c.facts
+    # the first count: every pod of every node came
+    assert (facts.census_pods_moved, facts.census_pods_held) == (6, 6)
+    assert facts.nodes_recounted == NODES
+    c.pack(_batch(3, "b"))  # nothing moved: no row visited, no pod handled
+    assert (facts.census_pods_moved, facts.census_pods_held) == (6, 6)
+    c.cache.add_pod(_svc_pod("sixth", 1, node="n4"))
+    c.pack(_batch(3, "c"))
+    assert facts.nodes_recounted == NODES + 1
+    assert (facts.census_pods_moved, facts.census_pods_held) == (7, 12)
+    c.cache.remove_pod(five[2])
+    c.pack(_batch(3, "d"))
+    # a pod that went was resident on the row too: the six it held
+    assert (facts.census_pods_moved, facts.census_pods_held) == (8, 18)
+    # a node's own write names the row and moves no pod
+    c.add(_node(4, zone="z7"))
+    c.pack(_batch(3, "e"))
+    assert (facts.census_pods_moved, facts.census_pods_held) == (8, 23)
+
+
+def _whole_cluster(c):
+    rng = random.Random(5)
+    pods = _residents(6, 4, rng) + [
+        _svc_pod(f"every-{i}", i % 6, node=f"n{i}") for i in range(NODES)]
+    for p in pods:
+        c.cache.add_pod(p)
+    return pods
+
+
+def test_a_log_that_names_every_node_is_advanced_by_difference():
+    c = _Cluster(services=6)
+    _whole_cluster(c)
+    c.pack(_batch(6, "a"))
+    facts = c.facts
+    classes = _class_ids(facts)
+    moved, held = facts.census_pods_moved, facts.census_pods_held
+    assert moved == held == 6 * 4 + NODES
+    # a pod bound on every node: the log names as many nodes as there are
+    for i in range(NODES):
+        c.cache.add_pod(_svc_pod(f"wave-{i}", i % 6, node=f"n{i}"))
+    c.pack(_batch(6, "b"))
+    assert facts.nodes_recounted == 2 * NODES
+    assert facts.census_pods_moved - moved == NODES  # what moved, no more
+    assert facts.census_pods_held - held == held + NODES
+    assert classes == _class_ids(facts)
+
+
+def _membership_move(c):
+    c.add(_node(NODES + 3))
+
+
+def _truncated_log(c):
+    # the cap drops the older half, the census's cursor with it
+    c.snap.note_changed_many(["n0"] * 5000)
+
+
+def _new_slot_list(c):
+    c.tc = NodeTensorCache()
+
+
+@pytest.mark.parametrize(
+    "event", [_membership_move, _truncated_log, _new_slot_list],
+    ids=lambda f: f.__name__.strip("_"))
+def test_what_the_log_cannot_vouch_for_is_counted_from_nothing(event):
+    c = _Cluster(services=6)
+    pods = _whole_cluster(c)
+    c.pack(_batch(6, "a"))
+    facts = c.facts
+    classes = _class_ids(facts)
+    moved = facts.census_pods_moved
+    c.cache.remove_pod(pods[0])
+    event(c)
+    c.pack(_batch(6, "b"))
+    nodes = len(c.snap.list_node_infos())
+    assert facts.nodes_recounted == NODES + nodes
+    assert facts.census_pods_moved - moved == len(pods) - 1  # every pod came
+    assert not classes & _class_ids(facts)
+
+
+def test_a_kept_census_and_one_that_keeps_nothing_share_the_pods():
+    """Both read the same pod objects off one snapshot, in the
+    dispatcher within one call: nothing of either is on a pod."""
+    c = _Cluster(services=4)
+    rng = random.Random(11)
+    pods = _residents(4, 5, rng)
+    for p in pods:
+        c.cache.add_pod(p)
+    before = {id(p): dict(p.__dict__) for p in pods}
+    kept0 = _census_read(_census(c, c.facts))
+    fresh = _census(c)
+    assert fresh is not c.facts and not fresh.keeps
+    assert _census_read(fresh) == kept0
+    for step in range(12):
+        gone = pods.pop(rng.randrange(len(pods)))
+        c.cache.remove_pod(gone)
+        new = _svc_pod(f"in-{step}", step % 4, node=f"n{rng.randrange(NODES)}")
+        c.cache.add_pod(new)
+        pods.append(new)
+        first, second = (
+            (_census(c), _census(c, c.facts)) if step % 2
+            else (_census(c, c.facts), _census(c)))
+        assert _census_read(first) == _census_read(second)
+        third = _census(c)  # another fresh one changes nothing for the kept
+        assert _census_read(third) == _census_read(_census(c, c.facts))
+    # the classes are each census's own objects
+    assert not _class_ids(c.facts) & _class_ids(third)
+    # what the census left on a pod is a function of the pod alone: the
+    # memo of its scoring terms, which both read
+    for p in pods:
+        added = set(p.__dict__) - set(before.get(id(p), p.__dict__))
+        assert added <= {"_scoring_terms_memo"}
+    assert c.facts.census_pods_moved < c.facts.census_pods_held
+
+
+def test_a_row_leaves_a_term_with_its_last_owner_not_when_weights_cancel():
+    c = _Cluster(services=2)
+    minus = _svc_pod("minus", 0, node="n1", weight=50, anti=True)
+    plus = _svc_pod("plus", 0, node="n1", weight=50, anti=False)
+    other = _svc_pod("other", 0, node="n6", weight=50, anti=True)
+    hard = make_pod("hard").labels(app="svc-1").node("n1").container(
+        cpu="100m").pod_affinity(HOST, {"app": "svc-0"}).obj()
+    for p in (minus, plus, other, hard):
+        c.cache.add_pod(p)
+    c.pack(_batch(2, "a"))
+    (owners,) = c.facts.term_owners()  # one signature: weight is not in it
+    nt = c.tc.update(c.snap)
+    row = {name: j for j, name in enumerate(nt.names) if name}
+    n1, n6 = row["n1"], row["n6"]
+    # -50 and +50 cancel: the row stays, at 0.0, as a whole build has it
+    assert owners.preferred == {n1: 0.0, n6: -50.0}
+    assert owners.preferred_owners == {n1: 2, n6: 1}
+    assert owners.required == {n1: 1}
+    c.cache.remove_pod(plus)
+    c.pack(_batch(2, "b"))
+    assert owners.preferred == {n1: -50.0, n6: -50.0}
+    c.cache.remove_pod(minus)
+    c.pack(_batch(2, "c"))
+    # the last preferred owner of n1 left: the row leaves that kind, and
+    # the required owner keeps its own
+    assert owners.preferred == {n6: -50.0}
+    assert owners.preferred_owners == {n6: 1}
+    assert owners.required == {n1: 1}
+    c.cache.remove_pod(hard)
+    c.cache.remove_pod(other)
+    c.pack(_batch(2, "d"))
+    assert c.facts.term_owners() == []  # the term left with its last row
+    assert _census_read(c.facts)[0] == {}  # and each class with its last pod
+    c.cache.add_pod(_svc_pod("again", 0, node="n6", weight=50, anti=True))
+    c.pack(_batch(2, "e"))
+    (again,) = c.facts.term_owners()
+    assert again is not owners and again.preferred == {n6: -50.0}
 
 
 # -- (b) the two shapes -------------------------------------------------------
