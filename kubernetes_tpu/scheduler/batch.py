@@ -812,22 +812,27 @@ class BatchScheduler(Scheduler):
                 ).add(pi.pod.metadata.uid)
         if not inside:
             return batch_infos
-        cos = self._coscheduling(batch_infos)
-        if cos is None:
-            return batch_infos
-        room = size - len(batch_infos)
-        for key, uids in inside.items():
-            if room <= 0:
-                break
-            known, holding = cos.members(*key)
-            missing = [
-                pod_key for uid, pod_key in known.items()
-                if uid not in uids and uid not in holding
-            ]
-            if missing and len(missing) <= room:
-                took = self.queue.take(missing)
-                batch_infos.extend(took)
-                room -= len(took)
+        with flightrecorder.stage(
+            "gang_siblings", totals=self.stage_totals, groups=len(inside)
+        ) as siblings:
+            cos = self._coscheduling(batch_infos)
+            if cos is None:
+                return batch_infos
+            room = size - len(batch_infos)
+            had = len(batch_infos)
+            for key, uids in inside.items():
+                if room <= 0:
+                    break
+                known, holding = cos.members(*key)
+                missing = [
+                    pod_key for uid, pod_key in known.items()
+                    if uid not in uids and uid not in holding
+                ]
+                if missing and len(missing) <= room:
+                    took = self.queue.take(missing)
+                    batch_infos.extend(took)
+                    room -= len(took)
+            siblings.set_metadata(took=len(batch_infos) - had)
         return batch_infos
 
     def _gang_keys(self, solver_infos: List[PodInfo]):
@@ -906,11 +911,12 @@ class BatchScheduler(Scheduler):
         self, solver_infos, pod_scheduling_cycle, gangs, cos, stats
     ):
         totals = self.stage_totals
-        uid_of = [pi.pod.metadata.uid for pi in solver_infos]
-        members: dict = {}  # gang -> indices into solver_infos
-        for i, key in enumerate(gangs):
-            if key is not None:
-                members.setdefault(key, []).append(i)
+        with flightrecorder.stage("gang_fixup.members", totals=totals):
+            uid_of = [pi.pod.metadata.uid for pi in solver_infos]
+            members: dict = {}  # gang -> indices into solver_infos
+            for i, key in enumerate(gangs):
+                if key is not None:
+                    members.setdefault(key, []).append(i)
         with flightrecorder.stage("gang_fixup.census", totals=totals):
             quorum = self._gang_quorums(solver_infos, members, cos)
         # a gang that the batch's members cannot bring to its quorum
@@ -925,9 +931,10 @@ class BatchScheduler(Scheduler):
         bound = 2  # until the first pass has shown the batch's templates
         pending = None
         while True:
-            inactive = {
-                uid_of[i] for key in masked for i in members[key]
-            }
+            with flightrecorder.stage("gang_fixup.members", totals=totals):
+                inactive = {
+                    uid_of[i] for key in masked for i in members[key]
+                }
             if pending is not None:
                 with flightrecorder.stage(
                     "gang_fixup.resolve", totals=totals,
@@ -997,23 +1004,26 @@ class BatchScheduler(Scheduler):
                     key for key, why in masked.items() if why == "count"
                 )
             masked.update(certain)
-        for key in requeue:
-            masked[key] = "requeue"
-        inactive = {uid_of[i] for key in masked for i in members[key]}
-        pending["gang_failed_uids"] = inactive
-        pending["gang_requeue_uids"] = {
-            uid_of[i] for key in requeue for i in members[key]
-        }
-        stats["masked_groups"] = len(masked)
-        stats["masked_pods"] = len(inactive)
-        stats["requeued_pods"] = len(pending["gang_requeue_uids"])
-        # members of a masked gang that wait at Permit from an earlier
-        # batch wait for members that are not coming: their nodes are
-        # given back now, not at the timeout
-        for ns, group in masked:
-            cos.reject_waiting(
-                ns, group, "the rest of the pod group was not placed"
-            )
+        with flightrecorder.stage(
+            "gang_fixup.verdict", totals=totals, rejected_groups=len(masked)
+        ):
+            for key in requeue:
+                masked[key] = "requeue"
+            inactive = {uid_of[i] for key in masked for i in members[key]}
+            pending["gang_failed_uids"] = inactive
+            pending["gang_requeue_uids"] = {
+                uid_of[i] for key in requeue for i in members[key]
+            }
+            stats["masked_groups"] = len(masked)
+            stats["masked_pods"] = len(inactive)
+            stats["requeued_pods"] = len(pending["gang_requeue_uids"])
+            # members of a masked gang that wait at Permit from an earlier
+            # batch wait for members that are not coming: their nodes are
+            # given back now, not at the timeout
+            for ns, group in masked:
+                cos.reject_waiting(
+                    ns, group, "the rest of the pod group was not placed"
+                )
         return pending
 
     def _gang_quorums(self, solver_infos, members, cos) -> dict:
@@ -1651,92 +1661,109 @@ class BatchScheduler(Scheduler):
         # drain-redispatch is honestly its own span), with the per-pod
         # linkage (uid -> batch id, queue-wait, attempts) that makes a
         # pod's whole pod-to-bind path one join
-        now_m = time.monotonic()
-        waits = [max(0.0, now_m - pi.timestamp) for pi in solver_infos]
-        if flightrecorder.ENABLED:
-            span = flightrecorder.begin_batch(
-                len(solver_infos),
-                pods=[
-                    (pi.pod.metadata.uid, wait, pi.attempts)
-                    for pi, wait in zip(solver_infos, waits)
-                ],
+        # (dispatch's own children are trace-only, as it is: each holds
+        # 1 ms a batch or more in a burst cell, PERF.md section 5)
+        with flightrecorder.stage("dispatch.begin") as begin:
+            now_m = time.monotonic()
+            waits = [max(0.0, now_m - pi.timestamp) for pi in solver_infos]
+            if flightrecorder.ENABLED:
+                span = flightrecorder.begin_batch(
+                    len(solver_infos),
+                    pods=[
+                        (pi.pod.metadata.uid, wait, pi.attempts)
+                        for pi, wait in zip(solver_infos, waits)
+                    ],
+                )
+                pop_note = self._pop_note
+                if pop_note is not None:
+                    # the stages ran in the queue, before this batch had a
+                    # span: only the ring is still to be written
+                    self._pop_note = None
+                    work, pop_waited = pop_note
+                    if pop_waited:
+                        span.stage("pop_wait", pop_waited)
+                    span.stage("pop_batch", work)
+                if inactive_uids:
+                    span.note(gang_redispatch=True)
+                if raise_on_exhaust:
+                    span.note(bisect=True)
+            else:
+                span = flightrecorder.NULL_SPAN
+            dispatch.set_metadata(
+                batch=span.batch_id,
+                queue_wait_sum_ms=round(sum(waits) * 1e3, 3),
+                queue_wait_max_ms=round(max(waits, default=0.0) * 1e3, 3),
             )
-            pop_note = self._pop_note
-            if pop_note is not None:
-                # the stages ran in the queue, before this batch had a
-                # span: only the ring is still to be written
-                self._pop_note = None
-                work, pop_waited = pop_note
-                if pop_waited:
-                    span.stage("pop_wait", pop_waited)
-                span.stage("pop_batch", work)
-            if inactive_uids:
-                span.note(gang_redispatch=True)
-            if raise_on_exhaust:
-                span.note(bisect=True)
-        else:
-            span = flightrecorder.NULL_SPAN
-        dispatch.set_metadata(
-            batch=span.batch_id,
-            queue_wait_sum_ms=round(sum(waits) * 1e3, 3),
-            queue_wait_max_ms=round(max(waits, default=0.0) * 1e3, 3),
-        )
+            begin.set_metadata(batch=span.batch_id)
         totals = self.stage_totals
         with flightrecorder.stage("pack", span, totals) as packing:
-            pods = [pi.pod for pi in solver_infos]
-            # poison manifestation: any stamped pod in the dispatch fails
-            # every ladder tier (PoisonError), driving the exhaustion the
-            # bisection containment hangs off; a sub-batch WITHOUT the
-            # stamped pod solves normally -- exactly the signature the
-            # O(log B) search isolates on
-            poison_key = None
-            if get_injector() is not None:
-                for pod_p in pods:
-                    if pod_is_poisoned(pod_p):
-                        poison_key = pod_p.key()
-                        break
-            # batch-level constraint aggregates from the cached admission
-            # feature bits (scheduler/admission.py): any() over memo reads
-            # instead of re-walking every spec per dispatch
-            adms = self._memo_admissions(solver_infos)
-            has_hard_spread = any(a.hard_spread for a in adms)
-            batch_ports = any(a.ports for a in adms)
-            has_affinity_terms = any(a.affinity_req for a in adms)
-            has_affinity = has_affinity_terms or batch_ports
-            has_required_anti = any(a.required_anti for a in adms)
-            prof0 = self.profiles.get(pods[0].spec.scheduler_name)
-            # the batch's resource score rule is its profile's
-            config = (
-                self._profile_rules.get(pods[0].spec.scheduler_name)
-                or self.solver_config
-            )
-            # gated on the profile actually scoring with InterPodAffinity --
-            # otherwise the ipa family packs nothing and draining for it
-            # would serialize the pipeline for free
-            ipa_weight = (
-                prof0.score_plugin_weights().get("InterPodAffinity", 0)
-                if prof0 is not None
-                else 0
-            )
-            score_dynamic = (
-                any(a.score_soft for a in adms)
-                or (
-                    bool(ipa_weight)
-                    and any(a.score_pref for a in adms)
-                )
-                or batch_selector_spread_live(
-                    pods, prof0.informers if prof0 is not None else None
-                )
-            )
-            # this batch's pods become symmetric scorers for later batches
-            # once placed (preferred terms, and required affinity terms via
-            # hardPodAffinityWeight)
-            has_scoring_terms = bool(ipa_weight) and any(
-                a.scoring_terms for a in adms
-            )
-            nominated_by_node = self.queue.all_nominated_pods_by_node()
-
+            # pack's parts (``batch_id`` on each): totals and trace
+            # only, the ring keeps the one ``pack``
             batch_id = span.batch_id
+            with flightrecorder.stage(
+                "pack.aggregates", totals=totals, batch=batch_id
+            ) as aggregates:
+                pods = [pi.pod for pi in solver_infos]
+                # poison manifestation: any stamped pod in the dispatch fails
+                # every ladder tier (PoisonError), driving the exhaustion the
+                # bisection containment hangs off; a sub-batch WITHOUT the
+                # stamped pod solves normally -- exactly the signature the
+                # O(log B) search isolates on
+                poison_key = None
+                if get_injector() is not None:
+                    for pod_p in pods:
+                        if pod_is_poisoned(pod_p):
+                            poison_key = pod_p.key()
+                            break
+                # batch-level constraint aggregates from the cached admission
+                # feature bits (scheduler/admission.py): any() over memo reads
+                # instead of re-walking every spec per dispatch
+                adms = self._memo_admissions(solver_infos)
+                has_hard_spread = any(a.hard_spread for a in adms)
+                batch_ports = any(a.ports for a in adms)
+                has_affinity_terms = any(a.affinity_req for a in adms)
+                has_affinity = has_affinity_terms or batch_ports
+                has_required_anti = any(a.required_anti for a in adms)
+                prof0 = self.profiles.get(pods[0].spec.scheduler_name)
+                # the batch's resource score rule is its profile's
+                config = (
+                    self._profile_rules.get(pods[0].spec.scheduler_name)
+                    or self.solver_config
+                )
+                # gated on the profile actually scoring with
+                # InterPodAffinity -- otherwise the ipa family packs nothing
+                # and draining for it would serialize the pipeline for free
+                ipa_weight = (
+                    prof0.score_plugin_weights().get("InterPodAffinity", 0)
+                    if prof0 is not None
+                    else 0
+                )
+                score_dynamic = (
+                    any(a.score_soft for a in adms)
+                    or (
+                        bool(ipa_weight)
+                        and any(a.score_pref for a in adms)
+                    )
+                    or batch_selector_spread_live(
+                        pods, prof0.informers if prof0 is not None else None
+                    )
+                )
+                # this batch's pods become symmetric scorers for later batches
+                # once placed (preferred terms, and required affinity terms via
+                # hardPodAffinityWeight)
+                has_scoring_terms = bool(ipa_weight) and any(
+                    a.scoring_terms for a in adms
+                )
+                nominated_by_node = self.queue.all_nominated_pods_by_node()
+                nominee_uids = (
+                    {
+                        p.metadata.uid
+                        for noms in nominated_by_node.values()
+                        for p in noms
+                    }
+                    if nominated_by_node else set()
+                )
+                aggregates.set_metadata(nominees=len(nominee_uids))
 
             def drain_inflight(reason: str) -> None:
                 # the dispatcher waits, inside pack, for the batches in
@@ -1763,14 +1790,6 @@ class BatchScheduler(Scheduler):
                 nominated_by_node = self.queue.all_nominated_pods_by_node()
                 return True
 
-            nominee_uids = (
-                {
-                    p.metadata.uid
-                    for noms in nominated_by_node.values()
-                    for p in noms
-                }
-                if nominated_by_node else set()
-            )
             drained(
                 "spread" if has_hard_spread
                 else "affinity" if has_affinity_terms
@@ -1798,8 +1817,6 @@ class BatchScheduler(Scheduler):
             )
 
             snapshot = self.algorithm.snapshot
-            # pack's parts (``batch_id`` on each): totals and trace
-            # only, the ring keeps the one ``pack``
 
             assumed_seq = 0
             # a batch in flight when the snapshot is refreshed may be
@@ -1823,14 +1840,26 @@ class BatchScheduler(Scheduler):
                         nodes=len(snapshot.node_info_list),
                     )
 
+            def cluster_terms() -> flightrecorder.stage:
+                # what the residents and the nominees ask of every batch:
+                # the three reads below, each up to the drain it may ask
+                # for (the drains and the refreshes stay pack's own
+                # children, beside these)
+                return flightrecorder.stage(
+                    "pack.cluster_terms", totals=totals, batch=batch_id
+                )
+
             refresh_snapshot()
             # existing pods with required anti-affinity constrain EVERY
             # incoming pod symmetrically (filtering.go:404) -- such clusters
             # need the affinity tensors even for batches without affinity, and
             # their counts must include any in-flight placements
-            if not has_affinity_terms and cluster_has_required_anti_affinity(
-                snapshot
-            ):
+            with cluster_terms():
+                cluster_anti = (
+                    not has_affinity_terms
+                    and cluster_has_required_anti_affinity(snapshot)
+                )
+            if cluster_anti:
                 has_affinity = True
                 has_affinity_terms = True
                 if drained("anti"):
@@ -1838,30 +1867,36 @@ class BatchScheduler(Scheduler):
             # existing pods with symmetric scoring terms make EVERY batch's
             # preferred-affinity family live (scoring.go:111): the in-flight
             # counts must land before packing
-            cluster_ipa = bool(ipa_weight) and cluster_has_affinity_scoring(
-                snapshot
-            )
+            with cluster_terms():
+                cluster_ipa = (
+                    bool(ipa_weight)
+                    and cluster_has_affinity_scoring(snapshot)
+                )
             if not score_dynamic and cluster_ipa:
                 score_dynamic = True
                 if drained("dynamic_score"):
                     refresh_snapshot()
-                    cluster_ipa = cluster_has_affinity_scoring(snapshot)
-            if nominated_by_node and (
-                has_hard_spread or has_affinity or score_dynamic
-                # a CONSTRAINED nominee (required (anti-)affinity / spread)
-                # imposes symmetric constraints the resource-only overlay
-                # can't express even for a plain batch
-                or any(
-                    p.spec.affinity is not None
-                    and (
-                        p.spec.affinity.pod_affinity is not None
-                        or p.spec.affinity.pod_anti_affinity is not None
+                    with cluster_terms():
+                        cluster_ipa = cluster_has_affinity_scoring(snapshot)
+            with cluster_terms():
+                nominee_constrained = nominated_by_node and (
+                    has_hard_spread or has_affinity or score_dynamic
+                    # a CONSTRAINED nominee (required (anti-)affinity /
+                    # spread) imposes symmetric constraints the
+                    # resource-only overlay can't express even for a
+                    # plain batch
+                    or any(
+                        p.spec.affinity is not None
+                        and (
+                            p.spec.affinity.pod_affinity is not None
+                            or p.spec.affinity.pod_anti_affinity is not None
+                        )
+                        or p.spec.topology_spread_constraints
+                        for noms in nominated_by_node.values()
+                        for p in noms
                     )
-                    or p.spec.topology_spread_constraints
-                    for noms in nominated_by_node.values()
-                    for p in noms
                 )
-            ):
+            if nominee_constrained:
                 # ADVICE r2 (medium): nominees are overlaid as RESOURCES
                 # only; the affinity/spread/score count tensors pack from
                 # the snapshot, which excludes them, so a constrained device
@@ -1915,147 +1950,170 @@ class BatchScheduler(Scheduler):
                     rows=mask_rows.shape[0],
                     rows_reused=kept.rows_reused - reused0,
                 )
-            # pods requesting resources no node advertises are unsatisfiable:
-            # point them at a dedicated all-False row
-            if batch.unsatisfiable.any():
-                mask_rows = np.concatenate(
-                    [mask_rows, np.zeros((1, nt.capacity), dtype=bool)]
-                )
-                mask_index = mask_index.copy()
-                mask_index[batch.unsatisfiable] = mask_rows.shape[0] - 1
+            with flightrecorder.stage(
+                "pack.overlay", totals=totals, batch=batch_id
+            ) as overlay:
+                # pods requesting resources no node advertises are
+                # unsatisfiable: point them at a dedicated all-False row
+                if batch.unsatisfiable.any():
+                    mask_rows = np.concatenate(
+                        [mask_rows, np.zeros((1, nt.capacity), dtype=bool)]
+                    )
+                    mask_index = mask_index.copy()
+                    mask_index[batch.unsatisfiable] = mask_rows.shape[0] - 1
 
-            # Nominated-pod overlay: reserve capacity for preemption nominees
-            # (the batch analogue of _add_nominated_pods' virtual add,
-            # generic_scheduler.go:535). Conservatively reserves for ALL
-            # nominees EXCEPT pods already being placed: this batch's own
-            # members and pods inside in-flight batches (their placement
-            # rides the device carry; overlaying them too would double-count
-            # and spuriously starve nodes -- the old answer was a full
-            # pipeline drain per dispatch while ANY nomination lived, which
-            # serialized the dispatcher against the committer for the whole
-            # post-preemption burst).
-            node_requested, node_nzr = nt.requested, nt.non_zero_requested
-            # skip the overlay for pods being placed RIGHT NOW: this batch's
-            # members and pods inside dispatched-but-not-yet-committing
-            # batches (their placement rides the device carry; overlaying
-            # them too over-reserves their nodes and cascades spurious
-            # preemption). The mid-COMMIT head batch is NOT excluded: its
-            # failures are being requeued with live nominations by the
-            # deferred wave at this very moment, and their reservations
-            # must stand.
-            batch_uids = {pi.pod.metadata.uid for pi in solver_infos}
-            with self._pending_cv:
-                for pend in self._pending_q:
-                    if not pend.get("committing"):
-                        batch_uids.update(
-                            pi.pod.metadata.uid
-                            for pi in pend["solver_infos"]
-                        )
-            overlay_pods = []
-            overlay_rows = []
-            for node_name, nominated in nominated_by_node.items():
-                if node_name not in nt.names:
-                    continue
-                j = nt.row(node_name)
-                for npod in nominated:
-                    if npod.metadata.uid in batch_uids:
+                # Nominated-pod overlay: reserve capacity for preemption
+                # nominees (the batch analogue of _add_nominated_pods' virtual
+                # add, generic_scheduler.go:535). Conservatively reserves for
+                # ALL nominees EXCEPT pods already being placed: this batch's
+                # own members and pods inside in-flight batches (their
+                # placement rides the device carry; overlaying them too would
+                # double-count and spuriously starve nodes -- the old answer
+                # was a full pipeline drain per dispatch while ANY nomination
+                # lived, which serialized the dispatcher against the committer
+                # for the whole post-preemption burst).
+                node_requested = nt.requested
+                node_nzr = nt.non_zero_requested
+                # skip the overlay for pods being placed RIGHT NOW: this
+                # batch's members and pods inside dispatched-but-not-committing
+                # batches (their placement rides the device carry; overlaying
+                # them too over-reserves their nodes and cascades spurious
+                # preemption). The mid-COMMIT head batch is NOT excluded: its
+                # failures are being requeued with live nominations by the
+                # deferred wave at this very moment, and their reservations
+                # must stand.
+                batch_uids = {pi.pod.metadata.uid for pi in solver_infos}
+                own_uids = len(batch_uids)
+                with self._pending_cv:
+                    for pend in self._pending_q:
+                        if not pend.get("committing"):
+                            batch_uids.update(
+                                pi.pod.metadata.uid
+                                for pi in pend["solver_infos"]
+                            )
+                overlay_pods = []
+                overlay_rows = []
+                for node_name, nominated in nominated_by_node.items():
+                    if node_name not in nt.names:
                         continue
-                    overlay_pods.append(npod)
-                    overlay_rows.append(j)
-            overlaid = bool(overlay_pods)
-            if overlaid:
-                node_requested = node_requested.copy()
-                node_nzr = node_nzr.copy()
-                nbatch = pack_pod_batch(overlay_pods, nt.dims)
-                np.add.at(
-                    node_requested, np.asarray(overlay_rows), nbatch.requests
+                    j = nt.row(node_name)
+                    for npod in nominated:
+                        if npod.metadata.uid in batch_uids:
+                            continue
+                        overlay_pods.append(npod)
+                        overlay_rows.append(j)
+                overlaid = bool(overlay_pods)
+                if overlaid:
+                    node_requested = node_requested.copy()
+                    node_nzr = node_nzr.copy()
+                    nbatch = pack_pod_batch(overlay_pods, nt.dims)
+                    np.add.at(
+                        node_requested, np.asarray(overlay_rows),
+                        nbatch.requests,
+                    )
+                    np.add.at(
+                        node_nzr, np.asarray(overlay_rows),
+                        nbatch.non_zero_requests,
+                    )
+                overlay.set_metadata(
+                    inflight_pods=len(batch_uids) - own_uids,
+                    overlaid=len(overlay_pods),
                 )
-                np.add.at(
-                    node_nzr, np.asarray(overlay_rows),
-                    nbatch.non_zero_requests,
+            with flightrecorder.stage(
+                "pack.order", totals=totals, batch=batch_id
+            ) as ordering:
+                b = batch.size
+                # fixed solve shape: every batch pads to max_batch so the
+                # solver JITs exactly once per (node-bucket, variant). The
+                # adaptive controller may floor the pad at its current rung
+                # instead -- small batches then run a proportionally cheaper
+                # solve -- so the signature set is {warmed rungs} +
+                # {max_batch} plus the defensive oversize bucket. Warmup
+                # compiles the BASIC layouts for every rung; constrained
+                # layouts warm at max_batch only (the pre-existing
+                # latency-rung tradeoff: rare enough that the one-time
+                # compile lands on demand), so a batch whose aggregates say
+                # constraint families may pack never ESCALATES to a mid rung
+                # -- it takes the max_batch signature as before.
+                pad_floor = self.solve_pad
+                if not pad_floor or b > pad_floor:
+                    # escalate to the smallest pre-compiled rung that fits
+                    # (ladder-aware: an oversize plain batch lands on the
+                    # next warmed rung up instead of jumping straight to the
+                    # max_batch signature); anything past every warmed rung,
+                    # or possibly-constrained, takes the max_batch signature
+                    may_constrain = (
+                        has_hard_spread or has_affinity or score_dynamic
+                        or has_scoring_terms
+                    )
+                    fitting = [p for p in self._warmup_pads if p >= b]
+                    pad_floor = (
+                        min(fitting) if fitting and not may_constrain
+                        else self.max_batch
+                    )
+                padded = max(
+                    pad_floor, POD_BUCKET * math.ceil(b / POD_BUCKET)
                 )
+                order = batch.order
+                # -- tenant fairness bias (scheduler/tenancy.py): within
+                # each priority level, re-merge the solve order so the tenant
+                # with the lowest virtual dominant share places next -- the
+                # solve order IS the arbitration point of the
+                # sequential-replay scan, so every tier
+                # (pallas/XLA/mesh/host-greedy) honors the bias with zero
+                # kernel changes. Single-tenant batches exit after one
+                # namespace sweep.
+                tt = self.tenant_shares
+                if tt is not None and b > 1:
+                    from kubernetes_tpu.scheduler.tenancy import fair_order
 
-            b = batch.size
-            # fixed solve shape: every batch pads to max_batch so the solver
-            # JITs exactly once per (node-bucket, variant). The adaptive
-            # controller may floor the pad at its current rung instead --
-            # small batches then run a proportionally cheaper solve -- so
-            # the signature set is {warmed rungs} + {max_batch} plus the
-            # defensive oversize bucket. Warmup compiles the BASIC layouts
-            # for every rung; constrained layouts warm at max_batch only
-            # (the pre-existing latency-rung tradeoff: rare enough that
-            # the one-time compile lands on demand), so a batch whose
-            # aggregates say constraint families may pack never ESCALATES
-            # to a mid rung -- it takes the max_batch signature as before.
-            pad_floor = self.solve_pad
-            if not pad_floor or b > pad_floor:
-                # escalate to the smallest pre-compiled rung that fits
-                # (ladder-aware: an oversize plain batch lands on the next
-                # warmed rung up instead of jumping straight to the
-                # max_batch signature); anything past every warmed rung,
-                # or possibly-constrained, takes the max_batch signature
-                may_constrain = (
-                    has_hard_spread or has_affinity or score_dynamic
-                    or has_scoring_terms
-                )
-                fitting = [p for p in self._warmup_pads if p >= b]
-                pad_floor = (
-                    min(fitting) if fitting and not may_constrain
-                    else self.max_batch
-                )
-            padded = max(
-                pad_floor, POD_BUCKET * math.ceil(b / POD_BUCKET)
-            )
-            order = batch.order
-            # -- tenant fairness bias (scheduler/tenancy.py): within each
-            # priority level, re-merge the solve order so the tenant with
-            # the lowest virtual dominant share places next -- the solve
-            # order IS the arbitration point of the sequential-replay scan,
-            # so every tier (pallas/XLA/mesh/host-greedy) honors the bias
-            # with zero kernel changes. Single-tenant batches exit after
-            # one namespace sweep.
-            tt = self.tenant_shares
-            if tt is not None and b > 1:
-                from kubernetes_tpu.scheduler.tenancy import fair_order
+                    tt.refresh_capacity(nt)
+                    order = fair_order(order, pods, batch.priorities, tt)
+                if gangs is not None:
+                    order = _gang_contiguous(order, gangs)
+                req = np.zeros((padded, nt.dims.num_dims), dtype=np.int32)
+                nzr = np.zeros((padded, 2), dtype=np.int32)
+                midx = np.zeros(padded, dtype=np.int32)
+                active = np.zeros(padded, dtype=bool)
+                req[:b] = batch.requests[order]
+                nzr[:b] = batch.non_zero_requests[order]
+                midx[:b] = mask_index[order]
+                active[:b] = True
+                if inactive_uids:
+                    # gang quorum fixup: masked group members solve to
+                    # NO_NODE
+                    for k in range(b):
+                        if (
+                            solver_infos[int(order[k])].pod.metadata.uid
+                            in inactive_uids
+                        ):
+                            active[k] = False
+                u = mask_rows.shape[0]
+                u_padded = MASK_ROW_BUCKET * math.ceil(u / MASK_ROW_BUCKET)
+                rows = np.zeros((u_padded, nt.capacity), dtype=bool)
+                rows[:u] = mask_rows
 
-                tt.refresh_capacity(nt)
-                order = fair_order(order, pods, batch.priorities, tt)
-            if gangs is not None:
-                order = _gang_contiguous(order, gangs)
-            req = np.zeros((padded, nt.dims.num_dims), dtype=np.int32)
-            nzr = np.zeros((padded, 2), dtype=np.int32)
-            midx = np.zeros(padded, dtype=np.int32)
-            active = np.zeros(padded, dtype=bool)
-            req[:b] = batch.requests[order]
-            nzr[:b] = batch.non_zero_requests[order]
-            midx[:b] = mask_index[order]
-            active[:b] = True
-            if inactive_uids:
-                # gang quorum fixup: masked group members solve to NO_NODE
-                for k in range(b):
-                    if (
-                        solver_infos[int(order[k])].pod.metadata.uid
-                        in inactive_uids
-                    ):
-                        active[k] = False
-            u = mask_rows.shape[0]
-            u_padded = MASK_ROW_BUCKET * math.ceil(u / MASK_ROW_BUCKET)
-            rows = np.zeros((u_padded, nt.capacity), dtype=bool)
-            rows[:u] = mask_rows
-
-            # hard topology-spread constraints solve on device via the
-            # group-count scan (ops/topology.py); required (anti-)affinity via
-            # the count-tensor replay (ops/affinity.py)
-            # non-resource score plugins: pack when they can influence ranking
-            # (dynamic families already forced a pipeline drain above, so the
-            # snapshot these counts come from includes in-flight placements)
-            ordered_pods = [pods[int(i)] for i in order]
-            hard_w = 1
-            if prof0 is not None:
-                ipa_plugin = prof0.plugin_instance("InterPodAffinity")
-                hard_w = getattr(
-                    ipa_plugin, "hard_pod_affinity_weight", 1
-                ) if ipa_plugin is not None else 1
+                # hard topology-spread constraints solve on device via the
+                # group-count scan (ops/topology.py); required (anti-)affinity
+                # via the count-tensor replay (ops/affinity.py)
+                # non-resource score plugins: pack when they can influence
+                # ranking (dynamic families already forced a pipeline drain
+                # above, so the snapshot these counts come from includes
+                # in-flight placements)
+                ordered_pods = [pods[int(i)] for i in order]
+                hard_w = 1
+                if prof0 is not None:
+                    ipa_plugin = prof0.plugin_instance("InterPodAffinity")
+                    hard_w = getattr(
+                        ipa_plugin, "hard_pod_affinity_weight", 1
+                    ) if ipa_plugin is not None else 1
+                ordering.set_metadata(
+                    padded=padded,
+                    inactive=(
+                        int(b - np.count_nonzero(active[:b]))
+                        if inactive_uids else 0
+                    ),
+                )
             score_batch = None
             spread = None
             affinity = None
@@ -2150,249 +2208,255 @@ class BatchScheduler(Scheduler):
                     self.attempt_schedule(pi)
                 return None
 
-        span.note(padded=padded)
-        dispatch.set_metadata(padded=padded)
-        solve_timer = metrics.SinceTimer(metrics.batch_solve_duration)
+        # from the packed batch to the solve's call: the prewarm test, the
+        # state's handshake, the upload's pieces and the tiers to try
+        with flightrecorder.stage("dispatch.handshake", batch=batch_id):
+            span.note(padded=padded)
+            dispatch.set_metadata(padded=padded)
+            solve_timer = metrics.SinceTimer(metrics.batch_solve_duration)
 
-        # preemption prewarm: when the batch's most demanding request
-        # fits on NO node right now, failures (and a preemption wave)
-        # are coming -- build + upload the victim pack on a helper
-        # thread WHILE the solve runs, instead of paying the ~0.25s
-        # pack + ~5MB upload inside the wave
-        if self.preemptor is not None and b:
-            free_nodes = nt.allocatable - node_requested  # [N, R]
-            req_max = req[:b].max(axis=0)
-            if not (
-                (free_nodes >= req_max).all(axis=1) & nt.valid
-            ).any():
-                self.preemptor.prewarm_pack_async()
+            # preemption prewarm: when the batch's most demanding request
+            # fits on NO node right now, failures (and a preemption wave)
+            # are coming -- build + upload the victim pack on a helper
+            # thread WHILE the solve runs, instead of paying the ~0.25s
+            # pack + ~5MB upload inside the wave
+            if self.preemptor is not None and b:
+                free_nodes = nt.allocatable - node_requested  # [N, R]
+                req_max = req[:b].max(axis=0)
+                if not (
+                    (free_nodes >= req_max).all(axis=1) & nt.valid
+                ).any():
+                    self.preemptor.prewarm_pack_async()
 
-        constrained = (
-            spread is not None
-            or affinity is not None
-            or score_batch is not None
-        )
-        if constrained and nt.capacity > CONSTRAINED_NODE_CAP:
-            self._drain_pending()
-            self.envelope_fallbacks += 1
-            span.finish(
-                tier=TIER_SEQUENTIAL, routed="constrained_node_cap"
+            constrained = (
+                spread is not None
+                or affinity is not None
+                or score_batch is not None
             )
-            for pi in solver_infos:
-                self.pods_fallback += 1
-                self.attempt_schedule(pi)
-            return None
-
-        # -- device-state generation handshake (scheduler/device_state.py) ---
-        # Runs after every route-to-host bail-out above: it reconciles the
-        # state's bookkeeping on the assumption that the decided upload /
-        # scatter actually reaches the device this dispatch.
-        state = self.device_state
-
-        def negotiate(unmirrored: bool):
-            return state.negotiate(
-                nt, self.tensor_cache, node_requested, node_nzr, overlaid,
-                in_flight=in_flight_at_pack or self._pending_exists(),
-                unmirrored=unmirrored, assumed_seq=assumed_seq,
-            )
-
-        unmirrored = self._unmirrored_exists()
-        neg = negotiate(unmirrored)
-        mirrored = False
-        if neg is None and unmirrored:
-            # the dispatcher waits, outside pack, until every batch in
-            # flight has mirrored: a wait with a name of its own. With
-            # nothing unmirrored the answer cannot change (a pack that
-            # predates a commit stays one): straight to the drain
-            with flightrecorder.stage(
-                "mirror_wait", totals=totals, batch=span.batch_id
-            ):
-                mirrored = self._await_mirrors()
-        if mirrored:
-            # the blocked path (membership adopt / divergence repair)
-            # only needs the carry to equal the expectation, which holds
-            # the moment every in-flight batch has MIRRORED -- so wait for
-            # the mirrors (the committer signals them; typically a few
-            # ms) and renegotiate before paying a full pipeline drain
-            neg = negotiate(False)
-            if neg is not None:
-                self.speculative_rewinds += 1
-                metrics.speculative_rewinds.inc(reason="mirror_wait")
-        if neg is None:
-            # the handshake needs an upload but the device carry is ahead
-            # of the host by the in-flight batches (node churn, bind
-            # failure, dead carry): land them, then redo this dispatch
-            # from the fresh host state
-            if self._pending_exists():
-                self.speculative_rewinds += 1
-                metrics.speculative_rewinds.inc(reason="drain")
-            self._drain_pending()
-            span.finish(routed="drain_redispatch")
-            return self._dispatch_solve(
-                solver_infos, pod_scheduling_cycle,
-                inactive_uids=inactive_uids,
-            )
-        if neg.row_patch_rewind:
-            self.speculative_rewinds += 1
-            metrics.speculative_rewinds.inc(reason="row_patch")
-        # how the resident state is brought up to date and the rows sent
-        # for it, in the ring and on the profiler's
-        # ``sched/solve_dispatch`` span: a carry that is reused where it
-        # should have been uploaded shows here and nowhere else
-        span.note(carry=neg.carry, delta_rows=neg.delta_rows)
-        carry_stats = {
-            "devices": 1 if self.mesh is None else int(self.mesh.devices.size),
-            "carry": neg.carry,
-            "carry_rows": neg.carry_rows,
-            # of them, the slots a node joined or left since the last
-            # batch (membership churn rides the same scatter)
-            "member_rows": neg.member_rows,
-            # the pods of the batch: the steps a one-chip kernel runs of
-            # the ``padded`` that ``sched/dispatch`` says
-            "steps": int(b),
-            # the call's resource columns (four fixed, one an extended
-            # resource the nodes advertise) and whether its profile
-            # scores MostAllocated
-            "r_dims": int(nt.dims.num_dims),
-            "score_most": int(bool(config.most_allocated_weight)),
-            # the static rows of the score family the call carries: 0
-            # where the family is not live
-            "score_sig_rows": (
-                0 if score_batch is None
-                else int(score_batch.direct_rows.shape[0])
-            ),
-        }
-        # single-buffer upload: the whole batch -- including a
-        # constrained batch's ~40 family count tensors -- rides ONE
-        # int32 buffer, re-sliced (and bitcast for float tensors)
-        # on device (ops/assignment.py solve_packed), so a dispatch
-        # is one host->device transfer instead of one per operand.
-        # Chosen on an earlier machine; one transfer versus many is
-        # not re-measured on this one (PERF.md "Decisions to
-        # re-measure"). On a mesh the buffer
-        # uploads replicated while the resident node state stays
-        # SHARDED over the node axis; the delta-scatter slots apply
-        # shard-locally in the sharded twin, so steady-state churn
-        # costs O(DELTA_ROW_BUCKET) on the link regardless of N
-        pieces = [
-            ("req", req),
-            ("nzr", nzr),
-            ("midx", midx),
-            ("active", active.astype(np.int32)),
-            # on a mesh the rows ship as a separate bool operand,
-            # column-sharded host-side (ops/host_masks.py) -- each
-            # shard uploads only its [U, N/P] mask columns
-            ("rows", mask_rows_upload(rows, self.mesh)),
-        ]
-        if not neg.static_ok:
-            pieces.append(("alloc", nt.allocatable))
-            pieces.append(("valid", nt.valid.astype(np.int32)))
-        if not neg.carry_ok:
-            pieces.append(("req_state", node_requested))
-            pieces.append(("nzr_state", node_nzr))
-        else:
-            # steady state: the resident [N, R] tensors stay on
-            # device; only the changed-row scatter rides the buffer
-            pieces += delta_slot_pieces(
-                nt.capacity, nt.dims.num_dims,
-                fix_rows=neg.fix_rows, alloc_rows=neg.alloc_rows,
-                node_requested=node_requested, node_nzr=node_nzr,
-                allocatable=nt.allocatable, valid=nt.valid,
-            )
-        if constrained:
-            from kubernetes_tpu.ops.assignment import ConstPiece
-
-            def fam_pieces(prefix, packed_arrs, noop_arrs):
-                """Present families ride the buffer; absent ones
-                become ConstPiece markers (free on-device constants
-                instead of ~1MB of uploaded zeros/sentinels). On a
-                MESH absent families ride as real zero arrays
-                instead: every ConstPiece combo is its own layout
-                (= its own multi-second GSPMD compile), and the
-                mesh contract is TWO constrained jit signatures per
-                mesh shape, the score family absent (its few
-                placeholder rows) and live -- the upload cost of the
-                noop tensors is what the pre-delta mesh path always
-                paid."""
-                if packed_arrs is not None:
-                    for i, a in enumerate(packed_arrs):
-                        pieces.append((f"{prefix}{i}", np.asarray(a)))
-                elif self.mesh is not None:
-                    for i, a in enumerate(noop_arrs):
-                        pieces.append((f"{prefix}{i}", np.asarray(a)))
-                else:
-                    for i, a in enumerate(noop_arrs):
-                        pieces.append(
-                            (f"{prefix}{i}", ConstPiece.from_uniform(a))
-                        )
-
-            fam_pieces(
-                "sp",
-                pad_spread_tensors(spread, padded)
-                if spread is not None else None,
-                noop_spread_tensors(padded, nt.capacity),
-            )
-            fam_pieces(
-                "af",
-                pad_affinity_tensors(affinity, padded)
-                if affinity is not None else None,
-                noop_affinity_tensors(padded, nt.capacity),
-            )
-            fam_pieces(
-                "sc",
-                pad_score_tensors(score_batch, padded)
-                if score_batch is not None else None,
-                noop_score_tensors(padded, nt.capacity),
-            )
-        solve_mode = "constrained" if constrained else self.solver_mode
-
-        def run_device(allow_pallas: bool):
-            if poison_key is not None:
-                raise PoisonError(poison_key)
-            inj = get_injector()
-            if inj is not None:
-                hang = inj.hang_seconds_maybe(
-                    FaultPoint.DEVICE_SOLVE_HANG
+            if constrained and nt.capacity > CONSTRAINED_NODE_CAP:
+                self._drain_pending()
+                self.envelope_fallbacks += 1
+                span.finish(
+                    tier=TIER_SEQUENTIAL, routed="constrained_node_cap"
                 )
-                if hang > 0:
-                    time.sleep(hang)
-                inj.raise_maybe(FaultPoint.DEVICE_SOLVE)
-            return solve_packed(
-                pieces,
-                *state.operands(neg),
-                config=config,
-                mode=solve_mode,
-                allow_pallas=allow_pallas,
-                mesh=self.mesh,
-            )
+                for pi in solver_infos:
+                    self.pods_fallback += 1
+                    self.attempt_schedule(pi)
+                return None
 
-        def run_host_greedy():
-            if poison_key is not None:
-                # the malformed row poisons the host replay too (it
-                # packs from the same arrays); only the per-pod
-                # sequential oracle fails it ALONE
-                raise PoisonError(poison_key)
-            a, r_out, z_out = host_greedy_assign(
-                nt.allocatable, node_requested, node_nzr, nt.valid,
-                req, nzr, rows, midx, active,
-                config=config,
-            )
-            return a, r_out, z_out, None, None
+            # -- device-state generation handshake
+            # (scheduler/device_state.py) --
+            # Runs after every route-to-host bail-out above: it reconciles the
+            # state's bookkeeping on the assumption that the decided upload /
+            # scatter actually reaches the device this dispatch.
+            state = self.device_state
 
-        attempts = [
-            (t, (lambda ap=(t == TIER_PALLAS): run_device(ap)))
-            for t in self._device_tiers(
-                solve_mode, padded, nt.capacity, nt.dims.num_dims,
-                u_padded,
-            )
-        ]
-        # the host tier needs host state that reflects EVERY
-        # placement; with batches in flight the device carry is
-        # ahead of node_requested, so the tier is only offered when
-        # nothing is pending (exhaustion with pending batches drains
-        # and redispatches from fresh host state instead)
-        if not constrained and not self._pending_exists():
-            attempts.append((TIER_HOST_GREEDY, run_host_greedy))
+            def negotiate(unmirrored: bool):
+                return state.negotiate(
+                    nt, self.tensor_cache, node_requested, node_nzr, overlaid,
+                    in_flight=in_flight_at_pack or self._pending_exists(),
+                    unmirrored=unmirrored, assumed_seq=assumed_seq,
+                )
+
+            unmirrored = self._unmirrored_exists()
+            neg = negotiate(unmirrored)
+            mirrored = False
+            if neg is None and unmirrored:
+                # the dispatcher waits, outside pack, until every batch in
+                # flight has mirrored: a wait with a name of its own. With
+                # nothing unmirrored the answer cannot change (a pack that
+                # predates a commit stays one): straight to the drain
+                with flightrecorder.stage(
+                    "mirror_wait", totals=totals, batch=span.batch_id
+                ):
+                    mirrored = self._await_mirrors()
+            if mirrored:
+                # the blocked path (membership adopt / divergence repair)
+                # only needs the carry to equal the expectation, which holds
+                # the moment every in-flight batch has MIRRORED -- so wait for
+                # the mirrors (the committer signals them; typically a few
+                # ms) and renegotiate before paying a full pipeline drain
+                neg = negotiate(False)
+                if neg is not None:
+                    self.speculative_rewinds += 1
+                    metrics.speculative_rewinds.inc(reason="mirror_wait")
+            if neg is None:
+                # the handshake needs an upload but the device carry is ahead
+                # of the host by the in-flight batches (node churn, bind
+                # failure, dead carry): land them, then redo this dispatch
+                # from the fresh host state
+                if self._pending_exists():
+                    self.speculative_rewinds += 1
+                    metrics.speculative_rewinds.inc(reason="drain")
+                self._drain_pending()
+                span.finish(routed="drain_redispatch")
+                return self._dispatch_solve(
+                    solver_infos, pod_scheduling_cycle,
+                    inactive_uids=inactive_uids,
+                )
+            if neg.row_patch_rewind:
+                self.speculative_rewinds += 1
+                metrics.speculative_rewinds.inc(reason="row_patch")
+            # how the resident state is brought up to date and the rows sent
+            # for it, in the ring and on the profiler's
+            # ``sched/solve_dispatch`` span: a carry that is reused where it
+            # should have been uploaded shows here and nowhere else
+            span.note(carry=neg.carry, delta_rows=neg.delta_rows)
+            carry_stats = {
+                "devices": (
+                    1 if self.mesh is None else int(self.mesh.devices.size)
+                ),
+                "carry": neg.carry,
+                "carry_rows": neg.carry_rows,
+                # of them, the slots a node joined or left since the last
+                # batch (membership churn rides the same scatter)
+                "member_rows": neg.member_rows,
+                # the pods of the batch: the steps a one-chip kernel runs of
+                # the ``padded`` that ``sched/dispatch`` says
+                "steps": int(b),
+                # the call's resource columns (four fixed, one an extended
+                # resource the nodes advertise) and whether its profile
+                # scores MostAllocated
+                "r_dims": int(nt.dims.num_dims),
+                "score_most": int(bool(config.most_allocated_weight)),
+                # the static rows of the score family the call carries: 0
+                # where the family is not live
+                "score_sig_rows": (
+                    0 if score_batch is None
+                    else int(score_batch.direct_rows.shape[0])
+                ),
+            }
+            # single-buffer upload: the whole batch -- including a
+            # constrained batch's ~40 family count tensors -- rides ONE
+            # int32 buffer, re-sliced (and bitcast for float tensors)
+            # on device (ops/assignment.py solve_packed), so a dispatch
+            # is one host->device transfer instead of one per operand.
+            # Chosen on an earlier machine; one transfer versus many is
+            # not re-measured on this one (PERF.md "Decisions to
+            # re-measure"). On a mesh the buffer
+            # uploads replicated while the resident node state stays
+            # SHARDED over the node axis; the delta-scatter slots apply
+            # shard-locally in the sharded twin, so steady-state churn
+            # costs O(DELTA_ROW_BUCKET) on the link regardless of N
+            pieces = [
+                ("req", req),
+                ("nzr", nzr),
+                ("midx", midx),
+                ("active", active.astype(np.int32)),
+                # on a mesh the rows ship as a separate bool operand,
+                # column-sharded host-side (ops/host_masks.py) -- each
+                # shard uploads only its [U, N/P] mask columns
+                ("rows", mask_rows_upload(rows, self.mesh)),
+            ]
+            if not neg.static_ok:
+                pieces.append(("alloc", nt.allocatable))
+                pieces.append(("valid", nt.valid.astype(np.int32)))
+            if not neg.carry_ok:
+                pieces.append(("req_state", node_requested))
+                pieces.append(("nzr_state", node_nzr))
+            else:
+                # steady state: the resident [N, R] tensors stay on
+                # device; only the changed-row scatter rides the buffer
+                pieces += delta_slot_pieces(
+                    nt.capacity, nt.dims.num_dims,
+                    fix_rows=neg.fix_rows, alloc_rows=neg.alloc_rows,
+                    node_requested=node_requested, node_nzr=node_nzr,
+                    allocatable=nt.allocatable, valid=nt.valid,
+                )
+            if constrained:
+                from kubernetes_tpu.ops.assignment import ConstPiece
+
+                def fam_pieces(prefix, packed_arrs, noop_arrs):
+                    """Present families ride the buffer; absent ones
+                    become ConstPiece markers (free on-device constants
+                    instead of ~1MB of uploaded zeros/sentinels). On a
+                    MESH absent families ride as real zero arrays
+                    instead: every ConstPiece combo is its own layout
+                    (= its own multi-second GSPMD compile), and the
+                    mesh contract is TWO constrained jit signatures per
+                    mesh shape, the score family absent (its few
+                    placeholder rows) and live -- the upload cost of the
+                    noop tensors is what the pre-delta mesh path always
+                    paid."""
+                    if packed_arrs is not None:
+                        for i, a in enumerate(packed_arrs):
+                            pieces.append((f"{prefix}{i}", np.asarray(a)))
+                    elif self.mesh is not None:
+                        for i, a in enumerate(noop_arrs):
+                            pieces.append((f"{prefix}{i}", np.asarray(a)))
+                    else:
+                        for i, a in enumerate(noop_arrs):
+                            pieces.append(
+                                (f"{prefix}{i}", ConstPiece.from_uniform(a))
+                            )
+
+                fam_pieces(
+                    "sp",
+                    pad_spread_tensors(spread, padded)
+                    if spread is not None else None,
+                    noop_spread_tensors(padded, nt.capacity),
+                )
+                fam_pieces(
+                    "af",
+                    pad_affinity_tensors(affinity, padded)
+                    if affinity is not None else None,
+                    noop_affinity_tensors(padded, nt.capacity),
+                )
+                fam_pieces(
+                    "sc",
+                    pad_score_tensors(score_batch, padded)
+                    if score_batch is not None else None,
+                    noop_score_tensors(padded, nt.capacity),
+                )
+            solve_mode = "constrained" if constrained else self.solver_mode
+
+            def run_device(allow_pallas: bool):
+                if poison_key is not None:
+                    raise PoisonError(poison_key)
+                inj = get_injector()
+                if inj is not None:
+                    hang = inj.hang_seconds_maybe(
+                        FaultPoint.DEVICE_SOLVE_HANG
+                    )
+                    if hang > 0:
+                        time.sleep(hang)
+                    inj.raise_maybe(FaultPoint.DEVICE_SOLVE)
+                return solve_packed(
+                    pieces,
+                    *state.operands(neg),
+                    config=config,
+                    mode=solve_mode,
+                    allow_pallas=allow_pallas,
+                    mesh=self.mesh,
+                )
+
+            def run_host_greedy():
+                if poison_key is not None:
+                    # the malformed row poisons the host replay too (it
+                    # packs from the same arrays); only the per-pod
+                    # sequential oracle fails it ALONE
+                    raise PoisonError(poison_key)
+                a, r_out, z_out = host_greedy_assign(
+                    nt.allocatable, node_requested, node_nzr, nt.valid,
+                    req, nzr, rows, midx, active,
+                    config=config,
+                )
+                return a, r_out, z_out, None, None
+
+            attempts = [
+                (t, (lambda ap=(t == TIER_PALLAS): run_device(ap)))
+                for t in self._device_tiers(
+                    solve_mode, padded, nt.capacity, nt.dims.num_dims,
+                    u_padded,
+                )
+            ]
+            # the host tier needs host state that reflects EVERY
+            # placement; with batches in flight the device carry is
+            # ahead of node_requested, so the tier is only offered when
+            # nothing is pending (exhaustion with pending batches drains
+            # and redispatches from fresh host state instead)
+            if not constrained and not self._pending_exists():
+                attempts.append((TIER_HOST_GREEDY, run_host_greedy))
         try:
             with flightrecorder.stage(
                 "device_solve", span, totals, **carry_stats
@@ -2430,43 +2494,46 @@ class BatchScheduler(Scheduler):
                     exhaust_err.__cause__, PoisonError
                 ),
             )
-        assignments_dev, *resident = out
-        if tier == TIER_HOST_GREEDY:
-            state.host_solved(neg, assignments_dev, req, nzr, overlaid)
-        else:
-            if state.landed(neg, resident, overlaid):
-                self._note_device_rebuilt()
-            assignments_dev.copy_to_host_async()
-        if self.mesh is not None and tier == TIER_PALLAS:
-            self.mesh_shard_solves += 1
-        span.note(tier=booked)
-        return {
-            # the attempt's own name: its breaker guards the download
-            "tier": tier,
-            "carry_in": neg.carry_in,
-            "span": span,
-            "solver_infos": list(solver_infos),
-            "has_required_anti": has_required_anti,
-            # constraints between pods (spread, affinity, score
-            # families): the gang fixup counts no slots under them
-            "constrained": constrained,
-            "has_ports": batch_ports,
-            "has_scoring_terms": has_scoring_terms,
-            "order": order,
-            "assignments_dev": assignments_dev,
-            "download": self._eager_download(assignments_dev),
-            "req": req,
-            "nzr": nzr,
-            "b": b,
-            "names": nt.names,
-            "num_nodes": nt.num_nodes,
-            "snapshot": snapshot,
-            "cycle": pod_scheduling_cycle,
-            "overlaid": overlaid,
-            "solve_timer": solve_timer,
-            "mask_rows": mask_rows,
-            "mask_index_solved": midx,
-        }
+        # what follows the solve's dispatch: the resident state's new
+        # references, the download begun, the pending record
+        with flightrecorder.stage("dispatch.landed", batch=batch_id):
+            assignments_dev, *resident = out
+            if tier == TIER_HOST_GREEDY:
+                state.host_solved(neg, assignments_dev, req, nzr, overlaid)
+            else:
+                if state.landed(neg, resident, overlaid):
+                    self._note_device_rebuilt()
+                assignments_dev.copy_to_host_async()
+            if self.mesh is not None and tier == TIER_PALLAS:
+                self.mesh_shard_solves += 1
+            span.note(tier=booked)
+            return {
+                # the attempt's own name: its breaker guards the download
+                "tier": tier,
+                "carry_in": neg.carry_in,
+                "span": span,
+                "solver_infos": list(solver_infos),
+                "has_required_anti": has_required_anti,
+                # constraints between pods (spread, affinity, score
+                # families): the gang fixup counts no slots under them
+                "constrained": constrained,
+                "has_ports": batch_ports,
+                "has_scoring_terms": has_scoring_terms,
+                "order": order,
+                "assignments_dev": assignments_dev,
+                "download": self._eager_download(assignments_dev),
+                "req": req,
+                "nzr": nzr,
+                "b": b,
+                "names": nt.names,
+                "num_nodes": nt.num_nodes,
+                "snapshot": snapshot,
+                "cycle": pod_scheduling_cycle,
+                "overlaid": overlaid,
+                "solve_timer": solve_timer,
+                "mask_rows": mask_rows,
+                "mask_index_solved": midx,
+            }
 
     # -- blast-radius containment (robustness/containment.py) ----------------
 

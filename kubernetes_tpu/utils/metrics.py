@@ -281,11 +281,6 @@ plugin_execution_duration = registry.register(Histogram(
     "Duration for running a plugin at a specific extension point.",
     ("plugin", "extension_point", "status"),
 ))
-queue_incoming_pods = registry.register(Counter(
-    "scheduler_queue_incoming_pods_total",
-    "Number of pods added to scheduling queues by event and queue type.",
-    ("queue", "event"),
-))
 permit_wait_duration = registry.register(Histogram(
     "scheduler_permit_wait_duration_seconds",
     "Duration of waiting on permit.",

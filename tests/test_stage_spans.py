@@ -734,7 +734,8 @@ def test_a_fresh_schedulers_totals_are_its_own():
 UNCLOCKED = {"sched/dispatch", "sched/commit.gather", "sched/commit.clone",
              "sched/commit.assume", "sched/pack.score",
              "sched/pack.score.images", "sched/pack.score.zones",
-             "sched/pack.image_index"}
+             "sched/pack.image_index", "sched/dispatch.begin",
+             "sched/dispatch.handshake", "sched/dispatch.landed"}
 TICK_MS = 10.0  # the coarsest CPU clock a host of ours has
 
 
@@ -1497,3 +1498,254 @@ def test_a_pack_that_predates_a_commit_never_becomes_the_carry():
     assert int(shadow.sum()) == int(truth.requested.sum())
     assert np.array_equal(np.sort(shadow.sum(axis=1)),
                           np.sort(truth.requested.sum(axis=1)))
+
+
+# -- every millisecond of a dispatch has a name (PR 53) ----------------------
+
+#: pack's children since PR 53 and how many spans of each a dispatch
+#: without a drain shows: ``pack.cluster_terms`` is three reads, each up
+#: to the drain it may ask for
+NEW_PACK_CHILDREN = {"pack.aggregates": 1, "pack.cluster_terms": 3,
+                     "pack.overlay": 1, "pack.order": 1}
+
+
+def _inside(child, parent):
+    return (child["line"] == parent["line"]
+            and parent["start"] <= child["start"]
+            and child["end"] <= parent["end"])
+
+
+@pytest.mark.parametrize("child, a_dispatch",
+                         sorted(NEW_PACK_CHILDREN.items()))
+def test_packs_new_children_come_once_a_dispatch_inside_it(
+    burst_trace, child, a_dispatch
+):
+    events, _dump, sched = burst_trace
+    packs = named(events, "sched/pack")
+    children = named(events, "sched/" + child)
+    assert len(children) == a_dispatch * len(packs)
+    for pack in packs:
+        mine = [c for c in children
+                if c["stats"]["batch"] == pack["stats"]["batch"]]
+        assert len(mine) == a_dispatch
+        assert all(_inside(c, pack) for c in mine)
+        assert all("cpu_ms" in c["stats"] for c in mine)  # clocked: totals
+    assert sched.stage_totals.calls()[child] == \
+        a_dispatch * sched.stage_totals.calls()["pack"]
+
+
+def test_packs_children_follow_one_another_and_say_what_they_saw(burst_trace):
+    events, _dump, sched = burst_trace
+    for pack in named(events, "sched/pack"):
+        batch = pack["stats"]["batch"]
+        mine = sorted(
+            (ev for ev in events if ev["name"].startswith("sched/pack.")
+             and ev["name"].count(".") == 1
+             and ev["stats"].get("batch") == batch),
+            key=lambda ev: ev["start"],
+        )
+        assert [ev["name"][len("sched/pack."):] for ev in mine] == [
+            "aggregates", "snapshot", "cluster_terms", "cluster_terms",
+            "cluster_terms", "state", "pods", "masks", "overlay", "order",
+            "families",
+        ]
+        for before, after in zip(mine, mine[1:]):  # siblings, never nested
+            assert before["end"] <= after["start"]
+        by_name = {ev["name"]: ev for ev in mine}
+        assert own_stats(by_name["sched/pack.aggregates"]) == {
+            "batch": batch, "nominees": 0}
+        overlay = own_stats(by_name["sched/pack.overlay"])
+        assert overlay["overlaid"] == 0 and overlay["inflight_pods"] >= 0
+        (dispatch,) = [d for d in named(events, "sched/dispatch")
+                       if d["stats"]["batch"] == batch]
+        assert own_stats(by_name["sched/pack.order"]) == {
+            "batch": batch, "padded": dispatch["stats"]["padded"],
+            "inactive": 0}
+    # no gang member in the run: the sweep found none and opened no span
+    assert not named(events, "sched/gang_siblings")
+    assert "gang_siblings" not in sched.stage_totals.calls()
+
+
+def test_the_new_totals_count_dispatches_without_a_session():
+    server, client, informers, sched = _stack(num_nodes=4)
+    sched.start()
+    try:
+        _burst(client, sched, 30, tag="off")
+    finally:
+        sched.stop()
+        informers.stop()
+    calls = sched.stage_totals.calls()
+    assert calls["pack"] >= 1
+    for child, a_dispatch in NEW_PACK_CHILDREN.items():
+        assert calls[child] == a_dispatch * calls["pack"], child
+    seconds = sched.stage_seconds
+    assert sum(seconds[c] for c in NEW_PACK_CHILDREN) <= seconds["pack"]
+    assert not {"gang_siblings", "gang_fixup.members",
+                "gang_fixup.verdict"} & set(calls)
+
+
+def test_a_nominee_and_a_batch_in_flight_show_on_packs_children(tmp_path):
+    """A pod nominated onto a node and outside the batch is counted by
+    ``pack.aggregates`` and overlaid by ``pack.overlay``, which also says
+    how many uids it took from the batches in flight: those behind the
+    one the committer holds, whose own nominations stand."""
+    client, informers, sched, release = _held_stack(max_inflight=3)
+    nominee = make_pod("nominee").container(cpu="10m", memory="16Mi").obj()
+    try:
+        with profiled(tmp_path) as events:
+            _dispatch(client, sched, _plain("first"))  # committing, held
+            _wait_for(lambda: sched._pending_q[0].get("committing"),
+                      "the committer never took the first batch")
+            _dispatch(client, sched, _plain("second", 3))  # behind it
+            sched.queue.update_nominated_pod_for_node(nominee, "node-0")
+            _release_once_the_dispatcher_waits(sched, release)
+            # overlaid with batches in flight: the handshake lands them
+            # and the dispatch starts again from a fresh pack
+            _dispatch(client, sched, _plain("third", 2))
+            sched._drain_pending()
+            sched.wait_for_inflight_binds()
+    finally:
+        release.set()
+        sched.stop()
+        informers.stop()
+    by_start = lambda ev: ev["start"]
+    aggregates = sorted(named(events, "sched/pack.aggregates"), key=by_start)
+    overlays = sorted(named(events, "sched/pack.overlay"), key=by_start)
+    assert [ev["stats"]["nominees"] for ev in aggregates] == [0, 0, 1, 1]
+    assert [(ev["stats"]["inflight_pods"], ev["stats"]["overlaid"])
+            for ev in overlays] == [(0, 0), (0, 0), (3, 1), (0, 1)]
+    routed = [sp["routed"] for sp in flightrecorder.RECORDER.dump()["spans"]]
+    assert routed.count("drain_redispatch") == 1
+
+
+def _gang_stack(nodes, cpu):
+    from kubernetes_tpu.api.types import ObjectMeta, PodGroup
+
+    server = APIServer()
+    client = Client(server)
+    informers = InformerFactory(server)
+    sched = new_scheduler(client, informers, batch=True, max_batch=32)
+    for i in range(nodes):
+        client.create_node(
+            make_node(f"n{i}").capacity(cpu=cpu, memory="8Gi").obj())
+    client.create_pod_group(PodGroup(
+        metadata=ObjectMeta(name="g8", namespace="default"), min_member=8))
+    informers.start()
+    informers.wait_for_cache_sync()
+    sched.queue.run()
+    return client, informers, sched
+
+
+def _gang_pods(count=8):
+    from kubernetes_tpu.api.types import POD_GROUP_LABEL
+
+    pods = [make_pod(f"g{i}").container(cpu="1", memory="128Mi").obj()
+            for i in range(count)]
+    for pod in pods:
+        pod.metadata.labels[POD_GROUP_LABEL] = "g8"
+    return pods
+
+
+def test_a_gang_batch_shows_its_siblings_members_and_verdict(tmp_path):
+    """A gang of 8 that does not fit beside 4 plain pods that do: the
+    first pass fails the gang, the second solves with its 8 slots masked
+    (``inactive`` on that dispatch's ``pack.order``), and the verdict
+    rejects the one group."""
+    client, informers, sched = _gang_stack(nodes=1, cpu="4")
+    pods = _gang_pods() + [
+        make_pod(f"plain{i}").container(cpu="1", memory="128Mi").obj()
+        for i in range(4)
+    ]
+    try:
+        with profiled(tmp_path) as events:
+            client.create_pods_bulk(pods)
+            _wait_for(lambda: len(sched.queue.pending_pods()) == 12,
+                      "the pods never queued")
+            assert sched.schedule_batch(timeout=1.0) == 12
+            sched.wait_for_inflight_binds()
+    finally:
+        sched.stop()
+        informers.stop()
+    (fixup,) = named(events, "sched/gang_fixup")
+    assert fixup["stats"]["passes"] == 2
+    (siblings,) = named(events, "sched/gang_siblings")
+    assert own_stats(siblings) == {"groups": 1, "took": 0}
+    assert siblings["line"] == fixup["line"]
+    assert siblings["end"] <= fixup["start"]  # before any dispatch
+    # the member index once, and the masked uids once a pass
+    members = named(events, "sched/gang_fixup.members")
+    assert len(members) == 1 + fixup["stats"]["passes"]
+    (verdict,) = named(events, "sched/gang_fixup.verdict")
+    assert own_stats(verdict) == {
+        "rejected_groups": fixup["stats"]["masked_groups"]}
+    assert verdict["stats"]["rejected_groups"] == 1
+    for child in members + [verdict]:
+        assert _inside(child, fixup) and "cpu_ms" in child["stats"]
+    downloads = named(events, "sched/gang_fixup.download")
+    assert len(downloads) == fixup["stats"]["passes"]
+    # nothing of the fix-up's own is inside one of its dispatches
+    dispatches = [d for d in named(events, "sched/dispatch")
+                  if _inside(d, fixup)]
+    assert len(dispatches) == 2
+    for child in members + [verdict] + downloads:
+        assert not any(_inside(child, d) for d in dispatches)
+    first, second = sorted(named(events, "sched/pack.order"),
+                           key=lambda ev: ev["start"])
+    assert first["stats"]["inactive"] == 0
+    assert second["stats"]["inactive"] == 8
+    calls = sched.stage_totals.calls()
+    assert calls["gang_siblings"] == 1 and calls["gang_fixup.verdict"] == 1
+    assert calls["gang_fixup.members"] == 3
+    assert calls["pack.order"] == calls["pack"] == 2
+
+
+def test_gang_siblings_says_how_many_members_it_took_from_the_queue(tmp_path):
+    """A pop that holds two of a gang's eight members takes the other
+    six with it: the span's ``took``."""
+    client, informers, sched = _gang_stack(nodes=2, cpu="4")
+    try:
+        client.create_pods_bulk(_gang_pods())
+        _wait_for(lambda: len(sched.queue.pending_pods()) == 8,
+                  "the gang never queued")
+        popped = sched.queue.pop_batch(2, timeout=1.0)
+        assert len(popped) == 2
+        with profiled(tmp_path) as events:
+            whole = sched._with_gang_siblings(popped, 32)
+    finally:
+        sched.stop()
+        informers.stop()
+    assert len(whole) == 8
+    (siblings,) = named(events, "sched/gang_siblings")
+    assert own_stats(siblings) == {"groups": 1, "took": 6}
+    assert sched.stage_totals.calls()["gang_siblings"] == 1
+
+
+@pytest.mark.parametrize("child", ["dispatch.begin", "dispatch.handshake",
+                                   "dispatch.landed"])
+def test_dispatchs_own_children_are_trace_only_once_a_dispatch(
+    burst_trace, child
+):
+    """What ``sched/dispatch`` does outside pack and the solve's call:
+    spans with no totals, as ``dispatch`` itself is, so never clocked."""
+    events, _dump, sched = burst_trace
+    dispatches = named(events, "sched/dispatch")
+    children = named(events, "sched/" + child)
+    assert len(children) == len(dispatches) >= 2
+    for dispatch in dispatches:
+        (mine,) = [c for c in children
+                   if c["stats"]["batch"] == dispatch["stats"]["batch"]]
+        assert _inside(mine, dispatch)
+        assert own_stats(mine) == mine["stats"] == {
+            "batch": dispatch["stats"]["batch"]}
+        (pack,) = [p for p in named(events, "sched/pack")
+                   if p["stats"]["batch"] == dispatch["stats"]["batch"]]
+        (solve,) = [s for s in named(events, "sched/solve_dispatch")
+                    if s["stats"]["batch"] == dispatch["stats"]["batch"]]
+        if child == "dispatch.begin":
+            assert mine["end"] <= pack["start"]
+        elif child == "dispatch.handshake":
+            assert pack["end"] <= mine["start"]
+            assert mine["end"] <= solve["start"]
+        else:
+            assert solve["end"] <= mine["start"]
+    assert child not in sched.stage_totals.calls()

@@ -1542,11 +1542,14 @@ def bench_trace_overhead(num_pods: int = 1000, num_nodes: int = 200):
 #: the stage timers that make the burst's hot path
 HOT_STAGES = ("pop_batch", "pack", "device_solve", "download", "commit")
 #: every flightrecorder.stage one batch passes through
+#: (``pack.cluster_terms`` three times: one span for each read)
 BATCH_STAGES = (
-    "pop_wait", "pop_batch", "dispatch", "pack", "pack.drain",
-    "pack.snapshot", "pack.state", "pack.pods", "pack.masks",
-    "pack.families", "pack.score", "device_solve", "inflight_wait",
-    "download", "commit",
+    "pop_wait", "pop_batch", "dispatch", "pack", "pack.aggregates",
+    "pack.drain", "pack.snapshot", "pack.cluster_terms",
+    "pack.cluster_terms", "pack.cluster_terms", "pack.state", "pack.pods",
+    "pack.masks", "pack.overlay", "pack.order",
+    "pack.families", "pack.score", "dispatch.begin", "dispatch.handshake",
+    "dispatch.landed", "device_solve", "inflight_wait", "download", "commit",
     "commit.gather", "commit.clone", "commit.assume", "bind", "bind.api",
 )
 
